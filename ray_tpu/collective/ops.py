@@ -84,10 +84,7 @@ def axis_index(axis: AxisName):
 
 
 def axis_size(axis: str) -> int:
-    # jax.lax.axis_size only exists in newer JAX; psum of a Python constant
-    # over a named axis constant-folds to the axis size at trace time, so
-    # the result stays a static int (ppermute tables need it).
-    return jax.lax.psum(1, axis)
+    return jax.lax.axis_size(axis)
 
 
 def barrier_jit(axis: AxisName):

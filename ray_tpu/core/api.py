@@ -170,13 +170,10 @@ def _with_tpus(resources: Optional[dict], num_tpus: Optional[float]) -> dict:
     # Autodetect via the accelerator-manager plugin layer (reference:
     # `_private/accelerators/` consulted at node start). Explicit user
     # values always win.
-    try:
-        from ..util.accelerators import detect_node_accelerator_resources
+    from ..util.accelerators import detect_node_accelerator_resources
 
-        for key, val in detect_node_accelerator_resources().items():
-            resources.setdefault(key, val)
-    except Exception:  # noqa: BLE001
-        pass
+    for key, val in detect_node_accelerator_resources().items():
+        resources.setdefault(key, val)
     return resources
 
 

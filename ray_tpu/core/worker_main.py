@@ -675,6 +675,16 @@ class WorkerProcess:
 
         return restore
 
+    @staticmethod
+    def _claim_tpu(spec: TaskSpec):
+        """Turn a TPU grant into chips reserved for this process, before the
+        task or actor can touch JAX (`accelerators.tpu.claim_chips`)."""
+        quantity = spec.resources.get("TPU", 0)
+        if quantity > 0 and os.environ.get("RAY_TPU_WORKER_TPU") == "1":
+            from ..util.accelerators.tpu import claim_chips
+
+            claim_chips(quantity, os.environ["RAY_TPU_SESSION_DIR"])
+
     def _flush_phases(self, spec: TaskSpec, phases):
         """Ship per-task phase spans (dep-fetch/deserialize/execute/store)
         through the batched task_events channel — the controller timeline
@@ -711,6 +721,8 @@ class WorkerProcess:
             phases.append(("deserialize", t1, time.time()))
             if is_actor_method:
                 func = getattr(self.actor_instance, spec.method_name)
+            else:
+                self._claim_tpu(spec)
             # Env setup BEFORE context: if it raises (RuntimeEnvSetupError),
             # no task context was set, so nothing leaks onto later work.
             restore_env = self._runtime_env_vars(spec)
@@ -856,6 +868,7 @@ class WorkerProcess:
         try:
             resolved = self._resolve(spec, deps)
             cls, args, kwargs = resolve_payload(spec.func_payload, resolved)
+            self._claim_tpu(spec)
             self._set_ctx(spec.task_id, spec.actor_id, self._trace_of(spec))
             # Actor env vars persist for the actor's lifetime (its process
             # is dedicated) — reference behavior for actor runtime_env.
@@ -1074,6 +1087,7 @@ class WorkerProcess:
                 and not spec.arg_refs
                 and spec.num_returns == 1
                 and spec.options.runtime_env is None
+                and not spec.resources.get("TPU")
             ):
                 self._execute_task_fast(spec, reply)
             else:

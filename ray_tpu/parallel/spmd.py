@@ -51,20 +51,12 @@ def parallelize(
 
     @functools.wraps(fn)
     def wrapper(*args, **kwargs):
-        with jax.sharding.use_mesh(mesh) if hasattr(jax.sharding, "use_mesh") else _nullcontext():
+        with jax.set_mesh(mesh):
             return jitted(*args, **kwargs)
 
     wrapper.jitted = jitted
     wrapper.lower = jitted.lower
     return wrapper
-
-
-class _nullcontext:
-    def __enter__(self):
-        return None
-
-    def __exit__(self, *a):
-        return False
 
 
 def shard_fn(
@@ -86,16 +78,10 @@ def shard_fn(
     (e.g. a pipeline manual over `pp` whose stages still auto-shard over
     dp/fsdp/tp).
     """
-    if hasattr(jax, "shard_map"):
-        kwargs = dict(mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      check_vma=check_vma)
-        if manual_axes is not None:
-            kwargs["axis_names"] = frozenset(manual_axes)
-        return jax.shard_map(fn, **kwargs)
-    from jax.experimental.shard_map import shard_map  # older jax fallback
-
-    kwargs = dict(mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  check_rep=check_vma)
+    kwargs = {}
     if manual_axes is not None:
-        kwargs["auto"] = frozenset(mesh.axis_names) - frozenset(manual_axes)
-    return shard_map(fn, **kwargs)
+        kwargs["axis_names"] = frozenset(manual_axes)
+    return jax.shard_map(
+        fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
+        check_vma=check_vma, **kwargs,
+    )

@@ -156,6 +156,10 @@ class InferenceEngine:
                 f"role must be mixed|prefill|decode, got {self.opts.role!r}"
             )
         self._jnp = jax.numpy
+        # Where the kernels run, as JAX reports it — benches and the chip
+        # smoke read the platform from here, never from a flag.
+        dev = jax.devices()[0]
+        self._device = {"platform": dev.platform, "device_kind": dev.device_kind}
         if params is None:
             params = init_params(jax.random.PRNGKey(self.opts.seed), cfg)
         self.params = params
@@ -1039,6 +1043,7 @@ class InferenceEngine:
         )
         return {
             **extra,
+            **self._device,
             "queue_depth": self.scheduler.queue_depth,
             "running": self.scheduler.num_running,
             "kv_utilization": kv_stats.utilization,

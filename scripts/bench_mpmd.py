@@ -332,8 +332,8 @@ def run(record: bool, steps: int, quick: bool, interleave: int = 2,
             "model_flops_per_s_mpmd": flops_per_step / mp["median_step_s"],
             "note": (
                 "CPU host: absolute MFU is not meaningful here. The path to "
-                "the ROADMAP 40% multi-host bar: r5 measured 48% MFU "
-                "single-host (BENCH_r05.json); MPMD keeps each stage a "
+                "the ROADMAP 40% multi-host bar: the single-host step's MFU "
+                "is the ledger's (PERF_LEDGER.jsonl); MPMD keeps each stage a "
                 "single-mesh program (same per-stage MFU profile), and the "
                 "pipeline-level overheads that subtract from it are exactly "
                 "the two numbers recorded above — bubble fraction "

@@ -26,9 +26,6 @@ if "xla_force_host_platform_device_count" not in flags:
     ).strip()
 
 import jax
-
-jax.config.update("jax_platforms", "cpu")
-
 import optax
 from jax.sharding import NamedSharding, PartitionSpec as P
 

@@ -89,6 +89,9 @@ def build_static_app(serve, model_kwargs, batch, new_tokens, tpu):
             self.gen = jax.jit(make_generate(cfg, new_tokens))
             self.rng = jax.random.PRNGKey(0)
 
+        def platform(self):
+            return self.jax.devices()[0].platform
+
         @serve.batch(max_batch_size=batch, batch_wait_timeout_s=0.02)
         def __call__(self, requests):
             jnp = self.jax.numpy
@@ -110,12 +113,14 @@ def build_static_app(serve, model_kwargs, batch, new_tokens, tpu):
     return GPTStatic.bind()
 
 
-def build_engine_app(serve, model_kwargs, max_num_seqs, engine_overrides=None,
-                     deploy_overrides=None):
+def build_engine_app(serve, model_kwargs, max_num_seqs, tpu,
+                     engine_overrides=None, deploy_overrides=None):
     opts = dict(num_blocks=129, block_size=16, max_num_seqs=max_num_seqs)
     opts.update(engine_overrides or {})
+    actor_opts = {"max_concurrency": 16, **({"num_tpus": 1} if tpu else {})}
     return serve.LLMDeployment.options(
-        max_ongoing_requests=256, **(deploy_overrides or {})
+        max_ongoing_requests=256, ray_actor_options=actor_opts,
+        replica_startup_timeout_s=2400, **(deploy_overrides or {})
     ).bind(
         model="gpt2-small",
         model_overrides=model_kwargs,
@@ -164,6 +169,12 @@ def run_load(base_url, reqs, rate, seed):
     return results, wall
 
 
+def rows_platform(rows):
+    """The platform the replicas of a report's rows ran on, as each replica's
+    own `jax.devices()[0]` named it (never the --tpu flag)."""
+    return "+".join(sorted({r["platform"] for r in rows.values()}))
+
+
 def percentile(xs, p):
     """Rounded percentile, or None for an empty bucket (e.g. --p-long 0/1)."""
     if not xs:
@@ -182,7 +193,7 @@ def bench_mode(mode, args, model_kwargs):
     app = (
         build_static_app(serve, model_kwargs, args.batch, args.long, args.tpu)
         if mode == "static"
-        else build_engine_app(serve, model_kwargs, args.batch)
+        else build_engine_app(serve, model_kwargs, args.batch, args.tpu)
     )
     serve.run(app, name=f"bench_{mode}", route_prefix=f"/{mode}",
               timeout_s=2400)
@@ -234,9 +245,12 @@ def bench_mode(mode, args, model_kwargs):
             "p99_s": percentile(long_l, 0.99),
         },
     }
+    h = serve.get_app_handle(f"bench_{mode}")
     if mode == "engine":
-        h = serve.get_app_handle("bench_engine")
         out["engine_stats"] = h.engine_stats.remote().result(timeout_s=30)
+        out["platform"] = out["engine_stats"]["platform"]
+    else:
+        out["platform"] = h.platform.remote().result(timeout_s=30)
     serve.delete(f"bench_{mode}")
     return out
 
@@ -270,7 +284,9 @@ def _bench_engine_config(label, args, model_kwargs, engine_overrides, reqs,
     from ray_tpu import serve
 
     serve.start(http_options={"host": "127.0.0.1", "port": 0})
-    app = build_engine_app(serve, model_kwargs, args.batch, engine_overrides)
+    app = build_engine_app(
+        serve, model_kwargs, args.batch, args.tpu, engine_overrides
+    )
     serve.run(app, name=f"bench_{label}", route_prefix=f"/{label}",
               timeout_s=2400)
     base = f"http://127.0.0.1:{serve.http_port()}/{label}"
@@ -284,6 +300,7 @@ def _bench_engine_config(label, args, model_kwargs, engine_overrides, reqs,
     h = serve.get_app_handle(f"bench_{label}")
     stats = h.engine_stats.remote().result(timeout_s=30)
     out["engine_stats"] = stats
+    out["platform"] = stats["platform"]
     out["ttft_p50_s"] = stats.get("ttft_p50_s")
     serve.delete(f"bench_{label}")
     print(json.dumps({label: out}), flush=True)
@@ -343,7 +360,7 @@ def bench_prefix(args, model_kwargs):
             "p_long": args.p_long,
             "batch": args.batch,
             "kv_budget_blocks": 129,
-            "platform": "tpu" if args.tpu else "cpu",
+            "platform": rows_platform(rows),
         },
         "results": rows,
         "comparison": comparison,
@@ -405,7 +422,7 @@ def bench_longprompt(args, model_kwargs):
             "new_tokens": args.short,
             "p_long_prompt": args.p_long,
             "batch": args.batch,
-            "platform": "tpu" if args.tpu else "cpu",
+            "platform": rows_platform(rows),
         },
         "results": rows,
         "comparison": comparison,
@@ -439,7 +456,8 @@ def _bench_fleet_config(label, args, model_kwargs, reqs, kinds, warm,
 
     serve.start(http_options={"host": "127.0.0.1", "port": 0})
     app = build_engine_app(
-        serve, model_kwargs, args.batch, engine_overrides, deploy_overrides
+        serve, model_kwargs, args.batch, args.tpu, engine_overrides,
+        deploy_overrides,
     )
     name = f"bench_{label}"
     serve.run(app, name=name, route_prefix=f"/{label}", timeout_s=2400)
@@ -465,6 +483,7 @@ def _bench_fleet_config(label, args, model_kwargs, reqs, kinds, warm,
         spec_acc += st["spec_accepted"]
     ttfts = pre_ttfts or ttfts
     out["replicas"] = replicas
+    out["platform"] = rows_platform(per_replica)
     out["engine_options"] = dict(engine_overrides)
     out["per_replica"] = {
         t: {
@@ -597,7 +616,7 @@ def bench_fleet(args, model_kwargs):
             "p_long": args.p_long,
             "batch": args.batch,
             "kv_blocks_total": args.kv_blocks,
-            "platform": "tpu" if args.tpu else "cpu",
+            "platform": rows_platform(rows),
         },
         "results": rows,
         "comparison": comparison,
@@ -711,7 +730,7 @@ def bench_disagg(args, model_kwargs):
             "p_long": args.p_long,
             "batch": args.batch,
             "kv_blocks_total": args.kv_blocks,
-            "platform": "tpu" if args.tpu else "cpu",
+            "platform": rows_platform(rows),
         },
         "results": rows,
         "comparison": comparison,
@@ -815,7 +834,7 @@ def main():
             "long": args.long,
             "p_long": args.p_long,
             "batch": args.batch,
-            "platform": "tpu" if args.tpu else "cpu",
+            "platform": rows_platform(results),
         },
         "results": results,
     }

@@ -104,10 +104,14 @@ def test_manager_registry():
     assert get_accelerator_manager_for_resource("NPU") is None
 
 
-def test_tpu_manager_detection(monkeypatch):
-    monkeypatch.setenv("TPU_VISIBLE_CHIPS", "0,1,2,3")
+def test_tpu_manager_detection(monkeypatch, tmp_path):
+    """The node's TPU resource is the count of accelerator device nodes."""
     from ray_tpu.util.accelerators import tpu
 
+    monkeypatch.delenv("TPU_VISIBLE_CHIPS", raising=False)
+    monkeypatch.setattr(tpu, "_DEV_ROOT", str(tmp_path))
+    for i in range(4):
+        (tmp_path / f"accel{i}").touch()
     tpu.detect_num_chips.cache_clear()
     mgr = TPUAcceleratorManager()
     assert mgr.get_current_node_num_accelerators() == 4

@@ -15,22 +15,14 @@ from ray_tpu.parallel import (
 )
 
 
-# Environment-bound skips (precise causes, re-enabled automatically when
-# the environment changes): XLA's CPU SPMD partitioner cannot lower the
+# Environment-bound skip: XLA's CPU SPMD partitioner cannot lower the
 # PartitionId instruction ("UNIMPLEMENTED: PartitionId instruction is not
 # supported for SPMD partitioning"), so fsdp/tp-composed pipelines only run
-# on real accelerators; and jax 0.4.37's shard_map gradient rewrite raises
-# an internal _SpecError for the MoE aux-loss pipeline (fixed upstream in
-# later jax).
+# on real accelerators.
 _SKIP_CPU_SPMD = pytest.mark.skipif(
     jax.default_backend() == "cpu",
     reason="XLA CPU SPMD partitioner lacks PartitionId (UNIMPLEMENTED); "
     "fsdp/tp-composed pipeline needs a real accelerator",
-)
-_SKIP_SHARD_MAP_GRAD = pytest.mark.skipif(
-    tuple(int(x) for x in jax.__version__.split(".")[:3]) <= (0, 4, 37),
-    reason="jax<=0.4.37 shard_map grad raises an internal _SpecError on "
-    "the MoE aux-loss pipeline",
 )
 
 
@@ -174,7 +166,6 @@ class TestGPTPipeline:
         )(staged, batch)
         assert bool(jnp.isfinite(loss))
 
-    @_SKIP_SHARD_MAP_GRAD
     def test_gpt_pipeline_moe_aux_and_router_grads(self):
         import jax
         import jax.numpy as jnp
