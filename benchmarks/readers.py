@@ -1,0 +1,166 @@
+"""Readers: how a named metric is taken from a run's observations. Name,
+unit, layer and `moves` live in `BENCHMARK.json` alone; the file
+`benchmarks/metrics/<reader>.json` holds only the reader: one of the kinds
+below with its parameters. A metric `<reader>.<suffix>` uses the file of
+`<reader>`, so one reader serves a quantity that is split by cell because
+its cells report different end-to-end metrics (`itl_p90_ms.sat`). A new
+metric over a span, counter, series or trace event that a runner already
+records is a new file, not new code. A reader that finds nothing to read
+returns None and the metric is left out of the line."""
+
+from __future__ import annotations
+
+import statistics
+from typing import Optional
+
+from . import harness, peaks, stats
+
+
+def _series(obs, r):
+    return (obs.get("series") or {}).get(r["series"]) or None
+
+
+def phase(obs, r):
+    return (obs.get("phases") or {}).get(r["key"])
+
+
+def series_percentile(obs, r):
+    xs = _series(obs, r)
+    return None if not xs else stats.percentile(xs, r["q"]) * r.get("scale", 1.0)
+
+
+def series_mean(obs, r):
+    xs = _series(obs, r)
+    return None if not xs else statistics.fmean(xs) * r.get("scale", 1.0)
+
+
+def series_share_above(obs, r):
+    xs = _series(obs, r)
+    if not xs:
+        return None
+    cut = r["factor"] * statistics.median(xs)
+    return 100.0 * sum(x > cut for x in xs) / len(xs)
+
+
+def counter(obs, r):
+    v = (obs.get("counters") or {}).get(r["key"])
+    return None if v is None else v * r.get("scale", 1.0)
+
+
+def counter_ratio(obs, r):
+    c = obs.get("counters") or {}
+    num, den = c.get(r["num"]), c.get(r["den"])
+    return None if num is None or not den else r.get("scale", 1.0) * num / den
+
+
+def whole_step_rate(obs, r):
+    xs, f = _series(obs, {"series": "step_end_s"}), obs["facts"]
+    if not xs:
+        return None
+    return stats.whole_step_rate(
+        xs, 0.0, f["tokens_per_step"], f["chips"], 5 * f["group_steps"])
+
+
+def group_median_rate(obs, r, min_groups=3):
+    # three, not five: in a traced run the profiler's start and stop take
+    # steps from the window
+    xs, f = _series(obs, {"series": "step_end_s"}), obs["facts"]
+    if not xs:
+        return None
+    return stats.group_median_rate(
+        xs, 0.0, f["group_steps"], f["tokens_per_step"], f["chips"], min_groups)
+
+
+def token_rate(obs, r):
+    xs = _series(obs, r)
+    return None if not xs or len(xs) < 2 else stats.token_rate(xs)
+
+
+def mfu(obs, r):
+    """The model step's own utilization: required FLOPs at the STEADY rate
+    over the peak. Stalls of other layers show in `train_tok_s_chip` and
+    `step_outlier_share`, and a traced run has the profiler's in it."""
+    rate = group_median_rate(obs, r)
+    if rate is None:
+        return None
+    f = obs["facts"]
+    need = peaks.train_flops_per_token(f["model"], f["seq"]) * rate
+    return 100.0 * need / peaks.peak(obs["device"]["kind"])["bf16_flops"]
+
+
+def _trace(obs):
+    return obs.get("trace") or None
+
+
+def trace_idle_share(obs, r):
+    t = _trace(obs)
+    return None if not t else 100.0 * (1.0 - t["busy_s"] / t["window_s"])
+
+
+def trace_module_ms(obs, r):
+    t = _trace(obs)
+    if not t:
+        return None
+    runs = [x for name, xs in t["module_s"].items() if r["module"] in name for x in xs]
+    return None if not runs else 1e3 * statistics.median(runs)
+
+
+def trace_op_share(obs, r):
+    t = _trace(obs)
+    if not t:
+        return None
+    got = sum(v for k, v in t["op_self_s"].items() if any(n in k for n in r["ops"]))
+    return 100.0 * got / t["busy_s"]
+
+
+def trace_collective_exposed_share(obs, r):
+    t = _trace(obs)
+    return None if not t else 100.0 * t["collective_exposed_s"] / t["window_s"]
+
+
+def kernel_roofline(obs, r):
+    """Least time the chip could take for the kernel's calls in the traced
+    window, over the time they took. Costs are per call on one device's
+    shard [BH/device, S, Dh]."""
+    t = _trace(obs)
+    if not t:
+        return None
+    k, f = r["kernel"], obs["facts"]
+    names = [n for n in t["op_self_s"] if n.startswith(k)]
+    took = sum(t["op_self_s"][n] for n in names)
+    calls = sum(t["op_count"][n] for n in names)
+    if not took or not calls:
+        return None
+    cost = peaks.KERNEL_COSTS[k](f["flash_bh_per_device"], f["seq"], f["model"]["d_head"])
+    least, _bound = peaks.roofline_seconds(cost, obs["device"]["kind"])
+    return 100.0 * least * calls / took
+
+
+def hbm_roofline(obs, r):
+    """(weights + live KV) / peak HBM bandwidth over the decode program's
+    median device time."""
+    ms = trace_module_ms(obs, r)
+    c = obs.get("counters") or {}
+    if ms is None or c.get("kv_util_mean") is None:
+        return None
+    f = obs["facts"]
+    live = c["kv_util_mean"] * f["kv_pool_bytes"]
+    least = (peaks.weight_bytes(f["model"]) + live) / peaks.peak(obs["device"]["kind"])["hbm_bytes_s"]
+    return 100.0 * least / (ms * 1e-3)
+
+
+KINDS = {f.__name__: f for f in (
+    phase, series_percentile, series_mean, series_share_above, counter,
+    counter_ratio, whole_step_rate, group_median_rate, token_rate, mfu, trace_idle_share,
+    trace_module_ms, trace_op_share, trace_collective_exposed_share,
+    kernel_roofline, hbm_roofline,
+)}
+
+
+def reader_spec(name: str) -> dict:
+    return harness.load_json(harness.HERE, "metrics", name.split(".", 1)[0] + ".json")
+
+
+def read(name: str, obs: dict) -> Optional[float]:
+    spec = reader_spec(name)
+    return KINDS[spec["kind"]](obs, spec)
