@@ -275,7 +275,8 @@ class ClusterBackend(RuntimeBackend):
                 # RTT midpoint of the register round-trip — the instant
                 # the controller most plausibly sampled the "time" it
                 # returns. Used below for flight-recorder clock alignment.
-                out["_rtt_mid"] = (w0 + _t.time()) / 2.0
+                w1 = _t.time()
+                out["_rtt_mid"], out["_rtt"] = (w0 + w1) / 2.0, w1 - w0
             return out
 
         try:
@@ -303,7 +304,8 @@ class ClusterBackend(RuntimeBackend):
             # LAN, and registration is once per process).
             from ..util import flight
 
-            flight.set_clock_offset(float(result["time"]) - rtt_mid)
+            flight.set_clock_offset(float(result["time"]) - rtt_mid,
+                                    rtt_s=result.pop("_rtt", 0.0))
             flight.set_component(self.role)
         if result.get("session_dir"):
             self.session_dir = result["session_dir"]

@@ -463,7 +463,8 @@ class WorkerProcess:
             # controller's clock, not this host's.
             from ..util import flight
 
-            flight.set_clock_offset(float(out["time"]) - (t0 + t1) / 2.0)
+            flight.set_clock_offset(
+                float(out["time"]) - (t0 + t1) / 2.0, rtt_s=t1 - t0)
             flight.set_component("worker")
 
     async def _on_push(self, msg: dict):

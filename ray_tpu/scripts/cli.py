@@ -314,6 +314,18 @@ def cmd_flight(backend, info, args):
               f"  drain {rep['drain_s']:.3f}s")
         print(f"  transport-wait {rep['transport_wait_s']:.3f}s  "
               f"compute {rep['compute_s']:.3f}s")
+    rep = payload["serve"]
+    if rep and rep["steps"]:
+        phases = "  ".join(f"{k[:-3]} {v:.2f}" for k, v in rep["phase_ms"].items())
+        print(f"engine steps: {rep['steps']} of {rep['step_ms']:.2f} ms; "
+              f"idle wait {rep['wait_share']:.1f}% of {rep['window_s']:.1f}s")
+        print(f"  ms a step: {phases}")
+    if rep and rep["requests"]:
+        print(f"traced requests: {rep['requests']}  ttft {rep['ttft_mean_ms']:.1f} ms = "
+              f"ingress {rep['ingress_p50_ms']:.1f} + queue {rep['queue_wait_p50_ms']:.1f}"
+              f" + prefill {rep['prefill_p50_ms']:.1f} + deliver "
+              f"{rep['deliver_p50_ms']:.1f} (p50s); unattributed "
+              f"{rep['ttft_unattributed_share']:.1f}%")
 
 
 def main(argv=None):

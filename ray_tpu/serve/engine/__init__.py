@@ -35,6 +35,7 @@ __all__ = [
     "InferenceEngine",
     "RequestOutput",
     "LLMDeployment",
+    "LLMReplica",
 ]
 
 _LAZY = {
@@ -42,6 +43,7 @@ _LAZY = {
     "InferenceEngine": "engine",
     "RequestOutput": "engine",
     "LLMDeployment": "deployment",
+    "LLMReplica": "deployment",
 }
 
 

@@ -28,7 +28,7 @@ from typing import Any, Dict, List, Optional
 from ..deployment import deployment as _deployment
 
 
-class _LLMReplica:
+class LLMReplica:
     """User-facing methods of one engine replica (wrapped by Serve's generic
     `Replica` actor; streaming rides `handle_request_streaming`)."""
 
@@ -178,4 +178,6 @@ LLMDeployment = _deployment(
     name="LLMDeployment",
     max_ongoing_requests=64,
     ray_actor_options={"max_concurrency": 16},
-)(_LLMReplica)
+)(LLMReplica)
+
+_LLMReplica = LLMReplica   # benchmarks/runners/serve.py imports this name

@@ -41,6 +41,7 @@ from typing import Any, Dict, Iterator, List, Optional
 
 from collections import deque
 
+from ...util import flight
 from ...util.metrics import quantile as _quantile
 from .kv_manager import KVBlockManager
 from .scheduler import Scheduler, Sequence, SchedulerOutput, _next_pow2
@@ -249,10 +250,13 @@ class InferenceEngine:
         # (t, hits, misses) snapshots — fleet_state's RECENT hit-rate
         # window, the autoscaler's cache-cold signal.
         self._hit_snaps: "deque" = deque(maxlen=64)
-        # request_id -> {trace, submit_t, admit_t, first_t} (wall-clock):
+        # request_id -> {trace, submit_ns, admit_ns, first_ns} (monotonic):
         # per-request span bookkeeping for traced (Serve) submissions —
         # untraced submits (engine unit tests, direct callers) skip it.
         self._trace_info: Dict[str, Dict[str, Any]] = {}
+        self._lane = f"serve/engine-{self.opts.role or 'colocated'}"
+        self._phases: Dict[str, int] = {}       # this step's, see _step
+        self._idle = {"waited_ns": 0}   # _loop's wait, until a step records it
         self._init_metrics()
 
     # ------------------------------------------------------------- metrics
@@ -440,7 +444,7 @@ class InferenceEngine:
             self._outputs[request_id] = RequestOutput(request_id)
             if trace_id:
                 self._trace_info[request_id] = {
-                    "trace": trace_id, "submit_t": time.time(),
+                    "trace": trace_id, "submit_ns": time.monotonic_ns(),
                 }
             self._work.notify_all()
         return request_id
@@ -513,39 +517,28 @@ class InferenceEngine:
         return True
 
     def _emit_request_spans(self, seq: Sequence):
-        """Ship queue-wait/admission/prefill/first-token/completion spans for
-        a finished traced request (one shipment per request)."""
+        """Queue-wait/admission/prefill/first-token/completion spans of a
+        finished traced request, into the flight ring: the driver thread
+        sends nothing, the ring's flusher ships them."""
         rec = self._trace_info.pop(seq.request_id, None)
         if rec is None:
             return
-        try:
-            from ...util.tracing import record_events, span_event
-
-            tid = rec["trace"]
-            now = time.time()
-            submit = rec["submit_t"]
-            admit = rec.get("admit_t", now)
-            first = rec.get("first_t", admit)
-            attrs = {"request_id": seq.request_id,
-                     "tokens": seq.num_generated}
-            # One control-plane message for the whole request — per-span
-            # sends inside step() would stall the decode loop for every
-            # in-flight sequence at high completion rates.
-            record_events([
-                span_event("engine.queue_wait", submit, admit - submit,
-                           trace_id=tid, attrs=attrs),
-                span_event("engine.admission", admit, 0.0, trace_id=tid,
-                           attrs=attrs),
-                span_event("engine.prefill", admit, first - admit,
-                           trace_id=tid, attrs=attrs),
-                span_event("engine.first_token", first, 0.0, trace_id=tid,
-                           attrs=attrs),
-                span_event("engine.completion", first, now - first,
-                           trace_id=tid,
-                           attrs={**attrs, "finish_reason": seq.finish_reason}),
-            ])
-        except Exception:  # noqa: BLE001 — tracing is never load-bearing
-            pass
+        now = time.monotonic_ns()
+        submit = rec["submit_ns"]
+        admit = rec.get("admit_ns", now)
+        first = rec.get("first_ns", admit)
+        attrs = {"request_id": seq.request_id, "tokens": seq.num_generated}
+        for name, t0, t1, more in (
+            ("engine.queue_wait", submit, admit, {}),
+            ("engine.admission", admit, admit, {}),
+            ("engine.prefill", admit, first, {}),
+            ("engine.first_token", first, first, {}),
+            ("engine.completion", first, now,
+             {"finish_reason": seq.finish_reason}),
+        ):
+            flight.record(name, t0, t1, trace=rec["trace"],
+                          lane=self._lane + "/requests",
+                          attrs={**attrs, **more})
 
     def _apply_cow(self):
         """Land queued copy-on-write block copies (shared block forked by
@@ -663,7 +656,6 @@ class InferenceEngine:
             return None
         from concurrent.futures import Future, TimeoutError as _FutTimeout
 
-        from ...util import flight
         from ...util.tracing import get_trace_id
 
         # Captured HERE (the replica RPC thread carries the request's task
@@ -745,8 +737,6 @@ class InferenceEngine:
         if not desc or not self.opts.enable_prefix_caching \
                 or self._stop.is_set():
             return 0
-        from ...util import flight
-
         trace = desc.get("trace")
         t0 = flight.now_ns()
 
@@ -813,45 +803,52 @@ class InferenceEngine:
         """One prefill chunk: compute prompt[start : start+n] into the paged
         cache. Only the FINAL chunk samples the first token (TTFT)."""
         seq = chunk.seq
+        ph = self._phases
         rec = self._trace_info.get(seq.request_id)
-        if rec is not None and "admit_t" not in rec:
-            rec["admit_t"] = time.time()
+        if rec is not None and "admit_ns" not in rec:
+            rec["admit_ns"] = time.monotonic_ns()
         jnp = self._jnp
         np = self._np
-        table = self.block_manager.block_table(seq.request_id)
-        L = chunk.num_tokens
-        # Same bucketing primitive as the scheduler's decode shapes —
-        # agreement between the two is what bounds the XLA program set.
-        Sp = _next_pow2(L)
-        W = _next_pow2(len(table))
-        tokens = np.zeros((1, Sp), np.int32)
-        tokens[0, :L] = seq.prompt[chunk.start:chunk.start + L]
-        bt = np.zeros((W,), np.int32)
-        bt[: len(table)] = table
-        logits, self.kv = self._prefill(
-            self.params,
-            jnp.asarray(tokens),
-            jnp.asarray(L, jnp.int32),
-            jnp.asarray(chunk.start, jnp.int32),
-            jnp.asarray(bt),
-            self.kv,
-            self.cfg,
-        )
-        seq.num_computed = chunk.start + L
-        # The chunk's KV is landed — its newly-FULL blocks are now safe to
-        # serve as prefix-cache hits for later prompts. Under the engine
-        # lock: registration touches the hot-hash digest that telemetry
-        # (`fleet_state`, actor RPC thread) iterates.
-        with self._lock:
-            self.block_manager.register_computed(
-                seq.request_id, seq.prompt, seq.num_computed
+        with flight.phase("engine.build", ph, "build_ns"):
+            table = self.block_manager.block_table(seq.request_id)
+            L = chunk.num_tokens
+            # Same bucketing primitive as the scheduler's decode shapes —
+            # agreement between the two is what bounds the XLA program set.
+            Sp = _next_pow2(L)
+            W = _next_pow2(len(table))
+            tokens = np.zeros((1, Sp), np.int32)
+            tokens[0, :L] = seq.prompt[chunk.start:chunk.start + L]
+            bt = np.zeros((W,), np.int32)
+            bt[: len(table)] = table
+            args = (
+                jnp.asarray(tokens),
+                jnp.asarray(L, jnp.int32),
+                jnp.asarray(chunk.start, jnp.int32),
+                jnp.asarray(bt),
             )
+        with flight.phase("engine.dispatch", ph, "dispatch_ns"):
+            logits, self.kv = self._prefill(
+                self.params, *args, self.kv, self.cfg
+            )
+            del args    # the input buffers are released here, not at return
+        with flight.phase("engine.schedule", ph, "sched_ns"):
+            seq.num_computed = chunk.start + L
+            # The chunk's KV is landed — its newly-FULL blocks are now safe
+            # to serve as prefix-cache hits for later prompts. Under the
+            # engine lock: registration touches the hot-hash digest that
+            # telemetry (`fleet_state`, actor RPC thread) iterates.
+            with self._lock:
+                self.block_manager.register_computed(
+                    seq.request_id, seq.prompt, seq.num_computed
+                )
         if chunk.last:
-            tok = self._sample(np.asarray(logits))
-            self._emit(seq, tok)
-            if rec is not None:
-                rec.setdefault("first_t", time.time())
-            self._maybe_finish(seq)
+            with flight.phase("engine.fetch_logits", ph, "fetch_ns"):
+                logits = np.asarray(logits)
+            with flight.phase("engine.sample", ph, "sample_ns"):
+                self._emit(seq, self._sample(logits))
+                if rec is not None:
+                    rec.setdefault("first_ns", time.monotonic_ns())
+                self._maybe_finish(seq)
 
     def _run_verify(self, out: SchedulerOutput):
         """Speculative step: every decode lane rides ONE `verify_step_paged`
@@ -863,35 +860,45 @@ class InferenceEngine:
         greedy decode, just fewer dispatches."""
         jnp = self._jnp
         np = self._np
+        ph = self._phases
         seqs = out.decodes
-        B = out.batch_bucket
-        W = out.width_bucket
-        K1 = self.opts.spec_tokens + 1
-        tokens = np.zeros((B, K1), np.int32)
-        positions = np.zeros((B,), np.int32)
-        valid_len = np.zeros((B,), np.int32)  # 0 for padding lanes
-        tables = np.zeros((B, W), np.int32)   # padding lanes -> null block
-        lane_drafts: List[List[int]] = []
-        for i, seq in enumerate(seqs):
-            d = out.drafts.get(seq.request_id, [])
-            lane_drafts.append(d)
-            tokens[i, 0] = seq.output[-1]
-            if d:
-                tokens[i, 1:1 + len(d)] = d
-            positions[i] = seq.num_tokens - 1
-            valid_len[i] = 1 + len(d)
-            table = self.block_manager.block_table(seq.request_id)
-            tables[i, : len(table)] = table
-        logits, self.kv = self._verify(
-            self.params,
-            jnp.asarray(tokens),
-            jnp.asarray(positions),
-            jnp.asarray(valid_len),
-            jnp.asarray(tables),
-            self.kv,
-            self.cfg,
-        )
-        logits = np.asarray(logits)
+        with flight.phase("engine.build", ph, "build_ns"):
+            B = out.batch_bucket
+            W = out.width_bucket
+            K1 = self.opts.spec_tokens + 1
+            tokens = np.zeros((B, K1), np.int32)
+            positions = np.zeros((B,), np.int32)
+            valid_len = np.zeros((B,), np.int32)  # 0 for padding lanes
+            tables = np.zeros((B, W), np.int32)   # padding lanes -> null block
+            lane_drafts: List[List[int]] = []
+            for i, seq in enumerate(seqs):
+                d = out.drafts.get(seq.request_id, [])
+                lane_drafts.append(d)
+                tokens[i, 0] = seq.output[-1]
+                if d:
+                    tokens[i, 1:1 + len(d)] = d
+                positions[i] = seq.num_tokens - 1
+                valid_len[i] = 1 + len(d)
+                table = self.block_manager.block_table(seq.request_id)
+                tables[i, : len(table)] = table
+            args = (
+                jnp.asarray(tokens),
+                jnp.asarray(positions),
+                jnp.asarray(valid_len),
+                jnp.asarray(tables),
+            )
+        with flight.phase("engine.dispatch", ph, "dispatch_ns"):
+            logits, self.kv = self._verify(
+                self.params, *args, self.kv, self.cfg
+            )
+            del args    # the input buffers are released here, not at return
+        with flight.phase("engine.fetch_logits", ph, "fetch_ns"):
+            logits = np.asarray(logits)
+        with flight.phase("engine.sample", ph, "sample_ns"):
+            self._accept_drafts(seqs, lane_drafts, logits)
+
+    def _accept_drafts(self, seqs, lane_drafts, logits):
+        """Greedy acceptance of a verify step's drafts (see `_run_verify`)."""
         for i, seq in enumerate(seqs):
             d = lane_drafts[i]
             greedy = logits[i].argmax(axis=-1)
@@ -923,107 +930,131 @@ class InferenceEngine:
             return self._run_verify(out)
         jnp = self._jnp
         np = self._np
+        ph = self._phases
         seqs = out.decodes
-        B = out.batch_bucket
-        W = out.width_bucket
-        tokens = np.zeros((B,), np.int32)
-        positions = np.zeros((B,), np.int32)
-        tables = np.zeros((B, W), np.int32)  # padding lanes -> null block
-        for i, seq in enumerate(seqs):
-            tokens[i] = seq.output[-1]
-            positions[i] = seq.num_tokens - 1   # where this token's KV lands
-            table = self.block_manager.block_table(seq.request_id)
-            tables[i, : len(table)] = table
-        logits, self.kv = self._decode(
-            self.params,
-            jnp.asarray(tokens),
-            jnp.asarray(positions),
-            jnp.asarray(tables),
-            self.kv,
-            self.cfg,
-        )
-        logits = np.asarray(logits)
-        for i, seq in enumerate(seqs):
-            self._emit(seq, self._sample(logits[i]))
-            self._maybe_finish(seq)
+        with flight.phase("engine.build", ph, "build_ns"):
+            B = out.batch_bucket
+            W = out.width_bucket
+            tokens = np.zeros((B,), np.int32)
+            positions = np.zeros((B,), np.int32)
+            tables = np.zeros((B, W), np.int32)  # padding lanes -> null block
+            for i, seq in enumerate(seqs):
+                tokens[i] = seq.output[-1]
+                positions[i] = seq.num_tokens - 1   # where this token's KV lands
+                table = self.block_manager.block_table(seq.request_id)
+                tables[i, : len(table)] = table
+            args = (
+                jnp.asarray(tokens),
+                jnp.asarray(positions),
+                jnp.asarray(tables),
+            )
+        with flight.phase("engine.dispatch", ph, "dispatch_ns"):
+            logits, self.kv = self._decode(
+                self.params, *args, self.kv, self.cfg
+            )
+            del args    # the input buffers are released here, not at return
+        with flight.phase("engine.fetch_logits", ph, "fetch_ns"):
+            logits = np.asarray(logits)
+        with flight.phase("engine.sample", ph, "sample_ns"):
+            for i, seq in enumerate(seqs):
+                self._emit(seq, self._sample(logits[i]))
+                self._maybe_finish(seq)
 
     def step(self) -> Dict[str, Any]:
         """One engine iteration; safe to drive manually (tests) or from the
         driver thread. Returns a stats snapshot."""
-        t0 = time.monotonic()
-        # Flight-recorder step span: one monotonic_ns read + enabled()
-        # check up front; the record itself only happens on steps that did
-        # work. Budgeted ≤5% of decode-step time (test_flight_perf_smoke).
-        from ...util import flight
+        with flight.phase("engine.step"):
+            return self._step()
 
+    def _step(self) -> Dict[str, Any]:
+        t0 = time.monotonic()
+        # Host phases of the step, nanoseconds summed over it and put on
+        # its ONE `engine.step` flight record (flight.SERVE_STEP_PHASES):
+        # a dozen monotonic_ns reads and inactive profiler annotations; the
+        # record itself only happens on steps that did work. The span is
+        # the first six phases; `export_ns` follows it and `waited_ns` (the
+        # driver thread's idle wait, `_loop`) precedes it. Budgeted ≤5% of
+        # decode-step time (test_flight_perf_smoke).
         fl_on = flight.enabled()
-        t0_ns = time.monotonic_ns() if fl_on else 0
-        self._step_ttfts, self._step_tpots = [], []
-        self._step_spec = [0, 0]  # [proposed, accepted]
-        tok0 = self.total_tokens
-        with self._lock:
-            out = self.scheduler.schedule()
-        self.total_preemptions += len(out.preempted)
-        for seq in out.preempted:
-            # Recompute preemption re-queues the request: its admission,
-            # prefill, and first-token spans restart at the next schedule
-            # (keeping first_t would put first_token BEFORE admission).
-            rec = self._trace_info.get(seq.request_id)
-            if rec is not None:
-                rec.pop("admit_t", None)
-                rec.pop("first_t", None)
+        ph = self._phases = dict.fromkeys(flight.SERVE_STEP_PHASES, 0)
+        t0_ns = time.monotonic_ns()
+        with flight.phase("engine.schedule", ph, "sched_ns"):
+            self._step_ttfts, self._step_tpots = [], []
+            self._step_spec = [0, 0]  # [proposed, accepted]
+            tok0 = self.total_tokens
+            with self._lock:
+                out = self.scheduler.schedule()
+            self.total_preemptions += len(out.preempted)
+            for seq in out.preempted:
+                # Recompute preemption re-queues the request: its admission,
+                # prefill, and first-token spans restart at the next
+                # schedule (keeping first_ns would put first_token BEFORE
+                # admission).
+                rec = self._trace_info.get(seq.request_id)
+                if rec is not None:
+                    rec.pop("admit_ns", None)
+                    rec.pop("first_ns", None)
         # Drain order is load-bearing (kv_manager header): eviction SAVES
         # read their blocks' bytes before COW copies or tier/import LOADS
         # can overwrite them, and everything lands before kernels run.
-        self._apply_host_saves()
-        self._apply_cow()
-        self._apply_host_loads()
-        self._service_side_work()
+        with flight.phase("engine.side_work", ph, "side_ns"):
+            self._apply_host_saves()
+            self._apply_cow()
+            self._apply_host_loads()
+            self._service_side_work()
         for chunk in out.prefills:
             self._run_prefill(chunk)
         if out.decodes:
             self._run_decode(out)
+        # The span ends where the step's own work does; building `stats`
+        # and the metrics export follow it as `export_ns`.
+        t1_ns = time.monotonic_ns()
 
-        now = time.monotonic()
-        self._tok_window = [t for t in self._tok_window if now - t <= 10.0]
-        kv_stats = self.block_manager.stats()
-        stats = {
-            "queue_depth": self.scheduler.queue_depth,
-            "running": self.scheduler.num_running,
-            "kv_utilization": kv_stats.utilization,
-            "kv_free_blocks": kv_stats.free_blocks,
-            "kv_cached_blocks": kv_stats.cached_blocks,
-            "prefix_cache_hits": kv_stats.hits,
-            "prefix_cache_misses": kv_stats.misses,
-            "prefix_cache_evictions": kv_stats.evictions,
-            "host_tier_hits": kv_stats.host_hits,
-            "host_tier_bytes": kv_stats.host_bytes,
-            "blocks_imported": self.total_blocks_imported,
-            "blocks_exported": self.total_blocks_exported,
-            "step_budget_tokens": out.step_tokens,
-            "tokens_per_s": (
-                len(self._tok_window) / max(now - self._tok_window[0], 1e-3)
-                if self._tok_window
-                else 0.0
-            ),
-            "step_tokens": self.total_tokens - tok0,
-            "step_preemptions": len(out.preempted),
-            "step_prefills": len(out.prefills),
-            "step_decodes": len(out.decodes),
-            "step_spec_proposed": self._step_spec[0],
-            "step_spec_accepted": self._step_spec[1],
-            "step_ttfts": list(self._step_ttfts),
-            "step_tpots": list(self._step_tpots),
-            "step_s": now - t0,
-        }
+        with flight.phase("engine.export_metrics", ph, "export_ns"):
+            now = time.monotonic()
+            self._tok_window = [t for t in self._tok_window if now - t <= 10.0]
+            kv_stats = self.block_manager.stats()
+            stats = {
+                "queue_depth": self.scheduler.queue_depth,
+                "running": self.scheduler.num_running,
+                "kv_utilization": kv_stats.utilization,
+                "kv_free_blocks": kv_stats.free_blocks,
+                "kv_cached_blocks": kv_stats.cached_blocks,
+                "prefix_cache_hits": kv_stats.hits,
+                "prefix_cache_misses": kv_stats.misses,
+                "prefix_cache_evictions": kv_stats.evictions,
+                "host_tier_hits": kv_stats.host_hits,
+                "host_tier_bytes": kv_stats.host_bytes,
+                "blocks_imported": self.total_blocks_imported,
+                "blocks_exported": self.total_blocks_exported,
+                "step_budget_tokens": out.step_tokens,
+                "tokens_per_s": (
+                    len(self._tok_window) / max(now - self._tok_window[0], 1e-3)
+                    if self._tok_window
+                    else 0.0
+                ),
+                "step_tokens": self.total_tokens - tok0,
+                "step_preemptions": len(out.preempted),
+                "step_prefills": len(out.prefills),
+                "step_decodes": len(out.decodes),
+                "step_spec_proposed": self._step_spec[0],
+                "step_spec_accepted": self._step_spec[1],
+                "step_ttfts": list(self._step_ttfts),
+                "step_tpots": list(self._step_tpots),
+                "step_s": now - t0,
+            }
+            self._export_metrics(stats)
         if fl_on and (out.prefills or out.decodes):
+            idle, self._idle = self._idle, {"waited_ns": 0}
             flight.record(
-                "engine.step", t0_ns, time.monotonic_ns(),
-                lane=f"serve/engine-{self.opts.role or 'colocated'}",
+                "engine.step", t0_ns, t1_ns, lane=self._lane,
                 attrs={"prefills": len(out.prefills),
                        "decodes": len(out.decodes),
-                       "tokens": stats["step_tokens"]})
-        self._export_metrics(stats)
+                       "tokens": stats["step_tokens"],
+                       **idle, **ph,
+                       "queue_depth": stats["queue_depth"],
+                       "running": stats["running"],
+                       "kv_util": stats["kv_utilization"]})
         return stats
 
     def stats(self, include_raw: bool = False) -> Dict[str, Any]:
@@ -1152,15 +1183,23 @@ class InferenceEngine:
             except Exception:  # noqa: BLE001
                 pass
 
+    def _nothing_to_run(self) -> bool:
+        return (
+            not self.scheduler.has_work()
+            and not self._side_work
+            and not self._stop.is_set()
+        )
+
     def _loop(self):
         while not self._stop.is_set():
             with self._work:
-                while (
-                    not self.scheduler.has_work()
-                    and not self._side_work
-                    and not self._stop.is_set()
-                ):
-                    self._work.wait(timeout=0.1)
+                if self._nothing_to_run():
+                    # No demand: not the program's time to shorten, so it
+                    # is kept apart from every wait inside a step.
+                    with flight.phase("engine.wait_work", self._idle,
+                                      "waited_ns"):
+                        while self._nothing_to_run():
+                            self._work.wait(timeout=0.1)
             if self._stop.is_set():
                 return
             try:

@@ -89,14 +89,27 @@ def _routing_prompt(args, kwargs) -> Optional[List[int]]:
     return None
 
 
+def _record_handle_span(span: Dict[str, Any], end_ns: int, **attrs):
+    """The caller's side of one traced call, as ONE `serve.handle` flight
+    span in the caller's process: from the call to its last chunk (or its
+    result). With `replica.handle[_stream]` and the engine's request spans
+    the same trace id then covers caller -> router -> replica -> queue ->
+    prefill -> first token -> delivery."""
+    flight.record(
+        "serve.handle", span["t0"], end_ns, trace=span["trace"],
+        lane="serve/handle", attrs={**span["attrs"], **attrs})
+
+
 class DeploymentResponse:
     """Future-like result of `handle.method.remote()` (reference
     `serve/handle.py` DeploymentResponse)."""
 
-    def __init__(self, ref=None, future=None, on_done=None, retry=None):
+    def __init__(self, ref=None, future=None, on_done=None, retry=None,
+                 span=None):
         self._ref = ref
         self._future = future
         self._on_done = on_done
+        self._span = span   # set for a call that carries a trace id
         # One-shot failover: on a REPLICA failure (not a user exception),
         # re-route the call through the router once (`Router.call` wires
         # this up for unary calls).
@@ -121,6 +134,9 @@ class DeploymentResponse:
             if self._on_done is not None:
                 self._on_done()
                 self._on_done = None
+            span, self._span = self._span, None
+            if span is not None:
+                _record_handle_span(span, flight.now_ns())
 
     def __del__(self):
         # Fire-and-forget callers never invoke result(); release the
@@ -144,24 +160,37 @@ class DeploymentResponseGenerator:
     the disaggregated handoff path yields tokens from two replicas'
     streams behind one facade."""
 
-    def __init__(self, ref_generator, on_done=None, direct_gen=None):
+    def __init__(self, ref_generator, on_done=None, direct_gen=None,
+                 span=None):
         self._gen = ref_generator
         self._on_done = on_done
         self._direct = direct_gen
+        self._span = span   # set for a call that carries a trace id
 
     def __iter__(self):
         import ray_tpu
 
+        span, self._span = self._span, None
+        chunks = first_ns = last_ns = 0
         try:
-            if self._direct is not None:
-                yield from self._direct
-                return
-            for ref in self._gen:
-                yield ray_tpu.get(ref)
+            source = self._direct if self._direct is not None else (
+                ray_tpu.get(ref) for ref in self._gen)
+            for chunk in source:
+                if span is not None:
+                    last_ns = flight.now_ns()
+                    first_ns = first_ns or last_ns
+                    chunks += 1
+                yield chunk
         finally:
             if self._on_done is not None:
                 self._on_done()
                 self._on_done = None
+            if span is not None:
+                # first_chunk_ts on the controller's clock, like every ts
+                more = {"chunks": chunks}
+                if chunks:
+                    more["first_chunk_ts"] = flight.recorder().wall(first_ns)
+                _record_handle_span(span, last_ns or flight.now_ns(), **more)
 
 
 class _Batcher:
@@ -649,7 +678,22 @@ class Router:
             self._done(idx)
 
     # ---------------------------------------------------------------- calls
+    @staticmethod
+    def _call_span(t0: int, t_pick: int, method: str,
+                   replica_tag: str) -> Optional[Dict[str, Any]]:
+        """Bookkeeping of the `serve.handle` span (`_record_handle_span`)
+        for a call that carries a trace id, taken once the actor task is
+        submitted: `pick_ns` is refresh + `_pick_replica`, `submit_ns` the
+        `.remote()`. None for an untraced call: it records nothing."""
+        trace = tracing.get_trace_id()
+        if not trace:
+            return None
+        return {"trace": trace, "t0": t0, "attrs": {
+            "method": method, "replica": replica_tag, "pick_ns": t_pick - t0,
+            "submit_ns": flight.now_ns() - t_pick}}
+
     def call(self, method: str, args, kwargs, model_id: str = "") -> DeploymentResponse:
+        t0 = flight.now_ns()
         self._refresh()
         batch_cfg = self._info["batch_methods"].get(method)
         if batch_cfg is not None:
@@ -671,11 +715,13 @@ class Router:
             if plan is not None:
                 return self._disagg_response(plan)
         idx, replica, failed_tag = self._pick_replica(model_id, prompt=prompt)
+        t_pick = flight.now_ns()
         try:
             ref = replica.handle_request.remote(method, args, kwargs, model_id)
         except Exception:
             self._done(idx)
             raise
+        span = self._call_span(t0, t_pick, method, failed_tag)
         self._maybe_report_metrics()
 
         def retry(timeout_s):
@@ -699,7 +745,7 @@ class Router:
 
         # Outstanding count drops when the caller consumes the result.
         return DeploymentResponse(
-            ref=ref, on_done=lambda: self._done(idx), retry=retry
+            ref=ref, on_done=lambda: self._done(idx), retry=retry, span=span
         )
 
     def call_streaming(
@@ -708,6 +754,7 @@ class Router:
         """Streaming call: chunks arrive as the replica's generator yields
         (reference: `handle.options(stream=True)` →
         ObjectRefGenerator-backed responses)."""
+        t0 = flight.now_ns()
         self._refresh()
         prompt = _routing_prompt(args, kwargs)
         if not model_id:
@@ -715,9 +762,10 @@ class Router:
             if plan is not None:
                 self._maybe_report_metrics()
                 return DeploymentResponseGenerator(None, direct_gen=self._disagg_stream_gen(plan))
-        idx, replica, _ = self._pick_replica(
+        idx, replica, tag = self._pick_replica(
             model_id, prompt=prompt
         )
+        t_pick = flight.now_ns()
         try:
             gen = getattr(replica, "handle_request_streaming").options(
                 num_returns="streaming"
@@ -725,8 +773,10 @@ class Router:
         except Exception:
             self._done(idx)
             raise
+        span = self._call_span(t0, t_pick, method, tag)
         self._maybe_report_metrics()
-        return DeploymentResponseGenerator(gen, on_done=lambda: self._done(idx))
+        return DeploymentResponseGenerator(
+            gen, on_done=lambda: self._done(idx), span=span)
 
     def call_batch(self, method: str, batched_args: List, model_id: str) -> List:
         import ray_tpu

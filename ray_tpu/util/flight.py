@@ -42,6 +42,8 @@ from __future__ import annotations
 
 import contextlib
 import os
+import statistics
+import sys
 import threading
 import time
 from typing import Any, Dict, List, Optional
@@ -85,9 +87,14 @@ class FlightRecorder:
         self._offset = 0.0
 
     # ------------------------------------------------------------ clock
-    def set_clock_offset(self, offset_s: float) -> None:
-        """controller_wall ≈ local_wall + offset_s (RTT midpoint)."""
-        self._offset = float(offset_s)
+    def set_clock_offset(self, offset_s: float, rtt_s: float = 0.0) -> None:
+        """controller_wall ≈ local_wall + offset_s (RTT midpoint). The
+        midpoint is off by up to half the round trip that measured it (the
+        controller answers after it has done the registering), so an offset
+        inside that cannot be told from none — one machine, or hosts NTP
+        keeps in step — and is taken as none: spans of two processes of one
+        host are then ordered by the host's one clock."""
+        self._offset = float(offset_s) if abs(offset_s) > rtt_s / 2.0 else 0.0
 
     @property
     def clock_offset(self) -> float:
@@ -227,8 +234,8 @@ def _reset_for_tests() -> None:
         _RECORDER = None
 
 
-def set_clock_offset(offset_s: float) -> None:
-    recorder().set_clock_offset(offset_s)
+def set_clock_offset(offset_s: float, rtt_s: float = 0.0) -> None:
+    recorder().set_clock_offset(offset_s, rtt_s)
 
 
 def set_component(name: str) -> None:
@@ -252,6 +259,53 @@ def span(name: str, **kw):
         return contextlib.nullcontext()
     ensure_flusher()
     return recorder().span(name, **kw)
+
+
+# jax.profiler.TraceAnnotation, once jax is in the process (never imported
+# from here: the driver and the controller stay off JAX).
+_ANNOTATION = None
+
+
+def _annotation():
+    global _ANNOTATION
+    if _ANNOTATION is None:
+        prof = getattr(sys.modules.get("jax"), "profiler", None)
+        _ANNOTATION = getattr(prof, "TraceAnnotation", None)
+    return _ANNOTATION
+
+
+class phase:
+    """``with flight.phase("engine.fetch_logits", acc, "fetch_ns"):`` — one
+    phase of a hot loop, timed where the work happens and put on two
+    clocks at once: the nanoseconds between the two ``monotonic_ns``
+    stamps are ADDED to ``into[key]`` (the caller puts the totals on the
+    one span it records anyway — no record per phase), and, in a process
+    that has already imported jax, the body runs inside a
+    ``jax.profiler.TraceAnnotation(name)``, so a profiler session shows the
+    same phase on the device trace's clock. With no session the annotation
+    is an inactive TraceMe (~0.4 us)."""
+
+    __slots__ = ("name", "into", "key", "_ann", "_t0")
+
+    def __init__(self, name: str, into: Optional[Dict[str, int]] = None,
+                 key: Optional[str] = None):
+        self.name, self.into, self.key = name, into, key
+
+    def __enter__(self):
+        ann = _annotation()
+        self._ann = ann(self.name) if ann is not None else None
+        if self._ann is not None:
+            self._ann.__enter__()
+        self._t0 = time.monotonic_ns()
+        return self
+
+    def __exit__(self, *exc):
+        dt = time.monotonic_ns() - self._t0
+        if self._ann is not None:
+            self._ann.__exit__(*exc)
+        if self.into is not None:
+            self.into[self.key] = self.into.get(self.key, 0) + dt
+        return False
 
 
 # ------------------------------------------------------------------ shipping
@@ -538,6 +592,100 @@ def ingest_report(events: List[dict]) -> Optional[dict]:
     }
 
 
+# Serving span vocabulary (serve/README.md "Observability"):
+#   engine.step     — one per engine iteration that ran a kernel, on lane
+#                     ``serve/engine-<role>``; attrs prefills/decodes/tokens,
+#                     the host phases of the step in nanoseconds
+#                     (waited_ns | sched/side/build/dispatch/fetch/sample_ns |
+#                     export_ns) and queue_depth/running/kv_util at its end
+#   engine.queue_wait|admission|prefill|first_token|completion — one set per
+#                     traced request, lane ``serve/engine-<role>/requests``
+#   serve.handle    — the caller's side of one traced handle call (lane
+#                     ``serve/handle``): start = the call, end = its last
+#                     chunk; attrs method/replica/pick_ns/submit_ns/chunks
+#                     and first_chunk_ts (controller clock)
+SERVE_STEP_PHASES = ("sched_ns", "side_ns", "build_ns", "dispatch_ns",
+                     "fetch_ns", "sample_ns", "export_ns")
+
+
+def _mean(xs: List[float]) -> Optional[float]:
+    return statistics.fmean(xs) if xs else None
+
+
+def serve_report(events: List[dict]) -> Optional[dict]:
+    """Where a serving engine's host time goes and what a request's time to
+    first token is made of, from flight spans — pipeline_report's role for
+    the Serve plane.
+
+    Steps (``engine.step``): the mean milliseconds a step spends in each
+    host phase, and ``wait_share`` = the share of the span window the
+    driver thread sat in ``_loop``'s wait with nothing to run (no demand:
+    not the program's to shorten). Requests, joined by trace id over those
+    that have a ``serve.handle`` span with a first chunk AND the engine's
+    request spans: ingress (the call to ``engine.submit``), queue wait,
+    prefill, delivery (first token emitted to first chunk at the caller),
+    and ``ttft_unattributed_share`` = the part of the mean call-to-first-
+    chunk time the four do not explain (0 when the spans close the sum).
+    Returns None when no serving spans are present."""
+    steps: List[dict] = []
+    by_trace: Dict[str, Dict[str, dict]] = {}
+    for ev in events:
+        if ev.get("event") != "span":
+            continue
+        name = ev.get("name", "")
+        if name == "engine.step":
+            steps.append(ev)
+        elif ev.get("trace") and name in (
+                "serve.handle", "engine.queue_wait", "engine.prefill",
+                "engine.first_token"):
+            by_trace.setdefault(ev["trace"], {}).setdefault(name, ev)
+    if not steps and not by_trace:
+        return None
+    out: Dict[str, Any] = {"steps": len(steps)}
+    if steps:
+        args = [e.get("args") or {} for e in steps]
+        t0 = min(e["ts"] for e in steps)
+        t1 = max(e["ts"] + e.get("dur", 0.0) for e in steps)
+        n = len(steps)
+        decode = [a for a in args if a.get("decodes")]
+        out.update({
+            "window_s": t1 - t0,
+            "step_ms": 1e3 * sum(e.get("dur", 0.0) for e in steps) / n,
+            "phase_ms": {k: 1e-6 * sum(a.get(k, 0) for a in args) / n
+                         for k in SERVE_STEP_PHASES},
+            "wait_share": (100.0 * 1e-9 * sum(a.get("waited_ns", 0)
+                                              for a in args) / (t1 - t0)
+                           if t1 > t0 else 0.0),
+            "decode_lanes_mean": _mean([a["decodes"] for a in decode]),
+            "queue_depth_mean": _mean([a["queue_depth"] for a in args
+                                       if "queue_depth" in a]),
+            "kv_util_mean": _mean([a["kv_util"] for a in decode
+                                   if "kv_util" in a]),
+        })
+    rows = []
+    for spans in by_trace.values():
+        h, q, p, f = (spans.get(k) for k in (
+            "serve.handle", "engine.queue_wait", "engine.prefill",
+            "engine.first_token"))
+        first = ((h or {}).get("args") or {}).get("first_chunk_ts")
+        if first is None or not (q and p and f):
+            continue
+        rows.append({"ingress": q["ts"] - h["ts"], "queue_wait": q["dur"],
+                     "prefill": p["dur"], "deliver": first - f["ts"],
+                     "ttft": first - h["ts"]})
+    out["requests"] = len(rows)
+    if rows:
+        parts = ("ingress", "queue_wait", "prefill", "deliver")
+        ttft = _mean([r["ttft"] for r in rows])
+        told = sum(_mean([r[k] for r in rows]) for k in parts)
+        out.update({f"{k}_p50_ms": 1e3 * statistics.median(r[k] for r in rows)
+                    for k in parts})
+        out["ttft_mean_ms"] = 1e3 * ttft
+        out["ttft_unattributed_share"] = (
+            100.0 * (1.0 - told / ttft) if ttft > 0 else 0.0)
+    return out
+
+
 def flight_payload(events: List[dict], trace_id: Optional[str] = None) -> dict:
     """ONE shared export for every flight surface (``ray-tpu flight``,
     ``GET /api/flight``) — both emit exactly this, so they cannot
@@ -555,5 +703,6 @@ def flight_payload(events: List[dict], trace_id: Optional[str] = None) -> dict:
         "lanes": dict(sorted(lanes.items())),
         "pipeline": pipeline_report(events),
         "ingest": ingest_report(events),
+        "serve": serve_report(events),
         "trace_events": merged_chrome_trace(events, trace_id),
     }

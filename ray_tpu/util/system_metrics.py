@@ -3,9 +3,9 @@
 reporter feeding the dashboard and Prometheus).
 
 No psutil dependency: cpu from /proc/stat deltas, memory from
-/proc/meminfo, disk from statvfs, TPU duty cycle from the JAX runtime when
-a chip is attached (best-effort — 0.0 when unavailable, matching nodes
-without accelerators)."""
+/proc/meminfo, disk from statvfs, TPU HBM occupancy from the JAX runtime
+when a chip is attached (best-effort — 0.0 when unavailable, matching
+nodes without accelerators)."""
 
 from __future__ import annotations
 
@@ -55,7 +55,7 @@ class SystemMetricsSampler:
             "mem_used_bytes": mem_total - mem_avail,
             "disk_total_bytes": disk_total,
             "disk_used_bytes": disk_total - disk_free,
-            "tpu_duty_cycle": tpu_duty_cycle(),
+            "tpu_hbm_used_pct": tpu_hbm_used_pct(),
             "ts": time.time(),
         }
 
@@ -82,12 +82,14 @@ def _tpu_sample_failed():
     _tpu_retry_at = time.monotonic() + cooldown
 
 
-def tpu_duty_cycle() -> float:
-    """Best-effort TPU utilization: reported ONLY from processes whose JAX
-    BACKEND is already initialized (never import or initialize here — a
-    metrics sampler that triggers the jax import / chip attach inside a
-    health tick would blow the probe deadline AND take the chip from the
-    worker it was granted to). A slow stats call pauses sampling for a
+def tpu_hbm_used_pct() -> float:
+    """Best-effort HBM occupancy of the first chip (`bytes_in_use` over
+    `bytes_limit`, percent): a memory number, NOT a duty cycle — busy and
+    idle time come only from a profiler trace. Reported ONLY from processes
+    whose JAX BACKEND is already initialized (never import or initialize
+    here — a metrics sampler that triggers the jax import / chip attach
+    inside a health tick would blow the probe deadline AND take the chip
+    from the worker it was granted to). A slow stats call pauses sampling for a
     (growing) cooldown, then retries."""
     global _tpu_bad_streak
     import sys
@@ -106,9 +108,6 @@ def tpu_duty_cycle() -> float:
         devs = jax.devices()
         if not devs or devs[0].platform != "tpu":
             return 0.0
-        # jax.local_devices memory stats as a utilization proxy when the
-        # runtime exposes them (duty-cycle counters need libtpu monitoring,
-        # absent from this environment).
         stats = devs[0].memory_stats() or {}
         if time.monotonic() - t0 > 0.25:
             _tpu_sample_failed()  # too slow to poll every tick

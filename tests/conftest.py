@@ -21,9 +21,20 @@ import pytest  # noqa: E402
 import ray_tpu  # noqa: E402
 
 
+def _drop_stray_runtime():
+    """A thread an earlier test left behind (a prefetcher still pulling)
+    that calls the API after that test's shutdown boots a fresh default
+    runtime (`api._global_runtime` auto-inits). Without this, the next
+    fixture's `init` then raises "called twice", skips its own teardown, and
+    every later test of the file errors the same way (seen once: 23 of
+    `tests/test_data.py` after its first test, six workers, PR 24)."""
+    ray_tpu.shutdown()      # a no-op when there is none
+
+
 @pytest.fixture
 def local_runtime():
     """In-process runtime (reference analog: `ray_start_regular` local-mode)."""
+    _drop_stray_runtime()
     ray_tpu.init(local_mode=True, ignore_reinit_error=False)
     yield
     ray_tpu.shutdown()
@@ -32,6 +43,7 @@ def local_runtime():
 @pytest.fixture
 def cluster_runtime():
     """Full multiprocess runtime on this machine."""
+    _drop_stray_runtime()
     ray_tpu.init(num_cpus=4)
     yield
     ray_tpu.shutdown()

@@ -16,7 +16,10 @@ pytestmark = pytest.mark.cluster
 
 
 def _dashboard_url():
-    info_path = os.path.join("/tmp/ray_tpu/session_latest", "address.json")
+    from ray_tpu.core import api
+
+    # this test's own session: under xdist `session_latest` may be another's
+    info_path = os.path.join(api._global_runtime().backend.session_dir, "address.json")
     with open(info_path) as f:
         return json.load(f)["dashboard_url"]
 

@@ -100,6 +100,20 @@ def test_requeue_respects_cap_and_counts_overflow():
     assert rec.dropped == 3
 
 
+def test_clock_offset_inside_its_own_uncertainty_is_none():
+    """An offset smaller than half the round trip that measured it is not
+    evidence of skew: processes of one host keep the host's one clock."""
+    rec = FlightRecorder(cap=8, component="unit")
+    rec.set_clock_offset(0.004, rtt_s=0.010)
+    assert rec.clock_offset == 0.0
+    rec.set_clock_offset(-0.004, rtt_s=0.010)
+    assert rec.clock_offset == 0.0
+    rec.set_clock_offset(0.006, rtt_s=0.010)
+    assert rec.clock_offset == 0.006
+    rec.set_clock_offset(2.5)
+    assert rec.clock_offset == 2.5
+
+
 def test_clock_offset_rebases_spans_onto_controller_clock():
     rec = FlightRecorder(cap=8)
     rec.set_clock_offset(2.5)
@@ -201,10 +215,13 @@ def test_ingest_report_attributes_data_stalls():
 
 
 @pytest.mark.cluster
-def test_streaming_pipeline_records_data_lane_spans(cluster_runtime):
+def test_streaming_pipeline_records_data_lane_spans(cluster_runtime, monkeypatch):
     """A live pull-plane run + ingest bridge lands per-operator spans on
     `data/op{i}` lanes and ingest spans on `data/ingest`, and the recorder
     snapshot feeds ingest_report end to end."""
+    # The ring is read below: a flusher thread that an earlier test of this
+    # process started would drain it to the controller every half second.
+    monkeypatch.setattr(flight, "flush", lambda: 0)
     from ray_tpu import data as rdata
     from ray_tpu.data.context import DataContext
     from ray_tpu.data.streaming import StreamingIngest
@@ -285,6 +302,118 @@ def test_flight_spans_merge_into_trace_forest():
     assert t is not None
     assert {s["name"] for s in t["spans"]} == {"disagg.prefill_handoff",
                                                "kv.export"}
+
+
+# ------------------------------------------------------- phases of a loop
+def test_phase_adds_elapsed_ns_and_never_imports_jax():
+    """`flight.phase` in a process that has not imported jax: the time
+    between its two stamps is ADDED to the accumulator, nothing is
+    recorded, and jax stays out of `sys.modules` (the driver and the
+    controller stay off it)."""
+    import subprocess
+    import sys
+
+    code = (
+        "import sys, time\n"
+        "from ray_tpu.util import flight\n"
+        "acc = {'a_ns': 5}\n"
+        "with flight.phase('engine.a', acc, 'a_ns'):\n"
+        "    time.sleep(0.01)\n"
+        "with flight.phase('engine.a', acc, 'a_ns'):\n"
+        "    with flight.phase('engine.b', acc, 'b_ns'):\n"
+        "        time.sleep(0.01)\n"
+        "with flight.phase('engine.c'):\n"
+        "    pass\n"
+        "assert 'jax' not in sys.modules, 'phase imported jax'\n"
+        "assert acc['a_ns'] >= acc['b_ns'] + 10_000_000 > 20_000_000, acc\n"
+        "assert set(acc) == {'a_ns', 'b_ns'} and len(flight.recorder()) == 0\n"
+        "print('ok')\n"
+    )
+    out = subprocess.run([sys.executable, "-c", code], capture_output=True,
+                         text=True, timeout=120)
+    assert out.returncode == 0 and out.stdout.strip() == "ok", out.stderr
+
+
+def test_phase_keeps_time_when_the_body_raises():
+    acc = {}
+    with pytest.raises(KeyError):
+        with flight.phase("engine.x", acc, "x_ns"):
+            raise KeyError("boom")
+    assert acc["x_ns"] >= 0
+
+
+def _serve_step(ts, dur, decodes=2, prefills=0, **ns):
+    return _span("engine.step", ts, dur, lane="serve/engine-mixed",
+                 decodes=decodes, prefills=prefills, tokens=decodes,
+                 **{**dict.fromkeys(flight.SERVE_STEP_PHASES, 0),
+                    "waited_ns": 0, "queue_depth": 1, "running": decodes,
+                    "kv_util": 0.25, **ns})
+
+
+def _serve_request(tid, t, *, ingress=0.010, wait=0.050, prefill=0.200,
+                   deliver=0.020, first_chunk=True, skip=()):
+    """The spans one traced request leaves: caller, then engine."""
+    submit = t + ingress
+    first = submit + wait + prefill
+    handle = {"method": "generate_stream", "replica": "r0", "chunks": 3}
+    if first_chunk:
+        handle["first_chunk_ts"] = first + deliver
+    spans = {
+        "serve.handle": _span("serve.handle", t, 1.0, lane="serve/handle",
+                              trace=tid, **handle),
+        "engine.queue_wait": _span("engine.queue_wait", submit, wait,
+                                   lane="e/requests", trace=tid),
+        "engine.prefill": _span("engine.prefill", submit + wait, prefill,
+                                lane="e/requests", trace=tid),
+        "engine.first_token": _span("engine.first_token", first, 0.0,
+                                    lane="e/requests", trace=tid),
+    }
+    return [ev for name, ev in spans.items() if name not in skip]
+
+
+def test_serve_report_phases_and_ttft_closure():
+    """serve_report on hand-made spans: phase means per step, the idle
+    share of the window, gauges over decode steps only; a request joins
+    only when both sides are there (a missing engine side, a missing
+    caller side and a request with no first chunk are left out, not
+    guessed), and the four parts close the mean TTFT."""
+    events = [
+        _serve_step(100.0, 0.100, sched_ns=1_000_000, fetch_ns=9_000_000,
+                    export_ns=6_000_000, waited_ns=500_000_000),
+        _serve_step(100.9, 0.100, decodes=0, prefills=1, kv_util=0.75,
+                    sched_ns=3_000_000, export_ns=2_000_000),   # no decode
+    ]
+    events += _serve_request("t1", 100.0)
+    events += _serve_request("t2", 100.2, ingress=0.030, deliver=0.040)
+    events += _serve_request("t3", 100.3, skip=("engine.prefill",))
+    events += _serve_request("t4", 100.4, skip=("serve.handle",))
+    events += _serve_request("t5", 100.5, first_chunk=False)
+    rep = flight.serve_report(events)
+    assert rep["steps"] == 2 and abs(rep["window_s"] - 1.0) < 1e-9
+    assert abs(rep["step_ms"] - 100.0) < 1e-6
+    assert abs(rep["phase_ms"]["sched_ns"] - 2.0) < 1e-9
+    assert abs(rep["phase_ms"]["fetch_ns"] - 4.5) < 1e-9
+    assert abs(rep["phase_ms"]["export_ns"] - 4.0) < 1e-9
+    assert abs(rep["wait_share"] - 50.0) < 1e-6
+    assert rep["decode_lanes_mean"] == 2 and rep["kv_util_mean"] == 0.25
+    assert rep["queue_depth_mean"] == 1
+    assert rep["requests"] == 2
+    assert abs(rep["ingress_p50_ms"] - 20.0) < 1e-3     # median of 10, 30
+    assert abs(rep["queue_wait_p50_ms"] - 50.0) < 1e-3
+    assert abs(rep["prefill_p50_ms"] - 200.0) < 1e-3
+    assert abs(rep["deliver_p50_ms"] - 30.0) < 1e-3
+    assert abs(rep["ttft_mean_ms"] - 300.0) < 1e-3     # (280 + 320) / 2
+    assert abs(rep["ttft_unattributed_share"]) < 1e-6
+    # a delivery the spans do not explain shows as the unattributed share
+    late = _serve_request("t6", 200.0)
+    late[0]["args"]["first_chunk_ts"] += 0.070          # 280 -> 350 ms
+    rep = flight.serve_report(late)
+    assert rep["steps"] == 0 and rep["requests"] == 1
+    assert abs(rep["ttft_unattributed_share"] - 0.0) < 1e-6  # deliver grew
+    late[1]["dur"] -= 0.035                             # a part goes missing
+    assert abs(flight.serve_report(late)["ttft_unattributed_share"] - 10.0) < 1e-6
+    assert flight.serve_report(_two_lane_step(1, 100.0)) is None
+    assert flight.flight_payload(events)["serve"]["requests"] == 2
 
 
 # ------------------------------------------- one export path, two surfaces

@@ -28,7 +28,10 @@ def cluster_rt():
 
 
 def _session_info():
-    with open("/tmp/ray_tpu/session_latest/address.json") as f:
+    """This test's own session, not `session_latest`: under xdist that
+    link may belong to the cluster of another worker's test."""
+    path = os.path.join(api._global_runtime().backend.session_dir, "address.json")
+    with open(path) as f:
         return json.load(f)
 
 
@@ -268,6 +271,9 @@ def test_tail_logs_returns_worker_output(cluster_rt):
 def _run_cli(*args):
     env = dict(os.environ)
     env["PYTHONPATH"] = "/root/repo" + os.pathsep + env.get("PYTHONPATH", "")
+    info = _session_info()      # not whichever session `session_latest` names
+    env["RAY_TPU_ADDRESS"] = info["address"]
+    env["RAY_TPU_AUTH_TOKEN"] = info.get("auth_token", "")
     return subprocess.run(
         [sys.executable, "-m", "ray_tpu.scripts.cli", *args],
         capture_output=True, text=True, timeout=60, env=env, cwd="/root/repo",
@@ -421,8 +427,7 @@ def test_node_system_metrics_reported():
         import json
         import os
 
-        with open("/tmp/ray_tpu/session_latest/address.json") as f:
-            metrics_url = json.load(f)["metrics_url"]
+        metrics_url = _session_info()["metrics_url"]
         text = urllib.request.urlopen(metrics_url, timeout=10).read().decode()
         assert "ray_tpu_node_mem_used_bytes" in text
         assert 'ray_tpu_node_cpu_percent{node="node0"}' in text

@@ -777,3 +777,196 @@ class TestLLMDeployment:
         )
         assert len(chunks) == 5
         serve.delete("llm")
+
+
+# ------------------------------------------- host phases and request spans
+IN_SPAN = ("sched_ns", "side_ns", "build_ns", "dispatch_ns", "fetch_ns",
+           "sample_ns")
+
+
+@pytest.fixture
+def ring(monkeypatch):
+    """A fresh flight ring that nothing drains: returns the spans of one
+    name recorded since the test began."""
+    from ray_tpu.util import flight
+
+    monkeypatch.setenv("RAY_TPU_FLIGHT", "1")
+    monkeypatch.setattr(flight, "flush", lambda: 0)   # the flusher's target
+    flight._reset_for_tests()
+    yield lambda name: [e for e in flight.recorder().snapshot()
+                        if e["name"].startswith(name)]
+    flight._reset_for_tests()
+
+
+class TestEnginePhases:
+    def test_step_record_carries_phases_that_sum_to_its_duration(self, ring):
+        """Every `engine.step` record carries the seven phase attrs, the
+        idle wait and the three gauges; the six phases inside the span
+        account for its duration to within 2% (a model wide enough that a
+        step is milliseconds: what is left over is interpreter time between
+        two phases, microseconds a step)."""
+        import jax
+
+        from ray_tpu.models.gpt import init_params
+        from ray_tpu.util import flight
+
+        cfg = _tiny_cfg(vocab_size=2048, n_layers=6, d_model=256, n_heads=4,
+                        d_head=64, d_mlp=1024)
+        eng = _make_engine(cfg, init_params(jax.random.PRNGKey(0), cfg))
+        # the first pass compiles, the second (prefix-cache hits: other
+        # chunk shapes) compiles again; the third is steady and is judged
+        for _ in range(3):
+            for i in range(4):
+                eng.submit([1 + i] * 8, max_new_tokens=12)
+            first = len(ring("engine.step"))
+            _drive(eng)
+        steps = ring("engine.step")[first:]
+        assert len(steps) >= 12
+        for ev in steps:
+            a = ev["args"]
+            assert set(flight.SERVE_STEP_PHASES) | {
+                "waited_ns", "queue_depth", "running", "kv_util",
+                "prefills", "decodes", "tokens"} <= set(a)
+            assert all(isinstance(a[k], int) and a[k] >= 0
+                       for k in flight.SERVE_STEP_PHASES)
+            assert a["waited_ns"] == 0          # driven by step(): no _loop
+            assert a["lane"] == "serve/engine-mixed"
+            assert sum(a[k] for k in IN_SPAN) <= ev["dur"] * 1e9 + 1e3
+        # the median step, so that one step descheduled between two phases
+        # on a loaded machine does not decide it
+        shares = sorted(sum(ev["args"][k] for k in IN_SPAN) / (ev["dur"] * 1e9)
+                        for ev in steps)
+        assert 0.98 <= shares[len(shares) // 2] <= 1.0, shares
+        last = steps[-1]["args"]
+        assert last["queue_depth"] == 0 and last["export_ns"] > 0
+
+    def test_idle_wait_is_carried_into_the_next_step_record(
+        self, tiny_engine_parts, ring
+    ):
+        cfg, params = tiny_engine_parts
+        eng = _make_engine(cfg, params)
+        eng.start()
+        try:
+            assert eng.generate([1, 2, 3], 2) and eng.generate([1, 2, 3], 2)
+            time.sleep(0.35)                     # the driver thread idles
+            assert len(eng.generate([4, 5, 6], 3)) == 3
+        finally:
+            eng.shutdown()
+        waits = [e["args"]["waited_ns"] for e in ring("engine.step")]
+        # the wait shows on the first step after it, and only there
+        assert max(waits) >= 0.3e9
+        after = waits[waits.index(max(waits)) + 1:]
+        assert after and all(w < 0.05e9 for w in after)
+
+    def test_request_spans_go_through_the_ring_in_order_after_preemption(
+        self, tiny_engine_parts, ring, monkeypatch
+    ):
+        """The five request spans of a traced request land in the flight
+        ring with their names, attrs and trace id, submit <= admit <= first
+        <= end, also when the request was preempted and recomputed; an
+        untraced request records none."""
+        from ray_tpu.util import tracing
+
+        cfg, params = tiny_engine_parts
+        eng = _make_engine(cfg, params, num_blocks=9, block_size=4)
+        ids = iter(["t-a", "t-b", None])
+        monkeypatch.setattr(tracing, "get_trace_id", lambda: next(ids))
+        rids = [eng.submit([3] * 8, max_new_tokens=16) for _ in range(3)]
+        _drive(eng, max_steps=500)
+        assert eng.total_preemptions > 0
+        spans = ring("engine.")
+        names = ["engine.queue_wait", "engine.admission", "engine.prefill",
+                 "engine.first_token", "engine.completion"]
+        for tid, rid in zip(("t-a", "t-b"), rids):
+            mine = {e["name"]: e for e in spans if e["trace"] == tid}
+            assert list(mine) == names
+            for e in mine.values():
+                assert e["args"]["request_id"] == rid
+                assert e["args"]["tokens"] == 16
+                assert e["args"]["lane"] == "serve/engine-mixed/requests"
+            assert mine["engine.completion"]["args"]["finish_reason"] == "length"
+            q, p, f, c = (mine[n] for n in (names[0], *names[2:]))
+            eps = 1e-6
+            assert q["ts"] + q["dur"] <= p["ts"] + eps          # submit <= admit
+            assert abs(mine["engine.admission"]["ts"] - p["ts"]) < eps
+            assert p["ts"] + p["dur"] <= f["ts"] + eps          # admit <= first
+            assert abs(f["ts"] - c["ts"]) < eps and c["dur"] > 0  # first <= end
+        traced = {e["trace"] for e in spans if e["name"] != "engine.step"}
+        assert traced == {"t-a", "t-b"}
+
+    def test_driver_thread_sends_no_trace_event(
+        self, tiny_engine_parts, monkeypatch
+    ):
+        """A finished traced request costs the decode thread no control-
+        plane send: its spans leave the process on the ring's flusher (here:
+        an explicit flush from this thread), never from `llm-engine`."""
+        import types
+
+        from ray_tpu.core import api
+        from ray_tpu.util import flight, tracing
+
+        sends = []
+
+        class Backend:
+            def record_trace_event(self, events):
+                sends.append((threading.current_thread().name,
+                              [e["name"] for e in events]))
+
+        rt = types.SimpleNamespace(
+            backend=Backend(), _context=types.SimpleNamespace(trace_id="t-x"))
+        monkeypatch.setenv("RAY_TPU_FLIGHT", "1")
+        monkeypatch.setattr(api, "_runtime_or_attach", lambda: rt)
+        flight._reset_for_tests()
+        assert tracing.get_trace_id() == "t-x"
+        cfg, params = tiny_engine_parts
+        eng = _make_engine(cfg, params)
+        eng.start()
+        try:
+            assert len(eng.generate([1, 2, 3], 4)) == 4
+        finally:
+            eng.shutdown()
+        flight.flush()      # what the flusher thread does every half second
+        flight._reset_for_tests()
+        shipped = [n for _, names in sends for n in names]
+        assert "engine.completion" in shipped and "engine.step" in shipped
+        assert all(thread != "llm-engine" for thread, _ in sends), sends
+
+    def test_phases_are_annotations_in_a_profiler_session(
+        self, tiny_engine_parts, tmp_path
+    ):
+        """Under a `jax.profiler` session the same phases are on the
+        profiler's clock: `engine.*` annotations in the host plane of the
+        `.xplane.pb`, the phases nested in `engine.step`."""
+        import glob
+
+        import jax
+        from jax.profiler import ProfileData
+
+        cfg, params = tiny_engine_parts
+        eng = _make_engine(cfg, params)
+        eng.submit([1, 2, 3, 4], max_new_tokens=3)
+        _drive(eng)                                   # compiled
+        eng.submit([5, 6, 7, 8], max_new_tokens=3)
+        opts = jax.profiler.ProfileOptions()
+        opts.python_tracer_level = 0
+        jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+        try:
+            _drive(eng)
+        finally:
+            jax.profiler.stop_trace()
+        (path,) = glob.glob(str(tmp_path / "plugins/profile/*/*.xplane.pb"))
+        events = [
+            (e.name, e.start_ns, e.start_ns + e.duration_ns)
+            for plane in ProfileData.from_file(path).planes
+            if plane.name.startswith("/host:CPU")
+            for line in plane.lines for e in line.events
+            if e.name.startswith("engine.")]
+        names = {n for n, _, _ in events}
+        assert {"engine.step", "engine.schedule", "engine.side_work",
+                "engine.build", "engine.dispatch", "engine.fetch_logits",
+                "engine.sample", "engine.export_metrics"} <= names, names
+        steps = [(s, e) for n, s, e in events if n == "engine.step"]
+        assert len(steps) == 3
+        for n, s, e in events:
+            if n != "engine.step":
+                assert any(s0 <= s and e <= e0 for s0, e0 in steps), n

@@ -253,7 +253,11 @@ def test_serve_request_trace_end_to_end(cluster_runtime):
         assert want <= names, f"missing spans: {want - names}"
 
         # Dashboard surfaces the same trace.
-        with open("/tmp/ray_tpu/session_latest/address.json") as f:
+        # this test's own session: under xdist `session_latest` may be the
+        # cluster of another worker's test
+        from ray_tpu.core import api
+
+        with open(api._global_runtime().backend.session_dir + "/address.json") as f:
             info = json.load(f)
         rows = json.loads(
             urllib.request.urlopen(info["dashboard_url"] + "/api/traces",
@@ -317,5 +321,72 @@ def test_serve_request_trace_end_to_end(cluster_runtime):
         )
         assert 'deployment="LLMDeployment"' in hit_line
         assert 'replica="' in hit_line, "cache counters must be replica-tagged"
+    finally:
+        serve.shutdown()
+
+
+def test_serve_handle_trace_covers_caller_to_delivery(cluster_runtime):
+    """One trace id set by a Python caller covers the request's whole path
+    through `handle.options(stream=True)`: `serve.handle` (caller's
+    process), `replica.handle_stream` and the engine's five request spans,
+    with the first chunk's stamp between the engine's first token and the
+    span's end; the unary path leaves a `serve.handle` without it."""
+    from ray_tpu import serve
+    from ray_tpu.util import flight
+
+    serve.start()
+    app = serve.LLMDeployment.bind(
+        model="gpt2-small",
+        model_overrides=dict(
+            vocab_size=64, n_layers=2, d_model=48, n_heads=3, d_head=16,
+            d_mlp=96, max_seq=128, attn_impl="ref", remat=False,
+            dtype="float32",
+        ),
+        engine_options={"num_blocks": 32, "block_size": 4, "max_num_seqs": 4},
+    )
+    handle = serve.run(app, name="llm-handle", route_prefix="/llm-handle",
+                       timeout_s=120)
+    try:
+        # untraced, and it warms the engine's programs
+        assert len(list(handle.options(stream=True).generate_stream.remote(
+            [1, 2, 3], 3))) == 3
+        tid, tid2 = tracing.new_trace_id(), tracing.new_trace_id()
+        tracing.set_trace_id(tid)
+        chunks = list(handle.options(stream=True).generate_stream.remote(
+            [4, 5, 6, 7], 5))
+        tracing.set_trace_id(tid2)
+        out = handle.generate.remote([4, 5, 6, 7], 2).result(timeout_s=60)
+        tracing.set_trace_id(None)
+        assert len(chunks) == 5 and len(out["tokens"]) == 2
+
+        want = {"serve.handle", "replica.handle_stream", "engine.queue_wait",
+                "engine.admission", "engine.prefill", "engine.first_token",
+                "engine.completion"}
+        end = time.monotonic() + 60.0
+        while time.monotonic() < end:
+            flight.flush()                      # the caller's own ring
+            events = [e for e in ray_tpu.timeline() if e.get("event") == "span"]
+            mine = {e["name"]: e for e in events if e.get("trace") == tid}
+            unary = {e["name"]: e for e in events if e.get("trace") == tid2}
+            if want <= set(mine) and {"serve.handle", "replica.handle"} <= set(unary):
+                break
+            time.sleep(0.3)
+        assert want <= set(mine), f"missing spans: {want - set(mine)}"
+        h, first = mine["serve.handle"], mine["engine.first_token"]
+        a = h["args"]
+        assert a["method"] == "generate_stream" and a["chunks"] == 5
+        assert a["replica"] and a["pick_ns"] >= 0 and a["submit_ns"] > 0
+        assert a["lane"] == "serve/handle"
+        # caller -> engine.submit -> first token -> first chunk -> last chunk
+        # (one machine: every stamp is on the same clock to well under 50 ms)
+        eps = 0.05
+        assert h["ts"] <= mine["engine.queue_wait"]["ts"] + eps
+        assert first["ts"] - eps <= a["first_chunk_ts"] <= h["ts"] + h["dur"] + eps
+        assert "first_chunk_ts" not in unary["serve.handle"]["args"]
+        assert unary["serve.handle"]["args"]["method"] == "generate"
+        # the untraced call recorded nothing of its own
+        assert sum(1 for e in events if e["name"] == "serve.handle") == 2
+        rep = flight.serve_report([e for e in events if e.get("trace") == tid])
+        assert rep["requests"] == 1 and abs(rep["ttft_unattributed_share"]) < 10
     finally:
         serve.shutdown()

@@ -127,7 +127,7 @@ def test_joblib_backend(cluster_runtime):
 
 
 # ---------------------------------------------------- system metrics latch
-def test_tpu_duty_cycle_cooldown_not_permanent(monkeypatch):
+def test_tpu_hbm_used_pct_cooldown_not_permanent(monkeypatch):
     """A slow/failed TPU stats sample must pause sampling for a cooldown and
     then RETRY — the r5 permanent latch killed the metric for the process
     lifetime on one transient hiccup (ADVICE r5 #2)."""
@@ -142,7 +142,7 @@ def test_tpu_duty_cycle_cooldown_not_permanent(monkeypatch):
     first_cooldown = sm._tpu_retry_at - _time.monotonic()
     assert 0 < first_cooldown <= sm._TPU_COOLDOWN_S + 1
     # In cooldown: short-circuits to 0.0 without touching jax.
-    assert sm.tpu_duty_cycle() == 0.0
+    assert sm.tpu_hbm_used_pct() == 0.0
 
     # Consecutive failures back off exponentially, capped.
     sm._tpu_sample_failed()
@@ -164,7 +164,7 @@ def test_tpu_duty_cycle_cooldown_not_permanent(monkeypatch):
         raise RuntimeError("transient stats failure")
 
     monkeypatch.setattr(jax, "devices", boom)
-    assert sm.tpu_duty_cycle() == 0.0
+    assert sm.tpu_hbm_used_pct() == 0.0
     assert sm._tpu_bad_streak == streak_before + 1, "sampler did not retry"
 
     # And a healthy (fast, non-TPU) sample resets nothing harmful: with the
