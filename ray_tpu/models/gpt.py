@@ -1004,18 +1004,31 @@ def decode_step(params, token, cache, cfg: GPTConfig):
 # --------------------------------------------------- paged KV-cache decode
 # Block-table cache layout for the continuous-batching engine
 # (`ray_tpu.serve.engine`): the KV cache is a pool of fixed-size token
-# blocks [L, NB, H, BS, Dh]; each sequence owns an ordered block table and
-# token position p lives at (table[p // BS], p % BS). Unlike `init_cache`'s
-# dense [L, B, H, M, Dh] layout, sequences of wildly different lengths
-# share one physical pool with no per-sequence max_seq reservation — the
-# memory model that makes iteration-level admission worth doing.
-# Block 0 is the engine's null block: padding lanes in bucketed batches
-# point their tables at it so their writes land somewhere harmless.
+# blocks [L, NB, BS, H*Dh]; each sequence owns an ordered block table and
+# token position p is ONE contiguous row [H*Dh] at (table[p // BS], p % BS).
+# Unlike `init_cache`'s dense [L, B, H, M, Dh] layout, sequences of wildly
+# different lengths share one physical pool with no per-sequence max_seq
+# reservation — the memory model that makes iteration-level admission
+# worth doing. Block 0 is the engine's null block: padding lanes in
+# bucketed batches point their tables at it so their writes land somewhere
+# harmless.
+#
+# Why rows, and why the pool is the layer scan's CARRY: the TPU keeps an
+# array in the layout whose two minor dimensions fill its (16, 128) bf16
+# tiles. A pool [.., BS, Dh] with Dh = 64 half-fills a tile, so the device
+# stored it block-index-minor and every layer of every paged program
+# transposed the layer's whole pool in and out (83% of device time on the
+# v5e, PERF.md §6 PR 25). [BS, H*Dh] tiles exactly when H*Dh is a multiple
+# of 128, the device keeps it row-major, and a carried, donated pool is
+# then updated in place: a program moves only the rows it writes and the
+# blocks its lanes read. Other widths stay correct, not fast.
+# `scripts/paged_rehearse.py` compiles the three programs for a described
+# v5e and lists whatever pool-sized operation is left.
 
 
 def init_paged_cache(cfg: GPTConfig, num_blocks: int, block_size: int):
-    """Physical paged KV pool: {"k","v"} of [L, NB, H, BS, Dh] in cfg.dtype."""
-    shape = (cfg.n_layers, num_blocks, cfg.n_heads, block_size, cfg.d_head)
+    """Physical paged KV pool: {"k","v"} of [L, NB, BS, H*Dh] in cfg.dtype."""
+    shape = (cfg.n_layers, num_blocks, block_size, cfg.n_heads * cfg.d_head)
     return {"k": jnp.zeros(shape, cfg.dtype), "v": jnp.zeros(shape, cfg.dtype)}
 
 
@@ -1028,16 +1041,108 @@ def _rope_rotate(x, c, s):
 
 
 def _rope_qk(cfg: GPTConfig, q, k, rope_tables, positions):
-    """RoPE for [B, H, 1, Dh] q/k at per-lane integer positions [B]."""
+    """RoPE for [B, H, S, Dh] q/k at per-lane integer positions [B, S]."""
     cos, sin = rope_tables
     rd = min(cfg.rotary_dim, cfg.d_head)
-    c = cos[positions][:, None, None, :]  # [B, 1, 1, rd/2]
-    s = sin[positions][:, None, None, :]
+    c = cos[positions][:, None]  # [B, 1, S, rd/2]
+    s = sin[positions][:, None]
     if rd < cfg.d_head:
         q = jnp.concatenate([_rope_rotate(q[..., :rd], c, s), q[..., rd:]], -1)
         k = jnp.concatenate([_rope_rotate(k[..., :rd], c, s), k[..., rd:]], -1)
         return q, k
     return _rope_rotate(q, c, s), _rope_rotate(k, c, s)
+
+
+def _paged_layers(params, tokens, pos, valid, block_tables, kv, cfg: GPTConfig):
+    """Embedding and the layer loop of the three paged programs: lane b
+    brings S new tokens, token j at global position pos[b, j], over its
+    block table.
+
+    tokens, pos [B, S] int32; valid [B, S] bool (or True) — K/V of invalid
+    slots go to the null block, so a padded slot can never clobber a
+    neighbouring block through index clamping;
+    block_tables [B, W] int32. Each layer writes the new tokens' K/V rows
+    in place FIRST, then attends causally over the gathered table history
+    (query j sees columns 0..pos[b, j]) — cached prefix, earlier chunks and
+    the new tokens themselves all come back through one path. The pool
+    rides the scan as its carry, indexed by the layer number; the stacked
+    weights are the xs. Returns (hidden states [B, S, E] before the final
+    norm, kv)."""
+    if cfg.mlp_type == "moe":
+        raise NotImplementedError("paged decode does not support MoE yet")
+    B, S = tokens.shape
+    W = block_tables.shape[1]
+    BS = kv["k"].shape[2]
+    M = W * BS
+    H, Dh = cfg.n_heads, cfg.d_head
+    scale = 1.0 / math.sqrt(Dh)
+    x = params["tok_embed"][tokens].astype(cfg.dtype)  # [B, S, E]
+    if cfg.pos == "learned":
+        x = x + params["pos_embed"][pos].astype(cfg.dtype)
+    rope_tables = None
+    if cfg.pos == "rotary":
+        rope_tables = rope_frequencies(
+            min(cfg.rotary_dim, Dh), cfg.max_seq, dtype=jnp.float32
+        )
+    phys = jnp.where(
+        valid,
+        jnp.take_along_axis(block_tables, jnp.minimum(pos // BS, W - 1), axis=1),
+        0,
+    )                                                  # [B, S] physical block
+    off = pos % BS
+    seen = jnp.arange(M)[None, None, None, :] <= pos[:, None, :, None]
+    layer_stack = {k: params[k] for k in _LAYER_KEYS if k in params}
+
+    def scan_body(carry, inp):
+        x, kk, vv = carry                              # kk/vv: the whole pool
+        l, layer_params = inp
+        p = jax.tree_util.tree_map(lambda a: a.astype(cfg.dtype), layer_params)
+        h = _norm(x, p["ln1_w"], p["ln1_b"], cfg.norm)
+        qkv = jnp.einsum("bse,ethd->btshd", h, p["w_qkv"]) + p["b_qkv"][:, None]
+        q, k = (qkv[:, i].transpose(0, 2, 1, 3) for i in range(2))  # [B,H,S,Dh]
+        if cfg.pos == "rotary":
+            q, k = _rope_qk(cfg, q, k, rope_tables, pos)
+        k = k.transpose(0, 2, 1, 3).reshape(B, S, H * Dh)
+        v = qkv[:, 2].reshape(B, S, H * Dh)
+        kk = kk.at[l, phys, off].set(k.astype(kk.dtype))
+        vv = vv.at[l, phys, off].set(v.astype(vv.dtype))
+        # Each lane's history: [B, W, BS, H*Dh] -> [B, W*BS, H, Dh].
+        gk = kk[l, block_tables].reshape(B, M, H, Dh)
+        gv = vv[l, block_tables].reshape(B, M, H, Dh)
+        scores = jnp.einsum(
+            "bhsd,bthd->bhst", q, gk, preferred_element_type=jnp.float32
+        ) * scale                                      # [B, H, S, M]
+        probs = jax.nn.softmax(jnp.where(seen, scores, -1e30), axis=-1)
+        attn = jnp.einsum("bhst,bthd->bhsd", probs.astype(gv.dtype), gv)
+        attn_out = jnp.einsum("bhsd,hde->bse", attn, p["w_o"]) + p["b_o"]
+
+        if cfg.parallel_block:
+            mlp_in = h
+        else:
+            x = x + attn_out
+            mlp_in = _norm(x, p["ln2_w"], p["ln2_b"], cfg.norm)
+        u = jnp.einsum("bse,ef->bsf", mlp_in, p["w_in"]) + p["b_in"]
+        if cfg.activation == "swiglu":
+            g = jnp.einsum("bse,ef->bsf", mlp_in, p["w_gate"])
+            u = jax.nn.silu(g) * u
+        else:
+            u = jax.nn.gelu(u)
+        mlp_out = jnp.einsum("bsf,fe->bse", u, p["w_out"]) + p["b_out"]
+        out = x + attn_out + mlp_out if cfg.parallel_block else x + mlp_out
+        return (out, kk, vv), None
+
+    (x, kk, vv), _ = jax.lax.scan(
+        scan_body, (x, kv["k"], kv["v"]),
+        (jnp.arange(cfg.n_layers), layer_stack),
+    )
+    return x, {"k": kk, "v": vv}
+
+
+def _paged_logits(params, x, cfg: GPTConfig):
+    """Final norm and head over hidden states [..., E] -> [..., V] f32."""
+    x = _norm(x, params["ln_f_w"], params["ln_f_b"], cfg.norm)
+    head = params["tok_embed"].T if cfg.tie_embeddings else params["lm_head"]
+    return jnp.einsum("...e,ev->...v", x, head.astype(cfg.dtype)).astype(jnp.float32)
 
 
 def prefill_paged(params, tokens, real_len, pos_offset, block_table, kv,
@@ -1050,96 +1155,20 @@ def prefill_paged(params, tokens, real_len, pos_offset, block_table, kv,
     prompt[pos_offset : pos_offset + real_len]; `real_len` / `pos_offset`
     are traced scalars (one compiled program per (Sp, W) bucket pair covers
     every chunk length and offset); `block_table` [W] int32 maps the
-    sequence's blocks. Each layer scatters the chunk's K/V to its (block,
-    offset) slots FIRST, then attends over the gathered table history —
-    prefix-cache hits and earlier chunks' KV below `pos_offset` are read
-    from the cache, never recomputed, and a monolithic prefill is just the
-    pos_offset=0 chunk covering the whole prompt. K/V of padded positions
-    scatter to the null block. Returns (next-token logits [V] f32 at global
-    position pos_offset + real_len - 1, kv) — only meaningful on the FINAL
-    chunk of a prompt.
+    sequence's blocks. Prefix-cache hits and earlier chunks' KV below
+    `pos_offset` are read from the cache, never recomputed, and a
+    monolithic prefill is just the pos_offset=0 chunk covering the whole
+    prompt. K/V of padded positions go to the null block. Returns
+    (next-token logits [V] f32 at global position pos_offset + real_len -
+    1, kv) — only meaningful on the FINAL chunk of a prompt.
     """
-    if cfg.mlp_type == "moe":
-        raise NotImplementedError("paged decode does not support MoE yet")
-    _, Sp = tokens.shape
-    BS = kv["k"].shape[3]
-    W = block_table.shape[0]
-    M = W * BS
-    H, Dh = cfg.n_heads, cfg.d_head
-    scale = 1.0 / math.sqrt(cfg.d_head)
-    rel = jnp.arange(Sp)
-    positions = pos_offset + rel                 # global token positions [Sp]
-    x = params["tok_embed"][tokens].astype(cfg.dtype)  # [1, Sp, E]
-    if cfg.pos == "learned":
-        x = x + params["pos_embed"][positions].astype(cfg.dtype)
-    rope_tables = None
-    if cfg.pos == "rotary":
-        rd = min(cfg.rotary_dim, cfg.d_head)
-        rope_tables = rope_frequencies(rd, cfg.max_seq, dtype=jnp.float32)
-    valid = rel < real_len
-    phys = jnp.where(valid, block_table[jnp.minimum(positions // BS, W - 1)], 0)
-    off = positions % BS
-    cols = jnp.arange(M)
-    layer_stack = {k: params[k] for k in _LAYER_KEYS if k in params}
-
-    def scan_body(x, inp):
-        layer_params, kk, vv = inp  # kk/vv: [NB, H, BS, Dh]
-        p = jax.tree_util.tree_map(lambda a: a.astype(cfg.dtype), layer_params)
-        h = _norm(x, p["ln1_w"], p["ln1_b"], cfg.norm)
-        qkv = jnp.einsum("bse,ethd->btshd", h, p["w_qkv"]) + p["b_qkv"][:, None]
-        q, k, v = (
-            qkv[:, i].transpose(0, 2, 1, 3).reshape(1, H, Sp, Dh)
-            for i in range(3)
-        )
-        if cfg.pos == "rotary":
-            cos, sin = rope_tables
-            rd = min(cfg.rotary_dim, Dh)
-            c, s = cos[positions], sin[positions]
-            q = jnp.concatenate(
-                [apply_rope(q[..., :rd], c, s, None), q[..., rd:]], -1
-            ) if rd < Dh else apply_rope(q, c, s, None)
-            k = jnp.concatenate(
-                [apply_rope(k[..., :rd], c, s, None), k[..., rd:]], -1
-            ) if rd < Dh else apply_rope(k, c, s, None)
-        # Scatter the chunk's K/V to each position's (block, offset) slot,
-        # then gather the WHOLE table history — cached prefix, earlier
-        # chunks, and this chunk all come back through one path.
-        kk = kk.at[phys, :, off].set(k[0].transpose(1, 0, 2).astype(kk.dtype))
-        vv = vv.at[phys, :, off].set(v[0].transpose(1, 0, 2).astype(vv.dtype))
-        gk = kk[block_table].transpose(1, 0, 2, 3).reshape(H, M, Dh)
-        gv = vv[block_table].transpose(1, 0, 2, 3).reshape(H, M, Dh)
-        scores = jnp.einsum(
-            "hsd,htd->hst", q[0], gk, preferred_element_type=jnp.float32
-        ) * scale                                    # [H, Sp, M]
-        scores = jnp.where(
-            cols[None, None, :] <= positions[None, :, None], scores, -1e30
-        )
-        probs = jax.nn.softmax(scores, axis=-1)
-        attn = jnp.einsum("hst,htd->hsd", probs.astype(gv.dtype), gv)
-        attn_out = jnp.einsum("bhsd,hde->bse", attn[None], p["w_o"]) + p["b_o"]
-
-        if cfg.parallel_block:
-            mlp_in = h
-        else:
-            x = x + attn_out
-            mlp_in = _norm(x, p["ln2_w"], p["ln2_b"], cfg.norm)
-        u = jnp.einsum("bse,ef->bsf", mlp_in, p["w_in"]) + p["b_in"]
-        if cfg.activation == "swiglu":
-            g = jnp.einsum("bse,ef->bsf", mlp_in, p["w_gate"])
-            u = jax.nn.silu(g) * u
-        else:
-            u = jax.nn.gelu(u)
-        mlp_out = jnp.einsum("bsf,fe->bse", u, p["w_out"]) + p["b_out"]
-        out = x + attn_out + mlp_out if cfg.parallel_block else x + mlp_out
-        return out, (kk, vv)
-
-    x, (ks, vs) = jax.lax.scan(scan_body, x, (layer_stack, kv["k"], kv["v"]))
-    kv = {"k": ks, "v": vs}
-    x = _norm(x, params["ln_f_w"], params["ln_f_b"], cfg.norm)
+    rel = jnp.arange(tokens.shape[1])
+    pos = (pos_offset + rel)[None]               # global token positions [1, Sp]
+    x, kv = _paged_layers(
+        params, tokens, pos, (rel < real_len)[None], block_table[None], kv, cfg
+    )
     h = x[0, jnp.maximum(real_len - 1, 0)]  # [E] — last REAL chunk position
-    head = params["tok_embed"].T if cfg.tie_embeddings else params["lm_head"]
-    logits = jnp.einsum("e,ev->v", h, head.astype(cfg.dtype))
-    return logits.astype(jnp.float32), kv
+    return _paged_logits(params, h, cfg), kv
 
 
 def decode_step_paged(params, token, positions, block_tables, kv, cfg: GPTConfig):
@@ -1152,76 +1181,10 @@ def decode_step_paged(params, token, positions, block_tables, kv, cfg: GPTConfig
     (block table = null block, position 0) produce garbage logits the
     engine discards.
     """
-    if cfg.mlp_type == "moe":
-        raise NotImplementedError("paged decode does not support MoE yet")
-    B = token.shape[0]
-    W = block_tables.shape[1]
-    BS = kv["k"].shape[3]
-    M = W * BS
-    H, Dh = cfg.n_heads, cfg.d_head
-    scale = 1.0 / math.sqrt(cfg.d_head)
-    x = params["tok_embed"][token][:, None].astype(cfg.dtype)  # [B, 1, E]
-    if cfg.pos == "learned":
-        x = x + params["pos_embed"][positions][:, None].astype(cfg.dtype)
-    rope_tables = None
-    if cfg.pos == "rotary":
-        rd = min(cfg.rotary_dim, cfg.d_head)
-        rope_tables = rope_frequencies(rd, cfg.max_seq, dtype=jnp.float32)
-    phys = jnp.take_along_axis(
-        block_tables, (positions // BS)[:, None], axis=1
-    )[:, 0]                                            # [B] physical block
-    off = positions % BS
-    cols = jnp.arange(M)
-    layer_stack = {k: params[k] for k in _LAYER_KEYS if k in params}
-
-    def scan_body(x, inp):
-        layer_params, kk, vv = inp  # kk/vv: [NB, H, BS, Dh]
-        p = jax.tree_util.tree_map(lambda a: a.astype(cfg.dtype), layer_params)
-        h = _norm(x, p["ln1_w"], p["ln1_b"], cfg.norm)
-        qkv = jnp.einsum("bse,ethd->btshd", h, p["w_qkv"]) + p["b_qkv"][:, None]
-        q, k, v = (
-            qkv[:, i].transpose(0, 2, 1, 3).reshape(B, H, 1, Dh) for i in range(3)
-        )
-        if cfg.pos == "rotary":
-            q, k = _rope_qk(cfg, q, k, rope_tables, positions)
-        # Scatter this step's K/V to each lane's (block, offset) slot.
-        kk = kk.at[phys, :, off].set(k[:, :, 0].astype(kk.dtype))
-        vv = vv.at[phys, :, off].set(v[:, :, 0].astype(vv.dtype))
-        # Gather each lane's history: [B, W, H, BS, Dh] -> [B, H, W*BS, Dh].
-        gk = kk[block_tables].transpose(0, 2, 1, 3, 4).reshape(B, H, M, Dh)
-        gv = vv[block_tables].transpose(0, 2, 1, 3, 4).reshape(B, H, M, Dh)
-        scores = jnp.einsum(
-            "bhsd,bhtd->bhst", q, gk, preferred_element_type=jnp.float32
-        ) * scale                                       # [B, H, 1, M]
-        scores = jnp.where(
-            cols[None, None, None, :] <= positions[:, None, None, None],
-            scores, -1e30,
-        )
-        probs = jax.nn.softmax(scores, axis=-1)
-        attn = jnp.einsum("bhst,bhtd->bhsd", probs.astype(gv.dtype), gv)
-        attn_out = jnp.einsum("bhsd,hde->bse", attn, p["w_o"]) + p["b_o"]
-
-        if cfg.parallel_block:
-            mlp_in = h
-        else:
-            x = x + attn_out
-            mlp_in = _norm(x, p["ln2_w"], p["ln2_b"], cfg.norm)
-        u = jnp.einsum("bse,ef->bsf", mlp_in, p["w_in"]) + p["b_in"]
-        if cfg.activation == "swiglu":
-            g = jnp.einsum("bse,ef->bsf", mlp_in, p["w_gate"])
-            u = jax.nn.silu(g) * u
-        else:
-            u = jax.nn.gelu(u)
-        mlp_out = jnp.einsum("bsf,fe->bse", u, p["w_out"]) + p["b_out"]
-        out = x + attn_out + mlp_out if cfg.parallel_block else x + mlp_out
-        return out, (kk, vv)
-
-    x, (ks, vs) = jax.lax.scan(scan_body, x, (layer_stack, kv["k"], kv["v"]))
-    kv = {"k": ks, "v": vs}
-    x = _norm(x, params["ln_f_w"], params["ln_f_b"], cfg.norm)
-    head = params["tok_embed"].T if cfg.tie_embeddings else params["lm_head"]
-    logits = jnp.einsum("be,ev->bv", x[:, -1], head.astype(cfg.dtype))
-    return logits.astype(jnp.float32), kv
+    x, kv = _paged_layers(
+        params, token[:, None], positions[:, None], True, block_tables, kv, cfg
+    )
+    return _paged_logits(params, x[:, 0], cfg), kv
 
 
 def verify_step_paged(params, tokens, positions, valid_len, block_tables, kv,
@@ -1232,106 +1195,21 @@ def verify_step_paged(params, tokens, positions, valid_len, block_tables, kv,
     tokens [B, K1] int32 — lane b's token j sits at global position
     `positions[b] + j` (j=0 is the last emitted token whose KV has not
     landed yet, j>=1 are draft proposals); `valid_len` [B] int32 is the
-    per-lane count of real tokens (<= K1; 0 for padding lanes — the K/V of
-    slots at or past it scatter to the null block so a short draft can
-    never clobber a neighbouring block through index clamping);
+    per-lane count of real tokens (<= K1; 0 for padding lanes);
     block_tables [B, W] int32 as in `decode_step_paged`. Each layer
-    scatters all K1 tokens' K/V first, then attends causally (query j sees
+    writes all K1 tokens' K/V first, then attends causally (query j sees
     history 0..positions[b]+j), so logits[b, j] is EXACTLY what a
     sequential `decode_step_paged` would produce after accepting drafts
     0..j-1 — the greedy accept rule (longest matching draft prefix + one
     corrective/bonus token) therefore reproduces non-speculative greedy
     decode token-for-token. Returns (logits [B, K1, V] f32, kv).
     """
-    if cfg.mlp_type == "moe":
-        raise NotImplementedError("paged decode does not support MoE yet")
-    B, K1 = tokens.shape
-    W = block_tables.shape[1]
-    BS = kv["k"].shape[3]
-    M = W * BS
-    H, Dh = cfg.n_heads, cfg.d_head
-    scale = 1.0 / math.sqrt(cfg.d_head)
-    pos = positions[:, None] + jnp.arange(K1)[None, :]          # [B, K1]
-    x = params["tok_embed"][tokens].astype(cfg.dtype)           # [B, K1, E]
-    if cfg.pos == "learned":
-        x = x + params["pos_embed"][pos].astype(cfg.dtype)
-    rope_tables = None
-    if cfg.pos == "rotary":
-        rd = min(cfg.rotary_dim, cfg.d_head)
-        rope_tables = rope_frequencies(rd, cfg.max_seq, dtype=jnp.float32)
-    valid = jnp.arange(K1)[None, :] < valid_len[:, None]        # [B, K1]
-    phys = jnp.where(
-        valid,
-        jnp.take_along_axis(
-            block_tables, jnp.minimum(pos // BS, W - 1), axis=1
-        ),
-        0,
+    rel = jnp.arange(tokens.shape[1])[None, :]
+    pos = positions[:, None] + rel                              # [B, K1]
+    x, kv = _paged_layers(
+        params, tokens, pos, rel < valid_len[:, None], block_tables, kv, cfg
     )
-    off = pos % BS
-    cols = jnp.arange(M)
-    layer_stack = {k: params[k] for k in _LAYER_KEYS if k in params}
-
-    def scan_body(x, inp):
-        layer_params, kk, vv = inp  # kk/vv: [NB, H, BS, Dh]
-        p = jax.tree_util.tree_map(lambda a: a.astype(cfg.dtype), layer_params)
-        h = _norm(x, p["ln1_w"], p["ln1_b"], cfg.norm)
-        qkv = jnp.einsum("bse,ethd->btshd", h, p["w_qkv"]) + p["b_qkv"][:, None]
-        q, k, v = (
-            qkv[:, i].transpose(0, 2, 1, 3).reshape(B, H, K1, Dh)
-            for i in range(3)
-        )
-        if cfg.pos == "rotary":
-            cos, sin = rope_tables
-            rd = min(cfg.rotary_dim, Dh)
-            c = cos[pos][:, None]                               # [B, 1, K1, rd/2]
-            s = sin[pos][:, None]
-            if rd < Dh:
-                q = jnp.concatenate(
-                    [_rope_rotate(q[..., :rd], c, s), q[..., rd:]], -1
-                )
-                k = jnp.concatenate(
-                    [_rope_rotate(k[..., :rd], c, s), k[..., rd:]], -1
-                )
-            else:
-                q, k = _rope_rotate(q, c, s), _rope_rotate(k, c, s)
-        # Scatter every lane's K1 tokens to their (block, offset) slots,
-        # then gather each lane's table history — the drafts' own keys come
-        # back through the same path, so query j attends drafts 0..j.
-        kk = kk.at[phys, :, off].set(k.transpose(0, 2, 1, 3).astype(kk.dtype))
-        vv = vv.at[phys, :, off].set(v.transpose(0, 2, 1, 3).astype(vv.dtype))
-        gk = kk[block_tables].transpose(0, 2, 1, 3, 4).reshape(B, H, M, Dh)
-        gv = vv[block_tables].transpose(0, 2, 1, 3, 4).reshape(B, H, M, Dh)
-        scores = jnp.einsum(
-            "bhsd,bhtd->bhst", q, gk, preferred_element_type=jnp.float32
-        ) * scale                                               # [B, H, K1, M]
-        scores = jnp.where(
-            cols[None, None, None, :] <= pos[:, None, :, None], scores, -1e30
-        )
-        probs = jax.nn.softmax(scores, axis=-1)
-        attn = jnp.einsum("bhst,bhtd->bhsd", probs.astype(gv.dtype), gv)
-        attn_out = jnp.einsum("bhsd,hde->bse", attn, p["w_o"]) + p["b_o"]
-
-        if cfg.parallel_block:
-            mlp_in = h
-        else:
-            x = x + attn_out
-            mlp_in = _norm(x, p["ln2_w"], p["ln2_b"], cfg.norm)
-        u = jnp.einsum("bse,ef->bsf", mlp_in, p["w_in"]) + p["b_in"]
-        if cfg.activation == "swiglu":
-            g = jnp.einsum("bse,ef->bsf", mlp_in, p["w_gate"])
-            u = jax.nn.silu(g) * u
-        else:
-            u = jax.nn.gelu(u)
-        mlp_out = jnp.einsum("bsf,fe->bse", u, p["w_out"]) + p["b_out"]
-        out = x + attn_out + mlp_out if cfg.parallel_block else x + mlp_out
-        return out, (kk, vv)
-
-    x, (ks, vs) = jax.lax.scan(scan_body, x, (layer_stack, kv["k"], kv["v"]))
-    kv = {"k": ks, "v": vs}
-    x = _norm(x, params["ln_f_w"], params["ln_f_b"], cfg.norm)
-    head = params["tok_embed"].T if cfg.tie_embeddings else params["lm_head"]
-    logits = jnp.einsum("bke,ev->bkv", x, head.astype(cfg.dtype))
-    return logits.astype(jnp.float32), kv
+    return _paged_logits(params, x, cfg), kv
 
 
 def make_generate(cfg: GPTConfig, max_new_tokens: int, temperature: float = 0.0):

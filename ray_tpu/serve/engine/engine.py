@@ -564,13 +564,13 @@ class InferenceEngine:
 
     def _block_blobs(self, blocks: List[int]):
         """The given blocks' KV bytes as contiguous host arrays [2(k/v),
-        L, H, BS, Dh] each — the unit of the host tier and the transfer
+        L, BS, H*Dh] each — the unit of the host tier and the transfer
         plane. Batched: ONE device read per KV array (then per-block host
         copies), not two blocking transfers per block — saves/exports sit
         at the top of the hot step path."""
         np = self._np
         jdx = self._jnp.asarray(blocks)
-        ks = np.asarray(self.kv["k"][:, jdx])   # [L, n, H, BS, Dh]
+        ks = np.asarray(self.kv["k"][:, jdx])   # [L, n, BS, H*Dh]
         vs = np.asarray(self.kv["v"][:, jdx])
         return [
             np.ascontiguousarray(np.stack([ks[:, i], vs[:, i]]))
@@ -619,12 +619,14 @@ class InferenceEngine:
 
     def _kv_sig(self) -> str:
         """Layout signature guarding imports: block bytes only interchange
-        between engines with identical model geometry, block size, and
-        dtype."""
+        between engines with identical model geometry, block size, dtype
+        and block layout ("rows": a block is [BS, H*Dh], one row a token —
+        a blob of the older head-major blocks has the same bytes in
+        another order, so it must not be adopted)."""
         c = self.cfg
         return (
             f"{c.n_layers}:{c.n_heads}:{c.d_head}:{self.opts.block_size}:"
-            f"{self._jnp.dtype(c.dtype).str}"
+            f"{self._jnp.dtype(c.dtype).str}:rows"
         )
 
     def prompt_digests(self, prompt: List[int]) -> List[bytes]:

@@ -4,7 +4,7 @@
 scheduling).
 
 The physical KV cache is a fixed pool of `num_blocks` blocks of
-`block_size` token slots each (the engine owns the actual [L, NB, H, BS, Dh]
+`block_size` token slots each (the engine owns the actual [L, NB, BS, H*Dh]
 arrays; this class owns only the *map*). Each live sequence holds an ordered
 block table — logical token position `p` lives in physical block
 `table[p // block_size]` at offset `p % block_size`.

@@ -82,6 +82,11 @@ def worker_spawn_env(env: Dict[str, str], tpu: bool) -> Dict[str, str]:
     if tpu:
         env["JAX_PLATFORMS"] = "tpu"
         place_compile_cache(env)
+        # JAX keeps only programs that took a second to compile. A paged
+        # serving program of gpt2-large compiles in 0.8-1.0 s, so a replica
+        # recompiled all 53 of its programs at every start (51 s of warm-up
+        # against 23 s from the cache, PERF.md §6 PR 25). Keep them all.
+        env.setdefault("JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS", "0")
     else:
         platforms = env.get("JAX_PLATFORMS", "").lower().split(",")
         if "tpu" in platforms or platforms == [""]:  # e.g. "tpu,cpu" on TPU VMs

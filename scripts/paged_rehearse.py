@@ -1,0 +1,182 @@
+"""Compile-only rehearsal of the three paged programs, without the chip:
+`JAX_PLATFORMS=cpu python3 -m scripts.paged_rehearse --model gpt2-large
+--num-blocks 1024 --block-size 16 [--lanes 8] [--width 16] [--chunk 64]
+[--spec 4] [--n-layers N]` from the root of a checkout.
+
+Compiles `decode_step_paged`, `prefill_paged` and `verify_step_paged` of
+`models/gpt.py` for one described (not attached) `v5e:2x2` device, jitted
+and donated exactly as the engine does (`serve/engine/engine.py:
+_paged_jits`), and prints for each the GiB of arguments, temporaries and
+output, and every operation of the compiled program whose result is at
+least half of one layer's pool (K or V), with its layout. In a healthy
+paged program the pool enters in the layout the device keeps, is the layer
+scan's carry, and only the in-place row update names it (`fusion(scatter)`
+or `dynamic-update-slice` with the pool's own shape, aliased to the
+argument); the `convert`s are the per-step bf16 copy of the weights, and at
+many lanes x blocks the gathered history itself grows past the threshold.
+A pool-sized `copy` between two different layouts is a relayout the device
+pays in every layer of every step (PERF.md §6, PR 25).
+
+Nothing runs: a compile that passes is not a chip run, and no time, rate or
+share comes from here. The last line of stdout is one JSON object."""
+
+from __future__ import annotations
+
+import argparse
+import json
+import os
+import re
+import sys
+
+os.environ.setdefault("TPU_LOG_DIR", "disabled")
+
+_DTYPE_BYTES = {"pred": 1, "s8": 1, "u8": 1, "bf16": 2, "f16": 2, "s16": 2,
+                "u16": 2, "f32": 4, "s32": 4, "u32": 4, "f64": 8, "s64": 8,
+                "u64": 8}
+# `%name = bf16[36,1024,16,1280]{3,2,1,0:T(8,128)(2,1)} opcode(...)`; a tuple
+# result (`(bf16[..], ..)`) is walked shape by shape.
+_INSTR = re.compile(r"^\s*(?:ROOT\s+)?%?([\w.\-]+)\s*=\s*(.+?)\s+([\w\-]+)\(")
+_SHAPE = re.compile(r"(\w+)\[([\d,]*)\](\{[^}]*\})?")
+
+
+_PASSES_ALONG = ("parameter", "tuple", "get-tuple-element", "bitcast", "while")
+
+
+def big_ops(hlo_text: str, min_bytes: int):
+    """[(opcode, name, shape-with-layout, bytes)] of every instruction of the
+    compiled program with a result array of at least `min_bytes`. A fusion
+    is one operation, named `fusion(<opcode of its root>)`; what only passes
+    arrays along (parameters, tuples, the loop itself) is left out."""
+    roots, inside, out = {}, None, []
+    lines = hlo_text.splitlines()
+    for line in lines:           # first pass: each fused computation's root
+        if line.startswith("%fused_computation"):
+            inside = line.split()[0].lstrip("%")
+        elif line.startswith("}"):
+            inside = None
+        elif inside and line.lstrip().startswith("ROOT"):
+            m = _INSTR.match(line)
+            roots[inside] = m.group(3) if m else "?"
+    inside = None
+    for line in lines:
+        if line.startswith("%fused_computation"):
+            inside = True
+        elif line.startswith("}"):
+            inside = None
+        m = None if inside else _INSTR.match(line)
+        if not m or m.group(3) in _PASSES_ALONG:
+            continue
+        name, result, opcode = m.groups()
+        if opcode == "fusion":
+            called = re.search(r"calls=%([\w.\-]+)", line)
+            opcode = f"fusion({roots.get(called.group(1), '?') if called else '?'})"
+        for dtype, dims, layout in _SHAPE.findall(result):
+            n = _DTYPE_BYTES.get(dtype, 0)
+            for d in filter(None, dims.split(",")):
+                n *= int(d)
+            if n >= min_bytes:
+                out.append((opcode, name, f"{dtype}[{dims}]{layout}", n))
+    return out
+
+
+def rehearse(cfg, device, num_blocks: int, block_size: int, lanes: int = 8,
+             width: int = 16, chunk: int = 64, spec: int = 4) -> dict:
+    """Compile the three paged programs of `cfg` for `device` (a described
+    device of `jax.experimental.topologies`) at one shape bucket each."""
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import SingleDeviceSharding
+
+    from ray_tpu.models.gpt import init_paged_cache, init_params
+    from ray_tpu.serve.engine.engine import _paged_jits
+
+    one_chip = SingleDeviceSharding(device)
+
+    def on_chip(tree):
+        return jax.tree_util.tree_map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+            tree)
+
+    params = on_chip(jax.eval_shape(
+        lambda k: init_params(k, cfg), jax.random.PRNGKey(0)))
+    kv = on_chip(jax.eval_shape(
+        lambda: init_paged_cache(cfg, num_blocks, block_size)))
+
+    def i32(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one_chip)
+
+    prefill, decode, verify = _paged_jits()
+    programs = {
+        "decode_step_paged": lambda: decode.lower(
+            params, i32(lanes), i32(lanes), i32(lanes, width), kv, cfg),
+        "prefill_paged": lambda: prefill.lower(
+            params, i32(1, chunk), i32(), i32(), i32(width), kv, cfg),
+        "verify_step_paged": lambda: verify.lower(
+            params, i32(lanes, spec + 1), i32(lanes), i32(lanes),
+            i32(lanes, width), kv, cfg),
+    }
+    layer_pool = kv["k"].size // cfg.n_layers * kv["k"].dtype.itemsize
+    report = {
+        "n_layers": cfg.n_layers,
+        "pool_shape": list(kv["k"].shape), "pool_dtype": str(kv["k"].dtype),
+        "pool_GiB": 2 * cfg.n_layers * layer_pool / 2**30,
+        "layer_pool_MiB": layer_pool / 2**20,
+        "lanes": lanes, "width": width, "chunk": chunk, "spec": spec,
+        "programs": {},
+    }
+    for name, lower in programs.items():
+        try:
+            compiled = lower().compile()
+        except Exception as e:  # noqa: BLE001 — a refusal is the answer
+            report["programs"][name] = {"refused": str(e).splitlines()[0][:300]}
+            continue
+        mem = compiled.memory_analysis()
+        ops = big_ops(compiled.as_text(), layer_pool // 2)
+        report["programs"][name] = {
+            "arguments_GiB": mem.argument_size_in_bytes / 2**30,
+            "temp_GiB": mem.temp_size_in_bytes / 2**30,
+            "output_GiB": mem.output_size_in_bytes / 2**30,
+            "alias_GiB": mem.alias_size_in_bytes / 2**30,
+            "pool_sized_ops": [
+                {"op": op, "name": n, "result": shape, "MiB": b / 2**20}
+                for op, n, shape, b in ops
+            ],
+        }
+        print(f"{name}: args {mem.argument_size_in_bytes / 2**30:.2f} GiB, "
+              f"temp {mem.temp_size_in_bytes / 2**30:.2f}, "
+              f"output {mem.output_size_in_bytes / 2**30:.2f}, "
+              f"aliased {mem.alias_size_in_bytes / 2**30:.2f}; "
+              f"{len(ops)} operation(s) of >= {layer_pool / 2**21:.1f} MiB",
+              flush=True)
+        for op, n, shape, b in ops:
+            print(f"    {op:28s} {shape}  {b / 2**20:.1f} MiB  %{n}", flush=True)
+    return report
+
+
+def main() -> int:
+    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    ap.add_argument("--model", default="gpt2-large")
+    ap.add_argument("--num-blocks", type=int, default=1024)
+    ap.add_argument("--block-size", type=int, default=16)
+    ap.add_argument("--lanes", type=int, default=8)
+    ap.add_argument("--width", type=int, default=16, help="blocks in a table")
+    ap.add_argument("--chunk", type=int, default=64, help="prefill chunk tokens")
+    ap.add_argument("--spec", type=int, default=4, help="draft tokens verified")
+    ap.add_argument("--n-layers", type=int, default=None,
+                    help="cut the preset's depth (a 6 B model on one chip)")
+    a = ap.parse_args()
+    from jax.experimental import topologies
+
+    from ray_tpu.models.gpt import CONFIGS
+
+    overrides = {} if a.n_layers is None else {"n_layers": a.n_layers}
+    cfg = CONFIGS[a.model](**overrides, remat=False, remat_policy=None)
+    topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    report = rehearse(cfg, topo.devices[0], a.num_blocks, a.block_size,
+                      a.lanes, a.width, a.chunk, a.spec)
+    print(json.dumps({"model": a.model, **report}))
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())

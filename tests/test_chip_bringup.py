@@ -57,6 +57,12 @@ def test_spawn_env_pins_the_platform():
     env = tpu.worker_spawn_env({"JAX_PLATFORMS": "cpu"}, tpu=True)
     assert env["JAX_PLATFORMS"] == "tpu" and env["RAY_TPU_WORKER_TPU"] == "1"
     assert env[tpu.COMPILE_CACHE_ENV] == os.path.join(REPO, ".jax_cache")
+    # every program is kept, not only those that took a second to compile;
+    # a threshold given from outside is left alone
+    assert env["JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS"] == "0"
+    given = {"JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS": "2.5"}
+    assert tpu.worker_spawn_env(given, tpu=True)[
+        "JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS"] == "2.5"
     for inherited in ({}, {"JAX_PLATFORMS": "tpu"}, {"JAX_PLATFORMS": "tpu,cpu"}):
         env = tpu.worker_spawn_env(dict(inherited), tpu=False)
         assert env["JAX_PLATFORMS"] == "cpu" and env["RAY_TPU_WORKER_TPU"] == "0"

@@ -334,6 +334,10 @@ class TestDisaggEngineParity:
         first = list(pre.stream(rid))
         desc = pre.export_prompt_kv(prompt)
         assert desc is not None and len(desc["digests"]) == len(prompt) // 4
+        # One block on the wire: [k/v, L, BS, H*Dh], a token's row whole.
+        assert tuple(desc["shape"]) == (
+            2, cfg.n_layers, 4, cfg.n_heads * cfg.d_head
+        )
         pre.shutdown()
 
         dec = _make_engine(cfg, params, role="decode")
@@ -422,21 +426,34 @@ class TestDisaggEngineParity:
         dec.shutdown()
         assert first + rest == ref
 
-    def test_import_rejects_mismatched_layout(self, tiny_engine_parts):
+    @pytest.mark.parametrize("mismatch", ["block_size", "block_layout"])
+    def test_import_rejects_mismatched_layout(self, tiny_engine_parts, mismatch):
+        """A descriptor from an engine with another block size, or from one
+        that kept its blocks head-major (the signature before PR 25:
+        the same bytes in another order), is refused whole; the importer
+        recomputes the prompt and answers as if nothing had been offered."""
         cfg, params = tiny_engine_parts
         pre = _make_engine(cfg, params, block_size=4)
         pre.start()
         prompt = list(range(1, 18))
-        list(pre.stream(pre.submit(prompt, 1)))
+        first = list(pre.stream(pre.submit(prompt, 1)))
+        ref = first + pre.generate(prompt + first, 5)
         desc = pre.export_prompt_kv(prompt)
         pre.shutdown()
-        assert desc is not None
-        other = _make_engine(cfg, params, block_size=8)
+        assert desc is not None and desc["sig"].endswith(":rows")
+        if mismatch == "block_layout":
+            desc = {**desc, "sig": desc["sig"][: -len(":rows")]}
+            other = _make_engine(cfg, params, block_size=4)
+        else:
+            other = _make_engine(cfg, params, block_size=8)
         other.start()
         assert other.import_blocks(desc) == 0, (
             "imported KV across incompatible block layouts"
         )
+        rest = other.generate(prompt + first, 5)
+        st = other.stats()
         other.shutdown()
+        assert first + rest == ref and st["blocks_imported"] == 0
 
     def test_host_tier_round_trip_through_engine(self, tiny_engine_parts):
         """A pool too small to retain a prefix evicts it to the host tier;
@@ -456,6 +473,8 @@ class TestDisaggEngineParity:
         e.start()
         out1 = e.generate(p1, 6)
         e.generate(p2, 6)              # evicts p1's blocks -> tier saves
+        saved = next(iter(e.host_tier._blobs.values()))
+        assert saved.shape == (2, cfg.n_layers, 4, cfg.n_heads * cfg.d_head)
         out1b = e.generate(p1, 6)      # re-admission: tier consult
         st = e.stats()
         e.shutdown()

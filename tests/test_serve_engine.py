@@ -722,6 +722,332 @@ class TestEngineDecode:
         assert out.finish_reason == "eos"
 
 
+# ------------------------------------------------ the paged programs alone
+# Three kinds of model through the same three functions (`models/gpt.py`
+# `_paged_layers`), each held to the dense `prefill` / `decode_step` logits:
+# learned positions; full rotary; and GPT-J's parallel block with
+# rotary_dim < d_head. Two have H*Dh = 48, not a multiple of the 128 lanes
+# the pool's rows are laid out for: they must stay correct, not fast.
+PAGED_PRESETS = {
+    "learned-48": dict(pos="learned", norm="layernorm", activation="gelu"),
+    "rotary-48": {},
+    "gptj-128": dict(
+        d_model=64, n_heads=4, d_head=32, d_mlp=128, rotary_dim=8,
+        parallel_block=True, tie_embeddings=False, norm="layernorm",
+        activation="gelu",
+    ),
+}
+_BS, _NB = 4, 32
+
+
+def _paged_case(name):
+    """(cfg, params, tokens [40], dense logits): dense[i] is the dense
+    path's next-token logits after tokens[: i + 1], for i >= 8."""
+    import jax
+    import jax.numpy as jnp
+
+    from ray_tpu.models.gpt import (
+        decode_step, init_cache, init_params, prefill,
+    )
+
+    cfg = _tiny_cfg(**PAGED_PRESETS[name])
+    params = jax.tree_util.tree_map(
+        lambda a: a * 3.0, init_params(jax.random.PRNGKey(5), cfg)
+    )
+    tokens = jax.random.randint(jax.random.PRNGKey(11), (40,), 0, 64)
+    logits, cache = jax.jit(prefill, static_argnums=2)(
+        params, tokens[None, :9], cfg, init_cache(cfg, 1, 64)
+    )
+    dense = {8: logits[0]}
+    step = jax.jit(decode_step, static_argnums=3)
+    for i in range(9, 40):
+        logits, cache = step(params, tokens[i][None], cache, cfg)
+        dense[i] = logits[0]
+    return cfg, params, tokens, dense
+
+
+@pytest.fixture(scope="module", params=list(PAGED_PRESETS))
+def paged_case(request):
+    return _paged_case(request.param)
+
+
+def _paged(cfg):
+    """The engine's own jitted (and pool-donating) programs, and a pool."""
+    from ray_tpu.models.gpt import init_paged_cache
+    from ray_tpu.serve.engine.engine import _paged_jits
+
+    return (*_paged_jits(), init_paged_cache(cfg, _NB, _BS))
+
+
+def _prefill_chunks(prefill, params, cfg, tokens, table, kv, start, chunks):
+    """Prefill tokens[start:] into `table` in the given chunk lengths, each
+    right-padded to a bucket of 16; returns (last chunk's logits, kv)."""
+    import jax.numpy as jnp
+
+    pos = start
+    for n in chunks:
+        padded = jnp.zeros((1, 16), jnp.int32).at[0, :n].set(tokens[pos:pos + n])
+        logits, kv = prefill(
+            params, padded, jnp.int32(n), jnp.int32(pos), table, kv, cfg
+        )
+        pos += n
+    return logits, kv
+
+
+def _close(a, b):
+    import numpy as np
+
+    np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=2e-4, atol=2e-4)
+
+
+class TestPagedPrograms:
+    # Scattered, unordered physical blocks: a table is a map, not a range.
+    TABLE_A = [7, 3, 21, 12, 5, 30, 9, 18]
+    TABLE_B = [7, 3, 21, 14, 2, 25, 11, 6]     # shares A's first three blocks
+    TABLE_C = [4, 13, 22, 31, 1, 10, 19, 28]   # shares none
+
+    def test_pool_is_rows_of_all_heads(self, paged_case):
+        from ray_tpu.models.gpt import init_paged_cache
+
+        cfg = paged_case[0]
+        kv = init_paged_cache(cfg, _NB, _BS)
+        shape = (cfg.n_layers, _NB, _BS, cfg.n_heads * cfg.d_head)
+        assert kv["k"].shape == kv["v"].shape == shape
+        assert kv["k"].dtype == cfg.dtype
+
+    def test_chunked_prefill_matches_dense(self, paged_case):
+        """A 23-token prompt in chunks of 9, 7, 7 (non-zero `pos_offset`,
+        padded buckets, chunks that straddle blocks), then a second
+        sequence that shares the first three blocks as a cached prefix and
+        prefills only from position 12: both give the dense logits."""
+        import jax.numpy as jnp
+        import numpy as np
+
+        cfg, params, tokens, dense = paged_case
+        prefill, _, _, kv = _paged(cfg)
+        table = jnp.asarray(self.TABLE_A, jnp.int32)
+        logits, kv = _prefill_chunks(
+            prefill, params, cfg, tokens, table, kv, 0, (9, 7, 7)
+        )
+        _close(logits, dense[22])
+        before = np.array(kv["k"])
+        logits, kv = _prefill_chunks(
+            prefill, params, cfg, tokens, jnp.asarray(self.TABLE_B, jnp.int32),
+            kv, 12, (4, 7),
+        )
+        _close(logits, dense[22])
+        after = np.asarray(kv["k"])
+        shared = self.TABLE_B[:3]
+        np.testing.assert_array_equal(after[:, shared], before[:, shared])
+
+    def test_decode_lanes_match_dense_and_padding_writes_block_0(
+        self, paged_case
+    ):
+        """Two sequences at unrelated positions and two padded lanes in one
+        bucket of four: each real lane's logits are the dense path's, step
+        after step, and the pool changes only in the two rows written and
+        in the null block."""
+        import jax.numpy as jnp
+        import numpy as np
+
+        cfg, params, tokens, dense = paged_case
+        prefill, decode, _, kv = _paged(cfg)
+        ta = jnp.asarray(self.TABLE_A, jnp.int32)
+        tb = jnp.asarray(self.TABLE_C, jnp.int32)
+        _, kv = _prefill_chunks(prefill, params, cfg, tokens, ta, kv, 0, (16, 7))
+        _, kv = _prefill_chunks(prefill, params, cfg, tokens, tb, kv, 0, (10,))
+        tables = jnp.stack([ta, jnp.zeros_like(ta), tb, jnp.zeros_like(ta)])
+        for step in range(3):
+            pa, pb = 23 + step, 10 + step
+            before = {n: np.array(a) for n, a in kv.items()}
+            logits, kv = decode(
+                params, jnp.asarray([tokens[pa], 0, tokens[pb], 0]),
+                jnp.asarray([pa, 0, pb, 0], jnp.int32), tables, kv, cfg,
+            )
+            _close(logits[0], dense[pa])
+            _close(logits[2], dense[pb])
+            for name, arr in kv.items():
+                changed = np.argwhere(
+                    (np.asarray(arr) != before[name]).any(axis=(0, 3))
+                )
+                written = {(int(ta[pa // _BS]), pa % _BS),
+                           (int(tb[pb // _BS]), pb % _BS)}
+                assert {(b, o) for b, o in changed.tolist() if b} == written
+                assert all(o == 0 for b, o in changed.tolist() if b == 0)
+
+    def test_verify_matches_sequential_decode(self, paged_case):
+        """Three tokens a lane in one forward: logits[b, j] are the dense
+        path's after tokens 0..pos+j; a lane with a shorter `valid_len`
+        and a padded lane write nothing outside their own rows and block
+        0."""
+        import jax.numpy as jnp
+        import numpy as np
+
+        cfg, params, tokens, dense = paged_case
+        prefill, _, verify, kv = _paged(cfg)
+        ta = jnp.asarray(self.TABLE_A, jnp.int32)
+        tb = jnp.asarray(self.TABLE_C, jnp.int32)
+        _, kv = _prefill_chunks(prefill, params, cfg, tokens, ta, kv, 0, (14,))
+        _, kv = _prefill_chunks(prefill, params, cfg, tokens, tb, kv, 0, (11,))
+        before = np.array(kv["v"])
+        logits, kv = verify(
+            params,
+            jnp.stack([tokens[14:17], tokens[11:14], jnp.zeros(3, jnp.int32),
+                       jnp.zeros(3, jnp.int32)]),
+            jnp.asarray([14, 11, 0, 0], jnp.int32),
+            jnp.asarray([3, 2, 0, 0], jnp.int32),
+            jnp.stack([ta, tb, jnp.zeros_like(ta), jnp.zeros_like(ta)]),
+            kv, cfg,
+        )
+        for j in range(3):
+            _close(logits[0, j], dense[14 + j])
+        for j in range(2):
+            _close(logits[1, j], dense[11 + j])
+        changed = np.argwhere((np.asarray(kv["v"]) != before).any(axis=(0, 3)))
+        written = {(int(ta[p // _BS]), p % _BS) for p in (14, 15, 16)} | {
+            (int(tb[p // _BS]), p % _BS) for p in (11, 12)}
+        assert {(b, o) for b, o in changed.tolist() if b} == written
+
+    @pytest.mark.parametrize("program", ["prefill", "decode", "verify"])
+    def test_pool_is_the_layer_scans_carry(self, program):
+        """Structure: the layer scan carries the two pool arrays and has no
+        xs or ys of the pool's size — as xs -> ys the device rewrote the
+        whole pool in every program (PERF.md §6, PR 25)."""
+        import jax
+        import jax.numpy as jnp
+
+        from ray_tpu.models import gpt
+
+        cfg = _tiny_cfg()
+        params = jax.eval_shape(
+            lambda k: gpt.init_params(k, cfg), jax.random.PRNGKey(0))
+        kv = jax.eval_shape(lambda: gpt.init_paged_cache(cfg, 64, _BS))
+        i32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32)  # noqa: E731
+        fn, args = {
+            "prefill": (gpt.prefill_paged,
+                        (params, i32(1, 16), i32(), i32(), i32(8), kv)),
+            "decode": (gpt.decode_step_paged,
+                       (params, i32(4), i32(4), i32(4, 8), kv)),
+            "verify": (gpt.verify_step_paged,
+                       (params, i32(4, 3), i32(4), i32(4), i32(4, 8), kv)),
+        }[program]
+        jaxpr = jax.make_jaxpr(lambda *a: fn(*a, cfg))(*args)
+        scans = [e for e in jaxpr.jaxpr.eqns if e.primitive.name == "scan"]
+        assert len(scans) == 1, "one layer scan"
+        (scan,) = scans
+        nc, ncar = scan.params["num_consts"], scan.params["num_carry"]
+        pool = kv["k"].shape
+        carry = [v.aval.shape for v in scan.invars[nc:nc + ncar]]
+        assert carry.count(pool) == 2, f"pool not carried: {carry}"
+        assert [v.aval.shape for v in scan.outvars[:ncar]].count(pool) == 2
+        streamed = scan.invars[nc + ncar:] + scan.outvars[ncar:]
+        assert all(v.aval.size < kv["k"].size for v in streamed), (
+            "a pool-sized array rides the scan as xs or ys"
+        )
+
+    def test_cow_copies_every_layers_rows(self, tiny_engine_parts):
+        """Copy-on-write of a forked partial block on the physical pool:
+        the fresh block gets the source's rows in every layer, K and V,
+        and no other block changes."""
+        import numpy as np
+
+        cfg, params = tiny_engine_parts
+        eng = _make_engine(cfg, params)
+        toks = [9, 8, 7, 6, 5, 4]
+        rid = eng.submit(toks, max_new_tokens=1)
+        out = eng.stream(rid)
+        _drive(eng)
+        list(out)
+        bm = eng.block_manager
+        with eng._lock:
+            bm.allocate_cached("parent", toks, 6)
+            bm.register_computed("parent", toks, 6)
+            src = bm.block_table("parent")[1]
+            bm.fork("parent", "child")
+            dst = bm.grow("child", 7)[1]
+        assert dst != src
+        rng = np.random.default_rng(0)
+        eng.kv = {
+            n: a.at[:, src].set(rng.standard_normal(a.shape[0:1] + a.shape[2:]))
+            for n, a in eng.kv.items()
+        }
+        before = {n: np.asarray(a) for n, a in eng.kv.items()}
+        eng._apply_cow()
+        for n, a in eng.kv.items():
+            a = np.asarray(a)
+            np.testing.assert_array_equal(a[:, dst], before[n][:, src])
+            assert np.abs(a[:, dst]).sum() > 0
+            others = [b for b in range(a.shape[1]) if b != dst]
+            np.testing.assert_array_equal(a[:, others], before[n][:, others])
+
+
+@pytest.fixture(scope="module")
+def v5e_chip():
+    """One described (not attached) v5e device; only ever asked for inside a
+    test of this file, so collection is the same in every xdist worker."""
+    try:
+        from jax.experimental import topologies
+
+        topo = _within(60, lambda: topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2"))
+    except Exception as e:  # noqa: BLE001 — no TPU compiler here: skip
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return topo.devices[0]
+
+
+def _within(seconds, fn):
+    """fn() on a daemon thread, with this test's own time limit: a compile
+    that hangs fails here and does not hold the whole run."""
+    res = {}
+
+    def run():
+        try:
+            res["value"] = fn()
+        except BaseException as e:  # noqa: BLE001 — re-raised below
+            res["error"] = e
+
+    t = threading.Thread(target=run, daemon=True)
+    t.start()
+    t.join(seconds)
+    if t.is_alive():
+        raise TimeoutError(f"not done after {seconds} s")
+    if "error" in res:
+        raise res["error"]
+    return res["value"]
+
+
+def test_paged_programs_compile_for_v5e_without_a_pool_copy(v5e_chip):
+    """Compile-only, for the chip's own compiler: at H*Dh = 256 (two lane
+    tiles) the device keeps the pool row-major, so each of the three
+    programs updates it in place: temporaries smaller than the pool and no
+    pool-sized copy or transpose. With the pool [.., BS, Dh] as the scan's
+    xs -> ys this read 1.5 x the pool and four relayout copies a layer."""
+    import jax.numpy as jnp
+
+    from ray_tpu.models.gpt import GPTConfig
+    from scripts.paged_rehearse import rehearse
+
+    cfg = GPTConfig(
+        vocab_size=256, n_layers=2, d_model=64, n_heads=4, d_head=64,
+        d_mlp=256, max_seq=256, attn_impl="ref", remat=False,
+        dtype=jnp.bfloat16,
+    )
+    report = _within(240, lambda: rehearse(
+        cfg, v5e_chip, 64, 16, lanes=4, width=4, chunk=16, spec=2))
+    pool_bytes = report["pool_GiB"] * 2**30
+    assert set(report["programs"]) == {
+        "decode_step_paged", "prefill_paged", "verify_step_paged"}
+    for name, prog in report["programs"].items():
+        assert "refused" not in prog, (name, prog)
+        assert prog["temp_GiB"] * 2**30 < pool_bytes, (name, prog)
+        assert prog["alias_GiB"] * 2**30 >= pool_bytes, f"{name}: not donated"
+        # every stacked weight of this preset is smaller than a layer's pool
+        moved = [o for o in prog["pool_sized_ops"]
+                 if o["MiB"] >= report["layer_pool_MiB"]
+                 and ("copy" in o["op"] or "transpose" in o["op"])]
+        assert not moved, (name, moved)
+
+
 # ------------------------------------------------- serve data-plane wiring
 @pytest.fixture
 def serve_instance():
