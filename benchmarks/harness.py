@@ -6,6 +6,7 @@ the runtime grants it to."""
 from __future__ import annotations
 
 import glob
+import importlib
 import json
 import os
 import signal
@@ -17,6 +18,10 @@ HERE = os.path.dirname(os.path.abspath(__file__))
 ROOT = os.path.dirname(HERE)
 OUT = os.path.join(ROOT, ".bench_out")          # listed in .gitignore
 _RUN_TAG = "BENCH_PROCESS_TAG"
+# What a configuration's architecture module exports (`benchmarks/arch/`).
+ARCH_INTERFACE = ("dims", "program", "make_logits", "make_loss",
+                  "train_flops_per_token", "weight_bytes", "kv_block_bytes",
+                  "kernel_costs")
 
 
 def load_json(*parts: str) -> dict:
@@ -26,6 +31,17 @@ def load_json(*parts: str) -> dict:
 
 def benchmark() -> dict:
     return load_json(ROOT, "BENCHMARK.json")
+
+
+def arch(name: str):
+    """The architecture module a configuration names: `benchmarks.arch.<name>`,
+    or the module of a dotted name as it stands. A module that lacks a name
+    of the interface is refused here, before any chip is asked for."""
+    mod = importlib.import_module(name if "." in name else f"benchmarks.arch.{name}")
+    missing = [n for n in ARCH_INTERFACE if not callable(getattr(mod, n, None))]
+    if missing:
+        raise SystemExit(f"architecture module {mod.__name__} lacks {missing}")
+    return mod
 
 
 def load_cell(name: str) -> dict:
@@ -41,6 +57,7 @@ def load_cell(name: str) -> dict:
     if mix["kind"] not in config["runners"]:
         raise SystemExit(
             f"{name}: {entry['file']} has no runner part for traffic kind {mix['kind']!r}")
+    arch(config["arch"])
     return {"cell": cell, "config": config, "traffic": mix, "bench": bench}
 
 
@@ -48,26 +65,6 @@ def cell_metrics(bench: dict, cell_name: str, section: str) -> list:
     """Names of the `section` metrics this cell reports."""
     return [m["name"] for m in bench[section]
             if "workloads" not in m or cell_name in m["workloads"]]
-
-
-def model_dims(config: dict, rehearse: bool) -> dict:
-    """The published keys of a GPT-2/GPT-J `config.json` as the sizes the
-    program's `GPTConfig` and the benchmark's arithmetic use."""
-    c = dict(config)
-    if rehearse:
-        c.update(config["rehearsal"]["sizes"])
-    pad = config["assumed"]["vocab_pad_multiple"]
-    E, H = c["n_embd"], c["n_head"]
-    return {
-        "n_layers": c["n_layer"], "d_model": E, "n_heads": H,
-        "d_head": E // H, "d_mlp": c.get("n_inner") or 4 * E,
-        "max_seq": c["n_positions"],
-        "vocab_size": -(-c["vocab_size"] // pad) * pad,
-        "pos": "rotary" if c.get("rotary_dim") else "learned",
-        "rotary_dim": c.get("rotary_dim") or 64,
-        "parallel_block": bool(config["assumed"].get("parallel_block", False)),
-        "tie_embeddings": bool(c.get("tie_word_embeddings", True)),
-    }
 
 
 def key_seed(seed: int) -> int:

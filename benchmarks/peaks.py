@@ -1,5 +1,7 @@
-"""The table of peaks and the operation/byte arithmetic, kept with the
-benchmark so that no later PR can move the yardstick.
+"""The table of peaks and the cost of the kernels that architectures share,
+kept with the benchmark so that no later PR can move the yardstick. What a
+model needs (parameters, FLOPs a token, bytes a step, which kernels with what
+shapes) is its architecture module's, `benchmarks/arch/`.
 
 Peaks of one chip, keyed by JAX's `device_kind`. Source: Google Cloud
 documentation, "TPU v5e" (197 TFLOP/s bf16, 819 GB/s HBM). An unknown
@@ -23,33 +25,6 @@ def peak(device_kind: str) -> dict:
         ) from None
 
 
-# ------------------------------------------------------------------ model
-def n_matmul_params(m: dict) -> int:
-    """Parameters that take part in a matrix multiplication per token: the
-    layers' four projections and the output head (tied or not). Embedding
-    look-ups and norms cost no matmul FLOPs."""
-    E, L, F, V = m["d_model"], m["n_layers"], m["d_mlp"], m["vocab_size"]
-    Hd = m["n_heads"] * m["d_head"]
-    per_layer = E * 3 * Hd + Hd * E + E * F + F * E
-    return L * per_layer + E * V
-
-
-def train_flops_per_token(m: dict, seq: int) -> float:
-    """FLOPs the forward and backward passes REQUIRE per trained token:
-    6 per matmul parameter, plus causal attention (QK^T and PV, forward 1x +
-    backward 2x, half the square). Recomputed operations are not counted."""
-    attn = 6.0 * m["n_layers"] * m["n_heads"] * m["d_head"] * seq  # 12*S*Hd/2
-    return 6.0 * n_matmul_params(m) + attn
-
-
-def weight_bytes(m: dict, bytes_per_param: int = 4) -> int:
-    """Bytes of weights a decode step streams (f32 masters as the engine
-    holds them; tied head counted once)."""
-    E, V = m["d_model"], m["vocab_size"]
-    n = n_matmul_params(m) + (0 if m["tie_embeddings"] else E * V)
-    return n * bytes_per_param
-
-
 # ---------------------------------------------------------------- kernels
 # One call of a flash kernel on q,k,v of [BH, S, Dh] (bf16), causal.
 # Matmul FLOPs over the causal half (+ the diagonal blocks are counted as
@@ -70,13 +45,6 @@ def flash_bwd_dkv_cost(bh: int, seq: int, dh: int) -> dict:
     flops = 4 * 2 * bh * seq * seq * dh / 2          # QK^T, dP, dV=P^T dO, dK=dS^T Q
     bytes_ = 2 * bh * seq * dh * 6 + 8 * bh * seq    # q,k,v,do in, dk,dv out
     return {"flops": flops, "bytes": bytes_}
-
-
-KERNEL_COSTS = {
-    "flash_fwd": flash_fwd_cost,
-    "flash_bwd_dq": flash_bwd_dq_cost,
-    "flash_bwd_dkv": flash_bwd_dkv_cost,
-}
 
 
 def roofline_seconds(cost: dict, device_kind: str) -> tuple:

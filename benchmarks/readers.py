@@ -4,9 +4,14 @@ unit, layer and `moves` live in `BENCHMARK.json` alone; the file
 below with its parameters. A metric `<reader>.<suffix>` uses the file of
 `<reader>`, so one reader serves a quantity that is split by cell because
 its cells report different end-to-end metrics (`itl_p90_ms.sat`). A new
-metric over a span, counter, series or trace event that a runner already
-records is a new file, not new code. A reader that finds nothing to read
-returns None and the metric is left out of the line."""
+metric over a span, counter, series or trace event that the program records
+is a new file, not new code: the runners keep every `engine.*` / `serve.*`
+span of the window with its args (`obs["spans"]`), every integer of
+`engine_stats()` as the window's delta (`obs["counters"]`) and every scalar
+the train step reports as a series. What depends on the model is asked of
+the configuration's architecture module (`obs["facts"]["arch"]`). A reader
+that finds nothing to read returns None and the metric is left out of the
+line."""
 
 from __future__ import annotations
 
@@ -14,6 +19,10 @@ import statistics
 from typing import Optional
 
 from . import harness, peaks, stats
+
+
+def _arch(obs):
+    return harness.arch(obs["facts"]["arch"])
 
 
 def _series(obs, r):
@@ -53,6 +62,57 @@ def counter_ratio(obs, r):
     return None if num is None or not den else r.get("scale", 1.0) * num / den
 
 
+def _window_spans(obs, r):
+    """The spans of one name that start inside the window, or up to
+    `after_window_s` after it (a request due at its very end); with `where`,
+    those whose arg of that name is not 0."""
+    w = obs.get("window")
+    if not w:
+        return []
+    end = w["t0"] + w["seconds"] + r.get("after_window_s", 0.0)
+    return [ev for ev in obs.get("spans") or ()
+            if ev["name"] == r["span"] and w["t0"] <= ev["ts"] <= end
+            and ("where" not in r or (ev.get("args") or {}).get(r["where"]))]
+
+
+def _span_value(ev, arg):
+    return ev["dur"] if arg == "dur" else (ev.get("args") or {}).get(arg, 0)
+
+
+def span_percentile(obs, r):
+    """Percentile `q` of a span's duration, or of one of its args (`arg`)."""
+    xs = [_span_value(ev, r.get("arg", "dur")) for ev in _window_spans(obs, r)]
+    return None if not xs else stats.percentile(xs, r["q"]) * r.get("scale", 1.0)
+
+
+def span_sum(obs, r):
+    """A span's arg (`dur`: its duration) summed over the window, over
+    `"over"`: the window's `seconds`, the span `count`, or another arg's
+    sum (`{"arg": name}`)."""
+    evs = _window_spans(obs, r)
+    if not evs:
+        return None
+    over = r["over"]
+    den = (obs["window"]["seconds"] if over == "seconds" else len(evs) if over == "count"
+           else sum(_span_value(ev, over["arg"]) for ev in evs))
+    total = sum(_span_value(ev, r["arg"]) for ev in evs)
+    return None if not den else r.get("scale", 1.0) * total / den
+
+
+def span_time_mean(obs, r):
+    """Mean over the window's TIME of a gauge the span carries (`arg`): each
+    record's value holds from its span's end to the next one's, the first
+    one's from the window's start."""
+    evs = sorted(_window_spans(obs, r), key=lambda ev: ev["ts"])
+    if not evs:
+        return None
+    w = obs["window"]
+    edges = [w["t0"]] + [ev["ts"] + ev["dur"] for ev in evs[1:]] + [w["t0"] + w["seconds"]]
+    held = sum(_span_value(ev, r["arg"]) * max(0.0, b - a)
+               for ev, a, b in zip(evs, edges, edges[1:]))
+    return r.get("scale", 1.0) * held / w["seconds"]
+
+
 def whole_step_rate(obs, r):
     xs, f = _series(obs, {"series": "step_end_s"}), obs["facts"]
     if not xs:
@@ -84,7 +144,7 @@ def mfu(obs, r):
     if rate is None:
         return None
     f = obs["facts"]
-    need = peaks.train_flops_per_token(f["model"], f["seq"]) * rate
+    need = _arch(obs).train_flops_per_token(f["model"], f["seq"]) * rate
     return 100.0 * need / peaks.peak(obs["device"]["kind"])["bf16_flops"]
 
 
@@ -131,27 +191,29 @@ def kernel_roofline(obs, r):
     calls = sum(t["op_count"][n] for n in names)
     if not took or not calls:
         return None
-    cost = peaks.KERNEL_COSTS[k](f["flash_bh_per_device"], f["seq"], f["model"]["d_head"])
+    cost = _arch(obs).kernel_costs(f["model"], f["batch"], f["seq"], f["chips"])[k]
     least, _bound = peaks.roofline_seconds(cost, obs["device"]["kind"])
     return 100.0 * least * calls / took
 
 
 def hbm_roofline(obs, r):
     """(weights + live KV) / peak HBM bandwidth over the decode program's
-    median device time."""
+    median device time. Live KV is the pool times the live share of it, the
+    gauge `live` names on a span, as its mean over the window's time."""
     ms = trace_module_ms(obs, r)
-    c = obs.get("counters") or {}
-    if ms is None or c.get("kv_util_mean") is None:
+    util = span_time_mean(obs, r["live"])
+    if ms is None or util is None:
         return None
     f = obs["facts"]
-    live = c["kv_util_mean"] * f["kv_pool_bytes"]
-    least = (peaks.weight_bytes(f["model"]) + live) / peaks.peak(obs["device"]["kind"])["hbm_bytes_s"]
+    weights = _arch(obs).weight_bytes(f["model"])
+    least = (weights + util * f["kv_pool_bytes"]) / peaks.peak(obs["device"]["kind"])["hbm_bytes_s"]
     return 100.0 * least / (ms * 1e-3)
 
 
 KINDS = {f.__name__: f for f in (
     phase, series_percentile, series_mean, series_share_above, counter,
-    counter_ratio, whole_step_rate, group_median_rate, token_rate, mfu, trace_idle_share,
+    counter_ratio, span_percentile, span_sum, span_time_mean, whole_step_rate,
+    group_median_rate, token_rate, mfu, trace_idle_share,
     trace_module_ms, trace_op_share, trace_collective_exposed_share,
     kernel_roofline, hbm_roofline,
 )}

@@ -1,6 +1,7 @@
 """Compile-only rehearsal for a train cell across chips, without the chip:
 `JAX_PLATFORMS=cpu python3 -m benchmarks.rehearse_compile gptj-6b.train-fsdp4
-[n_layer,batch_per_chip ...]`.
+[depth,batch_per_chip ...]`, where depth is a value for the first key the
+configuration lists under `reduced` (`n_layer` there).
 
 Compiles the cell's real train step for `v5e:2x2` as described (not attached)
 devices, with the cell's mesh, shardings and optimizer, and prints the bytes
@@ -16,7 +17,7 @@ import sys
 os.environ.setdefault("TPU_LOG_DIR", "disabled")
 
 
-def compile_step(cell_name: str, n_layer=None, batch_per_chip=None) -> dict:
+def compile_step(cell_name: str, depth=None, batch_per_chip=None) -> dict:
     import jax
     import jax.numpy as jnp
     import optax
@@ -36,15 +37,19 @@ def compile_step(cell_name: str, n_layer=None, batch_per_chip=None) -> dict:
     loaded = harness.load_cell(cell_name)
     config, mix, cell = loaded["config"], loaded["traffic"], loaded["cell"]
     part = config["runners"]["train"]
-    if n_layer is not None:
-        config = {**config, "n_layer": n_layer}
+    if depth is not None:
+        if not config["reduced"]:
+            raise SystemExit(f"{cell_name}: its configuration reduces no key to vary")
+        config = {**config, config["reduced"][0]: depth}
     bpc = batch_per_chip or mix["batch_per_chip"]
-    m = harness.model_dims(config, False)
+    arch = harness.arch(config["arch"])
+    m = arch.dims(config, False)
+    model, overrides = arch.program(config, m)
     topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
     devices = list(topo.devices)[: cell["chips"]]
     mesh = make_mesh(devices, **part["mesh"])
-    cfg = CONFIGS[config["program_model"]](
-        **{**m, "max_seq": mix["seq"]}, attn_impl=part["attn_impl"], remat=True,
+    cfg = CONFIGS[model](
+        **{**overrides, "max_seq": mix["seq"]}, attn_impl=part["attn_impl"], remat=True,
         remat_policy=part["remat_policy"])
     shardings = param_shardings(cfg, mesh)
     shapes = jax.eval_shape(lambda k: init_params(k, cfg), jax.random.PRNGKey(0))
@@ -72,7 +77,8 @@ def compile_step(cell_name: str, n_layer=None, batch_per_chip=None) -> dict:
     mem = compiled.memory_analysis()
     hlo = compiled.as_text()
     return {
-        "n_layer": m["n_layers"], "global_batch": batch, "seq": mix["seq"],
+        **{k: config[k] for k in config["reduced"][:1]},
+        "global_batch": batch, "seq": mix["seq"],
         "params_B": sum(v.size for v in shapes.values()) / 1e9,
         "per_device_GiB": {
             "arguments": mem.argument_size_in_bytes / 2**30,
@@ -89,11 +95,11 @@ def compile_step(cell_name: str, n_layer=None, batch_per_chip=None) -> dict:
 def main() -> int:
     cell = sys.argv[1]
     tries = [tuple(int(x) for x in a.split(",")) for a in sys.argv[2:]] or [(None, None)]
-    for n_layer, bpc in tries:
+    for depth, bpc in tries:
         try:
-            print(compile_step(cell, n_layer, bpc), flush=True)
+            print(compile_step(cell, depth, bpc), flush=True)
         except Exception as e:  # noqa: BLE001 — a refusal is the answer
-            print({"n_layer": n_layer, "batch_per_chip": bpc,
+            print({"depth": depth, "batch_per_chip": bpc,
                    "refused": str(e).splitlines()[0][:300]}, flush=True)
     return 0
 

@@ -7,9 +7,11 @@ tokens at once, so time to first token cannot be seen through it).
 From the program the runner takes public entry points only: the replica's
 constructor, `generate`, `generate_stream` and `engine_stats`, the engine's
 `submit` and `stream`, the `EngineOptions` fields, and the spans the program
-records itself (`engine.step` of the flight recorder, `engine.queue_wait` of
-the tracing plane, both read from `ray_tpu.timeline()`). Warm-up and the
-correctness check are real requests. The replica is `LLMDeployment`'s own
+records itself (every `engine.*` and `serve.*` span of the window with its
+args, read once from `ray_tpu.timeline()` after `flight.flush()`). Sizes, the
+program model, the reference and the bytes come from the configuration's
+architecture module (`benchmarks/arch/`). Warm-up and the correctness check
+are real requests. The replica is `LLMDeployment`'s own
 class with probe methods added (`bench_*`) for what only the process that
 holds the chip can do: weights made under one `jax.jit`, JAX's compile
 events, the device's memory peak, the profiler. The one private name left
@@ -22,11 +24,10 @@ import dataclasses
 import math
 import os
 import shutil
-import statistics
 import threading
 import time
 
-from .. import harness, stats, traffic
+from .. import harness, readers, stats, traffic
 from ray_tpu.serve.engine.deployment import LLMDeployment, _LLMReplica
 
 
@@ -55,10 +56,11 @@ class BenchReplica(_LLMReplica):
             self._compiles += 1
 
     # ------------------------------------------------------------- set-up
-    def bench_check_tokens(self, dims, seed, prompt_len, new_tokens):
+    def bench_check_tokens(self, arch, dims, seed, prompt_len, new_tokens):
         """A seeded prompt through `generate` (chunked prefill, then decode
-        through the paged cache), greedy; against the plain reference's full
-        forward pass over the prompt and the tokens that came back. With
+        through the paged cache), greedy; against the full forward pass of the
+        plain reference of the architecture module `arch` over the prompt and
+        the tokens that came back. With
         random weights the largest logit changes on rounding, so the tokens
         are not compared with the reference's own choice: each is held to the
         reference's logits, and the error is how far below the reference's
@@ -67,8 +69,6 @@ class BenchReplica(_LLMReplica):
         import jax.numpy as jnp
         import numpy as np
 
-        from benchmarks import reference
-
         t = time.perf_counter()
         rng = np.random.default_rng([seed, 1])    # not the window's own stream
         prompt = rng.integers(1, dims["vocab_size"], prompt_len).tolist()
@@ -76,7 +76,8 @@ class BenchReplica(_LLMReplica):
         if len(got) != new_tokens:
             raise RuntimeError(f"check request: asked {new_tokens} tokens, got {got}")
         full = jnp.asarray(prompt + got[:-1], jnp.int32)
-        want = np.asarray(reference.make_logits(dims)(self._params, full))[prompt_len - 1:]
+        logits = harness.arch(arch).make_logits(dims)
+        want = np.asarray(logits(self._params, full))[prompt_len - 1:]
         chosen = want[np.arange(new_tokens), got]
         err = float((want.max(-1) - chosen).max() / np.abs(want).max())
         self._phases["reference_s"] = time.perf_counter() - t
@@ -108,8 +109,10 @@ class BenchReplica(_LLMReplica):
 
     # ------------------------------------------------------------- window
     def _totals(self):
-        s = self.engine_stats()
-        return {"compiles": self._compiles, "engine_tokens": s["total_tokens"],
+        """Every integer `engine_stats()` gives, under its own name, with
+        the names the checks and older metric files read."""
+        s = {k: v for k, v in self.engine_stats().items() if type(v) is int}
+        return {**s, "compiles": self._compiles, "engine_tokens": s["total_tokens"],
                 "engine_finished": s["total_finished"],
                 "preemptions": s["total_preemptions"],
                 "prefix_hits": s["prefix_cache_hits"]}
@@ -220,55 +223,21 @@ class _Client:
                 "errors": errors[:3]}
 
 
-def _spans(ray, name, t_from, t_to):
-    """The program's own spans of one name inside the window, from the
-    controller's timeline."""
+def _spans(ray, t_from, t_to):
+    """Every `engine.*` and `serve.*` span the program recorded that starts
+    between the two instants, with its args, from the controller's timeline
+    (which keeps its newest 10,000 events)."""
+    from ray_tpu.util import flight
+
+    flight.flush()                  # this process's `serve.handle` spans
+    time.sleep(1.0)                 # the workers' span flush
     try:
         events = ray.timeline()
-    except Exception:  # noqa: BLE001 — a reader with nothing to read
+    except Exception:  # noqa: BLE001 — readers with nothing to read
         return []
     return [ev for ev in events
-            if ev.get("event") == "span" and ev.get("name") == name
-            and t_from <= ev.get("ts", 0) <= t_to]
-
-
-def _step_counters(steps: list) -> dict:
-    """Counters from the `engine.step` spans the flight recorder keeps: one
-    per step that did work, with its decode lanes and prefill chunks."""
-    if not steps:
-        return {}
-    lanes = [(ev.get("args") or {}).get("decodes", 0) for ev in steps]
-    return {"engine_steps": len(steps),
-            "engine_step_s_sum": sum(ev["dur"] for ev in steps),
-            "decode_steps": sum(1 for x in lanes if x > 0),
-            "decode_lanes_sum": sum(lanes),
-            "prefill_chunks": sum((ev.get("args") or {}).get("prefills", 0) for ev in steps)}
-
-
-class _Poll(threading.Thread):
-    """`engine_stats` once a second (traced runs and sweeps only): KV
-    occupancy and queue depth, which no span carries."""
-
-    def __init__(self, call):
-        super().__init__(daemon=True)
-        self.call, self.rows, self.stop = call, [], threading.Event()
-
-    def run(self):
-        while not self.stop.wait(1.0):
-            try:
-                s = self.call("engine_stats", timeout_s=10)
-            except Exception:  # noqa: BLE001 — a sample less
-                continue
-            self.rows.append((s["kv_utilization"], s["queue_depth"], s["running"]))
-
-    def counters(self) -> dict:
-        self.stop.set()
-        self.join(15)
-        if not self.rows:
-            return {}
-        return {"kv_util_mean": statistics.fmean(r[0] for r in self.rows),
-                "queue_depth_max": max(r[1] for r in self.rows),
-                "queue_depth_last": self.rows[-1][1], "running_last": self.rows[-1][2]}
+            if ev.get("event") == "span" and t_from <= ev.get("ts", 0) <= t_to
+            and ev.get("name", "").startswith(("engine.", "serve."))]
 
 
 def run(ctx: dict) -> dict:
@@ -285,9 +254,9 @@ def run(ctx: dict) -> dict:
         part["engine_options"] = pre.pop("engine_options")
         part["token_check"] = pre.pop("token_check")
         mix = {**mix, **pre}
-    dims = harness.model_dims(config, rehearse)
-    overrides = {k: dims[k] for k in ("n_layers", "d_model", "n_heads", "d_head",
-                                      "d_mlp", "max_seq", "vocab_size")}
+    arch = harness.arch(config["arch"])
+    dims = arch.dims(config, rehearse)
+    model, overrides = arch.program(config, dims)
     opts = dataclasses.asdict(EngineOptions(**part["engine_options"]))
     actor_options = dict(part["ray_actor_options"])
     if not rehearse:
@@ -301,7 +270,7 @@ def run(ctx: dict) -> dict:
     handle = serve.run(
         bench_llm.options(ray_actor_options=actor_options,
                           replica_startup_timeout_s=900).bind(
-            model=config["program_model"], model_overrides=overrides,
+            model=model, model_overrides=overrides,
             engine_options=part["engine_options"],
             seed=harness.key_seed(ctx["seed"]), t0_wall=ctx["t0_wall"]),
         name="bench", route_prefix="/bench", timeout_s=900)
@@ -311,7 +280,7 @@ def run(ctx: dict) -> dict:
     if not rehearse and info["device"]["platform"] != "tpu":
         raise RuntimeError(f"replica is on {info['device']}, not the TPU")
     check = part["token_check"]
-    token_err, token_agree = call("bench_check_tokens", dims, ctx["seed"],
+    token_err, token_agree = call("bench_check_tokens", config["arch"], dims, ctx["seed"],
                                   check["prompt_len"], check["new_tokens"])
     rates = ctx.get("sweep") or [mix["arrivals"]["rate_rps"]]
     top = {**mix, "arrivals": {**mix["arrivals"], "rate_rps": max(rates)}}
@@ -345,10 +314,8 @@ def run(ctx: dict) -> dict:
             tracer = threading.Thread(target=traced, daemon=True)
         w0 = call("bench_window_start")
         phases["setup_s"] = time.time() - ctx["t0_wall"]
-        poll = _Poll(call) if probes else None
-        for th in (tracer, poll):
-            if th:
-                th.start()
+        if tracer:
+            tracer.start()
         client.run(seconds, drain_s=60.0 if ctx.get("sweep") else 0.0,
                    first_token_grace_s=mix.get("first_token_grace_s", 0.0))
         verdict = client.verdict()      # before the engine's count, so that
@@ -357,15 +324,12 @@ def run(ctx: dict) -> dict:
         if tracer:
             tracer.join(300)
         series = client.series(seconds)
-        if probes:
-            counters.update(poll.counters())
-            time.sleep(1.0)             # the workers' span flush
-            counters.update(_step_counters(
-                _spans(ray_tpu, "engine.step", w0, w0 + seconds)))
-            series["queue_wait_ms"] = [
-                1e3 * ev["dur"]
-                for ev in _spans(ray_tpu, "engine.queue_wait", w0, w0 + seconds + 1.0)]
+        window = {"t0": w0, "seconds": seconds}
+        # a second past the window: the spans of a request due at its end
+        spans = _spans(ray_tpu, w0, w0 + seconds + 1.0) if probes else []
         if ctx.get("sweep"):
+            seen = {"window": window, "spans": spans}
+            step = {"span": "engine.step", "over": "count"}
             done_by = lambda f: sum(1 for r in client.rec if r["done"] and r["stamps"][-1] <= f * seconds)
             sent_by = lambda f: sum(1 for r in client.rec if r["due"] <= f * seconds)
             fin = [x for x in series["ttft_ms"] if math.isfinite(x)]
@@ -376,14 +340,16 @@ def run(ctx: dict) -> dict:
                 "tok_s": len(series["token_t_s"]) / seconds,
                 "ttft_p50_ms": stats.percentile(fin, 50) if fin else None,
                 "itl_p90_ms": stats.percentile(series["itl_ms"], 90) if series["itl_ms"] else None,
-                "lanes_mean": counters.get("decode_lanes_sum", 0) / max(1, counters.get("decode_steps", 0)),
-                "engine_step_ms": 1e3 * counters.get("engine_step_s_sum", 0.0) / max(1, counters.get("engine_steps", 0)),
-                "queue_depth_max": counters.get("queue_depth_max"),
+                "lanes_mean": readers.span_sum(
+                    seen, {**step, "arg": "decodes", "where": "decodes"}),
+                "engine_step_ms": readers.span_sum(seen, {**step, "arg": "dur", "scale": 1e3}),
+                "queue_depth_max": readers.span_percentile(
+                    seen, {**step, "arg": "queue_depth", "q": 100}),
                 "failed": verdict["failed"], "phases": dict(phases),
             })
     client_tokens = len(series["token_t_s"])
     sent = sum(1 for r in client.rec if r["sent"] is not None)
-    block_bytes = 2 * dims["n_layers"] * dims["n_heads"] * dims["d_head"] * opts["block_size"] * 2
+    block_bytes = arch.kv_block_bytes(dims, opts["block_size"])
     checks = {
         "token_err": token_err, "token_argmax_agree": [token_agree, check["new_tokens"]],
         "tokens_match_reference": token_err <= part["token_tolerance"],
@@ -402,7 +368,9 @@ def run(ctx: dict) -> dict:
     peak = counters.pop("memory_peak_bytes")
     return {
         "phases": phases, "series": series, "counters": counters,
-        "facts": {"model": dims, "kv_pool_bytes": opts["num_blocks"] * block_bytes,
+        "spans": spans, "window": window,
+        "facts": {"arch": config["arch"], "model": dims,
+                  "kv_pool_bytes": opts["num_blocks"] * block_bytes,
                   "engine_options": opts},
         "trace": reduced[0], "checks": checks, "sweep": sweep,
         "attempted": sent, "failed": verdict["failed"],

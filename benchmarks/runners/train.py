@@ -27,7 +27,7 @@ def _train_loop(config):
     )
     from ray_tpu.train.jax_trainer import jax_utils
 
-    from benchmarks import reference, trace as trace_mod
+    from benchmarks import trace as trace_mod
     from benchmarks.traffic import TokenBatches
 
     wall0 = config["t0_wall"]
@@ -40,11 +40,13 @@ def _train_loop(config):
     if config["chips"] and len(devices) != config["chips"]:
         raise RuntimeError(f"granted {config['chips']} chips, JAX sees {len(devices)}")
 
+    arch = harness.arch(config["arch"])
     m, mix, part = config["dims"], config["traffic"], config["part"]
     seq, batch = mix["seq"], mix["batch_per_chip"] * len(devices)
     mesh = jax_utils.get_mesh(**part["mesh"])
-    cfg = CONFIGS[config["program_model"]](
-        **{**m, "max_seq": seq}, attn_impl=part["attn_impl"], remat=True,
+    model, overrides = config["program"]
+    cfg = CONFIGS[model](
+        **{**overrides, "max_seq": seq}, attn_impl=part["attn_impl"], remat=True,
         remat_policy=part["remat_policy"],
     )
     shardings = param_shardings(cfg, mesh)
@@ -63,7 +65,7 @@ def _train_loop(config):
 
     # The plain reference on the whole first batch, one sequence a device.
     t = time.perf_counter()
-    ref = jax.jit(jax.vmap(reference.make_loss(m), in_axes=(None, 0)))
+    ref = jax.jit(jax.vmap(arch.make_loss(m), in_axes=(None, 0)))
     n_dev = len(devices)
     ref_sum = 0.0
     for i in range(0, batch, n_dev):
@@ -90,7 +92,7 @@ def _train_loop(config):
     hlo = step.as_text()
     mosaic = {
         k: sum(1 for ln in hlo.splitlines() if "tpu_custom_call" in ln and k in ln)
-        for k in ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv")
+        for k in arch.kernel_costs(m, batch, seq, len(devices))
     }
     mem = step.memory_analysis()
     del hlo
@@ -108,7 +110,7 @@ def _train_loop(config):
     tr = mix["trace"]
     trace_dir = config["trace_dir"]
     annotate = jax.profiler.TraceAnnotation
-    step_ends, report_s, losses = [], [], []
+    step_ends, report_s, losses, reported = [], [], [], []
     traced = None
     starve0 = ingest.starve_s
     phases["setup_s"] = time.time() - wall0
@@ -130,6 +132,7 @@ def _train_loop(config):
         end = time.perf_counter()
         step_ends.append(end - t_start)
         losses.append(loss)
+        reported.append(metrics)                 # read after the window
         with annotate("bench.report"):
             train.report({"step": i, "loss": loss})
         report_s.append(time.perf_counter() - end)
@@ -162,14 +165,14 @@ def _train_loop(config):
     peak = max(((d.memory_stats() or {}).get("peak_bytes_in_use") or 0) for d in devices)
     train.report({"final": True, "obs": {
         "phases": phases,
-        "series": {"step_end_s": step_ends, "report_s": report_s,
+        "series": {**{k: [float(r[k]) for r in reported] for k in reported[0]},
+                   "step_end_s": step_ends, "report_s": report_s,
                    "step_s": [b - a for a, b in zip([0.0] + step_ends, step_ends)]},
         "counters": {"starve_s": starve_s, "window_s": window_s,
                      "steps": len(step_ends)},
         "facts": {
             "tokens_per_step": batch * seq, "seq": seq, "chips": len(devices),
-            "group_steps": g, "model": m,
-            "flash_bh_per_device": batch * m["n_heads"] // len(devices),
+            "group_steps": g, "arch": config["arch"], "model": m, "batch": batch,
             "compiled_bytes": {"arguments": mem.argument_size_in_bytes,
                                "temp": mem.temp_size_in_bytes} if mem else None,
         },
@@ -192,13 +195,15 @@ def run(ctx: dict) -> dict:
     if ctx["rehearse"]:
         mix = {**mix, **config["rehearsal"]["train"]}
     name = cell["name"]
+    arch = harness.arch(config["arch"])
+    dims = arch.dims(config, ctx["rehearse"])
     result = JaxTrainer(
         _train_loop,
         train_loop_config=dict(
             t0_wall=ctx["t0_wall"], seed=ctx["seed"], seconds=ctx["seconds"],
             trace=ctx["trace"], rehearse=ctx["rehearse"], chips=chips,
-            dims=harness.model_dims(config, ctx["rehearse"]),
-            program_model=config["program_model"], traffic=mix, part=part,
+            arch=config["arch"], dims=dims, program=arch.program(config, dims),
+            traffic=mix, part=part,
             trace_dir=os.path.join(harness.OUT, "trace", name),
         ),
         scaling_config=ScalingConfig(

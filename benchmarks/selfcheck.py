@@ -6,8 +6,10 @@ Holds the trace reduction to a small trace recorded on a TPU v5e
 series with the window's edge moved by half a step (it must not move) and
 with one slow step (it must move by the stall's share of the time, while the
 steady per-layer rate beside it does not), the percentile, lateness and token-rate arithmetic
-to known answers, every name and unit of `BENCHMARK.json` to the allowed
-characters, and every cell to its files.
+to known answers, the span readers to hand-made spans, the GPT family's
+architecture module to known sizes and costs, every name and unit of
+`BENCHMARK.json` to the allowed characters, and every cell, configuration and
+metric to its files.
 
 `--record <path>` is how the recorded trace was made: it runs on the chip,
 in this process, a few steps of a small jitted program between annotated
@@ -135,10 +137,6 @@ def check_arithmetic() -> None:
     obs = {"series": {"late_ms": [0.1, 0.2, 5.0], "ttft_ms": [10.0, 20.0, math.inf]}}
     assert readers.series_percentile(obs, {"series": "ttft_ms", "q": 50}) == 20.0
     assert readers.series_percentile(obs, {"series": "late_ms", "q": 100}) == 5.0
-    m = {"n_layers": 36, "d_model": 1280, "n_heads": 20, "d_head": 64, "d_mlp": 5120,
-         "vocab_size": 50304, "tie_embeddings": True}
-    f = peaks.train_flops_per_token(m, 1024)
-    assert abs(f / 4.95e9 - 1) < 0.02, f                   # 6 x 708M + attention
     assert peaks.roofline_seconds(peaks.flash_fwd_cost(260, 1024, 64), "TPU v5 lite")[1] == "compute"
     try:
         peaks.peak("TPU v9")
@@ -166,6 +164,81 @@ def check_arithmetic() -> None:
     assert used <= warmed, used - warmed
 
 
+def check_spans() -> None:
+    """The span readers on hand-made spans: a known percentile, a known sum
+    over seconds, count and another arg, the window's edges, and an absent
+    span, which gives None so that the metric is left out."""
+    step = lambda ts, dur, **args: {"name": "engine.step", "ts": ts, "dur": dur, "args": args}
+    obs = {"window": {"t0": 100.0, "seconds": 10.0}, "spans": [
+        step(99.0, 0.5, decodes=9, waited_ns=10 ** 9, kv_util=0.9),      # before the window
+        step(100.0, 0.010, decodes=0, prefills=1, waited_ns=2 * 10 ** 9, kv_util=0.0),
+        step(102.0, 0.020, decodes=2, prefills=0, waited_ns=0, kv_util=0.2),
+        step(104.0, 0.030, decodes=4, prefills=1, waited_ns=10 ** 9, kv_util=0.4),
+        step(110.5, 0.040, decodes=8, waited_ns=10 ** 9, kv_util=0.8),   # after it
+        {"name": "engine.queue_wait", "ts": 101.0, "dur": 0.001, "args": {}},
+        {"name": "engine.queue_wait", "ts": 105.0, "dur": 0.003, "args": {}},
+        {"name": "engine.queue_wait", "ts": 110.5, "dur": 0.005, "args": {}},
+    ]}
+    near = lambda got, want: got is not None and abs(got - want) < 1e-9 * max(1.0, abs(want))
+    steps = {"span": "engine.step"}
+    assert near(readers.span_sum(obs, {**steps, "arg": "dur", "over": "count", "scale": 1e3}), 20.0)
+    assert near(readers.span_sum(obs, {**steps, "arg": "waited_ns", "over": "seconds",
+                                       "scale": 1e-7}), 30.0)            # 3 s of 10: 30%
+    assert near(readers.span_sum(obs, {**steps, "arg": "decodes", "over": "count",
+                                       "where": "decodes"}), 3.0)        # (2 + 4) / 2 steps
+    assert near(readers.span_sum(obs, {**steps, "arg": "decodes", "over": {"arg": "prefills"}}), 3.0)
+    assert near(readers.span_percentile(obs, {**steps, "q": 50, "scale": 1e3}), 20.0)
+    assert near(readers.span_percentile(obs, {**steps, "q": 100, "arg": "decodes"}), 4.0)
+    waits = {"span": "engine.queue_wait", "q": 50, "scale": 1e3}
+    assert near(readers.span_percentile(obs, waits), 2.0)
+    assert near(readers.span_percentile(obs, {**waits, "after_window_s": 1.0}), 3.0)
+    # kv_util held 0.0 until 102.02, 0.2 until 104.03, 0.4 to the window's end
+    want = (0.2 * (104.03 - 102.02) + 0.4 * (110.0 - 104.03)) / 10.0
+    assert near(readers.span_time_mean(obs, {**steps, "arg": "kv_util"}), want)
+    absent = {"span": "engine.nothing", "arg": "dur", "over": "count", "q": 50}
+    assert readers.span_sum(obs, absent) is None and readers.span_percentile(obs, absent) is None
+    assert readers.span_sum({}, {**steps, "arg": "dur", "over": "count"}) is None
+    assert readers.span_sum(obs, {**steps, "arg": "dur", "over": {"arg": "absent"}}) is None
+    # through a metric's own file, as `run.py` reads it; no spans: left out
+    assert near(readers.read("engine_step_ms", obs), 20.0)
+    assert near(readers.read("decode_lanes_mean.sat", obs), 3.0)
+    assert near(readers.read("queue_wait_p50_ms", obs), 3.0)
+    assert near(readers.read("engine_wait_share", obs), 30.0)
+    assert readers.read("prefill_span_p50_ms", obs) is None
+    assert readers.read("engine_step_ms", {"spans": [], "window": obs["window"]}) is None
+
+
+def check_arch() -> None:
+    """The GPT family's module on gpt2-large's own file: its sizes, the
+    program model they select, and the operations and bytes to known answers."""
+    gpt = harness.arch("gpt")
+    config = harness.load_json(harness.HERE, "configs", "gpt2-large.json")
+    m = gpt.dims(config, False)
+    assert m == {"n_layers": 36, "d_model": 1280, "n_heads": 20, "d_head": 64, "d_mlp": 5120,
+                 "max_seq": 1024, "vocab_size": 50304, "pos": "learned", "rotary_dim": 64,
+                 "parallel_block": False, "tie_embeddings": True}, m
+    assert gpt.program(config, m) == ("gpt2-large", m)
+    assert gpt.dims(config, True)["d_model"] == config["rehearsal"]["sizes"]["n_embd"]
+    f = gpt.train_flops_per_token(m, 1024)
+    assert abs(f / 4.95e9 - 1) < 0.02, f                   # 6 x 708M + attention
+    assert gpt.weight_bytes(m) == 4 * 772177920            # tied head counted once
+    assert gpt.weight_bytes({**m, "tie_embeddings": False}) == 4 * (772177920 + 1280 * 50304)
+    assert gpt.kv_block_bytes(m, 16) == 2949120            # the 2.95 MB of the config's notes
+    costs = gpt.kernel_costs(m, 13, 1024, 1)
+    assert costs == {"flash_fwd": peaks.flash_fwd_cost(260, 1024, 64),
+                     "flash_bwd_dq": peaks.flash_bwd_dq_cost(260, 1024, 64),
+                     "flash_bwd_dkv": peaks.flash_bwd_dkv_cost(260, 1024, 64)}
+    fsdp = gpt.dims(harness.load_json(harness.HERE, "configs", "gptj-6b.json"), False)
+    assert (fsdp["d_head"], fsdp["pos"], fsdp["parallel_block"], fsdp["tie_embeddings"]) == (
+        256, "rotary", True, False)
+    assert gpt.kernel_costs(fsdp, 8, 2048, 4)["flash_fwd"] == peaks.flash_fwd_cost(32, 2048, 256)
+    try:
+        harness.arch("no_such_family")
+        raise AssertionError("an unknown architecture must be an error")
+    except ImportError:
+        pass
+
+
 def check_files() -> None:
     bench = harness.benchmark()
     assert set(bench) == {"command", "paths", "run_seconds", "configs", "workloads",
@@ -178,7 +251,9 @@ def check_files() -> None:
         assert m["moves"] in e2e, m
     for c in bench["configs"]:
         assert _NAME.match(c["name"]) and os.path.exists(os.path.join(harness.ROOT, c["file"]))
-        assert harness.load_json(harness.ROOT, c["file"])["reduced"] == c["reduced"]
+        config = harness.load_json(harness.ROOT, c["file"])
+        assert config["reduced"] == c["reduced"]
+        harness.arch(config["arch"])            # resolves, with the whole interface
     for w in bench["workloads"]:
         assert _NAME.match(w["name"]) and _NAME.match(w["traffic"]) and len(w["why"]) <= 200
         loaded = harness.load_cell(w["name"])
@@ -191,11 +266,14 @@ def check_files() -> None:
         assert loaded["traffic"]["kind"] in loaded["config"]["runners"]
 
 
+CHECKS = (check_rate, check_arithmetic, check_spans, check_arch, check_files, check_trace)
+
+
 def main() -> int:
     if len(sys.argv) == 3 and sys.argv[1] == "--record":
         record(sys.argv[2])
         return 0
-    for check in (check_rate, check_arithmetic, check_files, check_trace):
+    for check in CHECKS:
         check()
         print(f"ok  {check.__name__}")
     return 0
