@@ -11,7 +11,9 @@ Design (vs the reference's torch models driven through Train/DeepSpeed —
   * bf16 params/activations, f32 optimizer state & softmax stats.
 
 Flagship configs: `gpt2_*` (LayerNorm/GELU/learned-pos), `gptj_6b`
-(parallel block + rotary), `llama_7b`-style (RMSNorm/SwiGLU/rotary).
+(parallel block + rotary), `llama_7b`-style (RMSNorm/SwiGLU/rotary),
+`smallthinker_21b_a3b` (grouped-query heads, window and global layers,
+dropless top-k experts; served through the paged programs).
 """
 
 from __future__ import annotations
@@ -38,11 +40,24 @@ class GPTConfig:
     d_head: int = 64
     d_mlp: int = 3072
     max_seq: int = 1024
+    # Grouped-query attention: K/V heads, each shared by n_heads/n_kv_heads
+    # query heads. None = n_heads (multi-head, the fused w_qkv layout).
+    n_kv_heads: Optional[int] = None
     # Architecture knobs.
     norm: str = "layernorm"          # layernorm | rmsnorm
-    activation: str = "gelu"         # gelu | swiglu
+    activation: str = "gelu"         # gelu | swiglu | reglu (relu-gated)
     pos: str = "learned"             # learned | rotary
     rotary_dim: int = 64
+    rope_theta: float = 10000.0
+    # Per-layer kinds (None = every layer alike), one entry a layer, as the
+    # published configs give them: rope_layout 1 = rotary, 0 = NO positional
+    # term at all (needs pos="rotary"); sliding_window_layout 1 = the layer
+    # attends to the last `sliding_window` keys (query i sees keys j with
+    # i - window < j <= i), 0 = global. All layers share parameter shapes,
+    # so the kind rides the layer scan as data. JSON lists become tuples.
+    rope_layout: Optional[Tuple[int, ...]] = None
+    sliding_window_layout: Optional[Tuple[int, ...]] = None
+    sliding_window: int = 0
     parallel_block: bool = False     # GPT-J: attn and mlp in parallel
     tie_embeddings: bool = True
     # Mixture-of-Experts (expert parallelism over the ep mesh axis).
@@ -51,8 +66,21 @@ class GPTConfig:
     moe_top_k: int = 2
     moe_capacity_factor: float = 1.25
     moe_aux_weight: float = 0.01
+    # capacity: GShard top-1/top-2 with capacity dropping (training).
+    # dropless: top-k for any k, softmax over the kept logits, no token
+    # dropped, gated experts (ops/moe.py), the router fed from the layer's
+    # own input before the first norm ("router ahead of attention") -- the
+    # routing the paged path serves.
+    moe_routing: str = "capacity"
     # Execution knobs.
     dtype: Any = jnp.bfloat16
+    # The dtype `init_params` makes the tree in. float32: masters for the
+    # optimizer, cast a layer at a time. With bfloat16 that cast is no
+    # operation and a serving program streams 2 bytes a parameter.
+    param_dtype: Any = jnp.float32
+    # How `init_params` scales random weights. "gpt2": std 0.02, residual
+    # projections over sqrt(2L). "unit_stream": see `_UNIT_STREAM_GAINS`.
+    init: str = "gpt2"               # gpt2 | unit_stream
     attn_impl: str = "flash"         # flash | ring | ulysses | ref
     remat: bool = True
     # None (save nothing) | "dots" | "attn" (save flash attention's out+lse
@@ -60,6 +88,40 @@ class GPTConfig:
     # recompute per the r4 profile; +~32 MB/layer at B=12,S=1024).
     remat_policy: Optional[str] = None
     sp_axis: str = "sp"
+
+    def __post_init__(self):
+        for name in ("rope_layout", "sliding_window_layout"):
+            v = getattr(self, name)
+            if v is not None:
+                v = tuple(int(bool(e)) for e in v)
+                if len(v) != self.n_layers:
+                    raise ValueError(
+                        f"{name} has {len(v)} entries for {self.n_layers} layers")
+                object.__setattr__(self, name, v)
+        if self.n_heads % self.kv_heads:
+            raise ValueError(
+                f"n_heads {self.n_heads} not a multiple of n_kv_heads {self.kv_heads}")
+        if self.sliding_window_layout and any(self.sliding_window_layout) \
+                and self.sliding_window < 1:
+            raise ValueError("window layers need sliding_window >= 1")
+        if self.rope_layout is not None and self.pos != "rotary":
+            raise ValueError('rope_layout needs pos="rotary"')
+
+    @property
+    def kv_heads(self) -> int:
+        return self.n_kv_heads or self.n_heads
+
+    @property
+    def layer_kinds(self):
+        """None when every layer is alike, else ([L] rotary flags, [L]
+        windows, 0 = global) as the layouts give them."""
+        if self.rope_layout is None and not (
+                self.sliding_window_layout and any(self.sliding_window_layout)):
+            return None
+        L = self.n_layers
+        rope = self.rope_layout or (int(self.pos == "rotary"),) * L
+        win = self.sliding_window_layout or (0,) * L
+        return rope, tuple(self.sliding_window * w for w in win)
 
     @property
     def moe_config(self):
@@ -79,12 +141,13 @@ class GPTConfig:
     @property
     def n_params(self) -> int:
         E, L, F, V, Hd = self.d_model, self.n_layers, self.d_mlp, self.vocab_size, self.n_heads * self.d_head
+        gated = self.activation in ("swiglu", "reglu")
         if self.mlp_type == "moe":
-            n_mats = 3 if self.activation == "swiglu" else 2
+            n_mats = 3 if gated else 2
             mlp_params = self.moe_experts * n_mats * E * F + E * self.moe_experts
         else:
-            mlp_params = (2 if self.activation == "swiglu" else 1) * E * F + F * E
-        per_layer = E * 3 * Hd + Hd * E + mlp_params
+            mlp_params = (2 if gated else 1) * E * F + F * E
+        per_layer = E * (Hd + 2 * self.kv_heads * self.d_head) + Hd * E + mlp_params
         per_layer += 2 * E  # norms
         total = L * per_layer + V * E + (0 if self.tie_embeddings else E * V)
         if self.pos == "learned":
@@ -156,12 +219,55 @@ def llama_7b(**kw):
     )
 
 
+def smallthinker_21b_a3b(**kw):
+    """SmallThinker-21BA3B-Instruct (huggingface.co/PowerInfer): 28 query
+    heads over 4 K/V heads of 128, one global layer WITHOUT positional
+    encoding then three rotary layers with a window of 4,096, in every
+    layer 64 ReLU-gated experts of 768, top-6 without drops, the router
+    fed from the layer's input; weights held in bfloat16. Serving only
+    (the paged programs and `forward` with attn_impl="ref")."""
+    L = kw.get("n_layers", 52)
+    kinds = tuple(int(l % 4 != 0) for l in range(L))
+    return GPTConfig(
+        **{
+            **dict(
+                n_layers=L,
+                d_model=2560,
+                n_heads=28,
+                n_kv_heads=4,
+                d_head=128,
+                d_mlp=768,
+                vocab_size=151936,
+                max_seq=16384,
+                norm="rmsnorm",
+                activation="reglu",
+                pos="rotary",
+                rotary_dim=128,
+                rope_theta=1500000.0,
+                rope_layout=kinds,
+                sliding_window_layout=kinds,
+                sliding_window=4096,
+                tie_embeddings=False,
+                mlp_type="moe",
+                moe_experts=64,
+                moe_top_k=6,
+                moe_routing="dropless",
+                param_dtype=jnp.bfloat16,
+                init="unit_stream",
+                attn_impl="ref",
+            ),
+            **kw,
+        }
+    )
+
+
 CONFIGS = {
     "gpt2-small": gpt2_small,
     "gpt2-medium": gpt2_medium,
     "gpt2-large": gpt2_large,
     "gptj-6b": gptj_6b,
     "llama-7b": llama_7b,
+    "smallthinker-21b-a3b": smallthinker_21b_a3b,
 }
 
 
@@ -179,18 +285,22 @@ def param_logical_dims(cfg: GPTConfig) -> Dict[str, Tuple[Optional[str], ...]]:
         "ln1_w": ("layers", "embed_act"),
         "ln1_b": ("layers", "embed_act"),
     }
+    if cfg.kv_heads != cfg.n_heads:
+        del dims["w_qkv"], dims["b_qkv"]
+        dims["w_q"] = ("layers", "embed", "heads", "head_dim")
+        dims["w_kv"] = ("layers", "embed", None, "heads", "head_dim")
     if cfg.mlp_type == "moe":
         dims["moe_router"] = ("layers", "embed", "experts")
         dims["moe_w_in"] = ("layers", "experts", "embed", "mlp")
         dims["moe_w_out"] = ("layers", "experts", "mlp", "embed")
-        if cfg.activation == "swiglu":
+        if cfg.activation in ("swiglu", "reglu"):
             dims["moe_w_gate"] = ("layers", "experts", "embed", "mlp")
     else:
         dims["w_in"] = ("layers", "embed", "mlp")
         dims["b_in"] = ("layers", "mlp_act")
         dims["w_out"] = ("layers", "mlp", "embed")
         dims["b_out"] = ("layers", "embed_act")
-        if cfg.activation == "swiglu":
+        if cfg.activation in ("swiglu", "reglu"):
             dims["w_gate"] = ("layers", "embed", "mlp")
     if not cfg.parallel_block:
         dims["ln2_w"] = ("layers", "embed_act")
@@ -202,15 +312,72 @@ def param_logical_dims(cfg: GPTConfig) -> Dict[str, Tuple[Optional[str], ...]]:
     return dims
 
 
+# init="unit_stream": random weights under which greedy tokens depend on every
+# mechanism of the layer, as they do in a trained model. With std 0.02 a deep
+# random stack collapses: near-uniform attention and experts that every
+# position then chooses alike add the same vector at every position, it
+# outgrows the token's own embedding within three layers (97% of the final
+# stream is one vector), and every position decodes one and the same token
+# whatever the window, the positions or the routing did. Here the embedding
+# has std 1.5, so the stream keeps the token's identity (the common vector
+# stays under a sixth of it), and a matrix of fan-in n has std gain / sqrt(n):
+# attention logits of std q * k = 3.6 (a handful of keys carry a query's
+# weight, so which keys a layer may see matters), router logits of the
+# stream's own size (unequal gate weights), and twelve layers that together
+# add somewhat more than the embedding. The benchmark's token check rests on
+# this (`scripts/smallthinker_tolerance.py` reads it on the chip); no
+# program's shape or time depends on the numbers.
+_UNIT_STREAM_GAINS = {"embed": 1.5, "q": 1.9, "k": 1.9, "v": 1.0, "o": 0.9,
+                      "router": 1.0, "expert_in": 1.0, "expert_out": 0.5, "head": 1.0}
+
+
+def _init_unit_stream(rng, cfg: GPTConfig) -> Dict[str, jnp.ndarray]:
+    if cfg.kv_heads == cfg.n_heads or cfg.mlp_type != "moe" \
+            or cfg.activation not in ("swiglu", "reglu") or cfg.parallel_block \
+            or cfg.pos != "rotary" or cfg.tie_embeddings:
+        raise NotImplementedError(
+            'init="unit_stream" covers grouped-query rotary models with gated '
+            "experts and an untied head")
+    E, L, F, V, X = cfg.d_model, cfg.n_layers, cfg.d_mlp, cfg.vocab_size, cfg.moe_experts
+    H, Hkv, Dh = cfg.n_heads, cfg.kv_heads, cfg.d_head
+    k = jax.random.split(rng, 16)
+    dt, g = cfg.param_dtype, _UNIT_STREAM_GAINS
+
+    def n(key, shape, gain, fan_in):
+        return (jax.random.normal(key, shape, jnp.float32)
+                * (gain / math.sqrt(fan_in))).astype(dt)
+
+    ones, zeros = (lambda: jnp.ones((L, E), dt)), (lambda: jnp.zeros((L, E), dt))
+    return {
+        "tok_embed": n(k[0], (V, E), g["embed"], 1),
+        "ln_f_w": jnp.ones((E,), dt), "ln_f_b": jnp.zeros((E,), dt),
+        "w_q": n(k[1], (L, E, H, Dh), g["q"], E),
+        "w_kv": jnp.stack([n(k[9], (L, E, Hkv, Dh), g["k"], E),
+                           n(k[10], (L, E, Hkv, Dh), g["v"], E)], axis=2),
+        "w_o": n(k[2], (L, H, Dh, E), g["o"], H * Dh), "b_o": zeros(),
+        "ln1_w": ones(), "ln1_b": zeros(), "ln2_w": ones(), "ln2_b": zeros(),
+        "moe_router": n(k[3], (L, E, X), g["router"], E),
+        "moe_w_in": n(k[4], (L, X, E, F), g["expert_in"], E),
+        "moe_w_gate": n(k[8], (L, X, E, F), g["expert_in"], E),
+        "moe_w_out": n(k[5], (L, X, F, E), g["expert_out"], F),
+        "lm_head": n(k[7], (E, V), g["head"], E),
+    }
+
+
 def init_params(rng, cfg: GPTConfig) -> Dict[str, jnp.ndarray]:
+    if cfg.init == "unit_stream":
+        return _init_unit_stream(rng, cfg)
+    if cfg.init != "gpt2":
+        raise ValueError(f"init {cfg.init!r}: gpt2 | unit_stream")
     E, L, F, V = cfg.d_model, cfg.n_layers, cfg.d_mlp, cfg.vocab_size
     H, Dh = cfg.n_heads, cfg.d_head
     k = jax.random.split(rng, 16)
     std = 0.02
     resid_std = std / math.sqrt(2 * L)
-    # Master params live in f32 (optimizer precision); forward casts each
-    # layer's weights to cfg.dtype (bf16) as the scan touches it.
-    dt = jnp.float32
+    # Master params live in f32 by default (optimizer precision); forward
+    # casts each layer's weights to cfg.dtype (bf16) as the scan touches it.
+    # A serving preset states bfloat16 and the cast is then no operation.
+    dt = cfg.param_dtype
 
     def n(key, shape, s=std):
         return (jax.random.normal(key, shape, jnp.float32) * s).astype(dt)
@@ -226,19 +393,25 @@ def init_params(rng, cfg: GPTConfig) -> Dict[str, jnp.ndarray]:
         "ln1_w": jnp.ones((L, E), dt),
         "ln1_b": jnp.zeros((L, E), dt),
     }
+    if cfg.kv_heads != H:
+        # Grouped-query layout: Q apart from the (fewer) K/V heads; no
+        # projection biases (the fused multi-head layout keeps its own).
+        del params["w_qkv"], params["b_qkv"]
+        params["w_q"] = n(k[1], (L, E, H, Dh))
+        params["w_kv"] = n(k[9], (L, E, 2, cfg.kv_heads, Dh))
     if cfg.mlp_type == "moe":
         X = cfg.moe_experts
         params["moe_router"] = n(k[3], (L, E, X))
         params["moe_w_in"] = n(k[4], (L, X, E, F))
         params["moe_w_out"] = n(k[5], (L, X, F, E), resid_std)
-        if cfg.activation == "swiglu":
+        if cfg.activation in ("swiglu", "reglu"):
             params["moe_w_gate"] = n(k[8], (L, X, E, F))
     else:
         params["w_in"] = n(k[3], (L, E, F))
         params["b_in"] = jnp.zeros((L, F), dt)
         params["w_out"] = n(k[4], (L, F, E), resid_std)
         params["b_out"] = jnp.zeros((L, E), dt)
-        if cfg.activation == "swiglu":
+        if cfg.activation in ("swiglu", "reglu"):
             params["w_gate"] = n(k[5], (L, E, F))
     if not cfg.parallel_block:
         params["ln2_w"] = jnp.ones((L, E), dt)
@@ -305,29 +478,133 @@ def _attention(cfg: GPTConfig, q, k, v, mesh=None):
     return shard_fn(impl, mesh, in_specs=(spec,) * 3, out_specs=spec)(q, k, v)
 
 
+def _project_qkv(cfg: GPTConfig, p, h):
+    """h [B, S, E] -> q [B, S, H, Dh], k and v [B, S, Hkv, Dh]: the fused
+    multi-head w_qkv, or the grouped-query pair w_q / w_kv."""
+    if "w_qkv" in p:
+        qkv = jnp.einsum("bse,ethd->btshd", h, p["w_qkv"]) + p["b_qkv"][:, None]
+        return qkv[:, 0], qkv[:, 1], qkv[:, 2]
+    q = jnp.einsum("bse,ehd->bshd", h, p["w_q"])
+    kv = jnp.einsum("bse,ethd->btshd", h, p["w_kv"])
+    return q, kv[:, 0], kv[:, 1]
+
+
+def _dense_mlp(cfg: GPTConfig, p, mlp_in):
+    u = jnp.einsum("bse,ef->bsf", mlp_in, p["w_in"]) + p["b_in"]
+    if cfg.activation in ("swiglu", "reglu"):
+        g = jnp.einsum("bse,ef->bsf", mlp_in, p["w_gate"])
+        u = (jax.nn.silu(g) if cfg.activation == "swiglu" else jax.nn.relu(g)) * u
+    else:
+        u = jax.nn.gelu(u)
+    return jnp.einsum("bsf,fe->bse", u, p["w_out"]) + p["b_out"]
+
+
+def _dropless_mlp(cfg: GPTConfig, router, experts, block_in, mlp_in,
+                  layer=None, valid=None):
+    """The dropless expert layer over [B, S, E]: float32 router on the
+    layer's input `block_in`, top-k without capacity, gated experts
+    (ops/moe.py). `experts` = (w_gate, w_in,
+    w_out), this layer's or with `layer` the whole stacks. Returns (y,
+    (experts touched, busiest expert's share)) of this layer's routing."""
+    from ..ops import moe
+
+    B, S, E = mlp_in.shape
+    logits = block_in.reshape(B * S, E).astype(jnp.float32) @ router.astype(jnp.float32)
+    idx, w = moe.dropless_route(logits, cfg.moe_top_k)
+    combine = moe.dropless_combine(idx, w, cfg.moe_experts)
+    # Few tokens cannot reach every expert: read only the chosen ones.
+    few = B * S * cfg.moe_top_k < cfg.moe_experts
+    y = moe.dropless_experts(
+        mlp_in.reshape(B * S, E), combine, *experts, cfg.activation,
+        layer=layer, touched_k=cfg.moe_top_k if few else 0)
+    load = moe.dropless_load(combine, None if valid is None else valid.reshape(B * S))
+    return y.reshape(B, S, E), load
+
+
+def _attention_plain(cfg: GPTConfig, q, k, v, positions, window=None):
+    """Masked attention in XLA operations for what the kernels do not take:
+    K/V heads shared by groups of query heads, and a per-layer window
+    (`window`: a traced scalar, query i sees keys j with i - window < j <=
+    i). q [B, H, S, Dh]; k, v [B, Hkv, S, Dh]; positions [S]."""
+    B, H, S, Dh = q.shape
+    Hkv = k.shape[1]
+    qg = q.reshape(B, Hkv, H // Hkv, S, Dh)
+    scores = jnp.einsum(
+        "bgrsd,bgtd->bgrst", qg, k, preferred_element_type=jnp.float32
+    ) / math.sqrt(Dh)
+    i, j = positions[:, None], positions[None, :]
+    mask = j <= i
+    if window is not None:
+        mask = mask & (j > i - window)
+    probs = jax.nn.softmax(jnp.where(mask, scores, -1e30), axis=-1)
+    out = jnp.einsum("bgrst,bgtd->bgrsd", probs.astype(v.dtype), v)
+    return out.reshape(B, H, S, Dh)
+
+
+_NO_WINDOW = 1 << 30    # a window no sequence reaches: a global layer's
+
+
+def _layer_kind_xs(cfg: GPTConfig):
+    """The per-layer kinds as scan inputs: None, or {"rope" [L] bool,
+    "window" [L] int32 (a global layer's is `_NO_WINDOW`)}."""
+    kinds = cfg.layer_kinds
+    if kinds is None:
+        return None
+    rope, win = kinds
+    return {"rope": jnp.asarray(rope, bool),
+            "window": jnp.asarray([w or _NO_WINDOW for w in win], jnp.int32)}
+
+
+def _refuse_new_fields(cfg: GPTConfig, what: str, moe: bool = True):
+    """The paths that were not generalised say so: grouped-query heads,
+    per-layer kinds and dropless experts run in `forward` (attn_impl="ref")
+    and in the paged programs only."""
+    bad = []
+    if cfg.kv_heads != cfg.n_heads:
+        bad.append("grouped-query heads (n_kv_heads)")
+    if cfg.layer_kinds is not None:
+        bad.append("per-layer kinds (rope_layout / sliding_window_layout)")
+    if moe and cfg.mlp_type == "moe":
+        bad.append("an expert MLP")
+    if bad:
+        raise NotImplementedError(f"{what} does not support " + ", ".join(bad))
+
+
 def _block(cfg: GPTConfig, rope_tables, mesh, x, layer_params, positions,
-           return_kv: bool = False):
+           return_kv: bool = False, kind=None):
     """One transformer block; x: [B, S, E] in cfg.dtype. With return_kv the
     post-RoPE K/V ([B, H, S, Dh]) come back too — the prefill path stores
-    them in the decode cache."""
+    them in the decode cache. `kind`: this layer's entry of
+    `_layer_kind_xs` (traced scalars) for a model with layers of two kinds."""
     # Cast this layer's master weights to compute dtype (bf16 → MXU).
     p = jax.tree_util.tree_map(lambda a: a.astype(cfg.dtype), layer_params)
     B, S, E = x.shape
     H, Dh = cfg.n_heads, cfg.d_head
+    block_in = x
 
     h = _norm(x, p["ln1_w"], p["ln1_b"], cfg.norm)
-    qkv = jnp.einsum("bse,ethd->btshd", h, p["w_qkv"]) + p["b_qkv"][:, None]
-    q, k, v = (qkv[:, i].transpose(0, 2, 1, 3).reshape(B, H, S, Dh) for i in range(3))
-    # qkv[:, i] is [B, S, H, Dh] -> [B, H, S, Dh]
+    q, k, v = (a.transpose(0, 2, 1, 3) for a in _project_qkv(cfg, p, h))
+    # [B, S, heads, Dh] -> [B, heads, S, Dh]
     if cfg.pos == "rotary":
         cos, sin = rope_tables
         rd = min(cfg.rotary_dim, Dh)
         c, s = cos[positions], sin[positions]
-        q = jnp.concatenate([apply_rope(q[..., :rd], c, s, None), q[..., rd:]], -1) \
+        qr = jnp.concatenate([apply_rope(q[..., :rd], c, s, None), q[..., rd:]], -1) \
             if rd < Dh else apply_rope(q, c, s, None)
-        k = jnp.concatenate([apply_rope(k[..., :rd], c, s, None), k[..., rd:]], -1) \
+        kr = jnp.concatenate([apply_rope(k[..., :rd], c, s, None), k[..., rd:]], -1) \
             if rd < Dh else apply_rope(k, c, s, None)
-    attn = _attention(cfg, q, k, v, mesh)  # [B, H, S, Dh]
+        # A layer without positional encoding keeps q and k as projected.
+        q, k = (qr, kr) if kind is None else (
+            jnp.where(kind["rope"], qr, q), jnp.where(kind["rope"], kr, k))
+    if kind is not None or cfg.kv_heads != H:
+        if cfg.attn_impl != "ref":
+            raise NotImplementedError(
+                "grouped-query heads and per-layer windows run attn_impl='ref' "
+                f"in forward (got {cfg.attn_impl!r}); the kernels take neither")
+        attn = _attention_plain(
+            cfg, q, k, v, positions, None if kind is None else kind["window"])
+    else:
+        attn = _attention(cfg, q, k, v, mesh)  # [B, H, S, Dh]
     attn_out = jnp.einsum("bhsd,hde->bse", attn, p["w_o"]) + p["b_o"]
 
     if cfg.parallel_block:
@@ -337,7 +614,11 @@ def _block(cfg: GPTConfig, rope_tables, mesh, x, layer_params, positions,
         mlp_in = _norm(x, p["ln2_w"], p["ln2_b"], cfg.norm)
 
     aux = jnp.zeros((), jnp.float32)
-    if cfg.mlp_type == "moe":
+    if cfg.mlp_type == "moe" and cfg.moe_routing == "dropless":
+        mlp_out, _load = _dropless_mlp(
+            cfg, layer_params["moe_router"],
+            (p["moe_w_gate"], p["moe_w_in"], p["moe_w_out"]), block_in, mlp_in)
+    elif cfg.mlp_type == "moe":
         from ..ops.moe import moe_forward
 
         moe_params = {
@@ -349,13 +630,7 @@ def _block(cfg: GPTConfig, rope_tables, mesh, x, layer_params, positions,
             moe_params["w_gate"] = p["moe_w_gate"]
         mlp_out, aux = moe_forward(moe_params, mlp_in, cfg.moe_config)
     else:
-        u = jnp.einsum("bse,ef->bsf", mlp_in, p["w_in"]) + p["b_in"]
-        if cfg.activation == "swiglu":
-            g = jnp.einsum("bse,ef->bsf", mlp_in, p["w_gate"])
-            u = jax.nn.silu(g) * u
-        else:
-            u = jax.nn.gelu(u)
-        mlp_out = jnp.einsum("bsf,fe->bse", u, p["w_out"]) + p["b_out"]
+        mlp_out = _dense_mlp(cfg, p, mlp_in)
 
     out = x + attn_out + mlp_out if cfg.parallel_block else x + mlp_out
     if return_kv:
@@ -364,7 +639,7 @@ def _block(cfg: GPTConfig, rope_tables, mesh, x, layer_params, positions,
 
 
 _LAYER_KEYS = (
-    "w_qkv", "b_qkv", "w_o", "b_o", "w_in", "b_in", "w_out", "b_out",
+    "w_qkv", "b_qkv", "w_q", "w_kv", "w_o", "b_o", "w_in", "b_in", "w_out", "b_out",
     "ln1_w", "ln1_b", "ln2_w", "ln2_b", "w_gate",
     "moe_router", "moe_w_in", "moe_w_out", "moe_w_gate",
 )
@@ -400,7 +675,8 @@ def forward(params, tokens, cfg: GPTConfig, positions=None, mesh=None, return_au
     rope_tables = None
     if cfg.pos == "rotary":
         rd = min(cfg.rotary_dim, cfg.d_head)
-        rope_tables = rope_frequencies(rd, cfg.max_seq, dtype=jnp.float32)
+        rope_tables = rope_frequencies(
+            rd, cfg.max_seq, theta=cfg.rope_theta, dtype=jnp.float32)
 
     layer_stack = {k: params[k] for k in _LAYER_KEYS if k in params}
 
@@ -408,11 +684,13 @@ def forward(params, tokens, cfg: GPTConfig, positions=None, mesh=None, return_au
     if cfg.remat:
         block = jax.checkpoint(block, policy=_remat_policy(cfg))
 
-    def scan_body(x, layer_params):
-        x, aux = block(x, layer_params, positions)
+    def scan_body(x, inp):
+        layer_params, kind = inp
+        x, aux = block(x, layer_params, positions, kind=kind)
         return x, aux
 
-    x, aux_stack = jax.lax.scan(scan_body, x, layer_stack)
+    x, aux_stack = jax.lax.scan(
+        scan_body, x, (layer_stack, _layer_kind_xs(cfg)))
 
     x = _norm(x, params["ln_f_w"], params["ln_f_b"], cfg.norm)
     head = params["tok_embed"].T if cfg.tie_embeddings else params["lm_head"]
@@ -577,6 +855,7 @@ def stage_forward(
     slice, final norm + head if `last`. `inp` is tokens [B, S] on the first
     stage, activations [B, S, E] (cfg.dtype — what the compiled-DAG edge
     ships between hosts) otherwise. Returns (output, moe_aux_sum)."""
+    _refuse_new_fields(cfg, "a pipeline stage", moe=False)
     if first:
         _, S = inp.shape
         if positions is None:
@@ -593,7 +872,8 @@ def stage_forward(
     rope_tables = None
     if cfg.pos == "rotary":
         rd = min(cfg.rotary_dim, cfg.d_head)
-        rope_tables = rope_frequencies(rd, cfg.max_seq, dtype=jnp.float32)
+        rope_tables = rope_frequencies(
+            rd, cfg.max_seq, theta=cfg.rope_theta, dtype=jnp.float32)
 
     layer_stack = {k: stage_params[k] for k in _LAYER_KEYS if k in stage_params}
     block = functools.partial(_block, cfg, rope_tables, mesh)
@@ -763,6 +1043,7 @@ def pipeline_loss_fn(
 
     from ..parallel.spmd import shard_fn
 
+    _refuse_new_fields(cfg, "the GPipe pipeline", moe=False)
     if cfg.attn_impl in ("ring", "ulysses"):
         raise NotImplementedError(
             f"attn_impl={cfg.attn_impl!r} needs its own manual sp axis and "
@@ -788,7 +1069,8 @@ def pipeline_loss_fn(
     rope_tables = None
     if cfg.pos == "rotary":
         rd = min(cfg.rotary_dim, cfg.d_head)
-        rope_tables = rope_frequencies(rd, cfg.max_seq, dtype=jnp.float32)
+        rope_tables = rope_frequencies(
+            rd, cfg.max_seq, theta=cfg.rope_theta, dtype=jnp.float32)
 
     stage_stack = {k: params[k] for k in _LAYER_KEYS if k in params}
     block = functools.partial(_block, cfg, rope_tables, None)
@@ -891,6 +1173,7 @@ def prefill(params, tokens, cfg: GPTConfig, cache):
 
     Returns (last_logits [B, V] f32, cache). Prompts are fixed-length
     (left-pad upstream for ragged batches). No remat (inference)."""
+    _refuse_new_fields(cfg, "the dense-cache prefill")
     B, S = tokens.shape
     positions = jnp.arange(S)
     x = params["tok_embed"][tokens].astype(cfg.dtype)
@@ -899,7 +1182,8 @@ def prefill(params, tokens, cfg: GPTConfig, cache):
     rope_tables = None
     if cfg.pos == "rotary":
         rd = min(cfg.rotary_dim, cfg.d_head)
-        rope_tables = rope_frequencies(rd, cfg.max_seq, dtype=jnp.float32)
+        rope_tables = rope_frequencies(
+            rd, cfg.max_seq, theta=cfg.rope_theta, dtype=jnp.float32)
     layer_stack = {k: params[k] for k in _LAYER_KEYS if k in params}
 
     icfg = dataclasses.replace(cfg, remat=False, remat_policy=None)
@@ -934,8 +1218,7 @@ def decode_step(params, token, cache, cfg: GPTConfig):
     Attention is a plain masked dot against the cache — at S=1 the MXU
     matmuls are [B,H,1,D]x[B,H,M,D]; flash brings nothing and Pallas grid
     overhead would dominate."""
-    if cfg.mlp_type == "moe":
-        raise NotImplementedError("decode_step does not support MoE yet")
+    _refuse_new_fields(cfg, "the dense-cache decode_step")
     B = token.shape[0]
     pos = cache["len"]                       # scalar int32
     x = params["tok_embed"][token][:, None].astype(cfg.dtype)  # [B, 1, E]
@@ -944,7 +1227,8 @@ def decode_step(params, token, cache, cfg: GPTConfig):
     rope_tables = None
     if cfg.pos == "rotary":
         rd = min(cfg.rotary_dim, cfg.d_head)
-        rope_tables = rope_frequencies(rd, cfg.max_seq, dtype=jnp.float32)
+        rope_tables = rope_frequencies(
+            rd, cfg.max_seq, theta=cfg.rope_theta, dtype=jnp.float32)
     M = cache["k"].shape[3]
     scale = 1.0 / math.sqrt(cfg.d_head)
     H, Dh = cfg.n_heads, cfg.d_head
@@ -983,13 +1267,7 @@ def decode_step(params, token, cache, cfg: GPTConfig):
         else:
             x = x + attn_out
             mlp_in = _norm(x, p["ln2_w"], p["ln2_b"], cfg.norm)
-        u = jnp.einsum("bse,ef->bsf", mlp_in, p["w_in"]) + p["b_in"]
-        if cfg.activation == "swiglu":
-            g = jnp.einsum("bse,ef->bsf", mlp_in, p["w_gate"])
-            u = jax.nn.silu(g) * u
-        else:
-            u = jax.nn.gelu(u)
-        mlp_out = jnp.einsum("bsf,fe->bse", u, p["w_out"]) + p["b_out"]
+        mlp_out = _dense_mlp(cfg, p, mlp_in)
         out = x + attn_out + mlp_out if cfg.parallel_block else x + mlp_out
         return out, (ck, cv)
 
@@ -1026,9 +1304,51 @@ def decode_step(params, token, cache, cfg: GPTConfig):
 # v5e and lists whatever pool-sized operation is left.
 
 
+@dataclasses.dataclass(frozen=True)
+class KVLayout:
+    """How the layers share the one paged pool [per_group, NB, BS, row].
+
+    A model whose layers are all of one kind is ONE group: per_group = L,
+    layer l keeps its rows at pool[l], one block table a sequence. With
+    global and window layers the layers are dealt into groups of equal
+    size, each of one kind (12 layers = 3 global + 9 window: four groups
+    of 3; 52 = 13 + 39: four of 13), so that a block -- `per_group` layers
+    x block_size tokens -- has the same bytes whichever group holds it and
+    every group draws from the same `num_blocks`. A sequence has one block
+    table a group; a window group gives back the blocks that fell behind
+    its window while the sequence lives (serve/engine/kv_manager.py)."""
+
+    per_group: int                  # layers in a group = the pool's leading dim
+    windows: Tuple[int, ...]        # per group: 0 = keeps every token, else the window
+    group_of: Tuple[int, ...]       # [L] the layer's group
+    slot_of: Tuple[int, ...]        # [L] the layer's index inside pool[:]
+
+
+@functools.lru_cache(maxsize=None)
+def kv_layout(cfg: GPTConfig) -> KVLayout:
+    L = cfg.n_layers
+    kinds = cfg.layer_kinds
+    win = kinds[1] if kinds is not None else (0,) * L
+    glob = [l for l in range(L) if not win[l]]
+    wind = [l for l in range(L) if win[l]]
+    if not glob or not wind:
+        return KVLayout(L, (cfg.sliding_window if wind else 0,), (0,) * L,
+                        tuple(range(L)))
+    per = math.gcd(len(glob), len(wind))
+    group_of, slot_of, windows = [0] * L, [0] * L, []
+    for layers, w in ((glob, 0), (wind, cfg.sliding_window)):
+        for i, l in enumerate(layers):
+            group_of[l] = len(windows) + i // per
+            slot_of[l] = i % per
+        windows += [w] * (len(layers) // per)
+    return KVLayout(per, tuple(windows), tuple(group_of), tuple(slot_of))
+
+
 def init_paged_cache(cfg: GPTConfig, num_blocks: int, block_size: int):
-    """Physical paged KV pool: {"k","v"} of [L, NB, BS, H*Dh] in cfg.dtype."""
-    shape = (cfg.n_layers, num_blocks, block_size, cfg.n_heads * cfg.d_head)
+    """Physical paged KV pool: {"k","v"} of [per_group, NB, BS, Hkv*Dh] in
+    cfg.dtype (`kv_layout`; per_group = L for a model of one kind)."""
+    shape = (kv_layout(cfg).per_group, num_blocks, block_size,
+             cfg.kv_heads * cfg.d_head)
     return {"k": jnp.zeros(shape, cfg.dtype), "v": jnp.zeros(shape, cfg.dtype)}
 
 
@@ -1061,20 +1381,43 @@ def _paged_layers(params, tokens, pos, valid, block_tables, kv, cfg: GPTConfig):
     tokens, pos [B, S] int32; valid [B, S] bool (or True) — K/V of invalid
     slots go to the null block, so a padded slot can never clobber a
     neighbouring block through index clamping;
-    block_tables [B, W] int32. Each layer writes the new tokens' K/V rows
-    in place FIRST, then attends causally over the gathered table history
-    (query j sees columns 0..pos[b, j]) — cached prefix, earlier chunks and
+    block_tables [B, W] int32, or [B, G, W] for a model whose layers form G
+    groups (`kv_layout`): one table a group, every one W wide, a released
+    or not yet allocated entry pointing at the null block. Each layer
+    writes the new tokens' K/V rows in place FIRST, then attends causally
+    over the gathered table history (query j sees columns 0..pos[b, j]; on
+    a window layer only those above pos[b, j] - window, which is what
+    keeps the null block's rows out) — cached prefix, earlier chunks and
     the new tokens themselves all come back through one path. The pool
-    rides the scan as its carry, indexed by the layer number; the stacked
-    weights are the xs. Returns (hidden states [B, S, E] before the final
-    norm, kv)."""
-    if cfg.mlp_type == "moe":
-        raise NotImplementedError("paged decode does not support MoE yet")
+    rides the scan as its carry, indexed by the layer's slot; the stacked
+    weights and the layer's kind (rotary or not, its window, its group) are
+    the xs. K/V heads are shared by groups of query heads by folding the
+    group into the query axis, so multi-head attention is the same
+    operations with a group of one. Where a lane's table is wider than the
+    window and the step's tokens, a window layer takes the branch that
+    gathers only the blocks from its window's first one on
+    (`attend_window`). An expert MLP is the dropless layer of
+    `_dropless_mlp`. Returns (hidden states [B, S, E] before the final
+    norm, kv, None or the mean over layers of (experts touched, busiest
+    expert's share) [2] f32)."""
+    moe = cfg.mlp_type == "moe"
+    if moe and cfg.moe_routing != "dropless":
+        raise NotImplementedError(
+            "paged decode serves dropless experts only (moe_routing='dropless'); "
+            "capacity-dropping routing is the training path's")
+    lay = kv_layout(cfg)
+    G = len(lay.windows)
+    if G == 1 and block_tables.ndim == 3:
+        block_tables = block_tables[:, 0]
+    if block_tables.ndim != (2 if G == 1 else 3):
+        raise ValueError(
+            f"block tables {block_tables.shape} for a model of {G} KV group(s)")
     B, S = tokens.shape
-    W = block_tables.shape[1]
+    W = block_tables.shape[-1]
     BS = kv["k"].shape[2]
     M = W * BS
-    H, Dh = cfg.n_heads, cfg.d_head
+    H, Hkv, Dh = cfg.n_heads, cfg.kv_heads, cfg.d_head
+    R = H // Hkv
     scale = 1.0 / math.sqrt(Dh)
     x = params["tok_embed"][tokens].astype(cfg.dtype)  # [B, S, E]
     if cfg.pos == "learned":
@@ -1082,38 +1425,104 @@ def _paged_layers(params, tokens, pos, valid, block_tables, kv, cfg: GPTConfig):
     rope_tables = None
     if cfg.pos == "rotary":
         rope_tables = rope_frequencies(
-            min(cfg.rotary_dim, Dh), cfg.max_seq, dtype=jnp.float32
+            min(cfg.rotary_dim, Dh), cfg.max_seq, theta=cfg.rope_theta,
+            dtype=jnp.float32
         )
-    phys = jnp.where(
-        valid,
-        jnp.take_along_axis(block_tables, jnp.minimum(pos // BS, W - 1), axis=1),
-        0,
-    )                                                  # [B, S] physical block
+    blk = jnp.minimum(pos // BS, W - 1)
+
+    def physical(table):                               # [B, W] -> [B, S]
+        return jnp.where(valid, jnp.take_along_axis(table, blk, axis=1), 0)
+
+    phys = physical(block_tables) if G == 1 else None
     off = pos % BS
-    seen = jnp.arange(M)[None, None, None, :] <= pos[:, None, :, None]
+    kpos = jnp.arange(M)[None, None, None, :]
+    qpos = pos[:, None, :, None]
+    seen = kpos <= qpos                                # [B, 1, S, M]
     layer_stack = {k: params[k] for k in _LAYER_KEYS if k in params}
+    kinds = _layer_kind_xs(cfg)
+    if kinds is not None:
+        kinds["group"] = jnp.asarray(lay.group_of, jnp.int32)
+        kinds["slot"] = jnp.asarray(lay.slot_of, jnp.int32)
+    # A step of a few tokens reads only the experts they chose: the expert
+    # stacks then stay whole (a slice the scan cuts would be copied into the
+    # inner loop) and the layer number finds the expert where it lies.
+    # A window layer reads no further back than its window: where the lane's
+    # table is wider than window + S tokens it gathers only the `narrow`
+    # blocks from the window's first one on (the layer's kind picks the
+    # branch at run time; shapes depend on W, S and the config alone, so
+    # the program's key does not change).
+    narrow = W
+    if kinds is not None and cfg.sliding_window:
+        narrow = min(W, (cfg.sliding_window + S - 2) // BS + 2)
+
+    def attend(q, gk, gv, mask):
+        """q [B, Hkv, R*S, Dh] over gathered rows [B, T, Hkv, Dh]."""
+        if R > 1:   # the R query heads of a K/V head ride its query axis
+            mask = jnp.tile(mask, (1, 1, R, 1))
+        scores = jnp.einsum(
+            "bhsd,bthd->bhst", q, gk, preferred_element_type=jnp.float32
+        ) * scale                                      # [B, Hkv, R*S, T]
+        probs = jax.nn.softmax(jnp.where(mask, scores, -1e30), axis=-1)
+        return jnp.einsum("bhst,bthd->bhsd", probs.astype(gv.dtype), gv)
+
+    def attend_table(q, kk, vv, slot, table, window):
+        # Each lane's history: [B, W, BS, Hkv*Dh] -> [B, W*BS, Hkv, Dh].
+        gk = kk[slot, table].reshape(B, M, Hkv, Dh)
+        gv = vv[slot, table].reshape(B, M, Hkv, Dh)
+        mask = seen if window is None else seen & (kpos > qpos - window)
+        return attend(q, gk, gv, mask)
+
+    def attend_window(q, kk, vv, slot, table, window):
+        first = jnp.clip((pos[:, :1] - window + 1) // BS, 0, W - narrow)
+        idx = first + jnp.arange(narrow)[None]         # [B, narrow] table columns
+        blocks = jnp.take_along_axis(table, idx, axis=1)
+        gk = kk[slot, blocks].reshape(B, narrow * BS, Hkv, Dh)
+        gv = vv[slot, blocks].reshape(B, narrow * BS, Hkv, Dh)
+        kp = (idx[..., None] * BS + jnp.arange(BS)).reshape(B, 1, 1, narrow * BS)
+        return attend(q, gk, gv, (kp <= qpos) & (kp > qpos - window))
+
+    stacks = None
+    if moe:
+        tok_valid = jnp.logical_and(
+            valid, (block_tables != 0).any(axis=tuple(range(1, block_tables.ndim)))[:, None])
+        if B * S * cfg.moe_top_k < cfg.moe_experts:
+            stacks = tuple(layer_stack.pop(k) for k in
+                           ("moe_w_gate", "moe_w_in", "moe_w_out"))
 
     def scan_body(carry, inp):
         x, kk, vv = carry                              # kk/vv: the whole pool
-        l, layer_params = inp
+        l, layer_params, kind = inp
         p = jax.tree_util.tree_map(lambda a: a.astype(cfg.dtype), layer_params)
+        block_in = x
         h = _norm(x, p["ln1_w"], p["ln1_b"], cfg.norm)
-        qkv = jnp.einsum("bse,ethd->btshd", h, p["w_qkv"]) + p["b_qkv"][:, None]
-        q, k = (qkv[:, i].transpose(0, 2, 1, 3) for i in range(2))  # [B,H,S,Dh]
+        q, k, v = _project_qkv(cfg, p, h)              # [B, S, heads, Dh]
+        q, k = q.transpose(0, 2, 1, 3), k.transpose(0, 2, 1, 3)  # [B,heads,S,Dh]
         if cfg.pos == "rotary":
-            q, k = _rope_qk(cfg, q, k, rope_tables, pos)
-        k = k.transpose(0, 2, 1, 3).reshape(B, S, H * Dh)
-        v = qkv[:, 2].reshape(B, S, H * Dh)
-        kk = kk.at[l, phys, off].set(k.astype(kk.dtype))
-        vv = vv.at[l, phys, off].set(v.astype(vv.dtype))
-        # Each lane's history: [B, W, BS, H*Dh] -> [B, W*BS, H, Dh].
-        gk = kk[l, block_tables].reshape(B, M, H, Dh)
-        gv = vv[l, block_tables].reshape(B, M, H, Dh)
-        scores = jnp.einsum(
-            "bhsd,bthd->bhst", q, gk, preferred_element_type=jnp.float32
-        ) * scale                                      # [B, H, S, M]
-        probs = jax.nn.softmax(jnp.where(seen, scores, -1e30), axis=-1)
-        attn = jnp.einsum("bhst,bthd->bhsd", probs.astype(gv.dtype), gv)
+            qr, kr = _rope_qk(cfg, q, k, rope_tables, pos)
+            q, k = (qr, kr) if kind is None else (
+                jnp.where(kind["rope"], qr, q), jnp.where(kind["rope"], kr, k))
+        k = k.transpose(0, 2, 1, 3).reshape(B, S, Hkv * Dh)
+        v = v.reshape(B, S, Hkv * Dh)
+        if G == 1:
+            slot, table, ph = l, block_tables, phys
+        else:
+            slot = kind["slot"]
+            table = jnp.take(block_tables, kind["group"], axis=1)
+            ph = physical(table)
+        kk = kk.at[slot, ph, off].set(k.astype(kk.dtype))
+        vv = vv.at[slot, ph, off].set(v.astype(vv.dtype))
+        if R > 1:
+            q = q.reshape(B, Hkv, R * S, Dh)
+        if kind is None:
+            attn = attend_table(q, kk, vv, slot, table, None)
+        elif narrow < W:
+            attn = jax.lax.cond(
+                kind["window"] < _NO_WINDOW, attend_window, attend_table,
+                q, kk, vv, slot, table, kind["window"])
+        else:
+            attn = attend_table(q, kk, vv, slot, table, kind["window"])
+        if R > 1:
+            attn = attn.reshape(B, H, S, Dh)
         attn_out = jnp.einsum("bhsd,hde->bse", attn, p["w_o"]) + p["b_o"]
 
         if cfg.parallel_block:
@@ -1121,21 +1530,23 @@ def _paged_layers(params, tokens, pos, valid, block_tables, kv, cfg: GPTConfig):
         else:
             x = x + attn_out
             mlp_in = _norm(x, p["ln2_w"], p["ln2_b"], cfg.norm)
-        u = jnp.einsum("bse,ef->bsf", mlp_in, p["w_in"]) + p["b_in"]
-        if cfg.activation == "swiglu":
-            g = jnp.einsum("bse,ef->bsf", mlp_in, p["w_gate"])
-            u = jax.nn.silu(g) * u
+        load = None
+        if moe:
+            experts = stacks or (p["moe_w_gate"], p["moe_w_in"], p["moe_w_out"])
+            mlp_out, load = _dropless_mlp(
+                cfg, layer_params["moe_router"], experts, block_in, mlp_in,
+                layer=l if stacks else None, valid=tok_valid)
+            load = jnp.stack(load)
         else:
-            u = jax.nn.gelu(u)
-        mlp_out = jnp.einsum("bsf,fe->bse", u, p["w_out"]) + p["b_out"]
+            mlp_out = _dense_mlp(cfg, p, mlp_in)
         out = x + attn_out + mlp_out if cfg.parallel_block else x + mlp_out
-        return (out, kk, vv), None
+        return (out, kk, vv), load
 
-    (x, kk, vv), _ = jax.lax.scan(
+    (x, kk, vv), loads = jax.lax.scan(
         scan_body, (x, kv["k"], kv["v"]),
-        (jnp.arange(cfg.n_layers), layer_stack),
+        (jnp.arange(cfg.n_layers), layer_stack, kinds),
     )
-    return x, {"k": kk, "v": vv}
+    return x, {"k": kk, "v": vv}, (loads.mean(axis=0) if moe else None)
 
 
 def _paged_logits(params, x, cfg: GPTConfig):
@@ -1155,7 +1566,8 @@ def prefill_paged(params, tokens, real_len, pos_offset, block_table, kv,
     prompt[pos_offset : pos_offset + real_len]; `real_len` / `pos_offset`
     are traced scalars (one compiled program per (Sp, W) bucket pair covers
     every chunk length and offset); `block_table` [W] int32 maps the
-    sequence's blocks. Prefix-cache hits and earlier chunks' KV below
+    sequence's blocks ([G, W], one table a group, for a model of G KV
+    groups). Prefix-cache hits and earlier chunks' KV below
     `pos_offset` are read from the cache, never recomputed, and a
     monolithic prefill is just the pos_offset=0 chunk covering the whole
     prompt. K/V of padded positions go to the null block. Returns
@@ -1164,7 +1576,7 @@ def prefill_paged(params, tokens, real_len, pos_offset, block_table, kv,
     """
     rel = jnp.arange(tokens.shape[1])
     pos = (pos_offset + rel)[None]               # global token positions [1, Sp]
-    x, kv = _paged_layers(
+    x, kv, _ = _paged_layers(
         params, tokens, pos, (rel < real_len)[None], block_table[None], kv, cfg
     )
     h = x[0, jnp.maximum(real_len - 1, 0)]  # [E] — last REAL chunk position
@@ -1179,12 +1591,16 @@ def decode_step_paged(params, token, positions, block_tables, kv, cfg: GPTConfig
     int32. Lanes are independent sequences at unrelated positions — the
     continuous batch. Returns (logits [B, V] f32, kv). Padding lanes
     (block table = null block, position 0) produce garbage logits the
-    engine discards.
+    engine discards. For an expert model (`cfg.mlp_type == "moe"`) the step's
+    routing comes back beside the logits: (logits, [2] f32 = experts with
+    at least one token and the busiest expert's share of the assignments,
+    mean over layers, padding lanes left out), kv.
     """
-    x, kv = _paged_layers(
+    x, kv, load = _paged_layers(
         params, token[:, None], positions[:, None], True, block_tables, kv, cfg
     )
-    return _paged_logits(params, x[:, 0], cfg), kv
+    logits = _paged_logits(params, x[:, 0], cfg)
+    return (logits, kv) if load is None else ((logits, load), kv)
 
 
 def verify_step_paged(params, tokens, positions, valid_len, block_tables, kv,
@@ -1206,7 +1622,7 @@ def verify_step_paged(params, tokens, positions, valid_len, block_tables, kv,
     """
     rel = jnp.arange(tokens.shape[1])[None, :]
     pos = positions[:, None] + rel                              # [B, K1]
-    x, kv = _paged_layers(
+    x, kv, _ = _paged_layers(
         params, tokens, pos, rel < valid_len[:, None], block_tables, kv, cfg
     )
     return _paged_logits(params, x, cfg), kv

@@ -150,3 +150,106 @@ MOE_LOGICAL_DIMS = {
     "w_out": ("experts", "mlp", "embed"),
     "w_gate": ("experts", "embed", "mlp"),
 }
+
+
+# ------------------------------------------------------- dropless top-k
+# Beside the capacity-dropping GShard path above (training configurations):
+# routing for any k with NO capacity, so no token is ever dropped, and gate
+# weights that are a softmax over the k kept logits (the same as a softmax
+# over all experts renormalised over the kept ones). Static shapes without a
+# capacity mean the experts are applied densely and masked: every expert
+# sees every token, and the [N, X] combine matrix is zero where a token did
+# not choose the expert. `touched_k` adds the small-batch form a decode
+# step wants: when the step's N*k assignments cannot reach every expert, a
+# loop over the experts that were chosen reads only those experts' weights.
+
+
+def dropless_route(logits, top_k: int):
+    """logits [N, X] (any float) -> (idx [N, k] int32, weights [N, k] f32):
+    the k largest logits of each token and the softmax over those k."""
+    vals, idx = jax.lax.top_k(logits.astype(jnp.float32), top_k)
+    return idx.astype(jnp.int32), jax.nn.softmax(vals, axis=-1)
+
+
+def dropless_combine(idx, weights, num_experts: int):
+    """[N, k] choices -> combine [N, X] f32 (a token's gate weight at each
+    expert it chose, 0 elsewhere)."""
+    return (jax.nn.one_hot(idx, num_experts, dtype=jnp.float32)
+            * weights[..., None]).sum(axis=-2)
+
+
+def _gated(act: str, g, u):
+    if act == "reglu":
+        return jax.nn.relu(g) * u
+    if act == "swiglu":
+        return jax.nn.silu(g) * u
+    raise ValueError(f"dropless experts are gated (reglu | swiglu), got {act!r}")
+
+
+def dropless_experts(x, combine, w_gate, w_in, w_out, activation: str,
+                     layer=None, touched_k: int = 0):
+    """y [N, D] = sum_e combine[n, e] * W_out,e( act(W_gate,e x) * (W_in,e x) ).
+
+    x [N, D]; combine [N, X] f32; weights [X, D, F] / [X, F, D], or with
+    `layer` (a traced index) the whole stacks [L, X, ...] of which layer
+    `layer` is read in place. Two forms, the same mathematics:
+
+    * dense (default): one [N, D] x [D, X*F] product for gate and up, the
+      combine weights folded into the hidden activations, one [N, X*F] x
+      [X*F, D] product down. Reads every expert once: right from some ten
+      tokens up, where top-k of N tokens reaches most experts anyway.
+    * `touched_k` = k > 0: a loop over at most min(N*k, X) experts, the chosen
+      ones first, each applied to all N tokens under its combine column;
+      an expert nobody chose is never read. Right for a decode step of a
+      few lanes, whose time is the bytes of expert weights it streams.
+    """
+    N, D = x.shape
+    X = combine.shape[-1]
+    dt = x.dtype
+
+    def one(a, e):
+        """Expert e's slice of a stacked weight, read where it lies."""
+        if layer is None:
+            return jax.lax.dynamic_index_in_dim(a, e, 0, keepdims=False)
+        return jax.lax.dynamic_slice(
+            a, (layer, e, 0, 0), (1, 1) + a.shape[2:])[0, 0]
+
+    if not touched_k:
+        if layer is not None:
+            w_gate, w_in, w_out = (
+                jax.lax.dynamic_index_in_dim(a, layer, 0, keepdims=False)
+                for a in (w_gate, w_in, w_out))
+        g = jnp.einsum("nd,xdf->nxf", x, w_gate.astype(dt))
+        u = jnp.einsum("nd,xdf->nxf", x, w_in.astype(dt))
+        h = _gated(activation, g, u) * combine[..., None].astype(dt)
+        return jnp.einsum("nxf,xfd->nd", h, w_out.astype(dt))
+
+    hit = (combine > 0).any(axis=0)                      # [X] chosen by someone
+    order = jnp.argsort(~hit, stable=True)               # chosen experts first
+    n_hit = hit.sum()
+
+    def body(i, y):
+        def run(y):
+            e = order[i]
+            g = x @ one(w_gate, e).astype(dt)
+            u = x @ one(w_in, e).astype(dt)
+            c = jax.lax.dynamic_index_in_dim(combine, e, 1, keepdims=True)
+            h = _gated(activation, g, u) * c.astype(dt)
+            return y + (h @ one(w_out, e).astype(dt)).astype(jnp.float32)
+
+        return jax.lax.cond(i < n_hit, run, lambda y: y, y)
+
+    trips = min(N * touched_k, X)
+    y = jax.lax.fori_loop(0, trips, body, jnp.zeros((N, D), jnp.float32))
+    return y.astype(dt)
+
+
+def dropless_load(combine, valid=None):
+    """(experts with at least one token, the busiest expert's share of the
+    assignments) of one layer's routing, f32 scalars; `valid` [N] leaves
+    padding tokens out."""
+    chosen = combine > 0
+    if valid is not None:
+        chosen = chosen & valid[:, None]
+    per = chosen.sum(axis=0).astype(jnp.float32)         # [X] assignments
+    return (per > 0).sum().astype(jnp.float32), per.max() / jnp.maximum(per.sum(), 1.0)

@@ -148,7 +148,7 @@ class InferenceEngine:
     ):
         import jax
 
-        from ...models.gpt import init_paged_cache, init_params
+        from ...models.gpt import init_paged_cache, init_params, kv_layout
 
         self.cfg = dataclasses.replace(cfg, remat=False, remat_policy=None)
         self.opts = options or EngineOptions()
@@ -156,7 +156,22 @@ class InferenceEngine:
             raise ValueError(
                 f"role must be mixed|prefill|decode, got {self.opts.role!r}"
             )
+        # One block table a KV group (`models/gpt.py` kv_layout). The host
+        # tier, the disaggregated roles and block export/import move blocks
+        # of ONE row shape and one table: a model whose layers form several
+        # groups is refused here, not served wrongly (ROADMAP D9).
+        self._groups = len(kv_layout(self.cfg).windows)
+        if self._groups > 1:
+            if self.opts.host_kv_bytes > 0 and self.opts.enable_prefix_caching:
+                raise ValueError(
+                    f"a model of {self._groups} KV groups runs with the host "
+                    "KV tier off (host_kv_bytes=0)")
+            if self.opts.role != "mixed":
+                raise ValueError(
+                    f"a model of {self._groups} KV groups serves role='mixed' "
+                    "only: the prefill/decode hand-off exports blocks of one group")
         self._jnp = jax.numpy
+        self._jax = jax
         # Where the kernels run, as JAX reports it — benches and the chip
         # smoke read the platform from here, never from a flag.
         dev = jax.devices()[0]
@@ -177,6 +192,7 @@ class InferenceEngine:
             self.opts.block_size,
             enable_prefix_caching=self.opts.enable_prefix_caching,
             host_tier=self.host_tier,
+            group_windows=kv_layout(self.cfg).windows,
         )
         proposer = None
         if self.opts.spec_tokens > 0:
@@ -236,6 +252,9 @@ class InferenceEngine:
         self.total_spec_accepted = 0
         self.total_blocks_imported = 0
         self.total_blocks_exported = 0
+        # Expert routing: the last decode step's (experts touched, busiest
+        # expert's share), which came back with its logits.
+        self._step_moe = None
         # Side work serviced by the driver thread at step boundaries, where
         # self.kv is stable (kernel donation invalidates old buffers, so no
         # other thread may ever read the KV arrays): ("export", digests,
@@ -625,7 +644,7 @@ class InferenceEngine:
         another order, so it must not be adopted)."""
         c = self.cfg
         return (
-            f"{c.n_layers}:{c.n_heads}:{c.d_head}:{self.opts.block_size}:"
+            f"{c.n_layers}:{c.kv_heads}:{c.d_head}:{self.opts.block_size}:"
             f"{self._jnp.dtype(c.dtype).str}:rows"
         )
 
@@ -653,6 +672,7 @@ class InferenceEngine:
         donated KV arrays); this caller blocks until serviced. Returns None
         when there is nothing exportable (short prompt, blocks already
         evicted everywhere, engine stopped)."""
+        self._one_group("export_prompt_kv")
         digests = self.prompt_digests(prompt)
         if not digests or self._stop.is_set():
             return None
@@ -736,6 +756,7 @@ class InferenceEngine:
         each block as a cached entry whose bytes the driver thread lands
         before its next kernel. Returns the number adopted; 0 means the
         importer simply recomputes (degraded mode is the pre-disagg path)."""
+        self._one_group("import_blocks")
         if not desc or not self.opts.enable_prefix_caching \
                 or self._stop.is_set():
             return 0
@@ -787,6 +808,25 @@ class InferenceEngine:
                 n += 1
         return _span(n, len(needed))
 
+    def _one_group(self, what: str):
+        if self._groups > 1:
+            raise NotImplementedError(
+                f"{what}: blocks of a model of {self._groups} KV groups are "
+                "not exported or imported")
+
+    def _tables_into(self, arr, seq: Sequence):
+        """A sequence's block table(s) into a zeroed [W] / [G, W] row."""
+        if self._groups == 1:
+            table = self.block_manager.block_table(seq.request_id)
+            arr[: len(table)] = table
+        else:
+            for g, table in enumerate(
+                    self.block_manager.block_tables(seq.request_id)):
+                arr[g, : len(table)] = table
+
+    def _table_shape(self, *lead) -> tuple:
+        return lead if self._groups == 1 else (*lead[:-1], self._groups, lead[-1])
+
     def _service_side_work(self):
         """Run queued export requests at the step boundary (after loads:
         freshly imported bytes are already exportable onward)."""
@@ -812,16 +852,16 @@ class InferenceEngine:
         jnp = self._jnp
         np = self._np
         with flight.phase("engine.build", ph, "build_ns"):
-            table = self.block_manager.block_table(seq.request_id)
             L = chunk.num_tokens
             # Same bucketing primitive as the scheduler's decode shapes —
             # agreement between the two is what bounds the XLA program set.
             Sp = _next_pow2(L)
-            W = _next_pow2(len(table))
+            W = _next_pow2(self.block_manager.blocks_for(
+                self.block_manager.seq_len(seq.request_id)))
             tokens = np.zeros((1, Sp), np.int32)
             tokens[0, :L] = seq.prompt[chunk.start:chunk.start + L]
-            bt = np.zeros((W,), np.int32)
-            bt[: len(table)] = table
+            bt = np.zeros(self._table_shape(W), np.int32)
+            self._tables_into(bt, seq)
             args = (
                 jnp.asarray(tokens),
                 jnp.asarray(L, jnp.int32),
@@ -871,7 +911,7 @@ class InferenceEngine:
             tokens = np.zeros((B, K1), np.int32)
             positions = np.zeros((B,), np.int32)
             valid_len = np.zeros((B,), np.int32)  # 0 for padding lanes
-            tables = np.zeros((B, W), np.int32)   # padding lanes -> null block
+            tables = np.zeros(self._table_shape(B, W), np.int32)   # padding lanes -> null block
             lane_drafts: List[List[int]] = []
             for i, seq in enumerate(seqs):
                 d = out.drafts.get(seq.request_id, [])
@@ -881,8 +921,7 @@ class InferenceEngine:
                     tokens[i, 1:1 + len(d)] = d
                 positions[i] = seq.num_tokens - 1
                 valid_len[i] = 1 + len(d)
-                table = self.block_manager.block_table(seq.request_id)
-                tables[i, : len(table)] = table
+                self._tables_into(tables[i], seq)
             args = (
                 jnp.asarray(tokens),
                 jnp.asarray(positions),
@@ -939,12 +978,11 @@ class InferenceEngine:
             W = out.width_bucket
             tokens = np.zeros((B,), np.int32)
             positions = np.zeros((B,), np.int32)
-            tables = np.zeros((B, W), np.int32)  # padding lanes -> null block
+            tables = np.zeros(self._table_shape(B, W), np.int32)  # padding lanes -> null block
             for i, seq in enumerate(seqs):
                 tokens[i] = seq.output[-1]
                 positions[i] = seq.num_tokens - 1   # where this token's KV lands
-                table = self.block_manager.block_table(seq.request_id)
-                tables[i, : len(table)] = table
+                self._tables_into(tables[i], seq)
             args = (
                 jnp.asarray(tokens),
                 jnp.asarray(positions),
@@ -956,7 +994,10 @@ class InferenceEngine:
             )
             del args    # the input buffers are released here, not at return
         with flight.phase("engine.fetch_logits", ph, "fetch_ns"):
-            logits = np.asarray(logits)
+            if isinstance(logits, tuple):   # an expert model: the step's routing
+                logits, self._step_moe = self._jax.device_get(logits)
+            else:
+                logits = np.asarray(logits)
         with flight.phase("engine.sample", ph, "sample_ns"):
             for i, seq in enumerate(seqs):
                 self._emit(seq, self._sample(logits[i]))
@@ -983,6 +1024,7 @@ class InferenceEngine:
         with flight.phase("engine.schedule", ph, "sched_ns"):
             self._step_ttfts, self._step_tpots = [], []
             self._step_spec = [0, 0]  # [proposed, accepted]
+            self._step_moe = None
             tok0 = self.total_tokens
             with self._lock:
                 out = self.scheduler.schedule()
@@ -1048,12 +1090,15 @@ class InferenceEngine:
             self._export_metrics(stats)
         if fl_on and (out.prefills or out.decodes):
             idle, self._idle = self._idle, {"waited_ns": 0}
+            moe = {} if self._step_moe is None else {
+                "experts_touched": float(self._step_moe[0]),
+                "expert_load_max": float(self._step_moe[1])}
             flight.record(
                 "engine.step", t0_ns, t1_ns, lane=self._lane,
                 attrs={"prefills": len(out.prefills),
                        "decodes": len(out.decodes),
                        "tokens": stats["step_tokens"],
-                       **idle, **ph,
+                       **moe, **idle, **ph,
                        "queue_depth": stats["queue_depth"],
                        "running": stats["running"],
                        "kv_util": stats["kv_utilization"]})
@@ -1090,6 +1135,7 @@ class InferenceEngine:
             "host_tier_bytes": kv_stats.host_bytes,
             "blocks_imported": self.total_blocks_imported,
             "blocks_exported": self.total_blocks_exported,
+            "window_blocks_released": self.block_manager.window_released,
             "total_tokens": self.total_tokens,
             "total_finished": self.total_finished,
             "total_preemptions": self.total_preemptions,

@@ -54,6 +54,35 @@ decode growth hits the budget mid-flight.
 Block 0 is RESERVED as the null/scratch block: the engine pads decode
 batches to bucket shapes by pointing dummy lanes' block tables at block 0,
 so their writes land somewhere harmless. It is never handed out.
+
+Layers of two kinds over ONE pool (`group_windows`, from the model's
+`kv_layout`): a model with global and sliding-window layers deals its layers
+into G groups of equal size, each of one kind, and the pool is
+[layers a group, NB, BS, row]: a block has the same bytes whichever group
+holds it, there is one `num_blocks` and one free list, and nothing divides
+memory between the kinds. A sequence has one block table a GROUP, all
+`blocks_for(len)` long. A global group holds a block for every token. A
+window group (window w) holds only the blocks a future query can still see:
+before a program whose first query sits at position q runs, `slide` (and
+`grow`, for decode) RELEASES every block wholly below q - w + 1 and acquires
+the blocks up to the last position the program writes; a released or not yet
+acquired entry is the null block, which the window mask and the causal mask
+keep out of every sum. At rest a lane past the window therefore holds, in a
+window group, at most blocks_for(w) + 1 blocks (while a prefill chunk is in
+flight, the chunk's blocks more). Admission asks the pool for what the
+sequence will hold at rest (`blocks_needed`) and acquires a window group's
+blocks as the prefill reaches them, so `can_allocate`, `fits_ever`,
+`free_blocks`, preemption and `KVStats` all count what is really held.
+
+Prefix reuse with groups, the simplest sound rule: a full block of tokens is
+registered under its chained hash as ONE entry naming G physical blocks, one
+a group, and is reusable only while EVERY group's copy still stands. A
+window group's released block rests on the cached list like a finished
+sequence's (its rows are still that prefix's K/V); the moment any one copy
+is reclaimed for new content the whole entry leaves the index and its other
+copies lose their registration, so a prefix hit can never hand out a block
+whose rows were overwritten. `fork`, the host tier, `adopt_block` and
+`export_sources` are the one-group manager's: with G > 1 they refuse.
 """
 
 from __future__ import annotations
@@ -122,7 +151,17 @@ class KVBlockManager:
         block_size: int,
         enable_prefix_caching: bool = True,
         host_tier=None,
+        group_windows: Sequence[int] = (0,),
     ):
+        self.group_windows = tuple(int(w) for w in group_windows)
+        if not self.group_windows or min(self.group_windows) < 0:
+            raise ValueError(f"bad group_windows {group_windows!r}")
+        self._G = len(self.group_windows)
+        self._sliding = any(self.group_windows)
+        if self._G > 1 and host_tier is not None:
+            raise ValueError(
+                "the host KV tier holds blocks of one group; a model whose "
+                f"layers form {self._G} KV groups runs with it off")
         if num_blocks < 2:
             raise ValueError("need >= 2 blocks (block 0 is the null block)")
         if block_size < 1:
@@ -142,10 +181,14 @@ class KVBlockManager:
         # is recency (oldest first = LRU eviction order).
         self._cached: "OrderedDict[int, None]" = OrderedDict()
         self._ref: Dict[int, int] = {}            # live blocks only
-        self._tables: Dict[str, List[int]] = {}
+        # One table a group, each blocks_for(len) long; NULL_BLOCK where a
+        # window group released the block or has not acquired it yet.
+        self._tables: Dict[str, List[List[int]]] = {}
         self._lens: Dict[str, int] = {}           # tokens stored per sequence
+        self._held_lo: Dict[str, List[int]] = {}  # first held index, a group
         self._hash_of: Dict[int, bytes] = {}      # registered block -> key
-        self._index: Dict[bytes, int] = {}        # key -> canonical block
+        # key -> the canonical blocks, one a group
+        self._index: Dict[bytes, Tuple[int, ...]] = {}
         self._chain: Dict[str, List[bytes]] = {}  # per-seq registered keys
         # Recency-ordered registered/hit hashes (hottest LAST): the bounded
         # hot-prefix digest the fleet router steers by. Advisory only —
@@ -178,6 +221,7 @@ class KVBlockManager:
         self.evictions = 0
         self.cow_copies = 0
         self.host_hits = 0
+        self.window_released = 0   # blocks window groups gave back while live
 
     # ------------------------------------------------------------- queries
     @property
@@ -197,15 +241,43 @@ class KVBlockManager:
     def blocks_for(self, num_tokens: int) -> int:
         return -(-num_tokens // self.block_size)  # ceil div
 
+    def _span(self, window: int, first_query: int, upto: int) -> Tuple[int, int]:
+        """[lo, hi): the block indices a group of `window` (0 = global)
+        holds when the next query sits at `first_query` and positions below
+        `upto` are covered."""
+        lo = max(0, first_query - window + 1) // self.block_size if window else 0
+        return lo, max(lo, self.blocks_for(upto))
+
+    def blocks_needed(self, num_tokens: int) -> int:
+        """Blocks a sequence of `num_tokens` holds at rest, over all groups:
+        every token's in a global group, the window and one block in a
+        window group. What admission asks the pool for: a window group
+        acquires its blocks as the prefill reaches them (`slide`), but a
+        prompt is admitted only when the pool could give them all."""
+        nb = self.blocks_for(num_tokens)
+        return sum(min(nb, self.blocks_for(w) + 1) if w else nb
+                   for w in self.group_windows)
+
     def can_allocate(self, num_tokens: int) -> bool:
-        return self.blocks_for(num_tokens) <= self.free_blocks
+        return self.blocks_needed(num_tokens) <= self.free_blocks
 
     def fits_ever(self, num_tokens: int) -> bool:
         """Could this many tokens fit an EMPTY pool? (submit-time sanity)"""
-        return self.blocks_for(num_tokens) <= self.num_blocks - 1
+        return self.blocks_needed(num_tokens) <= self.num_blocks - 1
 
     def block_table(self, seq_id: str) -> List[int]:
-        return list(self._tables[seq_id])
+        """The first group's table (THE table of a one-group model; with
+        groups, the one whose length is the sequence's width)."""
+        return list(self._tables[seq_id][0])
+
+    def block_tables(self, seq_id: str) -> List[List[int]]:
+        """One table a group, all the same length."""
+        return [list(t) for t in self._tables[seq_id]]
+
+    def held_blocks(self, seq_id: str) -> List[int]:
+        """Blocks really held, a group."""
+        return [sum(1 for b in t if b != self.NULL_BLOCK)
+                for t in self._tables[seq_id]]
 
     def seq_len(self, seq_id: str) -> int:
         return self._lens[seq_id]
@@ -272,7 +344,13 @@ class KVBlockManager:
             if b not in protected:
                 del self._cached[b]
                 h = self._hash_of.pop(b)
-                del self._index[h]
+                for other in self._index.pop(h):
+                    # The entry is gone for every group: a copy that rests
+                    # cached is blank now, a live one just unregistered.
+                    if other != b and self._hash_of.pop(other, None) is not None \
+                            and other in self._cached:
+                        del self._cached[other]
+                        self._free.append(other)
                 self.evictions += 1
                 pending = self._pending_loads.pop(b, None)
                 if self._tier is not None and pending is None:
@@ -350,10 +428,11 @@ class KVBlockManager:
             raise ValueError("allocate needs >= 1 token")
         if token_ids is not None and len(token_ids) > num_tokens:
             raise ValueError("token_ids longer than the allocation")
-        need_total = self.blocks_for(num_tokens)
-        # Chain walk: per leading full block, an HBM index hit ("idx", b),
-        # a host-tier hit ("tier", h, bytes) — acquired below and loaded by
-        # the engine before its next kernel — or a miss (walk ends).
+        nb = self.blocks_for(num_tokens)
+        # Chain walk: per leading full block, an HBM index hit ("idx",
+        # blocks, one a group), a host-tier hit ("tier", h, bytes) — acquired
+        # below and loaded by the engine before its next kernel — or a miss
+        # (walk ends).
         walk: List[Tuple] = []
         chain: List[bytes] = []
         if self.caching and token_ids is not None and len(token_ids) > 1:
@@ -366,9 +445,9 @@ class KVBlockManager:
                     prev,
                     token_ids[i * self.block_size:(i + 1) * self.block_size],
                 )
-                b = self._index.get(h)
-                if b is not None:
-                    walk.append(("idx", b))
+                blocks = self._index.get(h)
+                if blocks is not None:
+                    walk.append(("idx", blocks))
                 elif self._tier is not None:
                     blob = self._tier.get(h)  # touches the tier's LRU
                     if blob is None:
@@ -382,7 +461,19 @@ class KVBlockManager:
             self.hits += len(walk)
             self.host_hits += sum(1 for w in walk if w[0] == "tier")
             self.misses += cacheable - len(walk)
-        idx_hits = [w[1] for w in walk if w[0] == "idx"]
+        cached_tokens = len(walk) * self.block_size
+        # What each group holds from the start: every block (global), or
+        # (window) the blocks from the first computed position's window up
+        # to that position; `slide` acquires the rest as the prefill reaches
+        # them. Hits outside a window group's span are not taken.
+        spans = [
+            self._span(w, cached_tokens, cached_tokens + 1 if w else num_tokens)
+            for w in self.group_windows
+        ]
+        idx_hits = [
+            w[1][g] for i, w in enumerate(walk) if w[0] == "idx"
+            for g, (lo, hi) in enumerate(spans) if lo <= i < hi
+        ]
         # Hits currently resting on the cached list are about to be revived —
         # they can't double as eviction fodder for our own fresh blocks
         # (COW-protected ones were never counted evictable to begin with).
@@ -392,39 +483,43 @@ class KVBlockManager:
             1 for b in idx_hits
             if b not in self._ref and b not in protected
         )
-        need_new = need_total - len(idx_hits)
+        # Asked of the pool: what the sequence holds at rest (`blocks_needed`,
+        # which is exactly the acquisition when no group slides).
+        need_new = max(sum(hi - lo for lo, hi in spans),
+                       self.blocks_needed(num_tokens)) - len(idx_hits)
         if need_new > len(self._free) + self._evictable() - reviving:
             raise KVCacheExhausted(
                 f"{need_new} blocks needed, "
                 f"{len(self._free) + self._evictable() - reviving} available"
             )
-        # Revive/share EVERY index hit first: a tier-hit acquisition below
+        # Revive/share EVERY index hit first: a fresh acquisition below
         # may evict from the cached list, and a hit resting there must not
         # be its victim.
-        for w in walk:
-            if w[0] == "idx":
-                self._incref(w[1])
-        table: List[int] = []
-        for w in walk:
-            if w[0] == "idx":
-                table.append(w[1])
-            else:
-                _, h, blob = w
-                nb = self._acquire()
-                self._ref[nb] = 1
-                self._index[h] = nb
-                self._hash_of[nb] = h
-                self._pending_loads[nb] = (h, blob, False)
-                table.append(nb)
-        for _ in range(need_total - len(walk)):
-            nb = self._acquire()
-            self._ref[nb] = 1
-            table.append(nb)
-        self._tables[seq_id] = table
+        for b in idx_hits:
+            self._incref(b)
+        tables: List[List[int]] = []
+        for g, (lo, hi) in enumerate(spans):
+            table = [self.NULL_BLOCK] * nb
+            for i in range(lo, hi):
+                w = walk[i] if i < len(walk) else None
+                if w is not None and w[0] == "idx":
+                    table[i] = w[1][g]
+                    continue
+                fresh = self._acquire()
+                self._ref[fresh] = 1
+                table[i] = fresh
+                if w is not None:       # a tier hit (one-group manager only)
+                    _, h, blob = w
+                    self._index[h] = (fresh,)
+                    self._hash_of[fresh] = h
+                    self._pending_loads[fresh] = (h, blob, False)
+            tables.append(table)
+        self._tables[seq_id] = tables
+        self._held_lo[seq_id] = [lo for lo, _ in spans]
         self._lens[seq_id] = num_tokens
         self._chain[seq_id] = chain
-        self._landed[seq_id] = len(walk) * self.block_size
-        return list(table), len(walk) * self.block_size
+        self._landed[seq_id] = cached_tokens
+        return list(tables[0]), cached_tokens
 
     def fork(self, parent_id: str, child_id: str) -> List[int]:
         """Share `parent_id`'s table up to its LANDED watermark with a new
@@ -446,15 +541,18 @@ class KVBlockManager:
         ids unknown) that was never advanced by `grow(..., num_computed=)`
         or `register_computed` has watermark 0 and shares NOTHING — the
         manager cannot tell its content from speculative garbage."""
+        if self._G > 1:
+            raise NotImplementedError("fork of a sequence over several KV groups")
         if child_id in self._tables:
             raise ValueError(f"sequence {child_id!r} already has an allocation")
-        table = self._tables[parent_id]  # KeyError = unknown parent
+        table = self._tables[parent_id][0]  # KeyError = unknown parent
         landed = self._landed.get(parent_id, 0)
         keep = min(self.blocks_for(landed), len(table))
         shared = table[:keep]
         for b in shared:
             self._incref(b)
-        self._tables[child_id] = list(shared)
+        self._tables[child_id] = [list(shared)]
+        self._held_lo[child_id] = [0]
         self._lens[child_id] = min(landed, self._lens[parent_id])
         chain = self._chain.get(parent_id, ())
         self._chain[child_id] = list(chain[:keep])
@@ -467,6 +565,7 @@ class KVBlockManager:
         new_len: int,
         token_ids: Optional[Sequence[int]] = None,
         num_computed: Optional[int] = None,
+        first_query: Optional[int] = None,
     ) -> List[int]:
         """Extend `seq_id`'s table to cover `new_len` tokens (decode append).
 
@@ -481,22 +580,30 @@ class KVBlockManager:
         `new_len` below the current coverage is a no-op on the table
         (registration still runs): a speculative grow funds draft slots the
         verify step may reject, so the NEXT step legitimately asks for less
-        than the table already covers."""
-        table = self._tables[seq_id]
+        than the table already covers.
+
+        With window groups, `first_query` is the position of the first
+        token the coming step computes (default: `num_computed`, else the
+        last covered position): after the global groups grew and the full
+        blocks were registered, each window group slides (`slide`)."""
+        tables = self._tables[seq_id]
         cur = self._lens[seq_id]
         if new_len < cur:
             new_len = cur
-        need = self.blocks_for(new_len) - len(table)
+        need = self.blocks_for(new_len) - len(tables[0])
         wi = cur // self.block_size      # block the next write lands in
+        keeps_all = [g for g, w in enumerate(self.group_windows) if not w]
         need_cow = int(
-            wi < len(table) and self._ref[table[wi]] > 1
+            self._G == 1 and wi < len(tables[0])
+            and self._ref.get(tables[0][wi], 0) > 1
         )
-        if need + need_cow > len(self._free) + self._evictable():
+        if need * len(keeps_all) + need_cow > len(self._free) + self._evictable():
             raise KVCacheExhausted(
-                f"{need + need_cow} blocks needed, "
+                f"{need * len(keeps_all) + need_cow} blocks needed, "
                 f"{len(self._free) + self._evictable()} free"
             )
         if need_cow:
+            table = tables[0]
             src = table[wi]
             dst = self._acquire()
             self._ref[dst] = 1
@@ -504,14 +611,73 @@ class KVBlockManager:
             table[wi] = dst
             self._release_one(src)   # still held by the other owner(s)
             self.cow_copies += 1
-        for _ in range(need):
-            nb = self._acquire()
-            self._ref[nb] = 1
-            table.append(nb)
+        for g, table in enumerate(tables):
+            for _ in range(need):
+                if g in keeps_all:
+                    nb = self._acquire()
+                    self._ref[nb] = 1
+                    table.append(nb)
+                else:
+                    table.append(self.NULL_BLOCK)   # `slide` fills its span
         self._lens[seq_id] = new_len
         if token_ids is not None and num_computed is not None:
             self.register_computed(seq_id, token_ids, num_computed)
-        return list(table)
+        if self._sliding:
+            if first_query is None:
+                first_query = num_computed if num_computed is not None else cur - 1
+            self.slide(seq_id, first_query, new_len)
+        return list(tables[0])
+
+    def slide(self, seq_id: str, first_query: int, upto: int) -> int:
+        """Move `seq_id`'s window groups forward, before a program whose
+        first query sits at position `first_query` and which writes
+        positions below `upto`: every block wholly behind the window of
+        `first_query` is RELEASED (no later query can see it; a registered
+        one rests on the cached list, its rows still that prefix's), then
+        the blocks up to `upto` are acquired. The releases stand even when
+        the acquisition raises KVCacheExhausted (the scheduler preempts and
+        asks again). Returns the blocks released. Nothing to do for a model
+        without window groups."""
+        if not self._sliding:
+            return 0
+        released = self._release_behind(seq_id, first_query)
+        tables, held_lo = self._tables[seq_id], self._held_lo[seq_id]
+        upto = min(upto, self._lens[seq_id])
+        want = []
+        for g, w in enumerate(self.group_windows):
+            if w:
+                _, hi = self._span(w, first_query, upto)
+                want += [(tables[g], i) for i in range(held_lo[g], hi)
+                         if tables[g][i] == self.NULL_BLOCK]
+        if len(want) > len(self._free) + self._evictable():
+            raise KVCacheExhausted(
+                f"{len(want)} window blocks needed, "
+                f"{len(self._free) + self._evictable()} free"
+            )
+        for table, i in want:
+            nb = self._acquire()
+            self._ref[nb] = 1
+            table[i] = nb
+        return released
+
+    def _release_behind(self, seq_id: str, first_query: int) -> int:
+        """Give back every window-group block no query at or after
+        `first_query` can see."""
+        tables, held_lo = self._tables[seq_id], self._held_lo[seq_id]
+        released = 0
+        for g, w in enumerate(self.group_windows):
+            if not w:
+                continue
+            table = tables[g]
+            lo, _ = self._span(w, first_query, first_query)
+            for i in range(held_lo[g], min(lo, len(table))):
+                if table[i] != self.NULL_BLOCK:
+                    self._release_one(table[i])
+                    table[i] = self.NULL_BLOCK
+                    released += 1
+            held_lo[g] = max(held_lo[g], lo)
+        self.window_released += released
+        return released
 
     def register_computed(
         self,
@@ -526,37 +692,56 @@ class KVBlockManager:
 
         If a block's key already has a canonical twin (same content computed
         by an earlier sequence), this table adopts the twin and releases its
-        own copy — identical prefixes converge to identical tables."""
+        own copy — identical prefixes converge to identical tables.
+
+        With window groups, positions below `num_computed` being landed
+        means the next query sits at or after it: once the full blocks are
+        registered, the blocks behind its window are released, so that at
+        rest a sequence holds the window and at most one block more."""
         landed = min(num_computed, len(token_ids))
         if landed > self._landed.get(seq_id, 0):
             self._landed[seq_id] = landed
+        self._register_full_blocks(seq_id, token_ids, num_computed)
+        if self._sliding:
+            self._release_behind(seq_id, landed)
+
+    def _register_full_blocks(self, seq_id, token_ids, num_computed) -> None:
         if not self.caching:
             return
         chain = self._chain.setdefault(seq_id, [])
-        table = self._tables[seq_id]
+        tables = self._tables[seq_id]
         full = min(num_computed, len(token_ids)) // self.block_size
         while len(chain) < full:
             i = len(chain)
+            mine = tuple(t[i] for t in tables)
+            if self.NULL_BLOCK in mine:
+                # A group no longer holds this block (it slid past before
+                # the block could be registered): the chain ends here.
+                break
             prev = chain[-1] if chain else b""
             h = _chain_hash(
                 prev, token_ids[i * self.block_size:(i + 1) * self.block_size]
             )
-            b = table[i]
             canon = self._index.get(h)
-            if canon is not None and canon != b:
-                self._incref(canon)
-                table[i] = canon
-                self._release_one(b)
+            if canon is not None and canon != mine:
+                for table, b, c in zip(tables, mine, canon):
+                    if b != c:
+                        self._incref(c)
+                        table[i] = c
+                        self._release_one(b)
             elif canon is None:
-                self._index[h] = b
-                self._hash_of[b] = h
+                self._index[h] = mine
+                for b in mine:
+                    self._hash_of[b] = h
             self._touch_hot(h)
             chain.append(h)
 
     # ----------------------------------------------------- tier / transfer
     def holds(self, h: bytes) -> Optional[int]:
-        """Physical block registered under content hash `h`, or None."""
-        return self._index.get(h)
+        """Physical block registered under content hash `h` (the first
+        group's), or None."""
+        blocks = self._index.get(h)
+        return None if blocks is None else blocks[0]
 
     def adopt_block(self, h: bytes, blob) -> Optional[int]:
         """Adopt externally-computed KV content (a remote replica's export,
@@ -565,13 +750,15 @@ class KVBlockManager:
         as a pending LOAD the engine lands before its next kernel. Returns
         the block, or None when the pool has nothing to give (the import
         degrades to recompute — never an error)."""
+        if self._G > 1:
+            raise NotImplementedError("adopt_block over several KV groups")
         if not self.caching or h in self._index:
             return None
         try:
             b = self._acquire()
         except KVCacheExhausted:
             return None
-        self._index[h] = b
+        self._index[h] = (b,)
         self._hash_of[b] = h
         self._cached[b] = None  # ref 0, content retained, MRU end
         self._pending_loads[b] = (h, blob, True)
@@ -584,9 +771,11 @@ class KVBlockManager:
         ("blob", bytes) for content still in flight (pending load) or only
         host-tier-resident, None when nowhere. The engine reads HBM sources
         at a step boundary, where the arrays are stable."""
+        if self._G > 1:
+            raise NotImplementedError("export_sources over several KV groups")
         out: List[Optional[Tuple]] = []
         for h in digests:
-            b = self._index.get(h)
+            b = self.holds(h)
             if b is not None:
                 pending = self._pending_loads.get(b)
                 if pending is not None and pending[0] == h:
@@ -633,13 +822,15 @@ class KVBlockManager:
         (full, hashed) blocks, which are RETAINED on the cached LRU list to
         serve future prefix hits until evicted. Raises KeyError on an
         unknown (or already-freed) seq_id — the double-free guard."""
-        table = self._tables.pop(seq_id)  # KeyError = double free
+        tables = self._tables.pop(seq_id)  # KeyError = double free
         del self._lens[seq_id]
+        del self._held_lo[seq_id]
         self._chain.pop(seq_id, None)
         self._landed.pop(seq_id, None)
-        for b in table:
+        held = [b for t in tables for b in t if b != self.NULL_BLOCK]
+        for b in held:
             self._release_one(b)
-        return len(table)
+        return len(held)
 
     def check_invariants(self) -> None:
         """Every block is in exactly one place (free xor cached xor live),
@@ -654,27 +845,39 @@ class KVBlockManager:
             assert b not in self._ref, f"cached block {b} has live refs"
             seen.add(b)
         refs: Dict[int, int] = {}
-        for sid, table in self._tables.items():
-            assert len(table) == self.blocks_for(self._lens[sid]), (
-                f"{sid!r}: table/len mismatch"
-            )
-            assert len(self._chain.get(sid, ())) <= len(table), (
-                f"{sid!r}: more registered blocks than table entries"
-            )
-            for b in table:
-                assert b not in self._free and b not in self._cached, (
-                    f"block {b} live AND free/cached"
+        for sid, tables in self._tables.items():
+            assert len(tables) == self._G, f"{sid!r}: {len(tables)} tables"
+            for w, table in zip(self.group_windows, tables):
+                assert len(table) == self.blocks_for(self._lens[sid]), (
+                    f"{sid!r}: table/len mismatch"
                 )
-                refs[b] = refs.get(b, 0) + 1
+                assert len(self._chain.get(sid, ())) <= len(table), (
+                    f"{sid!r}: more registered blocks than table entries"
+                )
+                held = [i for i, b in enumerate(table) if b != self.NULL_BLOCK]
+                assert w or len(held) == len(table), (
+                    f"{sid!r}: a global group lost a block"
+                )
+                assert not held or held == list(range(held[0], held[-1] + 1)), (
+                    f"{sid!r}: a window group's blocks are not one run"
+                )
+                for i in held:
+                    b = table[i]
+                    assert b not in self._free and b not in self._cached, (
+                        f"block {b} live AND free/cached"
+                    )
+                    refs[b] = refs.get(b, 0) + 1
         assert refs == self._ref, (
             f"refcount drift: counted {refs}, recorded {self._ref}"
         )
         seen.update(refs)
         assert len(seen) == self.num_blocks - 1, "lost/leaked blocks"
-        for h, b in self._index.items():
-            assert self._hash_of.get(b) == h, f"index/hash_of drift on block {b}"
+        for h, blocks in self._index.items():
+            assert len(blocks) == self._G, f"index entry of {len(blocks)} blocks"
+            for b in blocks:
+                assert self._hash_of.get(b) == h, f"index/hash_of drift on block {b}"
         for b, h in self._hash_of.items():
-            assert self._index.get(h) == b, f"hash_of/index drift on block {b}"
+            assert b in self._index.get(h, ()), f"hash_of/index drift on block {b}"
         for sid, landed in self._landed.items():
             assert landed <= self._lens[sid], (
                 f"{sid!r}: landed watermark {landed} past allocation "

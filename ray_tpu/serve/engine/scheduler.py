@@ -280,7 +280,8 @@ class Scheduler:
             while True:
                 try:
                     self.kv.grow(
-                        seq.request_id, seq.num_tokens + 1 + len(d), **reg
+                        seq.request_id, seq.num_tokens + 1 + len(d),
+                        first_query=seq.num_tokens - 1, **reg
                     )
                     break
                 except KVCacheExhausted:
@@ -314,12 +315,18 @@ class Scheduler:
 
         # 2. Continue in-flight partial prefills (admission order) before
         # admitting anyone new — their blocks are already committed.
-        for seq in self.running:
+        # A window group's blocks for the chunk are acquired here (and the
+        # ones behind its window released): exhaustion preempts the youngest.
+        for seq in list(self.running):
             if len(prefills) >= self.max_prefills_per_step or budget <= 0:
                 break
             if seq.state != RUNNING or seq.is_decoding:
                 continue
-            chunk = self._chunk_for(seq, budget)
+            chunk = self._fit_chunk(seq, budget, preempted)
+            if chunk is None:           # not even one token's blocks
+                self._preempt(seq)
+                preempted.append(seq)
+                continue
             prefills.append(chunk)
             budget -= chunk.num_tokens
 
@@ -342,15 +349,24 @@ class Scheduler:
                 )
             except KVCacheExhausted:
                 break  # stays queued — refusal, not failure
+            seq.num_computed = cached
+            chunk = self._fit_chunk(seq, budget, None)
+            if chunk is None:
+                self.kv.free(seq.request_id)
+                seq.num_computed = 0
+                break  # stays queued
             self.waiting.popleft()
             seq.state = RUNNING
-            seq.num_computed = cached
             seq.num_cached = cached
             self.running.append(seq)
-            chunk = self._chunk_for(seq, budget)
             prefills.append(chunk)
             budget -= chunk.num_tokens
 
+        # A chunk's window blocks may have preempted a lane or a chunk that
+        # was already on this step's work order.
+        if preempted:
+            decodes = [s for s in decodes if s.state == RUNNING]
+            prefills = [c for c in prefills if c.seq.state == RUNNING]
         # A lane preempted AFTER its draft was funded must not leak a stale
         # drafts entry into the work order.
         if drafts:
@@ -370,6 +386,31 @@ class Scheduler:
             width_bucket=_next_pow2(max_w) if max_w else 0,
             drafts=drafts,
         )
+
+    def _fit_chunk(self, seq: Sequence, budget: int,
+                   preempted: Optional[List[Sequence]]) -> Optional[PrefillChunk]:
+        """The sequence's next chunk with its window groups moved to it
+        (`KVBlockManager.slide`: the blocks behind the window released, the
+        chunk's acquired). When the pool cannot give the chunk's blocks:
+        preempt the youngest OTHER sequence (never for a new admission:
+        `preempted` None), then halve the chunk; None when not even one
+        token's blocks can be had. A model without window groups always
+        gets its chunk: its blocks were all acquired at admission."""
+        chunk = self._chunk_for(seq, budget)
+        while True:
+            try:
+                self.kv.slide(seq.request_id, chunk.start,
+                              chunk.start + chunk.num_tokens)
+                return chunk
+            except KVCacheExhausted:
+                victim = None if preempted is None else self._pick_victim(exclude=seq)
+                if victim is not None:
+                    self._preempt(victim)
+                    preempted.append(victim)
+                elif chunk.num_tokens > 1:
+                    chunk = self._chunk_for(seq, chunk.num_tokens // 2)
+                else:
+                    return None
 
     def _pick_victim(self, exclude: Sequence) -> Optional[Sequence]:
         for seq in reversed(self.running):  # youngest first

@@ -1,7 +1,8 @@
 """Compile-only rehearsal of the three paged programs, without the chip:
 `JAX_PLATFORMS=cpu python3 -m scripts.paged_rehearse --model gpt2-large
 --num-blocks 1024 --block-size 16 [--lanes 8] [--width 16] [--chunk 64]
-[--spec 4] [--n-layers N]` from the root of a checkout.
+[--spec 4] [--n-layers N]` from the root of a checkout (a model whose
+layers form several KV groups gets one block table a group).
 
 Compiles `decode_step_paged`, `prefill_paged` and `verify_step_paged` of
 `models/gpt.py` for one described (not attached) `v5e:2x2` device, jitted
@@ -80,14 +81,17 @@ def big_ops(hlo_text: str, min_bytes: int):
 
 
 def rehearse(cfg, device, num_blocks: int, block_size: int, lanes: int = 8,
-             width: int = 16, chunk: int = 64, spec: int = 4) -> dict:
+             width: int = 16, chunk: int = 64, spec: int = 4,
+             init: bool = False) -> dict:
     """Compile the three paged programs of `cfg` for `device` (a described
-    device of `jax.experimental.topologies`) at one shape bucket each."""
+    device of `jax.experimental.topologies`) at one shape bucket each; with
+    `init`, also `init_params` under one jit (what making the weights in a
+    stated dtype keeps beside them)."""
     import jax
     import jax.numpy as jnp
     from jax.sharding import SingleDeviceSharding
 
-    from ray_tpu.models.gpt import init_paged_cache, init_params
+    from ray_tpu.models.gpt import init_paged_cache, init_params, kv_layout
     from ray_tpu.serve.engine.engine import _paged_jits
 
     one_chip = SingleDeviceSharding(device)
@@ -106,20 +110,27 @@ def rehearse(cfg, device, num_blocks: int, block_size: int, lanes: int = 8,
         return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one_chip)
 
     prefill, decode, verify = _paged_jits()
+    groups = len(kv_layout(cfg).windows)    # one block table a KV group
+    table = (width,) if groups == 1 else (groups, width)
     programs = {
         "decode_step_paged": lambda: decode.lower(
-            params, i32(lanes), i32(lanes), i32(lanes, width), kv, cfg),
+            params, i32(lanes), i32(lanes), i32(lanes, *table), kv, cfg),
         "prefill_paged": lambda: prefill.lower(
-            params, i32(1, chunk), i32(), i32(), i32(width), kv, cfg),
+            params, i32(1, chunk), i32(), i32(), i32(*table), kv, cfg),
         "verify_step_paged": lambda: verify.lower(
             params, i32(lanes, spec + 1), i32(lanes), i32(lanes),
-            i32(lanes, width), kv, cfg),
+            i32(lanes, *table), kv, cfg),
     }
-    layer_pool = kv["k"].size // cfg.n_layers * kv["k"].dtype.itemsize
+    if init:
+        programs["init_params"] = lambda: jax.jit(
+            lambda k: init_params(k, cfg)).lower(
+                jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=one_chip))
+    layer_pool = kv["k"].size // kv["k"].shape[0] * kv["k"].dtype.itemsize
     report = {
         "n_layers": cfg.n_layers,
         "pool_shape": list(kv["k"].shape), "pool_dtype": str(kv["k"].dtype),
-        "pool_GiB": 2 * cfg.n_layers * layer_pool / 2**30,
+        "pool_GiB": 2 * kv["k"].shape[0] * layer_pool / 2**30,
+        "weights_GiB": sum(a.size * a.dtype.itemsize for a in params.values()) / 2**30,
         "layer_pool_MiB": layer_pool / 2**20,
         "lanes": lanes, "width": width, "chunk": chunk, "spec": spec,
         "programs": {},
@@ -173,7 +184,7 @@ def main() -> int:
     cfg = CONFIGS[a.model](**overrides, remat=False, remat_policy=None)
     topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
     report = rehearse(cfg, topo.devices[0], a.num_blocks, a.block_size,
-                      a.lanes, a.width, a.chunk, a.spec)
+                      a.lanes, a.width, a.chunk, a.spec, init=True)
     print(json.dumps({"model": a.model, **report}))
     return 0
 

@@ -1,0 +1,141 @@
+"""The readings behind `token_tolerance` of `smallthinker-21b-a3b`
+(`benchmarks/configs/smallthinker-21b-a3b.json`), taken on the chip at the
+published widths, in one process: `python3 -m scripts.smallthinker_tolerance
+[--seeds 3000000001,3000000002] [--parts wrong,float8]`.
+
+Every reading is the number the benchmark itself would print:
+`benchmarks.runners.serve.BenchReplica.bench_check_tokens`, the harness's own
+function, called on a stand-in that holds what it reads of a replica (the
+parameter tree and `generate`), with the cell's own engine options, prompt
+length and count of new tokens. For each seed:
+
+- `sound`: the engine's greedy tokens (chunked paged prefill, then paged
+  decode) held to the plain float32 reference;
+- `wrong`: the same engine held to four WRONG references, which a sound
+  program must fail: top-(k-1) routing, no window, a window one block too
+  wide, rotary positions on the layers that have none;
+- `float8`: the engine serving the weights rounded to float8's mantissa
+  (e4m3: three bits; the nearest precision below the bfloat16 the
+  configuration states), held to the reference with the weights as they are.
+
+On the CPU (`--rehearse`) the same at the configuration's tiny preset:
+control flow only."""
+
+from __future__ import annotations
+
+import argparse
+import json
+import sys
+import time
+
+
+class _Held:
+    """What `bench_check_tokens` reads of a replica."""
+
+    def __init__(self, params, generate):
+        self._params, self._phases, self._generate, self.tokens = params, {}, generate, None
+
+    def generate(self, prompt, new_tokens):
+        self.tokens = self._generate(prompt, new_tokens)
+        return {"tokens": self.tokens}
+
+
+def main(argv=None) -> int:
+    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    ap.add_argument("--seeds", default="3000000001")
+    ap.add_argument("--rehearse", action="store_true")
+    ap.add_argument("--parts", default="wrong,float8",
+                    help="what to read beside the sound engine's own token error: "
+                         "wrong (all four wrong references) or their names, float8")
+    a = ap.parse_args(argv)
+    parts = set(a.parts.split(","))
+    import jax
+
+    from benchmarks import harness
+    from benchmarks.runners.serve import BenchReplica
+    from ray_tpu.models import gpt
+    from ray_tpu.serve.engine import EngineOptions, InferenceEngine
+
+    config = harness.load_json(harness.ROOT, "benchmarks/configs/smallthinker-21b-a3b.json")
+    arch = harness.arch(config["arch"])
+    m = arch.dims(config, a.rehearse)
+    part = config["rehearsal"]["requests"] if a.rehearse else config["runners"]["requests"]
+    opts = EngineOptions(**part["engine_options"])
+    n_prompt, n_new = part["token_check"]["prompt_len"], part["token_check"]["new_tokens"]
+    name, overrides = arch.program(config, m)
+    cfg = gpt.CONFIGS[name](**overrides)
+    init = jax.jit(lambda k: gpt.init_params(k, cfg))
+    dev = jax.devices()[0]
+    wrongs = {
+        "top_k_minus_one": {"top_k": m["top_k"] - 1},
+        "window_off": {"window_layout": [0] * m["n_layers"]},
+        "window_one_block_wide": {"window": m["window"] + opts.block_size},
+        "rope_on_nope_layers": {"rope_layout": [1] * m["n_layers"]},
+    }
+
+    def check(held, seed, dims=m):
+        err, agree = BenchReplica.bench_check_tokens(
+            held, config["arch"], dims, seed, n_prompt, n_new)
+        return {"token_err": err, "argmax_agree": agree}
+
+    rows = []
+    for seed in (int(s) for s in a.seeds.split(",")):
+        t0 = time.perf_counter()
+        params = init(jax.random.PRNGKey(harness.key_seed(seed)))
+        eng = InferenceEngine(cfg, params=params, options=opts)
+        eng.start()
+        held = _Held(params, eng.generate)
+        row = {"seed": seed, "sound": check(held, seed)}
+        row["distinct_tokens"] = len(set(held.tokens))
+        row["window_blocks_released"] = eng.stats()["window_blocks_released"]
+        for wname, change in wrongs.items():
+            if wname in parts or "wrong" in parts:
+                row[wname] = check(held, seed, {**m, **change})
+        eng.shutdown()
+        del eng, held
+        if "float8" in parts:
+            eng = InferenceEngine(cfg, params=degrade(params), options=opts)
+            eng.start()
+            params = None
+            low = eng.generate(_prompt(seed, m, n_prompt), n_new)
+            eng.shutdown()
+            del eng      # and with it the rounded tree and its pool
+            held = _Held(init(jax.random.PRNGKey(harness.key_seed(seed))),
+                         lambda prompt, n: low)
+            row["float8_weights"] = check(held, seed)
+            del held
+        params = None       # one tree at a time fits the chip
+        row["seconds"] = time.perf_counter() - t0
+        rows.append(row)
+        print(json.dumps(row), flush=True)
+    print(json.dumps({"device": {"platform": dev.platform, "kind": dev.device_kind},
+                      "prompt_len": n_prompt, "new_tokens": n_new,
+                      "token_tolerance": config["runners"]["requests"]["token_tolerance"],
+                      "rows": rows}))
+    return 0
+
+
+def _prompt(seed, m, n_prompt):
+    """The check's own prompt (`bench_check_tokens` draws it so)."""
+    import numpy as np
+
+    return np.random.default_rng([seed, 1]).integers(1, m["vocab_size"], n_prompt).tolist()
+
+
+def degrade(params):
+    """The tree with every matrix rounded to float8's mantissa (e4m3: 1 + 3
+    bits; the device widens a real float8 convert away), in place."""
+    import jax
+    import jax.numpy as jnp
+
+    def q(w):
+        if w.ndim < 2:
+            return w
+        frac, exp = jnp.frexp(w.astype(jnp.float32))
+        return jnp.ldexp(jnp.round(frac * 16.0) / 16.0, exp).astype(w.dtype)
+
+    return jax.jit(lambda t: {k: q(v) for k, v in t.items()}, donate_argnums=0)(params)
+
+
+if __name__ == "__main__":
+    sys.exit(main())

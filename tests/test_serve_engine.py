@@ -1048,6 +1048,39 @@ def test_paged_programs_compile_for_v5e_without_a_pool_copy(v5e_chip):
         assert not moved, (name, moved)
 
 
+def test_two_kinds_of_layer_compile_for_v5e_over_one_pool_in_place(v5e_chip):
+    """Compile-only, at SmallThinker's published widths (one period of four
+    layers, the whole vocabulary, a pool of 1,024 blocks of 64 tokens): the
+    pool [layers a group, NB, BS, 512] is still the scan's in-place carry
+    with a table a group, a window layer's branch gathers its 65 blocks and
+    not the lane's 256, and a decode step of a few lanes keeps the expert
+    stacks whole (no copy of a layer's experts into the loop)."""
+    from ray_tpu.models.gpt import CONFIGS, kv_layout
+    from scripts.paged_rehearse import rehearse
+
+    cfg = CONFIGS["smallthinker-21b-a3b"](
+        n_layers=4, rope_layout=(0, 1, 1, 1), sliding_window_layout=(0, 1, 1, 1),
+        remat=False)
+    assert kv_layout(cfg).per_group == 1 and len(kv_layout(cfg).windows) == 4
+    report = _within(400, lambda: rehearse(
+        cfg, v5e_chip, 1024, 64, lanes=4, width=256, chunk=512, spec=2))
+    pool_bytes = report["pool_GiB"] * 2**30
+    assert report["pool_shape"] == [1, 1024, 64, 512]
+    for name, prog in report["programs"].items():
+        assert "refused" not in prog, (name, prog)
+        assert prog["alias_GiB"] * 2**30 >= pool_bytes, f"{name}: not donated"
+        assert prog["temp_GiB"] < 1.5, (name, prog)
+        moved = [o for o in prog["pool_sized_ops"]
+                 if "copy" in o["op"] or "transpose" in o["op"]
+                 or "64,2560,768]" in o["result"] or "64,768,2560]" in o["result"]]
+        assert not moved, (name, moved)
+    # a 512-token chunk's scores: [4 K/V heads, 7 x 512 queries, keys]: the
+    # whole 16,384-token table on a global layer, 73 blocks on a window layer
+    scores = [o["result"] for o in report["programs"]["prefill_paged"]["pool_sized_ops"]
+              if o["result"].startswith("f32[4,3584,")]
+    assert any("3584,16384]" in r for r in scores) and any("3584,4672]" in r for r in scores)
+
+
 # ------------------------------------------------- serve data-plane wiring
 @pytest.fixture
 def serve_instance():
