@@ -25,6 +25,7 @@ from typing import Any, Callable, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from ..ops import apply_rope, flash_attention, layernorm, ring_attention, rmsnorm, rope_frequencies
 from ..ops.attention import attention_reference, ulysses_attention
@@ -1373,6 +1374,44 @@ def _rope_qk(cfg: GPTConfig, q, k, rope_tables, positions):
     return _rope_rotate(q, c, s), _rope_rotate(k, c, s)
 
 
+# Keys one trip of the paged key loop covers (`_paged_layers`). A table of
+# at most this many keys is attended in one shot, with no loop at all. Set
+# on the chip (PERF.md §6, PR 29); a constant, not a field of `GPTConfig`.
+_ATTN_TILE_KEYS = 1024
+
+
+def paged_attn_tiling(width: int, block_size: int) -> Tuple[int, int]:
+    """(blocks a tile, tiles) into which `_paged_layers` cuts a block table
+    `width` blocks wide: from static shapes alone."""
+    tile = max(1, _ATTN_TILE_KEYS // block_size)
+    return (width, 1) if width <= tile else (tile, -(-width // tile))
+
+
+def paged_attn_trips(xp, first_pos, last_pos, real, window, tile_keys, tiles):
+    """Run-time bounds of the key loop over a table of `tiles` tiles of
+    `tile_keys` keys: (each lane's first tile [B], trips). Lane b's real
+    queries lie at positions first_pos[b]..last_pos[b] and see no key
+    outside tiles first[b]..first[b] + trips - 1: its last tile holds
+    last_pos[b], its first one first_pos[b] - window + 1 (tile 0 under a
+    global layer's `_NO_WINDOW`), and the trips are the most any `real`
+    lane needs. `xp` is `jax.numpy` inside the program and `numpy` on the
+    host, which counts with the same arithmetic (`paged_attn_keys`)."""
+    last = xp.minimum(last_pos // tile_keys, tiles - 1)
+    first = xp.clip((first_pos - window + 1) // tile_keys, 0, last)
+    return first, xp.where(real, last - first + 1, 1).max()
+
+
+def paged_attn_keys(lanes: int, width: int, block_size: int, last_pos, real):
+    """What one dispatched paged program computes attention over in a
+    global layer, counted on the host (numpy [B] arguments as
+    `paged_attn_trips` takes them): (keys its bounds cover = lanes x trips
+    x tile, keys of the padded tables = lanes x width x block_size)."""
+    tile, tiles = paged_attn_tiling(width, block_size)
+    _, trips = paged_attn_trips(    # no window: where a lane's queries start is moot
+        np, last_pos, last_pos, real, _NO_WINDOW, tile * block_size, tiles)
+    return lanes * int(trips) * tile * block_size, lanes * width * block_size
+
+
 def _paged_layers(params, tokens, pos, valid, block_tables, kv, cfg: GPTConfig):
     """Embedding and the layer loop of the three paged programs: lane b
     brings S new tokens, token j at global position pos[b, j], over its
@@ -1385,21 +1424,29 @@ def _paged_layers(params, tokens, pos, valid, block_tables, kv, cfg: GPTConfig):
     groups (`kv_layout`): one table a group, every one W wide, a released
     or not yet allocated entry pointing at the null block. Each layer
     writes the new tokens' K/V rows in place FIRST, then attends causally
-    over the gathered table history (query j sees columns 0..pos[b, j]; on
-    a window layer only those above pos[b, j] - window, which is what
-    keeps the null block's rows out) — cached prefix, earlier chunks and
-    the new tokens themselves all come back through one path. The pool
-    rides the scan as its carry, indexed by the layer's slot; the stacked
-    weights and the layer's kind (rotary or not, its window, its group) are
-    the xs. K/V heads are shared by groups of query heads by folding the
-    group into the query axis, so multi-head attention is the same
-    operations with a group of one. Where a lane's table is wider than the
-    window and the step's tokens, a window layer takes the branch that
-    gathers only the blocks from its window's first one on
-    (`attend_window`). An expert MLP is the dropless layer of
-    `_dropless_mlp`. Returns (hidden states [B, S, E] before the final
-    norm, kv, None or the mean over layers of (experts touched, busiest
-    expert's share) [2] f32)."""
+    over the table's history (query j sees columns 0..pos[b, j]; on a
+    window layer only those above pos[b, j] - window, which is what keeps
+    the null block's rows out) — cached prefix, earlier chunks and the new
+    tokens themselves all come back through one path. The pool rides the
+    scan as its carry, indexed by the layer's slot; the stacked weights
+    and the layer's kind (rotary or not, its window, its group) are the
+    xs. K/V heads are shared by groups of query heads by folding the group
+    into the query axis, so multi-head attention is the same operations
+    with a group of one.
+
+    Attention runs over the keys the step can see, not the table's padded
+    width. A table of one tile (`paged_attn_tiling`) is gathered whole and
+    soft-maxed in one shot. A wider one is a loop over key tiles with an
+    online softmax (running maximum, sum and accumulator in float32), each
+    trip gathering only its tile's blocks through the table; the loop's
+    bounds are run-time values from the step's positions and the layer's
+    window (`paged_attn_trips`: padding lanes and invalid slots take no
+    part), so shapes and program keys depend on (S, W) alone. The mask, the
+    same in both forms, decides what a query sees; the bounds only skip
+    tiles in which it is false everywhere. An expert MLP is the dropless
+    layer of `_dropless_mlp`. Returns (hidden states [B, S, E] before the
+    final norm, kv, None or the mean over layers of (experts touched,
+    busiest expert's share) [2] f32)."""
     moe = cfg.mlp_type == "moe"
     if moe and cfg.moe_routing != "dropless":
         raise NotImplementedError(
@@ -1415,7 +1462,6 @@ def _paged_layers(params, tokens, pos, valid, block_tables, kv, cfg: GPTConfig):
     B, S = tokens.shape
     W = block_tables.shape[-1]
     BS = kv["k"].shape[2]
-    M = W * BS
     H, Hkv, Dh = cfg.n_heads, cfg.kv_heads, cfg.d_head
     R = H // Hkv
     scale = 1.0 / math.sqrt(Dh)
@@ -1435,59 +1481,78 @@ def _paged_layers(params, tokens, pos, valid, block_tables, kv, cfg: GPTConfig):
 
     phys = physical(block_tables) if G == 1 else None
     off = pos % BS
-    kpos = jnp.arange(M)[None, None, None, :]
     qpos = pos[:, None, :, None]
-    seen = kpos <= qpos                                # [B, 1, S, M]
     layer_stack = {k: params[k] for k in _LAYER_KEYS if k in params}
     kinds = _layer_kind_xs(cfg)
     if kinds is not None:
         kinds["group"] = jnp.asarray(lay.group_of, jnp.int32)
         kinds["slot"] = jnp.asarray(lay.slot_of, jnp.int32)
-    # A step of a few tokens reads only the experts they chose: the expert
-    # stacks then stay whole (a slice the scan cuts would be copied into the
-    # inner loop) and the layer number finds the expert where it lies.
-    # A window layer reads no further back than its window: where the lane's
-    # table is wider than window + S tokens it gathers only the `narrow`
-    # blocks from the window's first one on (the layer's kind picks the
-    # branch at run time; shapes depend on W, S and the config alone, so
-    # the program's key does not change).
-    narrow = W
-    if kinds is not None and cfg.sliding_window:
-        narrow = min(W, (cfg.sliding_window + S - 2) // BS + 2)
+    TB, NT = paged_attn_tiling(W, BS)
+    T = TB * BS                                        # keys a tile
+    # [B, S]: a real lane's valid slots (a padding lane's table is all null)
+    real = jnp.broadcast_to(jnp.logical_and(valid, (block_tables != 0).any(
+        axis=tuple(range(1, block_tables.ndim)))[:, None]), (B, S))
+    if NT == 1:
+        kpos = jnp.arange(T)[None, None, None, :]
+        seen = kpos <= qpos                            # [B, 1, S, W*BS]
+    else:
+        first_pos = jnp.where(real, pos, _NO_WINDOW).min(axis=1)
+        last_pos = jnp.where(real, pos, 0).max(axis=1)
+        real_lane = real.any(axis=1)
 
-    def attend(q, gk, gv, mask):
-        """q [B, Hkv, R*S, Dh] over gathered rows [B, T, Hkv, Dh]."""
+    def scores_of(q, kk, vv, slot, blocks, kp, seen, window):
+        """Masked float32 scores [B, Hkv, R*S, n*BS] of q [B, Hkv, R*S, Dh]
+        against the rows of `blocks` [B, n] (key positions `kp`), and those
+        blocks' V rows [B, n*BS, Hkv, Dh]."""
+        gk = kk[slot, blocks].reshape(B, -1, Hkv, Dh)
+        gv = vv[slot, blocks].reshape(B, -1, Hkv, Dh)
+        mask = seen if window is None else seen & (kp > qpos - window)
         if R > 1:   # the R query heads of a K/V head ride its query axis
             mask = jnp.tile(mask, (1, 1, R, 1))
         scores = jnp.einsum(
             "bhsd,bthd->bhst", q, gk, preferred_element_type=jnp.float32
-        ) * scale                                      # [B, Hkv, R*S, T]
-        probs = jax.nn.softmax(jnp.where(mask, scores, -1e30), axis=-1)
-        return jnp.einsum("bhst,bthd->bhsd", probs.astype(gv.dtype), gv)
+        ) * scale
+        return jnp.where(mask, scores, -1e30), gv
 
-    def attend_table(q, kk, vv, slot, table, window):
-        # Each lane's history: [B, W, BS, Hkv*Dh] -> [B, W*BS, Hkv, Dh].
-        gk = kk[slot, table].reshape(B, M, Hkv, Dh)
-        gv = vv[slot, table].reshape(B, M, Hkv, Dh)
-        mask = seen if window is None else seen & (kpos > qpos - window)
-        return attend(q, gk, gv, mask)
+    def attend(q, kk, vv, slot, table, window):
+        if NT == 1:
+            scores, gv = scores_of(q, kk, vv, slot, table, kpos, seen, window)
+            probs = jax.nn.softmax(scores, axis=-1)
+            return jnp.einsum("bhst,bthd->bhsd", probs.astype(gv.dtype), gv)
+        first, trips = paged_attn_trips(
+            jnp, first_pos, last_pos, real_lane,
+            _NO_WINDOW if window is None else window, T, NT)
+        table = jnp.pad(table, ((0, 0), (0, NT * TB - W)))
 
-    def attend_window(q, kk, vv, slot, table, window):
-        first = jnp.clip((pos[:, :1] - window + 1) // BS, 0, W - narrow)
-        idx = first + jnp.arange(narrow)[None]         # [B, narrow] table columns
-        blocks = jnp.take_along_axis(table, idx, axis=1)
-        gk = kk[slot, blocks].reshape(B, narrow * BS, Hkv, Dh)
-        gv = vv[slot, blocks].reshape(B, narrow * BS, Hkv, Dh)
-        kp = (idx[..., None] * BS + jnp.arange(BS)).reshape(B, 1, 1, narrow * BS)
-        return attend(q, gk, gv, (kp <= qpos) & (kp > qpos - window))
+        def trip(j, carry):
+            m, l, acc = carry
+            tile = first + j                           # [B]; past the table: masked
+            cols = jnp.minimum(tile, NT - 1)[:, None] * TB + jnp.arange(TB)
+            kp = (tile[:, None] * T + jnp.arange(T))[:, None, None, :]
+            scores, gv = scores_of(
+                q, kk, vv, slot, jnp.take_along_axis(table, cols, axis=1),
+                kp, kp <= qpos, window)
+            m_new = jnp.maximum(m, scores.max(axis=-1))
+            p = jnp.exp(scores - m_new[..., None])
+            fade = jnp.exp(m - m_new)
+            acc = acc * fade[..., None] + jnp.einsum(
+                "bhst,bthd->bhsd", p.astype(gv.dtype), gv,
+                preferred_element_type=jnp.float32)
+            return m_new, l * fade + p.sum(axis=-1), acc
 
+        rows = q.shape[:3]
+        _, l, acc = jax.lax.fori_loop(0, trips, trip, (
+            jnp.full(rows, -1e30, jnp.float32), jnp.zeros(rows, jnp.float32),
+            jnp.zeros(q.shape, jnp.float32)))
+        return (acc / l[..., None]).astype(vv.dtype)
+
+    # A step of a few tokens reads only the experts they chose: the expert
+    # stacks then stay whole (a slice the scan cuts would be copied into the
+    # inner loop) and the layer number finds the expert where it lies.
     stacks = None
-    if moe:
-        tok_valid = jnp.logical_and(
-            valid, (block_tables != 0).any(axis=tuple(range(1, block_tables.ndim)))[:, None])
-        if B * S * cfg.moe_top_k < cfg.moe_experts:
-            stacks = tuple(layer_stack.pop(k) for k in
-                           ("moe_w_gate", "moe_w_in", "moe_w_out"))
+    if moe and B * S * cfg.moe_top_k < cfg.moe_experts:
+        stacks = tuple(layer_stack.pop(k) for k in
+                       ("moe_w_gate", "moe_w_in", "moe_w_out"))
 
     def scan_body(carry, inp):
         x, kk, vv = carry                              # kk/vv: the whole pool
@@ -1513,14 +1578,8 @@ def _paged_layers(params, tokens, pos, valid, block_tables, kv, cfg: GPTConfig):
         vv = vv.at[slot, ph, off].set(v.astype(vv.dtype))
         if R > 1:
             q = q.reshape(B, Hkv, R * S, Dh)
-        if kind is None:
-            attn = attend_table(q, kk, vv, slot, table, None)
-        elif narrow < W:
-            attn = jax.lax.cond(
-                kind["window"] < _NO_WINDOW, attend_window, attend_table,
-                q, kk, vv, slot, table, kind["window"])
-        else:
-            attn = attend_table(q, kk, vv, slot, table, kind["window"])
+        attn = attend(q, kk, vv, slot, table,
+                      None if kind is None else kind["window"])
         if R > 1:
             attn = attn.reshape(B, H, S, Dh)
         attn_out = jnp.einsum("bhsd,hde->bse", attn, p["w_o"]) + p["b_o"]
@@ -1535,7 +1594,7 @@ def _paged_layers(params, tokens, pos, valid, block_tables, kv, cfg: GPTConfig):
             experts = stacks or (p["moe_w_gate"], p["moe_w_in"], p["moe_w_out"])
             mlp_out, load = _dropless_mlp(
                 cfg, layer_params["moe_router"], experts, block_in, mlp_in,
-                layer=l if stacks else None, valid=tok_valid)
+                layer=l if stacks else None, valid=real)
             load = jnp.stack(load)
         else:
             mlp_out = _dense_mlp(cfg, p, mlp_in)

@@ -148,7 +148,9 @@ class InferenceEngine:
     ):
         import jax
 
-        from ...models.gpt import init_paged_cache, init_params, kv_layout
+        from ...models.gpt import (
+            init_paged_cache, init_params, kv_layout, paged_attn_keys,
+        )
 
         self.cfg = dataclasses.replace(cfg, remat=False, remat_policy=None)
         self.opts = options or EngineOptions()
@@ -252,6 +254,11 @@ class InferenceEngine:
         self.total_spec_accepted = 0
         self.total_blocks_imported = 0
         self.total_blocks_exported = 0
+        # Keys the dispatched programs' attention covered in a global layer
+        # and keys of their padded tables (`models.gpt.paged_attn_keys`).
+        self._attn_keys = paged_attn_keys
+        self.total_attn_keys = [0, 0]
+        self._step_attn = [0, 0]
         # Expert routing: the last decode step's (experts touched, busiest
         # expert's share), which came back with its logits.
         self._step_moe = None
@@ -841,6 +848,15 @@ class InferenceEngine:
             except Exception as e:  # noqa: BLE001 — fail the waiter, not the loop
                 fut.set_exception(e)
 
+    def _count_attn(self, lanes: int, width: int, last_pos, real):
+        """Add one program's (keys run, keys padded) to the step's and the
+        engine's counts, from the helper its own loop bounds come from."""
+        run, padded = self._attn_keys(
+            lanes, width, self.opts.block_size, last_pos, real)
+        for count in (self._step_attn, self.total_attn_keys):
+            count[0] += run
+            count[1] += padded
+
     def _run_prefill(self, chunk):
         """One prefill chunk: compute prompt[start : start+n] into the paged
         cache. Only the FINAL chunk samples the first token (TTFT)."""
@@ -862,6 +878,7 @@ class InferenceEngine:
             tokens[0, :L] = seq.prompt[chunk.start:chunk.start + L]
             bt = np.zeros(self._table_shape(W), np.int32)
             self._tables_into(bt, seq)
+            self._count_attn(1, W, np.asarray([chunk.start + L - 1]), True)
             args = (
                 jnp.asarray(tokens),
                 jnp.asarray(L, jnp.int32),
@@ -922,6 +939,7 @@ class InferenceEngine:
                 positions[i] = seq.num_tokens - 1
                 valid_len[i] = 1 + len(d)
                 self._tables_into(tables[i], seq)
+            self._count_attn(B, W, positions + valid_len - 1, valid_len > 0)
             args = (
                 jnp.asarray(tokens),
                 jnp.asarray(positions),
@@ -983,6 +1001,7 @@ class InferenceEngine:
                 tokens[i] = seq.output[-1]
                 positions[i] = seq.num_tokens - 1   # where this token's KV lands
                 self._tables_into(tables[i], seq)
+            self._count_attn(B, W, positions, np.arange(B) < len(seqs))
             args = (
                 jnp.asarray(tokens),
                 jnp.asarray(positions),
@@ -1024,6 +1043,7 @@ class InferenceEngine:
         with flight.phase("engine.schedule", ph, "sched_ns"):
             self._step_ttfts, self._step_tpots = [], []
             self._step_spec = [0, 0]  # [proposed, accepted]
+            self._step_attn = [0, 0]  # [keys run, keys padded]
             self._step_moe = None
             tok0 = self.total_tokens
             with self._lock:
@@ -1098,6 +1118,8 @@ class InferenceEngine:
                 attrs={"prefills": len(out.prefills),
                        "decodes": len(out.decodes),
                        "tokens": stats["step_tokens"],
+                       "attn_keys_run": self._step_attn[0],
+                       "attn_keys_padded": self._step_attn[1],
                        **moe, **idle, **ph,
                        "queue_depth": stats["queue_depth"],
                        "running": stats["running"],
@@ -1136,6 +1158,8 @@ class InferenceEngine:
             "blocks_imported": self.total_blocks_imported,
             "blocks_exported": self.total_blocks_exported,
             "window_blocks_released": self.block_manager.window_released,
+            "attn_keys_run": self.total_attn_keys[0],
+            "attn_keys_padded": self.total_attn_keys[1],
             "total_tokens": self.total_tokens,
             "total_finished": self.total_finished,
             "total_preemptions": self.total_preemptions,

@@ -16,6 +16,8 @@ if "xla_force_host_platform_device_count" not in flags:
         flags + " --xla_force_host_platform_device_count=8"
     ).strip()
 
+import contextlib  # noqa: E402
+
 import pytest  # noqa: E402
 
 import ray_tpu  # noqa: E402
@@ -29,6 +31,37 @@ def _drop_stray_runtime():
     every later test of the file errors the same way (seen once: 23 of
     `tests/test_data.py` after its first test, six workers, PR 24)."""
     ray_tpu.shutdown()      # a no-op when there is none
+
+
+@contextlib.contextmanager
+def _paged_jits_with_tile(keys):
+    import jax
+
+    from ray_tpu.models import gpt
+
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(gpt, "_ATTN_TILE_KEYS", keys)
+        yield (
+            jax.jit(lambda *a: gpt.prefill_paged(*a),
+                    static_argnums=(6,), donate_argnums=(5,)),
+            jax.jit(lambda *a: gpt.decode_step_paged(*a),
+                    static_argnums=(5,), donate_argnums=(4,)),
+            jax.jit(lambda *a: gpt.verify_step_paged(*a),
+                    static_argnums=(6,), donate_argnums=(5,)),
+        )
+
+
+@pytest.fixture(scope="session")
+def tile_keys():
+    """`with tile_keys(n) as (prefill, decode, verify):` the paged programs
+    jitted anew (and pool-donating, as the engine's are) and traced with
+    `models.gpt._ATTN_TILE_KEYS = n`, the keys a trip of `_paged_layers`'
+    key loop covers: a table of at most n keys is attended in one shot,
+    a wider one in tiles. The constant is read while tracing, so calls
+    belong inside the `with`. Each program is wrapped in a function of its
+    own: jit's cache of traces is keyed by the function, and would hand
+    back the other form's."""
+    return _paged_jits_with_tile
 
 
 @pytest.fixture

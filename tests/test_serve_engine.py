@@ -771,12 +771,22 @@ def paged_case(request):
     return _paged_case(request.param)
 
 
-def _paged(cfg):
-    """The engine's own jitted (and pool-donating) programs, and a pool."""
-    from ray_tpu.models.gpt import init_paged_cache
-    from ray_tpu.serve.engine.engine import _paged_jits
+# Keys a trip of `_paged_layers`' key loop covers in these tests: the tables
+# below (8 blocks of 4) are then one tile (one shot, no loop) or four.
+ONE_SHOT, TILED = 1 << 20, 8
 
-    return (*_paged_jits(), init_paged_cache(cfg, _NB, _BS))
+
+@pytest.fixture(params=[ONE_SHOT, TILED], ids=["one-shot", "tiled"])
+def paged_jits(request, tile_keys):
+    with tile_keys(request.param) as jits:
+        yield jits
+
+
+def _paged(cfg, jits):
+    """The three programs and a pool."""
+    from ray_tpu.models.gpt import init_paged_cache
+
+    return (*jits, init_paged_cache(cfg, _NB, _BS))
 
 
 def _prefill_chunks(prefill, params, cfg, tokens, table, kv, start, chunks):
@@ -815,7 +825,7 @@ class TestPagedPrograms:
         assert kv["k"].shape == kv["v"].shape == shape
         assert kv["k"].dtype == cfg.dtype
 
-    def test_chunked_prefill_matches_dense(self, paged_case):
+    def test_chunked_prefill_matches_dense(self, paged_case, paged_jits):
         """A 23-token prompt in chunks of 9, 7, 7 (non-zero `pos_offset`,
         padded buckets, chunks that straddle blocks), then a second
         sequence that shares the first three blocks as a cached prefix and
@@ -824,7 +834,7 @@ class TestPagedPrograms:
         import numpy as np
 
         cfg, params, tokens, dense = paged_case
-        prefill, _, _, kv = _paged(cfg)
+        prefill, _, _, kv = _paged(cfg, paged_jits)
         table = jnp.asarray(self.TABLE_A, jnp.int32)
         logits, kv = _prefill_chunks(
             prefill, params, cfg, tokens, table, kv, 0, (9, 7, 7)
@@ -841,7 +851,7 @@ class TestPagedPrograms:
         np.testing.assert_array_equal(after[:, shared], before[:, shared])
 
     def test_decode_lanes_match_dense_and_padding_writes_block_0(
-        self, paged_case
+        self, paged_case, paged_jits
     ):
         """Two sequences at unrelated positions and two padded lanes in one
         bucket of four: each real lane's logits are the dense path's, step
@@ -851,7 +861,7 @@ class TestPagedPrograms:
         import numpy as np
 
         cfg, params, tokens, dense = paged_case
-        prefill, decode, _, kv = _paged(cfg)
+        prefill, decode, _, kv = _paged(cfg, paged_jits)
         ta = jnp.asarray(self.TABLE_A, jnp.int32)
         tb = jnp.asarray(self.TABLE_C, jnp.int32)
         _, kv = _prefill_chunks(prefill, params, cfg, tokens, ta, kv, 0, (16, 7))
@@ -875,7 +885,7 @@ class TestPagedPrograms:
                 assert {(b, o) for b, o in changed.tolist() if b} == written
                 assert all(o == 0 for b, o in changed.tolist() if b == 0)
 
-    def test_verify_matches_sequential_decode(self, paged_case):
+    def test_verify_matches_sequential_decode(self, paged_case, paged_jits):
         """Three tokens a lane in one forward: logits[b, j] are the dense
         path's after tokens 0..pos+j; a lane with a shorter `valid_len`
         and a padded lane write nothing outside their own rows and block
@@ -884,7 +894,7 @@ class TestPagedPrograms:
         import numpy as np
 
         cfg, params, tokens, dense = paged_case
-        prefill, _, verify, kv = _paged(cfg)
+        prefill, _, verify, kv = _paged(cfg, paged_jits)
         ta = jnp.asarray(self.TABLE_A, jnp.int32)
         tb = jnp.asarray(self.TABLE_C, jnp.int32)
         _, kv = _prefill_chunks(prefill, params, cfg, tokens, ta, kv, 0, (14,))
@@ -908,16 +918,154 @@ class TestPagedPrograms:
             (int(tb[p // _BS]), p % _BS) for p in (11, 12)}
         assert {(b, o) for b, o in changed.tolist() if b} == written
 
-    @pytest.mark.parametrize("program", ["prefill", "decode", "verify"])
-    def test_pool_is_the_layer_scans_carry(self, program):
-        """Structure: the layer scan carries the two pool arrays and has no
-        xs or ys of the pool's size — as xs -> ys the device rewrote the
-        whole pool in every program (PERF.md §6, PR 25)."""
+    @pytest.mark.parametrize("tile", [4, 8], ids=["8-tiles", "4-tiles"])
+    def test_key_loop_gives_what_one_shot_gives(self, paged_case, tile_keys, tile):
+        """The loop over key tiles against the one-shot form on the same
+        pool, to float32 rounding: a prompt's first, middle and last chunk
+        (the last one ends in the table's last tile), then decode lanes of
+        very different lengths beside padding lanes, then a verify step."""
+        import jax.numpy as jnp
+        import numpy as np
+
+        cfg, params, tokens, dense = paged_case
+        ta = jnp.asarray(self.TABLE_A, jnp.int32)
+        tc = jnp.asarray(self.TABLE_C, jnp.int32)
+        null = jnp.zeros_like(ta)
+        got = {}
+        for keys in (ONE_SHOT, tile):
+            with tile_keys(keys) as jits:
+                prefill, decode, verify, kv = _paged(cfg, jits)
+                out, pos = [], 0
+                for n in (9, 7, 13):
+                    logits, kv = _prefill_chunks(
+                        prefill, params, cfg, tokens, ta, kv, pos, (n,))
+                    out.append(logits)
+                    pos += n
+                _, kv = _prefill_chunks(prefill, params, cfg, tokens, tc, kv, 0, (3,))
+                logits, kv = decode(
+                    params, jnp.asarray([0, tokens[29], 0, tokens[3]]),
+                    jnp.asarray([0, 29, 0, 3], jnp.int32),
+                    jnp.stack([null, ta, null, tc]), kv, cfg)
+                out += [logits[1], logits[3]]
+                logits, kv = verify(
+                    params, jnp.stack([tokens[4:6], jnp.zeros(2, jnp.int32),
+                                       tokens[30:32]]),
+                    jnp.asarray([4, 0, 30], jnp.int32), jnp.asarray([1, 0, 2], jnp.int32),
+                    jnp.stack([tc, null, ta]), kv, cfg)
+                out += [logits[0, 0], logits[2]]
+                got[keys] = [np.asarray(o) for o in out] + [
+                    np.asarray(kv["k"])[:, 1:], np.asarray(kv["v"])[:, 1:]]
+        for a, b in zip(got[ONE_SHOT], got[tile]):
+            np.testing.assert_allclose(a, b, rtol=2e-5, atol=2e-5)
+        _close(got[tile][2], dense[28])     # and both are the dense path's
+        _close(got[tile][3], dense[29])
+        _close(got[tile][6][1], dense[31])
+
+    def test_key_loop_only_where_a_table_is_wider_than_a_tile(self):
+        """Structure: a table of one tile lowers with no inner loop at all
+        (the operations from before the loop: every program of a model
+        whose tables fit a tile); a wider one with one loop a layer, whose
+        trip count is a run-time value and not the table's width."""
         import jax
         import jax.numpy as jnp
 
         from ray_tpu.models import gpt
 
+        cfg = _tiny_cfg()
+        params = jax.eval_shape(
+            lambda k: gpt.init_params(k, cfg), jax.random.PRNGKey(0))
+        kv = jax.eval_shape(lambda: gpt.init_paged_cache(cfg, 64, _BS))
+        i32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32)  # noqa: E731
+
+        def inner_loops(tile):
+            with pytest.MonkeyPatch.context() as mp:
+                mp.setattr(gpt, "_ATTN_TILE_KEYS", tile)
+                jaxpr = jax.make_jaxpr(
+                    lambda *a: gpt.decode_step_paged(*a, cfg)
+                )(params, i32(4), i32(4), i32(4, 8), kv)
+            (scan,) = [e for e in jaxpr.jaxpr.eqns if e.primitive.name == "scan"]
+            return [e for e in scan.params["jaxpr"].jaxpr.eqns
+                    if e.primitive.name == "while"]
+
+        assert gpt.paged_attn_tiling(8, _BS) == (8, 1)      # the module's own tile
+        assert inner_loops(gpt._ATTN_TILE_KEYS) == []
+        assert inner_loops(8 * _BS) == []
+        assert len(inner_loops(TILED)) == 1
+
+    def test_key_loop_stops_at_the_last_tile_a_lane_can_see(self, paged_case, tile_keys):
+        """Behaviour of the bounds: with NaN in the V rows of every block
+        past the tile that holds the longest real lane's position, the loop
+        still gives the dense logits (0 x NaN would poison a form that
+        computes over the whole table, as the one-shot form shows); the
+        padding lanes take no part in the bounds."""
+        import jax.numpy as jnp
+        import numpy as np
+
+        cfg, params, tokens, dense = paged_case
+        ta = jnp.asarray(self.TABLE_A, jnp.int32)
+        null = jnp.zeros_like(ta)
+        tables = jnp.stack([null, ta, null, null])
+        finite = {}
+        for keys in (ONE_SHOT, TILED):
+            with tile_keys(keys) as jits:
+                prefill, decode, _, kv = _paged(cfg, jits)
+                _, kv = _prefill_chunks(prefill, params, cfg, tokens, ta, kv, 0, (14,))
+                kv["v"] = kv["v"].at[:, jnp.asarray(self.TABLE_A[4:])].set(jnp.nan)
+                logits, kv = decode(
+                    params, jnp.asarray([0, tokens[14], 0, 0]),
+                    jnp.asarray([0, 14, 0, 0], jnp.int32), tables, kv, cfg)
+                finite[keys] = bool(np.isfinite(np.asarray(logits[1])).all())
+                if keys == TILED:
+                    _close(logits[1], dense[14])
+        assert finite == {ONE_SHOT: False, TILED: True}
+
+    def test_host_counts_keys_with_the_programs_own_bounds(self):
+        """`paged_attn_keys`, what the engine counts a dispatched program's
+        attention by, at the module's own tile: the trips of the longest
+        REAL lane over every lane of the bucket, against the padded tables;
+        a table of one tile counts whole. The bounds are one function for
+        `numpy` and `jax.numpy`."""
+        import jax.numpy as jnp
+        import numpy as np
+
+        from ray_tpu.models import gpt
+
+        T = gpt._ATTN_TILE_KEYS
+        assert gpt.paged_attn_tiling(256, 64) == (T // 64, 256 * 64 // T)
+        pos = np.asarray([9 * T - 24, 300, 0, 0])
+        real = np.asarray([True, True, False, False])
+        assert gpt.paged_attn_keys(4, 256, 64, pos, real) == (4 * 9 * T, 4 * 256 * 64)
+        assert gpt.paged_attn_keys(4, 256, 64, pos[::-1], real) == (4 * T, 4 * 256 * 64)
+        assert gpt.paged_attn_keys(1, 256, 64, np.asarray([511]), True) == (T, 256 * 64)
+        assert gpt.paged_attn_keys(2, T // 64, 64, pos[:2] % T, True) == (2 * T, 2 * T)
+        rng = np.random.default_rng(0)
+        last = rng.integers(0, 16 * T, (50, 8))
+        first = last - rng.integers(0, 600, (50, 8))
+        real = rng.random((50, 8)) < 0.7
+        for window in (gpt._NO_WINDOW, 4 * T, T + 5):
+            for f, l, r in zip(first, last, real):
+                want = gpt.paged_attn_trips(np, f, l, r, window, T, 16)
+                got = gpt.paged_attn_trips(
+                    jnp, jnp.asarray(f), jnp.asarray(l), jnp.asarray(r), window, T, 16)
+                assert (np.asarray(got[0]) == want[0]).all() and int(got[1]) == want[1]
+                # every key a real lane's queries may see lies inside the bounds
+                lo = np.maximum(f - window + 1, 0) // T
+                assert (want[0] <= lo)[r].all()
+                assert ((l // T)[r] < (want[0] + want[1])[r]).all()
+
+    @pytest.mark.parametrize("program", ["prefill", "decode", "verify"])
+    def test_pool_is_the_layer_scans_carry(self, program, monkeypatch):
+        """Structure: the layer scan carries the two pool arrays and has no
+        xs or ys of the pool's size — as xs -> ys the device rewrote the
+        whole pool in every program (PERF.md §6, PR 25). The key loop
+        inside a layer reads the pool where it lies: nothing of the pool's
+        size is among the values it carries from trip to trip."""
+        import jax
+        import jax.numpy as jnp
+
+        from ray_tpu.models import gpt
+
+        monkeypatch.setattr(gpt, "_ATTN_TILE_KEYS", TILED)
         cfg = _tiny_cfg()
         params = jax.eval_shape(
             lambda k: gpt.init_params(k, cfg), jax.random.PRNGKey(0))
@@ -944,6 +1092,14 @@ class TestPagedPrograms:
         assert all(v.aval.size < kv["k"].size for v in streamed), (
             "a pool-sized array rides the scan as xs or ys"
         )
+        (loop,) = [e for e in scan.params["jaxpr"].jaxpr.eqns
+                   if e.primitive.name == "while"]
+        consts = loop.params["cond_nconsts"] + loop.params["body_nconsts"]
+        carried = loop.invars[consts:] + loop.outvars
+        assert all(v.aval.size < kv["k"].size for v in carried), (
+            "the key loop carries a pool-sized array"
+        )
+        assert [v.aval.shape for v in loop.invars[:consts]].count(pool) == 2
 
     def test_cow_copies_every_layers_rows(self, tiny_engine_parts):
         """Copy-on-write of a forked partial block on the physical pool:
@@ -1052,10 +1208,11 @@ def test_two_kinds_of_layer_compile_for_v5e_over_one_pool_in_place(v5e_chip):
     """Compile-only, at SmallThinker's published widths (one period of four
     layers, the whole vocabulary, a pool of 1,024 blocks of 64 tokens): the
     pool [layers a group, NB, BS, 512] is still the scan's in-place carry
-    with a table a group, a window layer's branch gathers its 65 blocks and
-    not the lane's 256, and a decode step of a few lanes keeps the expert
-    stacks whole (no copy of a layer's experts into the loop)."""
-    from ray_tpu.models.gpt import CONFIGS, kv_layout
+    with a table a group and is not copied into the key loop, a 512-token
+    chunk's scores are one tile's and not the lane's 256 blocks', and a
+    decode step of a few lanes keeps the expert stacks whole (no copy of a
+    layer's experts into the loop)."""
+    from ray_tpu.models.gpt import _ATTN_TILE_KEYS, CONFIGS, kv_layout
     from scripts.paged_rehearse import rehearse
 
     cfg = CONFIGS["smallthinker-21b-a3b"](
@@ -1069,16 +1226,19 @@ def test_two_kinds_of_layer_compile_for_v5e_over_one_pool_in_place(v5e_chip):
     for name, prog in report["programs"].items():
         assert "refused" not in prog, (name, prog)
         assert prog["alias_GiB"] * 2**30 >= pool_bytes, f"{name}: not donated"
-        assert prog["temp_GiB"] < 1.5, (name, prog)
+        assert prog["temp_GiB"] < 0.25, (name, prog)
         moved = [o for o in prog["pool_sized_ops"]
                  if "copy" in o["op"] or "transpose" in o["op"]
                  or "64,2560,768]" in o["result"] or "64,768,2560]" in o["result"]]
         assert not moved, (name, moved)
-    # a 512-token chunk's scores: [4 K/V heads, 7 x 512 queries, keys]: the
-    # whole 16,384-token table on a global layer, 73 blocks on a window layer
-    scores = [o["result"] for o in report["programs"]["prefill_paged"]["pool_sized_ops"]
-              if o["result"].startswith("f32[4,3584,")]
-    assert any("3584,16384]" in r for r in scores) and any("3584,4672]" in r for r in scores)
+    # a 512-token chunk's scores, [4 K/V heads, 7 x 512 queries, keys], are
+    # one tile wide on every layer: with the whole 16,384-token table on a
+    # global layer (0.9 GiB) and 73 blocks on a window layer this read 0.88
+    # GiB of temporaries (PERF.md §6, PR 29)
+    scores = {o["result"].split("{")[0]
+              for o in report["programs"]["prefill_paged"]["pool_sized_ops"]
+              if o["result"].startswith("f32[4,3584,")}
+    assert scores == {f"f32[4,3584,{_ATTN_TILE_KEYS}]"}, scores
 
 
 # ------------------------------------------------- serve data-plane wiring
@@ -1185,7 +1345,10 @@ class TestEnginePhases:
             a = ev["args"]
             assert set(flight.SERVE_STEP_PHASES) | {
                 "waited_ns", "queue_depth", "running", "kv_util",
-                "prefills", "decodes", "tokens"} <= set(a)
+                "prefills", "decodes", "tokens", "attn_keys_run",
+                "attn_keys_padded"} <= set(a)
+            # tables of one tile: the programs compute over all they gather
+            assert 0 < a["attn_keys_run"] == a["attn_keys_padded"]
             assert all(isinstance(a[k], int) and a[k] >= 0
                        for k in flight.SERVE_STEP_PHASES)
             assert a["waited_ns"] == 0          # driven by step(): no _loop
@@ -1198,6 +1361,10 @@ class TestEnginePhases:
         assert 0.98 <= shares[len(shares) // 2] <= 1.0, shares
         last = steps[-1]["args"]
         assert last["queue_depth"] == 0 and last["export_ns"] > 0
+        total = eng.stats()
+        assert total["attn_keys_padded"] >= sum(
+            ev["args"]["attn_keys_padded"] for ev in steps)
+        assert total["attn_keys_run"] == total["attn_keys_padded"]
 
     def test_idle_wait_is_carried_into_the_next_step_record(
         self, tiny_engine_parts, ring

@@ -2,8 +2,8 @@
 sizes with seeded random weights, each against the plain reference of
 `benchmarks/arch/smallthinker.py`: `forward`; chunked paged prefill then
 paged decode through the block manager's tables for a sequence that crosses
-the window by several blocks (logits, not tokens); verify against
-sequential decode; four wrong references that must fail; routing that drops
+the window by several blocks (logits, not tokens), with tables of one tile
+and of sixteen (`_paged_layers`' key loop); verify against sequential decode; four wrong references that must fail; routing that drops
 nothing; grouped-query heads against multi-head; the engine end to end."""
 
 import copy
@@ -24,6 +24,10 @@ PUBLISHED = {
     "program_model": "smallthinker-21b-a3b",
 }
 TOL = 2e-5      # float32 program against float32 reference, logits near 1
+# Keys a trip of `_paged_layers`' key loop covers: the tables below (32
+# blocks of 8) are one tile (one shot, no loop) or sixteen, and the window
+# of 32 then starts inside a tile at most positions.
+FORMS = {"one-shot": 1 << 20, "tiled": 16}
 
 
 @pytest.fixture(scope="module")
@@ -74,22 +78,25 @@ def test_forward_matches_the_reference(case):
     assert _err(got, want) < TOL
 
 
-@pytest.fixture(scope="module")
-def through(case):
-    return _through_the_manager(case)
+@pytest.fixture(scope="module", params=list(FORMS))
+def through(case, request, tile_keys):
+    with tile_keys(FORMS[request.param]) as jits:
+        return _through_the_manager(case, jits)
 
 
-def _through_the_manager(case, chunk=24, n_prompt=100):
+def _through_the_manager(case, jits, chunk=24, n_prompt=100):
     """Chunked `prefill_paged` then `decode_step_paged` over the tables the
     block manager gives, sliding as the scheduler does; yields (position,
-    logits) of every prefill chunk's last position and every decode step."""
+    logits) of every prefill chunk's last position and every decode step.
+    Before every call the null block's rows, which every released entry of
+    a window group's table points at, are set to a large value: the mask
+    must keep them from every output."""
     import jax.numpy as jnp
 
     from ray_tpu.models import gpt
     from ray_tpu.serve.engine import KVBlockManager
-    from ray_tpu.serve.engine.engine import _paged_jits
 
-    prefill, decode, _verify = _paged_jits()
+    prefill, decode, _verify = jits
     cfg, params, _m, tokens, _want = case
     lay = gpt.kv_layout(cfg)
     mgr = KVBlockManager(40, BS, group_windows=lay.windows)
@@ -103,6 +110,9 @@ def _through_the_manager(case, chunk=24, n_prompt=100):
             t[g, : len(tab)] = tab
         return jnp.asarray(t)
 
+    def poisoned(kv):
+        return {n: a.at[:, 0].set(1e4) for n, a in kv.items()}
+
     out, start = [], 0
     while start < n_prompt:
         n = min(chunk, n_prompt - start)
@@ -112,7 +122,7 @@ def _through_the_manager(case, chunk=24, n_prompt=100):
         padded[0, :n] = prompt[start:start + n]
         logits, kv = prefill(
             params, jnp.asarray(padded), jnp.int32(n), jnp.int32(start),
-            tables(), kv, cfg)
+            tables(), poisoned(kv), cfg)
         start += n
         mgr.register_computed("s", prompt, start)
         out.append((start - 1, np.asarray(logits)))
@@ -122,7 +132,7 @@ def _through_the_manager(case, chunk=24, n_prompt=100):
         mgr.check_invariants()
         (logits, _load), kv = decode(
             params, jnp.asarray(tokens[pos:pos + 1]), jnp.asarray([pos]),
-            tables()[None], kv, cfg)
+            tables()[None], poisoned(kv), cfg)
         out.append((pos, np.asarray(logits)[0]))
     return mgr, out
 
@@ -139,7 +149,14 @@ def test_paged_prefill_and_decode_across_the_window_match_the_reference(case, th
     assert mgr.window_released == 3 * (held[0] - held[1])
 
 
-def test_verify_step_equals_sequential_decode(case):
+@pytest.mark.parametrize("form", list(FORMS))
+def test_verify_step_equals_sequential_decode(case, form, tile_keys):
+    """Four tokens a lane in one forward, beside a padding lane. In the
+    tiled form the V rows of the window groups' blocks that lie wholly
+    below the tile of the window's first key are NaN: a window layer's
+    loop starts at that tile (0 x NaN would reach the output of one that
+    started at tile 0), and the padding lane at position 0 does not pull
+    the start down."""
     import jax.numpy as jnp
 
     from ray_tpu.models import gpt
@@ -152,13 +169,18 @@ def test_verify_step_equals_sequential_decode(case):
     kv = gpt.init_paged_cache(cfg, 41, BS)
     padded = np.zeros((1, 64), np.int32)
     padded[0, :n0] = tokens[:n0]
-    _, kv = gpt.prefill_paged(params, jnp.asarray(padded), jnp.int32(n0),
-                              jnp.int32(0), jnp.asarray(table), kv, cfg)
     toks = np.zeros((2, k1), np.int32)
     toks[0] = tokens[n0:n0 + k1]
-    logits, _ = gpt.verify_step_paged(
-        params, jnp.asarray(toks), jnp.asarray([n0, 0]), jnp.asarray([k1, 0]),
-        jnp.asarray(np.stack([table, np.zeros_like(table)])), kv, cfg)
+    with tile_keys(FORMS[form]) as (prefill, _decode, verify):
+        _, kv = prefill(params, jnp.asarray(padded), jnp.int32(n0),
+                        jnp.int32(0), jnp.asarray(table), kv, cfg)
+        if form == "tiled":
+            # first key a query at 60 sees on a window layer: 29, in tile 1
+            assert (n0 - WINDOW + 1) // FORMS[form] == 1
+            kv["v"] = kv["v"].at[:, jnp.asarray(table[1:, :2].ravel())].set(jnp.nan)
+        logits, _ = verify(
+            params, jnp.asarray(toks), jnp.asarray([n0, 0]), jnp.asarray([k1, 0]),
+            jnp.asarray(np.stack([table, np.zeros_like(table)])), kv, cfg)
     assert _err(logits[0], want[n0:n0 + k1]) < TOL
 
 
@@ -273,7 +295,17 @@ def _engine(case, **opts):
     return InferenceEngine(case[0], params=case[1], options=options)
 
 
-def test_engine_serves_across_the_window_and_counts_what_it_did(case):
+@pytest.mark.parametrize("form", list(FORMS))
+def test_engine_serves_across_the_window_and_counts_what_it_did(case, form, tile_keys, monkeypatch):
+    from ray_tpu.serve.engine import engine as engine_module
+
+    cfg, params, m, tokens, _want = case
+    with tile_keys(FORMS[form]) as jits:
+        monkeypatch.setattr(engine_module, "_JITS", jits)
+        _serves_across_the_window(case, form)
+
+
+def _serves_across_the_window(case, form):
     cfg, params, m, tokens, _want = case
     eng = _engine(case)
     prompt = [int(t) for t in tokens[:100]]
@@ -292,6 +324,10 @@ def test_engine_serves_across_the_window_and_counts_what_it_did(case):
     assert (want.argmax(-1) == np.asarray(got)).all()
     stats = eng.stats()
     assert stats["window_blocks_released"] > 0
+    # tables of one tile are computed over whole; of several, as far as the
+    # longest lane of each step reaches
+    run, padded = stats["attn_keys_run"], stats["attn_keys_padded"]
+    assert 0 < run == padded if form == "one-shot" else 0 < run < padded, (run, padded)
     touched, share = np.asarray(records).T
     assert (touched >= 3).all() and (touched <= 6).all()
     assert (share >= 1 / 6 - 1e-6).all() and (share <= 1 / 3 + 1e-6).all()
