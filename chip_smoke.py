@@ -6,8 +6,8 @@ published widths of gpt2-large (36 L, d=1280, 20x64 heads, MLP 5120, vocab
 
   1. trainer — `JaxTrainer(...).fit()` in one TPU worker that owns every
      local chip: gpt2-large, S=1024, flash attention + remat_policy="attn",
-     adamw with bf16 first moments (bench.py's shape) on an fsdp mesh over
-     `jax.devices()`, a few steps on one fixed batch;
+     adamw with bf16 first moments (the `gpt2-large.train` cell's shape) on
+     an fsdp mesh over `jax.devices()`, a few steps on one fixed batch;
   2. server — `serve.run(LLMDeployment...)` on one chip, requests of mixed
      prompt lengths over the HTTP proxy, some in flight together;
   3. reference — in a plain `num_tpus=1` task: each Pallas kernel against
@@ -42,7 +42,7 @@ import uuid
 
 MODEL = "gpt2-large"
 SEQ = 1024
-BATCH_PER_CHIP = 13  # bench.py's batch; 14 is the largest that compiles (PR 21)
+BATCH_PER_CHIP = 13  # the train cell's batch; 14 is the largest that compiles (PR 21)
 TRAIN_STEPS = 8
 # (prompt tokens, new tokens): one prefill chunk, two, and five (chunk = 64).
 REQUESTS = ((5, 16), (70, 24), (300, 8))

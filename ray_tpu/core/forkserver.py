@@ -6,7 +6,7 @@ boot by pre-forking on backlog hints; here the amortization is structural —
 a per-node TEMPLATE process pays the interpreter+import cost once (python +
 numpy + the worker module + jax-on-CPU, ~2 s of CPU on the bench host),
 then `fork()`s a ready worker per request in ~10 ms. This is what turns the
-2,000-actor envelope from boot-bound (ENVELOPE_r3: 1,943 s) into
+2,000-actor envelope (`scripts/envelope.py`) from boot-bound into
 fork-bound.
 
 Design constraints:

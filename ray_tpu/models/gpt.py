@@ -27,7 +27,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..ops import apply_rope, flash_attention, layernorm, ring_attention, rmsnorm, rope_frequencies
+from ..ops import flash_attention, layernorm, ring_attention, rmsnorm, rope_frequencies, rotate_half
 from ..ops.attention import attention_reference, ulysses_attention
 from ..parallel.mesh import ShardingRules
 
@@ -154,10 +154,6 @@ class GPTConfig:
         if self.pos == "learned":
             total += self.max_seq * E
         return total
-
-    def flops_per_token(self, seq_len: int) -> float:
-        """Training FLOPs/token: 6N + attention term (12·L·E·S·(S/S) approx)."""
-        return 6.0 * self.n_params + 12.0 * self.n_layers * self.d_model * seq_len
 
 
 # Canonical configs ---------------------------------------------------------
@@ -506,7 +502,8 @@ def _dropless_mlp(cfg: GPTConfig, router, experts, block_in, mlp_in,
     layer's input `block_in`, top-k without capacity, gated experts
     (ops/moe.py). `experts` = (w_gate, w_in,
     w_out), this layer's or with `layer` the whole stacks. Returns (y,
-    (experts touched, busiest expert's share)) of this layer's routing."""
+    [2] f32 = experts touched and the busiest expert's share) of this
+    layer's routing, tokens outside `valid` [B, S] left out of the count."""
     from ..ops import moe
 
     B, S, E = mlp_in.shape
@@ -519,7 +516,36 @@ def _dropless_mlp(cfg: GPTConfig, router, experts, block_in, mlp_in,
         mlp_in.reshape(B * S, E), combine, *experts, cfg.activation,
         layer=layer, touched_k=cfg.moe_top_k if few else 0)
     load = moe.dropless_load(combine, None if valid is None else valid.reshape(B * S))
-    return y.reshape(B, S, E), load
+    return y.reshape(B, S, E), jnp.stack(load)
+
+
+def _mlp(cfg: GPTConfig, p, router, block_in, mlp_in, stacks=None, layer=None,
+         valid=None):
+    """The layer's MLP over mlp_in [B, S, E], chosen by `cfg`: dense,
+    capacity-routed experts (training) or dropless experts. `p` holds the
+    layer's weights in cfg.dtype, `router` the router as stored; `stacks`
+    are the whole expert stacks where the layer scan did not cut them
+    (`_paged_layers`), read at `layer`. Returns (y, aux loss f32 scalar,
+    None or the dropless routing's load)."""
+    aux, load = jnp.zeros((), jnp.float32), None
+    if cfg.mlp_type == "moe" and cfg.moe_routing == "dropless":
+        experts = stacks or (p["moe_w_gate"], p["moe_w_in"], p["moe_w_out"])
+        y, load = _dropless_mlp(cfg, router, experts, block_in, mlp_in,
+                                layer=layer if stacks else None, valid=valid)
+    elif cfg.mlp_type == "moe":
+        from ..ops.moe import moe_forward
+
+        moe_params = {
+            "w_router": router,  # router math stays f32
+            "w_in": p["moe_w_in"],
+            "w_out": p["moe_w_out"],
+        }
+        if cfg.activation == "swiglu":
+            moe_params["w_gate"] = p["moe_w_gate"]
+        y, aux = moe_forward(moe_params, mlp_in, cfg.moe_config)
+    else:
+        y = _dense_mlp(cfg, p, mlp_in)
+    return y, aux, load
 
 
 def _attention_plain(cfg: GPTConfig, q, k, v, positions, window=None):
@@ -556,56 +582,65 @@ def _layer_kind_xs(cfg: GPTConfig):
             "window": jnp.asarray([w or _NO_WINDOW for w in win], jnp.int32)}
 
 
-def _refuse_new_fields(cfg: GPTConfig, what: str, moe: bool = True):
-    """The paths that were not generalised say so: grouped-query heads,
-    per-layer kinds and dropless experts run in `forward` (attn_impl="ref")
-    and in the paged programs only."""
+def _refuse_new_fields(cfg: GPTConfig, what: str):
+    """A check on input for the programs that cannot take a field: the dense
+    cache [L, B, H, M, Dh] has no K/V-head count and no window, and the
+    stage split cuts no per-layer kinds. Grouped-query heads and per-layer
+    kinds run in `forward` (attn_impl="ref") and in the paged programs."""
     bad = []
     if cfg.kv_heads != cfg.n_heads:
         bad.append("grouped-query heads (n_kv_heads)")
     if cfg.layer_kinds is not None:
         bad.append("per-layer kinds (rope_layout / sliding_window_layout)")
-    if moe and cfg.mlp_type == "moe":
-        bad.append("an expert MLP")
     if bad:
         raise NotImplementedError(f"{what} does not support " + ", ".join(bad))
 
 
-def _block(cfg: GPTConfig, rope_tables, mesh, x, layer_params, positions,
-           return_kv: bool = False, kind=None):
-    """One transformer block; x: [B, S, E] in cfg.dtype. With return_kv the
-    post-RoPE K/V ([B, H, S, Dh]) come back too — the prefill path stores
-    them in the decode cache. `kind`: this layer's entry of
-    `_layer_kind_xs` (traced scalars) for a model with layers of two kinds."""
+def _rotary(cfg: GPTConfig, rope_tables, positions, q, k):
+    """q and k [B, heads, S, Dh] with their first `rotary_dim` features
+    rotated to integer `positions`: [S] (every lane alike), [] (one
+    position, S = 1) or [B, S] (each lane's own)."""
+    rd = min(cfg.rotary_dim, cfg.d_head)
+
+    def rows(table):                    # [..., S, rd/2], leading dims broadcast
+        r = table[positions]
+        if positions.ndim == 2:         # [B, S, rd/2]: the heads' axis goes in
+            return r[:, None]
+        return r[None] if positions.ndim == 0 else r
+
+    c, s = rows(rope_tables[0]), rows(rope_tables[1])
+
+    def rotated(x):
+        if rd == cfg.d_head:
+            return rotate_half(x, c, s)
+        return jnp.concatenate([rotate_half(x[..., :rd], c, s), x[..., rd:]], -1)
+
+    return rotated(q), rotated(k)
+
+
+def _block(cfg: GPTConfig, rope_tables, attend, x, layer_params, positions,
+           kind=None, stacks=None, layer=None, valid=None):
+    """One transformer block, the only one: x [B, S, E] in cfg.dtype ->
+    (x, the state `attend` hands back, MoE aux loss, dropless load or None).
+
+    `attend(q, k, v, kind)` is the program's attention: q [B, H, S, Dh] and
+    k, v [B, Hkv, S, Dh] after rotary -> (attention [B, H, S, Dh], whatever
+    cache state the program carries). `kind`: this layer's entry of
+    `_layer_kind_xs` (traced scalars) for a model with layers of two kinds;
+    `stacks`, `layer`, `valid`: see `_mlp`."""
     # Cast this layer's master weights to compute dtype (bf16 → MXU).
     p = jax.tree_util.tree_map(lambda a: a.astype(cfg.dtype), layer_params)
-    B, S, E = x.shape
-    H, Dh = cfg.n_heads, cfg.d_head
     block_in = x
 
     h = _norm(x, p["ln1_w"], p["ln1_b"], cfg.norm)
     q, k, v = (a.transpose(0, 2, 1, 3) for a in _project_qkv(cfg, p, h))
     # [B, S, heads, Dh] -> [B, heads, S, Dh]
     if cfg.pos == "rotary":
-        cos, sin = rope_tables
-        rd = min(cfg.rotary_dim, Dh)
-        c, s = cos[positions], sin[positions]
-        qr = jnp.concatenate([apply_rope(q[..., :rd], c, s, None), q[..., rd:]], -1) \
-            if rd < Dh else apply_rope(q, c, s, None)
-        kr = jnp.concatenate([apply_rope(k[..., :rd], c, s, None), k[..., rd:]], -1) \
-            if rd < Dh else apply_rope(k, c, s, None)
+        qr, kr = _rotary(cfg, rope_tables, positions, q, k)
         # A layer without positional encoding keeps q and k as projected.
         q, k = (qr, kr) if kind is None else (
             jnp.where(kind["rope"], qr, q), jnp.where(kind["rope"], kr, k))
-    if kind is not None or cfg.kv_heads != H:
-        if cfg.attn_impl != "ref":
-            raise NotImplementedError(
-                "grouped-query heads and per-layer windows run attn_impl='ref' "
-                f"in forward (got {cfg.attn_impl!r}); the kernels take neither")
-        attn = _attention_plain(
-            cfg, q, k, v, positions, None if kind is None else kind["window"])
-    else:
-        attn = _attention(cfg, q, k, v, mesh)  # [B, H, S, Dh]
+    attn, state = attend(q, k, v, kind)
     attn_out = jnp.einsum("bhsd,hde->bse", attn, p["w_o"]) + p["b_o"]
 
     if cfg.parallel_block:
@@ -613,30 +648,10 @@ def _block(cfg: GPTConfig, rope_tables, mesh, x, layer_params, positions,
     else:
         x = x + attn_out
         mlp_in = _norm(x, p["ln2_w"], p["ln2_b"], cfg.norm)
-
-    aux = jnp.zeros((), jnp.float32)
-    if cfg.mlp_type == "moe" and cfg.moe_routing == "dropless":
-        mlp_out, _load = _dropless_mlp(
-            cfg, layer_params["moe_router"],
-            (p["moe_w_gate"], p["moe_w_in"], p["moe_w_out"]), block_in, mlp_in)
-    elif cfg.mlp_type == "moe":
-        from ..ops.moe import moe_forward
-
-        moe_params = {
-            "w_router": layer_params["moe_router"],  # router math stays f32
-            "w_in": p["moe_w_in"],
-            "w_out": p["moe_w_out"],
-        }
-        if cfg.activation == "swiglu":
-            moe_params["w_gate"] = p["moe_w_gate"]
-        mlp_out, aux = moe_forward(moe_params, mlp_in, cfg.moe_config)
-    else:
-        mlp_out = _dense_mlp(cfg, p, mlp_in)
-
+    mlp_out, aux, load = _mlp(cfg, p, layer_params.get("moe_router"), block_in,
+                              mlp_in, stacks, layer, valid)
     out = x + attn_out + mlp_out if cfg.parallel_block else x + mlp_out
-    if return_kv:
-        return out, (aux, k, v)
-    return out, aux
+    return out, state, aux, load
 
 
 _LAYER_KEYS = (
@@ -644,6 +659,65 @@ _LAYER_KEYS = (
     "ln1_w", "ln1_b", "ln2_w", "ln2_b", "w_gate",
     "moe_router", "moe_w_in", "moe_w_out", "moe_w_gate",
 )
+
+
+def _embed(params, tokens, positions, cfg: GPTConfig):
+    """Token embedding [..., E] in cfg.dtype, plus the learned position rows
+    of a model that has them."""
+    x = params["tok_embed"][tokens].astype(cfg.dtype)
+    if cfg.pos == "learned":
+        x = x + params["pos_embed"][positions].astype(cfg.dtype)
+    return x
+
+
+def _rope_tables(cfg: GPTConfig):
+    """(cos, sin) [max_seq, rotary_dim/2] float32, or None without rotary."""
+    if cfg.pos != "rotary":
+        return None
+    return rope_frequencies(min(cfg.rotary_dim, cfg.d_head), cfg.max_seq,
+                            theta=cfg.rope_theta, dtype=jnp.float32)
+
+
+def _layer_stack(params):
+    """The stacked per-layer weights [L, ...]: what the layer scan cuts."""
+    return {k: params[k] for k in _LAYER_KEYS if k in params}
+
+
+def _logits(params, x, cfg: GPTConfig):
+    """Final norm and head over hidden states [..., E] -> [..., V] in
+    cfg.dtype; the cache programs hand float32 on."""
+    x = _norm(x, params["ln_f_w"], params["ln_f_b"], cfg.norm)
+    head = params["tok_embed"].T if cfg.tie_embeddings else params["lm_head"]
+    return jnp.einsum("...e,ev->...v", x, head.astype(cfg.dtype))
+
+
+def _layer_loop(cfg: GPTConfig, mesh, positions):
+    """The layer scan of the programs that see a whole sequence and keep no
+    cache (`forward`, a pipeline stage): (x, layer_stack, kinds=None) ->
+    (x, [L] aux). Their `attend` is the configured kernel (`_attention`),
+    or `_attention_plain` where heads are grouped or layers have kinds."""
+
+    def attend(q, k, v, kind):
+        if kind is None and cfg.kv_heads == cfg.n_heads:
+            return _attention(cfg, q, k, v, mesh), None
+        if cfg.attn_impl != "ref":
+            raise NotImplementedError(
+                "grouped-query heads and per-layer windows run attn_impl='ref' "
+                f"in forward (got {cfg.attn_impl!r}); the kernels take neither")
+        window = None if kind is None else kind["window"]
+        return _attention_plain(cfg, q, k, v, positions, window), None
+
+    block = functools.partial(_block, cfg, _rope_tables(cfg), attend)
+    if cfg.remat:
+        block = jax.checkpoint(block, policy=_remat_policy(cfg))
+
+    def scan_body(x, inp):
+        layer_params, kind = inp
+        x, _, aux, _ = block(x, layer_params, positions, kind=kind)
+        return x, aux
+
+    return lambda x, layer_stack, kinds=None: jax.lax.scan(
+        scan_body, x, (layer_stack, kinds))
 
 
 def global_positions(cfg: GPTConfig, local_seq: int):
@@ -669,33 +743,10 @@ def forward(params, tokens, cfg: GPTConfig, positions=None, mesh=None, return_au
     if positions is None:
         # In automatic (pjit) mode shapes are global — plain arange is right.
         positions = jnp.arange(S) if mesh is not None else global_positions(cfg, S)
-    x = params["tok_embed"][tokens].astype(cfg.dtype)
-    if cfg.pos == "learned":
-        x = x + params["pos_embed"][positions].astype(cfg.dtype)
-
-    rope_tables = None
-    if cfg.pos == "rotary":
-        rd = min(cfg.rotary_dim, cfg.d_head)
-        rope_tables = rope_frequencies(
-            rd, cfg.max_seq, theta=cfg.rope_theta, dtype=jnp.float32)
-
-    layer_stack = {k: params[k] for k in _LAYER_KEYS if k in params}
-
-    block = functools.partial(_block, cfg, rope_tables, mesh)
-    if cfg.remat:
-        block = jax.checkpoint(block, policy=_remat_policy(cfg))
-
-    def scan_body(x, inp):
-        layer_params, kind = inp
-        x, aux = block(x, layer_params, positions, kind=kind)
-        return x, aux
-
-    x, aux_stack = jax.lax.scan(
-        scan_body, x, (layer_stack, _layer_kind_xs(cfg)))
-
-    x = _norm(x, params["ln_f_w"], params["ln_f_b"], cfg.norm)
-    head = params["tok_embed"].T if cfg.tie_embeddings else params["lm_head"]
-    logits = jnp.einsum("bse,ev->bsv", x, head.astype(cfg.dtype))
+    x = _embed(params, tokens, positions, cfg)
+    x, aux_stack = _layer_loop(cfg, mesh, positions)(
+        x, _layer_stack(params), _layer_kind_xs(cfg))
+    logits = _logits(params, x, cfg)
     if return_aux:
         return logits, aux_stack.sum()
     return logits
@@ -752,7 +803,7 @@ def loss_fn(params, batch, cfg: GPTConfig, mesh=None):
 
 def make_train_step(cfg: GPTConfig, optimizer, mesh=None, loss=None) -> Callable:
     """Returns `step(state, batch) -> (state, metrics)`; jit at the call site
-    with shardings (see ray_tpu.train.JaxTrainer / bench.py). `loss`
+    with shardings (see ray_tpu.train.JaxTrainer, benchmarks/runners/train.py). `loss`
     overrides the loss callable (params, batch) -> scalar — the pipeline
     train step rides this hook."""
     if loss is None:
@@ -856,44 +907,15 @@ def stage_forward(
     slice, final norm + head if `last`. `inp` is tokens [B, S] on the first
     stage, activations [B, S, E] (cfg.dtype — what the compiled-DAG edge
     ships between hosts) otherwise. Returns (output, moe_aux_sum)."""
-    _refuse_new_fields(cfg, "a pipeline stage", moe=False)
-    if first:
-        _, S = inp.shape
-        if positions is None:
-            positions = jnp.arange(S) if mesh is not None else global_positions(cfg, S)
-        x = stage_params["tok_embed"][inp].astype(cfg.dtype)
-        if cfg.pos == "learned":
-            x = x + stage_params["pos_embed"][positions].astype(cfg.dtype)
-    else:
-        x = inp.astype(cfg.dtype)
-        S = x.shape[1]
-        if positions is None:
-            positions = jnp.arange(S) if mesh is not None else global_positions(cfg, S)
-
-    rope_tables = None
-    if cfg.pos == "rotary":
-        rd = min(cfg.rotary_dim, cfg.d_head)
-        rope_tables = rope_frequencies(
-            rd, cfg.max_seq, theta=cfg.rope_theta, dtype=jnp.float32)
-
-    layer_stack = {k: stage_params[k] for k in _LAYER_KEYS if k in stage_params}
-    block = functools.partial(_block, cfg, rope_tables, mesh)
-    if cfg.remat:
-        block = jax.checkpoint(block, policy=_remat_policy(cfg))
-
-    def scan_body(x, layer_params):
-        x, aux = block(x, layer_params, positions)
-        return x, aux
-
-    x, aux_stack = jax.lax.scan(scan_body, x, layer_stack)
+    _refuse_new_fields(cfg, "a pipeline stage")
+    S = inp.shape[1]
+    if positions is None:
+        positions = jnp.arange(S) if mesh is not None else global_positions(cfg, S)
+    x = _embed(stage_params, inp, positions, cfg) if first else inp.astype(cfg.dtype)
+    x, aux_stack = _layer_loop(cfg, mesh, positions)(x, _layer_stack(stage_params))
     if not last:
         return x, aux_stack.sum()
-    x = _norm(x, stage_params["ln_f_w"], stage_params["ln_f_b"], cfg.norm)
-    head = (
-        stage_params["tok_embed"].T if cfg.tie_embeddings else stage_params["lm_head"]
-    )
-    logits = jnp.einsum("bse,ev->bsv", x, head.astype(cfg.dtype))
-    return logits, aux_stack.sum()
+    return _logits(stage_params, x, cfg), aux_stack.sum()
 
 
 def check_mpmd_partitionable(
@@ -1044,7 +1066,7 @@ def pipeline_loss_fn(
 
     from ..parallel.spmd import shard_fn
 
-    _refuse_new_fields(cfg, "the GPipe pipeline", moe=False)
+    _refuse_new_fields(cfg, "the GPipe pipeline")
     if cfg.attn_impl in ("ring", "ulysses"):
         raise NotImplementedError(
             f"attn_impl={cfg.attn_impl!r} needs its own manual sp axis and "
@@ -1059,31 +1081,17 @@ def pipeline_loss_fn(
         raise ValueError(f"batch {B} not divisible by num_microbatches {M}")
     positions = jnp.arange(S_len)
 
-    x = params["tok_embed"][inputs].astype(cfg.dtype)
-    if cfg.pos == "learned":
-        x = x + params["pos_embed"][positions].astype(cfg.dtype)
+    x = _embed(params, inputs, positions, cfg)
     # The pipeline input crosses the shard_map boundary in f32: AD transposes
     # its stage-0 broadcast into a psum, and bf16 psums crash the partitioner
     # in subset-manual mode (see the matching forward-path comment below).
     xm = x.reshape(M, B // M, S_len, x.shape[-1]).astype(jnp.float32)
 
-    rope_tables = None
-    if cfg.pos == "rotary":
-        rd = min(cfg.rotary_dim, cfg.d_head)
-        rope_tables = rope_frequencies(
-            rd, cfg.max_seq, theta=cfg.rope_theta, dtype=jnp.float32)
-
-    stage_stack = {k: params[k] for k in _LAYER_KEYS if k in params}
-    block = functools.partial(_block, cfg, rope_tables, None)
-    if cfg.remat:
-        block = jax.checkpoint(block, policy=_remat_policy(cfg))
+    stage_stack = _layer_stack(params)
+    layers = _layer_loop(cfg, None, positions)
 
     def stage_fn(stage_params, act):
-        def body(h, layer_params):
-            h, aux = block(h, layer_params, positions)
-            return h, aux
-
-        act, aux_stack = lax.scan(body, act, stage_params)
+        act, aux_stack = layers(act, stage_params)
         return act, aux_stack.sum()
 
     def per_stage(stacked, xm):
@@ -1129,10 +1137,7 @@ def pipeline_loss_fn(
     y, aux = gpipe(stage_stack, xm)
     y = y.astype(cfg.dtype).reshape(B, S_len, -1)
 
-    h = _norm(y, params["ln_f_w"].astype(cfg.dtype), params["ln_f_b"].astype(cfg.dtype), cfg.norm)
-    head = params["tok_embed"].T if cfg.tie_embeddings else params["lm_head"]
-    logits = jnp.einsum("bse,ev->bsv", h, head.astype(cfg.dtype))
-    return _ce_loss(logits, targets, mask) + aux
+    return _ce_loss(_logits(params, y, cfg), targets, mask) + aux
 
 
 def make_pipeline_train_step(
@@ -1151,11 +1156,11 @@ def make_pipeline_train_step(
 
 
 # ---------------------------------------------------------------- generation
-# KV-cache autoregressive inference (reference analog: the Serve LLM
-# deployments the reference runs through vLLM/transformers — here decode is
-# a first-class device-side loop: prefill fills the cache in one forward,
-# then `lax.scan` advances one token per step entirely on-device, so a
-# generation of N tokens is ONE dispatch, not N host round-trips).
+# Dense KV-cache generation: prefill fills a [L, B, H, M, Dh] cache in one
+# forward, then `lax.scan` advances one token per step on the device, so a
+# generation of N tokens is ONE dispatch. Serving runs the paged programs
+# below; these are their parity reference (tests/test_generate.py, the
+# engine's tests) and chip_smoke.py's check, not a serving path.
 
 
 def init_cache(cfg: GPTConfig, batch: int, max_seq: Optional[int] = None):
@@ -1175,29 +1180,20 @@ def prefill(params, tokens, cfg: GPTConfig, cache):
     Returns (last_logits [B, V] f32, cache). Prompts are fixed-length
     (left-pad upstream for ragged batches). No remat (inference)."""
     _refuse_new_fields(cfg, "the dense-cache prefill")
-    B, S = tokens.shape
+    S = tokens.shape[1]
     positions = jnp.arange(S)
-    x = params["tok_embed"][tokens].astype(cfg.dtype)
-    if cfg.pos == "learned":
-        x = x + params["pos_embed"][positions].astype(cfg.dtype)
-    rope_tables = None
-    if cfg.pos == "rotary":
-        rd = min(cfg.rotary_dim, cfg.d_head)
-        rope_tables = rope_frequencies(
-            rd, cfg.max_seq, theta=cfg.rope_theta, dtype=jnp.float32)
-    layer_stack = {k: params[k] for k in _LAYER_KEYS if k in params}
-
+    x = _embed(params, tokens, positions, cfg)
+    rope_tables = _rope_tables(cfg)
     icfg = dataclasses.replace(cfg, remat=False, remat_policy=None)
 
+    def attend(q, k, v, kind):          # the post-RoPE K/V are what the cache stores
+        return _attention(icfg, q, k, v), (k, v)
+
     def scan_body(x, layer_params):
-        x, (aux, k, v) = _block(
-            icfg, rope_tables, None, x, layer_params, positions, return_kv=True
-        )
-        return x, (k, v)
+        x, kv, _, _ = _block(icfg, rope_tables, attend, x, layer_params, positions)
+        return x, kv
 
-    x, (ks, vs) = jax.lax.scan(scan_body, x, layer_stack)  # [L, B, H, S, Dh]
-
-    M = cache["k"].shape[3]
+    x, (ks, vs) = jax.lax.scan(scan_body, x, _layer_stack(params))  # [L, B, H, S, Dh]
     cache = {
         "k": jax.lax.dynamic_update_slice(
             cache["k"], ks.astype(cache["k"].dtype), (0, 0, 0, 0, 0)
@@ -1207,10 +1203,7 @@ def prefill(params, tokens, cfg: GPTConfig, cache):
         ),
         "len": jnp.asarray(S, jnp.int32),
     }
-    x = _norm(x, params["ln_f_w"], params["ln_f_b"], cfg.norm)
-    head = params["tok_embed"].T if cfg.tie_embeddings else params["lm_head"]
-    logits = jnp.einsum("be,ev->bv", x[:, -1], head.astype(cfg.dtype))
-    return logits.astype(jnp.float32), cache
+    return _logits(params, x[:, -1], cfg).astype(jnp.float32), cache
 
 
 def decode_step(params, token, cache, cfg: GPTConfig):
@@ -1220,39 +1213,13 @@ def decode_step(params, token, cache, cfg: GPTConfig):
     matmuls are [B,H,1,D]x[B,H,M,D]; flash brings nothing and Pallas grid
     overhead would dominate."""
     _refuse_new_fields(cfg, "the dense-cache decode_step")
-    B = token.shape[0]
     pos = cache["len"]                       # scalar int32
-    x = params["tok_embed"][token][:, None].astype(cfg.dtype)  # [B, 1, E]
-    if cfg.pos == "learned":
-        x = x + params["pos_embed"][pos][None, None].astype(cfg.dtype)
-    rope_tables = None
-    if cfg.pos == "rotary":
-        rd = min(cfg.rotary_dim, cfg.d_head)
-        rope_tables = rope_frequencies(
-            rd, cfg.max_seq, theta=cfg.rope_theta, dtype=jnp.float32)
-    M = cache["k"].shape[3]
+    x = _embed(params, token[:, None], pos, cfg)            # [B, 1, E]
+    rope_tables = _rope_tables(cfg)
     scale = 1.0 / math.sqrt(cfg.d_head)
-    H, Dh = cfg.n_heads, cfg.d_head
-    cols = jnp.arange(M)
+    cols = jnp.arange(cache["k"].shape[3])
 
-    layer_stack = {k: params[k] for k in _LAYER_KEYS if k in params}
-
-    def scan_body(x, inp):
-        layer_params, ck, cv = inp
-        p = jax.tree_util.tree_map(lambda a: a.astype(cfg.dtype), layer_params)
-        h = _norm(x, p["ln1_w"], p["ln1_b"], cfg.norm)
-        qkv = jnp.einsum("bse,ethd->btshd", h, p["w_qkv"]) + p["b_qkv"][:, None]
-        q, k, v = (
-            qkv[:, i].transpose(0, 2, 1, 3).reshape(B, H, 1, Dh) for i in range(3)
-        )
-        if cfg.pos == "rotary":
-            cos, sin = rope_tables
-            rd = min(cfg.rotary_dim, Dh)
-            c, s = cos[pos][None], sin[pos][None]  # [1, rd/2]
-            q = jnp.concatenate([apply_rope(q[..., :rd], c, s, None), q[..., rd:]], -1) \
-                if rd < Dh else apply_rope(q, c, s, None)
-            k = jnp.concatenate([apply_rope(k[..., :rd], c, s, None), k[..., rd:]], -1) \
-                if rd < Dh else apply_rope(k, c, s, None)
+    def attend(ck, cv, q, k, v, kind):
         ck = jax.lax.dynamic_update_slice(ck, k.astype(ck.dtype), (0, 0, pos, 0))
         cv = jax.lax.dynamic_update_slice(cv, v.astype(cv.dtype), (0, 0, pos, 0))
         scores = jnp.einsum(
@@ -1260,24 +1227,18 @@ def decode_step(params, token, cache, cfg: GPTConfig):
         ) * scale                                      # [B, H, 1, M]
         scores = jnp.where(cols[None, None, None, :] <= pos, scores, -1e30)
         probs = jax.nn.softmax(scores, axis=-1)
-        attn = jnp.einsum("bhst,bhtd->bhsd", probs.astype(cv.dtype), cv)
-        attn_out = jnp.einsum("bhsd,hde->bse", attn, p["w_o"]) + p["b_o"]
+        return jnp.einsum("bhst,bhtd->bhsd", probs.astype(cv.dtype), cv), (ck, cv)
 
-        if cfg.parallel_block:
-            mlp_in = h
-        else:
-            x = x + attn_out
-            mlp_in = _norm(x, p["ln2_w"], p["ln2_b"], cfg.norm)
-        mlp_out = _dense_mlp(cfg, p, mlp_in)
-        out = x + attn_out + mlp_out if cfg.parallel_block else x + mlp_out
-        return out, (ck, cv)
+    def scan_body(x, inp):
+        layer_params, ck, cv = inp
+        x, kv, _, _ = _block(cfg, rope_tables, functools.partial(attend, ck, cv),
+                             x, layer_params, pos)
+        return x, kv
 
-    x, (ks, vs) = jax.lax.scan(scan_body, x, (layer_stack, cache["k"], cache["v"]))
+    x, (ks, vs) = jax.lax.scan(
+        scan_body, x, (_layer_stack(params), cache["k"], cache["v"]))
     cache = {"k": ks, "v": vs, "len": pos + 1}
-    x = _norm(x, params["ln_f_w"], params["ln_f_b"], cfg.norm)
-    head = params["tok_embed"].T if cfg.tie_embeddings else params["lm_head"]
-    logits = jnp.einsum("be,ev->bv", x[:, -1], head.astype(cfg.dtype))
-    return logits.astype(jnp.float32), cache
+    return _logits(params, x[:, -1], cfg).astype(jnp.float32), cache
 
 
 # --------------------------------------------------- paged KV-cache decode
@@ -1351,27 +1312,6 @@ def init_paged_cache(cfg: GPTConfig, num_blocks: int, block_size: int):
     shape = (kv_layout(cfg).per_group, num_blocks, block_size,
              cfg.kv_heads * cfg.d_head)
     return {"k": jnp.zeros(shape, cfg.dtype), "v": jnp.zeros(shape, cfg.dtype)}
-
-
-def _rope_rotate(x, c, s):
-    """Half-split rotation with caller-broadcast (cos, sin) — the per-lane
-    positions of a paged decode batch don't fit apply_rope's leading-dim
-    broadcast."""
-    x1, x2 = jnp.split(x, 2, axis=-1)
-    return jnp.concatenate([x1 * c - x2 * s, x2 * c + x1 * s], -1).astype(x.dtype)
-
-
-def _rope_qk(cfg: GPTConfig, q, k, rope_tables, positions):
-    """RoPE for [B, H, S, Dh] q/k at per-lane integer positions [B, S]."""
-    cos, sin = rope_tables
-    rd = min(cfg.rotary_dim, cfg.d_head)
-    c = cos[positions][:, None]  # [B, 1, S, rd/2]
-    s = sin[positions][:, None]
-    if rd < cfg.d_head:
-        q = jnp.concatenate([_rope_rotate(q[..., :rd], c, s), q[..., rd:]], -1)
-        k = jnp.concatenate([_rope_rotate(k[..., :rd], c, s), k[..., rd:]], -1)
-        return q, k
-    return _rope_rotate(q, c, s), _rope_rotate(k, c, s)
 
 
 # Keys one trip of the paged key loop covers (`_paged_layers`). A table of
@@ -1465,15 +1405,8 @@ def _paged_layers(params, tokens, pos, valid, block_tables, kv, cfg: GPTConfig):
     H, Hkv, Dh = cfg.n_heads, cfg.kv_heads, cfg.d_head
     R = H // Hkv
     scale = 1.0 / math.sqrt(Dh)
-    x = params["tok_embed"][tokens].astype(cfg.dtype)  # [B, S, E]
-    if cfg.pos == "learned":
-        x = x + params["pos_embed"][pos].astype(cfg.dtype)
-    rope_tables = None
-    if cfg.pos == "rotary":
-        rope_tables = rope_frequencies(
-            min(cfg.rotary_dim, Dh), cfg.max_seq, theta=cfg.rope_theta,
-            dtype=jnp.float32
-        )
+    x = _embed(params, tokens, pos, cfg)               # [B, S, E]
+    rope_tables = _rope_tables(cfg)
     blk = jnp.minimum(pos // BS, W - 1)
 
     def physical(table):                               # [B, W] -> [B, S]
@@ -1482,7 +1415,7 @@ def _paged_layers(params, tokens, pos, valid, block_tables, kv, cfg: GPTConfig):
     phys = physical(block_tables) if G == 1 else None
     off = pos % BS
     qpos = pos[:, None, :, None]
-    layer_stack = {k: params[k] for k in _LAYER_KEYS if k in params}
+    layer_stack = _layer_stack(params)
     kinds = _layer_kind_xs(cfg)
     if kinds is not None:
         kinds["group"] = jnp.asarray(lay.group_of, jnp.int32)
@@ -1514,7 +1447,8 @@ def _paged_layers(params, tokens, pos, valid, block_tables, kv, cfg: GPTConfig):
         ) * scale
         return jnp.where(mask, scores, -1e30), gv
 
-    def attend(q, kk, vv, slot, table, window):
+    def gathered(q, kk, vv, slot, table, window):
+        """Attention of q [B, Hkv, R*S, Dh] over the rows `table` names."""
         if NT == 1:
             scores, gv = scores_of(q, kk, vv, slot, table, kpos, seen, window)
             probs = jax.nn.softmax(scores, axis=-1)
@@ -1546,6 +1480,25 @@ def _paged_layers(params, tokens, pos, valid, block_tables, kv, cfg: GPTConfig):
             jnp.zeros(q.shape, jnp.float32)))
         return (acc / l[..., None]).astype(vv.dtype)
 
+    def attend(kk, vv, l, q, k, v, kind):
+        """The new rows into the pool (kk, vv) at the layer's slot, then
+        attention over the layer's table; the pool is the state."""
+        k = k.transpose(0, 2, 1, 3).reshape(B, S, Hkv * Dh)
+        v = v.transpose(0, 2, 1, 3).reshape(B, S, Hkv * Dh)
+        if G == 1:
+            slot, table, ph = l, block_tables, phys
+        else:
+            slot = kind["slot"]
+            table = jnp.take(block_tables, kind["group"], axis=1)
+            ph = physical(table)
+        kk = kk.at[slot, ph, off].set(k.astype(kk.dtype))
+        vv = vv.at[slot, ph, off].set(v.astype(vv.dtype))
+        if R > 1:   # the R query heads of a K/V head ride its query axis
+            q = q.reshape(B, Hkv, R * S, Dh)
+        attn = gathered(q, kk, vv, slot, table,
+                        None if kind is None else kind["window"])
+        return attn.reshape(B, H, S, Dh) if R > 1 else attn, (kk, vv)
+
     # A step of a few tokens reads only the experts they chose: the expert
     # stacks then stay whole (a slice the scan cuts would be copied into the
     # inner loop) and the layer number finds the expert where it lies.
@@ -1557,62 +1510,16 @@ def _paged_layers(params, tokens, pos, valid, block_tables, kv, cfg: GPTConfig):
     def scan_body(carry, inp):
         x, kk, vv = carry                              # kk/vv: the whole pool
         l, layer_params, kind = inp
-        p = jax.tree_util.tree_map(lambda a: a.astype(cfg.dtype), layer_params)
-        block_in = x
-        h = _norm(x, p["ln1_w"], p["ln1_b"], cfg.norm)
-        q, k, v = _project_qkv(cfg, p, h)              # [B, S, heads, Dh]
-        q, k = q.transpose(0, 2, 1, 3), k.transpose(0, 2, 1, 3)  # [B,heads,S,Dh]
-        if cfg.pos == "rotary":
-            qr, kr = _rope_qk(cfg, q, k, rope_tables, pos)
-            q, k = (qr, kr) if kind is None else (
-                jnp.where(kind["rope"], qr, q), jnp.where(kind["rope"], kr, k))
-        k = k.transpose(0, 2, 1, 3).reshape(B, S, Hkv * Dh)
-        v = v.reshape(B, S, Hkv * Dh)
-        if G == 1:
-            slot, table, ph = l, block_tables, phys
-        else:
-            slot = kind["slot"]
-            table = jnp.take(block_tables, kind["group"], axis=1)
-            ph = physical(table)
-        kk = kk.at[slot, ph, off].set(k.astype(kk.dtype))
-        vv = vv.at[slot, ph, off].set(v.astype(vv.dtype))
-        if R > 1:
-            q = q.reshape(B, Hkv, R * S, Dh)
-        attn = attend(q, kk, vv, slot, table,
-                      None if kind is None else kind["window"])
-        if R > 1:
-            attn = attn.reshape(B, H, S, Dh)
-        attn_out = jnp.einsum("bhsd,hde->bse", attn, p["w_o"]) + p["b_o"]
-
-        if cfg.parallel_block:
-            mlp_in = h
-        else:
-            x = x + attn_out
-            mlp_in = _norm(x, p["ln2_w"], p["ln2_b"], cfg.norm)
-        load = None
-        if moe:
-            experts = stacks or (p["moe_w_gate"], p["moe_w_in"], p["moe_w_out"])
-            mlp_out, load = _dropless_mlp(
-                cfg, layer_params["moe_router"], experts, block_in, mlp_in,
-                layer=l if stacks else None, valid=real)
-            load = jnp.stack(load)
-        else:
-            mlp_out = _dense_mlp(cfg, p, mlp_in)
-        out = x + attn_out + mlp_out if cfg.parallel_block else x + mlp_out
-        return (out, kk, vv), load
+        x, (kk, vv), _, load = _block(
+            cfg, rope_tables, functools.partial(attend, kk, vv, l), x,
+            layer_params, pos, kind, stacks, l, real)
+        return (x, kk, vv), load
 
     (x, kk, vv), loads = jax.lax.scan(
         scan_body, (x, kv["k"], kv["v"]),
         (jnp.arange(cfg.n_layers), layer_stack, kinds),
     )
     return x, {"k": kk, "v": vv}, (loads.mean(axis=0) if moe else None)
-
-
-def _paged_logits(params, x, cfg: GPTConfig):
-    """Final norm and head over hidden states [..., E] -> [..., V] f32."""
-    x = _norm(x, params["ln_f_w"], params["ln_f_b"], cfg.norm)
-    head = params["tok_embed"].T if cfg.tie_embeddings else params["lm_head"]
-    return jnp.einsum("...e,ev->...v", x, head.astype(cfg.dtype)).astype(jnp.float32)
 
 
 def prefill_paged(params, tokens, real_len, pos_offset, block_table, kv,
@@ -1639,7 +1546,7 @@ def prefill_paged(params, tokens, real_len, pos_offset, block_table, kv,
         params, tokens, pos, (rel < real_len)[None], block_table[None], kv, cfg
     )
     h = x[0, jnp.maximum(real_len - 1, 0)]  # [E] — last REAL chunk position
-    return _paged_logits(params, h, cfg), kv
+    return _logits(params, h, cfg).astype(jnp.float32), kv
 
 
 def decode_step_paged(params, token, positions, block_tables, kv, cfg: GPTConfig):
@@ -1658,7 +1565,7 @@ def decode_step_paged(params, token, positions, block_tables, kv, cfg: GPTConfig
     x, kv, load = _paged_layers(
         params, token[:, None], positions[:, None], True, block_tables, kv, cfg
     )
-    logits = _paged_logits(params, x[:, 0], cfg)
+    logits = _logits(params, x[:, 0], cfg).astype(jnp.float32)
     return (logits, kv) if load is None else ((logits, load), kv)
 
 
@@ -1684,13 +1591,16 @@ def verify_step_paged(params, tokens, positions, valid_len, block_tables, kv,
     x, kv, _ = _paged_layers(
         params, tokens, pos, rel < valid_len[:, None], block_tables, kv, cfg
     )
-    return _paged_logits(params, x, cfg), kv
+    return _logits(params, x, cfg).astype(jnp.float32), kv
 
 
 def make_generate(cfg: GPTConfig, max_new_tokens: int, temperature: float = 0.0):
     """Returns jittable `gen(params, prompt [B, S0], rng) -> tokens
     [B, max_new_tokens]`: prefill + a device-side `lax.scan` decode loop —
-    one dispatch per GENERATION, not per token."""
+    one dispatch per GENERATION, not per token. A reference and a smoke
+    check over the dense cache: every prompt of a batch has one length and
+    nothing is admitted while it runs (the engine serves the paged
+    programs)."""
 
     def sample(logits, key):
         if temperature <= 0.0:

@@ -6,7 +6,7 @@ from .attention import (
 )
 from .moe import MoEConfig, moe_forward, moe_init, moe_router
 from .norms import layernorm, rmsnorm
-from .rope import apply_rope, rope_frequencies
+from .rope import apply_rope, rope_frequencies, rotate_half
 
 __all__ = [
     "flash_attention",
@@ -16,6 +16,7 @@ __all__ = [
     "rmsnorm",
     "layernorm",
     "apply_rope",
+    "rotate_half",
     "rope_frequencies",
     "MoEConfig",
     "moe_init",

@@ -14,23 +14,25 @@ def rope_frequencies(head_dim: int, max_seq: int, theta: float = 10000.0, dtype=
     return jnp.cos(ang).astype(dtype), jnp.sin(ang).astype(dtype)
 
 
-def apply_rope(x, cos, sin, positions=None):
-    """x: [..., seq, head_dim]; cos/sin: [max_seq, head_dim//2].
+def rotate_half(x, c, s):
+    """Rotate x [..., seq, head_dim] by the angles whose (cos, sin) are c, s
+    [..., seq, head_dim//2], leading dims broadcast.
 
     Rotates pairs (x[2i], x[2i+1]) — GPT-NeoX/Llama convention via
     half-split (equivalent under a fixed permutation of dims).
     """
-    seq = x.shape[-2]
-    if positions is None:
-        c = cos[:seq]
-        s = sin[:seq]
-    else:
-        c = cos[positions]
-        s = sin[positions]
-    # Broadcast [seq, hd/2] across leading dims.
     while c.ndim < x.ndim:
         c = c[None]
         s = s[None]
     x1, x2 = jnp.split(x, 2, axis=-1)
     out = jnp.concatenate([x1 * c - x2 * s, x2 * c + x1 * s], axis=-1)
     return out.astype(x.dtype)
+
+
+def apply_rope(x, cos, sin, positions=None):
+    """x: [..., seq, head_dim]; cos/sin: [max_seq, head_dim//2]; rows
+    `positions` of the tables, or the first `seq`."""
+    seq = x.shape[-2]
+    if positions is None:
+        return rotate_half(x, cos[:seq], sin[:seq])
+    return rotate_half(x, cos[positions], sin[positions])

@@ -4,7 +4,7 @@ Reference analog: `rllib/algorithms/dt/dt.py` + `dt_torch_model.py` —
 return-conditioned behavior cloning: interleave (return-to-go, state,
 action) tokens, train a causal transformer to predict actions, act at eval
 time by conditioning on a target return. TPU redesign: the transformer
-REUSES this framework's GPT block stack (`models/gpt._block` — the same
+REUSES this framework's GPT block stack (`models/gpt._layer_loop` — the same
 jitted lax.scan layers, norms, and attention the LLM path uses) under
 custom continuous-input embeddings; the whole update is the shared
 `make_supervised_update` scan program (one XLA call per iteration).
@@ -18,7 +18,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ...models.gpt import GPTConfig, _LAYER_KEYS, _block, _norm, init_params
+from ...models.gpt import GPTConfig, _LAYER_KEYS, _layer_loop, _norm, init_params
 from ..core.learner import Learner
 from ..offline import EpisodeDataset
 from .algorithm import Algorithm
@@ -111,11 +111,7 @@ class DTModule:
 
         positions = jnp.arange(3 * K)
 
-        def scan_body(x, layer_params):
-            x, _ = _block(self.block_cfg, None, None, x, layer_params, positions)
-            return x, None
-
-        x, _ = jax.lax.scan(scan_body, x, params["blocks"])
+        x, _ = _layer_loop(self.block_cfg, None, positions)(x, params["blocks"])
         x = _norm(x, params["ln_f_w"], params["ln_f_b"], "layernorm")
         h_state = x[:, 1::3]  # the state-token positions predict actions
         return h_state @ params["w_head"] + params["b_head"]

@@ -5,7 +5,7 @@ group, each replica updates only its 1/dp chunk of the flat f32 optimizer
 state (adam m/v + f32 master params), and the updated parameter chunks
 ALL-GATHER back into the full working tree — optimizer memory per replica
 drops ~dp x vs a replicated adamw, which is exactly the state that OOMs
-first at GPT-J scale (MULTICHIP_GPTJ_r5.json had to drop dp entirely).
+first at GPT-J scale.
 
 Layout contract: the flat space is chunked with np.array_split sizing
 (`collective.ops.zero_shard_bounds`) — the SAME rule the host-plane

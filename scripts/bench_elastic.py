@@ -1,6 +1,6 @@
 """Elastic training bench: recovery MTTR + async checkpoint save overlap.
 
-Two measurements (ISSUE 4 satellite; records BENCH_ELASTIC_r01.json):
+Two measurements (ISSUE 4 satellite; written to `--out`):
 
   * recovery — boot the multiprocess cluster, run a 2-worker elastic gang
     with per-step collectives, SIGKILL one member after the gang has

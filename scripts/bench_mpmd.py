@@ -272,7 +272,9 @@ def run(record: bool, steps: int, quick: bool, interleave: int = 2,
     zero_bytes = mp["opt_bytes_per_replica"]
     rep_bytes = mp_rep["opt_bytes_per_replica"]
     tokens_per_step = batch * cfg.max_seq
-    flops_per_step = cfg.flops_per_token(cfg.max_seq) * tokens_per_step
+    # Training FLOPs a token: 6 a parameter plus the attention term.
+    flops_per_step = (6.0 * cfg.n_params + 12.0 * cfg.n_layers * cfg.d_model
+                      * cfg.max_seq) * tokens_per_step
     out = {
         "bench": "mpmd_pipeline_training",
         "host": {"nproc": os.cpu_count(), "note": "1-vCPU shared box; CPU jax"},

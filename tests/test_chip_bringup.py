@@ -119,11 +119,11 @@ def test_claim_chips_pins_a_subset(fake_dev, monkeypatch):
 
 
 def test_unknown_device_kind_has_no_peak():
-    import bench
+    from benchmarks.peaks import peak
 
-    assert bench.peak_flops_per_chip("TPU v5 lite") == 197e12
+    assert peak("TPU v5 lite")["bf16_flops"] == 197e12
     with pytest.raises(ValueError, match="TPU v9"):
-        bench.peak_flops_per_chip("TPU v9")
+        peak("TPU v9")
 
 
 def test_on_tpu_is_exact(monkeypatch):

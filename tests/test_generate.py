@@ -27,7 +27,10 @@ def _cfg(**kw):
     _cfg(),
     _cfg(pos="rotary", rotary_dim=16, parallel_block=True,
          tie_embeddings=False, norm="rmsnorm", activation="swiglu"),
-], ids=["gpt2-style", "gptj-style"])
+    # The one block brings the expert MLP to the dense cache too.
+    _cfg(activation="reglu", mlp_type="moe", moe_experts=4, moe_top_k=2,
+         moe_routing="dropless"),
+], ids=["gpt2-style", "gptj-style", "dropless-experts"])
 def test_decode_matches_forward(cfg):
     params = init_params(jax.random.PRNGKey(0), cfg)
     tokens = jax.random.randint(jax.random.PRNGKey(1), (2, 12), 0, cfg.vocab_size)

@@ -1,6 +1,6 @@
 """Kernel correctness vs the XLA reference, incl. ring/Ulysses on the fake
 8-device mesh. The Pallas compiled path itself is exercised on real TPU by
-bench.py; here the interpret path + CPU fallbacks guard the math."""
+the train cells of BENCHMARK.json; here the interpret path + CPU fallbacks guard the math."""
 
 import functools
 

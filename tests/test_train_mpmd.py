@@ -508,7 +508,18 @@ class TestParityGate:
         against both the unpipelined reference and the proven v=1
         pipeline (4 layers split into 2*2 virtual stages). The chunked
         jit programs fuse differently, so parity is allclose, not
-        bitwise."""
+        bitwise, and v=2 is held to v=1 at the tolerances that hold both
+        to the reference. Read on the CPU (PR 30), M=2: the losses differ
+        by half a float32 ulp in the second step (4.8698375 against
+        4.8698378, 4.9e-8 relative) and one element of 384 of `b_qkv`
+        ends 1.145e-6 apart (-1.1e-7 against 1.03e-6; the reference has
+        6.9e-7, and v=1 itself is up to 7.8e-7 from it). It is a K bias:
+        softmax ignores a constant added to every key, so its gradient
+        is zero but for rounding (-4.7e-12, -1.1e-13 in the reference)
+        and adamw divides that noise by sqrt(v) + 1e-8. M=4 read 6.8e-7
+        on another such element. An absolute 1e-6 between two float32
+        programs was under that noise; 1e-5 holds them as it holds each
+        to the reference."""
         cfg, params, batches = tiny_model
         ref_p, ref_losses, ref_gnorms, _ = self._reference(cfg, params, batches)
         out1 = run_local_pipeline(cfg, 2, 1, M, batches, params=params, lr=1e-3)
@@ -526,14 +537,14 @@ class TestParityGate:
         np.testing.assert_allclose(
             [h["loss"] for h in outv["history"]],
             [h["loss"] for h in out1["history"]],
-            rtol=1e-6,
+            rtol=2e-5, atol=1e-6,
         )
         for k, val in outv["params"].items():
             np.testing.assert_allclose(
                 val, np.asarray(ref_p[k]), rtol=1e-4, atol=1e-5, err_msg=k
             )
             np.testing.assert_allclose(
-                val, out1["params"][k], rtol=1e-4, atol=1e-6, err_msg=k
+                val, out1["params"][k], rtol=1e-4, atol=1e-5, err_msg=k
             )
 
     def test_bf16_wire_loss_curve(self, tiny_model):
