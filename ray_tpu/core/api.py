@@ -186,6 +186,16 @@ def _atexit_shutdown():
 
 def shutdown():
     global _runtime, _runtime_factory
+    if _runtime is not None:
+        # Counters and gauges still pending in this process ship once
+        # (util/metrics.py; the atexit hook comes through here too). Outside
+        # the lock: the flusher thread may be forcing a worker's runtime.
+        from ..util import metrics
+
+        try:
+            metrics.flush()
+        except Exception:  # noqa: BLE001 — metrics never hold up an exit
+            pass
     with _runtime_lock:
         _runtime_factory = None
         if _runtime is not None:

@@ -41,7 +41,7 @@ from typing import Any, Dict, Iterator, List, Optional
 
 from collections import deque
 
-from ...util import flight
+from ...util import flight, metrics as _metrics
 from ...util.metrics import quantile as _quantile
 from .kv_manager import KVBlockManager
 from .scheduler import Scheduler, Sequence, SchedulerOutput, _next_pow2
@@ -1165,6 +1165,10 @@ class InferenceEngine:
             "total_preemptions": self.total_preemptions,
             "spec_proposed": self.total_spec_proposed,
             "spec_accepted": self.total_spec_accepted,
+            # This PROCESS's totals (util/metrics.py): records made against
+            # messages the flusher handed to the control plane for them.
+            "metric_records": _metrics.records_total,
+            "metric_sends": _metrics.sends_total,
             "spec_acceptance_rate": (
                 round(self.total_spec_accepted / self.total_spec_proposed, 4)
                 if self.total_spec_proposed

@@ -1,18 +1,22 @@
 """User metrics API (reference: `python/ray/util/metrics.py` Counter/Gauge/
 Histogram → OpenCensus → `metrics_agent.py` Prometheus). Redesign: metrics
 push straight to the controller over the control plane and are served from
-its `/metrics` HTTP endpoint (see address.json's metrics_url). Histograms
-accumulate observations into configurable bucket boundaries CLIENT-side and
-ship per-bucket deltas; the controller aggregates and emits real
-`# TYPE <name> histogram` exposition (`_bucket{le=...}` / `_sum` /
-`_count`), so `histogram_quantile()` works in Prometheus."""
+its `/metrics` HTTP endpoint (see address.json's metrics_url). As in the
+reference, a record is a process-local accumulate and an interval ships it:
+every `inc` / `set` / `observe` folds into one pending table and the
+process's flusher thread sends what was written every _FLUSH_INTERVAL_S — a
+counter as the interval's sum, a gauge as its last value, a histogram as
+per-bucket deltas over its CLIENT-side boundaries. The controller adds,
+sets and aggregates them and emits real `# TYPE <name> histogram`
+exposition (`_bucket{le=...}` / `_sum` / `_count`), so
+`histogram_quantile()` works in Prometheus."""
 
 from __future__ import annotations
 
 import bisect
 import threading
 import time
-from typing import Dict, List, Optional, Sequence, Tuple
+from typing import Dict, Optional, Sequence, Tuple
 
 # Default latency-shaped boundaries (seconds), reference-style.
 DEFAULT_BOUNDARIES: Tuple[float, ...] = (
@@ -25,8 +29,7 @@ _FLUSH_INTERVAL_S = 0.25
 
 def _backend():
     """The connected cluster backend, or None (never boots a runtime from a
-    plain script — see api._runtime_or_attach); un-inited processes just
-    keep metrics local."""
+    plain script — see api._runtime_or_attach)."""
     from ..core import api
 
     rt = api._runtime_or_attach()
@@ -39,8 +42,8 @@ def prune_series(tags: Dict[str, str]) -> None:
     not leave gauges frozen in /metrics until the staleness sweep."""
     backend = _backend()
     fn = getattr(backend, "prune_metrics", None) if backend else None
-    if fn is not None:
-        fn({str(k): str(v) for k, v in tags.items()})
+    if fn is not None and tags:
+        _FLUSHER.prune({str(k): str(v) for k, v in tags.items()}, fn)
 
 
 def quantile(xs: Sequence[float], q: float) -> Optional[float]:
@@ -250,6 +253,110 @@ def flight_metrics() -> Dict[str, "_Metric"]:
         return _FLIGHT
 
 
+# Process totals the mechanism is judged by (`InferenceEngine.stats()` reports
+# them as `metric_records` / `metric_sends`): every `inc`, `set` and `observe`
+# made in this process, and every message handed to the backend for them.
+records_total = 0
+sends_total = 0
+
+_Key = Tuple[str, str, Tuple[Tuple[str, str], ...]]  # name, kind, sorted tags
+
+
+class _Flusher:
+    """The process's ONE pending table and the daemon thread that ships it
+    every _FLUSH_INTERVAL_S. A record is a lock-guarded local accumulate (no
+    control-plane message per `inc` / `set` / `observe`): the table is keyed
+    by series, not by instance, so a temporary `Counter("x").inc()` ships,
+    and it holds one entry a tag set, so it cannot grow with the call count.
+    An entry exists only if it was written since the last flush — an old
+    gauge is never re-sent, so the controller's staleness sweep still ages
+    it out."""
+
+    def __init__(self):
+        # Held for one update or one swap, never across a send.
+        self._lock = threading.Lock()
+        # One flush (or prune) at a time, so a gauge's values arrive in the
+        # order they were set; a recording thread never takes it.
+        self._flush_lock = threading.Lock()
+        # series -> the fields of its next message: value, help and, for a
+        # histogram, boundaries / bucket deltas / sum / count.
+        self._pending: Dict[_Key, dict] = {}
+        self._thread: Optional[threading.Thread] = None
+
+    def record(self, metric: "_Metric", value: float,
+               tags: Optional[Dict[str, str]]):
+        from ..core import api
+
+        global records_total
+        merged = {**metric._default_tags, **(tags or {})}
+        key = (metric._name, metric.kind,
+               tuple(sorted((str(k), str(v)) for k, v in merged.items())))
+        # A record from a process WITHOUT a runtime is dropped here, never a
+        # reason to boot one and never kept: an engine unit test's
+        # serve_engine_tokens_total would ship into the cluster a later test
+        # of the same pytest process starts. `is_initialized` is a lock-free
+        # peek; a worker's deferred runtime is forced by the flusher thread.
+        connected = api.is_initialized()
+        with self._lock:
+            records_total += 1
+            if not connected:
+                return
+            entry = self._pending.get(key)
+            if entry is None:
+                entry = self._pending[key] = {"help": metric._description}
+            metric._fold(entry, value)
+            if self._thread is None or not self._thread.is_alive():
+                self._thread = threading.Thread(
+                    target=self._loop, daemon=True, name="metrics-flusher"
+                )
+                self._thread.start()
+
+    def flush(self):
+        global sends_total
+        with self._flush_lock:
+            with self._lock:
+                pending, self._pending = self._pending, {}
+            if not pending:
+                return
+            backend = _backend()
+            send = getattr(backend, "record_metric", None) if backend else None
+            if send is None:
+                return  # the runtime went away since the record: dropped
+            for (name, kind, tags), entry in pending.items():
+                sends_total += 1
+                send(name, kind, entry.pop("value"), dict(tags), **entry)
+
+    def prune(self, match: Dict[str, str], send_prune):
+        """Drop pending entries whose tags include all of `match`, THEN send
+        the prune, behind any flush in flight: a late flush must not
+        resurrect a drained replica's gauges."""
+        with self._flush_lock:
+            with self._lock:
+                for key in [k for k in self._pending
+                            if match.items() <= dict(k[2]).items()]:
+                    del self._pending[key]
+            send_prune(match)
+
+    def _loop(self):
+        while True:
+            time.sleep(_FLUSH_INTERVAL_S)
+            try:
+                self.flush()
+            except Exception:  # noqa: BLE001 — metrics never load-bearing
+                pass
+
+
+_FLUSHER = _Flusher()
+
+
+def flush() -> None:
+    """Ship what is pending now, on the calling thread (one message a series
+    written since the last flush). `ray_tpu.shutdown()` calls it, so a driver
+    that counts and exits loses nothing; a test calls it instead of waiting
+    out the interval."""
+    _FLUSHER.flush()
+
+
 class _Metric:
     kind = "gauge"
 
@@ -263,68 +370,38 @@ class _Metric:
         self._default_tags = dict(tags)
         return self
 
-    def _record(self, value: float, tags: Optional[Dict[str, str]]):
-        # Same non-booting rule as Histogram._flush: a metric record from an
-        # un-inited process is DROPPED, never a reason to boot a runtime
-        # (an engine unit test driving step() used to leak a whole local
-        # runtime into the test session through one Gauge.set).
-        merged = {**self._default_tags, **(tags or {})}
-        backend = _backend()
-        send = getattr(backend, "record_metric", None) if backend else None
-        if send is not None:
-            send(self._name, self.kind, value, merged, help=self._description)
+    def _fold(self, entry: dict, value: float):
+        """Fold one record into the series' pending message (under the
+        flusher's lock)."""
+        raise NotImplementedError
 
 
 class Counter(_Metric):
+    """Monotonic count. Increments of one flush interval arrive at the
+    controller as one message carrying their sum."""
+
     kind = "counter"
 
     def inc(self, value: float = 1.0, tags: Optional[Dict[str, str]] = None):
         if value <= 0:
             raise ValueError("Counter increments must be positive")
-        self._record(value, tags)
+        _FLUSHER.record(self, value, tags)
+
+    def _fold(self, entry: dict, value: float):
+        entry["value"] = entry.get("value", 0.0) + value
 
 
 class Gauge(_Metric):
+    """Last value wins: a gauge set several times in one flush interval
+    arrives as its last value."""
+
     kind = "gauge"
 
     def set(self, value: float, tags: Optional[Dict[str, str]] = None):
-        self._record(value, tags)
+        _FLUSHER.record(self, value, tags)
 
-
-class _Flusher:
-    """One daemon thread per process ships every histogram's pending bucket
-    deltas every _FLUSH_INTERVAL_S — observations stay a lock-guarded local
-    accumulate (no control-plane message per observe), and the tail of a
-    burst still lands without requiring another observe to piggyback on."""
-
-    def __init__(self):
-        self._lock = threading.Lock()
-        self._histograms: List["Histogram"] = []
-        self._thread: Optional[threading.Thread] = None
-
-    def register(self, hist: "Histogram"):
-        with self._lock:
-            if hist not in self._histograms:
-                self._histograms.append(hist)
-            if self._thread is None or not self._thread.is_alive():
-                self._thread = threading.Thread(
-                    target=self._loop, daemon=True, name="metrics-flusher"
-                )
-                self._thread.start()
-
-    def _loop(self):
-        while True:
-            time.sleep(_FLUSH_INTERVAL_S)
-            with self._lock:
-                hists = list(self._histograms)
-            for h in hists:
-                try:
-                    h._flush()
-                except Exception:  # noqa: BLE001 — metrics never load-bearing
-                    pass
-
-
-_FLUSHER = _Flusher()
+    def _fold(self, entry: dict, value: float):
+        entry["value"] = value
 
 
 class Histogram(_Metric):
@@ -347,37 +424,17 @@ class Histogram(_Metric):
         if list(bounds) != sorted(bounds) or len(set(bounds)) != len(bounds):
             raise ValueError(f"histogram boundaries must be sorted/unique: {bounds}")
         self.boundaries = bounds
-        self._plock = threading.Lock()
-        # tags-key -> [bucket deltas (len = len(bounds)+1, last = +Inf),
-        #              sum delta, count delta]
-        self._pending: Dict[Tuple[Tuple[str, str], ...], list] = {}
 
     def observe(self, value: float, tags: Optional[Dict[str, str]] = None):
-        value = float(value)
-        merged = {**self._default_tags, **(tags or {})}
-        key = tuple(sorted((str(k), str(v)) for k, v in merged.items()))
-        idx = bisect.bisect_left(self.boundaries, value)  # le semantics
-        with self._plock:
-            acc = self._pending.get(key)
-            if acc is None:
-                acc = self._pending[key] = [[0] * (len(self.boundaries) + 1), 0.0, 0]
-            acc[0][idx] += 1
-            acc[1] += value
-            acc[2] += 1
-        _FLUSHER.register(self)
+        _FLUSHER.record(self, float(value), tags)
 
-    def _flush(self):
-        with self._plock:
-            if not self._pending:
-                return
-            backend = _backend()
-            send = getattr(backend, "record_metric", None) if backend else None
-            if send is None:
-                return  # keep accumulating; deltas are bounded per tag-set
-            pending, self._pending = self._pending, {}
-        for key, (buckets, total, count) in pending.items():
-            send(
-                self._name, "histogram", 0.0, dict(key),
-                boundaries=list(self.boundaries), buckets=buckets,
-                sum=total, count=count, help=self._description,
-            )
+    def _fold(self, entry: dict, value: float):
+        bounds = self.boundaries
+        if entry.get("boundaries") != bounds:
+            # New entry (or another instance of the name on another grid:
+            # restart the delta, as the controller restarts the series).
+            entry.update(value=0.0, boundaries=bounds,
+                         buckets=[0] * (len(bounds) + 1), sum=0.0, count=0)
+        entry["buckets"][bisect.bisect_left(bounds, value)] += 1  # le semantics
+        entry["sum"] += value
+        entry["count"] += 1

@@ -17,6 +17,7 @@ if "xla_force_host_platform_device_count" not in flags:
     ).strip()
 
 import contextlib  # noqa: E402
+import threading  # noqa: E402
 
 import pytest  # noqa: E402
 
@@ -86,6 +87,49 @@ def cluster_runtime():
 def shutdown_only():
     yield
     ray_tpu.shutdown()
+
+
+class _MetricSink:
+    """What `util.metrics` handed a backend, and from which thread."""
+
+    def __init__(self):
+        self.sent = []      # (thread name, name, kind, value, tags, extra)
+        self.pruned = []    # the tags of each prune
+        self.down = False
+
+    def record_metric(self, name, kind, value, tags, **extra):
+        self.sent.append((threading.current_thread().name, name, kind, value,
+                          dict(tags), extra))
+
+    def prune_metrics(self, tags):
+        self.pruned.append(dict(tags))
+
+    def shutdown(self):
+        self.down = True
+
+    def series(self, name, **tags):
+        """The messages of one series, in the order they arrived."""
+        want = {k: str(v) for k, v in tags.items()}
+        return [m for m in self.sent if m[1] == name and m[4] == want]
+
+
+@pytest.fixture
+def metric_sink(monkeypatch):
+    """This process connected to a runtime whose backend only notes the
+    metric messages it is handed: the real pending table and the real
+    flusher thread of `util.metrics`, no cluster."""
+    import types
+
+    from ray_tpu.core import api
+    from ray_tpu.util import metrics
+
+    _drop_stray_runtime()
+    sink = _MetricSink()
+    rt = types.SimpleNamespace(backend=sink, shutdown=sink.shutdown)
+    monkeypatch.setattr(api, "_runtime", rt)
+    yield sink
+    if api._runtime is rt:
+        metrics.flush()     # nothing of this test stays pending for the next
 
 
 def pytest_configure(config):

@@ -1457,6 +1457,39 @@ class TestEnginePhases:
         assert "engine.completion" in shipped and "engine.step" in shipped
         assert all(thread != "llm-engine" for thread, _ in sends), sends
 
+    def test_step_thread_sends_no_metric(self, tiny_engine_parts, metric_sink):
+        """A step's gauges and counters cost the stepping thread no control-
+        plane send (they were six blocking round trips a step): they fold
+        into the process's pending table and leave on `metrics-flusher`.
+        One flush later the backend holds the counter's whole sum and each
+        gauge's last value, and `stats()` carries the process's totals."""
+        from ray_tpu.util import metrics
+
+        cfg, params = tiny_engine_parts
+        eng = _make_engine(cfg, params)
+        r0, s0 = (eng.stats()[k] for k in ("metric_records", "metric_sends"))
+        eng.submit([1, 2, 3, 4, 5], 6)
+        eng.submit([7, 8, 9], 4)
+        me = threading.current_thread().name
+        steps = []
+        while eng.scheduler.has_work():
+            steps.append(eng.step())
+            assert len(steps) < 50, "engine did not drain"
+        assert not [m for m in metric_sink.sent if m[0] == me], metric_sink.sent
+        metrics.flush()
+        shipped = sum(m[3] for m in metric_sink.series("serve_engine_tokens_total"))
+        assert shipped == eng.total_tokens == 10
+        for name, key in (("serve_engine_queue_depth", "queue_depth"),
+                          ("serve_engine_running_seqs", "running"),
+                          ("serve_engine_kv_utilization", "kv_utilization"),
+                          ("serve_engine_tokens_per_s", "tokens_per_s")):
+            assert metric_sink.series(name)[-1][3] == steps[-1][key], name
+        st = eng.stats()
+        assert type(st["metric_records"]) is int
+        assert type(st["metric_sends"]) is int
+        assert st["metric_records"] - r0 >= 6 * len(steps)
+        assert st["metric_sends"] - s0 == len(metric_sink.sent)
+
     def test_phases_are_annotations_in_a_profiler_session(
         self, tiny_engine_parts, tmp_path
     ):
