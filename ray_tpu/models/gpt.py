@@ -13,7 +13,9 @@ Design (vs the reference's torch models driven through Train/DeepSpeed —
 Flagship configs: `gpt2_*` (LayerNorm/GELU/learned-pos), `gptj_6b`
 (parallel block + rotary), `llama_7b`-style (RMSNorm/SwiGLU/rotary),
 `smallthinker_21b_a3b` (grouped-query heads, window and global layers,
-dropless top-k experts; served through the paged programs).
+dropless top-k experts; served through the paged programs), `ouro_2_6b`
+(sandwich norms, the layer stack run four times over shared weights with an
+exit gate; served through the paged programs).
 """
 
 from __future__ import annotations
@@ -60,6 +62,16 @@ class GPTConfig:
     sliding_window_layout: Optional[Tuple[int, ...]] = None
     sliding_window: int = 0
     parallel_block: bool = False     # GPT-J: attn and mlp in parallel
+    # Sandwich norms: a second norm on each sublayer's OUTPUT before it joins
+    # the stream, x + N2(Attn(N1 x)) then a + N4(Mlp(N3 a)).
+    sandwich_norm: bool = False
+    # Layers run several times (a looped model): the whole stack `ut_steps`
+    # times over the SAME weights, the final norm closing every pass and its
+    # output the next pass's input, a one-unit exit gate read after each
+    # (`_close_pass`); each (pass, layer) pair keeps its own keys and values
+    # (`kv_layout`). Served by `forward` and the paged programs; every token
+    # runs every pass, whatever the gate reads.
+    ut_steps: int = 1
     tie_embeddings: bool = True
     # Mixture-of-Experts (expert parallelism over the ep mesh axis).
     mlp_type: str = "dense"          # dense | moe
@@ -80,8 +92,11 @@ class GPTConfig:
     # operation and a serving program streams 2 bytes a parameter.
     param_dtype: Any = jnp.float32
     # How `init_params` scales random weights. "gpt2": std 0.02, residual
-    # projections over sqrt(2L). "unit_stream": see `_UNIT_STREAM_GAINS`.
+    # projections over sqrt(2L). "unit_stream": a matrix of fan-in n has std
+    # gain / sqrt(n), its gain from `init_gains` (`_init_unit_stream`), which
+    # the preset states: (name, gain) pairs.
     init: str = "gpt2"               # gpt2 | unit_stream
+    init_gains: Tuple[Tuple[str, float], ...] = ()
     attn_impl: str = "flash"         # flash | ring | ulysses | ref
     remat: bool = True
     # None (save nothing) | "dots" | "attn" (save flash attention's out+lse
@@ -107,6 +122,11 @@ class GPTConfig:
             raise ValueError("window layers need sliding_window >= 1")
         if self.rope_layout is not None and self.pos != "rotary":
             raise ValueError('rope_layout needs pos="rotary"')
+        if self.ut_steps < 1:
+            raise ValueError(f"ut_steps {self.ut_steps}: at least one pass")
+        if self.sandwich_norm and self.parallel_block:
+            raise ValueError("sandwich_norm norms each sublayer's output on its "
+                             "way into the stream; a parallel_block has one sum")
 
     @property
     def kv_heads(self) -> int:
@@ -149,8 +169,10 @@ class GPTConfig:
         else:
             mlp_params = (2 if gated else 1) * E * F + F * E
         per_layer = E * (Hd + 2 * self.kv_heads * self.d_head) + Hd * E + mlp_params
-        per_layer += 2 * E  # norms
+        per_layer += (4 if self.sandwich_norm else 2) * E  # norms
         total = L * per_layer + V * E + (0 if self.tie_embeddings else E * V)
+        if self.ut_steps > 1:
+            total += E + 1  # the exit gate
         if self.pos == "learned":
             total += self.max_seq * E
         return total
@@ -250,7 +272,74 @@ def smallthinker_21b_a3b(**kw):
                 moe_top_k=6,
                 moe_routing="dropless",
                 param_dtype=jnp.bfloat16,
+                # The embedding has std 1.5, so the stream keeps the token's
+                # identity (the vector every position shares stays under a
+                # sixth of it); attention logits of std q * k = 3.6 (a
+                # handful of keys carry a query's weight, so which keys a
+                # layer may see matters), router logits of the stream's own
+                # size (unequal gate weights), and twelve layers that
+                # together add somewhat more than the embedding
+                # (`scripts/smallthinker_tolerance.py` reads it on the chip).
                 init="unit_stream",
+                init_gains=(("embed", 1.5), ("q", 1.9), ("k", 1.9), ("v", 1.0),
+                            ("o", 0.9), ("router", 1.0), ("mlp_in", 1.0),
+                            ("mlp_out", 0.5), ("head", 1.0)),
+                attn_impl="ref",
+            ),
+            **kw,
+        }
+    )
+
+
+def ouro_2_6b(**kw):
+    """Ouro-2.6B (huggingface.co/ByteDance/Ouro-2.6B, arXiv:2510.25741): 48
+    layers of 16 heads of 128 (plain multi-head, rotary over the whole head,
+    theta 1e6), SiLU-gated MLP of 5632, sandwich RMSNorms, the whole stack
+    run `total_ut_steps` = 4 times over the same weights with an exit gate
+    after each pass (at the published `early_exit_threshold` of 1.0 every
+    token runs all four); untied head, weights held in bfloat16. Serving
+    only: `forward` (attn_impl="ref") and the paged programs."""
+    return GPTConfig(
+        **{
+            **dict(
+                n_layers=48,
+                d_model=2048,
+                n_heads=16,
+                n_kv_heads=16,
+                d_head=128,
+                d_mlp=5632,
+                vocab_size=49152,
+                max_seq=65536,
+                norm="rmsnorm",
+                activation="swiglu",
+                pos="rotary",
+                rotary_dim=128,
+                rope_theta=1000000.0,
+                sandwich_norm=True,
+                ut_steps=4,
+                tie_embeddings=False,
+                param_dtype=jnp.bfloat16,
+                # A sublayer's output is normed on its way into the stream,
+                # so the scale of `w_o` and `w_out` is moot: what a layer
+                # adds is its post-norm's WEIGHT, here a constant `post_norm`.
+                # 2 x 48 additions of that size come to about twice the
+                # pass's unit-size input (the embedding has std 1), so a pass
+                # rewrites most of the stream and keeps a third of what it
+                # was given: greedy tokens then depend on every pass and on
+                # which cache a pass reads. `q` and `k` give attention scores
+                # of std 1.2^2 = 1.4: 192 layer applications are a long
+                # product of Jacobians, and under scores of std 3.6 (q, k
+                # 1.9, a near-argmax over the keys) bfloat16's rounding grew
+                # through them until the engine's tokens read as far from
+                # the float32 reference as a wrong model's (token error
+                # 0.5-0.7 against 0.7-1.4; at 1.2 at most 0.016 against a
+                # thinnest control of 0.094, 12 seeds: my chip runs, PR 32,
+                # `scripts/ouro_tolerance.py`). Attention that soft makes
+                # greedy tokens repeat more (5-31 distinct of 96).
+                init="unit_stream",
+                init_gains=(("embed", 1.0), ("q", 1.2), ("k", 1.2), ("v", 1.0),
+                            ("o", 1.0), ("mlp_in", 1.0), ("mlp_out", 1.0),
+                            ("post_norm", 0.2), ("exit_gate", 1.0), ("head", 1.0)),
                 attn_impl="ref",
             ),
             **kw,
@@ -265,10 +354,15 @@ CONFIGS = {
     "gptj-6b": gptj_6b,
     "llama-7b": llama_7b,
     "smallthinker-21b-a3b": smallthinker_21b_a3b,
+    "ouro-2.6b": ouro_2_6b,
 }
 
 
 # ------------------------------------------------------------------- params
+# The second norm of each sublayer under `sandwich_norm`, on its output.
+_POST_NORM_KEYS = ("ln1_post_w", "ln1_post_b", "ln2_post_w", "ln2_post_b")
+
+
 def param_logical_dims(cfg: GPTConfig) -> Dict[str, Tuple[Optional[str], ...]]:
     """Logical dims per parameter — feed through ShardingRules for shardings."""
     dims = {
@@ -302,6 +396,12 @@ def param_logical_dims(cfg: GPTConfig) -> Dict[str, Tuple[Optional[str], ...]]:
     if not cfg.parallel_block:
         dims["ln2_w"] = ("layers", "embed_act")
         dims["ln2_b"] = ("layers", "embed_act")
+    if cfg.sandwich_norm:
+        for name in _POST_NORM_KEYS:
+            dims[name] = ("layers", "embed_act")
+    if cfg.ut_steps > 1:
+        dims["exit_gate_w"] = ("embed_act",)
+        dims["exit_gate_b"] = ()
     if cfg.pos == "learned":
         dims["pos_embed"] = (None, "embed")
     if not cfg.tie_embeddings:
@@ -315,50 +415,63 @@ def param_logical_dims(cfg: GPTConfig) -> Dict[str, Tuple[Optional[str], ...]]:
 # position then chooses alike add the same vector at every position, it
 # outgrows the token's own embedding within three layers (97% of the final
 # stream is one vector), and every position decodes one and the same token
-# whatever the window, the positions or the routing did. Here the embedding
-# has std 1.5, so the stream keeps the token's identity (the common vector
-# stays under a sixth of it), and a matrix of fan-in n has std gain / sqrt(n):
-# attention logits of std q * k = 3.6 (a handful of keys carry a query's
-# weight, so which keys a layer may see matters), router logits of the
-# stream's own size (unequal gate weights), and twelve layers that together
-# add somewhat more than the embedding. The benchmark's token check rests on
-# this (`scripts/smallthinker_tolerance.py` reads it on the chip); no
-# program's shape or time depends on the numbers.
-_UNIT_STREAM_GAINS = {"embed": 1.5, "q": 1.9, "k": 1.9, "v": 1.0, "o": 0.9,
-                      "router": 1.0, "expert_in": 1.0, "expert_out": 0.5, "head": 1.0}
-
-
+# whatever the window, the positions, the routing or a pass did. Here the
+# embedding has std `embed` and a matrix of fan-in n has std gain / sqrt(n),
+# the gains from the preset (`GPTConfig.init_gains`, where each says why);
+# under sandwich norms a post-norm's weight is the constant `post_norm`; the
+# exit gate's logit has the size of `exit_gate`. The benchmark's token check
+# rests on this; no program's shape or time depends on the numbers.
 def _init_unit_stream(rng, cfg: GPTConfig) -> Dict[str, jnp.ndarray]:
-    if cfg.kv_heads == cfg.n_heads or cfg.mlp_type != "moe" \
-            or cfg.activation not in ("swiglu", "reglu") or cfg.parallel_block \
-            or cfg.pos != "rotary" or cfg.tie_embeddings:
+    if cfg.activation not in ("swiglu", "reglu") or cfg.parallel_block \
+            or cfg.pos != "rotary" or cfg.tie_embeddings or not cfg.init_gains:
         raise NotImplementedError(
-            'init="unit_stream" covers grouped-query rotary models with gated '
-            "experts and an untied head")
+            'init="unit_stream" covers rotary models with a gated MLP or gated '
+            "experts and an untied head whose preset states `init_gains`")
     E, L, F, V, X = cfg.d_model, cfg.n_layers, cfg.d_mlp, cfg.vocab_size, cfg.moe_experts
     H, Hkv, Dh = cfg.n_heads, cfg.kv_heads, cfg.d_head
     k = jax.random.split(rng, 16)
-    dt, g = cfg.param_dtype, _UNIT_STREAM_GAINS
+    dt, g = cfg.param_dtype, dict(cfg.init_gains)
 
     def n(key, shape, gain, fan_in):
         return (jax.random.normal(key, shape, jnp.float32)
                 * (gain / math.sqrt(fan_in))).astype(dt)
 
     ones, zeros = (lambda: jnp.ones((L, E), dt)), (lambda: jnp.zeros((L, E), dt))
-    return {
+    q, kv = n(k[1], (L, E, H, Dh), g["q"], E), [
+        n(k[9], (L, E, Hkv, Dh), g["k"], E), n(k[10], (L, E, Hkv, Dh), g["v"], E)]
+    params = {
         "tok_embed": n(k[0], (V, E), g["embed"], 1),
         "ln_f_w": jnp.ones((E,), dt), "ln_f_b": jnp.zeros((E,), dt),
-        "w_q": n(k[1], (L, E, H, Dh), g["q"], E),
-        "w_kv": jnp.stack([n(k[9], (L, E, Hkv, Dh), g["k"], E),
-                           n(k[10], (L, E, Hkv, Dh), g["v"], E)], axis=2),
         "w_o": n(k[2], (L, H, Dh, E), g["o"], H * Dh), "b_o": zeros(),
         "ln1_w": ones(), "ln1_b": zeros(), "ln2_w": ones(), "ln2_b": zeros(),
-        "moe_router": n(k[3], (L, E, X), g["router"], E),
-        "moe_w_in": n(k[4], (L, X, E, F), g["expert_in"], E),
-        "moe_w_gate": n(k[8], (L, X, E, F), g["expert_in"], E),
-        "moe_w_out": n(k[5], (L, X, F, E), g["expert_out"], F),
         "lm_head": n(k[7], (E, V), g["head"], E),
     }
+    if Hkv != H:    # the layouts of `init_params`
+        params.update({"w_q": q, "w_kv": jnp.stack(kv, axis=2)})
+    else:
+        params.update({"w_qkv": jnp.stack([q, *kv], axis=2),
+                       "b_qkv": jnp.zeros((L, 3, H, Dh), dt)})
+    if cfg.mlp_type == "moe":
+        params.update({
+            "moe_router": n(k[3], (L, E, X), g["router"], E),
+            "moe_w_in": n(k[4], (L, X, E, F), g["mlp_in"], E),
+            "moe_w_gate": n(k[8], (L, X, E, F), g["mlp_in"], E),
+            "moe_w_out": n(k[5], (L, X, F, E), g["mlp_out"], F),
+        })
+    else:
+        params.update({
+            "w_in": n(k[3], (L, E, F), g["mlp_in"], E), "b_in": jnp.zeros((L, F), dt),
+            "w_gate": n(k[5], (L, E, F), g["mlp_in"], E),
+            "w_out": n(k[4], (L, F, E), g["mlp_out"], F), "b_out": zeros(),
+        })
+    if cfg.sandwich_norm:
+        for name in _POST_NORM_KEYS:
+            params[name] = jnp.full((L, E), g["post_norm"], dt) \
+                if name.endswith("_w") else zeros()
+    if cfg.ut_steps > 1:
+        params["exit_gate_w"] = n(k[11], (E,), g["exit_gate"], E)
+        params["exit_gate_b"] = jnp.zeros((), dt)
+    return params
 
 
 def init_params(rng, cfg: GPTConfig) -> Dict[str, jnp.ndarray]:
@@ -413,6 +526,12 @@ def init_params(rng, cfg: GPTConfig) -> Dict[str, jnp.ndarray]:
     if not cfg.parallel_block:
         params["ln2_w"] = jnp.ones((L, E), dt)
         params["ln2_b"] = jnp.zeros((L, E), dt)
+    if cfg.sandwich_norm:
+        for name in _POST_NORM_KEYS:
+            params[name] = (jnp.ones if name.endswith("_w") else jnp.zeros)((L, E), dt)
+    if cfg.ut_steps > 1:
+        params["exit_gate_w"] = n(k[11], (E,))
+        params["exit_gate_b"] = jnp.zeros((), dt)
     if cfg.pos == "learned":
         params["pos_embed"] = n(k[6], (cfg.max_seq, E))
     if not cfg.tie_embeddings:
@@ -585,13 +704,17 @@ def _layer_kind_xs(cfg: GPTConfig):
 def _refuse_new_fields(cfg: GPTConfig, what: str):
     """A check on input for the programs that cannot take a field: the dense
     cache [L, B, H, M, Dh] has no K/V-head count and no window, and the
-    stage split cuts no per-layer kinds. Grouped-query heads and per-layer
-    kinds run in `forward` (attn_impl="ref") and in the paged programs."""
+    stage split cuts no per-layer kinds and loops over no passes. Grouped-query
+    heads, per-layer kinds and a looped stack run in `forward`
+    (attn_impl="ref" for the first two) and in the paged programs."""
     bad = []
     if cfg.kv_heads != cfg.n_heads:
         bad.append("grouped-query heads (n_kv_heads)")
     if cfg.layer_kinds is not None:
         bad.append("per-layer kinds (rope_layout / sliding_window_layout)")
+    if cfg.ut_steps > 1:
+        bad.append("layers run several times (ut_steps): no loop of passes, "
+                   "and no cache row a (pass, layer) pair")
     if bad:
         raise NotImplementedError(f"{what} does not support " + ", ".join(bad))
 
@@ -642,6 +765,8 @@ def _block(cfg: GPTConfig, rope_tables, attend, x, layer_params, positions,
             jnp.where(kind["rope"], qr, q), jnp.where(kind["rope"], kr, k))
     attn, state = attend(q, k, v, kind)
     attn_out = jnp.einsum("bhsd,hde->bse", attn, p["w_o"]) + p["b_o"]
+    if cfg.sandwich_norm:
+        attn_out = _norm(attn_out, p["ln1_post_w"], p["ln1_post_b"], cfg.norm)
 
     if cfg.parallel_block:
         mlp_in = h  # GPT-J: same normed input feeds attn and mlp
@@ -650,6 +775,8 @@ def _block(cfg: GPTConfig, rope_tables, attend, x, layer_params, positions,
         mlp_in = _norm(x, p["ln2_w"], p["ln2_b"], cfg.norm)
     mlp_out, aux, load = _mlp(cfg, p, layer_params.get("moe_router"), block_in,
                               mlp_in, stacks, layer, valid)
+    if cfg.sandwich_norm:
+        mlp_out = _norm(mlp_out, p["ln2_post_w"], p["ln2_post_b"], cfg.norm)
     out = x + attn_out + mlp_out if cfg.parallel_block else x + mlp_out
     return out, state, aux, load
 
@@ -657,7 +784,7 @@ def _block(cfg: GPTConfig, rope_tables, attend, x, layer_params, positions,
 _LAYER_KEYS = (
     "w_qkv", "b_qkv", "w_q", "w_kv", "w_o", "b_o", "w_in", "b_in", "w_out", "b_out",
     "ln1_w", "ln1_b", "ln2_w", "ln2_b", "w_gate",
-    "moe_router", "moe_w_in", "moe_w_out", "moe_w_gate",
+    "moe_router", "moe_w_in", "moe_w_out", "moe_w_gate", *_POST_NORM_KEYS,
 )
 
 
@@ -683,10 +810,34 @@ def _layer_stack(params):
     return {k: params[k] for k in _LAYER_KEYS if k in params}
 
 
+def _close_pass(params, x, cfg: GPTConfig):
+    """What ends a pass of a looped model: the final norm over the stream
+    [..., E] (its output is the next pass's input and, after the last pass,
+    what the head reads) and the exit gate on it, one linear unit in
+    float32: (x, lambda [...] f32 in (0, 1))."""
+    x = _norm(x, params["ln_f_w"], params["ln_f_b"], cfg.norm)
+    lam = jax.nn.sigmoid(
+        jnp.einsum("...e,e->...", x.astype(jnp.float32),
+                   params["exit_gate_w"].astype(jnp.float32))
+        + params["exit_gate_b"].astype(jnp.float32))
+    return x, lam
+
+
+def ut_exit_pdf(lams):
+    """The exit distribution of a looped model from its gates `lams` [T, ...]
+    f32, pass first: p_t = lambda_t * prod_{j<t} (1 - lambda_j) for t < T
+    and p_T the remainder -> [T, ...]."""
+    stay = jnp.cumprod(1.0 - lams[:-1], axis=0)             # still in after pass t
+    before = jnp.concatenate([jnp.ones_like(stay[:1]), stay[:-1]], axis=0)
+    return jnp.concatenate([lams[:-1] * before, stay[-1:]], axis=0)
+
+
 def _logits(params, x, cfg: GPTConfig):
     """Final norm and head over hidden states [..., E] -> [..., V] in
-    cfg.dtype; the cache programs hand float32 on."""
-    x = _norm(x, params["ln_f_w"], params["ln_f_b"], cfg.norm)
+    cfg.dtype; the cache programs hand float32 on. A looped model's stream
+    was normed when its last pass closed (`_close_pass`): no second norm."""
+    if cfg.ut_steps == 1:
+        x = _norm(x, params["ln_f_w"], params["ln_f_b"], cfg.norm)
     head = params["tok_embed"].T if cfg.tie_embeddings else params["lm_head"]
     return jnp.einsum("...e,ev->...v", x, head.astype(cfg.dtype))
 
@@ -695,7 +846,10 @@ def _layer_loop(cfg: GPTConfig, mesh, positions):
     """The layer scan of the programs that see a whole sequence and keep no
     cache (`forward`, a pipeline stage): (x, layer_stack, kinds=None) ->
     (x, [L] aux). Their `attend` is the configured kernel (`_attention`),
-    or `_attention_plain` where heads are grouped or layers have kinds."""
+    or `_attention_plain` where heads are grouped or layers have kinds. A
+    looped model (`forward` alone) hands `close_pass` too, x -> x: the scan
+    then runs `ut_steps` times over the same stack, closed over and not cut
+    a pass, each pass ended by `close_pass`; aux is [passes x L]."""
 
     def attend(q, k, v, kind):
         if kind is None and cfg.kv_heads == cfg.n_heads:
@@ -716,8 +870,18 @@ def _layer_loop(cfg: GPTConfig, mesh, positions):
         x, _, aux, _ = block(x, layer_params, positions, kind=kind)
         return x, aux
 
-    return lambda x, layer_stack, kinds=None: jax.lax.scan(
-        scan_body, x, (layer_stack, kinds))
+    def run(x, layer_stack, kinds=None, close_pass=None):
+        if cfg.ut_steps == 1:
+            return jax.lax.scan(scan_body, x, (layer_stack, kinds))
+
+        def one_pass(x, _):
+            x, aux = jax.lax.scan(scan_body, x, (layer_stack, kinds))
+            return close_pass(x), aux
+
+        x, aux = jax.lax.scan(one_pass, x, None, length=cfg.ut_steps)
+        return x, aux.reshape(-1)
+
+    return run
 
 
 def global_positions(cfg: GPTConfig, local_seq: int):
@@ -745,7 +909,8 @@ def forward(params, tokens, cfg: GPTConfig, positions=None, mesh=None, return_au
         positions = jnp.arange(S) if mesh is not None else global_positions(cfg, S)
     x = _embed(params, tokens, positions, cfg)
     x, aux_stack = _layer_loop(cfg, mesh, positions)(
-        x, _layer_stack(params), _layer_kind_xs(cfg))
+        x, _layer_stack(params), _layer_kind_xs(cfg),
+        lambda x: _close_pass(params, x, cfg)[0])
     logits = _logits(params, x, cfg)
     if return_aux:
         return logits, aux_stack.sum()
@@ -793,9 +958,21 @@ def _ce_loss(logits, targets, mask):
     return -ll.mean()
 
 
+def _refuse_looped_training(cfg: GPTConfig, what: str):
+    """A looped model's published objective is an expected loss over its
+    exit distribution with an entropy term; plain next-token cross-entropy
+    of the last pass is another objective, so training refuses the model."""
+    if cfg.ut_steps > 1:
+        raise NotImplementedError(
+            f"{what} does not train a model whose layers run several times "
+            f"(ut_steps={cfg.ut_steps}): its objective, an expected loss over "
+            "the exit distribution, is not implemented")
+
+
 def loss_fn(params, batch, cfg: GPTConfig, mesh=None):
     """batch: {"tokens": [B, S+1]} or {"inputs","targets"} → mean next-token
     cross-entropy (f32) + MoE aux."""
+    _refuse_looped_training(cfg, "loss_fn")
     inputs, targets, mask = _parse_batch(batch)
     logits, aux = forward(params, inputs, cfg, mesh=mesh, return_aux=True)
     return _ce_loss(logits, targets, mask) + aux
@@ -806,6 +983,7 @@ def make_train_step(cfg: GPTConfig, optimizer, mesh=None, loss=None) -> Callable
     with shardings (see ray_tpu.train.JaxTrainer, benchmarks/runners/train.py). `loss`
     overrides the loss callable (params, batch) -> scalar — the pipeline
     train step rides this hook."""
+    _refuse_looped_training(cfg, "make_train_step")
     if loss is None:
         def loss(params, batch):
             return loss_fn(params, batch, cfg, mesh)
@@ -953,6 +1131,7 @@ def check_mpmd_partitionable(
         raise NotImplementedError(
             "MPMD stages do not carry the MoE aux loss across hosts yet"
         )
+    _refuse_new_fields(cfg, "the MPMD stage split")
 
 
 def make_mpmd_stage_fns(
@@ -1245,7 +1424,9 @@ def decode_step(params, token, cache, cfg: GPTConfig):
 # Block-table cache layout for the continuous-batching engine
 # (`ray_tpu.serve.engine`): the KV cache is a pool of fixed-size token
 # blocks [L, NB, BS, H*Dh]; each sequence owns an ordered block table and
-# token position p is ONE contiguous row [H*Dh] at (table[p // BS], p % BS).
+# token position p is ONE contiguous row [H*Dh] at (table[p // BS], p % BS)
+# (L: the pool's depth in cache layers, `kv_layout`; a looped model's is
+# passes x layers).
 # Unlike `init_cache`'s dense [L, B, H, M, Dh] layout, sequences of wildly
 # different lengths share one physical pool with no per-sequence max_seq
 # reservation — the memory model that makes iteration-level admission
@@ -1278,12 +1459,25 @@ class KVLayout:
     x block_size tokens -- has the same bytes whichever group holds it and
     every group draws from the same `num_blocks`. A sequence has one block
     table a group; a window group gives back the blocks that fell behind
-    its window while the sequence lives (serve/engine/kv_manager.py)."""
+    its window while the sequence lives (serve/engine/kv_manager.py).
 
-    per_group: int                  # layers in a group = the pool's leading dim
+    A looped model (`ut_steps` passes over the same layers) keeps keys and
+    values a (pass, layer) pair: the pool's leading dimension is `depth` =
+    passes x per_group CACHE layers, pass t of layer l at pool[t * per_group
+    + slot_of[l]]; block tables, groups and windows are the layers' own. A
+    block is `depth` rows deep, so whoever reckons a block's bytes or takes
+    the pool's depth takes it from here, not from `n_layers`."""
+
+    per_group: int                  # layers in a group
     windows: Tuple[int, ...]        # per group: 0 = keeps every token, else the window
     group_of: Tuple[int, ...]       # [L] the layer's group
-    slot_of: Tuple[int, ...]        # [L] the layer's index inside pool[:]
+    slot_of: Tuple[int, ...]        # [L] the layer's index inside a pass's rows
+    passes: int = 1                 # rows a layer keeps: one a pass
+
+    @property
+    def depth(self) -> int:
+        """Cache layers = the pool's leading dim."""
+        return self.passes * self.per_group
 
 
 @functools.lru_cache(maxsize=None)
@@ -1295,7 +1489,7 @@ def kv_layout(cfg: GPTConfig) -> KVLayout:
     wind = [l for l in range(L) if win[l]]
     if not glob or not wind:
         return KVLayout(L, (cfg.sliding_window if wind else 0,), (0,) * L,
-                        tuple(range(L)))
+                        tuple(range(L)), cfg.ut_steps)
     per = math.gcd(len(glob), len(wind))
     group_of, slot_of, windows = [0] * L, [0] * L, []
     for layers, w in ((glob, 0), (wind, cfg.sliding_window)):
@@ -1303,13 +1497,14 @@ def kv_layout(cfg: GPTConfig) -> KVLayout:
             group_of[l] = len(windows) + i // per
             slot_of[l] = i % per
         windows += [w] * (len(layers) // per)
-    return KVLayout(per, tuple(windows), tuple(group_of), tuple(slot_of))
+    return KVLayout(per, tuple(windows), tuple(group_of), tuple(slot_of),
+                    cfg.ut_steps)
 
 
 def init_paged_cache(cfg: GPTConfig, num_blocks: int, block_size: int):
-    """Physical paged KV pool: {"k","v"} of [per_group, NB, BS, Hkv*Dh] in
-    cfg.dtype (`kv_layout`; per_group = L for a model of one kind)."""
-    shape = (kv_layout(cfg).per_group, num_blocks, block_size,
+    """Physical paged KV pool: {"k","v"} of [depth, NB, BS, Hkv*Dh] in
+    cfg.dtype (`kv_layout`; depth = L for a one-pass model of one kind)."""
+    shape = (kv_layout(cfg).depth, num_blocks, block_size,
              cfg.kv_heads * cfg.d_head)
     return {"k": jnp.zeros(shape, cfg.dtype), "v": jnp.zeros(shape, cfg.dtype)}
 
@@ -1384,9 +1579,19 @@ def _paged_layers(params, tokens, pos, valid, block_tables, kv, cfg: GPTConfig):
     part), so shapes and program keys depend on (S, W) alone. The mask, the
     same in both forms, decides what a query sees; the bounds only skip
     tiles in which it is false everywhere. An expert MLP is the dropless
-    layer of `_dropless_mlp`. Returns (hidden states [B, S, E] before the
-    final norm, kv, None or the mean over layers of (experts touched,
-    busiest expert's share) [2] f32)."""
+    layer of `_dropless_mlp`.
+
+    A looped model (`ut_steps` > 1) runs that layer scan `ut_steps` times
+    in an outer scan, the pool still the carry and written in place, the
+    stacked weights closed over (not cut a pass), pass t of a layer reading
+    and writing the pool rows of its own (pass, layer) pair (`kv_layout`),
+    every pass closed by the final norm and the exit gate (`_close_pass`).
+
+    Returns (hidden states [B, S, E] before the final norm -- after it for
+    a looped model, kv, None or the mean over layers of (experts touched,
+    busiest expert's share) [2] f32, None or a looped model's exit
+    distribution [passes run] f32: `ut_exit_pdf` of the gates of the passes
+    the pass scan ran, one entry a pass, mean over the real tokens)."""
     moe = cfg.mlp_type == "moe"
     if moe and cfg.moe_routing != "dropless":
         raise NotImplementedError(
@@ -1433,26 +1638,58 @@ def _paged_layers(params, tokens, pos, valid, block_tables, kv, cfg: GPTConfig):
         last_pos = jnp.where(real, pos, 0).max(axis=1)
         real_lane = real.any(axis=1)
 
+    # One query a K/V head (a decode step of a multi-head model) whose
+    # features fill whole lane tiles: attention as two matrix products over
+    # the gathered rows AS THE POOL LAYS THEM, [tokens, Hkv*Dh]. The scores
+    # are rows x the queries set block-diagonally ([Hkv*Dh, Hkv], head h's
+    # query in column h), the result is weights x rows ([Hkv, Hkv*Dh]) of
+    # which head h keeps its own Dh columns: the same bf16 products summed in
+    # float32 as the einsums below, the zeros adding nothing. One query row
+    # a head is no matrix product for the MXU, and what the compiler makes
+    # of that dot_general first copies the gathered rows into float32 and
+    # head-major, two more round trips of every row in every layer.
+    lone = R * S == 1 and Dh % 128 == 0
+    own_head = jnp.eye(Hkv, dtype=jnp.float32) if lone else None
+
     def scores_of(q, kk, vv, slot, blocks, kp, seen, window):
         """Masked float32 scores [B, Hkv, R*S, n*BS] of q [B, Hkv, R*S, Dh]
         against the rows of `blocks` [B, n] (key positions `kp`), and those
-        blocks' V rows [B, n*BS, Hkv, Dh]."""
-        gk = kk[slot, blocks].reshape(B, -1, Hkv, Dh)
-        gv = vv[slot, blocks].reshape(B, -1, Hkv, Dh)
+        blocks' V rows [B, n*BS, Hkv, Dh] ([B, n*BS, Hkv*Dh] for `lone`)."""
+        rows = (B, -1, Hkv * Dh) if lone else (B, -1, Hkv, Dh)
+        gk = kk[slot, blocks].reshape(rows)
+        gv = vv[slot, blocks].reshape(rows)
         mask = seen if window is None else seen & (kp > qpos - window)
         if R > 1:   # the R query heads of a K/V head ride its query axis
             mask = jnp.tile(mask, (1, 1, R, 1))
-        scores = jnp.einsum(
-            "bhsd,bthd->bhst", q, gk, preferred_element_type=jnp.float32
-        ) * scale
+        if lone:
+            qd = q[:, :, 0, :, None] * own_head.astype(q.dtype)[None, :, None, :]
+            scores = jnp.einsum(
+                "bte,beh->bht", gk, qd.reshape(B, Hkv * Dh, Hkv),
+                preferred_element_type=jnp.float32)[:, :, None] * scale
+        else:
+            scores = jnp.einsum(
+                "bhsd,bthd->bhst", q, gk, preferred_element_type=jnp.float32
+            ) * scale
         return jnp.where(mask, scores, -1e30), gv
+
+    def mixed(p, gv, out_dtype=None):
+        """Weights p [B, Hkv, R*S, T] f32 over the V rows `scores_of` gave
+        -> [B, Hkv, R*S, Dh] in `out_dtype` (the rows' own if None)."""
+        if lone:
+            every = jnp.einsum("bht,bte->bhe", p[:, :, 0].astype(gv.dtype), gv,
+                               preferred_element_type=jnp.float32)
+            out = (every.reshape(B, Hkv, Hkv, Dh)
+                   * own_head[None, :, :, None]).sum(axis=2)
+            return out[:, :, None].astype(out_dtype or gv.dtype)
+        return jnp.einsum("bhst,bthd->bhsd", p.astype(gv.dtype), gv,
+                          preferred_element_type=out_dtype)
 
     def gathered(q, kk, vv, slot, table, window):
         """Attention of q [B, Hkv, R*S, Dh] over the rows `table` names."""
         if NT == 1:
             scores, gv = scores_of(q, kk, vv, slot, table, kpos, seen, window)
             probs = jax.nn.softmax(scores, axis=-1)
-            return jnp.einsum("bhst,bthd->bhsd", probs.astype(gv.dtype), gv)
+            return mixed(probs, gv)
         first, trips = paged_attn_trips(
             jnp, first_pos, last_pos, real_lane,
             _NO_WINDOW if window is None else window, T, NT)
@@ -1469,9 +1706,7 @@ def _paged_layers(params, tokens, pos, valid, block_tables, kv, cfg: GPTConfig):
             m_new = jnp.maximum(m, scores.max(axis=-1))
             p = jnp.exp(scores - m_new[..., None])
             fade = jnp.exp(m - m_new)
-            acc = acc * fade[..., None] + jnp.einsum(
-                "bhst,bthd->bhsd", p.astype(gv.dtype), gv,
-                preferred_element_type=jnp.float32)
+            acc = acc * fade[..., None] + mixed(p, gv, jnp.float32)
             return m_new, l * fade + p.sum(axis=-1), acc
 
         rows = q.shape[:3]
@@ -1480,9 +1715,10 @@ def _paged_layers(params, tokens, pos, valid, block_tables, kv, cfg: GPTConfig):
             jnp.zeros(q.shape, jnp.float32)))
         return (acc / l[..., None]).astype(vv.dtype)
 
-    def attend(kk, vv, l, q, k, v, kind):
-        """The new rows into the pool (kk, vv) at the layer's slot, then
-        attention over the layer's table; the pool is the state."""
+    def attend(kk, vv, l, base, q, k, v, kind):
+        """The new rows into the pool (kk, vv) at the layer's slot (past
+        `base`, the first row of a looped model's pass), then attention
+        over the layer's table; the pool is the state."""
         k = k.transpose(0, 2, 1, 3).reshape(B, S, Hkv * Dh)
         v = v.transpose(0, 2, 1, 3).reshape(B, S, Hkv * Dh)
         if G == 1:
@@ -1491,6 +1727,8 @@ def _paged_layers(params, tokens, pos, valid, block_tables, kv, cfg: GPTConfig):
             slot = kind["slot"]
             table = jnp.take(block_tables, kind["group"], axis=1)
             ph = physical(table)
+        if base is not None:
+            slot = base + slot
         kk = kk.at[slot, ph, off].set(k.astype(kk.dtype))
         vv = vv.at[slot, ph, off].set(v.astype(vv.dtype))
         if R > 1:   # the R query heads of a K/V head ride its query axis
@@ -1507,19 +1745,35 @@ def _paged_layers(params, tokens, pos, valid, block_tables, kv, cfg: GPTConfig):
         stacks = tuple(layer_stack.pop(k) for k in
                        ("moe_w_gate", "moe_w_in", "moe_w_out"))
 
-    def scan_body(carry, inp):
-        x, kk, vv = carry                              # kk/vv: the whole pool
-        l, layer_params, kind = inp
-        x, (kk, vv), _, load = _block(
-            cfg, rope_tables, functools.partial(attend, kk, vv, l), x,
-            layer_params, pos, kind, stacks, l, real)
-        return (x, kk, vv), load
+    def layers(carry, base=None):
+        """The layer scan, once: (x, pool k, pool v) -> the same, [L] loads."""
 
-    (x, kk, vv), loads = jax.lax.scan(
-        scan_body, (x, kv["k"], kv["v"]),
-        (jnp.arange(cfg.n_layers), layer_stack, kinds),
-    )
-    return x, {"k": kk, "v": vv}, (loads.mean(axis=0) if moe else None)
+        def scan_body(carry, inp):
+            x, kk, vv = carry                          # kk/vv: the whole pool
+            l, layer_params, kind = inp
+            x, (kk, vv), _, load = _block(
+                cfg, rope_tables, functools.partial(attend, kk, vv, l, base), x,
+                layer_params, pos, kind, stacks, l, real)
+            return (x, kk, vv), load
+
+        return jax.lax.scan(
+            scan_body, carry, (jnp.arange(cfg.n_layers), layer_stack, kinds))
+
+    carry = (x, kv["k"], kv["v"])
+    if cfg.ut_steps == 1:
+        (x, kk, vv), loads = layers(carry)
+        return x, {"k": kk, "v": vv}, (loads.mean(axis=0) if moe else None), None
+
+    def one_pass(carry, t):
+        (x, kk, vv), loads = layers(carry, t * lay.per_group)
+        x, lam = _close_pass(params, x, cfg)
+        return (x, kk, vv), (loads, lam)
+
+    (x, kk, vv), (loads, lams) = jax.lax.scan(
+        one_pass, carry, jnp.arange(cfg.ut_steps))
+    pdf = jnp.where(real, ut_exit_pdf(lams), 0.0)      # [passes run, B, S]
+    exits = pdf.sum(axis=(1, 2)) / jnp.maximum(real.sum(), 1)
+    return x, {"k": kk, "v": vv}, (loads.mean(axis=(0, 1)) if moe else None), exits
 
 
 def prefill_paged(params, tokens, real_len, pos_offset, block_table, kv,
@@ -1542,7 +1796,7 @@ def prefill_paged(params, tokens, real_len, pos_offset, block_table, kv,
     """
     rel = jnp.arange(tokens.shape[1])
     pos = (pos_offset + rel)[None]               # global token positions [1, Sp]
-    x, kv, _ = _paged_layers(
+    x, kv, _, _ = _paged_layers(
         params, tokens, pos, (rel < real_len)[None], block_table[None], kv, cfg
     )
     h = x[0, jnp.maximum(real_len - 1, 0)]  # [E] — last REAL chunk position
@@ -1560,13 +1814,17 @@ def decode_step_paged(params, token, positions, block_tables, kv, cfg: GPTConfig
     engine discards. For an expert model (`cfg.mlp_type == "moe"`) the step's
     routing comes back beside the logits: (logits, [2] f32 = experts with
     at least one token and the busiest expert's share of the assignments,
-    mean over layers, padding lanes left out), kv.
+    mean over layers, padding lanes left out), kv. For a looped model
+    (`cfg.ut_steps` > 1) what its exit gate read comes back the same way:
+    (logits, [passes run] f32 = the exit distribution, one entry a pass the
+    program ran, mean over the real lanes), kv.
     """
-    x, kv, load = _paged_layers(
+    x, kv, load, exits = _paged_layers(
         params, token[:, None], positions[:, None], True, block_tables, kv, cfg
     )
     logits = _logits(params, x[:, 0], cfg).astype(jnp.float32)
-    return (logits, kv) if load is None else ((logits, load), kv)
+    facts = tuple(a for a in (load, exits) if a is not None)
+    return ((logits, *facts), kv) if facts else (logits, kv)
 
 
 def verify_step_paged(params, tokens, positions, valid_len, block_tables, kv,
@@ -1588,7 +1846,7 @@ def verify_step_paged(params, tokens, positions, valid_len, block_tables, kv,
     """
     rel = jnp.arange(tokens.shape[1])[None, :]
     pos = positions[:, None] + rel                              # [B, K1]
-    x, kv, _ = _paged_layers(
+    x, kv, _, _ = _paged_layers(
         params, tokens, pos, rel < valid_len[:, None], block_tables, kv, cfg
     )
     return _logits(params, x, cfg).astype(jnp.float32), kv

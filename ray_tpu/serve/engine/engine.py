@@ -162,7 +162,8 @@ class InferenceEngine:
         # tier, the disaggregated roles and block export/import move blocks
         # of ONE row shape and one table: a model whose layers form several
         # groups is refused here, not served wrongly (ROADMAP D9).
-        self._groups = len(kv_layout(self.cfg).windows)
+        self._layout = kv_layout(self.cfg)
+        self._groups = len(self._layout.windows)
         if self._groups > 1:
             if self.opts.host_kv_bytes > 0 and self.opts.enable_prefix_caching:
                 raise ValueError(
@@ -194,7 +195,7 @@ class InferenceEngine:
             self.opts.block_size,
             enable_prefix_caching=self.opts.enable_prefix_caching,
             host_tier=self.host_tier,
-            group_windows=kv_layout(self.cfg).windows,
+            group_windows=self._layout.windows,
         )
         proposer = None
         if self.opts.spec_tokens > 0:
@@ -260,8 +261,14 @@ class InferenceEngine:
         self.total_attn_keys = [0, 0]
         self._step_attn = [0, 0]
         # Expert routing: the last decode step's (experts touched, busiest
-        # expert's share), which came back with its logits.
+        # expert's share), which came back with its logits; a looped
+        # model's exit distribution likewise, one entry a pass the program
+        # RAN (`models.gpt._paged_layers`).
         self._step_moe = None
+        self._step_exit = None
+        # Passes of the layer stack over the decode steps' real lanes: [run,
+        # from the length of what came back; what `ut_steps` would be].
+        self.total_ut_passes = [0, 0]
         # Side work serviced by the driver thread at step boundaries, where
         # self.kv is stable (kernel donation invalidates old buffers, so no
         # other thread may ever read the KV arrays): ("export", digests,
@@ -590,7 +597,7 @@ class InferenceEngine:
 
     def _block_blobs(self, blocks: List[int]):
         """The given blocks' KV bytes as contiguous host arrays [2(k/v),
-        L, BS, H*Dh] each — the unit of the host tier and the transfer
+        pool depth, BS, H*Dh] each — the unit of the host tier and the transfer
         plane. Batched: ONE device read per KV array (then per-block host
         copies), not two blocking transfers per block — saves/exports sit
         at the top of the hot step path."""
@@ -648,11 +655,14 @@ class InferenceEngine:
         between engines with identical model geometry, block size, dtype
         and block layout ("rows": a block is [BS, H*Dh], one row a token —
         a blob of the older head-major blocks has the same bytes in
-        another order, so it must not be adopted)."""
+        another order, so it must not be adopted). The depth is the pool's
+        (`kv_layout`: cache layers, passes x layers for a looped model)
+        and the passes are named, so that a one-pass engine and a looped
+        one of equal widths never adopt each other's blocks."""
         c = self.cfg
         return (
-            f"{c.n_layers}:{c.kv_heads}:{c.d_head}:{self.opts.block_size}:"
-            f"{self._jnp.dtype(c.dtype).str}:rows"
+            f"{self._layout.depth}/{self._layout.passes}:{c.kv_heads}:{c.d_head}:"
+            f"{self.opts.block_size}:{self._jnp.dtype(c.dtype).str}:rows"
         )
 
     def prompt_digests(self, prompt: List[int]) -> List[bytes]:
@@ -1013,8 +1023,14 @@ class InferenceEngine:
             )
             del args    # the input buffers are released here, not at return
         with flight.phase("engine.fetch_logits", ph, "fetch_ns"):
-            if isinstance(logits, tuple):   # an expert model: the step's routing
-                logits, self._step_moe = self._jax.device_get(logits)
+            if isinstance(logits, tuple):   # the step's routing, the exit gate
+                logits, *facts = self._jax.device_get(logits)
+                if self.cfg.mlp_type == "moe":
+                    self._step_moe = facts.pop(0)
+                if self.cfg.ut_steps > 1:
+                    self._step_exit = facts.pop(0)
+                    self.total_ut_passes[0] += len(seqs) * len(self._step_exit)
+                    self.total_ut_passes[1] += len(seqs) * self.cfg.ut_steps
             else:
                 logits = np.asarray(logits)
         with flight.phase("engine.sample", ph, "sample_ns"):
@@ -1044,7 +1060,7 @@ class InferenceEngine:
             self._step_ttfts, self._step_tpots = [], []
             self._step_spec = [0, 0]  # [proposed, accepted]
             self._step_attn = [0, 0]  # [keys run, keys padded]
-            self._step_moe = None
+            self._step_moe = self._step_exit = None
             tok0 = self.total_tokens
             with self._lock:
                 out = self.scheduler.schedule()
@@ -1110,9 +1126,15 @@ class InferenceEngine:
             self._export_metrics(stats)
         if fl_on and (out.prefills or out.decodes):
             idle, self._idle = self._idle, {"waited_ns": 0}
-            moe = {} if self._step_moe is None else {
-                "experts_touched": float(self._step_moe[0]),
-                "expert_load_max": float(self._step_moe[1])}
+            facts = {}      # what came back with the decode step's logits
+            if self._step_moe is not None:
+                facts["experts_touched"] = float(self._step_moe[0])
+                facts["expert_load_max"] = float(self._step_moe[1])
+            if self._step_exit is not None:
+                pdf = self._step_exit.tolist()  # one entry a pass the program ran
+                facts["ut_passes"] = len(pdf)
+                facts["exit_step_mean"] = sum(t * p for t, p in enumerate(pdf, 1))
+                facts["exit_cdf_early"] = sum(pdf[:-1])
             flight.record(
                 "engine.step", t0_ns, t1_ns, lane=self._lane,
                 attrs={"prefills": len(out.prefills),
@@ -1120,7 +1142,7 @@ class InferenceEngine:
                        "tokens": stats["step_tokens"],
                        "attn_keys_run": self._step_attn[0],
                        "attn_keys_padded": self._step_attn[1],
-                       **moe, **idle, **ph,
+                       **facts, **idle, **ph,
                        "queue_depth": stats["queue_depth"],
                        "running": stats["running"],
                        "kv_util": stats["kv_utilization"]})
@@ -1160,6 +1182,8 @@ class InferenceEngine:
             "window_blocks_released": self.block_manager.window_released,
             "attn_keys_run": self.total_attn_keys[0],
             "attn_keys_padded": self.total_attn_keys[1],
+            "ut_passes_run": self.total_ut_passes[0],
+            "ut_passes_full": self.total_ut_passes[1],
             "total_tokens": self.total_tokens,
             "total_finished": self.total_finished,
             "total_preemptions": self.total_preemptions,

@@ -595,7 +595,9 @@ def ingest_report(events: List[dict]) -> Optional[dict]:
 # Serving span vocabulary (serve/README.md "Observability"):
 #   engine.step     — one per engine iteration that ran a kernel, on lane
 #                     ``serve/engine-<role>``; attrs prefills/decodes/tokens,
-#                     attn_keys_run/attn_keys_padded of its programs,
+#                     attn_keys_run/attn_keys_padded of its programs (a
+#                     looped model's decode steps also ut_passes,
+#                     exit_step_mean/exit_cdf_early),
 #                     the host phases of the step in nanoseconds
 #                     (waited_ns | sched/side/build/dispatch/fetch/sample_ns |
 #                     export_ns) and queue_depth/running/kv_util at its end

@@ -41,12 +41,28 @@ class _Held:
 
 
 def main(argv=None) -> int:
-    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    return readings(
+        "smallthinker-21b-a3b",
+        lambda m, opts: {
+            "top_k_minus_one": {"top_k": m["top_k"] - 1},
+            "window_off": {"window_layout": [0] * m["n_layers"]},
+            "window_one_block_wide": {"window": m["window"] + opts.block_size},
+            "rope_on_nope_layers": {"rope_layout": [1] * m["n_layers"]},
+        },
+        lambda stats: {"window_blocks_released": stats["window_blocks_released"]},
+        argv, __doc__)
+
+
+def readings(config_name, wrongs_of, row_of, argv, doc) -> int:
+    """The readings of one configuration (`benchmarks/configs/<config_name>.json`):
+    `wrongs_of(dims, engine options)` names the wrong references as changes to
+    the dims, `row_of(engine.stats())` adds what the sound engine counted."""
+    ap = argparse.ArgumentParser(description=doc.split("\n\n")[0])
     ap.add_argument("--seeds", default="3000000001")
     ap.add_argument("--rehearse", action="store_true")
     ap.add_argument("--parts", default="wrong,float8",
                     help="what to read beside the sound engine's own token error: "
-                         "wrong (all four wrong references) or their names, float8")
+                         "wrong (every wrong reference) or their names, float8")
     a = ap.parse_args(argv)
     parts = set(a.parts.split(","))
     import jax
@@ -56,7 +72,7 @@ def main(argv=None) -> int:
     from ray_tpu.models import gpt
     from ray_tpu.serve.engine import EngineOptions, InferenceEngine
 
-    config = harness.load_json(harness.ROOT, "benchmarks/configs/smallthinker-21b-a3b.json")
+    config = harness.load_json(harness.ROOT, f"benchmarks/configs/{config_name}.json")
     arch = harness.arch(config["arch"])
     m = arch.dims(config, a.rehearse)
     part = config["rehearsal"]["requests"] if a.rehearse else config["runners"]["requests"]
@@ -66,12 +82,7 @@ def main(argv=None) -> int:
     cfg = gpt.CONFIGS[name](**overrides)
     init = jax.jit(lambda k: gpt.init_params(k, cfg))
     dev = jax.devices()[0]
-    wrongs = {
-        "top_k_minus_one": {"top_k": m["top_k"] - 1},
-        "window_off": {"window_layout": [0] * m["n_layers"]},
-        "window_one_block_wide": {"window": m["window"] + opts.block_size},
-        "rope_on_nope_layers": {"rope_layout": [1] * m["n_layers"]},
-    }
+    wrongs = wrongs_of(m, opts)
 
     def check(held, seed, dims=m):
         err, agree = BenchReplica.bench_check_tokens(
@@ -87,7 +98,7 @@ def main(argv=None) -> int:
         held = _Held(params, eng.generate)
         row = {"seed": seed, "sound": check(held, seed)}
         row["distinct_tokens"] = len(set(held.tokens))
-        row["window_blocks_released"] = eng.stats()["window_blocks_released"]
+        row.update(row_of(eng.stats()))
         for wname, change in wrongs.items():
             if wname in parts or "wrong" in parts:
                 row[wname] = check(held, seed, {**m, **change})
