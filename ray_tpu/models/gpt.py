@@ -1852,6 +1852,21 @@ def verify_step_paged(params, tokens, positions, valid_len, block_tables, kv,
     return _logits(params, x, cfg).astype(jnp.float32), kv
 
 
+def sample_ids(logits, temperature, key):
+    """The one sampler: float32 logits [..., V] -> int32 ids [...]. The
+    first of the largest logits at temperature 0 (what NumPy's `argmax`
+    gives on the same numbers), a draw from `softmax(logits / temperature)`
+    otherwise, every leading index independently under the one `key`. A
+    temperature that is a traced scalar (the engine's programs: no program
+    is keyed by it) picks the branch on the device."""
+    greedy = lambda: jnp.argmax(logits, -1).astype(jnp.int32)
+    drawn = lambda: jax.random.categorical(
+        key, logits / temperature).astype(jnp.int32)
+    if isinstance(temperature, (int, float)):
+        return drawn() if temperature > 0.0 else greedy()
+    return jax.lax.cond(temperature > 0.0, drawn, greedy)
+
+
 def make_generate(cfg: GPTConfig, max_new_tokens: int, temperature: float = 0.0):
     """Returns jittable `gen(params, prompt [B, S0], rng) -> tokens
     [B, max_new_tokens]`: prefill + a device-side `lax.scan` decode loop —
@@ -1860,22 +1875,17 @@ def make_generate(cfg: GPTConfig, max_new_tokens: int, temperature: float = 0.0)
     nothing is admitted while it runs (the engine serves the paged
     programs)."""
 
-    def sample(logits, key):
-        if temperature <= 0.0:
-            return jnp.argmax(logits, -1).astype(jnp.int32)
-        return jax.random.categorical(key, logits / temperature).astype(jnp.int32)
-
     def gen(params, prompt, rng):
         B, S0 = prompt.shape
         cache = init_cache(cfg, B, S0 + max_new_tokens)
         logits, cache = prefill(params, prompt, cfg, cache)
         rng, k0 = jax.random.split(rng)
-        first = sample(logits, k0)
+        first = sample_ids(logits, temperature, k0)
 
         def step(carry, key):
             token, cache = carry
             logits, cache = decode_step(params, token, cache, cfg)
-            nxt = sample(logits, key)
+            nxt = sample_ids(logits, temperature, key)
             return (nxt, cache), token
 
         keys = jax.random.split(rng, max_new_tokens - 1) if max_new_tokens > 1 \

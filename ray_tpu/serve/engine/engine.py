@@ -25,6 +25,20 @@ One `step()` is one model iteration:
        their stop condition retire immediately, returning their blocks for
        the NEXT step's admissions.
 
+Steps chain on the device. The programs end in the sampler and hand back
+ids; each also leaves its ids in a device buffer of last ids, one entry a
+lane slot, from which the next decode program gathers its input. So the
+engine dispatches step n+1 BEFORE it reads step n's ids, and reads them
+one step behind (`_collect`), for the streams, the finish test and the
+books: the blocking read overlaps a running program and the device goes
+from program to program without the host in between. Step n+1 is planned
+from counts (`Sequence.unread`); a finish by `max_new_tokens` is known at
+dispatch (`Scheduler.retire`), an `eos` is not: such a lane rides one step
+too many and that sample is dropped. Where token VALUES are needed the step
+in flight is read first and the engine runs drained: a verify step, the
+preemption of a lane whose newest id is unread (`NeedsValues`), a KV
+export, `shutdown`. `step()` is one iteration of that one loop.
+
 The engine owns a dedicated driver thread (all JAX compute on one thread);
 `submit()`/`stream()` are called from any thread — replica actor method
 threads under Serve (`LLMDeployment` runs with max_concurrency > 1 so a
@@ -44,33 +58,105 @@ from collections import deque
 from ...util import flight, metrics as _metrics
 from ...util.metrics import quantile as _quantile
 from .kv_manager import KVBlockManager
-from .scheduler import Scheduler, Sequence, SchedulerOutput, _next_pow2
+from .scheduler import (
+    FINISHED, NeedsValues, Scheduler, Sequence, SchedulerOutput, _next_pow2,
+)
 
 _FINISH = object()  # stream sentinel
 
-# Jitted paged kernels are process-wide singletons: every engine (and every
+# Jitted paged programs are process-wide singletons: every engine (and every
 # replica in local-mode tests) shares one XLA program cache, keyed by the
 # (cfg, shape-bucket) signature jax.jit already tracks. Re-wrapping per
 # engine would recompile identical programs per instance.
 _JITS = None
 
 
+def init_sampler(max_num_seqs: int, seed: int, temperature: float):
+    """(last, sampling) as the sampled programs take them. `last` is carried
+    and donated like the pool: `ids` [max_num_seqs + 1] int32, the newest
+    sampled id of every lane slot (the spare entry takes what padding lanes
+    and chunks that end no prompt sample), and `draws`, the programs
+    dispatched so far, folded into the key. `sampling` never changes: (the
+    key of `seed`, the temperature as a float32 scalar). The key is an
+    `unsafe_rbg` one: its draws are one `rng_bit_generator` operation,
+    where threefry's unrolled rounds, lowered anew into every one of a
+    replica's programs, cost a third of a second of set-up a program
+    (PERF.md §6, PR 33)."""
+    import jax
+    import jax.numpy as jnp
+
+    last = {"ids": jnp.zeros((max_num_seqs + 1,), jnp.int32),
+            "draws": jnp.zeros((), jnp.int32)}
+    return last, (jax.random.key(seed, impl="unsafe_rbg"),
+                  jnp.asarray(temperature, jnp.float32))
+
+
 def _paged_jits():
+    """What the engine dispatches: `models/gpt.py`'s three paged entry
+    points, each ending in the sampler (`models.gpt.sample_ids`), so that a
+    step hands back ids and not logits, and the ids stay on the device for
+    the next step. One program a shape bucket, chained or not: whether a
+    lane's input id comes from the device's buffer or from the host is
+    DATA (`known`), temperature and the draw counter are traced scalars.
+    The names keep `decode_step_paged` / `prefill_paged` /
+    `verify_step_paged`: the benchmark finds the programs in the device
+    trace by them. Defined here, inside, so that a `_JITS` reset to None
+    (tests tracing under another `_ATTN_TILE_KEYS`) gets functions, and
+    with them traces, of its own."""
     global _JITS
-    if _JITS is None:
-        import jax
+    if _JITS is not None:
+        return _JITS
+    import jax
+    import jax.numpy as jnp
 
-        from ...models.gpt import (
-            decode_step_paged,
-            prefill_paged,
-            verify_step_paged,
-        )
+    from ...models import gpt
 
-        _JITS = (
-            jax.jit(prefill_paged, static_argnums=(6,), donate_argnums=(5,)),
-            jax.jit(decode_step_paged, static_argnums=(5,), donate_argnums=(4,)),
-            jax.jit(verify_step_paged, static_argnums=(6,), donate_argnums=(5,)),
-        )
+    def draw(logits, slot, last, sampling):
+        key, temperature = sampling
+        ids = gpt.sample_ids(
+            logits, temperature, jax.random.fold_in(key, last["draws"]))
+        return ids, {"ids": last["ids"].at[slot].set(ids),
+                     "draws": last["draws"] + 1}
+
+    def prefill_paged_sampled(params, tokens, meta, block_table, kv, last,
+                              sampling, cfg):
+        """`prefill_paged`, then the chunk's next id [1] into `last` at
+        `slot`: meta = (real_len, pos_offset, slot) int32; a chunk that
+        does not end its prompt names the spare slot."""
+        real_len, pos_offset, slot = meta
+        logits, kv = gpt.prefill_paged(
+            params, tokens, real_len, pos_offset, block_table, kv, cfg)
+        ids, last = draw(logits[None], slot[None], last, sampling)
+        return (ids,), kv, last
+
+    def decode_step_paged_sampled(params, lanes, block_tables, kv, last,
+                                  sampling, cfg):
+        """`decode_step_paged` over ids the device holds: lanes [4, B]
+        int32 = (slot, position, host id, known). A lane's input id is
+        `last["ids"][slot]` unless the host knows it (`known`); its sample
+        goes back to the same slot. Returns ((ids [B], *facts), kv, last),
+        `facts` what `decode_step_paged` hands back beside its logits."""
+        slot, positions, host_ids, known = lanes
+        token = jnp.where(known > 0, host_ids, last["ids"][slot])
+        out, kv = gpt.decode_step_paged(
+            params, token, positions, block_tables, kv, cfg)
+        logits, *facts = out if isinstance(out, tuple) else (out,)
+        ids, last = draw(logits, slot, last, sampling)
+        return (ids, *facts), kv, last
+
+    def verify_step_paged_sampled(params, tokens, positions, valid_len,
+                                  block_tables, kv, cfg):
+        """`verify_step_paged`, handing back the greedy ids [B, K1]: all
+        the accept rule reads."""
+        logits, kv = gpt.verify_step_paged(
+            params, tokens, positions, valid_len, block_tables, kv, cfg)
+        return gpt.sample_ids(logits, 0.0, None), kv
+
+    _JITS = (
+        jax.jit(prefill_paged_sampled, static_argnums=(7,), donate_argnums=(4, 5)),
+        jax.jit(decode_step_paged_sampled, static_argnums=(6,), donate_argnums=(3, 4)),
+        jax.jit(verify_step_paged_sampled, static_argnums=(6,), donate_argnums=(5,)),
+    )
     return _JITS
 
 
@@ -112,6 +198,18 @@ class EngineOptions:
     host_kv_bytes: int = 32 << 20
     # Deadline for one KV export/import (span fetch + handoff plumbing).
     kv_transfer_timeout_s: float = 30.0
+
+
+@dataclasses.dataclass
+class _InFlight:
+    """One step's programs on the device, their ids not read yet."""
+
+    # (sequences, the program's (ids, *facts) on the device, whether the
+    # ids are first tokens), one entry a sampling program
+    entries: List[tuple] = dataclasses.field(default_factory=list)
+    # The step's `engine.step` record (t0_ns, t1_ns, attrs), written when
+    # its ids are read: what came back with them belongs on it.
+    record: Optional[tuple] = None
 
 
 class RequestOutput:
@@ -232,13 +330,19 @@ class InferenceEngine:
             draft_proposer=proposer,
             prefill_budget_cap=prefill_cap,
         )
-        # cfg is static (hashable frozen dataclass); kv buffers are donated
-        # — each call consumes self.kv and hands back its successor.
+        # cfg is static (hashable frozen dataclass); the pool and the last
+        # ids are donated — each call consumes them and hands back their
+        # successors.
         self._prefill, self._decode, self._verify = _paged_jits()
+        self._last, self._sampling = init_sampler(
+            self.opts.max_num_seqs, self.opts.seed, self.opts.temperature)
+        self._spare = self.opts.max_num_seqs    # the slot nobody reads
+        # The previous step's samples, unread; this step's, as it dispatches.
+        self._inflight: Optional[_InFlight] = None
+        self._cur = _InFlight()
         import numpy as np
 
         self._np = np
-        self._sample_rng = np.random.default_rng(self.opts.seed)
         self._lock = threading.Lock()          # scheduler + queues
         self._work = threading.Condition(self._lock)
         self._outputs: Dict[str, RequestOutput] = {}
@@ -255,17 +359,19 @@ class InferenceEngine:
         self.total_spec_accepted = 0
         self.total_blocks_imported = 0
         self.total_blocks_exported = 0
+        # Decode and verify programs dispatched; of them, those dispatched
+        # while a lane's newest id was still unread, taken from the device.
+        self.total_decode_dispatched = 0
+        self.total_decode_chained = 0
+        self._step_chained = 0
         # Keys the dispatched programs' attention covered in a global layer
         # and keys of their padded tables (`models.gpt.paged_attn_keys`).
         self._attn_keys = paged_attn_keys
         self.total_attn_keys = [0, 0]
         self._step_attn = [0, 0]
-        # Expert routing: the last decode step's (experts touched, busiest
-        # expert's share), which came back with its logits; a looped
-        # model's exit distribution likewise, one entry a pass the program
-        # RAN (`models.gpt._paged_layers`).
+        # Expert routing: (experts touched, busiest expert's share) of the
+        # decode step whose ids this step read, which came back with them.
         self._step_moe = None
-        self._step_exit = None
         # Passes of the layer stack over the decode steps' real lanes: [run,
         # from the length of what came back; what `ut_steps` would be].
         self.total_ut_passes = [0, 0]
@@ -505,15 +611,6 @@ class InferenceEngine:
         return list(self.stream(rid))
 
     # ---------------------------------------------------------------- step
-    def _sample(self, logits_row) -> int:
-        if self.opts.temperature <= 0.0:
-            return int(logits_row.argmax())
-        z = logits_row / self.opts.temperature
-        z = z - z.max()
-        p = self._np.exp(z)
-        p /= p.sum()
-        return int(self._sample_rng.choice(len(p), p=p))
-
     def _emit(self, seq: Sequence, tok: int):
         seq.append_token(tok)
         out = self._outputs.get(seq.request_id)
@@ -868,8 +965,10 @@ class InferenceEngine:
             count[1] += padded
 
     def _run_prefill(self, chunk):
-        """One prefill chunk: compute prompt[start : start+n] into the paged
-        cache. Only the FINAL chunk samples the first token (TTFT)."""
+        """Dispatch one prefill chunk: compute prompt[start : start+n] into
+        the paged cache. Every chunk's program samples; only the FINAL
+        chunk's id is the sequence's first token (TTFT), written to its
+        slot and read one step behind."""
         seq = chunk.seq
         ph = self._phases
         rec = self._trace_info.get(seq.request_id)
@@ -889,16 +988,14 @@ class InferenceEngine:
             bt = np.zeros(self._table_shape(W), np.int32)
             self._tables_into(bt, seq)
             self._count_attn(1, W, np.asarray([chunk.start + L - 1]), True)
-            args = (
-                jnp.asarray(tokens),
-                jnp.asarray(L, jnp.int32),
-                jnp.asarray(chunk.start, jnp.int32),
-                jnp.asarray(bt),
-            )
+            meta = np.asarray(
+                [L, chunk.start, seq.slot if chunk.last else self._spare],
+                np.int32)
+            args = (jnp.asarray(tokens), jnp.asarray(meta), jnp.asarray(bt))
         with flight.phase("engine.dispatch", ph, "dispatch_ns"):
-            logits, self.kv = self._prefill(
-                self.params, *args, self.kv, self.cfg
-            )
+            out, self.kv, self._last = self._prefill(
+                self.params, *args, self.kv, self._last, self._sampling,
+                self.cfg)
             del args    # the input buffers are released here, not at return
         with flight.phase("engine.schedule", ph, "sched_ns"):
             seq.num_computed = chunk.start + L
@@ -910,14 +1007,78 @@ class InferenceEngine:
                 self.block_manager.register_computed(
                     seq.request_id, seq.prompt, seq.num_computed
                 )
-        if chunk.last:
-            with flight.phase("engine.fetch_logits", ph, "fetch_ns"):
-                logits = np.asarray(logits)
+            if chunk.last:
+                self._sampled([seq], out, first=True)
+
+    def _sampled(self, seqs: List[Sequence], out, first: bool = False):
+        """Book a dispatched program's samples: tokens by COUNT now
+        (`unread`), by value when `_collect` reads them. A sequence whose
+        last token this is gives its lane and blocks back at once."""
+        for a in out:
+            a.copy_to_host_async()
+        self._cur.entries.append((seqs, out, first))
+        done = []
+        for seq in seqs:
+            seq.unread += 1
+            if seq.num_remaining <= 0:
+                done.append(seq)
+        if done:
+            with self._lock:
+                for seq in done:
+                    self.scheduler.retire(seq)
+
+    def _collect(self):
+        """Read the ids of the step in flight (dispatched by the previous
+        `_step`, or drained early by this one) and do what waited for their
+        values: the streams, the finish tests, the books, the step's
+        record. An id of a sequence that finished meanwhile (it rode one
+        step past its `eos`) is dropped."""
+        fl, self._inflight = self._inflight, None
+        if fl is None:
+            return
+        ph = self._phases
+        tok0 = self.total_tokens
+        facts = {}
+        # One program at a time, in dispatch order: a first token goes out
+        # when its chunk's id is there, not when the decode program
+        # dispatched behind the chunk has ended too.
+        for seqs, out, first in fl.entries:
+            with flight.phase("engine.fetch_ids", ph, "fetch_ns"):
+                ids, *more = self._jax.device_get(out)
             with flight.phase("engine.sample", ph, "sample_ns"):
-                self._emit(seq, self._sample(logits))
-                if rec is not None:
-                    rec.setdefault("first_ns", time.monotonic_ns())
-                self._maybe_finish(seq)
+                if more:
+                    facts = self._decode_facts(len(seqs), more)
+                for seq, tok in zip(seqs, ids.tolist()):
+                    seq.unread -= 1
+                    if seq.state == FINISHED:
+                        continue
+                    self._emit(seq, tok)
+                    rec = first and self._trace_info.get(seq.request_id)
+                    if rec:
+                        rec.setdefault("first_ns", time.monotonic_ns())
+                    self._maybe_finish(seq)
+        if fl.record is not None:
+            t0_ns, t1_ns, attrs = fl.record
+            flight.record(
+                "engine.step", t0_ns, t1_ns, lane=self._lane,
+                attrs={**attrs, **facts, "tokens": self.total_tokens - tok0})
+
+    def _decode_facts(self, lanes: int, facts) -> Dict[str, Any]:
+        """What a decode program handed back beside its ids (the step's
+        routing, the exit gate), as attributes of its step's record."""
+        attrs = {}
+        if self.cfg.mlp_type == "moe":
+            self._step_moe = facts.pop(0)
+            attrs["experts_touched"] = float(self._step_moe[0])
+            attrs["expert_load_max"] = float(self._step_moe[1])
+        if self.cfg.ut_steps > 1:
+            pdf = facts.pop(0).tolist()     # one entry a pass the program ran
+            self.total_ut_passes[0] += lanes * len(pdf)
+            self.total_ut_passes[1] += lanes * self.cfg.ut_steps
+            attrs["ut_passes"] = len(pdf)
+            attrs["exit_step_mean"] = sum(t * p for t, p in enumerate(pdf, 1))
+            attrs["exit_cdf_early"] = sum(pdf[:-1])
+        return attrs
 
     def _run_verify(self, out: SchedulerOutput):
         """Speculative step: every decode lane rides ONE `verify_step_paged`
@@ -926,7 +1087,9 @@ class InferenceEngine:
         plain decode). Greedy acceptance: the longest draft prefix matching
         the model's own argmax is emitted, then one corrective (or, on full
         acceptance, bonus) token — token-for-token identical to plain
-        greedy decode, just fewer dispatches."""
+        greedy decode, just fewer dispatches. Drained: the drafts and the
+        lanes' current tokens are host values, and how many tokens a lane
+        gains is known only from the ids, so they are read at once."""
         jnp = self._jnp
         np = self._np
         ph = self._phases
@@ -957,20 +1120,20 @@ class InferenceEngine:
                 jnp.asarray(tables),
             )
         with flight.phase("engine.dispatch", ph, "dispatch_ns"):
-            logits, self.kv = self._verify(
+            greedy, self.kv = self._verify(
                 self.params, *args, self.kv, self.cfg
             )
             del args    # the input buffers are released here, not at return
-        with flight.phase("engine.fetch_logits", ph, "fetch_ns"):
-            logits = np.asarray(logits)
+        with flight.phase("engine.fetch_ids", ph, "fetch_ns"):
+            greedy = np.asarray(greedy)
         with flight.phase("engine.sample", ph, "sample_ns"):
-            self._accept_drafts(seqs, lane_drafts, logits)
+            self._accept_drafts(seqs, lane_drafts, greedy)
 
-    def _accept_drafts(self, seqs, lane_drafts, logits):
+    def _accept_drafts(self, seqs, lane_drafts, greedy_ids):
         """Greedy acceptance of a verify step's drafts (see `_run_verify`)."""
         for i, seq in enumerate(seqs):
             d = lane_drafts[i]
-            greedy = logits[i].argmax(axis=-1)
+            greedy = greedy_ids[i]
             emitted: List[int] = []
             accepted = 0
             for j, dt in enumerate(d):
@@ -995,6 +1158,11 @@ class InferenceEngine:
                     break
 
     def _run_decode(self, out: SchedulerOutput):
+        """Dispatch the step's decode program (or its verify program, which
+        is also read). A lane whose newest id is still unread takes it from
+        the device's buffer by its slot: the program is then CHAINED to the
+        one that sampled it, and the host was not in between."""
+        self.total_decode_dispatched += 1
         if out.drafts:
             return self._run_verify(out)
         jnp = self._jnp
@@ -1004,45 +1172,44 @@ class InferenceEngine:
         with flight.phase("engine.build", ph, "build_ns"):
             B = out.batch_bucket
             W = out.width_bucket
-            tokens = np.zeros((B,), np.int32)
-            positions = np.zeros((B,), np.int32)
+            # rows: slot, position, the id where the host knows it, known;
+            # padding lanes: the spare slot, token 0 at position 0
+            lanes = np.zeros((4, B), np.int32)
+            lanes[0] = self._spare
+            lanes[3] = 1
             tables = np.zeros(self._table_shape(B, W), np.int32)  # padding lanes -> null block
             for i, seq in enumerate(seqs):
-                tokens[i] = seq.output[-1]
-                positions[i] = seq.num_tokens - 1   # where this token's KV lands
+                lanes[0, i] = seq.slot
+                lanes[1, i] = seq.num_tokens - 1    # where this token's KV lands
+                if seq.unread:
+                    lanes[3, i] = 0
+                else:
+                    lanes[2, i] = seq.output[-1]
                 self._tables_into(tables[i], seq)
-            self._count_attn(B, W, positions, np.arange(B) < len(seqs))
-            args = (
-                jnp.asarray(tokens),
-                jnp.asarray(positions),
-                jnp.asarray(tables),
-            )
+            self._step_chained = int(not lanes[3].all())
+            self.total_decode_chained += self._step_chained
+            self._count_attn(B, W, lanes[1], np.arange(B) < len(seqs))
+            args = (jnp.asarray(lanes), jnp.asarray(tables))
         with flight.phase("engine.dispatch", ph, "dispatch_ns"):
-            logits, self.kv = self._decode(
-                self.params, *args, self.kv, self.cfg
-            )
+            sampled, self.kv, self._last = self._decode(
+                self.params, *args, self.kv, self._last, self._sampling,
+                self.cfg)
             del args    # the input buffers are released here, not at return
-        with flight.phase("engine.fetch_logits", ph, "fetch_ns"):
-            if isinstance(logits, tuple):   # the step's routing, the exit gate
-                logits, *facts = self._jax.device_get(logits)
-                if self.cfg.mlp_type == "moe":
-                    self._step_moe = facts.pop(0)
-                if self.cfg.ut_steps > 1:
-                    self._step_exit = facts.pop(0)
-                    self.total_ut_passes[0] += len(seqs) * len(self._step_exit)
-                    self.total_ut_passes[1] += len(seqs) * self.cfg.ut_steps
-            else:
-                logits = np.asarray(logits)
-        with flight.phase("engine.sample", ph, "sample_ns"):
-            for i, seq in enumerate(seqs):
-                self._emit(seq, self._sample(logits[i]))
-                self._maybe_finish(seq)
+        with flight.phase("engine.schedule", ph, "sched_ns"):
+            self._sampled(seqs, sampled)
 
     def step(self) -> Dict[str, Any]:
         """One engine iteration; safe to drive manually (tests) or from the
         driver thread. Returns a stats snapshot."""
         with flight.phase("engine.step"):
             return self._step()
+
+    def _try_schedule(self) -> Optional[SchedulerOutput]:
+        with self._lock:
+            try:
+                return self.scheduler.schedule()
+            except NeedsValues:
+                return None
 
     def _step(self) -> Dict[str, Any]:
         t0 = time.monotonic()
@@ -1051,8 +1218,10 @@ class InferenceEngine:
         # a dozen monotonic_ns reads and inactive profiler annotations; the
         # record itself only happens on steps that did work. The span is
         # the first six phases; `export_ns` follows it and `waited_ns` (the
-        # driver thread's idle wait, `_loop`) precedes it. Budgeted ≤5% of
-        # decode-step time (test_flight_perf_smoke).
+        # driver thread's idle wait, `_loop`) precedes it. `fetch_ns` is
+        # the wait for the PREVIOUS step's ids (and a verify step's own),
+        # `sample_ns` their delivery: emission and finish tests. Budgeted
+        # ≤5% of decode-step time (test_flight_perf_smoke).
         fl_on = flight.enabled()
         ph = self._phases = dict.fromkeys(flight.SERVE_STEP_PHASES, 0)
         t0_ns = time.monotonic_ns()
@@ -1060,10 +1229,21 @@ class InferenceEngine:
             self._step_ttfts, self._step_tpots = [], []
             self._step_spec = [0, 0]  # [proposed, accepted]
             self._step_attn = [0, 0]  # [keys run, keys padded]
-            self._step_moe = self._step_exit = None
+            self._step_moe = None
+            self._step_chained = 0
             tok0 = self.total_tokens
-            with self._lock:
-                out = self.scheduler.schedule()
+            had_flight = self._inflight is not None
+            # An export is served drained; so is a plan that needs token
+            # VALUES (`NeedsValues`: drafts, the fold of a preempted lane):
+            # read the step in flight, then plan. Never after planning: an
+            # `eos` read then would finish a lane the plan still holds.
+            out = None if self._side_work else self._try_schedule()
+        if out is None:
+            self._collect()
+        with flight.phase("engine.schedule", ph, "sched_ns"):
+            if out is None:
+                with self._lock:
+                    out = self.scheduler.schedule()
             self.total_preemptions += len(out.preempted)
             for seq in out.preempted:
                 # Recompute preemption re-queues the request: its admission,
@@ -1082,14 +1262,19 @@ class InferenceEngine:
             self._apply_cow()
             self._apply_host_loads()
             self._service_side_work()
+        cur = self._cur = _InFlight()
         for chunk in out.prefills:
             self._run_prefill(chunk)
+        own_tokens = self.total_tokens
         if out.decodes:
             self._run_decode(out)
+        own_tokens = self.total_tokens - own_tokens   # a verify step's, read at once
+        # Step n+1 is on the device: now read step n, one step behind.
+        self._collect()
+        self._inflight = cur if cur.entries else None
         # The span ends where the step's own work does; building `stats`
         # and the metrics export follow it as `export_ns`.
         t1_ns = time.monotonic_ns()
-
         with flight.phase("engine.export_metrics", ph, "export_ns"):
             now = time.monotonic()
             self._tok_window = [t for t in self._tok_window if now - t <= 10.0]
@@ -1124,28 +1309,25 @@ class InferenceEngine:
                 "step_s": now - t0,
             }
             self._export_metrics(stats)
-        if fl_on and (out.prefills or out.decodes):
+        if fl_on and (out.prefills or out.decodes or had_flight):
             idle, self._idle = self._idle, {"waited_ns": 0}
-            facts = {}      # what came back with the decode step's logits
-            if self._step_moe is not None:
-                facts["experts_touched"] = float(self._step_moe[0])
-                facts["expert_load_max"] = float(self._step_moe[1])
-            if self._step_exit is not None:
-                pdf = self._step_exit.tolist()  # one entry a pass the program ran
-                facts["ut_passes"] = len(pdf)
-                facts["exit_step_mean"] = sum(t * p for t, p in enumerate(pdf, 1))
-                facts["exit_cdf_early"] = sum(pdf[:-1])
-            flight.record(
-                "engine.step", t0_ns, t1_ns, lane=self._lane,
-                attrs={"prefills": len(out.prefills),
-                       "decodes": len(out.decodes),
-                       "tokens": stats["step_tokens"],
-                       "attn_keys_run": self._step_attn[0],
-                       "attn_keys_padded": self._step_attn[1],
-                       **facts, **idle, **ph,
-                       "queue_depth": stats["queue_depth"],
-                       "running": stats["running"],
-                       "kv_util": stats["kv_utilization"]})
+            attrs = {"prefills": len(out.prefills),
+                     "decodes": len(out.decodes),
+                     "chained": self._step_chained,
+                     "tokens": own_tokens,
+                     "attn_keys_run": self._step_attn[0],
+                     "attn_keys_padded": self._step_attn[1],
+                     **idle, **ph,
+                     "queue_depth": stats["queue_depth"],
+                     "running": stats["running"],
+                     "kv_util": stats["kv_utilization"]}
+            if self._inflight is not None:
+                # its tokens and what comes back with its ids are not here
+                # yet: `_collect` writes the record when it reads them
+                self._inflight.record = (t0_ns, t1_ns, attrs)
+            else:
+                flight.record("engine.step", t0_ns, t1_ns, lane=self._lane,
+                              attrs=attrs)
         return stats
 
     def stats(self, include_raw: bool = False) -> Dict[str, Any]:
@@ -1184,6 +1366,8 @@ class InferenceEngine:
             "attn_keys_padded": self.total_attn_keys[1],
             "ut_passes_run": self.total_ut_passes[0],
             "ut_passes_full": self.total_ut_passes[1],
+            "decode_dispatched": self.total_decode_dispatched,
+            "decode_chained": self.total_decode_chained,
             "total_tokens": self.total_tokens,
             "total_finished": self.total_finished,
             "total_preemptions": self.total_preemptions,
@@ -1263,9 +1447,15 @@ class InferenceEngine:
         self._stop.set()
         with self._work:
             self._work.notify_all()
+        stuck = False
         if self._thread is not None:
             self._thread.join(timeout=10.0)
-            self._thread = None
+            stuck, self._thread = self._thread.is_alive(), None
+        if not stuck:       # the driver thread is gone: the books are ours
+            try:
+                self._collect()     # a step in flight still delivers its tokens
+            except Exception:  # noqa: BLE001 — the streams are failed below
+                self._inflight = None
         # Fail every open stream — a consumer blocked in queue.get() would
         # otherwise hang forever once the driver thread is gone.
         with self._lock:
@@ -1286,6 +1476,7 @@ class InferenceEngine:
     def _nothing_to_run(self) -> bool:
         return (
             not self.scheduler.has_work()
+            and self._inflight is None      # a step in flight is work
             and not self._side_work
             and not self._stop.is_set()
         )
@@ -1311,9 +1502,10 @@ class InferenceEngine:
                     self._trace_info.clear()
                     # Drop all scheduler state: without it the loop would
                     # respin on the same poisoned batch forever.
-                    for seq in list(self.scheduler.running):
+                    for seq in self.scheduler.running + self.scheduler.closing:
                         self.scheduler.finish(seq, "error")
                     self.scheduler.waiting.clear()
                     self.scheduler._seqs.clear()
+                    self._inflight = None
                 for out in outs:
                     out._q.put(e)

@@ -36,6 +36,18 @@ its blocks are freed and it re-enters the wait queue with prompt+generated
 as the new prompt, vLLM's recompute-style preemption. With prefix caching
 on, its freed full blocks stay cached, so the recompute usually costs one
 cache-hit re-admission rather than a real re-prefill.
+
+One step ahead of the values: the engine dispatches step n+1 before it has
+read step n's sampled ids, so a step is planned from COUNTS. A sequence's
+`unread` ids (sampled on the device, not yet on the host) count as tokens
+everywhere a length is asked for; a sequence whose last token was just
+dispatched is retired at once (`retire`: lane, slot and blocks go back
+before the next `schedule()`, the stream closes when the id is read).
+Only two things here need token VALUES, and both raise `NeedsValues` so
+that the engine reads the step in flight and asks again: folding a
+preempted sequence's output into its prompt, and the n-gram proposer's
+look-up. Every running sequence holds a lane SLOT, its entry in the
+engine's device buffer of last ids.
 """
 
 from __future__ import annotations
@@ -50,6 +62,13 @@ from .kv_manager import KVBlockManager, KVCacheExhausted
 WAITING = "WAITING"
 RUNNING = "RUNNING"
 FINISHED = "FINISHED"
+
+
+class NeedsValues(Exception):
+    """`schedule()` reached a decision that reads a sequence's tokens while
+    its newest id is still unread on the device. Nothing was decided yet
+    that a second call would not decide again the same way: the engine
+    collects the step in flight and calls `schedule()` again."""
 
 
 @dataclasses.dataclass
@@ -75,10 +94,21 @@ class Sequence:
     finish_t: Optional[float] = None
     finish_reason: Optional[str] = None
     preemptions: int = 0
+    # Lane slot while RUNNING: this sequence's entry in the engine's device
+    # buffer of last sampled ids (-1: none).
+    slot: int = -1
+    # Ids sampled on the device that the engine has not read yet (0 or 1
+    # when a step is planned): tokens by count, not yet by value.
+    unread: int = 0
 
     @property
     def num_tokens(self) -> int:
-        return len(self.prompt) + len(self.output)
+        return len(self.prompt) + len(self.output) + self.unread
+
+    @property
+    def num_remaining(self) -> int:
+        """Tokens still to sample, the unread ones already taken off."""
+        return self.max_new_tokens - len(self.output) - self.unread
 
     @property
     def is_decoding(self) -> bool:
@@ -177,7 +207,14 @@ class Scheduler:
         self.proposer = draft_proposer
         self.waiting: Deque[Sequence] = deque()
         self.running: List[Sequence] = []
+        # Retired at dispatch (`retire`): the last id is on its way, lane
+        # and blocks are already given back.
+        self.closing: List[Sequence] = []
         self._seqs: Dict[str, Sequence] = {}
+        self._free_slots = list(range(max_num_seqs - 1, -1, -1))
+        # Victims of a `schedule()` that `NeedsValues` cut short: the call
+        # that completes reports them.
+        self._preempted: List[Sequence] = []
 
     # ------------------------------------------------------------ intake
     def add(self, seq: Sequence) -> None:
@@ -205,7 +242,8 @@ class Scheduler:
         return len(self.running)
 
     def has_work(self) -> bool:
-        return bool(self.waiting or self.running)
+        """Anything queued, running, or sampled and not yet delivered."""
+        return bool(self.waiting or self.running or self.closing)
 
     # --------------------------------------------------------- scheduling
     def finish(self, seq: Sequence, reason: str) -> None:
@@ -215,11 +253,27 @@ class Scheduler:
         seq.finish_reason = reason
         seq.finish_t = time.monotonic()
         if seq in self.running:
-            self.running.remove(seq)
-            self.kv.free(seq.request_id)
+            self._release(seq)
+        elif seq in self.closing:
+            self.closing.remove(seq)
         del self._seqs[seq.request_id]
         if self.proposer is not None:
             self.proposer.forget(seq.request_id)
+
+    def retire(self, seq: Sequence) -> None:
+        """The program that samples `seq`'s last token was just dispatched:
+        give back its lane, slot and blocks NOW, so that the next
+        `schedule()` decides on what a finished sequence leaves (programs
+        run in dispatch order: whoever takes the blocks writes them after
+        this one read them). `finish` closes it when the id is read."""
+        self._release(seq)
+        self.closing.append(seq)
+
+    def _release(self, seq: Sequence) -> None:
+        self.running.remove(seq)
+        self.kv.free(seq.request_id)
+        self._free_slots.append(seq.slot)
+        seq.slot = -1
 
     def _chunk_for(self, seq: Sequence, budget: int) -> PrefillChunk:
         n = min(len(seq.prompt) - seq.num_computed, budget, self.prefill_chunk)
@@ -232,7 +286,7 @@ class Scheduler:
 
     def schedule(self) -> SchedulerOutput:
         prefills: List[PrefillChunk] = []
-        preempted: List[Sequence] = []
+        preempted = self._preempted
         drafts: Dict[str, List[int]] = {}
 
         # Draft funding rides what's left after every decode lane gets its
@@ -267,11 +321,15 @@ class Scheduler:
                     token_ids=seq.prompt + seq.output, num_computed=landed
                 )
             d: List[int] = []
+            if self.proposer is not None and seq.unread:
+                # drafts are looked up in tokens, and a verify step takes
+                # every lane's current token from the host
+                raise NeedsValues
             if self.proposer is not None and draft_budget > 0:
                 # Cap: emitting accepted+1 tokens must never overshoot the
                 # request's remaining generation budget. The proposer keeps
                 # its own history copy — this call is O(new tokens).
-                remaining = seq.max_new_tokens - len(seq.output)
+                remaining = seq.num_remaining
                 if remaining > 1:
                     d = self.proposer.propose(
                         seq.request_id, seq.prompt, seq.output,
@@ -358,6 +416,7 @@ class Scheduler:
             self.waiting.popleft()
             seq.state = RUNNING
             seq.num_cached = cached
+            seq.slot = self._free_slots.pop()
             self.running.append(seq)
             prefills.append(chunk)
             budget -= chunk.num_tokens
@@ -373,6 +432,7 @@ class Scheduler:
             live = {s.request_id for s in decodes}
             drafts = {rid: d for rid, d in drafts.items() if rid in live}
 
+        self._preempted = []
         bb = _next_pow2(len(decodes)) if decodes else 0
         max_w = max(
             (len(self.kv.block_table(s.request_id)) for s in decodes),
@@ -423,8 +483,9 @@ class Scheduler:
         and requeue at the FRONT (it has seniority over never-run arrivals).
         With prefix caching, the freed full blocks stay cached — the
         "recompute" usually re-admits as cache hits."""
-        self.running.remove(seq)
-        self.kv.free(seq.request_id)
+        if seq.unread:
+            raise NeedsValues       # the fold below wants every token's value
+        self._release(seq)
         # Already-generated tokens were already streamed out; fold them into
         # the prompt and shrink the remaining generation budget to match.
         seq.max_new_tokens -= len(seq.output)

@@ -275,7 +275,7 @@ def _annotation():
 
 
 class phase:
-    """``with flight.phase("engine.fetch_logits", acc, "fetch_ns"):`` — one
+    """``with flight.phase("engine.fetch_ids", acc, "fetch_ns"):`` — one
     phase of a hot loop, timed where the work happens and put on two
     clocks at once: the nanoseconds between the two ``monotonic_ns``
     stamps are ADDED to ``into[key]`` (the caller puts the totals on the

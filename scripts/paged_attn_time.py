@@ -1,7 +1,7 @@
 """Time the paged programs of a benchmark configuration on the chip, one
 shape bucket at a time, outside the engine: `python3 -m scripts.paged_attn_time
 [--config smallthinker-21b-a3b] [--prefill 8:0,32:0,128:0,256:0,256:6144]
-[--decode 4:256:9000/300/500/200] [--tile-keys 1024,4096]`.
+[--decode 4:256:9000/300/500/200] [--tile-keys 1024,4096] [--sampled]`.
 
 `--prefill W:offset` is one 512-token chunk (the configuration's
 `prefill_chunk_tokens`) at `pos_offset` over a table of W blocks;
@@ -13,7 +13,11 @@ on the device, `engine_options`), the programs jitted and donated as the
 engine's are. `--tile-keys` times each shape once for every value of
 `models.gpt._ATTN_TILE_KEYS` (a program without that constant runs each
 shape once): how the tile of `_paged_layers`' key loop was chosen (PERF.md
-§6, PR 29).
+§6, PR 29). `--sampled` times each shape a second time as the program the
+engine dispatches (`serve/engine/engine.py: _paged_jits`: the same function
+with the sampler behind it, ids for logits, the last ids carried beside the
+pool), on the same input ids (an expert model routes by them): what the
+epilogue costs a shape (PERF.md §6, PR 33).
 
 Milliseconds a call, mean over `--reps` calls dispatched back to back and
 waited for once. A chip run or nothing: on the CPU (`--rehearse`, the
@@ -34,6 +38,7 @@ def main(argv=None) -> int:
     ap.add_argument("--prefill", default="8:0,32:0,128:0,256:0")
     ap.add_argument("--decode", default="")
     ap.add_argument("--tile-keys", default="")
+    ap.add_argument("--sampled", action="store_true")
     ap.add_argument("--reps", type=int, default=10)
     ap.add_argument("--rehearse", action="store_true")
     a = ap.parse_args(argv)
@@ -85,9 +90,27 @@ def main(argv=None) -> int:
         rows.append(row)
         print(json.dumps(row), flush=True)
 
+    if a.sampled:
+        from ray_tpu.serve.engine import engine as engine_module
+
+        slots = opts["max_num_seqs"]
+        last, sampling = engine_module.init_sampler(slots, 0, 0.0)
+
     for tile in tiles or [None]:
         if tile is not None:
             gpt._ATTN_TILE_KEYS = tile
+        if a.sampled:
+            engine_module._JITS = None      # traced anew under this tile
+
+            def carrying(jit):      # `timed`'s signature, the last ids carried here
+                def call(params, *rest):
+                    nonlocal last
+                    out, kv, last = jit(params, *rest[:-1], last, sampling, rest[-1])
+                    return out, kv
+                return call
+
+            sampled_prefill, sampled_decode, _ = map(
+                carrying, engine_module._paged_jits())
         # The constant is read while tracing and is no part of a program's
         # key; jit keeps traces by function, so each value gets its own.
         prefill = jax.jit(lambda *args: gpt.prefill_paged(*args),
@@ -99,8 +122,13 @@ def main(argv=None) -> int:
             n = min(chunk, W * BS - offset)
             args = (jnp.zeros((1, chunk), jnp.int32).at[0, :n].set(7), jnp.int32(n),
                     jnp.int32(offset), jnp.asarray(table(W)))
-            timed({"program": "prefill_paged", "tile_keys": tile, "chunk": chunk,
-                   "W": W, "keys": W * BS, "offset": offset}, prefill, args)
+            row = {"program": "prefill_paged", "tile_keys": tile, "chunk": chunk,
+                   "W": W, "keys": W * BS, "offset": offset}
+            timed(dict(row), prefill, args)
+            if a.sampled:
+                meta = jnp.asarray([n, offset, 0], jnp.int32)
+                timed({**row, "sampled": True}, sampled_prefill,
+                      (args[0], meta, args[3]))
         for spec in filter(None, a.decode.split(",")):
             B, W, poss = spec.split(":")
             B, W = int(B), int(W)
@@ -112,8 +140,15 @@ def main(argv=None) -> int:
             tables[:len(pos)] = table(W)    # lanes share blocks: reads only matter
             args = (jnp.full((B,), 7, jnp.int32), jnp.asarray(positions),
                     jnp.asarray(tables))
-            timed({"program": "decode_step_paged", "tile_keys": tile, "lanes": B,
-                   "W": W, "keys": W * BS, "positions": pos}, decode, args)
+            row = {"program": "decode_step_paged", "tile_keys": tile, "lanes": B,
+                   "W": W, "keys": W * BS, "positions": pos}
+            timed(dict(row), decode, args)
+            if a.sampled:     # rows: slot, position, host id, known: the same ids
+                lanes = np.zeros((4, B), np.int32)      # (experts route by them)
+                lanes[0], lanes[1] = np.arange(B) % slots, positions
+                lanes[2], lanes[3] = 7, 1
+                timed({**row, "sampled": True}, sampled_decode,
+                      (jnp.asarray(lanes), args[2]))
     report = {"device": {"platform": dev.platform, "kind": dev.device_kind},
               "config": a.config, "n_layers": cfg.n_layers, "block_size": BS,
               "rows": rows}

@@ -5,9 +5,10 @@
 layers form several KV groups gets one block table a group).
 
 Compiles `decode_step_paged`, `prefill_paged` and `verify_step_paged` of
-`models/gpt.py` for one described (not attached) `v5e:2x2` device, jitted
-and donated exactly as the engine does (`serve/engine/engine.py:
-_paged_jits`), and prints for each the GiB of arguments, temporaries and
+`models/gpt.py` for one described (not attached) `v5e:2x2` device, each
+with the sampling epilogue the engine puts behind it and jitted and donated
+exactly as the engine does (`serve/engine/engine.py: _paged_jits`: the
+programs it dispatches), and prints for each the GiB of arguments, temporaries and
 output, and every operation of the compiled program whose result is at
 least half of one layer's pool (K or V), with its layout. In a healthy
 paged program the pool enters in the layout the device keeps, is the layer
@@ -92,7 +93,7 @@ def rehearse(cfg, device, num_blocks: int, block_size: int, lanes: int = 8,
     from jax.sharding import SingleDeviceSharding
 
     from ray_tpu.models.gpt import init_paged_cache, init_params, kv_layout
-    from ray_tpu.serve.engine.engine import _paged_jits
+    from ray_tpu.serve.engine.engine import _paged_jits, init_sampler
 
     one_chip = SingleDeviceSharding(device)
 
@@ -105,6 +106,9 @@ def rehearse(cfg, device, num_blocks: int, block_size: int, lanes: int = 8,
         lambda k: init_params(k, cfg), jax.random.PRNGKey(0)))
     kv = on_chip(jax.eval_shape(
         lambda: init_paged_cache(cfg, num_blocks, block_size)))
+    # the last ids of `lanes` slots and the sampler's constants, as the
+    # engine carries them beside the pool
+    last, sampling = on_chip(jax.eval_shape(lambda: init_sampler(lanes, 0, 0.0)))
 
     def i32(*shape):
         return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one_chip)
@@ -114,9 +118,9 @@ def rehearse(cfg, device, num_blocks: int, block_size: int, lanes: int = 8,
     table = (width,) if groups == 1 else (groups, width)
     programs = {
         "decode_step_paged": lambda: decode.lower(
-            params, i32(lanes), i32(lanes), i32(lanes, *table), kv, cfg),
+            params, i32(4, lanes), i32(lanes, *table), kv, last, sampling, cfg),
         "prefill_paged": lambda: prefill.lower(
-            params, i32(1, chunk), i32(), i32(), i32(*table), kv, cfg),
+            params, i32(1, chunk), i32(3), i32(*table), kv, last, sampling, cfg),
         "verify_step_paged": lambda: verify.lower(
             params, i32(lanes, spec + 1), i32(lanes), i32(lanes),
             i32(lanes, *table), kv, cfg),

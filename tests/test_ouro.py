@@ -296,7 +296,7 @@ def test_step_record_carries_the_passes_the_program_ran_and_the_gate(
         lambda name, *a, attrs=None, **kw: records.append((name, attrs)))
     eng = _engine(case)
     decode, short = eng._decode, dataclasses.replace(eng.cfg, ut_steps=ran)
-    monkeypatch.setattr(eng, "_decode", lambda *a: decode(*a[:5], short))
+    monkeypatch.setattr(eng, "_decode", lambda *a: decode(*a[:-1], short))
     rid = eng.submit([int(t) for t in case[3][:30]], 5)
     _drain(eng)
     assert len(list(eng.stream(rid))) == 5
@@ -378,7 +378,6 @@ def test_one_pass_without_post_norms_is_the_parents_program():
     import jax.numpy as jnp
 
     from ray_tpu.models import gpt
-    from ray_tpu.serve.engine.engine import _paged_jits
 
     cfg = gpt.CONFIGS["gpt2-small"](remat=False, remat_policy=None)
     assert (cfg.ut_steps, cfg.sandwich_norm) == (1, False)
@@ -394,7 +393,8 @@ def test_one_pass_without_post_norms_is_the_parents_program():
     smallthinker = gpt.CONFIGS["smallthinker-21b-a3b"](n_layers=12)
     assert gpt.kv_layout(smallthinker).depth == gpt.kv_layout(smallthinker).per_group == 3
     i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32)
-    text = _paged_jits()[1].lower(
+    # the logits-returning program itself: the engine's puts a sampler behind it
+    text = jax.jit(gpt.decode_step_paged, static_argnums=(5,), donate_argnums=(4,)).lower(
         shapes(tree), i32(4), i32(4), i32(4, 8), kv, cfg).as_text()
     assert "while" in text and text.count("stablehlo.while") == 1   # one layer scan
     if jax.__version__ != "0.9.0":

@@ -722,6 +722,264 @@ class TestEngineDecode:
         assert out.finish_reason == "eos"
 
 
+# ------------------------------------------- steps chained on the device
+def _reference(cfg, params, prompt, n):
+    """The dense path's greedy ids (`prefill` / `decode_step`)."""
+    import jax
+    import jax.numpy as jnp
+
+    from ray_tpu.models.gpt import make_generate
+
+    return jax.jit(make_generate(cfg, n))(
+        params, jnp.asarray([prompt], jnp.int32), jax.random.PRNGKey(0)
+    )[0].tolist()
+
+
+def _plans(eng):
+    """Every work order the engine's loop runs from now on, as it runs."""
+    plans, schedule = [], eng.scheduler.schedule
+
+    def planned():
+        plans.append(schedule())
+        return plans[-1]
+
+    eng.scheduler.schedule = planned
+    return plans
+
+
+def _walk(eng, max_steps=600):
+    """Drive by hand; after every `step()` the block accounting holds.
+    Yields (plan, decode programs dispatched, of them chained) a step."""
+    plans = _plans(eng)
+    n = 0
+    while eng.scheduler.has_work():
+        before = (eng.total_decode_dispatched, eng.total_decode_chained)
+        eng.step()
+        eng.block_manager.check_invariants()
+        held = [s.slot for s in eng.scheduler.running]
+        assert sorted(held + eng.scheduler._free_slots) == list(
+            range(eng.opts.max_num_seqs)), "a lane slot was lost or shared"
+        yield (plans[-1], eng.total_decode_dispatched - before[0],
+               eng.total_decode_chained - before[1])
+        n += 1
+        assert n < max_steps, "engine did not drain"
+
+
+MIXED = [([7, 3, 11, 60, 2, 9, 1], 12), ([5, 5, 5, 9, 8], 7),
+         (list(range(1, 14)), 10), ([44, 2], 14)]
+
+
+class TestChainedSteps:
+    """The engine dispatches step n+1 before it reads step n's ids: the ids
+    a client gets are still the dense reference's, id for id."""
+
+    @pytest.mark.parametrize("num_blocks", [64, 12], ids=["roomy", "tight"])
+    def test_chained_greedy_ids_are_the_dense_references(
+            self, tiny_engine_parts, num_blocks):
+        cfg, params = tiny_engine_parts
+        eng = _make_engine(cfg, params, num_blocks=num_blocks)
+        rids = [eng.submit(p, n) for p, n in MIXED]
+        for _ in _walk(eng):
+            pass
+        for rid, (p, n) in zip(rids, MIXED):
+            assert list(eng.stream(rid)) == _reference(cfg, params, p, n)
+        st = eng.stats()
+        assert st["total_tokens"] == sum(n for _, n in MIXED)
+        assert st["decode_chained"] > st["decode_dispatched"] // 2
+        # 11 blocks of 4 hold less than the four requests' 70 tokens
+        assert (st["total_preemptions"] > 0) == (num_blocks == 12)
+        assert eng.block_manager.free_blocks == num_blocks - 1
+
+    def test_eos_stops_the_stream_though_the_lane_rides_one_step_more(
+            self, tiny_engine_parts):
+        cfg, params = tiny_engine_parts
+        prompt, n = MIXED[0]
+        ref = _reference(cfg, params, prompt, n)
+        k = max(i for i, t in enumerate(ref[:8]) if t not in ref[:i])
+        assert k >= 2, ref            # an id whose FIRST occurrence is late
+        # a pool the request fills: what comes next is admitted into its blocks
+        eng = _make_engine(cfg, params, num_blocks=6)
+        rid = eng.submit(prompt, n, eos_token=ref[k])
+        steps = list(_walk(eng))
+        out = eng.stream(rid)
+        assert list(out) == ref[:k + 1] and out.finish_reason == "eos"
+        # the eos was read after the next step was dispatched: k decode
+        # steps gave ids k..1 after the chunk's, one more ran for nothing
+        assert sum(d for _, d, _ in steps) == k + 1
+        assert eng.stats()["total_tokens"] == k + 1
+        assert eng.block_manager.free_blocks == 5
+        other = [9, 1, 33, 7, 3, 60, 2, 2, 11]
+        rid = eng.submit(other, 10)
+        for _ in _walk(eng):
+            pass
+        assert list(eng.stream(rid)) == _reference(cfg, params, other, 10)
+
+    def test_one_program_a_bucket_chained_or_not(self, tiny_engine_parts):
+        """What `benchmarks/traffic.py:warm_plan` assumes: one decode
+        program a (lanes, width) bucket and one prefill program a (chunk,
+        width) bucket, whether a step takes its ids from the device or
+        from the host."""
+        cfg, params = tiny_engine_parts
+        import dataclasses
+
+        cfg = dataclasses.replace(cfg, d_mlp=cfg.d_mlp + 8)  # programs of its own
+        import jax
+
+        from ray_tpu.models.gpt import init_params
+
+        eng = _make_engine(cfg, init_params(jax.random.PRNGKey(1), cfg),
+                           num_blocks=128, prefill_chunk_tokens=8,
+                           max_step_tokens=32)
+        pre0, dec0 = eng._prefill._cache_size(), eng._decode._cache_size()
+        decode_buckets, prefill_buckets, kinds = set(), set(), set()
+
+        def drive():
+            for step, (plan, dispatched, chained) in enumerate(_walk(eng)):
+                if step % 3 == 0:
+                    eng._collect()  # as a drain would: the next step is unchained
+                for c in plan.prefills:
+                    prefill_buckets.add((
+                        1 << (c.num_tokens - 1).bit_length(),
+                        1 << (-(-(len(c.seq.prompt) + 1) // 4) - 1).bit_length()))
+                if dispatched:
+                    decode_buckets.add((plan.batch_bucket, plan.width_bucket))
+                    kinds.add((plan.batch_bucket, plan.width_bucket, chained))
+
+        for i, (length, new) in enumerate(
+                [(3, 9), (7, 5), (9, 12), (14, 4), (22, 8), (30, 3), (5, 17)]):
+            eng.submit([(5 * i + j) % 60 + 1 for j in range(length)], new)
+            if i == 3:          # lanes and widths churn: drain once midway
+                drive()
+        drive()
+        assert len(decode_buckets) >= 4 and len(prefill_buckets) >= 4
+        # some bucket ran both ways
+        assert len(kinds) > len(decode_buckets)
+        assert eng._decode._cache_size() - dec0 <= len(decode_buckets)
+        assert eng._prefill._cache_size() - pre0 <= len(prefill_buckets)
+
+    def test_counters_say_which_steps_were_chained(self, tiny_engine_parts):
+        cfg, params = tiny_engine_parts
+        # one request: every decode step takes its id from the device, the
+        # first from the final chunk's program
+        eng = _make_engine(cfg, params)
+        eng.submit([1, 2, 3, 4, 5], 9)
+        steps = list(_walk(eng))
+        assert [(d, c) for _, d, c in steps] == [(0, 0)] + [(1, 1)] * 8 + [(0, 0)]
+        st = eng.stats()
+        assert (st["decode_dispatched"], st["decode_chained"]) == (8, 8)
+        assert type(st["decode_dispatched"]) is type(st["decode_chained"]) is int
+
+        # an export is served drained: the decode step after it is not chained
+        from concurrent.futures import Future
+
+        prompt = list(range(1, 10))
+        eng.submit(prompt, 6)
+        walk = _walk(eng)
+        for _ in range(3):
+            next(walk)
+        fut = Future()
+        eng._side_work.append(("export", eng.prompt_digests(prompt), fut))
+        _, dispatched, chained = next(walk)
+        assert fut.done() and (dispatched, chained) == (1, 0)
+        assert [c for _, d, c in walk if d] == [1, 1]
+
+        # the preemption of a lane whose newest id is unread is planned
+        # from values: the engine reads the step in flight first
+        eng = _make_engine(cfg, params, num_blocks=9)
+        for _ in range(3):
+            eng.submit([3] * 8, 16)
+        drained = 0
+        unread = {}
+        for plan, dispatched, chained in _walk(eng):
+            if any(unread.get(v.request_id) for v in plan.preempted):
+                drained += 1
+                assert chained == 0
+            unread = {s.request_id: s.unread for s in eng.scheduler.running}
+        assert eng.total_preemptions > 0 and drained > 0
+
+    def test_verify_steps_run_drained(self, tiny_engine_parts):
+        cfg, params = tiny_engine_parts
+        prompt = [5, 6, 7, 8] * 4
+        eng = _make_engine(cfg, params, spec_tokens=4, max_step_tokens=12)
+        rid = eng.submit(prompt, 20)
+        steps = list(_walk(eng))
+        assert list(eng.stream(rid)) == _reference(cfg, params, prompt, 20)
+        verify = [(d, c) for plan, d, c in steps if plan.drafts]
+        assert verify and all(v == (1, 0) for v in verify)
+        assert eng.stats()["spec_accepted"] > 0
+
+    def test_a_seed_repeats_its_ids_and_lanes_draw_apart(self, tiny_engine_parts):
+        cfg, params = tiny_engine_parts
+
+        def run(seed):
+            eng = _make_engine(cfg, params, temperature=1.5, seed=seed)
+            rids = [eng.submit([7, 3, 11, 60], 16) for _ in range(2)]
+            _drive(eng)
+            return [list(eng.stream(r)) for r in rids]
+
+        a, b, c = run(11), run(11), run(12)
+        assert a == b and a != c
+        assert a[0] != a[1]     # one prompt, one step, two lanes: two draws
+
+    def test_sampler_draws_from_softmax_of_logits_over_temperature(self):
+        import jax
+        import jax.numpy as jnp
+        import numpy as np
+
+        from ray_tpu.models.gpt import sample_ids
+
+        logits = jnp.asarray([[2.0, 0.5, 0.0, -1.0, 1.0, 0.0],
+                              [0.0, 0.0, 3.0, 0.0, -2.0, 1.0]], jnp.float32)
+
+        @jax.jit                # temperature traced, as in the engine's programs
+        def draws(temperature, key):
+            keys = jax.random.split(key, 4000)
+            return jax.vmap(lambda k: sample_ids(logits, temperature, k))(keys)
+
+        for t in (0.7, 2.0):
+            ids = np.asarray(draws(jnp.float32(t), jax.random.PRNGKey(5)))
+            want = np.asarray(jax.nn.softmax(logits / t, axis=-1))
+            for lane in range(2):
+                freq = np.bincount(ids[:, lane], minlength=6) / len(ids)
+                assert np.abs(freq - want[lane]).max() < 0.03, (t, freq, want[lane])
+        greedy = np.asarray(draws(jnp.float32(0.0), jax.random.PRNGKey(5)))
+        assert (greedy == np.asarray([0, 2])).all()
+        # equal maxima: the first, as NumPy's argmax
+        ties = jnp.asarray([[1.0, 3.0, 3.0, 0.0]], jnp.float32)
+        assert sample_ids(ties, 0.0, None).tolist() == [int(np.argmax(ties[0]))]
+
+    @pytest.mark.parametrize("threaded", [False, True], ids=["by-hand", "loop"])
+    def test_shutdown_with_a_step_in_flight_closes_every_stream(
+            self, tiny_engine_parts, threaded):
+        cfg, params = tiny_engine_parts
+        eng = _make_engine(cfg, params)
+        rid = eng.submit([1, 2, 3, 4], 40)
+        out = eng.stream(rid)
+        if threaded:
+            eng.start()
+            assert next(iter(out)) is not None      # the loop is running
+        else:
+            for _ in range(3):
+                eng.step()
+            assert eng._inflight is not None
+        got = {}
+
+        def consume():
+            try:
+                got["toks"] = list(out)
+            except RuntimeError as e:
+                got["err"] = e
+
+        t = threading.Thread(target=consume, daemon=True)
+        t.start()
+        eng.shutdown()
+        t.join(10)
+        assert not t.is_alive(), "a consumer hangs on a stream nobody closes"
+        assert "err" in got and eng._inflight is None
+        with pytest.raises(RuntimeError):
+            eng.submit([1], 1)
+
+
 # ------------------------------------------------ the paged programs alone
 # Three kinds of model through the same three functions (`models/gpt.py`
 # `_paged_layers`), each held to the dense `prefill` / `decode_step` logits:
@@ -1345,10 +1603,13 @@ class TestEnginePhases:
             a = ev["args"]
             assert set(flight.SERVE_STEP_PHASES) | {
                 "waited_ns", "queue_depth", "running", "kv_util",
-                "prefills", "decodes", "tokens", "attn_keys_run",
+                "prefills", "decodes", "chained", "tokens", "attn_keys_run",
                 "attn_keys_padded"} <= set(a)
             # tables of one tile: the programs compute over all they gather
-            assert 0 < a["attn_keys_run"] == a["attn_keys_padded"]
+            # (a step that only read the ids of the one before it ran none)
+            assert a["attn_keys_run"] == a["attn_keys_padded"]
+            assert (a["attn_keys_run"] > 0) == bool(a["prefills"] or a["decodes"])
+            assert a["chained"] in (0, 1) and a["chained"] <= a["decodes"]
             assert all(isinstance(a[k], int) and a[k] >= 0
                        for k in flight.SERVE_STEP_PHASES)
             assert a["waited_ns"] == 0          # driven by step(): no _loop
@@ -1522,10 +1783,11 @@ class TestEnginePhases:
             if e.name.startswith("engine.")]
         names = {n for n, _, _ in events}
         assert {"engine.step", "engine.schedule", "engine.side_work",
-                "engine.build", "engine.dispatch", "engine.fetch_logits",
+                "engine.build", "engine.dispatch", "engine.fetch_ids",
                 "engine.sample", "engine.export_metrics"} <= names, names
         steps = [(s, e) for n, s, e in events if n == "engine.step"]
-        assert len(steps) == 3
+        # three dispatch a program each; the fourth reads the third's id
+        assert len(steps) == 4
         for n, s, e in events:
             if n != "engine.step":
                 assert any(s0 <= s and e <= e0 for s0, e0 in steps), n

@@ -525,25 +525,27 @@ class TestSpeculativeDecoding:
         rep = [5, 6, 7, 8]
         for i in range(3):
             eng.submit(rep * 4, max_new_tokens=20, request_id=f"r{i}")
-        saw_draft = False
+        plans = []
+        schedule = eng.scheduler.schedule
+
+        def planned():      # every work order the engine's own loop ran
+            plans.append(schedule())
+            return plans[-1]
+
+        eng.scheduler.schedule = planned
         steps = 0
         while eng.scheduler.has_work() and steps < 500:
-            with eng._lock:
-                out = eng.scheduler.schedule()
+            eng.step()
+            steps += 1
+        assert steps < 500
+        for out in plans:
             assert out.step_tokens <= 12, (
                 f"budget blown: {out.step_tokens} > 12"
             )
-            if out.drafts:
-                saw_draft = True
-                for rid, d in out.drafts.items():
-                    assert 1 <= len(d) <= 4
-            eng._apply_cow()
-            for chunk in out.prefills:
-                eng._run_prefill(chunk)
-            if out.decodes:
-                eng._run_decode(out)
-            steps += 1
-        assert saw_draft, "identical lanes never produced a funded draft"
+            for rid, d in out.drafts.items():
+                assert 1 <= len(d) <= 4
+        assert any(out.drafts for out in plans), (
+            "identical lanes never produced a funded draft")
         eng.block_manager.check_invariants()
 
     def test_eos_mid_draft_stops_cleanly(self, tiny_engine_parts):

@@ -300,8 +300,9 @@ def test_engine_serves_across_the_window_and_counts_what_it_did(case, form, tile
     from ray_tpu.serve.engine import engine as engine_module
 
     cfg, params, m, tokens, _want = case
-    with tile_keys(FORMS[form]) as jits:
-        monkeypatch.setattr(engine_module, "_JITS", jits)
+    with tile_keys(FORMS[form]):
+        # the engine's own programs, made and traced anew under this tile
+        monkeypatch.setattr(engine_module, "_JITS", None)
         _serves_across_the_window(case, form)
 
 
