@@ -15,7 +15,10 @@ Flagship configs: `gpt2_*` (LayerNorm/GELU/learned-pos), `gptj_6b`
 `smallthinker_21b_a3b` (grouped-query heads, window and global layers,
 dropless top-k experts; served through the paged programs), `ouro_2_6b`
 (sandwich norms, the layer stack run four times over shared weights with an
-exit gate; served through the paged programs).
+exit gate; served through the paged programs), `ax_k1` (latent attention
+over one compressed cache row a token, a leading dense layer, sigmoid-routed
+experts of which this chip holds a range, beside a shared expert; served
+through the paged programs).
 """
 
 from __future__ import annotations
@@ -72,6 +75,28 @@ class GPTConfig:
     # (`kv_layout`). Served by `forward` and the paged programs; every token
     # runs every pass, whatever the gate reads.
     ut_steps: int = 1
+    # Latent attention (MLA), on where `kv_lora_rank` > 0: queries through a
+    # normed bottleneck of `q_lora_rank`; a token's keys and values through
+    # ONE normed latent row of `kv_lora_rank` plus `rotary_dim` rotary
+    # features every head shares, which is all a layer caches (`KVLayout`).
+    # A head is `d_head` features without position (its values are as wide)
+    # beside `rotary_dim` with it: the published `qk_nope_head_dim` =
+    # `v_head_dim` and `qk_rope_head_dim`. `_block` hands `attend` the
+    # ABSORBED operands (`_project_latent`); `n_kv_heads` is not read.
+    q_lora_rank: int = 0
+    kv_lora_rank: int = 0
+    # The published `rope_scaling` group of a YaRN model as (name, value)
+    # pairs (`factor`, `original_max_position_embeddings`, `beta_fast`,
+    # `beta_slow`, `mscale`, `mscale_all_dim`): `_rope_tables` interpolates
+    # the slow dimensions, `_latent_scale` carries mscale squared. A JSON
+    # object becomes sorted pairs.
+    rope_scaling: Optional[Tuple[Tuple[str, float], ...]] = None
+    # The first `dense_layers` of the `n_layers` have a dense gated MLP of
+    # `d_dense_mlp` whatever `mlp_type` says (`first_k_dense_replace`,
+    # `intermediate_size`): their weights lie beside the scanned stack under
+    # `lead_<name>` and run through the same `_block` before the scan.
+    dense_layers: int = 0
+    d_dense_mlp: int = 0
     tie_embeddings: bool = True
     # Mixture-of-Experts (expert parallelism over the ep mesh axis).
     mlp_type: str = "dense"          # dense | moe
@@ -85,6 +110,20 @@ class GPTConfig:
     # own input before the first norm ("router ahead of attention") -- the
     # routing the paged path serves.
     moe_routing: str = "capacity"
+    # Dropless routing's data. Scoring: "softmax" over the kept logits |
+    # "sigmoid" of every logit, the kept scores over their sum, times
+    # `moe_route_scale`. What the router reads: "block" (the layer's input,
+    # before the first norm) | "mlp" (the normed MLP input). `moe_shared`:
+    # always-on gated experts of `d_mlp` each, added to the routed sum.
+    # `moe_held` = (first, count): the range of the `moe_experts` routed
+    # experts THIS program holds (a chip's share of a layer): the router
+    # keeps its width and its top-k, the weight stacks are [L, count, ...],
+    # and an assignment to an absent expert adds nothing (no exchange).
+    moe_scoring: str = "softmax"
+    moe_route_scale: float = 1.0
+    moe_router_in: str = "block"
+    moe_shared: int = 0
+    moe_held: Optional[Tuple[int, int]] = None
     # Execution knobs.
     dtype: Any = jnp.bfloat16
     # The dtype `init_params` makes the tree in. float32: masters for the
@@ -124,6 +163,30 @@ class GPTConfig:
             raise ValueError('rope_layout needs pos="rotary"')
         if self.ut_steps < 1:
             raise ValueError(f"ut_steps {self.ut_steps}: at least one pass")
+        if isinstance(self.rope_scaling, dict):
+            object.__setattr__(self, "rope_scaling", tuple(sorted(
+                (k, float(v)) for k, v in self.rope_scaling.items() if k != "type")))
+        elif self.rope_scaling is not None:
+            object.__setattr__(self, "rope_scaling", tuple(
+                (k, float(v)) for k, v in self.rope_scaling))
+        if self.moe_held is not None:
+            first, count = (int(v) for v in self.moe_held)
+            if not 0 <= first < first + count <= self.moe_experts:
+                raise ValueError(f"moe_held {self.moe_held}: a range of the "
+                                 f"{self.moe_experts} routed experts")
+            object.__setattr__(self, "moe_held", (first, count))
+        if self.moe_scoring not in ("softmax", "sigmoid") \
+                or self.moe_router_in not in ("block", "mlp"):
+            raise ValueError("moe_scoring: softmax | sigmoid; moe_router_in: block | mlp")
+        if self.kv_lora_rank and (self.pos != "rotary" or not self.q_lora_rank):
+            raise ValueError('latent attention (kv_lora_rank) needs pos="rotary" '
+                             "and a query bottleneck (q_lora_rank)")
+        if self.dense_layers and (
+                not 0 < self.dense_layers < self.n_layers or self.d_dense_mlp < 1
+                or self.ut_steps > 1 or self.layer_kinds is not None):
+            raise ValueError(
+                "dense_layers: fewer than n_layers, of width d_dense_mlp, in a "
+                "one-pass model whose layers are of one attention kind")
         if self.sandwich_norm and self.parallel_block:
             raise ValueError("sandwich_norm norms each sublayer's output on its "
                              "way into the stream; a parallel_block has one sum")
@@ -131,6 +194,11 @@ class GPTConfig:
     @property
     def kv_heads(self) -> int:
         return self.n_kv_heads or self.n_heads
+
+    @property
+    def held_experts(self) -> int:
+        """Routed experts whose weights this program holds."""
+        return self.moe_held[1] if self.moe_held else self.moe_experts
 
     @property
     def layer_kinds(self):
@@ -161,16 +229,24 @@ class GPTConfig:
 
     @property
     def n_params(self) -> int:
+        """Parameters HELD (a `moe_held` range counts its own experts)."""
         E, L, F, V, Hd = self.d_model, self.n_layers, self.d_mlp, self.vocab_size, self.n_heads * self.d_head
         gated = self.activation in ("swiglu", "reglu")
         if self.mlp_type == "moe":
             n_mats = 3 if gated else 2
-            mlp_params = self.moe_experts * n_mats * E * F + E * self.moe_experts
+            mlp_params = ((self.held_experts + self.moe_shared) * n_mats * E * F
+                          + E * self.moe_experts)
         else:
             mlp_params = (2 if gated else 1) * E * F + F * E
-        per_layer = E * (Hd + 2 * self.kv_heads * self.d_head) + Hd * E + mlp_params
-        per_layer += (4 if self.sandwich_norm else 2) * E  # norms
-        total = L * per_layer + V * E + (0 if self.tie_embeddings else E * V)
+        attn = E * (Hd + 2 * self.kv_heads * self.d_head) + Hd * E
+        if self.kv_lora_rank:
+            Rq, Rkv, Hr = self.q_lora_rank, self.kv_lora_rank, self.n_heads * self.rotary_dim
+            attn = (E * Rq + Rq + Rq * (Hd + Hr) + E * (Rkv + self.rotary_dim) + Rkv
+                    + Rkv * 2 * Hd + Hd * E)
+        norms = (4 if self.sandwich_norm else 2) * E
+        total = (L * (attn + norms) + (L - self.dense_layers) * mlp_params
+                 + self.dense_layers * 3 * E * self.d_dense_mlp
+                 + V * E + (0 if self.tie_embeddings else E * V))
         if self.ut_steps > 1:
             total += E + 1  # the exit gate
         if self.pos == "learned":
@@ -347,6 +423,87 @@ def ouro_2_6b(**kw):
     )
 
 
+def ax_k1(**kw):
+    """A.X-K1 (huggingface.co/skt/A.X-K1, `model_type: "axk1"`; the layer is
+    DeepSeek-V3's): latent attention (queries through a normed bottleneck of
+    1536, keys and values through ONE normed latent row of 512 beside 64
+    rotary features all 64 heads share: 576 numbers a token a layer are all
+    that is cached), YaRN rotary (factor 32 over 4,096), one leading dense
+    layer (SiLU-gated MLP of 18432), then layers of 192 sigmoid-routed
+    experts of 2048 (top-8, the kept scores normalised, x 2.5, the router on
+    the normed MLP input) beside one shared expert; untied head, bfloat16.
+    Serving only: `forward` (attn_impl="ref") and the paged programs. 519 B
+    parameters whole: a chip serves its share of a deployment (`moe_held`, a
+    slice of the vocabulary, some of the layers), which the caller states."""
+    return GPTConfig(
+        **{
+            **dict(
+                n_layers=61,
+                dense_layers=1,
+                d_model=7168,
+                n_heads=64,
+                d_head=128,
+                q_lora_rank=1536,
+                kv_lora_rank=512,
+                d_mlp=2048,
+                d_dense_mlp=18432,
+                vocab_size=163840,
+                max_seq=131072,
+                norm="rmsnorm",
+                activation="swiglu",
+                pos="rotary",
+                rotary_dim=64,
+                rope_theta=10000.0,
+                rope_scaling=(("beta_fast", 32.0), ("beta_slow", 1.0), ("factor", 32.0),
+                              ("mscale", 1.0), ("mscale_all_dim", 1.0),
+                              ("original_max_position_embeddings", 4096.0)),
+                tie_embeddings=False,
+                mlp_type="moe",
+                moe_experts=192,
+                moe_top_k=8,
+                moe_routing="dropless",
+                moe_scoring="sigmoid",
+                moe_route_scale=2.5,
+                moe_router_in="mlp",
+                moe_shared=1,
+                param_dtype=jnp.bfloat16,
+                # As for `smallthinker_21b_a3b` (under std 0.02 a deep random
+                # stack decodes one token whatever its layers do): the
+                # embedding has std 1.5; both latents are normed, so the
+                # down-projections' scales (`dq`, `dkv`) are moot; queries and
+                # keys of std 1.5 over 192 features under the scale 192^-0.5 x
+                # mscale^2 = 0.131 give scores of std 4.1 (a handful of keys
+                # carry a query's weight, so a wrong scale or a rotation on
+                # the wrong columns moves tokens); router logits of std 2
+                # (the kept sigmoid scores all near 1, so the eight weights
+                # are near 0.31 each, where a softmax would spread them
+                # tenfold). Every MLP's output has gain 0.5: a routed expert
+                # adds 0.31 x 0.6 x 0.5 = 0.09 of the stream's unit where it
+                # lands (the held range sees one of a token's eight
+                # assignments every other layer). How they were chosen (token
+                # errors of the benchmark's own check, my chip runs, PR 34,
+                # `scripts/axk1_tolerance.py --init-gains`): at a routed
+                # output gain of 1 a top-8 near-tie that bfloat16 decides
+                # otherwise than float32 moved a checked token as far as
+                # float8 weights do (sound up to 0.048, float8 from 0.049, 12
+                # seeds); at 0.25 a softmax in the sigmoid's place no longer
+                # showed (0.019 against a sound 0.008); with q, k 1.3 float8
+                # read 0.046-0.093 beside a sound tail of 0.034 (18 seeds);
+                # with 1.7 bfloat16 itself diverged (sound up to 0.116); at
+                # 1.5 the sound engine reads at most 0.048 (31 seeds) and
+                # float8 from 0.129 (18 seeds). No program's shape or time
+                # depends on the numbers.
+                init="unit_stream",
+                init_gains=(("embed", 1.5), ("dq", 1.0), ("q", 1.5), ("dkv", 1.0),
+                            ("k", 1.5), ("v", 1.0), ("o", 0.9), ("router", 2.0),
+                            ("mlp_in", 1.0), ("mlp_out", 0.5), ("head", 1.0)),
+                attn_impl="ref",
+            ),
+            **kw,
+        }
+    )
+
+
 CONFIGS = {
     "gpt2-small": gpt2_small,
     "gpt2-medium": gpt2_medium,
@@ -355,6 +512,7 @@ CONFIGS = {
     "llama-7b": llama_7b,
     "smallthinker-21b-a3b": smallthinker_21b_a3b,
     "ouro-2.6b": ouro_2_6b,
+    "ax-k1": ax_k1,
 }
 
 
@@ -380,6 +538,11 @@ def param_logical_dims(cfg: GPTConfig) -> Dict[str, Tuple[Optional[str], ...]]:
         del dims["w_qkv"], dims["b_qkv"]
         dims["w_q"] = ("layers", "embed", "heads", "head_dim")
         dims["w_kv"] = ("layers", "embed", None, "heads", "head_dim")
+    if cfg.kv_lora_rank or cfg.dense_layers or cfg.moe_shared:
+        raise NotImplementedError(
+            "no sharding is written for latent attention (kv_lora_rank), "
+            "leading dense layers (dense_layers) or a shared expert "
+            "(moe_shared): they are served on one chip")
     if cfg.mlp_type == "moe":
         dims["moe_router"] = ("layers", "embed", "experts")
         dims["moe_w_in"] = ("layers", "experts", "embed", "mlp")
@@ -427,7 +590,8 @@ def _init_unit_stream(rng, cfg: GPTConfig) -> Dict[str, jnp.ndarray]:
         raise NotImplementedError(
             'init="unit_stream" covers rotary models with a gated MLP or gated '
             "experts and an untied head whose preset states `init_gains`")
-    E, L, F, V, X = cfg.d_model, cfg.n_layers, cfg.d_mlp, cfg.vocab_size, cfg.moe_experts
+    E, F, V, X = cfg.d_model, cfg.d_mlp, cfg.vocab_size, cfg.held_experts
+    L = cfg.n_layers - cfg.dense_layers         # the scanned stack's layers
     H, Hkv, Dh = cfg.n_heads, cfg.kv_heads, cfg.d_head
     k = jax.random.split(rng, 16)
     dt, g = cfg.param_dtype, dict(cfg.init_gains)
@@ -437,8 +601,6 @@ def _init_unit_stream(rng, cfg: GPTConfig) -> Dict[str, jnp.ndarray]:
                 * (gain / math.sqrt(fan_in))).astype(dt)
 
     ones, zeros = (lambda: jnp.ones((L, E), dt)), (lambda: jnp.zeros((L, E), dt))
-    q, kv = n(k[1], (L, E, H, Dh), g["q"], E), [
-        n(k[9], (L, E, Hkv, Dh), g["k"], E), n(k[10], (L, E, Hkv, Dh), g["v"], E)]
     params = {
         "tok_embed": n(k[0], (V, E), g["embed"], 1),
         "ln_f_w": jnp.ones((E,), dt), "ln_f_b": jnp.zeros((E,), dt),
@@ -446,18 +608,43 @@ def _init_unit_stream(rng, cfg: GPTConfig) -> Dict[str, jnp.ndarray]:
         "ln1_w": ones(), "ln1_b": zeros(), "ln2_w": ones(), "ln2_b": zeros(),
         "lm_head": n(k[7], (E, V), g["head"], E),
     }
-    if Hkv != H:    # the layouts of `init_params`
-        params.update({"w_q": q, "w_kv": jnp.stack(kv, axis=2)})
+    if cfg.kv_lora_rank:
+        # Latent attention: both latents are normed (weights of one); the
+        # shared rotary key columns of `w_dkv` and the per-head key half of
+        # `w_ukv` carry `k`, its value half `v`.
+        Rq, Rkv, Dr = cfg.q_lora_rank, cfg.kv_lora_rank, cfg.rotary_dim
+        params.update({
+            "w_dq": n(k[1], (L, E, Rq), g["dq"], E),
+            "q_norm_w": jnp.ones((L, Rq), dt),
+            "w_uq": n(k[9], (L, Rq, H, Dh + Dr), g["q"], Rq),
+            "w_dkv": jnp.concatenate([n(k[10], (L, E, Rkv), g["dkv"], E),
+                                      n(k[13], (L, E, Dr), g["k"], E)], axis=-1),
+            "kv_norm_w": jnp.ones((L, Rkv), dt),
+            "w_ukv": jnp.concatenate([n(k[14], (L, Rkv, H, Dh), g["k"], Rkv),
+                                      n(k[15], (L, Rkv, H, Dh), g["v"], Rkv)], axis=-1),
+        })
     else:
-        params.update({"w_qkv": jnp.stack([q, *kv], axis=2),
-                       "b_qkv": jnp.zeros((L, 3, H, Dh), dt)})
+        q, kv = n(k[1], (L, E, H, Dh), g["q"], E), [
+            n(k[9], (L, E, Hkv, Dh), g["k"], E), n(k[10], (L, E, Hkv, Dh), g["v"], E)]
+        if Hkv != H:    # the layouts of `init_params`
+            params.update({"w_q": q, "w_kv": jnp.stack(kv, axis=2)})
+        else:
+            params.update({"w_qkv": jnp.stack([q, *kv], axis=2),
+                           "b_qkv": jnp.zeros((L, 3, H, Dh), dt)})
     if cfg.mlp_type == "moe":
         params.update({
-            "moe_router": n(k[3], (L, E, X), g["router"], E),
+            "moe_router": n(k[3], (L, E, cfg.moe_experts), g["router"], E),
             "moe_w_in": n(k[4], (L, X, E, F), g["mlp_in"], E),
             "moe_w_gate": n(k[8], (L, X, E, F), g["mlp_in"], E),
             "moe_w_out": n(k[5], (L, X, F, E), g["mlp_out"], F),
         })
+        if cfg.moe_shared:
+            ks, Fs = jax.random.split(k[12], 3), cfg.moe_shared * F
+            params.update({
+                "shared_w_in": n(ks[0], (L, E, Fs), g["mlp_in"], E),
+                "shared_w_gate": n(ks[1], (L, E, Fs), g["mlp_in"], E),
+                "shared_w_out": n(ks[2], (L, Fs, E), g["mlp_out"], Fs),
+            })
     else:
         params.update({
             "w_in": n(k[3], (L, E, F), g["mlp_in"], E), "b_in": jnp.zeros((L, F), dt),
@@ -471,7 +658,24 @@ def _init_unit_stream(rng, cfg: GPTConfig) -> Dict[str, jnp.ndarray]:
     if cfg.ut_steps > 1:
         params["exit_gate_w"] = n(k[11], (E,), g["exit_gate"], E)
         params["exit_gate_b"] = jnp.zeros((), dt)
+    if cfg.dense_layers:    # the leading layers: the same block, a dense MLP
+        lead = _init_unit_stream(jax.random.fold_in(k[12], 1), _lead_cfg(cfg))
+        params.update({"lead_" + name: a for name, a in _layer_stack(lead).items()})
     return params
+
+
+def _lead_cfg(cfg: GPTConfig) -> GPTConfig:
+    """The config of the `dense_layers` leading layers as a model of their
+    own: the same attention, a dense MLP of `d_dense_mlp`."""
+    return dataclasses.replace(
+        cfg, n_layers=cfg.dense_layers, dense_layers=0, mlp_type="dense",
+        d_mlp=cfg.d_dense_mlp)
+
+
+def _lead_stack(params):
+    """The leading dense layers' stacked weights [dense_layers, ...] under
+    the names `_block` reads."""
+    return {k: params["lead_" + k] for k in _LAYER_KEYS if "lead_" + k in params}
 
 
 def init_params(rng, cfg: GPTConfig) -> Dict[str, jnp.ndarray]:
@@ -479,6 +683,10 @@ def init_params(rng, cfg: GPTConfig) -> Dict[str, jnp.ndarray]:
         return _init_unit_stream(rng, cfg)
     if cfg.init != "gpt2":
         raise ValueError(f"init {cfg.init!r}: gpt2 | unit_stream")
+    if cfg.kv_lora_rank or cfg.dense_layers or cfg.moe_shared or cfg.moe_held:
+        raise NotImplementedError(
+            'init="gpt2" makes no latent attention, leading dense layers, shared '
+            'expert or held range of experts: such a preset states init="unit_stream"')
     E, L, F, V = cfg.d_model, cfg.n_layers, cfg.d_mlp, cfg.vocab_size
     H, Dh = cfg.n_heads, cfg.d_head
     k = jax.random.split(rng, 16)
@@ -615,33 +823,57 @@ def _dense_mlp(cfg: GPTConfig, p, mlp_in):
     return jnp.einsum("bsf,fe->bse", u, p["w_out"]) + p["b_out"]
 
 
-def _dropless_mlp(cfg: GPTConfig, router, experts, block_in, mlp_in,
+def _dropless_mlp(cfg: GPTConfig, router, experts, router_in, mlp_in,
                   layer=None, valid=None):
-    """The dropless expert layer over [B, S, E]: float32 router on the
-    layer's input `block_in`, top-k without capacity, gated experts
-    (ops/moe.py). `experts` = (w_gate, w_in,
-    w_out), this layer's or with `layer` the whole stacks. Returns (y,
-    [2] f32 = experts touched and the busiest expert's share) of this
-    layer's routing, tokens outside `valid` [B, S] left out of the count."""
+    """The dropless expert layer over [B, S, E]: float32 router on
+    `router_in` (the layer's input or the normed MLP input, as
+    `cfg.moe_router_in` says), top-k without capacity under the config's
+    scoring, gated experts (ops/moe.py). `experts` = (w_gate, w_in,
+    w_out), this layer's or with `layer` the whole stacks. A program that
+    holds a range of the experts (`cfg.moe_held`) routes over all of them and
+    keeps the combine matrix's columns of its own: what an absent expert
+    would add is left out. Returns (y, [2] f32 = experts touched and the
+    busiest expert's share) of this layer's routing over the experts held,
+    tokens outside `valid` [B, S] left out of the count; under `moe_held`
+    [4]: also the tokens' assignments that fell on held experts, and all."""
     from ..ops import moe
 
     B, S, E = mlp_in.shape
-    logits = block_in.reshape(B * S, E).astype(jnp.float32) @ router.astype(jnp.float32)
-    idx, w = moe.dropless_route(logits, cfg.moe_top_k)
+    logits = router_in.reshape(B * S, E).astype(jnp.float32) @ router.astype(jnp.float32)
+    idx, w = moe.dropless_route(logits, cfg.moe_top_k, cfg.moe_scoring,
+                                cfg.moe_route_scale)
     combine = moe.dropless_combine(idx, w, cfg.moe_experts)
-    # Few tokens cannot reach every expert: read only the chosen ones.
-    few = B * S * cfg.moe_top_k < cfg.moe_experts
+    if cfg.moe_held:
+        combine = combine[:, cfg.moe_held[0]: sum(cfg.moe_held)]
     y = moe.dropless_experts(
         mlp_in.reshape(B * S, E), combine, *experts, cfg.activation,
-        layer=layer, touched_k=cfg.moe_top_k if few else 0)
-    load = moe.dropless_load(combine, None if valid is None else valid.reshape(B * S))
+        layer=layer, touched_k=cfg.moe_top_k if _few_tokens(cfg, B * S) else 0)
+    load = moe.dropless_load(combine, None if valid is None else valid.reshape(B * S),
+                             cfg.moe_top_k if cfg.moe_held else 0)
     return y.reshape(B, S, E), jnp.stack(load)
+
+
+def _few_tokens(cfg: GPTConfig, tokens: int) -> bool:
+    """Few tokens cannot reach every expert: such a step reads only the
+    chosen ones (`dropless_experts`' loop, at most as many trips as experts
+    are held). Of a token's top-k assignments the held range sees its share,
+    held / experts, so fewer land here than experts are held exactly when
+    tokens x top_k is under the ROUTER's width, whatever the range."""
+    return tokens * cfg.moe_top_k < cfg.moe_experts
+
+
+def _gated_mlp(cfg: GPTConfig, x, w_gate, w_in, w_out):
+    g = jnp.einsum("bse,ef->bsf", x, w_gate)
+    u = jnp.einsum("bse,ef->bsf", x, w_in)
+    u = (jax.nn.silu(g) if cfg.activation == "swiglu" else jax.nn.relu(g)) * u
+    return jnp.einsum("bsf,fe->bse", u, w_out)
 
 
 def _mlp(cfg: GPTConfig, p, router, block_in, mlp_in, stacks=None, layer=None,
          valid=None):
     """The layer's MLP over mlp_in [B, S, E], chosen by `cfg`: dense,
-    capacity-routed experts (training) or dropless experts. `p` holds the
+    capacity-routed experts (training) or dropless experts (beside them the
+    always-on shared expert of a model that has one). `p` holds the
     layer's weights in cfg.dtype, `router` the router as stored; `stacks`
     are the whole expert stacks where the layer scan did not cut them
     (`_paged_layers`), read at `layer`. Returns (y, aux loss f32 scalar,
@@ -649,8 +881,12 @@ def _mlp(cfg: GPTConfig, p, router, block_in, mlp_in, stacks=None, layer=None,
     aux, load = jnp.zeros((), jnp.float32), None
     if cfg.mlp_type == "moe" and cfg.moe_routing == "dropless":
         experts = stacks or (p["moe_w_gate"], p["moe_w_in"], p["moe_w_out"])
-        y, load = _dropless_mlp(cfg, router, experts, block_in, mlp_in,
-                                layer=layer if stacks else None, valid=valid)
+        y, load = _dropless_mlp(
+            cfg, router, experts, mlp_in if cfg.moe_router_in == "mlp" else block_in,
+            mlp_in, layer=layer if stacks else None, valid=valid)
+        if cfg.moe_shared:
+            y = y + _gated_mlp(cfg, mlp_in, p["shared_w_gate"], p["shared_w_in"],
+                               p["shared_w_out"])
     elif cfg.mlp_type == "moe":
         from ..ops.moe import moe_forward
 
@@ -671,20 +907,26 @@ def _attention_plain(cfg: GPTConfig, q, k, v, positions, window=None):
     """Masked attention in XLA operations for what the kernels do not take:
     K/V heads shared by groups of query heads, and a per-layer window
     (`window`: a traced scalar, query i sees keys j with i - window < j <=
-    i). q [B, H, S, Dh]; k, v [B, Hkv, S, Dh]; positions [S]."""
+    i). q [B, H, S, Dh]; k, v [B, Hkv, S, Dh]; positions [S]. Latent
+    attention's absorbed operands (`_project_latent`) are one K/V head whose
+    values (`v` None) are the key row's first `kv_lora_rank` columns."""
     B, H, S, Dh = q.shape
     Hkv = k.shape[1]
     qg = q.reshape(B, Hkv, H // Hkv, S, Dh)
     scores = jnp.einsum(
         "bgrsd,bgtd->bgrst", qg, k, preferred_element_type=jnp.float32
-    ) / math.sqrt(Dh)
+    )
+    if v is None:
+        v, scores = k[..., :cfg.kv_lora_rank], scores * _latent_scale(cfg)
+    else:
+        scores = scores / math.sqrt(Dh)
     i, j = positions[:, None], positions[None, :]
     mask = j <= i
     if window is not None:
         mask = mask & (j > i - window)
     probs = jax.nn.softmax(jnp.where(mask, scores, -1e30), axis=-1)
     out = jnp.einsum("bgrst,bgtd->bgrsd", probs.astype(v.dtype), v)
-    return out.reshape(B, H, S, Dh)
+    return out.reshape(B, H, S, v.shape[-1])
 
 
 _NO_WINDOW = 1 << 30    # a window no sequence reaches: a global layer's
@@ -705,8 +947,9 @@ def _refuse_new_fields(cfg: GPTConfig, what: str):
     """A check on input for the programs that cannot take a field: the dense
     cache [L, B, H, M, Dh] has no K/V-head count and no window, and the
     stage split cuts no per-layer kinds and loops over no passes. Grouped-query
-    heads, per-layer kinds and a looped stack run in `forward`
-    (attn_impl="ref" for the first two) and in the paged programs."""
+    heads, per-layer kinds, a looped stack, latent attention and leading
+    dense layers run in `forward` (attn_impl="ref" but for the looped stack)
+    and in the paged programs."""
     bad = []
     if cfg.kv_heads != cfg.n_heads:
         bad.append("grouped-query heads (n_kv_heads)")
@@ -715,23 +958,34 @@ def _refuse_new_fields(cfg: GPTConfig, what: str):
     if cfg.ut_steps > 1:
         bad.append("layers run several times (ut_steps): no loop of passes, "
                    "and no cache row a (pass, layer) pair")
+    if cfg.kv_lora_rank:
+        bad.append("latent attention (kv_lora_rank): no cache of one "
+                   "compressed row a token")
+    if cfg.dense_layers:
+        bad.append("leading dense layers (dense_layers) beside the scanned stack")
     if bad:
         raise NotImplementedError(f"{what} does not support " + ", ".join(bad))
 
 
-def _rotary(cfg: GPTConfig, rope_tables, positions, q, k):
-    """q and k [B, heads, S, Dh] with their first `rotary_dim` features
-    rotated to integer `positions`: [S] (every lane alike), [] (one
-    position, S = 1) or [B, S] (each lane's own)."""
-    rd = min(cfg.rotary_dim, cfg.d_head)
+def _rope_rows(rope_tables, positions):
+    """(cos, sin) rows [..., S, rd/2] of the tables at integer `positions`:
+    [S] (every lane alike), [] (one position, S = 1) or [B, S] (each lane's
+    own), leading dims to broadcast against [B, heads, S, .]."""
 
-    def rows(table):                    # [..., S, rd/2], leading dims broadcast
+    def rows(table):
         r = table[positions]
         if positions.ndim == 2:         # [B, S, rd/2]: the heads' axis goes in
             return r[:, None]
         return r[None] if positions.ndim == 0 else r
 
-    c, s = rows(rope_tables[0]), rows(rope_tables[1])
+    return rows(rope_tables[0]), rows(rope_tables[1])
+
+
+def _rotary(cfg: GPTConfig, rope_tables, positions, q, k):
+    """q and k [B, heads, S, Dh] with their first `rotary_dim` features
+    rotated to integer `positions` (`_rope_rows`)."""
+    rd = min(cfg.rotary_dim, cfg.d_head)
+    c, s = _rope_rows(rope_tables, positions)
 
     def rotated(x):
         if rd == cfg.d_head:
@@ -741,6 +995,61 @@ def _rotary(cfg: GPTConfig, rope_tables, positions, q, k):
     return rotated(q), rotated(k)
 
 
+def _yarn(cfg: GPTConfig, name: str) -> float:
+    return dict(cfg.rope_scaling)[name]
+
+
+def _yarn_mscale(factor: float, mscale: float) -> float:
+    return 0.1 * mscale * math.log(factor) + 1.0 if factor > 1.0 else 1.0
+
+
+def _latent_scale(cfg: GPTConfig) -> float:
+    """What latent attention's scores are multiplied by: one over the root
+    of a head's query width (`d_head` + `rotary_dim`), times YaRN's mscale
+    (of `mscale_all_dim`) squared where the rotary tables are scaled."""
+    scale = 1.0 / math.sqrt(cfg.d_head + cfg.rotary_dim)
+    if cfg.rope_scaling and _yarn(cfg, "mscale_all_dim"):
+        scale *= _yarn_mscale(_yarn(cfg, "factor"), _yarn(cfg, "mscale_all_dim")) ** 2
+    return scale
+
+
+def _project_latent(cfg: GPTConfig, p, h, rope_tables, positions):
+    """Latent attention's ABSORBED operands from the normed input h [B, S,
+    E]: (q' [B, H, S, R + Dr], the key row [B, 1, S, R + Dr], expand), R =
+    `kv_lora_rank`, Dr = `rotary_dim`.
+
+    A head's score against a key is q_nope . (c W_UK^h) + rot(q_rope) .
+    rot(k_r) = (q_nope W_UK^h^T) . c + ...: with the key up-projection moved
+    onto the query, every head attends over the SAME row [c | rot(k_r)], the
+    row a layer caches, and the weighted sum of the rows' first R columns,
+    [B, H, S, R], goes through the head's value up-projection afterwards
+    (`expand` -> [B, H, S, d_head]). To `attend` this is one K/V head shared
+    by all H query heads, with values that are columns of the keys. The
+    published checkpoint interleaves a rotary pair's two features (x[2i],
+    x[2i+1]); they are un-interleaved here, on q and k alike, and rotated as
+    halves, which leaves every score as it was."""
+    R, Dn = cfg.kv_lora_rank, cfg.d_head
+    cq = rmsnorm(jnp.einsum("bse,er->bsr", h, p["w_dq"]), p["q_norm_w"])
+    q = jnp.einsum("bsr,rhd->bhsd", cq, p["w_uq"])           # [B, H, S, Dn + Dr]
+    ckv = jnp.einsum("bse,er->bsr", h, p["w_dkv"])           # [B, S, R + Dr]
+    c = rmsnorm(ckv[..., :R], p["kv_norm_w"])
+    cos, sin = _rope_rows(rope_tables, positions)
+
+    def rotated(x):
+        halves = jnp.concatenate([x[..., 0::2], x[..., 1::2]], -1)
+        return rotate_half(halves, cos, sin)
+
+    w_uk, w_uv = p["w_ukv"][..., :Dn], p["w_ukv"][..., Dn:]   # [R, H, Dn] each
+    q = jnp.concatenate([jnp.einsum("bhsd,rhd->bhsr", q[..., :Dn], w_uk),
+                         rotated(q[..., Dn:])], -1)
+    k = jnp.concatenate([c[:, None], rotated(ckv[:, None, :, R:])], -1)
+
+    def expand(attn):
+        return jnp.einsum("bhsr,rhd->bhsd", attn, w_uv)
+
+    return q, k, expand
+
+
 def _block(cfg: GPTConfig, rope_tables, attend, x, layer_params, positions,
            kind=None, stacks=None, layer=None, valid=None):
     """One transformer block, the only one: x [B, S, E] in cfg.dtype ->
@@ -748,22 +1057,29 @@ def _block(cfg: GPTConfig, rope_tables, attend, x, layer_params, positions,
 
     `attend(q, k, v, kind)` is the program's attention: q [B, H, S, Dh] and
     k, v [B, Hkv, S, Dh] after rotary -> (attention [B, H, S, Dh], whatever
-    cache state the program carries). `kind`: this layer's entry of
-    `_layer_kind_xs` (traced scalars) for a model with layers of two kinds;
-    `stacks`, `layer`, `valid`: see `_mlp`."""
+    cache state the program carries); under latent attention the absorbed
+    operands of `_project_latent` (one key row a token, `v` None) -> [B, H, S,
+    kv_lora_rank]. `kind`: this layer's entry of `_layer_kind_xs` (traced
+    scalars) for a model with layers of two kinds; `stacks`, `layer`,
+    `valid`: see `_mlp`."""
     # Cast this layer's master weights to compute dtype (bf16 → MXU).
     p = jax.tree_util.tree_map(lambda a: a.astype(cfg.dtype), layer_params)
     block_in = x
 
     h = _norm(x, p["ln1_w"], p["ln1_b"], cfg.norm)
-    q, k, v = (a.transpose(0, 2, 1, 3) for a in _project_qkv(cfg, p, h))
-    # [B, S, heads, Dh] -> [B, heads, S, Dh]
-    if cfg.pos == "rotary":
-        qr, kr = _rotary(cfg, rope_tables, positions, q, k)
-        # A layer without positional encoding keeps q and k as projected.
-        q, k = (qr, kr) if kind is None else (
-            jnp.where(kind["rope"], qr, q), jnp.where(kind["rope"], kr, k))
-    attn, state = attend(q, k, v, kind)
+    if cfg.kv_lora_rank:
+        q, k, expand = _project_latent(cfg, p, h, rope_tables, positions)
+        attn, state = attend(q, k, None, kind)
+        attn = expand(attn)
+    else:
+        q, k, v = (a.transpose(0, 2, 1, 3) for a in _project_qkv(cfg, p, h))
+        # [B, S, heads, Dh] -> [B, heads, S, Dh]
+        if cfg.pos == "rotary":
+            qr, kr = _rotary(cfg, rope_tables, positions, q, k)
+            # A layer without positional encoding keeps q and k as projected.
+            q, k = (qr, kr) if kind is None else (
+                jnp.where(kind["rope"], qr, q), jnp.where(kind["rope"], kr, k))
+        attn, state = attend(q, k, v, kind)
     attn_out = jnp.einsum("bhsd,hde->bse", attn, p["w_o"]) + p["b_o"]
     if cfg.sandwich_norm:
         attn_out = _norm(attn_out, p["ln1_post_w"], p["ln1_post_b"], cfg.norm)
@@ -785,6 +1101,8 @@ _LAYER_KEYS = (
     "w_qkv", "b_qkv", "w_q", "w_kv", "w_o", "b_o", "w_in", "b_in", "w_out", "b_out",
     "ln1_w", "ln1_b", "ln2_w", "ln2_b", "w_gate",
     "moe_router", "moe_w_in", "moe_w_out", "moe_w_gate", *_POST_NORM_KEYS,
+    "w_dq", "q_norm_w", "w_uq", "w_dkv", "kv_norm_w", "w_ukv",
+    "shared_w_in", "shared_w_gate", "shared_w_out",
 )
 
 
@@ -798,11 +1116,32 @@ def _embed(params, tokens, positions, cfg: GPTConfig):
 
 
 def _rope_tables(cfg: GPTConfig):
-    """(cos, sin) [max_seq, rotary_dim/2] float32, or None without rotary."""
+    """(cos, sin) [max_seq, rotary_dim/2] float32, or None without rotary.
+    Under `rope_scaling` (YaRN) a dimension that turns fewer than
+    `beta_slow` times over the original positions is interpolated by
+    `factor`, one that turns more than `beta_fast` times is left, a linear
+    ramp between; cos and sin carry mscale(`mscale`) / mscale(`mscale_all_dim`)."""
     if cfg.pos != "rotary":
         return None
-    return rope_frequencies(min(cfg.rotary_dim, cfg.d_head), cfg.max_seq,
-                            theta=cfg.rope_theta, dtype=jnp.float32)
+    if not cfg.rope_scaling:
+        return rope_frequencies(min(cfg.rotary_dim, cfg.d_head), cfg.max_seq,
+                                theta=cfg.rope_theta, dtype=jnp.float32)
+    d, factor = cfg.rotary_dim, _yarn(cfg, "factor")
+
+    def turns_at(turns):        # the dimension that turns so often
+        return d * math.log(_yarn(cfg, "original_max_position_embeddings")
+                            / (turns * 2 * math.pi)) / (2 * math.log(cfg.rope_theta))
+
+    low = max(math.floor(turns_at(_yarn(cfg, "beta_fast"))), 0)
+    high = min(math.ceil(turns_at(_yarn(cfg, "beta_slow"))), d - 1)
+    ramp = np.clip((np.arange(d // 2) - low) / max(high - low, 0.001), 0.0, 1.0)
+    inv = cfg.rope_theta ** (-np.arange(0, d, 2) / d)
+    inv = inv * (1.0 - ramp) + inv / factor * ramp
+    amp = _yarn_mscale(factor, _yarn(cfg, "mscale")) / _yarn_mscale(
+        factor, _yarn(cfg, "mscale_all_dim"))
+    ang = jnp.outer(jnp.arange(cfg.max_seq, dtype=jnp.float32),
+                    jnp.asarray(inv, jnp.float32))
+    return jnp.cos(ang) * amp, jnp.sin(ang) * amp
 
 
 def _layer_stack(params):
@@ -849,15 +1188,17 @@ def _layer_loop(cfg: GPTConfig, mesh, positions):
     or `_attention_plain` where heads are grouped or layers have kinds. A
     looped model (`forward` alone) hands `close_pass` too, x -> x: the scan
     then runs `ut_steps` times over the same stack, closed over and not cut
-    a pass, each pass ended by `close_pass`; aux is [passes x L]."""
+    a pass, each pass ended by `close_pass`; aux is [passes x L]. A model
+    with leading dense layers hands their stack as `lead` (`_lead_stack`)."""
 
     def attend(q, k, v, kind):
-        if kind is None and cfg.kv_heads == cfg.n_heads:
+        if kind is None and cfg.kv_heads == cfg.n_heads and not cfg.kv_lora_rank:
             return _attention(cfg, q, k, v, mesh), None
         if cfg.attn_impl != "ref":
             raise NotImplementedError(
-                "grouped-query heads and per-layer windows run attn_impl='ref' "
-                f"in forward (got {cfg.attn_impl!r}); the kernels take neither")
+                "grouped-query heads, per-layer windows and latent attention run "
+                f"attn_impl='ref' in forward (got {cfg.attn_impl!r}); the kernels "
+                "take none of them")
         window = None if kind is None else kind["window"]
         return _attention_plain(cfg, q, k, v, positions, window), None
 
@@ -870,7 +1211,15 @@ def _layer_loop(cfg: GPTConfig, mesh, positions):
         x, _, aux, _ = block(x, layer_params, positions, kind=kind)
         return x, aux
 
-    def run(x, layer_stack, kinds=None, close_pass=None):
+    def run(x, layer_stack, kinds=None, close_pass=None, lead=None):
+        if cfg.dense_layers:    # the leading dense layers, the same block
+            lead_block = functools.partial(
+                _block, _lead_cfg(cfg), _rope_tables(cfg), attend)
+
+            def lead_body(x, layer_params):
+                return lead_block(x, layer_params, positions)[0], None
+
+            x, _ = jax.lax.scan(lead_body, x, lead)
         if cfg.ut_steps == 1:
             return jax.lax.scan(scan_body, x, (layer_stack, kinds))
 
@@ -910,7 +1259,7 @@ def forward(params, tokens, cfg: GPTConfig, positions=None, mesh=None, return_au
     x = _embed(params, tokens, positions, cfg)
     x, aux_stack = _layer_loop(cfg, mesh, positions)(
         x, _layer_stack(params), _layer_kind_xs(cfg),
-        lambda x: _close_pass(params, x, cfg)[0])
+        lambda x: _close_pass(params, x, cfg)[0], _lead_stack(params))
     logits = _logits(params, x, cfg)
     if return_aux:
         return logits, aux_stack.sum()
@@ -961,12 +1310,24 @@ def _ce_loss(logits, targets, mask):
 def _refuse_looped_training(cfg: GPTConfig, what: str):
     """A looped model's published objective is an expected loss over its
     exit distribution with an entropy term; plain next-token cross-entropy
-    of the last pass is another objective, so training refuses the model."""
+    of the last pass is another objective, so training refuses the model.
+    So it refuses what only the serving programs were written for: latent
+    attention, leading dense layers, a shared expert, a held range of
+    experts (no gradient is exchanged for the absent ones), sigmoid scoring
+    (its balance term is not written)."""
     if cfg.ut_steps > 1:
         raise NotImplementedError(
             f"{what} does not train a model whose layers run several times "
             f"(ut_steps={cfg.ut_steps}): its objective, an expected loss over "
             "the exit distribution, is not implemented")
+    served = [name for name, on in (
+        ("kv_lora_rank", cfg.kv_lora_rank), ("dense_layers", cfg.dense_layers),
+        ("moe_shared", cfg.moe_shared), ("moe_held", cfg.moe_held),
+        ("moe_scoring", cfg.moe_scoring != "softmax")) if on]
+    if served:
+        raise NotImplementedError(
+            f"{what} does not train a model with {', '.join(served)}: "
+            "these are served (forward with attn_impl='ref', the paged programs)")
 
 
 def loss_fn(params, batch, cfg: GPTConfig, mesh=None):
@@ -1466,30 +1827,59 @@ class KVLayout:
     passes x per_group CACHE layers, pass t of layer l at pool[t * per_group
     + slot_of[l]]; block tables, groups and windows are the layers' own. A
     block is `depth` rows deep, so whoever reckons a block's bytes or takes
-    the pool's depth takes it from here, not from `n_layers`."""
+    the pool's depth takes it from here, not from `n_layers`.
+
+    What a layer keeps a token is declared here too: a key row `key_row`
+    wide and a value row `value_row` wide, the pool's arrays "k" and "v". A
+    multi-head or grouped-query layer keeps kv_heads x d_head of each. A
+    LATENT layer (`kv_lora_rank`) keeps ONE row: the normed latent beside
+    the rotated key features every head shares, kv_lora_rank + rotary_dim
+    numbers, padded with zeros to a whole number of 128-column tiles (576 ->
+    640), and `value_row` is 0: its values are the key row's first
+    kv_lora_rank columns, and the pool has no "v". Why 640 in one array, not
+    576 and not two arrays (512 and 64): the compile-only rehearsal for the
+    v5e (`scripts/paged_rehearse.py`, PR 34) kept a pool of 576-wide rows
+    block-index-minor and copied all of it into and out of that layout in
+    every program (two pool-sized `copy` operations, 2 GiB each: what PR 25
+    removed for K/V rows, which tile exactly when their width is a multiple
+    of 128); a second array of 64 would half-fill a tile and meet the same
+    copy; rows of 640 stay row-major and are updated in place. The price is
+    a ninth more depth in the score product (the zeros add nothing to a
+    score) and 1,280 B a token a layer for 1,152. A model with leading dense
+    layers keeps their rows first: depth = n_layers, the scanned stack's
+    layer i at pool[dense_layers + i]."""
 
     per_group: int                  # layers in a group
     windows: Tuple[int, ...]        # per group: 0 = keeps every token, else the window
     group_of: Tuple[int, ...]       # [L] the layer's group
     slot_of: Tuple[int, ...]        # [L] the layer's index inside a pass's rows
     passes: int = 1                 # rows a layer keeps: one a pass
+    key_row: int = 0                # width of the row in pool "k"
+    value_row: int = 0              # width of the row in pool "v"; 0: no such pool
 
     @property
     def depth(self) -> int:
         """Cache layers = the pool's leading dim."""
         return self.passes * self.per_group
 
+    def block_bytes(self, block_size: int, itemsize: int) -> int:
+        """Bytes of one block as declared: `depth` cache layers x block_size
+        tokens x the rows' widths (what the device pads is not counted)."""
+        return self.depth * block_size * (self.key_row + self.value_row) * itemsize
+
 
 @functools.lru_cache(maxsize=None)
 def kv_layout(cfg: GPTConfig) -> KVLayout:
     L = cfg.n_layers
     kinds = cfg.layer_kinds
+    rows = ((-(-(cfg.kv_lora_rank + cfg.rotary_dim) // 128) * 128, 0)
+            if cfg.kv_lora_rank else (cfg.kv_heads * cfg.d_head,) * 2)
     win = kinds[1] if kinds is not None else (0,) * L
     glob = [l for l in range(L) if not win[l]]
     wind = [l for l in range(L) if win[l]]
     if not glob or not wind:
         return KVLayout(L, (cfg.sliding_window if wind else 0,), (0,) * L,
-                        tuple(range(L)), cfg.ut_steps)
+                        tuple(range(L)), cfg.ut_steps, *rows)
     per = math.gcd(len(glob), len(wind))
     group_of, slot_of, windows = [0] * L, [0] * L, []
     for layers, w in ((glob, 0), (wind, cfg.sliding_window)):
@@ -1498,15 +1888,20 @@ def kv_layout(cfg: GPTConfig) -> KVLayout:
             slot_of[l] = i % per
         windows += [w] * (len(layers) // per)
     return KVLayout(per, tuple(windows), tuple(group_of), tuple(slot_of),
-                    cfg.ut_steps)
+                    cfg.ut_steps, *rows)
 
 
 def init_paged_cache(cfg: GPTConfig, num_blocks: int, block_size: int):
     """Physical paged KV pool: {"k","v"} of [depth, NB, BS, Hkv*Dh] in
-    cfg.dtype (`kv_layout`; depth = L for a one-pass model of one kind)."""
-    shape = (kv_layout(cfg).depth, num_blocks, block_size,
-             cfg.kv_heads * cfg.d_head)
-    return {"k": jnp.zeros(shape, cfg.dtype), "v": jnp.zeros(shape, cfg.dtype)}
+    cfg.dtype (`kv_layout`; depth = L for a one-pass model of one kind); for
+    a latent model {"k"} alone, [depth, NB, BS, the latent row padded to
+    whole tiles]: the rows the layout declares."""
+    lay = kv_layout(cfg)
+    shape = (lay.depth, num_blocks, block_size)
+    pool = {"k": jnp.zeros(shape + (lay.key_row,), cfg.dtype)}
+    if lay.value_row:
+        pool["v"] = jnp.zeros(shape + (lay.value_row,), cfg.dtype)
+    return pool
 
 
 # Keys one trip of the paged key loop covers (`_paged_layers`). A table of
@@ -1587,9 +1982,17 @@ def _paged_layers(params, tokens, pos, valid, block_tables, kv, cfg: GPTConfig):
     and writing the pool rows of its own (pass, layer) pair (`kv_layout`),
     every pass closed by the final norm and the exit gate (`_close_pass`).
 
+    A latent model (`kv_lora_rank`) is, to the code below, ONE K/V head
+    shared by all H query heads folded into the query axis, whose key row
+    (`lay.key_row` wide) also holds its value row as its first
+    `kv_lora_rank` columns: `_block` hands the absorbed operands, the pool
+    is "k" alone. Leading dense layers run before the scan through the
+    same `_block` and `attend`, their rows first in the pool.
+
     Returns (hidden states [B, S, E] before the final norm -- after it for
     a looped model, kv, None or the mean over layers of (experts touched,
-    busiest expert's share) [2] f32, None or a looped model's exit
+    busiest expert's share) [2] f32 ([4] under `moe_held`: `_dropless_mlp`),
+    None or a looped model's exit
     distribution [passes run] f32: `ut_exit_pdf` of the gates of the passes
     the pass scan ran, one entry a pass, mean over the real tokens)."""
     moe = cfg.mlp_type == "moe"
@@ -1608,8 +2011,11 @@ def _paged_layers(params, tokens, pos, valid, block_tables, kv, cfg: GPTConfig):
     W = block_tables.shape[-1]
     BS = kv["k"].shape[2]
     H, Hkv, Dh = cfg.n_heads, cfg.kv_heads, cfg.d_head
-    R = H // Hkv
+    Dv = Dh                                            # a value row's width a head
     scale = 1.0 / math.sqrt(Dh)
+    if cfg.kv_lora_rank:    # ONE row a token, every head's key, its values inside
+        Hkv, Dh, Dv, scale = 1, lay.key_row, cfg.kv_lora_rank, _latent_scale(cfg)
+    R = H // Hkv
     x = _embed(params, tokens, pos, cfg)               # [B, S, E]
     rope_tables = _rope_tables(cfg)
     blk = jnp.minimum(pos // BS, W - 1)
@@ -1654,10 +2060,11 @@ def _paged_layers(params, tokens, pos, valid, block_tables, kv, cfg: GPTConfig):
     def scores_of(q, kk, vv, slot, blocks, kp, seen, window):
         """Masked float32 scores [B, Hkv, R*S, n*BS] of q [B, Hkv, R*S, Dh]
         against the rows of `blocks` [B, n] (key positions `kp`), and those
-        blocks' V rows [B, n*BS, Hkv, Dh] ([B, n*BS, Hkv*Dh] for `lone`)."""
+        blocks' V rows [B, n*BS, Hkv, Dv] ([B, n*BS, Hkv*Dh] for `lone`): of
+        a latent pool (`vv` None) the gathered key rows' first Dv columns."""
         rows = (B, -1, Hkv * Dh) if lone else (B, -1, Hkv, Dh)
         gk = kk[slot, blocks].reshape(rows)
-        gv = vv[slot, blocks].reshape(rows)
+        gv = gk[..., :Dv] if vv is None else vv[slot, blocks].reshape(rows)
         mask = seen if window is None else seen & (kp > qpos - window)
         if R > 1:   # the R query heads of a K/V head ride its query axis
             mask = jnp.tile(mask, (1, 1, R, 1))
@@ -1712,15 +2119,20 @@ def _paged_layers(params, tokens, pos, valid, block_tables, kv, cfg: GPTConfig):
         rows = q.shape[:3]
         _, l, acc = jax.lax.fori_loop(0, trips, trip, (
             jnp.full(rows, -1e30, jnp.float32), jnp.zeros(rows, jnp.float32),
-            jnp.zeros(q.shape, jnp.float32)))
-        return (acc / l[..., None]).astype(vv.dtype)
+            jnp.zeros(rows + (Dv,), jnp.float32)))
+        return (acc / l[..., None]).astype(kk.dtype)
 
     def attend(kk, vv, l, base, q, k, v, kind):
         """The new rows into the pool (kk, vv) at the layer's slot (past
-        `base`, the first row of a looped model's pass), then attention
-        over the layer's table; the pool is the state."""
+        `base`: the first row of a looped model's pass, or of the scanned
+        stack behind leading dense layers), then attention over the layer's
+        table; the pool is the state. A latent pool is `kk` alone, its row
+        and the queries padded with zeros to the declared width."""
+        if q.shape[-1] < Dh:
+            q, k = (jnp.pad(a, ((0, 0),) * 3 + ((0, Dh - a.shape[-1]),)) for a in (q, k))
         k = k.transpose(0, 2, 1, 3).reshape(B, S, Hkv * Dh)
-        v = v.transpose(0, 2, 1, 3).reshape(B, S, Hkv * Dh)
+        if vv is not None:
+            v = v.transpose(0, 2, 1, 3).reshape(B, S, Hkv * Dh)
         if G == 1:
             slot, table, ph = l, block_tables, phys
         else:
@@ -1730,18 +2142,19 @@ def _paged_layers(params, tokens, pos, valid, block_tables, kv, cfg: GPTConfig):
         if base is not None:
             slot = base + slot
         kk = kk.at[slot, ph, off].set(k.astype(kk.dtype))
-        vv = vv.at[slot, ph, off].set(v.astype(vv.dtype))
+        if vv is not None:
+            vv = vv.at[slot, ph, off].set(v.astype(vv.dtype))
         if R > 1:   # the R query heads of a K/V head ride its query axis
             q = q.reshape(B, Hkv, R * S, Dh)
         attn = gathered(q, kk, vv, slot, table,
                         None if kind is None else kind["window"])
-        return attn.reshape(B, H, S, Dh) if R > 1 else attn, (kk, vv)
+        return attn.reshape(B, H, S, Dv) if R > 1 else attn, (kk, vv)
 
     # A step of a few tokens reads only the experts they chose: the expert
     # stacks then stay whole (a slice the scan cuts would be copied into the
     # inner loop) and the layer number finds the expert where it lies.
     stacks = None
-    if moe and B * S * cfg.moe_top_k < cfg.moe_experts:
+    if moe and _few_tokens(cfg, B * S):
         stacks = tuple(layer_stack.pop(k) for k in
                        ("moe_w_gate", "moe_w_in", "moe_w_out"))
 
@@ -1756,13 +2169,27 @@ def _paged_layers(params, tokens, pos, valid, block_tables, kv, cfg: GPTConfig):
                 layer_params, pos, kind, stacks, l, real)
             return (x, kk, vv), load
 
-        return jax.lax.scan(
-            scan_body, carry, (jnp.arange(cfg.n_layers), layer_stack, kinds))
+        return jax.lax.scan(scan_body, carry, (
+            jnp.arange(cfg.n_layers - cfg.dense_layers), layer_stack, kinds))
 
-    carry = (x, kv["k"], kv["v"])
+    def pool(kk, vv):
+        return {"k": kk} if vv is None else {"k": kk, "v": vv}
+
+    carry = (x, kv["k"], kv.get("v"))
+    if cfg.dense_layers:    # the leading dense layers: pool rows 0 .. their count
+        def lead_body(carry, inp):
+            x, kk, vv = carry
+            l, layer_params = inp
+            x, (kk, vv), _, _ = _block(
+                _lead_cfg(cfg), rope_tables, functools.partial(attend, kk, vv, l, None),
+                x, layer_params, pos, valid=real)
+            return (x, kk, vv), None
+
+        carry, _ = jax.lax.scan(lead_body, carry, (
+            jnp.arange(cfg.dense_layers), _lead_stack(params)))
     if cfg.ut_steps == 1:
-        (x, kk, vv), loads = layers(carry)
-        return x, {"k": kk, "v": vv}, (loads.mean(axis=0) if moe else None), None
+        (x, kk, vv), loads = layers(carry, cfg.dense_layers or None)
+        return x, pool(kk, vv), (loads.mean(axis=0) if moe else None), None
 
     def one_pass(carry, t):
         (x, kk, vv), loads = layers(carry, t * lay.per_group)
@@ -1773,7 +2200,7 @@ def _paged_layers(params, tokens, pos, valid, block_tables, kv, cfg: GPTConfig):
         one_pass, carry, jnp.arange(cfg.ut_steps))
     pdf = jnp.where(real, ut_exit_pdf(lams), 0.0)      # [passes run, B, S]
     exits = pdf.sum(axis=(1, 2)) / jnp.maximum(real.sum(), 1)
-    return x, {"k": kk, "v": vv}, (loads.mean(axis=(0, 1)) if moe else None), exits
+    return x, pool(kk, vv), (loads.mean(axis=(0, 1)) if moe else None), exits
 
 
 def prefill_paged(params, tokens, real_len, pos_offset, block_table, kv,

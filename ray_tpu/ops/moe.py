@@ -164,11 +164,19 @@ MOE_LOGICAL_DIMS = {
 # loop over the experts that were chosen reads only those experts' weights.
 
 
-def dropless_route(logits, top_k: int):
+def dropless_route(logits, top_k: int, scoring: str = "softmax", scale: float = 1.0):
     """logits [N, X] (any float) -> (idx [N, k] int32, weights [N, k] f32):
-    the k largest logits of each token and the softmax over those k."""
+    the k largest logits of each token and, as `scoring` says, the softmax
+    over those k ("softmax") or their sigmoids over the sum of those k, times
+    `scale` ("sigmoid": the sigmoid keeps the logits' order, so the k largest
+    scores are the k largest logits)."""
     vals, idx = jax.lax.top_k(logits.astype(jnp.float32), top_k)
-    return idx.astype(jnp.int32), jax.nn.softmax(vals, axis=-1)
+    if scoring == "softmax":
+        return idx.astype(jnp.int32), jax.nn.softmax(vals, axis=-1)
+    if scoring != "sigmoid":
+        raise ValueError(f"dropless scoring {scoring!r}: softmax | sigmoid")
+    kept = jax.nn.sigmoid(vals)
+    return idx.astype(jnp.int32), kept / kept.sum(axis=-1, keepdims=True) * scale
 
 
 def dropless_combine(idx, weights, num_experts: int):
@@ -244,12 +252,18 @@ def dropless_experts(x, combine, w_gate, w_in, w_out, activation: str,
     return y.astype(dt)
 
 
-def dropless_load(combine, valid=None):
+def dropless_load(combine, valid=None, top_k: int = 0):
     """(experts with at least one token, the busiest expert's share of the
     assignments) of one layer's routing, f32 scalars; `valid` [N] leaves
-    padding tokens out."""
+    padding tokens out. `combine` cut to the columns of the experts held
+    here, with `top_k` given: also (the assignments that fell on those
+    columns, all the tokens' assignments = tokens x top_k)."""
     chosen = combine > 0
     if valid is not None:
         chosen = chosen & valid[:, None]
     per = chosen.sum(axis=0).astype(jnp.float32)         # [X] assignments
-    return (per > 0).sum().astype(jnp.float32), per.max() / jnp.maximum(per.sum(), 1.0)
+    load = ((per > 0).sum().astype(jnp.float32), per.max() / jnp.maximum(per.sum(), 1.0))
+    if not top_k:
+        return load
+    tokens = combine.shape[0] if valid is None else valid.sum()
+    return (*load, per.sum(), jnp.asarray(tokens * top_k, jnp.float32))

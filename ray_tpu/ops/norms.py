@@ -31,6 +31,11 @@ def _rmsnorm_pallas(x2d, w, eps, block_rows=256):
     from jax.experimental import pallas as pl
 
     N, D = x2d.shape
+    # A block's float32 copy stays within 4 MiB of VMEM (the kernel holds
+    # two of them beside the double-buffered input and output blocks): 256
+    # rows up to 4,096 columns, 128 rows at 7,168.
+    while block_rows > 8 and block_rows * D * 4 > 4 << 20:
+        block_rows //= 2
     block_rows = min(block_rows, N)
     return pl.pallas_call(
         functools.partial(_rmsnorm_kernel, eps=eps),

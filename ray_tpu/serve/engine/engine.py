@@ -258,19 +258,25 @@ class InferenceEngine:
             )
         # One block table a KV group (`models/gpt.py` kv_layout). The host
         # tier, the disaggregated roles and block export/import move blocks
-        # of ONE row shape and one table: a model whose layers form several
-        # groups is refused here, not served wrongly (ROADMAP D9).
+        # of ONE row shape (a K row and a V row) and one table: a model
+        # whose layers form several groups, or whose layers keep one latent
+        # row and no V (`KVLayout.value_row` 0), is refused here, not served
+        # wrongly (ROADMAP D9).
         self._layout = kv_layout(self.cfg)
         self._groups = len(self._layout.windows)
-        if self._groups > 1:
+        self._unmoved = (
+            f"a model of {self._groups} KV groups" if self._groups > 1
+            else "a model whose layers cache one latent row (no V rows)"
+            if not self._layout.value_row else None)
+        if self._unmoved:
             if self.opts.host_kv_bytes > 0 and self.opts.enable_prefix_caching:
                 raise ValueError(
-                    f"a model of {self._groups} KV groups runs with the host "
-                    "KV tier off (host_kv_bytes=0)")
+                    f"{self._unmoved} runs with the host KV tier off "
+                    "(host_kv_bytes=0)")
             if self.opts.role != "mixed":
                 raise ValueError(
-                    f"a model of {self._groups} KV groups serves role='mixed' "
-                    "only: the prefill/decode hand-off exports blocks of one group")
+                    f"{self._unmoved} serves role='mixed' only: the "
+                    "prefill/decode hand-off exports K and V blocks of one group")
         self._jnp = jax.numpy
         self._jax = jax
         # Where the kernels run, as JAX reports it — benches and the chip
@@ -283,6 +289,9 @@ class InferenceEngine:
         self.kv = init_paged_cache(
             self.cfg, self.opts.num_blocks, self.opts.block_size
         )
+        # One block's bytes, from what the layout declares a layer keeps.
+        self.kv_block_bytes = self._layout.block_bytes(
+            self.opts.block_size, jax.numpy.dtype(self.cfg.dtype).itemsize)
         self.host_tier = None
         if self.opts.host_kv_bytes > 0 and self.opts.enable_prefix_caching:
             from .kv_tier import HostKVTier
@@ -370,8 +379,11 @@ class InferenceEngine:
         self.total_attn_keys = [0, 0]
         self._step_attn = [0, 0]
         # Expert routing: (experts touched, busiest expert's share) of the
-        # decode step whose ids this step read, which came back with them.
+        # decode step whose ids this step read, which came back with them;
+        # for a program that holds a range of the experts also [assignments
+        # that fell on held experts, all], summed over layers and steps.
         self._step_moe = None
+        self.total_moe_assign = [0, 0]
         # Passes of the layer stack over the decode steps' real lanes: [run,
         # from the length of what came back; what `ut_steps` would be].
         self.total_ut_passes = [0, 0]
@@ -755,11 +767,15 @@ class InferenceEngine:
         another order, so it must not be adopted). The depth is the pool's
         (`kv_layout`: cache layers, passes x layers for a looped model)
         and the passes are named, so that a one-pass engine and a looped
-        one of equal widths never adopt each other's blocks."""
-        c = self.cfg
+        one of equal widths never adopt each other's blocks. The row kind
+        is the layout's: "rows" for a K row and a V row a token, "latent<n>"
+        for one latent row of n and no V, so a latent engine and a K/V
+        engine refuse each other's blocks whatever their widths."""
+        c, lay = self.cfg, self._layout
+        kind = "rows" if lay.value_row else f"latent{lay.key_row}"
         return (
-            f"{self._layout.depth}/{self._layout.passes}:{c.kv_heads}:{c.d_head}:"
-            f"{self.opts.block_size}:{self._jnp.dtype(c.dtype).str}:rows"
+            f"{lay.depth}/{lay.passes}:{c.kv_heads}:{c.d_head}:"
+            f"{self.opts.block_size}:{self._jnp.dtype(c.dtype).str}:{kind}"
         )
 
     def prompt_digests(self, prompt: List[int]) -> List[bytes]:
@@ -786,7 +802,7 @@ class InferenceEngine:
         donated KV arrays); this caller blocks until serviced. Returns None
         when there is nothing exportable (short prompt, blocks already
         evicted everywhere, engine stopped)."""
-        self._one_group("export_prompt_kv")
+        self._refuse_unmoved("export_prompt_kv")
         digests = self.prompt_digests(prompt)
         if not digests or self._stop.is_set():
             return None
@@ -870,7 +886,7 @@ class InferenceEngine:
         each block as a cached entry whose bytes the driver thread lands
         before its next kernel. Returns the number adopted; 0 means the
         importer simply recomputes (degraded mode is the pre-disagg path)."""
-        self._one_group("import_blocks")
+        self._refuse_unmoved("import_blocks")
         if not desc or not self.opts.enable_prefix_caching \
                 or self._stop.is_set():
             return 0
@@ -922,11 +938,10 @@ class InferenceEngine:
                 n += 1
         return _span(n, len(needed))
 
-    def _one_group(self, what: str):
-        if self._groups > 1:
+    def _refuse_unmoved(self, what: str):
+        if self._unmoved:
             raise NotImplementedError(
-                f"{what}: blocks of a model of {self._groups} KV groups are "
-                "not exported or imported")
+                f"{what}: blocks of {self._unmoved} are not exported or imported")
 
     def _tables_into(self, arr, seq: Sequence):
         """A sequence's block table(s) into a zeroed [W] / [G, W] row."""
@@ -1071,6 +1086,12 @@ class InferenceEngine:
             self._step_moe = facts.pop(0)
             attrs["experts_touched"] = float(self._step_moe[0])
             attrs["expert_load_max"] = float(self._step_moe[1])
+            if self.cfg.moe_held:   # means over the expert layers -> their sums
+                layers = self.cfg.n_layers - self.cfg.dense_layers
+                held, total = (int(round(float(v) * layers)) for v in self._step_moe[2:])
+                attrs["assign_held"], attrs["assign_total"] = held, total
+                self.total_moe_assign[0] += held
+                self.total_moe_assign[1] += total
         if self.cfg.ut_steps > 1:
             pdf = facts.pop(0).tolist()     # one entry a pass the program ran
             self.total_ut_passes[0] += lanes * len(pdf)
@@ -1366,6 +1387,8 @@ class InferenceEngine:
             "attn_keys_padded": self.total_attn_keys[1],
             "ut_passes_run": self.total_ut_passes[0],
             "ut_passes_full": self.total_ut_passes[1],
+            "moe_assign_held": self.total_moe_assign[0],
+            "moe_assign_total": self.total_moe_assign[1],
             "decode_dispatched": self.total_decode_dispatched,
             "decode_chained": self.total_decode_chained,
             "total_tokens": self.total_tokens,

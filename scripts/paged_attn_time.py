@@ -17,7 +17,15 @@ shape once): how the tile of `_paged_layers`' key loop was chosen (PERF.md
 engine dispatches (`serve/engine/engine.py: _paged_jits`: the same function
 with the sampler behind it, ids for logits, the last ids carried beside the
 pool), on the same input ids (an expert model routes by them): what the
-epilogue costs a shape (PERF.md §6, PR 33).
+epilogue costs a shape (PERF.md §6, PR 33). `--ids distinct` gives decode
+lane b the id 7 + b instead of 7 for all (an expert model's step reads the
+experts its lanes choose). `--latent-forms 64,256` (a latent model) times ONE
+layer's attention of one chunk over a table of W blocks, outside any program,
+in the two forms the mathematics allows: ABSORBED (what `_paged_layers` runs:
+the key up-projection on the query, every head over the one cached row) and
+EXPANDED (each tile's rows widened to per-head keys and values first), the
+same gathers, tiles, mask and online softmax (PERF.md §6, PR 34: the next
+`perf_opt`'s starting point, not a path of the program).
 
 Milliseconds a call, mean over `--reps` calls dispatched back to back and
 waited for once. A chip run or nothing: on the CPU (`--rehearse`, the
@@ -39,6 +47,8 @@ def main(argv=None) -> int:
     ap.add_argument("--decode", default="")
     ap.add_argument("--tile-keys", default="")
     ap.add_argument("--sampled", action="store_true")
+    ap.add_argument("--ids", choices=("same", "distinct"), default="same")
+    ap.add_argument("--latent-forms", default="")
     ap.add_argument("--reps", type=int, default=10)
     ap.add_argument("--rehearse", action="store_true")
     a = ap.parse_args(argv)
@@ -138,17 +148,25 @@ def main(argv=None) -> int:
             shape = (B, G, W) if G > 1 else (B, W)
             tables = np.zeros(shape, np.int32)
             tables[:len(pos)] = table(W)    # lanes share blocks: reads only matter
-            args = (jnp.full((B,), 7, jnp.int32), jnp.asarray(positions),
-                    jnp.asarray(tables))
+            ids = 7 + np.arange(B, dtype=np.int32) * (a.ids == "distinct")
+            args = (jnp.asarray(ids), jnp.asarray(positions), jnp.asarray(tables))
             row = {"program": "decode_step_paged", "tile_keys": tile, "lanes": B,
                    "W": W, "keys": W * BS, "positions": pos}
             timed(dict(row), decode, args)
             if a.sampled:     # rows: slot, position, host id, known: the same ids
                 lanes = np.zeros((4, B), np.int32)      # (experts route by them)
                 lanes[0], lanes[1] = np.arange(B) % slots, positions
-                lanes[2], lanes[3] = 7, 1
+                lanes[2], lanes[3] = ids, 1
                 timed({**row, "sampled": True}, sampled_decode,
                       (jnp.asarray(lanes), args[2]))
+    for W in (int(w) for w in a.latent_forms.split(",") if w):
+        for form, ms in latent_forms(cfg, params, kv["k"], table(W), chunk, BS, reps).items():
+            row = {"program": "latent_attention", "form": form, "chunk": chunk,
+                   "W": W, "keys": W * BS}
+            if on_chip:
+                row["ms"] = ms
+            rows.append(row)
+            print(json.dumps(row), flush=True)
     report = {"device": {"platform": dev.platform, "kind": dev.device_kind},
               "config": a.config, "n_layers": cfg.n_layers, "block_size": BS,
               "rows": rows}
@@ -158,6 +176,89 @@ def main(argv=None) -> int:
         json.dump(report, f)
     print(json.dumps(report))
     return 0
+
+
+def latent_forms(cfg, params, pool, table, chunk, BS, reps):
+    """{form: ms a call} of one layer's latent attention, `chunk` queries at
+    the END of a table of W blocks (every key seen by the last query), in
+    tiles of `_ATTN_TILE_KEYS` keys with an online softmax. Queries and
+    weights are the model's shapes with layer 0's up-projections."""
+    import jax
+    import jax.numpy as jnp
+
+    from ray_tpu.models import gpt
+
+    H, R, Dn, Dr = cfg.n_heads, cfg.kv_lora_rank, cfg.d_head, cfg.rotary_dim
+    T = min(gpt._ATTN_TILE_KEYS, len(table) * BS)
+    tiles = len(table) * BS // T
+    table = jnp.asarray(table).reshape(tiles, T // BS)
+    key = jax.random.PRNGKey(1)
+    q = jax.random.normal(key, (H, chunk, Dn + Dr), cfg.dtype)
+    w_ukv = params["w_ukv"][0]                              # [R, H, Dn + Dn]
+    qpos = (len(table.reshape(-1)) * BS - chunk + jnp.arange(chunk))[None, :, None]
+    scale = gpt._latent_scale(cfg)
+
+    def online(pool, scores_and_values):
+        def trip(j, carry):
+            m, l, acc = carry
+            rows = pool[0, table[j]].reshape(T, -1)         # [T, 640]
+            kp = (j * T + jnp.arange(T))[None, None, :]
+            scores, mix = scores_and_values(rows)
+            scores = jnp.where(kp <= qpos, scores * scale, -1e30)
+            m_new = jnp.maximum(m, scores.max(-1))
+            p = jnp.exp(scores - m_new[..., None])
+            fade = jnp.exp(m - m_new)
+            return m_new, l * fade + p.sum(-1), acc * fade[..., None] + mix(p)
+        return trip
+
+    def run(trip, width):
+        m, l, acc = jax.lax.fori_loop(0, tiles, trip, (
+            jnp.full((H, chunk), -1e30, jnp.float32), jnp.zeros((H, chunk), jnp.float32),
+            jnp.zeros((H, chunk, width), jnp.float32)))
+        return (acc / l[..., None]).astype(cfg.dtype)
+
+    @jax.jit
+    def absorbed(q, pool, w_ukv):
+        w_uk, w_uv = w_ukv[..., :Dn], w_ukv[..., Dn:]
+        qa = jnp.concatenate([jnp.einsum("hsd,rhd->hsr", q[..., :Dn], w_uk),
+                              q[..., Dn:]], -1)             # [H, S, R + Dr]
+
+        def sv(rows):
+            scores = jnp.einsum("hsd,td->hst", qa, rows[:, :R + Dr],
+                                preferred_element_type=jnp.float32)
+            return scores, lambda p: jnp.einsum(
+                "hst,tr->hsr", p.astype(rows.dtype), rows[:, :R],
+                preferred_element_type=jnp.float32)
+
+        out = run(online(pool, sv), R)
+        return jnp.einsum("hsr,rhd->hsd", out, w_uv)
+
+    @jax.jit
+    def expanded(q, pool, w_ukv):
+        w_uk, w_uv = w_ukv[..., :Dn], w_ukv[..., Dn:]
+
+        def sv(rows):
+            c = rows[:, :R]
+            k = jnp.einsum("tr,rhd->htd", c, w_uk)          # [H, T, Dn]
+            v = jnp.einsum("tr,rhd->htd", c, w_uv)
+            scores = (jnp.einsum("hsd,htd->hst", q[..., :Dn], k,
+                                 preferred_element_type=jnp.float32)
+                      + jnp.einsum("hsd,td->hst", q[..., Dn:], rows[:, R:R + Dr],
+                                   preferred_element_type=jnp.float32))
+            return scores, lambda p: jnp.einsum(
+                "hst,htd->hsd", p.astype(v.dtype), v, preferred_element_type=jnp.float32)
+
+        return run(online(pool, sv), Dn)
+
+    out = {}
+    for name, fn in (("absorbed", absorbed), ("expanded", expanded)):
+        jax.block_until_ready(fn(q, pool, w_ukv))
+        t = time.perf_counter()
+        for _ in range(reps):
+            y = fn(q, pool, w_ukv)
+        jax.block_until_ready(y)
+        out[name] = 1e3 * (time.perf_counter() - t) / reps
+    return out
 
 
 if __name__ == "__main__":

@@ -1,8 +1,9 @@
 """Compile-only rehearsal of the three paged programs, without the chip:
 `JAX_PLATFORMS=cpu python3 -m scripts.paged_rehearse --model gpt2-large
 --num-blocks 1024 --block-size 16 [--lanes 8] [--width 16] [--chunk 64]
-[--spec 4] [--n-layers N]` from the root of a checkout (a model whose
-layers form several KV groups gets one block table a group).
+[--spec 4] [--n-layers N] [--config <benchmark configuration>]` from the
+root of a checkout (a model whose layers form several KV groups gets one
+block table a group; a latent model's pool is one array).
 
 Compiles `decode_step_paged`, `prefill_paged` and `verify_step_paged` of
 `models/gpt.py` for one described (not attached) `v5e:2x2` device, each
@@ -133,7 +134,7 @@ def rehearse(cfg, device, num_blocks: int, block_size: int, lanes: int = 8,
     report = {
         "n_layers": cfg.n_layers,
         "pool_shape": list(kv["k"].shape), "pool_dtype": str(kv["k"].dtype),
-        "pool_GiB": 2 * kv["k"].shape[0] * layer_pool / 2**30,
+        "pool_GiB": len(kv) * kv["k"].shape[0] * layer_pool / 2**30,
         "weights_GiB": sum(a.size * a.dtype.itemsize for a in params.values()) / 2**30,
         "layer_pool_MiB": layer_pool / 2**20,
         "lanes": lanes, "width": width, "chunk": chunk, "spec": spec,
@@ -179,12 +180,23 @@ def main() -> int:
     ap.add_argument("--spec", type=int, default=4, help="draft tokens verified")
     ap.add_argument("--n-layers", type=int, default=None,
                     help="cut the preset's depth (a 6 B model on one chip)")
+    ap.add_argument("--config", default=None,
+                    help="a benchmark configuration (benchmarks/configs/<name>.json): "
+                         "its program model and overrides instead of --model")
     a = ap.parse_args()
     from jax.experimental import topologies
 
     from ray_tpu.models.gpt import CONFIGS
 
-    overrides = {} if a.n_layers is None else {"n_layers": a.n_layers}
+    overrides = {}
+    if a.config:
+        from benchmarks import harness
+
+        config = harness.load_json(harness.ROOT, f"benchmarks/configs/{a.config}.json")
+        arch = harness.arch(config["arch"])
+        a.model, overrides = arch.program(config, arch.dims(config, False))
+    if a.n_layers is not None:
+        overrides["n_layers"] = a.n_layers
     cfg = CONFIGS[a.model](**overrides, remat=False, remat_policy=None)
     topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
     report = rehearse(cfg, topo.devices[0], a.num_blocks, a.block_size,

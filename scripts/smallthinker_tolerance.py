@@ -24,6 +24,7 @@ control flow only."""
 from __future__ import annotations
 
 import argparse
+import dataclasses
 import json
 import sys
 import time
@@ -63,6 +64,9 @@ def readings(config_name, wrongs_of, row_of, argv, doc) -> int:
     ap.add_argument("--parts", default="wrong,float8",
                     help="what to read beside the sound engine's own token error: "
                          "wrong (every wrong reference) or their names, float8")
+    ap.add_argument("--init-gains", default="",
+                    help="name=gain,...: entries of the preset's init_gains to "
+                         "replace (how a preset's gains were chosen; shapes unmoved)")
     a = ap.parse_args(argv)
     parts = set(a.parts.split(","))
     import jax
@@ -80,6 +84,10 @@ def readings(config_name, wrongs_of, row_of, argv, doc) -> int:
     n_prompt, n_new = part["token_check"]["prompt_len"], part["token_check"]["new_tokens"]
     name, overrides = arch.program(config, m)
     cfg = gpt.CONFIGS[name](**overrides)
+    if a.init_gains:
+        gains = {**dict(cfg.init_gains),
+                 **{k: float(v) for k, v in (kv.split("=") for kv in a.init_gains.split(","))}}
+        cfg = dataclasses.replace(cfg, init_gains=tuple(gains.items()))
     init = jax.jit(lambda k: gpt.init_params(k, cfg))
     dev = jax.devices()[0]
     wrongs = wrongs_of(m, opts)

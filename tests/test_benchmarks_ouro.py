@@ -99,7 +99,7 @@ def test_the_cell_and_its_files_are_in_the_benchmark():
 
     resolves()
     bench = harness.benchmark()
-    assert len(bench["workloads"]) == 6 and len(bench["configs"]) == 4
+    assert len(bench["workloads"]) >= 6 and len(bench["configs"]) >= 4
     assert sum(w["chips"] == 4 for w in bench["workloads"]) == 1
     cell = next(w for w in bench["workloads"] if w["name"] == CELL)
     assert cell["chips"] == 1 and cell["traffic"] == "reason-steady"
