@@ -845,9 +845,11 @@ def _dropless_mlp(cfg: GPTConfig, router, experts, router_in, mlp_in,
     combine = moe.dropless_combine(idx, w, cfg.moe_experts)
     if cfg.moe_held:
         combine = combine[:, cfg.moe_held[0]: sum(cfg.moe_held)]
+    form = moe_form(cfg, B * S)
     y = moe.dropless_experts(
-        mlp_in.reshape(B * S, E), combine, *experts, cfg.activation,
-        layer=layer, touched_k=cfg.moe_top_k if _few_tokens(cfg, B * S) else 0)
+        mlp_in.reshape(B * S, E), combine, *experts, cfg.activation, layer=layer,
+        touched_k=cfg.moe_top_k if form == "loop" else 0,
+        grouped_k=cfg.moe_top_k if form == "grouped" else 0)
     load = moe.dropless_load(combine, None if valid is None else valid.reshape(B * S),
                              cfg.moe_top_k if cfg.moe_held else 0)
     return y.reshape(B, S, E), jnp.stack(load)
@@ -860,6 +862,16 @@ def _few_tokens(cfg: GPTConfig, tokens: int) -> bool:
     held / experts, so fewer land here than experts are held exactly when
     tokens x top_k is under the ROUTER's width, whatever the range."""
     return tokens * cfg.moe_top_k < cfg.moe_experts
+
+
+def moe_form(cfg: GPTConfig, tokens: int) -> str:
+    """Which schedule of the one expert sum (`ops/moe.py` `dropless_experts`)
+    a step of `tokens` tokens takes, from shapes alone: "loop" over the
+    chosen experts while `_few_tokens`; above it "grouped", the assignments
+    sorted by expert and only the row tiles they fill computed. The programs
+    take their choice from here and the engine counts with the same call
+    (`moe_tokens_grouped`)."""
+    return "loop" if _few_tokens(cfg, tokens) else "grouped"
 
 
 def _gated_mlp(cfg: GPTConfig, x, w_gate, w_in, w_out):
@@ -2150,11 +2162,12 @@ def _paged_layers(params, tokens, pos, valid, block_tables, kv, cfg: GPTConfig):
                         None if kind is None else kind["window"])
         return attn.reshape(B, H, S, Dv) if R > 1 else attn, (kk, vv)
 
-    # A step of a few tokens reads only the experts they chose: the expert
-    # stacks then stay whole (a slice the scan cuts would be copied into the
-    # inner loop) and the layer number finds the expert where it lies.
+    # A step reads only the experts its tokens chose (a loop over them, or
+    # the grouped tiles: `moe_form`): the expert stacks stay whole (a slice
+    # the scan cuts would be copied into the inner loop) and the layer number
+    # finds the expert where it lies.
     stacks = None
-    if moe and _few_tokens(cfg, B * S):
+    if moe:
         stacks = tuple(layer_stack.pop(k) for k in
                        ("moe_w_gate", "moe_w_in", "moe_w_out"))
 

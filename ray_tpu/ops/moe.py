@@ -14,6 +14,7 @@ standard load-balancing auxiliary loss.
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Dict, Tuple
 
 import jax
@@ -157,11 +158,14 @@ MOE_LOGICAL_DIMS = {
 # routing for any k with NO capacity, so no token is ever dropped, and gate
 # weights that are a softmax over the k kept logits (the same as a softmax
 # over all experts renormalised over the kept ones). Static shapes without a
-# capacity mean the experts are applied densely and masked: every expert
-# sees every token, and the [N, X] combine matrix is zero where a token did
-# not choose the expert. `touched_k` adds the small-batch form a decode
-# step wants: when the step's N*k assignments cannot reach every expert, a
-# loop over the experts that were chosen reads only those experts' weights.
+# capacity leave three schedules of the same sum (`dropless_experts`): every
+# expert applied to every token under an [N, X] combine matrix that is zero
+# where a token did not choose the expert (dense); a loop over the experts
+# that were chosen, for a decode step whose N*k assignments cannot reach
+# every expert (`touched_k`); and, for a step of many tokens, the assignments
+# sorted by expert and each expert's rows, padded to whole row tiles, put
+# through that expert once (`grouped`): as many tiles as the step's routing
+# fills, a run-time count.
 
 
 def dropless_route(logits, top_k: int, scoring: str = "softmax", scale: float = 1.0):
@@ -194,34 +198,297 @@ def _gated(act: str, g, u):
     raise ValueError(f"dropless experts are gated (reglu | swiglu), got {act!r}")
 
 
+# Rows a tile of the grouped form: the MXU's own height. An expert with one
+# token still costs a whole tile, whose time is the expert's bytes, not its
+# rows (set on the chip, PERF.md §6, PR 35); a constant, not a config field.
+GROUP_ROWS = 128
+
+
+def dropless_groups(combine, top_k: int, rows_tile: int):
+    """The grouped form's layout, plain JAX, from combine [N, X] whose rows
+    have at most `top_k` nonzero columns: expert e's rows are the tokens that
+    chose it, in their order, and its first row starts a whole tile of
+    `rows_tile`. Returns
+
+    * rows [N, X] int32: token n's row in expert e's group, -1 where it did
+      not choose e;
+    * tile_expert [T] int32: the expert whose weights tile t meets;
+    * tile_first [T] int32: the row of that expert's group tile t starts at;
+    * tiles, int32 scalar: the tiles the routing fills, the first ones.
+
+    Tile t holds the tokens with rows[:, tile_expert[t]] in tile_first[t] ..
+    + rows_tile - 1. T = N * min(top_k, X) // rows_tile + X bounds every
+    routing: a shape. What is computed is `tiles`, a run-time value."""
+    N, X = combine.shape
+    T = N * min(top_k, X) // rows_tile + X
+    chosen = combine > 0
+    count = jnp.cumsum(chosen, axis=0, dtype=jnp.int32)          # [N, X]
+    tiles_of = -(-count[-1] // rows_tile)                        # [X]
+    tile_end = jnp.cumsum(tiles_of)
+    t = jnp.arange(T, dtype=jnp.int32)
+    past = t[:, None] >= tile_end[None, :]                       # [T, X] experts done
+    tile_first = (t - (past * tiles_of[None, :]).sum(axis=1)) * rows_tile
+    return (jnp.where(chosen, count - 1, -1),
+            jnp.minimum(past.sum(axis=1), X - 1).astype(jnp.int32),
+            tile_first.astype(jnp.int32), tile_end[-1].astype(jnp.int32))
+
+
+def _one(a, layer, e):
+    """Expert e's slice of a stacked weight, read where it lies."""
+    if layer is None:
+        return jax.lax.dynamic_index_in_dim(a, e, 0, keepdims=False)
+    return jax.lax.dynamic_slice(a, (layer, e, 0, 0), (1, 1) + a.shape[2:])[0, 0]
+
+
+def _grouped_plain(x, combine, w_gate, w_in, w_out, activation, layer,
+                   rows, tile_expert, tile_first, tiles, rows_tile):
+    """y [N, D] f32 of the grouped form from its layout (`dropless_groups`):
+    the rows of tile t picked out of the tokens, through expert
+    tile_expert[t], each weighted by its token's combine weight and added to
+    that token, for the first `tiles` tiles; a trip past them is skipped. A
+    `lax` loop that differentiates: the path off the TPU, the kernels'
+    backward pass, and what they are held to."""
+    dt = x.dtype
+
+    def run(t, y):
+        e = tile_expert[t]
+        mine = jax.lax.dynamic_index_in_dim(rows, e, 1, keepdims=False) - tile_first[t]
+        pick = mine[None, :] == jnp.arange(rows_tile)[:, None]           # [TM, N]
+        tile = jnp.dot(pick.astype(dt), x)            # one 1 a row: exact
+        g = jnp.dot(tile, _one(w_gate, layer, e).astype(dt),
+                    preferred_element_type=jnp.float32)
+        u = jnp.dot(tile, _one(w_in, layer, e).astype(dt),
+                    preferred_element_type=jnp.float32)
+        o = jnp.dot(_gated(activation, g, u).astype(dt),
+                    _one(w_out, layer, e).astype(dt),
+                    preferred_element_type=jnp.float32)
+        w = jax.lax.dynamic_index_in_dim(combine, e, 1, keepdims=False)  # [N]
+        return y + jnp.dot(pick.T.astype(jnp.float32), o,
+                           precision=jax.lax.Precision.HIGHEST) * w[:, None]
+
+    def body(t, y):
+        return jax.lax.cond(t < tiles, run, lambda t, y: y, t, y)
+
+    return jax.lax.fori_loop(0, tile_expert.shape[0], body,
+                             jnp.zeros(x.shape, jnp.float32))
+
+
+# Bytes of one weight block a grid step of the grouped kernels fetches (two of
+# them for gate and up, each double-buffered); 4 MiB read no faster on the chip
+# (PERF.md §6, PR 35).
+_BLOCK_BYTES = 2 << 20
+
+
+def _weight_tile(rows: int, cols: int, itemsize: int) -> int:
+    """The most columns (a multiple of 128 that divides `cols`, or all of
+    them) of a [rows, cols] weight whose block stays within `_BLOCK_BYTES`."""
+    if cols % 128:
+        return cols
+    best = 128
+    for n in range(1, cols // 128 + 1):
+        if (cols // 128) % n == 0 and rows * n * 128 * itemsize <= _BLOCK_BYTES:
+            best = n * 128
+    return best
+
+
+# Tokens one call of the kernels keeps in fast memory (the step's tokens as
+# slabs of the model width, and a block of their float32 sums): a prefill
+# chunk of the serving cells; a longer step goes through in such pieces.
+_GROUP_TOKENS = 512
+
+
+def _grouped_pallas(x, combine, w_gate, w_in, w_out, activation, layer,
+                    rows, tile_expert, tile_first, tiles, rows_tile, interpret=False):
+    """`_grouped_plain` as two Pallas kernels over a grid whose tile axis is
+    `tiles`, a run-time bound, so what is fetched and multiplied follows the
+    routing. Each step's weight block comes out of the [L, X, ...] stacks at
+    (layer, tile_expert[t]) by the block index itself, as does that expert's
+    column of `rows` and of `combine`: no slice of a stack is ever copied, and
+    nothing of the layout's padded size exists beside the hidden rows
+    [T * rows_tile, F]:
+
+    * hidden: grid (tiles, slabs of the model width). The tokens stay in fast
+      memory; a tile's rows are picked out of them by a one-hot product (exact:
+      one 1 a row), then meet the expert's [slab, F] blocks of gate and up,
+      summed over the slabs in float32 scratch; the gated product is rounded
+      once, to the operands' type.
+    * down: grid (column blocks of the model width, tiles). A tile's hidden
+      rows through the expert's [F, block] of down; each row, weighted in
+      float32 by its token's combine weight, is added to that token's sums
+      [N, block] f32 by the transposed one-hot product, the addend split into
+      two bfloat16 terms so that the sum keeps float32's digits.
+
+    A routing that fills no tile still runs tile 0, whose rows are all
+    padding and add nothing. x [N, D], N at most `_GROUP_TOKENS`."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    N, D = x.shape
+    dt = x.dtype
+    if layer is None:       # one layer's weights: a stack of one
+        w_gate, w_in, w_out = (a[None] for a in (w_gate, w_in, w_out))
+        layer = 0
+    F = w_gate.shape[-1]
+    TM = rows_tile
+    T = tile_expert.shape[0]
+    # the model width a block: rows of a [td, F] slab of gate and up, columns
+    # of an [F, td] block of down
+    td = _weight_tile(F, D, w_gate.dtype.itemsize)
+    nd = D // td
+    Np = -(-N // 128) * 128                              # whole lane tiles of tokens
+    slabs = jnp.pad(x, ((0, Np - N), (0, 0))).reshape(Np, nd, td).transpose(1, 0, 2)
+    rows = jnp.pad(rows, ((0, Np - N), (0, 0)), constant_values=-1).T[:, None]
+    weights = jnp.pad(combine, ((0, Np - N), (0, 0))).T[:, None]       # [X, 1, Np]
+    run = jnp.maximum(tiles, 1)
+    scalars = (tile_expert, tile_first, jnp.asarray(layer, jnp.int32).reshape(1))
+    params = pltpu.CompilerParams(
+        dimension_semantics=("arbitrary", "arbitrary"), vmem_limit_bytes=64 << 20)
+
+    def picked(t, first, rows_ref):
+        """[TM, Np] bool: row j of tile t is token n's."""
+        return rows_ref[...] - first[t] == jax.lax.broadcasted_iota(
+            jnp.int32, (TM, Np), 0)
+
+    def hidden(te, first, l, rows_ref, x_ref, wg_ref, wu_ref, h_ref, g_acc, u_acc):
+        k = pl.program_id(1)
+
+        @pl.when(k == 0)
+        def _():
+            g_acc[...] = jnp.zeros_like(g_acc)
+            u_acc[...] = jnp.zeros_like(u_acc)
+
+        pick = jnp.where(picked(pl.program_id(0), first, rows_ref), 1.0, 0.0).astype(dt)
+        tile = jnp.dot(pick, x_ref[k], preferred_element_type=jnp.float32).astype(dt)
+        g_acc[...] += jnp.dot(tile, wg_ref[...].astype(dt),
+                              preferred_element_type=jnp.float32)
+        u_acc[...] += jnp.dot(tile, wu_ref[...].astype(dt),
+                              preferred_element_type=jnp.float32)
+
+        @pl.when(k == nd - 1)
+        def _():
+            h_ref[...] = _gated(activation, g_acc[...], u_acc[...]).astype(dt)
+
+    slab = pl.BlockSpec((None, None, td, F), lambda t, k, te, f, l: (l[0], te[t], k, 0))
+    h = pl.pallas_call(
+        hidden, out_shape=jax.ShapeDtypeStruct((T * TM, F), dt),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3, grid=(run, nd),
+            in_specs=[pl.BlockSpec((None, 1, Np), lambda t, k, te, f, l: (te[t], 0, 0)),
+                      pl.BlockSpec((nd, Np, td), lambda t, k, te, f, l: (0, 0, 0)),
+                      slab, slab],
+            out_specs=pl.BlockSpec((TM, F), lambda t, k, te, f, l: (t, 0)),
+            scratch_shapes=[pltpu.VMEM((TM, F), jnp.float32)] * 2),
+        compiler_params=params, interpret=interpret, name="moe_grouped_hidden",
+    )(*scalars, rows, slabs, w_gate, w_in)
+
+    def down(te, first, l, rows_ref, wt_ref, h_ref, wd_ref, y_ref):
+        t = pl.program_id(1)
+
+        @pl.when(t == 0)
+        def _():
+            y_ref[...] = jnp.zeros_like(y_ref)
+
+        mine = picked(t, first, rows_ref)
+        weight = jnp.sum(jnp.where(mine, wt_ref[...], 0.0), axis=1, keepdims=True)
+        o = jnp.dot(h_ref[...], wd_ref[...].astype(dt),
+                    preferred_element_type=jnp.float32) * weight       # [TM, td]
+        pick = jnp.where(mine, 1.0, 0.0).astype(jnp.bfloat16)
+        hi = o.astype(jnp.bfloat16)
+        lo = (o - hi.astype(jnp.float32)).astype(jnp.bfloat16)
+        back = lambda a: jax.lax.dot_general(      # pick^T a: each row to its token
+            pick, a, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+        y_ref[...] += back(hi) + back(lo)
+
+    column = pl.BlockSpec((None, 1, Np), lambda n, t, te, f, l: (te[t], 0, 0))
+    y = pl.pallas_call(
+        down, out_shape=jax.ShapeDtypeStruct((Np, D), jnp.float32),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3, grid=(nd, run),
+            in_specs=[column, column,
+                      pl.BlockSpec((TM, F), lambda n, t, te, f, l: (t, 0)),
+                      pl.BlockSpec((None, None, F, td),
+                                   lambda n, t, te, f, l: (l[0], te[t], 0, n))],
+            out_specs=pl.BlockSpec((Np, td), lambda n, t, te, f, l: (0, n))),
+        compiler_params=params, interpret=interpret, name="moe_grouped_down",
+    )(*scalars, rows, weights, h, w_out)
+    return y[:N]
+
+
+@functools.partial(jax.jit, static_argnames=("activation", "k", "rows_tile", "kernels"))
+def _grouped_run(x, combine, w_gate, w_in, w_out, layer, *, activation, k,
+                 rows_tile, kernels):
+    """The grouped form from operands to y [N, D] f32, by the kernels or the
+    plain loop. Jitted for its trace alone: a server's programs differ in
+    table widths far more often than in tokens, and every program of one
+    token count takes this trace (two kernels' worth) from the cache."""
+    run = _grouped_pallas if kernels else _grouped_plain
+    return run(x, combine, w_gate, w_in, w_out, activation, layer,
+               *dropless_groups(combine, k, rows_tile), rows_tile)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 7))
+def _grouped_experts(x, combine, w_gate, w_in, w_out, activation, layer, k):
+    """The grouped form, y [N, D] f32: the kernels on the TPU (widths of whole
+    lane tiles), else the plain loop, whose derivative is the backward pass
+    of both."""
+    from .attention import _on_tpu
+
+    D, F = w_gate.shape[-2:]
+    return _grouped_run(
+        x, combine, w_gate, w_in, w_out, layer, activation=activation, k=k,
+        rows_tile=GROUP_ROWS, kernels=_on_tpu() and D % 128 == 0 and F % 128 == 0)
+
+
+def _grouped_fwd(x, combine, w_gate, w_in, w_out, activation, layer, k):
+    return (_grouped_experts(x, combine, w_gate, w_in, w_out, activation, layer, k),
+            (x, combine, w_gate, w_in, w_out, layer))
+
+
+def _grouped_bwd(activation, k, saved, g):
+    *operands, layer = saved
+    _, vjp = jax.vjp(functools.partial(
+        _grouped_run, layer=layer, activation=activation, k=k,
+        rows_tile=GROUP_ROWS, kernels=False), *operands)
+    return (*vjp(g), None)
+
+
+_grouped_experts.defvjp(_grouped_fwd, _grouped_bwd)
+
+
 def dropless_experts(x, combine, w_gate, w_in, w_out, activation: str,
-                     layer=None, touched_k: int = 0):
+                     layer=None, touched_k: int = 0, grouped_k: int = 0):
     """y [N, D] = sum_e combine[n, e] * W_out,e( act(W_gate,e x) * (W_in,e x) ).
 
     x [N, D]; combine [N, X] f32; weights [X, D, F] / [X, F, D], or with
     `layer` (a traced index) the whole stacks [L, X, ...] of which layer
-    `layer` is read in place. Two forms, the same mathematics:
+    `layer` is read in place. Three forms, the same mathematics:
 
     * dense (default): one [N, D] x [D, X*F] product for gate and up, the
       combine weights folded into the hidden activations, one [N, X*F] x
-      [X*F, D] product down. Reads every expert once: right from some ten
-      tokens up, where top-k of N tokens reaches most experts anyway.
+      [X*F, D] product down. Every expert meets every token.
     * `touched_k` = k > 0: a loop over at most min(N*k, X) experts, the chosen
       ones first, each applied to all N tokens under its combine column;
       an expert nobody chose is never read. Right for a decode step of a
       few lanes, whose time is the bytes of expert weights it streams.
+    * `grouped_k` = k > 0 (a token's nonzero columns are at most k): the
+      tokens that chose an expert are its rows (`dropless_groups`), each
+      expert's rows through its gate, up and down products once, a tile of
+      `GROUP_ROWS` rows at a time and only the tiles the routing fills; a
+      token's k rows weighted by its combine weights and summed in float32.
+      An expert nobody chose is never read and a column that is zero costs
+      nothing: right for a step of many tokens. On the TPU the tiles are two
+      Pallas kernels that fetch each expert's blocks out of the stacks in
+      place (`_grouped_pallas`).
     """
     N, D = x.shape
     X = combine.shape[-1]
     dt = x.dtype
 
-    def one(a, e):
-        """Expert e's slice of a stacked weight, read where it lies."""
-        if layer is None:
-            return jax.lax.dynamic_index_in_dim(a, e, 0, keepdims=False)
-        return jax.lax.dynamic_slice(
-            a, (layer, e, 0, 0), (1, 1) + a.shape[2:])[0, 0]
-
+    if grouped_k:       # in pieces of the tokens the kernels keep in fast memory
+        return jnp.concatenate([
+            _grouped_experts(x[i:i + _GROUP_TOKENS], combine[i:i + _GROUP_TOKENS],
+                             w_gate, w_in, w_out, activation, layer, grouped_k)
+            for i in range(0, N, _GROUP_TOKENS)]).astype(dt)
     if not touched_k:
         if layer is not None:
             w_gate, w_in, w_out = (
@@ -239,11 +506,11 @@ def dropless_experts(x, combine, w_gate, w_in, w_out, activation: str,
     def body(i, y):
         def run(y):
             e = order[i]
-            g = x @ one(w_gate, e).astype(dt)
-            u = x @ one(w_in, e).astype(dt)
+            g = x @ _one(w_gate, layer, e).astype(dt)
+            u = x @ _one(w_in, layer, e).astype(dt)
             c = jax.lax.dynamic_index_in_dim(combine, e, 1, keepdims=True)
             h = _gated(activation, g, u) * c.astype(dt)
-            return y + (h @ one(w_out, e).astype(dt)).astype(jnp.float32)
+            return y + (h @ _one(w_out, layer, e).astype(dt)).astype(jnp.float32)
 
         return jax.lax.cond(i < n_hit, run, lambda y: y, y)
 

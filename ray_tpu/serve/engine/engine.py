@@ -247,7 +247,7 @@ class InferenceEngine:
         import jax
 
         from ...models.gpt import (
-            init_paged_cache, init_params, kv_layout, paged_attn_keys,
+            init_paged_cache, init_params, kv_layout, moe_form, paged_attn_keys,
         )
 
         self.cfg = dataclasses.replace(cfg, remat=False, remat_policy=None)
@@ -384,6 +384,11 @@ class InferenceEngine:
         # that fell on held experts, all], summed over layers and steps.
         self._step_moe = None
         self.total_moe_assign = [0, 0]
+        # Tokens (as the program is shaped) x expert layers of every paged
+        # program an expert model dispatched: [under the grouped form, all],
+        # the form asked of the function the program takes it from.
+        self._moe_form = moe_form
+        self.total_moe_tokens = [0, 0]
         # Passes of the layer stack over the decode steps' real lanes: [run,
         # from the length of what came back; what `ut_steps` would be].
         self.total_ut_passes = [0, 0]
@@ -979,6 +984,15 @@ class InferenceEngine:
             count[0] += run
             count[1] += padded
 
+    def _count_moe(self, tokens: int):
+        """Add one program of `tokens` tokens (lanes x tokens a lane, padding
+        and all) to the expert layers' count, by the form it takes."""
+        if self.cfg.mlp_type != "moe":
+            return
+        n = tokens * (self.cfg.n_layers - self.cfg.dense_layers)
+        self.total_moe_tokens[0] += n * (self._moe_form(self.cfg, tokens) == "grouped")
+        self.total_moe_tokens[1] += n
+
     def _run_prefill(self, chunk):
         """Dispatch one prefill chunk: compute prompt[start : start+n] into
         the paged cache. Every chunk's program samples; only the FINAL
@@ -1003,6 +1017,7 @@ class InferenceEngine:
             bt = np.zeros(self._table_shape(W), np.int32)
             self._tables_into(bt, seq)
             self._count_attn(1, W, np.asarray([chunk.start + L - 1]), True)
+            self._count_moe(Sp)
             meta = np.asarray(
                 [L, chunk.start, seq.slot if chunk.last else self._spare],
                 np.int32)
@@ -1134,6 +1149,7 @@ class InferenceEngine:
                 valid_len[i] = 1 + len(d)
                 self._tables_into(tables[i], seq)
             self._count_attn(B, W, positions + valid_len - 1, valid_len > 0)
+            self._count_moe(B * K1)
             args = (
                 jnp.asarray(tokens),
                 jnp.asarray(positions),
@@ -1210,6 +1226,7 @@ class InferenceEngine:
             self._step_chained = int(not lanes[3].all())
             self.total_decode_chained += self._step_chained
             self._count_attn(B, W, lanes[1], np.arange(B) < len(seqs))
+            self._count_moe(B)
             args = (jnp.asarray(lanes), jnp.asarray(tables))
         with flight.phase("engine.dispatch", ph, "dispatch_ns"):
             sampled, self.kv, self._last = self._decode(
@@ -1389,6 +1406,8 @@ class InferenceEngine:
             "ut_passes_full": self.total_ut_passes[1],
             "moe_assign_held": self.total_moe_assign[0],
             "moe_assign_total": self.total_moe_assign[1],
+            "moe_tokens_grouped": self.total_moe_tokens[0],
+            "moe_tokens_expert": self.total_moe_tokens[1],
             "decode_dispatched": self.total_decode_dispatched,
             "decode_chained": self.total_decode_chained,
             "total_tokens": self.total_tokens,

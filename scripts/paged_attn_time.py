@@ -18,8 +18,9 @@ engine dispatches (`serve/engine/engine.py: _paged_jits`: the same function
 with the sampler behind it, ids for logits, the last ids carried beside the
 pool), on the same input ids (an expert model routes by them): what the
 epilogue costs a shape (PERF.md §6, PR 33). `--ids distinct` gives decode
-lane b the id 7 + b instead of 7 for all (an expert model's step reads the
-experts its lanes choose). `--latent-forms 64,256` (a latent model) times ONE
+lane b the id 7 + b and a chunk's token j the id 7 + j instead of 7 for all
+(an expert model's step reads the experts its tokens choose: alike, a chunk's
+tokens would all be rows of the same few experts). `--latent-forms 64,256` (a latent model) times ONE
 layer's attention of one chunk over a table of W blocks, outside any program,
 in the two forms the mathematics allows: ABSORBED (what `_paged_layers` runs:
 the key up-projection on the query, every head over the one cached row) and
@@ -130,8 +131,9 @@ def main(argv=None) -> int:
         for spec in filter(None, a.prefill.split(",")):
             W, offset = (int(x) for x in spec.split(":"))
             n = min(chunk, W * BS - offset)
-            args = (jnp.zeros((1, chunk), jnp.int32).at[0, :n].set(7), jnp.int32(n),
-                    jnp.int32(offset), jnp.asarray(table(W)))
+            ids = 7 + np.arange(n, dtype=np.int32) * (a.ids == "distinct")
+            args = (jnp.zeros((1, chunk), jnp.int32).at[0, :n].set(ids % cfg.vocab_size),
+                    jnp.int32(n), jnp.int32(offset), jnp.asarray(table(W)))
             row = {"program": "prefill_paged", "tile_keys": tile, "chunk": chunk,
                    "W": W, "keys": W * BS, "offset": offset}
             timed(dict(row), prefill, args)

@@ -20,6 +20,9 @@ many lanes x blocks the gathered history itself grows past the threshold.
 A pool-sized `copy` between two different layouts is a relayout the device
 pays in every layer of every step (PERF.md §6, PR 25).
 
+The programs are compiled with the kernels the chip runs (the Pallas norms,
+the grouped experts' two kernels), not the plain paths of this process' backend.
+
 Nothing runs: a compile that passes is not a chip run, and no time, rate or
 share comes from here. The last line of stdout is one JSON object."""
 
@@ -187,7 +190,13 @@ def main() -> int:
     from jax.experimental import topologies
 
     from ray_tpu.models.gpt import CONFIGS
+    from ray_tpu.ops import attention
 
+    # The programs pick their kernels by `jax.default_backend()`, which is the
+    # CPU here: steer them to the TPU branch (the Pallas norms, the grouped
+    # experts' kernels), in this script and nowhere else, so that what the
+    # chip's compiler refuses of a kernel (VMEM, tiling) shows here.
+    attention._on_tpu = lambda: True
     overrides = {}
     if a.config:
         from benchmarks import harness

@@ -411,6 +411,17 @@ def test_engine_serves_exactly_and_counts_the_assignments_that_fell_here(
         == 2 * 3 * 2 * 19
     assert 0 < stats["moe_assign_held"] == sum(a["assign_held"] for a in decodes) \
         < stats["moe_assign_total"]
+    # every program's tokens as it is shaped x the two expert layers, by the
+    # form `moe_form` gives it: top-3 of 16, so the prompts' chunks (24 + 6 and
+    # 24 + 11 tokens in programs of 32, 8, 32 and 16) are grouped and the decode
+    # steps (at most 4 lanes) take the loop
+    from ray_tpu.models.gpt import moe_form
+
+    assert moe_form(cfg, 5) == "loop" and moe_form(cfg, 6) == "grouped"
+    assert sum(a["prefills"] for a in steps) == 4
+    assert stats["moe_tokens_grouped"] == 2 * (32 + 8 + 32 + 16)
+    lanes = stats["moe_tokens_expert"] - stats["moe_tokens_grouped"]
+    assert 2 * len(decodes) <= lanes <= 2 * 4 * len(decodes)
     # a latent layer's keys are counted as a global layer's
     assert 0 < stats["attn_keys_run"] <= stats["attn_keys_padded"]
 
@@ -502,14 +513,17 @@ def test_config_and_architecture_module_refuse_what_is_not_the_model():
 # programs (`serve/engine/engine.py` `_paged_jits`: each ends in the sampler;
 # 4 lanes, a pool of 64 blocks of 16, a chunk of 32, 2 drafts) at the parent
 # commit 4caabe5, under the jax they were taken with, with tables of 8 blocks
-# (one tile) and of 128 (the key loop).
+# (one tile) and of 128 (the key loop). PR 35 re-took the `smallthinker-21b-a3b`
+# `prefill` and `verify` entries (32 and 12 tokens x top-6 reach the 64 experts:
+# those programs took the dense form and take the grouped one); its `decode`
+# entries (4 lanes: the loop form) and the other 18 are still the parent's.
 _PARENT = {
     "gpt2-small/8": {"decode": ["47e12eb441a8db6f", 56599], "prefill": ["3d712626f6741d9d", 56211], "verify": ["cabdde9d7cb27d1c", 46062]},
     "gpt2-small/128": {"decode": ["a469eadf508f0a50", 69719], "prefill": ["3eaeb73f7472490f", 69106], "verify": ["cdc8b418bab759b6", 59099]},
     "gpt2-large/8": {"decode": ["4a3c99ad312b90d7", 56890], "prefill": ["586bdd407d571263", 56494], "verify": ["9c9ab8dce652d623", 46341]},
     "gpt2-large/128": {"decode": ["3e8b3a276dffa813", 70014], "prefill": ["d8ff5e567f857d44", 69393], "verify": ["325d1493576d59f0", 59382]},
-    "smallthinker-21b-a3b/8": {"decode": ["2b631670cf4b6751", 75755], "prefill": ["3fee03afbd4e71d9", 63850], "verify": ["ed74ee2962091af9", 55485]},
-    "smallthinker-21b-a3b/128": {"decode": ["220ea9aebbe176f3", 87784], "prefill": ["2c789de697d1826f", 76542], "verify": ["7db49700dafcbf77", 68326]},
+    "smallthinker-21b-a3b/8": {"decode": ["2b631670cf4b6751", 75755], "prefill": ["c257305dcd77c747", 81048], "verify": ["d59de93341f7cb78", 72691]},
+    "smallthinker-21b-a3b/128": {"decode": ["220ea9aebbe176f3", 87784], "prefill": ["1e8315c2e3ac9c53", 93742], "verify": ["0d0bacb981a5c958", 85530]},
     "ouro-2.6b/8": {"decode": ["daa65fea876c298e", 74846], "prefill": ["6f59bfc7e81f8afa", 65849], "verify": ["441542f5c0143299", 57230]},
     "ouro-2.6b/128": {"decode": ["6614de8a7a764202", 87645], "prefill": ["11867b981e748ff7", 79107], "verify": ["90cb98c74573bd2c", 70644]},
 }

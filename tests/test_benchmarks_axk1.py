@@ -47,6 +47,10 @@ def test_rehearsal_is_correct_and_counts_the_assignments_that_fell_here(obs):
     share = readers.read("moe_held_assign_share", obs)
     assert share == 100.0 * c["moe_assign_held"] / c["moe_assign_total"]
     assert 10.0 < share < 45.0                      # 4 of 16: a quarter in the mean
+    # every chunk program takes the grouped form, every decode step the loop
+    assert 0 < c["moe_tokens_grouped"] < c["moe_tokens_expert"]
+    grouped = readers.read("moe_grouped_token_share", obs)
+    assert grouped == 100.0 * c["moe_tokens_grouped"] / c["moe_tokens_expert"] > 50.0
     for name in ("kv_util_mean", "prefill_span_p90_ms", "queue_wait_p50_ms",
                  "decode_lanes_mean", "engine_step_ms", "moe_experts_touched_mean",
                  "moe_expert_load_max", "attn_keys_run_share", "decode_chained_share"):
@@ -140,7 +144,14 @@ def test_the_cell_and_its_files_are_in_the_benchmark():
             "setup_weights_s", "serve_idle_share", "compiles_in_window"} <= set(layer)
     per_layer = {m["name"]: m for m in bench["per_layer"]}
     assert all(per_layer[name]["moves"] in e2e for name in layer)
-    assert bench["per_layer"][-1]["name"] == "moe_held_assign_share"
+    names = [m["name"] for m in bench["per_layer"]]     # later PRs append
+    assert names.index("moe_held_assign_share") < names.index("moe_grouped_token_share")
+    assert per_layer["moe_grouped_token_share"]["workloads"] == [
+        "smallthinker-21b-a3b.mixed-len", CELL]
+    assert per_layer["moe_grouped_token_share"]["moves"] == "ttft_mean_ms"
+    assert readers.reader_spec("moe_grouped_token_share") == {
+        "kind": "counter_ratio", "num": "moe_tokens_grouped",
+        "den": "moe_tokens_expert", "scale": 100.0}
     assert per_layer["moe_held_assign_share"]["workloads"] == [CELL]
     for name in layer:
         assert readers.reader_spec(name)["kind"] in readers.KINDS, name
