@@ -886,7 +886,8 @@ class ClusterBackend(RuntimeBackend):
 
     def record_trace_event(self, ev) -> None:
         """Ship tracing span/timeline events (one dict or a batch list —
-        util/tracing.record_span / record_events); rides the same controller
+        util/tracing.record_events, the flight ring's flusher); rides the
+        same controller
         channel as worker task_events batches."""
         events = ev if isinstance(ev, list) else [ev]
         if self.worker is not None:

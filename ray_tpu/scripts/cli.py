@@ -320,6 +320,11 @@ def cmd_flight(backend, info, args):
         print(f"engine steps: {rep['steps']} of {rep['step_ms']:.2f} ms; "
               f"idle wait {rep['wait_share']:.1f}% of {rep['window_s']:.1f}s")
         print(f"  ms a step: {phases}")
+    for st in (rep or {}).get("stalls", ()):
+        print(f"  stall at +{st['at_s']:.2f}s: host {st['host_ms']:.1f} ms against a "
+              f"mean of {st['mean_ms']:.2f}, {st['phase']} {st['phase_ms']:.1f}, "
+              f"gc {st['gc_ms']:.1f}; bucket {st['bucket']} running "
+              f"{st['running']} queued {st['queue_depth']}")
     if rep and rep["requests"]:
         print(f"traced requests: {rep['requests']}  ttft {rep['ttft_mean_ms']:.1f} ms = "
               f"ingress {rep['ingress_p50_ms']:.1f} + queue {rep['queue_wait_p50_ms']:.1f}"

@@ -48,6 +48,7 @@ blocked `generate` never gates another request's `submit`).
 from __future__ import annotations
 
 import dataclasses
+import gc
 import queue
 import threading
 import time
@@ -63,6 +64,92 @@ from .scheduler import (
 )
 
 _FINISH = object()  # stream sentinel
+
+# A step is slow when the host's part of its span (the span less `fetch_ns`,
+# the wait for the device) passes this many times the running mean of that
+# part, once this many steps have run; the mean then follows the last steps,
+# each moving it by that share of its distance (`_book_step`).
+_SLOW_FACTOR = 4
+_SLOW_AFTER = 64
+
+# The engine's books (`InferenceEngine.stats`): integer totals it adds to
+# on every step that writes a record, whether or not the recorder is on.
+_STEP_BOOKS = (
+    "steps", "steps_chunk", "steps_decode", "steps_decode_only", "steps_slow",
+    "step_ns", "step_chunk_ns", "step_decode_only_ns", "host_ns", "slow_ns",
+    "loop_ns", "waited_ns", *flight.SERVE_STEP_PHASES,
+    "decode_lanes", "decode_bucket_lanes", "decode_lanes_beside_chunk",
+    "prefill_tokens", "prefill_tokens_padded",
+)
+
+
+class _GcPauses:
+    """The process's garbage collections, timed by a `gc.callbacks` hook: a
+    collection holds the interpreter lock, so it stops the step thread
+    whichever thread's allocation set it off, and lands in whichever phase
+    it interrupts. One hook a process, put in by the first engine and
+    taken out when the last one shuts down."""
+
+    def __init__(self):
+        self.ns = 0
+        self.collections = 0
+        self._t0 = 0
+        self._engines = 0
+        self._lock = threading.Lock()
+
+    def _hook(self, phase: str, info: dict):
+        if phase == "start":
+            self._t0 = time.monotonic_ns()
+        elif self._t0:      # collections do not nest: one writer at a time
+            self.ns += time.monotonic_ns() - self._t0
+            self.collections += 1
+            self._t0 = 0
+
+    def acquire(self):
+        with self._lock:
+            self._engines += 1
+            if self._engines == 1:
+                gc.callbacks.append(self._hook)
+
+    def release(self):
+        with self._lock:
+            self._engines -= 1
+            if self._engines == 0:
+                gc.callbacks.remove(self._hook)
+
+
+_GC = _GcPauses()
+
+
+class _Delivery:
+    """Books of a token's path from `_emit` to whoever iterates its
+    `RequestOutput`: [tokens taken, ns from emission to the consumer holding
+    the token (the queue hop and the interpreter lock), ns from handing a
+    token over to being asked for the next (the consumer shipping it on),
+    tokens that were already queued when asked for]. Each output sums its
+    own in its one consumer thread; the sums of streams that have ended are
+    folded under a lock, and a reading adds the open streams' as they stand,
+    so nothing is lost among consumers that run at once."""
+
+    def __init__(self):
+        self._lock = threading.Lock()
+        self._ended = [0, 0, 0, 0]
+        self._open: set = set()
+
+    def opened(self, out: "RequestOutput"):
+        with self._lock:
+            self._open.add(out)
+
+    def ended(self, out: "RequestOutput"):
+        with self._lock:
+            self._open.discard(out)
+            self._ended = [a + b for a, b in zip(self._ended, out.taken)]
+            out.taken[:] = [0, 0, 0, 0]     # iterated again, it starts anew
+
+    def read(self) -> List[int]:
+        with self._lock:
+            return [sum(col) for col in zip(
+                self._ended, *(out.taken for out in self._open))]
 
 # Jitted paged programs are process-wide singletons: every engine (and every
 # replica in local-mode tests) shares one XLA program cache, keyed by the
@@ -216,9 +303,11 @@ class RequestOutput:
     """Per-request stream endpoint: the engine thread feeds it, any
     consumer thread drains it."""
 
-    def __init__(self, request_id: str):
+    def __init__(self, request_id: str, delivery: _Delivery):
         self.request_id = request_id
-        self._q: "queue.Queue" = queue.Queue()
+        self._q: "queue.Queue" = queue.Queue()    # (token, emitted at, ns)
+        self._delivery = delivery
+        self.taken = [0, 0, 0, 0]       # this stream's row of `_Delivery`
         self.finish_reason: Optional[str] = None
         # Registry-cleanup handshake (under the engine lock): the engine
         # drops the registry entry once the request is BOTH finished and
@@ -228,13 +317,25 @@ class RequestOutput:
         self.retrieved = False
 
     def __iter__(self) -> Iterator[int]:
-        while True:
-            item = self._q.get()
-            if item is _FINISH:
-                return
-            if isinstance(item, Exception):
-                raise item
-            yield item
+        taken = self.taken
+        self._delivery.opened(self)
+        try:
+            while True:
+                behind = not self._q.empty()
+                item = self._q.get()
+                if item is _FINISH:
+                    return
+                if isinstance(item, Exception):
+                    raise item
+                tok, emitted_ns = item
+                held_ns = time.monotonic_ns()
+                taken[0] += 1
+                taken[1] += held_ns - emitted_ns
+                taken[3] += behind
+                yield tok
+                taken[2] += time.monotonic_ns() - held_ns
+        finally:
+            self._delivery.ended(self)
 
 
 class InferenceEngine:
@@ -413,6 +514,14 @@ class InferenceEngine:
         self._lane = f"serve/engine-{self.opts.role or 'colocated'}"
         self._phases: Dict[str, int] = {}       # this step's, see _step
         self._idle = {"waited_ns": 0}   # _loop's wait, until a step records it
+        # The books: what the step records carry, summed as they are made
+        # (`_book_step`), beside the tokens' delivery and the process's GC.
+        self._books: Dict[str, int] = dict.fromkeys(_STEP_BOOKS, 0)
+        self._host_mean = [0, 0]    # [steps seen, running mean host ns a step]
+        self._step_chunks = [0, 0]      # this step's chunk tokens: [real, padded]
+        self._delivery = _Delivery()
+        self._gc_hooked = True
+        _GC.acquire()
         self._init_metrics()
 
     # ------------------------------------------------------------- metrics
@@ -597,7 +706,7 @@ class InferenceEngine:
                 eos_token=eos_token,
             )
             self.scheduler.add(seq)
-            self._outputs[request_id] = RequestOutput(request_id)
+            self._outputs[request_id] = RequestOutput(request_id, self._delivery)
             if trace_id:
                 self._trace_info[request_id] = {
                     "trace": trace_id, "submit_ns": time.monotonic_ns(),
@@ -630,11 +739,12 @@ class InferenceEngine:
     # ---------------------------------------------------------------- step
     def _emit(self, seq: Sequence, tok: int):
         seq.append_token(tok)
+        now_ns = time.monotonic_ns()    # the token's emission, for its consumer
         out = self._outputs.get(seq.request_id)
         if out is not None:
-            out._q.put(tok)
+            out._q.put((tok, now_ns))
         self.total_tokens += 1
-        self._tok_window.append(time.monotonic())
+        self._tok_window.append(now_ns * 1e-9)
 
     def _maybe_finish(self, seq: Sequence) -> bool:
         reason = seq.should_stop()
@@ -1018,6 +1128,8 @@ class InferenceEngine:
             self._tables_into(bt, seq)
             self._count_attn(1, W, np.asarray([chunk.start + L - 1]), True)
             self._count_moe(Sp)
+            self._step_chunks[0] += L
+            self._step_chunks[1] += Sp
             meta = np.asarray(
                 [L, chunk.start, seq.slot if chunk.last else self._spare],
                 np.int32)
@@ -1269,6 +1381,8 @@ class InferenceEngine:
             self._step_attn = [0, 0]  # [keys run, keys padded]
             self._step_moe = None
             self._step_chained = 0
+            self._step_chunks = [0, 0]
+            gc0 = _GC.ns
             tok0 = self.total_tokens
             had_flight = self._inflight is not None
             # An export is served drained; so is a plan that needs token
@@ -1347,26 +1461,74 @@ class InferenceEngine:
                 "step_s": now - t0,
             }
             self._export_metrics(stats)
-        if fl_on and (out.prefills or out.decodes or had_flight):
+        if out.prefills or out.decodes or had_flight:
             idle, self._idle = self._idle, {"waited_ns": 0}
-            attrs = {"prefills": len(out.prefills),
-                     "decodes": len(out.decodes),
-                     "chained": self._step_chained,
-                     "tokens": own_tokens,
-                     "attn_keys_run": self._step_attn[0],
-                     "attn_keys_padded": self._step_attn[1],
-                     **idle, **ph,
-                     "queue_depth": stats["queue_depth"],
-                     "running": stats["running"],
-                     "kv_util": stats["kv_utilization"]}
-            if self._inflight is not None:
-                # its tokens and what comes back with its ids are not here
-                # yet: `_collect` writes the record when it reads them
-                self._inflight.record = (t0_ns, t1_ns, attrs)
-            else:
-                flight.record("engine.step", t0_ns, t1_ns, lane=self._lane,
-                              attrs=attrs)
+            times = {**idle, **ph}
+            load = {"queue_depth": stats["queue_depth"],
+                    "running": stats["running"]}
+            self._book_step(out, t0_ns, t1_ns, times, {
+                "bucket": out.batch_bucket, **load, "gc_ns": _GC.ns - gc0})
+            if fl_on:
+                attrs = {"prefills": len(out.prefills),
+                         "decodes": len(out.decodes),
+                         "chained": self._step_chained,
+                         "tokens": own_tokens,
+                         "attn_keys_run": self._step_attn[0],
+                         "attn_keys_padded": self._step_attn[1],
+                         **times, **load,
+                         "kv_util": stats["kv_utilization"]}
+                if self._inflight is not None:
+                    # its tokens and what comes back with its ids are not
+                    # here yet: `_collect` writes the record when it reads them
+                    self._inflight.record = (t0_ns, t1_ns, attrs)
+                else:
+                    flight.record("engine.step", t0_ns, t1_ns,
+                                  lane=self._lane, attrs=attrs)
         return stats
+
+    def _book_step(self, out: SchedulerOutput, t0_ns: int, t1_ns: int,
+                   times: Dict[str, int], load: Dict[str, int]):
+        """Add a step that writes a record to the books: the record's own
+        values, summed, the step's kind what it carried (a prefill chunk |
+        decode lanes and no chunk). Then the test for a slow step, on the
+        host's part of the span: `fetch_ns` is the device's program where
+        steps chain, the rest is this thread's own work and whatever held
+        it up. A slow step is booked (`steps_slow`, `slow_ns`: its excess
+        over the mean) and leaves ONE `engine.stall` flight span with its
+        phases and the GC pauses inside it, so that a stall of a run nobody
+        traced has a name afterwards."""
+        b = self._books
+        span = t1_ns - t0_ns
+        host = span - times["fetch_ns"]
+        lanes = len(out.decodes)
+        b["steps"] += 1
+        b["step_ns"] += span
+        b["host_ns"] += host
+        for key, ns in times.items():
+            b[key] += ns
+        b["prefill_tokens"] += self._step_chunks[0]
+        b["prefill_tokens_padded"] += self._step_chunks[1]
+        if lanes:
+            b["steps_decode"] += 1
+            b["decode_lanes"] += lanes
+            b["decode_bucket_lanes"] += out.batch_bucket
+            if out.prefills:
+                b["decode_lanes_beside_chunk"] += lanes
+        kind = "chunk" if out.prefills else "decode_only" if lanes else None
+        if kind is not None:
+            b[f"steps_{kind}"] += 1
+            b[f"step_{kind}_ns"] += span
+        seen = self._host_mean
+        seen[0] += 1
+        n, mean = seen
+        if n > _SLOW_AFTER and host > _SLOW_FACTOR * mean:
+            b["steps_slow"] += 1
+            b["slow_ns"] += host - mean
+            flight.record("engine.stall", t0_ns, t1_ns, lane=self._lane, attrs={
+                "mean_ns": mean, **load,
+                **{k: times[k] for k in flight.SERVE_STEP_PHASES[:-1]}})
+            host = _SLOW_FACTOR * mean    # moves the mean as a step at the edge
+        seen[1] = mean + (host - mean) // min(n, _SLOW_AFTER)
 
     def stats(self, include_raw: bool = False) -> Dict[str, Any]:
         """Engine counters + latency summaries. `include_raw=True` adds the
@@ -1410,6 +1572,11 @@ class InferenceEngine:
             "moe_tokens_expert": self.total_moe_tokens[1],
             "decode_dispatched": self.total_decode_dispatched,
             "decode_chained": self.total_decode_chained,
+            **self._books,
+            **dict(zip(("stream_tokens", "stream_wake_ns", "stream_send_ns",
+                        "stream_behind"), self._delivery.read())),
+            "gc_ns": _GC.ns,
+            "gc_collections": _GC.collections,
             "total_tokens": self.total_tokens,
             "total_finished": self.total_finished,
             "total_preemptions": self.total_preemptions,
@@ -1480,6 +1647,9 @@ class InferenceEngine:
         if self._thread is not None:
             return
         self._stop.clear()
+        if not self._gc_hooked:     # started again after a shutdown
+            self._gc_hooked = True
+            _GC.acquire()
         self._thread = threading.Thread(
             target=self._loop, daemon=True, name="llm-engine"
         )
@@ -1507,6 +1677,9 @@ class InferenceEngine:
             side, self._side_work = list(self._side_work), deque()
         for out in outs:
             out._q.put(RuntimeError("engine shut down"))
+        if self._gc_hooked:
+            self._gc_hooked = False
+            _GC.release()
         for _, _, fut in side:
             # Exporters blocked in export_prompt_kv must not wait out their
             # full transfer deadline on a dead driver thread.
@@ -1524,6 +1697,7 @@ class InferenceEngine:
         )
 
     def _loop(self):
+        t_ns = time.monotonic_ns()
         while not self._stop.is_set():
             with self._work:
                 if self._nothing_to_run():
@@ -1551,3 +1725,8 @@ class InferenceEngine:
                     self._inflight = None
                 for out in outs:
                     out._q.put(e)
+            # the whole iteration, wait and step and export: what the
+            # books' shares of the thread's time are taken over
+            now_ns = time.monotonic_ns()
+            self._books["loop_ns"] += now_ns - t_ns
+            t_ns = now_ns

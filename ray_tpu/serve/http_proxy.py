@@ -19,7 +19,7 @@ from typing import Optional
 from urllib.parse import parse_qs, urlparse
 
 
-from ..util import tracing
+from ..util import flight, tracing
 from ._common import response_bytes as _as_bytes
 
 
@@ -61,7 +61,7 @@ class HTTPProxy:
                 # `/api/traces?trace_id=<x-request-id>` shows the whole path.
                 rid = tracing.new_trace_id()
                 self.request_id = rid
-                t0 = time.time()
+                t0_ns = flight.now_ns()
                 status = 500
                 try:
                     tracing.set_trace_id(rid)
@@ -71,12 +71,11 @@ class HTTPProxy:
                     status, _ = self._serve_traced()
                 finally:
                     try:
-                        tracing.record_span(
-                            "proxy.request", t0, time.time() - t0,
-                            trace_id=rid,
+                        flight.record(
+                            "proxy.request", t0_ns, flight.now_ns(),
+                            trace=rid, lane="serve/proxy",
                             attrs={"method": self.command, "path": self.path,
-                                   "status": status, "request_id": rid},
-                        )
+                                   "status": status, "request_id": rid})
                         tracing.set_trace_id(None)
                     except Exception:  # noqa: BLE001
                         pass

@@ -8,12 +8,11 @@ Batched methods receive the router-formed list in one call.
 
 from __future__ import annotations
 
-import time
 from typing import Any, Dict, List, Optional, Tuple
 
 import cloudpickle
 
-from ..util import tracing
+from ..util import flight, tracing
 from .context import (
     ReplicaContext,
     _set_multiplexed_model_id,
@@ -72,19 +71,17 @@ class Replica:
         _set_request_id(rid)
         return rid
 
-    def _record_span(self, name: str, rid: str, method: str, t0: float):
+    def _record(self, name: str, rid: str, method: str, t0_ns: int):
+        """One flight span of a traced request's stay in this replica: an
+        append to the ring, no message on the request's thread."""
         if not rid:
             return  # untraced call (no request context) — keep timeline lean
-        try:
-            tracing.record_span(
-                name, t0, time.time() - t0, trace_id=rid,
-                attrs={"app": self._ctx.app_name,
-                       "deployment": self._ctx.deployment,
-                       "replica": self._ctx.replica_tag,
-                       "method": method, "request_id": rid},
-            )
-        except Exception:  # noqa: BLE001 — tracing is never load-bearing
-            pass
+        flight.record(
+            name, t0_ns, flight.now_ns(), trace=rid, lane="serve/replica",
+            attrs={"app": self._ctx.app_name,
+                   "deployment": self._ctx.deployment,
+                   "replica": self._ctx.replica_tag,
+                   "method": method, "request_id": rid})
 
     def handle_request(
         self,
@@ -97,13 +94,13 @@ class Replica:
         _set_multiplexed_model_id(multiplexed_model_id)
         rid = self._enter_request()
         self._num_processed += 1
-        t0 = time.time()
+        t0_ns = flight.now_ns()
         try:
             if self._is_function:
                 return self._callable(*args, **kwargs)
             return getattr(self._callable, method)(*args, **kwargs)
         finally:
-            self._record_span("replica.handle", rid, method, t0)
+            self._record("replica.handle", rid, method, t0_ns)
 
     def handle_request_streaming(
         self,
@@ -120,7 +117,7 @@ class Replica:
         rid = self._enter_request()
         self._num_processed += 1
         fn = self._callable if self._is_function else getattr(self._callable, method)
-        t0 = time.time()
+        t0_ns = flight.now_ns()
         out = fn(*args, **kwargs)
         import inspect
 
@@ -132,7 +129,7 @@ class Replica:
             yield from out
         finally:
             # Span covers the full drain — the generator body runs lazily.
-            self._record_span("replica.handle_stream", rid, method, t0)
+            self._record("replica.handle_stream", rid, method, t0_ns)
 
     def handle_batch(
         self,

@@ -1,12 +1,12 @@
 """Cluster flight recorder: a lock-light per-process ring of spans.
 
 Reference analog: TorchTitan's flight recorder + Ray's Dapper-style
-timeline. The tracing plane (util/tracing.py) ships *wall-clock* span
-events straight into the controller timeline; that is fine for
-request-scale spans (milliseconds and up) but useless for the hot paths
-we now claim numbers for — engine decode steps, 1F1B microbatch slots,
-bulk span pulls — where shipping an RPC per span would dwarf the thing
-being measured. The flight recorder closes that gap:
+timeline. The ONE way a span of this program reaches the controller
+timeline (util/tracing.py assembles and exports what arrives there): a
+message per span would dwarf the hot paths we claim numbers for — engine
+decode steps, 1F1B microbatch slots, bulk span pulls — and on a request's
+own thread it makes a traced run differ from the measured one, so
+request-scale spans (proxy, replica, engine requests) take the ring too:
 
 * ``record()`` is a bounded, lock-guarded list append of a small dict —
   no RPC, no allocation beyond the event itself. Timestamps are
@@ -30,10 +30,10 @@ being measured. The flight recorder closes that gap:
   (``set_clock_offset``), so ``wall()`` maps monotonic-ns into the
   *controller's* clock before spans ever leave the process.
 
-Span events drained here are shaped exactly like ``tracing.span_event``
-output (``event == "span"``) with ``args.lane`` marking them as flight
-spans, so they merge into ``trace_forest`` / ``/api/traces`` for free;
-``merged_chrome_trace`` additionally renders one Perfetto lane per
+Span events drained here are the timeline's free spans (``event ==
+"span"`` with ``ts``, ``name``, ``dur``, ``trace``, ``args``), ``args.lane``
+marking them as flight spans, so they merge into ``trace_forest`` /
+``/api/traces`` for free; ``merged_chrome_trace`` additionally renders one Perfetto lane per
 ``lane`` key with flow arrows along each ``flow`` key (microbatches,
 disagg handoffs) using the same crc32-stable ids as ``api.timeline``.
 """
@@ -601,14 +601,23 @@ def ingest_report(events: List[dict]) -> Optional[dict]:
 #                     the host phases of the step in nanoseconds
 #                     (waited_ns | sched/side/build/dispatch/fetch/sample_ns |
 #                     export_ns) and queue_depth/running/kv_util at its end
+#   engine.stall    — one per SLOW step (the host's part of its span, the
+#                     span less fetch_ns, over 4 x the running mean of that
+#                     part: `engine.py` `_book_step`), same lane and span:
+#                     mean_ns, the six phases inside the span, gc_ns inside
+#                     it, bucket (the decode program's), queue_depth, running;
+#                     `serve_report` lists them, `ray-tpu flight` prints them
 #   engine.queue_wait|admission|prefill|first_token|completion — one set per
 #                     traced request, lane ``serve/engine-<role>/requests``
+#   replica.handle|handle_stream — a traced request's stay in its replica
+#                     (lane ``serve/replica``); proxy.request — in the HTTP
+#                     proxy (lane ``serve/proxy``)
 #   serve.handle    — the caller's side of one traced handle call (lane
 #                     ``serve/handle``): start = the call, end = its last
 #                     chunk; attrs method/replica/pick_ns/submit_ns/chunks
 #                     and first_chunk_ts (controller clock)
 SERVE_STEP_PHASES = ("sched_ns", "side_ns", "build_ns", "dispatch_ns",
-                     "fetch_ns", "sample_ns", "export_ns")
+                     "fetch_ns", "sample_ns", "export_ns")  # the last: after the span
 
 
 def _mean(xs: List[float]) -> Optional[float]:
@@ -629,8 +638,12 @@ def serve_report(events: List[dict]) -> Optional[dict]:
     prefill, delivery (first token emitted to first chunk at the caller),
     and ``ttft_unattributed_share`` = the part of the mean call-to-first-
     chunk time the four do not explain (0 when the spans close the sum).
+    ``stalls`` names each slow step (``engine.stall``, written untraced
+    too): when, how long its host part ran against the mean, the phase that
+    took most of it, the GC inside it and the load.
     Returns None when no serving spans are present."""
     steps: List[dict] = []
+    stalls: List[dict] = []
     by_trace: Dict[str, Dict[str, dict]] = {}
     for ev in events:
         if ev.get("event") != "span":
@@ -638,13 +651,28 @@ def serve_report(events: List[dict]) -> Optional[dict]:
         name = ev.get("name", "")
         if name == "engine.step":
             steps.append(ev)
+        elif name == "engine.stall":
+            stalls.append(ev)
         elif ev.get("trace") and name in (
                 "serve.handle", "engine.queue_wait", "engine.prefill",
                 "engine.first_token"):
             by_trace.setdefault(ev["trace"], {}).setdefault(name, ev)
-    if not steps and not by_trace:
+    if not steps and not stalls and not by_trace:
         return None
-    out: Dict[str, Any] = {"steps": len(steps)}
+    out: Dict[str, Any] = {"steps": len(steps), "stalls": []}
+    first = min((e["ts"] for e in steps + stalls), default=0.0)
+    for ev in sorted(stalls, key=lambda e: e["ts"]):
+        a = ev.get("args") or {}
+        host = {k: a.get(k, 0) for k in SERVE_STEP_PHASES[:-1]    # in the span
+                if k != "fetch_ns"}
+        worst = max(host, key=host.get)
+        out["stalls"].append({
+            "at_s": ev["ts"] - first,
+            "host_ms": 1e3 * ev.get("dur", 0.0) - 1e-6 * a.get("fetch_ns", 0),
+            "mean_ms": 1e-6 * a.get("mean_ns", 0),
+            "phase": worst[:-3], "phase_ms": 1e-6 * host[worst],
+            "gc_ms": 1e-6 * a.get("gc_ns", 0),
+            **{k: a.get(k) for k in ("bucket", "queue_depth", "running")}})
     if steps:
         args = [e.get("args") or {} for e in steps]
         t0 = min(e["ts"] for e in steps)

@@ -12,8 +12,10 @@ event kinds feed it:
   recorded by the controller and by workers' batched task_events channel;
 * ``task_phase`` events (dep-fetch, deserialize, execute, store-result)
   recorded by executing workers per task;
-* free ``span`` events (``record_span``) from anywhere in the cluster —
-  the Serve plane records proxy/replica/engine request spans this way.
+* free ``span`` events from anywhere in the cluster, which reach the
+  timeline through the flight ring (``util/flight.py``: ``flight.record``
+  appends, the ring's flusher ships batches with ``record_events``) — the
+  Serve plane's proxy, replica and engine request spans.
 
 This module assembles the forest (`trace_forest`, keyed by trace_id) and
 emits Perfetto/chrome://tracing JSON with DETERMINISTIC lane and flow ids
@@ -55,30 +57,11 @@ def new_trace_id() -> str:
     return uuid.uuid4().hex[:16]
 
 
-def span_event(
-    name: str,
-    start: float,
-    dur: float,
-    trace_id: Optional[str] = None,
-    task: Optional[str] = None,
-    attrs: Optional[Dict[str, Any]] = None,
-) -> Dict[str, Any]:
-    """Build a span timeline event (wall-clock `start`, seconds `dur`)."""
-    ev: Dict[str, Any] = {
-        "ts": float(start), "event": "span", "name": name,
-        "dur": max(float(dur), 0.0),
-        "trace": trace_id or get_trace_id(),
-    }
-    if task:
-        ev["task"] = task
-    if attrs:
-        ev["args"] = dict(attrs)
-    return ev
-
-
 def record_events(events: List[Dict[str, Any]]) -> None:
-    """Ship span events (see span_event) into the controller timeline as ONE
-    control-plane message. No-op without a connected cluster backend."""
+    """Ship span events (dicts as `flight.FlightRecorder.record` builds them:
+    `ts`, `event: "span"`, `name`, `dur`, `trace`, `args`) into the controller
+    timeline as ONE control-plane message. No-op without a connected cluster
+    backend."""
     if not events:
         return
     from ..core import api
@@ -89,18 +72,6 @@ def record_events(events: List[Dict[str, Any]]) -> None:
     send = getattr(rt.backend, "record_trace_event", None)
     if send is not None:
         send(events)
-
-
-def record_span(
-    name: str,
-    start: float,
-    dur: float,
-    trace_id: Optional[str] = None,
-    task: Optional[str] = None,
-    attrs: Optional[Dict[str, Any]] = None,
-) -> None:
-    """Ship one span event into the controller timeline."""
-    record_events([span_event(name, start, dur, trace_id, task, attrs)])
 
 
 # ----------------------------------------------------------- span assembly

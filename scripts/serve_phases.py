@@ -1,6 +1,6 @@
 """Where a serving cell's host time goes, from the program's own spans:
 `python3 -m scripts.serve_phases --workload gpt2-large.chat --seed <n>
---profile <off|annotations|frames>` from the root of a checkout.
+--profile <none|off|annotations|frames>` from the root of a checkout.
 
 One run of a serving cell of the benchmark, through the benchmark's own
 runner and traffic (`benchmarks/runners/serve.py`, untouched), with a trace
@@ -13,11 +13,17 @@ the cell's end-to-end metrics, its existing per-layer metrics, `serve`
 `idle_named_share`: the share of the device's idle time in the traced
 window that is named by the engine's own `engine.*` annotations.
 
-`--profile` is the profiler session on the replica: `off` (trace ids only:
-what tracing costs when it is on, against `benchmarks.run --trace 0`),
-`annotations` (Python tracer off: gaps are named by the engine's phases),
-`frames` (the benchmark's own setting, Python tracer on: inside a phase the
-innermost frame wins the gap's name, so this is the ledger's view).
+`--profile` is the profiler session on the replica: `none` (no trace id
+and no profiler: the MEASURED course, as `benchmarks.run --trace 0` runs
+it, with the per-layer metrics that need no span beside the end-to-end
+ones: the engine's books and the other `engine_stats()` counters, as the
+window's deltas in `counters`; `serve.stalls` names the window's slow
+steps from their `engine.stall` spans, read from the timeline after the
+window), `off` (trace ids only: what tracing costs when it is on, against
+`none`), `annotations` (Python tracer off: gaps are
+named by the engine's phases), `frames` (the benchmark's own setting,
+Python tracer on: inside a phase the innermost frame wins the gap's name,
+so this is the ledger's view).
 
 This is a builder's tool, not the yardstick: `BENCHMARK.json` reads none of
 it. PERF.md §7 lists the edits to `benchmarks/` that would make these
@@ -80,7 +86,7 @@ def main(argv=None) -> int:
     ap.add_argument("--workload", required=True)
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--seconds", type=float, default=None)
-    ap.add_argument("--profile", choices=("off", "annotations", "frames"),
+    ap.add_argument("--profile", choices=("none", "off", "annotations", "frames"),
                     default="annotations")
     ap.add_argument("--rehearse", action="store_true")
     args = ap.parse_args(argv)
@@ -96,14 +102,18 @@ def main(argv=None) -> int:
     t0_wall = time.time()
     os.makedirs(harness.OUT, exist_ok=True)
     runtime = harness.Runtime(chips)
-    serve_runner.BenchReplica = replica_class(args.profile)
-    ctx = dict(loaded, seed=args.seed, seconds=seconds, trace=True,
+    traced = args.profile != "none"
+    if traced:
+        serve_runner.BenchReplica = replica_class(args.profile)
+    ctx = dict(loaded, seed=args.seed, seconds=seconds, trace=traced,
                rehearse=args.rehearse, t0_wall=t0_wall, sweep=None)
     try:
         obs = serve_runner.run(ctx)
         import ray_tpu
         from ray_tpu.util import flight
 
+        # after the window, whatever the profile: the step records and the
+        # `engine.stall` spans are written without a trace id too
         flight.flush()                       # this process's serve.handle spans
         time.sleep(1.0)
         w0 = t0_wall + obs["phases"]["setup_s"]
@@ -128,8 +138,8 @@ def main(argv=None) -> int:
     line = {"workload": cell["name"], "seed": args.seed, "profile": args.profile,
             "device": obs["device"], "attempted": obs["attempted"],
             "failed": obs["failed"], "checks": obs["checks"],
-            "metrics": metrics, "spans": len(spans),
-            "serve": flight.serve_report(spans)}
+            "metrics": metrics, "counters": obs["counters"],
+            "spans": len(spans), "serve": flight.serve_report(spans)}
     tr = obs.get("trace")
     if tr:
         gaps = tr["breakdown"]["idle_gaps"]

@@ -291,7 +291,7 @@ def test_merged_chrome_trace_lanes_flows_metadata():
 
 
 def test_flight_spans_merge_into_trace_forest():
-    """args.lane spans are shaped like tracing.span_event output, so a
+    """args.lane spans are the timeline's free span events, so a
     traced flight span joins the request's forest for free."""
     events = [
         _span("disagg.prefill_handoff", 10.0, 0.5, lane="serve/router",
@@ -302,6 +302,108 @@ def test_flight_spans_merge_into_trace_forest():
     assert t is not None
     assert {s["name"] for s in t["spans"]} == {"disagg.prefill_handoff",
                                                "kv.export"}
+
+
+def test_replica_spans_ride_the_ring_into_the_trace_forest(monkeypatch):
+    """A traced request's `replica.handle` / `replica.handle_stream` spans
+    are appends to the flight ring (the second span system,
+    `tracing.record_span`, one control-plane send a request on the
+    request's own thread, is gone): the request's thread sends nothing,
+    the drained events join the request's forest with the spans the ring
+    already carried, and an untraced call records none."""
+    import types
+
+    import cloudpickle
+
+    from ray_tpu.core import api
+    from ray_tpu.serve.replica import Replica
+
+    class Echo:
+        def stream(self, n):
+            yield from range(n)
+
+        def unary(self):
+            return "ok"
+
+    sends = []
+    rt = types.SimpleNamespace(
+        backend=types.SimpleNamespace(record_trace_event=sends.append),
+        _context=types.SimpleNamespace(trace_id="req-7"))
+    monkeypatch.setenv("RAY_TPU_FLIGHT", "1")
+    monkeypatch.setenv("RAY_TPU_FLIGHT_FLUSH_S", "3600")
+    monkeypatch.setattr(api, "_runtime_or_attach", lambda: rt)
+    flight._reset_for_tests()
+    assert not hasattr(tracing, "record_span")
+    rep = Replica("app", "dep", "dep#0", cloudpickle.dumps(Echo),
+                  cloudpickle.dumps(((), {})))
+    assert list(rep.handle_request_streaming("stream", (3,), {})) == [0, 1, 2]
+    assert rep.handle_request("unary", (), {}) == "ok"
+    rt._context.trace_id = None
+    assert rep.handle_request("unary", (), {}) == "ok"      # untraced
+    assert not sends
+    events = flight.recorder().drain() + [
+        _span("engine.completion", flight.cluster_time(), 0.1,
+              lane="serve/engine-mixed/requests", trace="req-7")]
+    flight._reset_for_tests()
+    tree = tracing.trace_forest(events)["req-7"]
+    assert [s["name"] for s in tree["spans"]] == [
+        "replica.handle_stream", "replica.handle", "engine.completion"]
+    for ev in tree["spans"][:2]:
+        a = ev["args"]
+        assert a["lane"] == "serve/replica" and a["request_id"] == "req-7"
+        assert (a["app"], a["deployment"], a["replica"]) == ("app", "dep", "dep#0")
+        assert ev["dur"] >= 0 and tree["start"] <= ev["ts"] <= tree["end"]
+    assert tree["spans"][0]["args"]["method"] == "stream"
+
+
+# The metric files that read the engine's books (ISSUE 37), each against
+# counters of a window it can be worked out from by hand.
+_BOOKS_OBS = {"counters": {
+    "steps": 1000, "steps_chunk": 100, "steps_decode": 800,
+    "steps_decode_only": 700, "step_ns": 20_000_000_000,
+    "step_chunk_ns": 7_000_000_000, "step_decode_only_ns": 8_400_000_000,
+    "host_ns": 3_000_000_000, "slow_ns": 250_000_000, "gc_ns": 40_000_000,
+    "waited_ns": 9_000_000_000, "loop_ns": 45_000_000_000,
+    "decode_lanes": 2400, "decode_bucket_lanes": 3200,
+    "decode_lanes_beside_chunk": 600, "prefill_tokens": 30_000,
+    "prefill_tokens_padded": 40_000, "stream_tokens": 2000,
+    "stream_wake_ns": 300_000_000, "stream_send_ns": 1_000_000_000,
+    "stream_behind": 50, "sched_ns": 100_000_000, "side_ns": 50_000_000,
+    "build_ns": 1_500_000_000, "dispatch_ns": 900_000_000,
+    "fetch_ns": 17_000_000_000, "sample_ns": 450_000_000,
+    "export_ns": 2_000_000_000, "steps_slow": 3, "gc_collections": 7}}
+
+
+@pytest.mark.parametrize("name, want", [
+    ("chunk_step_ms", 70.0), ("decode_only_step_ms", 12.0),
+    ("step_host_ms", 3.0), ("step_host_ms.sat", 3.0), ("stall_ms", 250.0),
+    ("gc_pause_ms", 40.0), ("gc_pause_ms.sat", 40.0),
+    ("engine_step_ms_books", 20.0), ("engine_wait_share_books", 20.0),
+    ("gap_chunk_share", 25.0), ("decode_bucket_mean", 4.0),
+    ("decode_bucket_fill_share", 75.0), ("decode_bucket_fill_share.sat", 75.0),
+    ("prefill_token_fill_share", 75.0), ("stream_wake_ms", 0.15),
+    ("stream_send_ms", 0.5), ("stream_send_ms.sat", 0.5),
+    ("stream_behind_share", 2.5), ("stream_behind_share.sat", 2.5),
+    ("step_sched_ms", 0.1), ("step_side_ms", 0.05), ("step_build_ms_books", 1.5),
+    ("step_dispatch_ms", 0.9), ("step_fetch_ms_books", 17.0),
+    ("step_sample_ms", 0.45), ("step_export_ms_books", 2.0),
+    ("stall_steps", 3.0), ("gc_collections", 7.0),
+])
+def test_metric_files_read_the_books(name, want):
+    """Every per-layer metric of the books is a `BENCHMARK.json` entry with
+    a parameter-only reader file: it reads the window's counters, gives
+    nothing (the metric is left out) for a program without the counter, as
+    the parent is, and a window's total of none reads 0."""
+    from benchmarks import harness, readers
+
+    entry = next(m for m in harness.benchmark()["per_layer"] if m["name"] == name)
+    assert entry["source"] == "program_counter" and entry["workloads"]
+    assert readers.reader_spec(name)["kind"] in ("counter", "counter_ratio")
+    assert readers.read(name, _BOOKS_OBS) == pytest.approx(want, rel=1e-12)
+    assert readers.read(name, {"counters": {"total_tokens": 5}}) is None
+    if readers.reader_spec(name)["kind"] == "counter":
+        quiet = {"counters": dict.fromkeys(_BOOKS_OBS["counters"], 0)}
+        assert readers.read(name, quiet) == 0.0
 
 
 # ------------------------------------------------------- phases of a loop
@@ -414,6 +516,48 @@ def test_serve_report_phases_and_ttft_closure():
     assert abs(flight.serve_report(late)["ttft_unattributed_share"] - 10.0) < 1e-6
     assert flight.serve_report(_two_lane_step(1, 100.0)) is None
     assert flight.flight_payload(events)["serve"]["requests"] == 2
+
+
+def test_stalls_are_named_by_serve_report_and_printed_by_the_cli(capsys):
+    """An `engine.stall` span (written for a slow step, traced or not) is
+    listed by `serve_report` with when it was, the host's part of its span
+    against the mean, the phase that took most of it, the GC inside it and
+    the load, and `ray-tpu flight` prints one line for it: a stall of a
+    run nobody traced is named from the timeline alone, even where its step
+    record is gone."""
+    import argparse
+
+    from ray_tpu.scripts import cli
+
+    phases = {**dict.fromkeys(flight.SERVE_STEP_PHASES[:-1], 0),
+              "build_ns": 114_000_000, "sched_ns": 1_000_000,
+              "fetch_ns": 9_000_000}
+    events = [
+        _serve_step(100.0, 0.010), _serve_step(100.5, 0.125, **phases),
+        _span("engine.stall", 100.5, 0.125, lane="serve/engine-mixed",
+              mean_ns=1_600_000, gc_ns=2_000_000, bucket=4, queue_depth=0,
+              running=3, **phases)]
+    rep = flight.serve_report(events)
+    assert rep["steps"] == 2 and len(rep["stalls"]) == 1
+    stall = rep["stalls"][0]
+    assert stall["at_s"] == pytest.approx(0.5)
+    assert stall["host_ms"] == pytest.approx(116.0)     # the span less fetch
+    assert stall["mean_ms"] == pytest.approx(1.6)
+    assert (stall["phase"], stall["phase_ms"]) == ("build", pytest.approx(114.0))
+    assert stall["gc_ms"] == pytest.approx(2.0)
+    assert (stall["bucket"], stall["queue_depth"], stall["running"]) == (4, 0, 3)
+    assert flight.serve_report(events[:2])["stalls"] == []
+    assert len(flight.serve_report(events[2:])["stalls"]) == 1   # alone on the ring
+
+    class Backend:
+        def _request(self, msg):
+            return {"timeline": events}
+
+    cli.cmd_flight(Backend(), None, argparse.Namespace(
+        wait=0.0, trace_id=None, output=None))
+    printed = capsys.readouterr().out
+    assert "stall at +0.50s: host 116.0 ms against a mean of 1.60, build 114.0" in printed
+    assert "bucket 4 running 3 queued 0" in printed
 
 
 # ------------------------------------------- one export path, two surfaces

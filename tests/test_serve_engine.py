@@ -1791,3 +1791,259 @@ class TestEnginePhases:
         for n, s, e in events:
             if n != "engine.step":
                 assert any(s0 <= s and e <= e0 for s0, e0 in steps), n
+
+
+# ------------------------------------------------------------------ books
+COUNT_BOOKS = ("steps", "steps_chunk", "steps_decode", "steps_decode_only",
+               "decode_lanes", "decode_bucket_lanes",
+               "decode_lanes_beside_chunk", "prefill_tokens",
+               "prefill_tokens_padded")
+STREAM_BOOKS = ("stream_tokens", "stream_wake_ns", "stream_send_ns",
+                "stream_behind")
+
+
+def _booked_run(cfg, params):
+    """Six prompts of 21 tokens in chunks of 8 (the last one 5, padded to
+    8) over four lanes, stepped by hand: the same course of steps whatever
+    the recorder does. Returns the engine's stats."""
+    eng = _make_engine(cfg, params, prefill_chunk_tokens=8)
+    rids = [eng.submit([1 + i] * 21, max_new_tokens=40) for i in range(6)]
+    _drive(eng)
+    assert all(len(list(eng.stream(rid))) == 40 for rid in rids)
+    return eng.stats()
+
+
+class TestEngineBooks:
+    @pytest.fixture(scope="class")
+    def booked_counts(self, tiny_engine_parts):
+        """The count books of the run above, taken once (the first run
+        also compiles its programs, for the runs judged after it)."""
+        st = _booked_run(*tiny_engine_parts)
+        return {k: st[k] for k in COUNT_BOOKS}
+
+    @pytest.mark.parametrize("recorder", ["on", "off", "cap64"])
+    def test_books_are_kept_whatever_the_recorder_does(
+        self, tiny_engine_parts, booked_counts, ring, monkeypatch, recorder
+    ):
+        """The books are the step records' values summed where the records
+        are made: with the recorder on they equal the sums over the run's
+        `engine.step` records; with `RAY_TPU_FLIGHT=0` (no record) and
+        with a ring of 64 (the span sums fall short) they are the same
+        counts. The identities hold in each."""
+        from ray_tpu.util import flight
+
+        if recorder == "off":
+            monkeypatch.setenv("RAY_TPU_FLIGHT", "0")
+        elif recorder == "cap64":
+            monkeypatch.setenv("RAY_TPU_FLIGHT_CAP", "64")
+            flight._reset_for_tests()
+        st = _booked_run(*tiny_engine_parts)
+        books = ("steps_slow", "step_ns", "step_chunk_ns",
+                 "step_decode_only_ns", "host_ns", "slow_ns", "loop_ns",
+                 "waited_ns", *flight.SERVE_STEP_PHASES, *COUNT_BOOKS,
+                 *STREAM_BOOKS, "gc_ns", "gc_collections")
+        assert all(type(st[k]) is int and st[k] >= 0 for k in books), st
+        assert {k: st[k] for k in COUNT_BOOKS} == booked_counts
+        assert st["steps"] > 64 and st["steps_chunk"] == 18
+        assert st["prefill_tokens"] == 6 * 21
+        assert st["prefill_tokens_padded"] == 6 * 24
+        assert st["stream_tokens"] == st["total_tokens"] == 6 * 40
+        # the identities
+        assert st["steps_chunk"] + st["steps_decode_only"] <= st["steps"]
+        assert st["steps_decode_only"] <= st["steps_decode"]
+        assert st["decode_lanes_beside_chunk"] <= st["decode_lanes"]
+        assert st["decode_lanes"] <= st["decode_bucket_lanes"] <= 4 * st["steps_decode"]
+        assert st["prefill_tokens"] <= st["prefill_tokens_padded"]
+        assert sum(st[k] for k in IN_SPAN) <= st["step_ns"]
+        assert st["host_ns"] == st["step_ns"] - st["fetch_ns"]
+        assert st["step_chunk_ns"] + st["step_decode_only_ns"] <= st["step_ns"]
+        assert st["loop_ns"] == st["waited_ns"] == 0    # stepped by hand
+        # against the records
+        steps = [e["args"] for e in ring("engine.step")]
+        assert len(steps) == {"on": st["steps"], "off": 0, "cap64": 64}[recorder]
+        sums = {
+            "steps": len(steps),
+            "steps_chunk": sum(1 for a in steps if a["prefills"]),
+            "steps_decode": sum(1 for a in steps if a["decodes"]),
+            "steps_decode_only": sum(
+                1 for a in steps if a["decodes"] and not a["prefills"]),
+            "decode_lanes": sum(a["decodes"] for a in steps),
+            "decode_lanes_beside_chunk": sum(
+                a["decodes"] for a in steps if a["prefills"]),
+            **{k: sum(a[k] for a in steps)
+               for k in ("waited_ns", *flight.SERVE_STEP_PHASES)},
+        }
+        if recorder == "on":
+            assert sums == {k: st[k] for k in sums}
+            spans = 1e9 * sum(e["dur"] for e in ring("engine.step"))
+            assert abs(spans - st["step_ns"]) < 1e-6 * st["step_ns"]
+        elif recorder == "cap64":
+            assert all(sums[k] < st[k] for k in ("steps", "decode_lanes", "build_ns"))
+
+    def test_stream_tokens_are_what_eight_concurrent_consumers_took(
+        self, tiny_engine_parts
+    ):
+        """Eight consumer threads drain their streams at once, under a
+        short switch interval, while a ninth reads `stats()`: the delivery
+        books lose no token (`stream_tokens` is what the consumers took,
+        one of them having walked away early, one having stopped and come
+        back), a reading never goes back, and the loop's time covers its
+        waits and its steps."""
+        import sys
+
+        cfg, params = tiny_engine_parts
+        eng = _make_engine(cfg, params, prefill_chunk_tokens=8)
+        took, readings, errors = [0] * 8, [], []
+
+        def consume(i):
+            try:
+                out = eng.stream(eng.submit([1 + i] * 21, max_new_tokens=40))
+                for _tok in out:
+                    took[i] += 1
+                    if i < 2 and took[i] == 5:
+                        break               # the generator is closed here
+                if i == 1:                  # and the second comes back for
+                    took[i] += len(list(out))   # the rest: counted once
+            except Exception as e:  # noqa: BLE001 — reported below
+                errors.append(e)
+
+        def read():
+            while any(t.is_alive() for t in threads):
+                readings.append(eng.stats()["stream_tokens"])
+                time.sleep(0.002)
+
+        threads = [threading.Thread(target=consume, args=(i,)) for i in range(8)]
+        reader = threading.Thread(target=read)
+        interval = sys.getswitchinterval()
+        sys.setswitchinterval(1e-5)
+        try:
+            eng.start()
+            for t in threads + [reader]:
+                t.start()
+            for t in threads + [reader]:
+                t.join(timeout=120)
+            assert not any(t.is_alive() for t in threads + [reader])
+        finally:
+            sys.setswitchinterval(interval)
+            eng.shutdown()
+        assert not errors, errors
+        assert took == [5] + [40] * 7
+        st = eng.stats()
+        assert st["stream_tokens"] == sum(took)
+        assert st["total_tokens"] >= 5 + 7 * 40
+        assert readings == sorted(readings) and readings[-1] <= sum(took)
+        assert 0 <= st["stream_behind"] <= st["stream_tokens"]
+        assert st["stream_wake_ns"] > 0 and st["stream_send_ns"] > 0
+        assert not eng._delivery._open      # every stream folded its sums
+        assert 0 < st["waited_ns"] + st["step_ns"] + st["export_ns"] <= st["loop_ns"]
+
+    def test_a_slow_host_part_is_booked_and_leaves_one_stall_span(
+        self, tiny_engine_parts, ring
+    ):
+        """`_book_step` on spans made by hand: a step is judged by the
+        host's part of its span (the span less `fetch_ns`) against ONE
+        running mean, from the 65th step on. A compile among the first
+        steps and a long wait for the device are no stall; 122 ms of host
+        against a mean of 2 is the one stall: `steps_slow` 1, `slow_ns` its
+        excess over the mean, ONE `engine.stall` span over the step's own
+        span with its phases, the GC inside it, the bucket and the load;
+        the stall moves the mean as a step at the edge of slow would."""
+        from ray_tpu.serve.engine.scheduler import SchedulerOutput
+        from ray_tpu.util import flight
+
+        eng = _make_engine(*tiny_engine_parts)
+        ms = 1_000_000
+        load = {"bucket": 4, "queue_depth": 2, "running": 3, "gc_ns": 0}
+        chunk = SchedulerOutput([object()], [1, 2, 3], [], 4, 8)
+        plain = SchedulerOutput([], [1, 2, 3], [], 4, 8)
+
+        def book(out, host_ms, fetch_ms, **extra):
+            times = {"waited_ns": 0, **dict.fromkeys(flight.SERVE_STEP_PHASES, 0),
+                     "build_ns": host_ms * ms, "fetch_ns": fetch_ms * ms}
+            eng._book_step(out, 5 * ms, (5 + host_ms + fetch_ms) * ms, times,
+                           {**load, **extra})
+
+        book(plain, 130, 10)                # a compile: the mean is young
+        for _ in range(63):
+            book(plain, 0, 10)
+        assert eng._host_mean[0] == 64
+        assert eng._host_mean[1] == pytest.approx(130 * ms / 64, rel=1e-4)
+        for _ in range(8):                  # chunks run 70 ms on the device
+            book(chunk, 2, 70)
+        book(plain, 2, 498)                 # the device kept it waiting
+        assert eng.stats()["steps_slow"] == 0 and not ring("engine.stall")
+        eng._host_mean[1] = 2 * ms
+        book(plain, 122, 10, gc_ns=3 * ms)
+        st = eng.stats()
+        assert (st["steps"], st["steps_chunk"], st["steps_decode_only"]) == (74, 8, 66)
+        assert st["step_chunk_ns"] == 8 * 72 * ms
+        assert st["host_ns"] == st["step_ns"] - st["fetch_ns"] == (130 + 18 + 122) * ms
+        assert st["steps_slow"] == 1 and st["slow_ns"] == 120 * ms
+        assert eng._host_mean == [74, 2 * ms + 6 * ms // 64]
+        (stall,) = ring("engine.stall")
+        a = stall["args"]
+        assert stall["dur"] == pytest.approx(0.132) and a["lane"] == "serve/engine-mixed"
+        assert (a["mean_ns"], a["build_ns"], a["fetch_ns"]) == (2 * ms, 122 * ms, 10 * ms)
+        assert set(a) == {"lane", "mean_ns", "gc_ns", "bucket", "queue_depth",
+                          "running", *flight.SERVE_STEP_PHASES[:-1]}
+        assert (a["gc_ns"], a["bucket"], a["queue_depth"], a["running"]) == (3 * ms, 4, 2, 3)
+        (named,) = flight.serve_report(ring("engine."))["stalls"]
+        assert named["phase"] == "build" and named["host_ms"] == pytest.approx(122.0)
+
+    def test_an_injected_sleep_in_one_step_leaves_its_stall_span(
+        self, tiny_engine_parts, ring, monkeypatch
+    ):
+        """Seventy decode-only steps with 10 ms of sleep in each (so that a
+        loaded machine's few milliseconds are no stall), one of them 0.4 s
+        longer: the engine books it and writes its `engine.stall` span over
+        that step's own span, with the decode bucket and the load. (Another
+        stall is the machine's, not the engine's: the test finds its own by
+        its length.)"""
+        cfg, params = tiny_engine_parts
+        warm = _make_engine(cfg, params)
+        warm.submit([3] * 6, max_new_tokens=3)
+        _drive(warm)                            # the programs are compiled
+        eng = _make_engine(cfg, params)
+        run_decode, n = eng._run_decode, [0]
+
+        def slowed(out):
+            n[0] += 1
+            time.sleep(0.41 if n[0] == 68 else 0.01)
+            return run_decode(out)
+
+        monkeypatch.setattr(eng, "_run_decode", slowed)
+        eng.submit([3] * 6, max_new_tokens=75)
+        _drive(eng)
+        st = eng.stats()
+        assert st["steps_decode_only"] >= 70 and st["steps_slow"] >= 1
+        assert st["slow_ns"] > 0.35e9
+        (stall,) = [e for e in ring("engine.stall") if e["dur"] > 0.4]
+        a = stall["args"]
+        assert a["bucket"] == 1 and a["running"] == 1 and a["queue_depth"] == 0
+        assert 0.005e9 < a["mean_ns"] < 0.1e9 and a["gc_ns"] >= 0
+        twin = [e for e in ring("engine.step") if e["ts"] == stall["ts"]]
+        assert len(twin) == 1 and twin[0]["dur"] == stall["dur"]
+
+    def test_a_forced_collection_moves_gc_ns(self, tiny_engine_parts):
+        """The engines of a process share ONE `gc.callbacks` hook, there
+        while any of them runs: a forced collection adds its pause to
+        `gc_ns`, and `shutdown` (twice: once counts) gives the hook up."""
+        import gc
+
+        from ray_tpu.serve.engine import engine as engine_mod
+
+        cfg, params = tiny_engine_parts
+        pauses = engine_mod._GC
+        held = pauses._engines
+        eng = _make_engine(cfg, params)
+        assert pauses._engines == held + 1
+        assert gc.callbacks.count(pauses._hook) == 1
+        before = eng.stats()
+        gc.collect()
+        after = eng.stats()
+        assert after["gc_collections"] > before["gc_collections"]
+        assert after["gc_ns"] > before["gc_ns"]
+        eng.shutdown()
+        eng.shutdown()
+        assert pauses._engines == held
+        assert gc.callbacks.count(pauses._hook) == (1 if held else 0)

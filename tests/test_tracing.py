@@ -251,6 +251,9 @@ def test_serve_request_trace_end_to_end(cluster_runtime):
                 break
             time.sleep(0.3)
         assert want <= names, f"missing spans: {want - names}"
+        lanes = {e["name"]: e["args"]["lane"] for e in spans}
+        assert lanes["proxy.request"] == "serve/proxy"      # the flight ring
+        assert lanes["replica.handle"] == "serve/replica"
 
         # Dashboard surfaces the same trace.
         # this test's own session: under xdist `session_latest` may be the
@@ -372,6 +375,11 @@ def test_serve_handle_trace_covers_caller_to_delivery(cluster_runtime):
                 break
             time.sleep(0.3)
         assert want <= set(mine), f"missing spans: {want - set(mine)}"
+        # the replica's span came over the flight ring, and `ray-tpu trace
+        # <id>` (trace_payload) shows the path from caller to completion
+        assert mine["replica.handle_stream"]["args"]["lane"] == "serve/replica"
+        shown = tracing.trace_payload(ray_tpu.timeline(), trace_id=tid)["trace"]
+        assert want <= {s["name"] for s in shown["spans"]}
         h, first = mine["serve.handle"], mine["engine.first_token"]
         a = h["args"]
         assert a["method"] == "generate_stream" and a["chunks"] == 5
