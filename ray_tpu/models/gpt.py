@@ -832,10 +832,14 @@ def _dropless_mlp(cfg: GPTConfig, router, experts, router_in, mlp_in,
     w_out), this layer's or with `layer` the whole stacks. A program that
     holds a range of the experts (`cfg.moe_held`) routes over all of them and
     keeps the combine matrix's columns of its own: what an absent expert
-    would add is left out. Returns (y, [2] f32 = experts touched and the
-    busiest expert's share) of this layer's routing over the experts held,
-    tokens outside `valid` [B, S] left out of the count; under `moe_held`
-    [4]: also the tokens' assignments that fell on held experts, and all."""
+    would add is left out. The sum is the grouped form whatever the step's
+    size (`moe.dropless_experts`): its cost is the row tiles the routing
+    fills, and a token outside `valid` [B, S] (a padding lane, a chunk's
+    padding) is routed nowhere, so it fills none. Returns (y, [2] f32 =
+    experts touched and the busiest expert's share) of this layer's routing
+    over the experts held, the valid tokens'; under `moe_held` [5]: also
+    their assignments that fell on held experts, all of them, and 1 if none
+    fell here (an empty routing: no expert is read)."""
     from ..ops import moe
 
     B, S, E = mlp_in.shape
@@ -845,33 +849,14 @@ def _dropless_mlp(cfg: GPTConfig, router, experts, router_in, mlp_in,
     combine = moe.dropless_combine(idx, w, cfg.moe_experts)
     if cfg.moe_held:
         combine = combine[:, cfg.moe_held[0]: sum(cfg.moe_held)]
-    form = moe_form(cfg, B * S)
+    if valid is not None:   # a padding token's output is thrown away: it routes nowhere
+        valid = valid.reshape(B * S)
+        combine = jnp.where(valid[:, None], combine, 0.0)
     y = moe.dropless_experts(
         mlp_in.reshape(B * S, E), combine, *experts, cfg.activation, layer=layer,
-        touched_k=cfg.moe_top_k if form == "loop" else 0,
-        grouped_k=cfg.moe_top_k if form == "grouped" else 0)
-    load = moe.dropless_load(combine, None if valid is None else valid.reshape(B * S),
-                             cfg.moe_top_k if cfg.moe_held else 0)
+        grouped_k=cfg.moe_top_k)
+    load = moe.dropless_load(combine, valid, cfg.moe_top_k if cfg.moe_held else 0)
     return y.reshape(B, S, E), jnp.stack(load)
-
-
-def _few_tokens(cfg: GPTConfig, tokens: int) -> bool:
-    """Few tokens cannot reach every expert: such a step reads only the
-    chosen ones (`dropless_experts`' loop, at most as many trips as experts
-    are held). Of a token's top-k assignments the held range sees its share,
-    held / experts, so fewer land here than experts are held exactly when
-    tokens x top_k is under the ROUTER's width, whatever the range."""
-    return tokens * cfg.moe_top_k < cfg.moe_experts
-
-
-def moe_form(cfg: GPTConfig, tokens: int) -> str:
-    """Which schedule of the one expert sum (`ops/moe.py` `dropless_experts`)
-    a step of `tokens` tokens takes, from shapes alone: "loop" over the
-    chosen experts while `_few_tokens`; above it "grouped", the assignments
-    sorted by expert and only the row tiles they fill computed. The programs
-    take their choice from here and the engine counts with the same call
-    (`moe_tokens_grouped`)."""
-    return "loop" if _few_tokens(cfg, tokens) else "grouped"
 
 
 def _gated_mlp(cfg: GPTConfig, x, w_gate, w_in, w_out):
@@ -2003,7 +1988,7 @@ def _paged_layers(params, tokens, pos, valid, block_tables, kv, cfg: GPTConfig):
 
     Returns (hidden states [B, S, E] before the final norm -- after it for
     a looped model, kv, None or the mean over layers of (experts touched,
-    busiest expert's share) [2] f32 ([4] under `moe_held`: `_dropless_mlp`),
+    busiest expert's share) [2] f32 ([5] under `moe_held`: `_dropless_mlp`),
     None or a looped model's exit
     distribution [passes run] f32: `ut_exit_pdf` of the gates of the passes
     the pass scan ran, one entry a pass, mean over the real tokens)."""
@@ -2162,10 +2147,9 @@ def _paged_layers(params, tokens, pos, valid, block_tables, kv, cfg: GPTConfig):
                         None if kind is None else kind["window"])
         return attn.reshape(B, H, S, Dv) if R > 1 else attn, (kk, vv)
 
-    # A step reads only the experts its tokens chose (a loop over them, or
-    # the grouped tiles: `moe_form`): the expert stacks stay whole (a slice
-    # the scan cuts would be copied into the inner loop) and the layer number
-    # finds the expert where it lies.
+    # A step reads only the experts its tokens chose (the grouped tiles): the
+    # expert stacks stay whole (a slice the scan cuts would be a copy of every
+    # expert of the layer) and the layer number finds the expert where it lies.
     stacks = None
     if moe:
         stacks = tuple(layer_stack.pop(k) for k in

@@ -158,14 +158,13 @@ MOE_LOGICAL_DIMS = {
 # routing for any k with NO capacity, so no token is ever dropped, and gate
 # weights that are a softmax over the k kept logits (the same as a softmax
 # over all experts renormalised over the kept ones). Static shapes without a
-# capacity leave three schedules of the same sum (`dropless_experts`): every
+# capacity leave two schedules of the same sum (`dropless_experts`): every
 # expert applied to every token under an [N, X] combine matrix that is zero
-# where a token did not choose the expert (dense); a loop over the experts
-# that were chosen, for a decode step whose N*k assignments cannot reach
-# every expert (`touched_k`); and, for a step of many tokens, the assignments
-# sorted by expert and each expert's rows, padded to whole row tiles, put
-# through that expert once (`grouped`): as many tiles as the step's routing
-# fills, a run-time count.
+# where a token did not choose the expert (dense: the reference), and the
+# assignments sorted by expert and each expert's rows, padded to whole row
+# tiles, put through that expert once (`grouped`: every served step, of one
+# token or of a chunk): as many tiles as the step's routing fills, a run-time
+# count, and none at all for a routing that chose no expert held here.
 
 
 def dropless_route(logits, top_k: int, scoring: str = "softmax", scale: float = 1.0):
@@ -318,8 +317,9 @@ def _grouped_pallas(x, combine, w_gate, w_in, w_out, activation, layer,
       [N, block] f32 by the transposed one-hot product, the addend split into
       two bfloat16 terms so that the sum keeps float32's digits.
 
-    A routing that fills no tile still runs tile 0, whose rows are all
-    padding and add nothing. x [N, D], N at most `_GROUP_TOKENS`."""
+    A routing that fills no tile (every assignment on experts held elsewhere,
+    or every token padding) runs neither kernel: it fetches no block of any
+    expert and gives zeros. x [N, D], N at most `_GROUP_TOKENS`."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -339,7 +339,6 @@ def _grouped_pallas(x, combine, w_gate, w_in, w_out, activation, layer,
     slabs = jnp.pad(x, ((0, Np - N), (0, 0))).reshape(Np, nd, td).transpose(1, 0, 2)
     rows = jnp.pad(rows, ((0, Np - N), (0, 0)), constant_values=-1).T[:, None]
     weights = jnp.pad(combine, ((0, Np - N), (0, 0))).T[:, None]       # [X, 1, Np]
-    run = jnp.maximum(tiles, 1)
     scalars = (tile_expert, tile_first, jnp.asarray(layer, jnp.int32).reshape(1))
     params = pltpu.CompilerParams(
         dimension_semantics=("arbitrary", "arbitrary"), vmem_limit_bytes=64 << 20)
@@ -368,19 +367,6 @@ def _grouped_pallas(x, combine, w_gate, w_in, w_out, activation, layer,
         def _():
             h_ref[...] = _gated(activation, g_acc[...], u_acc[...]).astype(dt)
 
-    slab = pl.BlockSpec((None, None, td, F), lambda t, k, te, f, l: (l[0], te[t], k, 0))
-    h = pl.pallas_call(
-        hidden, out_shape=jax.ShapeDtypeStruct((T * TM, F), dt),
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=3, grid=(run, nd),
-            in_specs=[pl.BlockSpec((None, 1, Np), lambda t, k, te, f, l: (te[t], 0, 0)),
-                      pl.BlockSpec((nd, Np, td), lambda t, k, te, f, l: (0, 0, 0)),
-                      slab, slab],
-            out_specs=pl.BlockSpec((TM, F), lambda t, k, te, f, l: (t, 0)),
-            scratch_shapes=[pltpu.VMEM((TM, F), jnp.float32)] * 2),
-        compiler_params=params, interpret=interpret, name="moe_grouped_hidden",
-    )(*scalars, rows, slabs, w_gate, w_in)
-
     def down(te, first, l, rows_ref, wt_ref, h_ref, wd_ref, y_ref):
         t = pl.program_id(1)
 
@@ -399,19 +385,35 @@ def _grouped_pallas(x, combine, w_gate, w_in, w_out, activation, layer,
             pick, a, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32)
         y_ref[...] += back(hi) + back(lo)
 
+    slab = pl.BlockSpec((None, None, td, F), lambda t, k, te, f, l: (l[0], te[t], k, 0))
     column = pl.BlockSpec((None, 1, Np), lambda n, t, te, f, l: (te[t], 0, 0))
-    y = pl.pallas_call(
-        down, out_shape=jax.ShapeDtypeStruct((Np, D), jnp.float32),
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=3, grid=(nd, run),
-            in_specs=[column, column,
-                      pl.BlockSpec((TM, F), lambda n, t, te, f, l: (t, 0)),
-                      pl.BlockSpec((None, None, F, td),
-                                   lambda n, t, te, f, l: (l[0], te[t], 0, n))],
-            out_specs=pl.BlockSpec((Np, td), lambda n, t, te, f, l: (0, n))),
-        compiler_params=params, interpret=interpret, name="moe_grouped_down",
-    )(*scalars, rows, weights, h, w_out)
-    return y[:N]
+
+    def kernels():
+        h = pl.pallas_call(
+            hidden, out_shape=jax.ShapeDtypeStruct((T * TM, F), dt),
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=3, grid=(tiles, nd),
+                in_specs=[pl.BlockSpec((None, 1, Np), lambda t, k, te, f, l: (te[t], 0, 0)),
+                          pl.BlockSpec((nd, Np, td), lambda t, k, te, f, l: (0, 0, 0)),
+                          slab, slab],
+                out_specs=pl.BlockSpec((TM, F), lambda t, k, te, f, l: (t, 0)),
+                scratch_shapes=[pltpu.VMEM((TM, F), jnp.float32)] * 2),
+            compiler_params=params, interpret=interpret, name="moe_grouped_hidden",
+        )(*scalars, rows, slabs, w_gate, w_in)
+        y = pl.pallas_call(
+            down, out_shape=jax.ShapeDtypeStruct((Np, D), jnp.float32),
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=3, grid=(nd, tiles),
+                in_specs=[column, column,
+                          pl.BlockSpec((TM, F), lambda n, t, te, f, l: (t, 0)),
+                          pl.BlockSpec((None, None, F, td),
+                                       lambda n, t, te, f, l: (l[0], te[t], 0, n))],
+                out_specs=pl.BlockSpec((Np, td), lambda n, t, te, f, l: (0, n))),
+            compiler_params=params, interpret=interpret, name="moe_grouped_down",
+        )(*scalars, rows, weights, h, w_out)
+        return y[:N]
+
+    return jax.lax.cond(tiles > 0, kernels, lambda: jnp.zeros((N, D), jnp.float32))
 
 
 @functools.partial(jax.jit, static_argnames=("activation", "k", "rows_tile", "kernels"))
@@ -456,32 +458,30 @@ _grouped_experts.defvjp(_grouped_fwd, _grouped_bwd)
 
 
 def dropless_experts(x, combine, w_gate, w_in, w_out, activation: str,
-                     layer=None, touched_k: int = 0, grouped_k: int = 0):
+                     layer=None, grouped_k: int = 0):
     """y [N, D] = sum_e combine[n, e] * W_out,e( act(W_gate,e x) * (W_in,e x) ).
 
     x [N, D]; combine [N, X] f32; weights [X, D, F] / [X, F, D], or with
     `layer` (a traced index) the whole stacks [L, X, ...] of which layer
-    `layer` is read in place. Three forms, the same mathematics:
+    `layer` is read in place. Two forms, the same mathematics:
 
     * dense (default): one [N, D] x [D, X*F] product for gate and up, the
       combine weights folded into the hidden activations, one [N, X*F] x
-      [X*F, D] product down. Every expert meets every token.
-    * `touched_k` = k > 0: a loop over at most min(N*k, X) experts, the chosen
-      ones first, each applied to all N tokens under its combine column;
-      an expert nobody chose is never read. Right for a decode step of a
-      few lanes, whose time is the bytes of expert weights it streams.
+      [X*F, D] product down. Every expert meets every token: the reference
+      the tests hold the other form to.
     * `grouped_k` = k > 0 (a token's nonzero columns are at most k): the
       tokens that chose an expert are its rows (`dropless_groups`), each
       expert's rows through its gate, up and down products once, a tile of
       `GROUP_ROWS` rows at a time and only the tiles the routing fills; a
       token's k rows weighted by its combine weights and summed in float32.
-      An expert nobody chose is never read and a column that is zero costs
-      nothing: right for a step of many tokens. On the TPU the tiles are two
-      Pallas kernels that fetch each expert's blocks out of the stacks in
-      place (`_grouped_pallas`).
+      An expert nobody chose is never read, a column or a row that is zero
+      costs nothing, and a routing with no assignment here reads no expert:
+      the cost follows the routing by itself, for one decode lane as for a
+      prefill chunk, so every served step takes this form. On the TPU the
+      tiles are two Pallas kernels that fetch each expert's blocks out of the
+      stacks in place (`_grouped_pallas`).
     """
-    N, D = x.shape
-    X = combine.shape[-1]
+    N = x.shape[0]
     dt = x.dtype
 
     if grouped_k:       # in pieces of the tokens the kernels keep in fast memory
@@ -489,34 +489,14 @@ def dropless_experts(x, combine, w_gate, w_in, w_out, activation: str,
             _grouped_experts(x[i:i + _GROUP_TOKENS], combine[i:i + _GROUP_TOKENS],
                              w_gate, w_in, w_out, activation, layer, grouped_k)
             for i in range(0, N, _GROUP_TOKENS)]).astype(dt)
-    if not touched_k:
-        if layer is not None:
-            w_gate, w_in, w_out = (
-                jax.lax.dynamic_index_in_dim(a, layer, 0, keepdims=False)
-                for a in (w_gate, w_in, w_out))
-        g = jnp.einsum("nd,xdf->nxf", x, w_gate.astype(dt))
-        u = jnp.einsum("nd,xdf->nxf", x, w_in.astype(dt))
-        h = _gated(activation, g, u) * combine[..., None].astype(dt)
-        return jnp.einsum("nxf,xfd->nd", h, w_out.astype(dt))
-
-    hit = (combine > 0).any(axis=0)                      # [X] chosen by someone
-    order = jnp.argsort(~hit, stable=True)               # chosen experts first
-    n_hit = hit.sum()
-
-    def body(i, y):
-        def run(y):
-            e = order[i]
-            g = x @ _one(w_gate, layer, e).astype(dt)
-            u = x @ _one(w_in, layer, e).astype(dt)
-            c = jax.lax.dynamic_index_in_dim(combine, e, 1, keepdims=True)
-            h = _gated(activation, g, u) * c.astype(dt)
-            return y + (h @ _one(w_out, layer, e).astype(dt)).astype(jnp.float32)
-
-        return jax.lax.cond(i < n_hit, run, lambda y: y, y)
-
-    trips = min(N * touched_k, X)
-    y = jax.lax.fori_loop(0, trips, body, jnp.zeros((N, D), jnp.float32))
-    return y.astype(dt)
+    if layer is not None:
+        w_gate, w_in, w_out = (
+            jax.lax.dynamic_index_in_dim(a, layer, 0, keepdims=False)
+            for a in (w_gate, w_in, w_out))
+    g = jnp.einsum("nd,xdf->nxf", x, w_gate.astype(dt))
+    u = jnp.einsum("nd,xdf->nxf", x, w_in.astype(dt))
+    h = _gated(activation, g, u) * combine[..., None].astype(dt)
+    return jnp.einsum("nxf,xfd->nd", h, w_out.astype(dt))
 
 
 def dropless_load(combine, valid=None, top_k: int = 0):
@@ -524,13 +504,16 @@ def dropless_load(combine, valid=None, top_k: int = 0):
     assignments) of one layer's routing, f32 scalars; `valid` [N] leaves
     padding tokens out. `combine` cut to the columns of the experts held
     here, with `top_k` given: also (the assignments that fell on those
-    columns, all the tokens' assignments = tokens x top_k)."""
+    columns, all the tokens' assignments = tokens x top_k, 1 if none fell
+    here: the layer's routing is empty and its product reads no expert)."""
     chosen = combine > 0
     if valid is not None:
         chosen = chosen & valid[:, None]
     per = chosen.sum(axis=0).astype(jnp.float32)         # [X] assignments
-    load = ((per > 0).sum().astype(jnp.float32), per.max() / jnp.maximum(per.sum(), 1.0))
+    here = per.sum()
+    load = ((per > 0).sum().astype(jnp.float32), per.max() / jnp.maximum(here, 1.0))
     if not top_k:
         return load
     tokens = combine.shape[0] if valid is None else valid.sum()
-    return (*load, per.sum(), jnp.asarray(tokens * top_k, jnp.float32))
+    return (*load, here, jnp.asarray(tokens * top_k, jnp.float32),
+            (here == 0).astype(jnp.float32))

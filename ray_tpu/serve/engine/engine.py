@@ -348,7 +348,7 @@ class InferenceEngine:
         import jax
 
         from ...models.gpt import (
-            init_paged_cache, init_params, kv_layout, moe_form, paged_attn_keys,
+            init_paged_cache, init_params, kv_layout, paged_attn_keys,
         )
 
         self.cfg = dataclasses.replace(cfg, remat=False, remat_policy=None)
@@ -482,14 +482,14 @@ class InferenceEngine:
         # Expert routing: (experts touched, busiest expert's share) of the
         # decode step whose ids this step read, which came back with them;
         # for a program that holds a range of the experts also [assignments
-        # that fell on held experts, all], summed over layers and steps.
+        # that fell on held experts, all] and [expert layers whose routing
+        # left none here (no expert read), all], summed over layers and steps.
         self._step_moe = None
         self.total_moe_assign = [0, 0]
+        self.total_moe_layers = [0, 0]
         # Tokens (as the program is shaped) x expert layers of every paged
-        # program an expert model dispatched: [under the grouped form, all],
-        # the form asked of the function the program takes it from.
-        self._moe_form = moe_form
-        self.total_moe_tokens = [0, 0]
+        # program an expert model dispatched: each takes the grouped form.
+        self.total_moe_tokens = 0
         # Passes of the layer stack over the decode steps' real lanes: [run,
         # from the length of what came back; what `ut_steps` would be].
         self.total_ut_passes = [0, 0]
@@ -1096,12 +1096,9 @@ class InferenceEngine:
 
     def _count_moe(self, tokens: int):
         """Add one program of `tokens` tokens (lanes x tokens a lane, padding
-        and all) to the expert layers' count, by the form it takes."""
-        if self.cfg.mlp_type != "moe":
-            return
-        n = tokens * (self.cfg.n_layers - self.cfg.dense_layers)
-        self.total_moe_tokens[0] += n * (self._moe_form(self.cfg, tokens) == "grouped")
-        self.total_moe_tokens[1] += n
+        and all) to the expert layers' count."""
+        if self.cfg.mlp_type == "moe":
+            self.total_moe_tokens += tokens * (self.cfg.n_layers - self.cfg.dense_layers)
 
     def _run_prefill(self, chunk):
         """Dispatch one prefill chunk: compute prompt[start : start+n] into
@@ -1215,10 +1212,13 @@ class InferenceEngine:
             attrs["expert_load_max"] = float(self._step_moe[1])
             if self.cfg.moe_held:   # means over the expert layers -> their sums
                 layers = self.cfg.n_layers - self.cfg.dense_layers
-                held, total = (int(round(float(v) * layers)) for v in self._step_moe[2:])
+                held, total, empty = (
+                    int(round(float(v) * layers)) for v in self._step_moe[2:])
                 attrs["assign_held"], attrs["assign_total"] = held, total
                 self.total_moe_assign[0] += held
                 self.total_moe_assign[1] += total
+                self.total_moe_layers[0] += empty
+                self.total_moe_layers[1] += layers
         if self.cfg.ut_steps > 1:
             pdf = facts.pop(0).tolist()     # one entry a pass the program ran
             self.total_ut_passes[0] += lanes * len(pdf)
@@ -1568,8 +1568,11 @@ class InferenceEngine:
             "ut_passes_full": self.total_ut_passes[1],
             "moe_assign_held": self.total_moe_assign[0],
             "moe_assign_total": self.total_moe_assign[1],
-            "moe_tokens_grouped": self.total_moe_tokens[0],
-            "moe_tokens_expert": self.total_moe_tokens[1],
+            "moe_layers_empty": self.total_moe_layers[0],
+            "moe_layers_routed": self.total_moe_layers[1],
+            # one served form: the two read alike (`moe_grouped_token_share`)
+            "moe_tokens_grouped": self.total_moe_tokens,
+            "moe_tokens_expert": self.total_moe_tokens,
             "decode_dispatched": self.total_decode_dispatched,
             "decode_chained": self.total_decode_chained,
             **self._books,

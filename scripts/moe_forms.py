@@ -1,6 +1,6 @@
-"""Time the three forms of the dropless expert layer (`ops/moe.py`
-`dropless_experts`: dense over every held expert, a loop over the experts the
-step's tokens chose, or the assignments grouped by expert into row tiles) on
+"""Time the two forms of the dropless expert layer (`ops/moe.py`
+`dropless_experts`: dense over every held expert, the reference, or the
+assignments grouped by expert into row tiles, what every served step takes) on
 the chip, at a preset's widths, inside a scan over the layers as the paged
 programs run them: `python3 -m scripts.moe_forms [--model
 smallthinker-21b-a3b | ax-k1] [--n-layers 12] [--held 0:12] [--tokens
@@ -10,10 +10,11 @@ range of the router's experts this chip holds, all of them if not given).
 For each token count: milliseconds a pass over all layers in each form, the
 share of the HBM roofline of reading the experts that were chosen (819 GB/s,
 v5e), and for the grouped form the rows its tiles compute against the rows
-the routing wants (the assignments on held experts). The rule in
-`models/gpt.py` `moe_form` (loop while tokens x top_k < experts, grouped above)
-was set from this table (PERF.md §6, PR 28 and PR 35). A chip run or nothing:
-on the CPU it prints counts only."""
+the routing wants (the assignments on held experts). That the served programs
+take the grouped form at every token count was set from this table (PERF.md
+§6, PR 35 and PR 39; a third form, a loop over the chosen experts, was timed
+here until PR 39 and lost at every count). A chip run or nothing: on the CPU
+it prints counts only."""
 
 from __future__ import annotations
 
@@ -22,7 +23,7 @@ import json
 import sys
 import time
 
-FORMS = ("dense", "loop", "grouped")
+FORMS = ("dense", "grouped")
 
 
 def main() -> int:
@@ -64,8 +65,7 @@ def main() -> int:
                 combine = moe.dropless_combine(idx, w, X)[:, first:first + held]
                 y = moe.dropless_experts(
                     x, combine, *(sl if cut else stacks), cfg.activation,
-                    layer=None if cut else l, touched_k=k if form == "loop" else 0,
-                    grouped_k=k if form == "grouped" else 0)
+                    layer=None if cut else l, grouped_k=k if form == "grouped" else 0)
                 if form == "grouped":
                     tiles += moe.dropless_groups(combine, k, moe.GROUP_ROWS)[3]
                 return (x + y, touched + moe.dropless_load(combine)[0],
@@ -83,8 +83,6 @@ def main() -> int:
         x = mk(jax.random.PRNGKey(n), (n, D)) * 50
         row = {"tokens": n}
         for form in a.forms.split(","):
-            if form == "loop" and n * k >= 4 * X:
-                continue
             fn = make(n, form)
             out = fn(x, stacks, router)
             jax.block_until_ready(out)

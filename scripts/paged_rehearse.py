@@ -1,9 +1,10 @@
 """Compile-only rehearsal of the three paged programs, without the chip:
 `JAX_PLATFORMS=cpu python3 -m scripts.paged_rehearse --model gpt2-large
 --num-blocks 1024 --block-size 16 [--lanes 8] [--width 16] [--chunk 64]
-[--spec 4] [--n-layers N] [--config <benchmark configuration>]` from the
-root of a checkout (a model whose layers form several KV groups gets one
-block table a group; a latent model's pool is one array).
+[--spec 4] [--n-layers N] [--config <benchmark configuration>]
+[--decode-lanes 1,2,4,8,16]` from the root of a checkout (a model whose
+layers form several KV groups gets one block table a group; a latent model's
+pool is one array).
 
 Compiles `decode_step_paged`, `prefill_paged` and `verify_step_paged` of
 `models/gpt.py` for one described (not attached) `v5e:2x2` device, each
@@ -22,6 +23,11 @@ pays in every layer of every step (PERF.md §6, PR 25).
 
 The programs are compiled with the kernels the chip runs (the Pallas norms,
 the grouped experts' two kernels), not the plain paths of this process' backend.
+`--decode-lanes` compiles the decode program again at each of those lane
+buckets and prints its temporaries; for an expert model also, from shapes,
+the grouped form's hidden rows between its two kernels, `[T x 128, F]` with
+`T = lanes x top_k // 128 + experts held` (one layer's at a time; the chip's
+compiler keeps them out of the temporaries it counts: PERF.md §6, PR 39).
 
 Nothing runs: a compile that passes is not a chip run, and no time, rate or
 share comes from here. The last line of stdout is one JSON object."""
@@ -87,11 +93,12 @@ def big_ops(hlo_text: str, min_bytes: int):
 
 def rehearse(cfg, device, num_blocks: int, block_size: int, lanes: int = 8,
              width: int = 16, chunk: int = 64, spec: int = 4,
-             init: bool = False) -> dict:
+             init: bool = False, decode_lanes=()) -> dict:
     """Compile the three paged programs of `cfg` for `device` (a described
     device of `jax.experimental.topologies`) at one shape bucket each; with
     `init`, also `init_params` under one jit (what making the weights in a
-    stated dtype keeps beside them)."""
+    stated dtype keeps beside them); the decode program again at each lane
+    bucket of `decode_lanes`."""
     import jax
     import jax.numpy as jnp
     from jax.sharding import SingleDeviceSharding
@@ -120,15 +127,21 @@ def rehearse(cfg, device, num_blocks: int, block_size: int, lanes: int = 8,
     prefill, decode, verify = _paged_jits()
     groups = len(kv_layout(cfg).windows)    # one block table a KV group
     table = (width,) if groups == 1 else (groups, width)
+
+    def decode_at(n):
+        return lambda: decode.lower(
+            params, i32(4, n), i32(n, *table), kv, last, sampling, cfg)
+
     programs = {
-        "decode_step_paged": lambda: decode.lower(
-            params, i32(4, lanes), i32(lanes, *table), kv, last, sampling, cfg),
+        "decode_step_paged": decode_at(lanes),
         "prefill_paged": lambda: prefill.lower(
             params, i32(1, chunk), i32(3), i32(*table), kv, last, sampling, cfg),
         "verify_step_paged": lambda: verify.lower(
             params, i32(lanes, spec + 1), i32(lanes), i32(lanes),
             i32(lanes, *table), kv, cfg),
     }
+    for n in decode_lanes:
+        programs[f"decode_step_paged@{n}"] = decode_at(n)
     if init:
         programs["init_params"] = lambda: jax.jit(
             lambda k: init_params(k, cfg)).lower(
@@ -141,7 +154,7 @@ def rehearse(cfg, device, num_blocks: int, block_size: int, lanes: int = 8,
         "weights_GiB": sum(a.size * a.dtype.itemsize for a in params.values()) / 2**30,
         "layer_pool_MiB": layer_pool / 2**20,
         "lanes": lanes, "width": width, "chunk": chunk, "spec": spec,
-        "programs": {},
+        "decode_lanes": list(decode_lanes), "programs": {},
     }
     for name, lower in programs.items():
         try:
@@ -162,7 +175,7 @@ def rehearse(cfg, device, num_blocks: int, block_size: int, lanes: int = 8,
             ],
         }
         print(f"{name}: args {mem.argument_size_in_bytes / 2**30:.2f} GiB, "
-              f"temp {mem.temp_size_in_bytes / 2**30:.2f}, "
+              f"temp {mem.temp_size_in_bytes / 2**30:.4f}, "
               f"output {mem.output_size_in_bytes / 2**30:.2f}, "
               f"aliased {mem.alias_size_in_bytes / 2**30:.2f}; "
               f"{len(ops)} operation(s) of >= {layer_pool / 2**21:.1f} MiB",
@@ -183,6 +196,8 @@ def main() -> int:
     ap.add_argument("--spec", type=int, default=4, help="draft tokens verified")
     ap.add_argument("--n-layers", type=int, default=None,
                     help="cut the preset's depth (a 6 B model on one chip)")
+    ap.add_argument("--decode-lanes", default="",
+                    help="lane buckets to compile the decode program at besides --lanes")
     ap.add_argument("--config", default=None,
                     help="a benchmark configuration (benchmarks/configs/<name>.json): "
                          "its program model and overrides instead of --model")
@@ -209,7 +224,16 @@ def main() -> int:
     cfg = CONFIGS[a.model](**overrides, remat=False, remat_policy=None)
     topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
     report = rehearse(cfg, topo.devices[0], a.num_blocks, a.block_size,
-                      a.lanes, a.width, a.chunk, a.spec, init=True)
+                      a.lanes, a.width, a.chunk, a.spec, init=True,
+                      decode_lanes=[int(n) for n in a.decode_lanes.split(",") if n])
+    if cfg.mlp_type == "moe":   # what the grouped form's hidden rows take, by shape
+        from ray_tpu.ops.moe import GROUP_ROWS
+
+        held = cfg.moe_held[1] if cfg.moe_held else cfg.moe_experts
+        for n in sorted({a.lanes, *report["decode_lanes"]}):
+            tiles = n * cfg.moe_top_k // GROUP_ROWS + held
+            print(f"decode at {n} lanes: hidden rows [{tiles} x {GROUP_ROWS}, {cfg.d_mlp}] "
+                  f"{tiles * GROUP_ROWS * cfg.d_mlp * 2 / 2**20:.1f} MiB")
     print(json.dumps({"model": a.model, **report}))
     return 0
 

@@ -157,11 +157,15 @@ def test_paged_prefill_and_decode_through_the_latent_pool_match_the_reference(
     assert len(out) == 5 + 30 and out[-1][0] == 129 > 16 * BS
     assert max(_err(lg, want[pos]) for pos, lg in out) < TOL
     # one lane, top-3 of 16 with 4 held, two expert layers: what comes back
-    # beside the logits is (touched, busiest share, held, all) a layer
+    # beside the logits is (touched, busiest share, held, all, 1 if none was
+    # held: the layer's routing is empty) a layer
     load = np.stack(loads)
-    assert load.shape == (30, 4) and (load[:, 3] == 3).all()
+    assert load.shape == (30, 5) and (load[:, 3] == 3).all()
     assert (load[:, 2] <= 3).all() and (load[:, 0] == load[:, 2]).all()
     assert 0 < load[:, 2].mean() < 3
+    assert set(load[:, 4].tolist()) <= {0.0, 0.5, 1.0} and 0 < load[:, 4].mean() < 1
+    assert ((load[:, 4] == 1.0) == (load[:, 2] == 0)).all()
+    assert (load[:, 4] <= 1.0 - load[:, 2] / 6).all()  # a layer holds 3 at most
 
 
 def test_bfloat16_program_stays_near_the_reference_on_the_same_weights(tile_keys):
@@ -271,7 +275,10 @@ def test_router_is_sigmoid_top_k_normalised_and_scaled():
     held = moe.dropless_combine(idx, w, 5)[:, 1:3]
     load = moe.dropless_load(held, None, 3)
     # experts 1 and 2 held: token 0 chose expert 1, token 1 both
-    assert np.allclose([float(v) for v in load], [2.0, 2 / 3, 3.0, 6.0])
+    assert np.allclose([float(v) for v in load], [2.0, 2 / 3, 3.0, 6.0, 0.0])
+    # token 0 alone, with expert 2 alone held: nothing of its routing fell here
+    none = moe.dropless_load(held[:1, 1:], None, 3)
+    assert [float(v) for v in none] == [0.0, 0.0, 0.0, 3.0, 1.0]
     with pytest.raises(ValueError, match="scoring"):
         moe.dropless_route(logits, 3, "tanh")
 
@@ -392,6 +399,13 @@ def test_engine_serves_exactly_and_counts_the_assignments_that_fell_here(
         lambda name, *a, attrs=None, **kw: records.append((name, attrs)))
     cfg, params, m, tokens, _want = case
     eng = _engine(case)
+    facts, read = [], eng._decode_facts
+
+    def keeping(lanes, more):   # what each decode program handed back beside its ids
+        facts.append(np.asarray(more[0]))
+        return read(lanes, more)
+
+    monkeypatch.setattr(eng, "_decode_facts", keeping)
     prompts = [[int(t) for t in tokens[:30]], [int(t) for t in tokens[40:75]]]
     rids = [eng.submit(p, 20) for p in prompts]
     _drain(eng)
@@ -411,16 +425,22 @@ def test_engine_serves_exactly_and_counts_the_assignments_that_fell_here(
         == 2 * 3 * 2 * 19
     assert 0 < stats["moe_assign_held"] == sum(a["assign_held"] for a in decodes) \
         < stats["moe_assign_total"]
-    # every program's tokens as it is shaped x the two expert layers, by the
-    # form `moe_form` gives it: top-3 of 16, so the prompts' chunks (24 + 6 and
-    # 24 + 11 tokens in programs of 32, 8, 32 and 16) are grouped and the decode
-    # steps (at most 4 lanes) take the loop
-    from ray_tpu.models.gpt import moe_form
-
-    assert moe_form(cfg, 5) == "loop" and moe_form(cfg, 6) == "grouped"
+    # the expert layers (two) whose routing left no assignment here, of the
+    # decode programs' real lanes: the scalar that rode back, summed; a layer
+    # that is not empty holds an assignment, and a step with none has both empty
+    assert len(facts) == len(decodes) and all(f.shape == (5,) for f in facts)
+    assert stats["moe_layers_routed"] == 2 * len(decodes)
+    empty = [int(round(2 * float(f[4]))) for f in facts]
+    assert stats["moe_layers_empty"] == sum(empty)
+    for n, a in zip(empty, decodes):
+        assert 2 - a["assign_held"] <= n <= 2 - (a["assign_held"] > 0)
+    assert 0 < stats["moe_layers_empty"] < stats["moe_layers_routed"]
+    # every program's tokens as it is shaped x the two expert layers: the
+    # prompts' chunks (24 + 6 and 24 + 11 tokens in programs of 32, 8, 32 and
+    # 16) and the decode steps (at most 4 lanes) take the one served form
     assert sum(a["prefills"] for a in steps) == 4
-    assert stats["moe_tokens_grouped"] == 2 * (32 + 8 + 32 + 16)
-    lanes = stats["moe_tokens_expert"] - stats["moe_tokens_grouped"]
+    assert stats["moe_tokens_grouped"] == stats["moe_tokens_expert"]
+    lanes = stats["moe_tokens_expert"] - 2 * (32 + 8 + 32 + 16)
     assert 2 * len(decodes) <= lanes <= 2 * 4 * len(decodes)
     # a latent layer's keys are counted as a global layer's
     assert 0 < stats["attn_keys_run"] <= stats["attn_keys_padded"]
@@ -513,17 +533,18 @@ def test_config_and_architecture_module_refuse_what_is_not_the_model():
 # programs (`serve/engine/engine.py` `_paged_jits`: each ends in the sampler;
 # 4 lanes, a pool of 64 blocks of 16, a chunk of 32, 2 drafts) at the parent
 # commit 4caabe5, under the jax they were taken with, with tables of 8 blocks
-# (one tile) and of 128 (the key loop). PR 35 re-took the `smallthinker-21b-a3b`
-# `prefill` and `verify` entries (32 and 12 tokens x top-6 reach the 64 experts:
-# those programs took the dense form and take the grouped one); its `decode`
-# entries (4 lanes: the loop form) and the other 18 are still the parent's.
+# (one tile) and of 128 (the key loop). PR 39 re-took the six
+# `smallthinker-21b-a3b` entries (`decode`: 4 lanes took the loop over chosen
+# experts and take the grouped form; `prefill` and `verify`, grouped since
+# PR 35: their padding rows are now routed nowhere); the other 18 are still
+# the parent's, so no program of a model without experts moved.
 _PARENT = {
     "gpt2-small/8": {"decode": ["47e12eb441a8db6f", 56599], "prefill": ["3d712626f6741d9d", 56211], "verify": ["cabdde9d7cb27d1c", 46062]},
     "gpt2-small/128": {"decode": ["a469eadf508f0a50", 69719], "prefill": ["3eaeb73f7472490f", 69106], "verify": ["cdc8b418bab759b6", 59099]},
     "gpt2-large/8": {"decode": ["4a3c99ad312b90d7", 56890], "prefill": ["586bdd407d571263", 56494], "verify": ["9c9ab8dce652d623", 46341]},
     "gpt2-large/128": {"decode": ["3e8b3a276dffa813", 70014], "prefill": ["d8ff5e567f857d44", 69393], "verify": ["325d1493576d59f0", 59382]},
-    "smallthinker-21b-a3b/8": {"decode": ["2b631670cf4b6751", 75755], "prefill": ["c257305dcd77c747", 81048], "verify": ["d59de93341f7cb78", 72691]},
-    "smallthinker-21b-a3b/128": {"decode": ["220ea9aebbe176f3", 87784], "prefill": ["1e8315c2e3ac9c53", 93742], "verify": ["0d0bacb981a5c958", 85530]},
+    "smallthinker-21b-a3b/8": {"decode": ["4e37645107422994", 85872], "prefill": ["683caebcddddb256", 82697], "verify": ["2e56174898997513", 74321]},
+    "smallthinker-21b-a3b/128": {"decode": ["1758aba14558b3c2", 97901], "prefill": ["fb52c1ec3674f57b", 94689], "verify": ["3184a5406968d88f", 86472]},
     "ouro-2.6b/8": {"decode": ["daa65fea876c298e", 74846], "prefill": ["6f59bfc7e81f8afa", 65849], "verify": ["441542f5c0143299", 57230]},
     "ouro-2.6b/128": {"decode": ["6614de8a7a764202", 87645], "prefill": ["11867b981e748ff7", 79107], "verify": ["90cb98c74573bd2c", 70644]},
 }

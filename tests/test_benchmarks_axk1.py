@@ -47,10 +47,18 @@ def test_rehearsal_is_correct_and_counts_the_assignments_that_fell_here(obs):
     share = readers.read("moe_held_assign_share", obs)
     assert share == 100.0 * c["moe_assign_held"] / c["moe_assign_total"]
     assert 10.0 < share < 45.0                      # 4 of 16: a quarter in the mean
-    # every chunk program takes the grouped form, every decode step the loop
-    assert 0 < c["moe_tokens_grouped"] < c["moe_tokens_expert"]
-    grouped = readers.read("moe_grouped_token_share", obs)
-    assert grouped == 100.0 * c["moe_tokens_grouped"] / c["moe_tokens_expert"] > 50.0
+    # every program, chunk or decode step, takes the one served form
+    assert 0 < c["moe_tokens_grouped"] == c["moe_tokens_expert"]
+    assert readers.read("moe_grouped_token_share", obs) == 100.0
+    # two expert layers a decode step; top-3 of 16 with 4 held leaves a lone
+    # lane's layer empty 4 times in 10
+    assert c["moe_layers_routed"] == 2 * len(decodes)
+    assert 0 <= c["moe_layers_empty"] <= c["moe_layers_routed"] - (c["moe_assign_held"] > 0)
+    empty = readers.read("moe_empty_layer_share", obs)
+    assert empty == 100.0 * c["moe_layers_empty"] / c["moe_layers_routed"]
+    # a program without the counter (the parent's) is read as nothing, not an error
+    assert readers.read("moe_empty_layer_share", {"counters": {
+        k: v for k, v in c.items() if not k.startswith("moe_layers")}}) is None
     for name in ("kv_util_mean", "prefill_span_p90_ms", "queue_wait_p50_ms",
                  "decode_lanes_mean", "engine_step_ms", "moe_experts_touched_mean",
                  "moe_expert_load_max", "attn_keys_run_share", "decode_chained_share"):
@@ -153,6 +161,14 @@ def test_the_cell_and_its_files_are_in_the_benchmark():
         "kind": "counter_ratio", "num": "moe_tokens_grouped",
         "den": "moe_tokens_expert", "scale": 100.0}
     assert per_layer["moe_held_assign_share"]["workloads"] == [CELL]
+    assert names.index("moe_grouped_token_share") < names.index("moe_empty_layer_share")
+    assert per_layer["moe_empty_layer_share"] == {
+        "name": "moe_empty_layer_share", "unit": "%", "better": "higher",
+        "source": "program_counter", "layer": "paged model path",
+        "moves": "itl_p90_ms", "workloads": [CELL]}
+    assert readers.reader_spec("moe_empty_layer_share") == {
+        "kind": "counter_ratio", "num": "moe_layers_empty", "den": "moe_layers_routed",
+        "scale": 100.0}
     for name in layer:
         assert readers.reader_spec(name)["kind"] in readers.KINDS, name
     assert readers.reader_spec("moe_held_assign_share") == {

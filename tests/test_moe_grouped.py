@@ -1,6 +1,7 @@
 """The grouped form of the dropless expert layer (`ops/moe.py`
-`dropless_experts(grouped_k=k)`): the assignments sorted by expert into row
-tiles, only the tiles the routing fills computed. All on the CPU: the layout
+`dropless_experts(grouped_k=k)`), the one form the served programs take: the
+assignments sorted by expert into row tiles, only the tiles the routing fills
+computed, none for a routing that is empty. All on the CPU: the layout
 and the combine are plain JAX, the tiles a `lax` loop off the TPU, and the
 Pallas kernels the TPU runs are held to that loop in interpret mode."""
 
@@ -132,19 +133,113 @@ def test_every_token_on_one_expert():
     assert float(jnp.abs(y - dense).max()) < 1e-5
 
 
-@pytest.mark.parametrize("tiles_by", ["plain", "pallas-interpret"])
-def test_no_assignment_held_gives_zeros_not_nan(tiles_by, monkeypatch):
-    import jax.numpy as jnp
-
+def _tiles_by(tiles_by, monkeypatch):
+    """Steer `dropless_experts(grouped_k=)` to the plain tiles (what the CPU
+    takes) or to the kernels the TPU runs, in interpret mode."""
     from ray_tpu.ops import attention, moe
 
-    if tiles_by != "plain":     # the kernels still run tile 0: all padding
+    if tiles_by != "plain":
         monkeypatch.setattr(attention, "_on_tpu", lambda: True)
         monkeypatch.setattr(moe, "_grouped_pallas", functools.partial(
             moe._grouped_pallas, interpret=True))
+
+
+TILES_BY = ["plain", "pallas-interpret"]
+
+
+@pytest.mark.parametrize("tiles_by", TILES_BY)
+def test_an_empty_routing_reads_no_expert_and_gives_zeros(tiles_by, monkeypatch):
+    """No assignment on an expert held here (or every token padding): no
+    tile, so neither kernel runs. EVERY expert's weights are NaN: one block
+    fetched and multiplied, even under rows that are all padding, would
+    show."""
+    import jax.numpy as jnp
+
+    from ray_tpu.ops import moe
+
+    _tiles_by(tiles_by, monkeypatch)
     x, combine, experts, k = _layer(16, (16, 2, "softmax", (4, 2)), D=128, F=128)
-    y = moe.dropless_experts(x, jnp.zeros_like(combine), *experts, "swiglu", grouped_k=k)
+    broken = tuple(a * jnp.nan for a in experts)
+    assert int(moe.dropless_groups(jnp.zeros_like(combine), k, moe.GROUP_ROWS)[3]) == 0
+    y = moe.dropless_experts(x, jnp.zeros_like(combine), *broken, "swiglu", grouped_k=k)
     assert y.shape == x.shape and (np.asarray(y) == 0).all()
+
+
+DECODE_ROUTINGS = ["64-of-64-top6-softmax", "12-of-192-top8-sigmoid-first"]
+
+
+@pytest.mark.parametrize("tiles_by", TILES_BY)
+@pytest.mark.parametrize("routing", DECODE_ROUTINGS)
+@pytest.mark.parametrize("tokens", [1, 2, 4, 8])
+def test_a_decode_steps_few_tokens_take_the_grouped_form(tokens, routing, tiles_by,
+                                                         monkeypatch):
+    """The one served form at a decode bucket's lanes: a tile of 128 rows of
+    which `tokens` or fewer are real, against the dense reference. Of the 192
+    experts 12 are held, so some of these steps route nothing here (both
+    forms then give zeros) and some do: both kinds are among the seeds."""
+    import jax.numpy as jnp
+
+    from ray_tpu.ops import moe
+
+    _tiles_by(tiles_by, monkeypatch)
+    filled = []
+    for seed in range(6):
+        x, combine, experts, k = _layer(tokens, routing, D=128, F=128, seed=seed)
+        dense = moe.dropless_experts(x, combine, *experts, "swiglu")
+        grouped = moe.dropless_experts(x, combine, *experts, "swiglu", grouped_k=k)
+        assert grouped.dtype == dense.dtype and grouped.shape == dense.shape
+        assert float(jnp.abs(grouped - dense).max()) < 1e-5 * max(
+            1.0, float(jnp.abs(dense).max()))
+        filled.append(bool((combine > 0).any()))
+        assert filled[-1] == (float(jnp.abs(dense).max()) > 0)
+    assert any(filled) and (all(filled) or "192" in routing)
+
+
+@pytest.mark.parametrize("tiles_by", TILES_BY)
+@pytest.mark.parametrize("routing", DECODE_ROUTINGS)
+def test_a_padding_lane_routes_nowhere(routing, tiles_by, monkeypatch):
+    """A step of 3 lanes in the bucket of 4 through `_dropless_mlp`: the
+    padding lane's logits choose experts no real lane does, whose weights are
+    NaN. Outside `valid` it is routed nowhere: the real lanes' outputs are
+    finite and the unpadded step's, the load counts the real lanes' alone."""
+    import jax
+    import jax.numpy as jnp
+
+    from ray_tpu.models import gpt
+
+    _tiles_by(tiles_by, monkeypatch)
+    X, k, scoring, held = ROUTINGS[routing]
+    first, count = held or (0, X)
+    D, F = 128, 128
+    cfg = gpt.GPTConfig(
+        vocab_size=64, n_layers=1, n_heads=2, d_model=D, d_mlp=F, max_seq=32,
+        mlp_type="moe", moe_routing="dropless", moe_experts=X, moe_top_k=k,
+        moe_scoring=scoring, moe_held=held, activation="swiglu", dtype=jnp.float32)
+    ks = jax.random.split(jax.random.PRNGKey(3), 5)
+    # the padding lane is all in feature 0, which the real lanes lack; the
+    # router sends feature 0 to the first k held experts and every other
+    # feature away from them
+    x = jnp.abs(jax.random.normal(ks[0], (4, 1, D))).at[:, :, 0].set(0.0)
+    x = x.at[3].set(0.0).at[3, 0, 0].set(10.0)
+    router = jax.random.normal(ks[1], (D, X))
+    router = router.at[:, first:first + k].set(-5.0).at[0, first:first + k].set(5.0)
+    if held:    # and the real lanes to some held expert past those
+        router = router.at[1:, first + k].set(5.0)
+    w_gate, w_in = (jax.random.normal(kk, (count, D, F)) * 0.3 for kk in ks[2:4])
+    w_out = jax.random.normal(ks[4], (count, F, D)) * 0.3
+    experts = tuple(a.at[:k].set(jnp.nan) for a in (w_gate, w_in, w_out))
+    valid = jnp.asarray([True, True, True, False])[:, None]
+
+    y, load = gpt._dropless_mlp(cfg, router, experts, x, x, valid=valid)
+    alone, load3 = gpt._dropless_mlp(cfg, router, experts, x[:3], x[:3])
+    assert np.isfinite(np.asarray(y[:3])).all() and float(jnp.abs(y[:3]).max()) > 0.01
+    assert float(jnp.abs(y[:3] - alone).max()) < 1e-5 * float(jnp.abs(alone).max())
+    assert (np.asarray(y[3]) == 0).all()
+    assert np.allclose(np.asarray(load), np.asarray(load3))
+    assert load.shape == ((5,) if held else (2,))
+    # unmasked, the same lane does choose them
+    bad, _ = gpt._dropless_mlp(cfg, router, experts, x, x)
+    assert np.isnan(np.asarray(bad[3])).all()
 
 
 @pytest.mark.parametrize("routing", list(ROUTINGS))
@@ -201,19 +296,16 @@ def test_the_kernels_in_interpret_mode_are_the_plain_tiles(tokens, dtype, stacke
     assert float(jnp.abs(kernel - plain).max()) < tol * size
 
 
-@pytest.mark.parametrize("tiles_by", ["plain", "pallas-interpret"])
+@pytest.mark.parametrize("tiles_by", TILES_BY)
 def test_the_grouped_form_differentiates_as_the_dense_one(tiles_by, monkeypatch):
     """`forward` takes this form too and has been differentiable: the plain
     loop's derivative is the backward pass, of the kernels as well."""
     import jax
     import jax.numpy as jnp
 
-    from ray_tpu.ops import attention, moe
+    from ray_tpu.ops import moe
 
-    if tiles_by != "plain":
-        monkeypatch.setattr(attention, "_on_tpu", lambda: True)
-        monkeypatch.setattr(moe, "_grouped_pallas", functools.partial(
-            moe._grouped_pallas, interpret=True))
+    _tiles_by(tiles_by, monkeypatch)
     x, combine, experts, k = _layer(40, (8, 3, "softmax", None), D=128, F=128)
 
     def loss(form):
@@ -254,16 +346,3 @@ def test_a_weight_block_divides_the_width_within_its_budget(rows, cols, itemsize
     got = moe._weight_tile(rows, cols, itemsize)
     assert got == want and cols % got == 0
     assert got == cols or rows * got * itemsize <= moe._BLOCK_BYTES
-
-
-@pytest.mark.parametrize("model,tokens,want", [
-    ("smallthinker-21b-a3b", 4, "loop"), ("smallthinker-21b-a3b", 10, "loop"),
-    ("smallthinker-21b-a3b", 11, "grouped"), ("smallthinker-21b-a3b", 512, "grouped"),
-    ("ax-k1", 16, "loop"), ("ax-k1", 23, "loop"), ("ax-k1", 24, "grouped"),
-    ("ax-k1", 512, "grouped")])
-def test_the_form_is_read_from_the_shape(model, tokens, want):
-    from ray_tpu.models import gpt
-
-    cfg = gpt.CONFIGS[model](moe_held=(0, 12)) if model == "ax-k1" else gpt.CONFIGS[model]()
-    assert gpt.moe_form(cfg, tokens) == want
-    assert (want == "loop") == gpt._few_tokens(cfg, tokens)

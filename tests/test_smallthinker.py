@@ -222,10 +222,10 @@ def test_routing_drops_no_token_when_all_choose_the_same_experts():
     assert (np.asarray(combine > 0).sum(-1) == k).all()
     want = sum(float(w[0, j]) * (jax.nn.relu(row @ w_gate[e]) * (row @ w_in[e])) @ w_out[e]
                for j, e in enumerate(np.asarray(idx)[0]))
-    for touched_k in (0, k):                           # dense, and the loop
+    for grouped_k in (0, k):                           # dense, and the served form
         y = moe.dropless_experts(x, combine, w_gate, w_in, w_out, "reglu",
-                                 touched_k=touched_k)
-        assert np.abs(np.asarray(y) - np.asarray(want)).max() < 1e-4, touched_k
+                                 grouped_k=grouped_k)
+        assert np.abs(np.asarray(y) - np.asarray(want)).max() < 1e-4, grouped_k
     touched, share = moe.dropless_load(combine)
     assert float(touched) == k and abs(float(share) - 1 / k) < 1e-6
     # the capacity path of training would have dropped most of these tokens
