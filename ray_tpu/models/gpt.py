@@ -18,6 +18,8 @@ dropless top-k experts; served through the paged programs), `ouro_2_6b`
 exit gate; served through the paged programs), `ax_k1` (latent attention
 over one compressed cache row a token, a leading dense layer, sigmoid-routed
 experts of which this chip holds a range, beside a shared expert; served
+through the paged programs), `jamba2_3b` (state-space layers whose state is
+one fixed slot a sequence, an attention layer every fourteenth; served
 through the paged programs).
 """
 
@@ -52,7 +54,7 @@ class GPTConfig:
     # Architecture knobs.
     norm: str = "layernorm"          # layernorm | rmsnorm
     activation: str = "gelu"         # gelu | swiglu | reglu (relu-gated)
-    pos: str = "learned"             # learned | rotary
+    pos: str = "learned"             # learned | rotary | none (no positional term)
     rotary_dim: int = 64
     rope_theta: float = 10000.0
     # Per-layer kinds (None = every layer alike), one entry a layer, as the
@@ -97,6 +99,20 @@ class GPTConfig:
     # `lead_<name>` and run through the same `_block` before the scan.
     dense_layers: int = 0
     d_dense_mlp: int = 0
+    # State-space layers (`ops/ssm.py`, Mamba-1 as Jamba runs it): one entry
+    # a layer, 1 = the layer's mixer is the state-space one, 0 = attention.
+    # The two mixers' weights are two stacks (`ssm_*` [layers of that kind,
+    # ...] beside the attention stack), the norms and the MLP one stack over
+    # all layers; the layer loop is cut into runs of one kind (`_mixed_layers`).
+    # Such a layer keeps no row a token: its state is one fixed slot a
+    # SEQUENCE (`KVLayout.state`). Inner width `ssm_expand` x d_model, a state
+    # of `ssm_state` a channel, a convolution over `ssm_conv` inputs, a step
+    # projected through `ssm_dt_rank`. A model with such layers has no bias.
+    ssm_layout: Optional[Tuple[int, ...]] = None
+    ssm_state: int = 16
+    ssm_conv: int = 4
+    ssm_expand: int = 2
+    ssm_dt_rank: int = 160
     tie_embeddings: bool = True
     # Mixture-of-Experts (expert parallelism over the ep mesh axis).
     mlp_type: str = "dense"          # dense | moe
@@ -145,7 +161,7 @@ class GPTConfig:
     sp_axis: str = "sp"
 
     def __post_init__(self):
-        for name in ("rope_layout", "sliding_window_layout"):
+        for name in ("rope_layout", "sliding_window_layout", "ssm_layout"):
             v = getattr(self, name)
             if v is not None:
                 v = tuple(int(bool(e)) for e in v)
@@ -187,6 +203,14 @@ class GPTConfig:
             raise ValueError(
                 "dense_layers: fewer than n_layers, of width d_dense_mlp, in a "
                 "one-pass model whose layers are of one attention kind")
+        if self.ssm_layout and (
+                self.layer_kinds is not None or self.ut_steps > 1 or self.kv_lora_rank
+                or self.dense_layers or self.mlp_type != "dense" or self.sandwich_norm
+                or self.parallel_block or self.pos == "learned"
+                or self.activation not in ("swiglu", "reglu")):
+            raise ValueError(
+                "ssm_layout: a one-pass model of plain (grouped-query) attention "
+                "layers without learned positions, a dense gated MLP in every layer")
         if self.sandwich_norm and self.parallel_block:
             raise ValueError("sandwich_norm norms each sublayer's output on its "
                              "way into the stream; a parallel_block has one sum")
@@ -194,6 +218,10 @@ class GPTConfig:
     @property
     def kv_heads(self) -> int:
         return self.n_kv_heads or self.n_heads
+
+    @property
+    def ssm_inner(self) -> int:
+        return self.ssm_expand * self.d_model
 
     @property
     def held_experts(self) -> int:
@@ -244,6 +272,13 @@ class GPTConfig:
             attn = (E * Rq + Rq + Rq * (Hd + Hr) + E * (Rkv + self.rotary_dim) + Rkv
                     + Rkv * 2 * Hd + Hd * E)
         norms = (4 if self.sandwich_norm else 2) * E
+        if self.ssm_layout:     # no bias anywhere; two mixers, one MLP shape
+            Di, N, R = self.ssm_inner, self.ssm_state, self.ssm_dt_rank
+            ssm = (E * 2 * Di + Di * self.ssm_conv + Di + Di * (R + 2 * N) + R + 2 * N
+                   + R * Di + Di + Di * N + Di + Di * E)
+            n_ssm = sum(self.ssm_layout)
+            return (n_ssm * ssm + (L - n_ssm) * attn + L * (3 * E * F + 2 * E)
+                    + V * E + E + (0 if self.tie_embeddings else E * V))
         total = (L * (attn + norms) + (L - self.dense_layers) * mlp_params
                  + self.dense_layers * 3 * E * self.d_dense_mlp
                  + V * E + (0 if self.tie_embeddings else E * V))
@@ -504,6 +539,72 @@ def ax_k1(**kw):
     )
 
 
+def jamba2_3b(**kw):
+    """AI21-Jamba2-3B (huggingface.co/ai21labs/AI21-Jamba2-3B, `model_type:
+    "jamba"`): 28 layers of 2560, layer i an attention layer where i % 14 ==
+    7 (20 query heads over ONE K/V head of 128, no positional term at all),
+    else a Mamba-1 mixer (inner width 5120, state 16, convolution 4, step
+    rank 160, three inner RMSNorms); every layer's MLP the dense SiLU-gated
+    one of 8192 (`num_experts` 1); RMSNorm 1e-6; vocabulary 65,536 TIED; no
+    bias anywhere. Serving only: `forward` (attn_impl="ref") and the paged
+    programs, whole on one chip."""
+    L = kw.get("n_layers", 28)
+    return GPTConfig(
+        **{
+            **dict(
+                n_layers=L,
+                d_model=2560,
+                n_heads=20,
+                n_kv_heads=1,
+                d_head=128,
+                d_mlp=8192,
+                vocab_size=65536,
+                max_seq=262144,
+                norm="rmsnorm",
+                activation="swiglu",
+                pos="none",
+                ssm_layout=tuple(int(l % 14 != 7) for l in range(L)),
+                ssm_state=16,
+                ssm_conv=4,
+                ssm_expand=2,
+                ssm_dt_rank=160,
+                tie_embeddings=True,
+                param_dtype=jnp.bfloat16,
+                # As for `smallthinker_21b_a3b`: a stream that keeps the
+                # token (embedding std 1) under layers that together add
+                # about twice as much. The state-space mixer's own numbers
+                # follow the family's published initialisation: `A_log` the
+                # log of 1..16, `b_dt` the inverse softplus of steps drawn
+                # log-uniform in 0.001-0.1, `D` 1, the step's projection of
+                # std `ssm_dt` / sqrt(rank). With steps that small a state
+                # decays over some 6-100 tokens and what it adds to the
+                # mixer's output beside the skip term D c is a sum over 16
+                # states of products B_t C_t' of two normed vectors: the
+                # gains of those two inner norms (`ssm_bc`, 3 each) make it
+                # the larger part, so that a state zeroed at a chunk's edge,
+                # a tail dropped or a padding token scanned moves the greedy
+                # tokens (`scripts/jamba_tolerance.py` reads each on the
+                # chip). The mixer's output is a product of five factors
+                # that each depend on its input (step, c, B, C, gate): at
+                # `ssm_out` 0.9 a mixer added 1.4 to a stream of 1-7 and 26
+                # of them grew a rounding 60-80fold (a sound bfloat16 engine
+                # read as far from float32 as float8 weights); at 0.2 a
+                # mixer adds 0.3, the stream ends at 2.2 (a fifth of its
+                # power the token's embedding) and a rounding grows 7fold.
+                # `q`, `k` 1.2 as `ouro_2_6b`; `o` 1.0 and `mlp_out` 0.35
+                # keep an attention layer's and an MLP's share beside it.
+                init="unit_stream",
+                init_gains=(("embed", 1.0), ("q", 1.2), ("k", 1.2), ("v", 1.0),
+                            ("o", 1.0), ("mlp_in", 1.0), ("mlp_out", 0.35),
+                            ("ssm_in", 1.0), ("ssm_conv", 1.0), ("ssm_x", 1.0),
+                            ("ssm_bc", 3.0), ("ssm_dt", 1.0), ("ssm_out", 0.2)),
+                attn_impl="ref",
+            ),
+            **kw,
+        }
+    )
+
+
 CONFIGS = {
     "gpt2-small": gpt2_small,
     "gpt2-medium": gpt2_medium,
@@ -513,6 +614,7 @@ CONFIGS = {
     "smallthinker-21b-a3b": smallthinker_21b_a3b,
     "ouro-2.6b": ouro_2_6b,
     "ax-k1": ax_k1,
+    "jamba2-3b": jamba2_3b,
 }
 
 
@@ -538,11 +640,11 @@ def param_logical_dims(cfg: GPTConfig) -> Dict[str, Tuple[Optional[str], ...]]:
         del dims["w_qkv"], dims["b_qkv"]
         dims["w_q"] = ("layers", "embed", "heads", "head_dim")
         dims["w_kv"] = ("layers", "embed", None, "heads", "head_dim")
-    if cfg.kv_lora_rank or cfg.dense_layers or cfg.moe_shared:
+    if cfg.kv_lora_rank or cfg.dense_layers or cfg.moe_shared or cfg.ssm_layout:
         raise NotImplementedError(
             "no sharding is written for latent attention (kv_lora_rank), "
-            "leading dense layers (dense_layers) or a shared expert "
-            "(moe_shared): they are served on one chip")
+            "leading dense layers (dense_layers), a shared expert (moe_shared) "
+            "or state-space layers (ssm_layout): they are served on one chip")
     if cfg.mlp_type == "moe":
         dims["moe_router"] = ("layers", "embed", "experts")
         dims["moe_w_in"] = ("layers", "experts", "embed", "mlp")
@@ -582,16 +684,25 @@ def param_logical_dims(cfg: GPTConfig) -> Dict[str, Tuple[Optional[str], ...]]:
 # embedding has std `embed` and a matrix of fan-in n has std gain / sqrt(n),
 # the gains from the preset (`GPTConfig.init_gains`, where each says why);
 # under sandwich norms a post-norm's weight is the constant `post_norm`; the
-# exit gate's logit has the size of `exit_gate`. The benchmark's token check
-# rests on this; no program's shape or time depends on the numbers.
+# exit gate's logit has the size of `exit_gate`. Under a TIED head random
+# weights score the input token by its own embedding's square, E x embed^2
+# beside a best other logit of 4.7 x sqrt(E) x embed x |stream|, and greedy
+# decoding repeats one token: the final norm's gain is +1 and -1 by turns
+# there, so that the head the stream meets, the embedding times those signs,
+# is as random as the embedding and at right angles to it in the mean (a
+# trained tied model does not answer with its input either). The benchmark's
+# token check rests on this; no program's shape or time depends on the numbers.
 def _init_unit_stream(rng, cfg: GPTConfig) -> Dict[str, jnp.ndarray]:
     if cfg.activation not in ("swiglu", "reglu") or cfg.parallel_block \
-            or cfg.pos != "rotary" or cfg.tie_embeddings or not cfg.init_gains:
+            or cfg.pos == "learned" or (cfg.tie_embeddings and not cfg.ssm_layout) \
+            or not cfg.init_gains:
         raise NotImplementedError(
-            'init="unit_stream" covers rotary models with a gated MLP or gated '
-            "experts and an untied head whose preset states `init_gains`")
+            'init="unit_stream" covers models without learned positions, with a '
+            "gated MLP or gated experts and an untied head (tied with state-space "
+            "layers) whose preset states `init_gains`")
     E, F, V, X = cfg.d_model, cfg.d_mlp, cfg.vocab_size, cfg.held_experts
     L = cfg.n_layers - cfg.dense_layers         # the scanned stack's layers
+    La = L - sum(cfg.ssm_layout or ())          # the attention stack's layers
     H, Hkv, Dh = cfg.n_heads, cfg.kv_heads, cfg.d_head
     k = jax.random.split(rng, 16)
     dt, g = cfg.param_dtype, dict(cfg.init_gains)
@@ -603,11 +714,16 @@ def _init_unit_stream(rng, cfg: GPTConfig) -> Dict[str, jnp.ndarray]:
     ones, zeros = (lambda: jnp.ones((L, E), dt)), (lambda: jnp.zeros((L, E), dt))
     params = {
         "tok_embed": n(k[0], (V, E), g["embed"], 1),
-        "ln_f_w": jnp.ones((E,), dt), "ln_f_b": jnp.zeros((E,), dt),
-        "w_o": n(k[2], (L, H, Dh, E), g["o"], H * Dh), "b_o": zeros(),
+        "ln_f_w": (jnp.where(jnp.arange(E) % 2, -1, 1).astype(dt)
+                   if cfg.tie_embeddings else jnp.ones((E,), dt)),
+        "ln_f_b": jnp.zeros((E,), dt),
+        "w_o": n(k[2], (La, H, Dh, E), g["o"], H * Dh), "b_o": zeros(),
         "ln1_w": ones(), "ln1_b": zeros(), "ln2_w": ones(), "ln2_b": zeros(),
-        "lm_head": n(k[7], (E, V), g["head"], E),
     }
+    if not cfg.tie_embeddings:
+        params["lm_head"] = n(k[7], (E, V), g["head"], E)
+    if cfg.ssm_layout:
+        params.update(_init_ssm_stack(k[11], cfg, n))
     if cfg.kv_lora_rank:
         # Latent attention: both latents are normed (weights of one); the
         # shared rotary key columns of `w_dkv` and the per-head key half of
@@ -624,8 +740,8 @@ def _init_unit_stream(rng, cfg: GPTConfig) -> Dict[str, jnp.ndarray]:
                                       n(k[15], (L, Rkv, H, Dh), g["v"], Rkv)], axis=-1),
         })
     else:
-        q, kv = n(k[1], (L, E, H, Dh), g["q"], E), [
-            n(k[9], (L, E, Hkv, Dh), g["k"], E), n(k[10], (L, E, Hkv, Dh), g["v"], E)]
+        q, kv = n(k[1], (La, E, H, Dh), g["q"], E), [
+            n(k[9], (La, E, Hkv, Dh), g["k"], E), n(k[10], (La, E, Hkv, Dh), g["v"], E)]
         if Hkv != H:    # the layouts of `init_params`
             params.update({"w_q": q, "w_kv": jnp.stack(kv, axis=2)})
         else:
@@ -661,7 +777,41 @@ def _init_unit_stream(rng, cfg: GPTConfig) -> Dict[str, jnp.ndarray]:
     if cfg.dense_layers:    # the leading layers: the same block, a dense MLP
         lead = _init_unit_stream(jax.random.fold_in(k[12], 1), _lead_cfg(cfg))
         params.update({"lead_" + name: a for name, a in _layer_stack(lead).items()})
+    if cfg.ssm_layout:      # as published: no bias anywhere
+        params = {name: a for name, a in params.items() if name not in _BIAS_KEYS}
     return params
+
+
+_BIAS_KEYS = ("ln_f_b", "b_qkv", "b_o", "ln1_b", "ln2_b", "b_in", "b_out")
+
+
+def _init_ssm_stack(rng, cfg: GPTConfig, n) -> Dict[str, jnp.ndarray]:
+    """The state-space mixers' stack [layers of that kind, ...] under the
+    names `_layer_stack` knows (`ssm_<name of ops/ssm.py>`): the family's
+    published initialisation (`jamba2_3b` says which and why), matrices
+    through `n` (key, shape, gain, fan-in). `A_log` is kept state-major, [N,
+    Di], the published [Di, N] transposed: channels on the lanes."""
+    E, Di, N, R, K = cfg.d_model, cfg.ssm_inner, cfg.ssm_state, cfg.ssm_dt_rank, cfg.ssm_conv
+    Ls, dt, g = sum(cfg.ssm_layout), cfg.param_dtype, dict(cfg.init_gains)
+    k = jax.random.split(rng, 6)
+    step = jnp.exp(jax.random.uniform(k[5], (Ls, Di), jnp.float32)
+                   * (math.log(0.1) - math.log(0.001)) + math.log(0.001))
+    return {
+        "ssm_w_in": n(k[0], (Ls, E, 2 * Di), g["ssm_in"], E),
+        "ssm_conv_w": n(k[1], (Ls, K, Di), g["ssm_conv"], K),
+        "ssm_conv_b": jnp.zeros((Ls, Di), dt),
+        "ssm_w_x": n(k[2], (Ls, Di, R + 2 * N), g["ssm_x"], Di),
+        "ssm_dt_norm_w": jnp.ones((Ls, R), dt),
+        "ssm_b_norm_w": jnp.full((Ls, N), g["ssm_bc"], dt),
+        "ssm_c_norm_w": jnp.full((Ls, N), g["ssm_bc"], dt),
+        "ssm_w_dt": n(k[3], (Ls, R, Di), g["ssm_dt"], R),
+        "ssm_b_dt": (step + jnp.log(-jnp.expm1(-step))).astype(dt),
+        "ssm_A_log": jnp.broadcast_to(
+            jnp.log(jnp.arange(1, N + 1, dtype=jnp.float32))[None, :, None],
+            (Ls, N, Di)).astype(dt),
+        "ssm_D": jnp.ones((Ls, Di), dt),
+        "ssm_w_out": n(k[4], (Ls, Di, E), g["ssm_out"], Di),
+    }
 
 
 def _lead_cfg(cfg: GPTConfig) -> GPTConfig:
@@ -683,10 +833,12 @@ def init_params(rng, cfg: GPTConfig) -> Dict[str, jnp.ndarray]:
         return _init_unit_stream(rng, cfg)
     if cfg.init != "gpt2":
         raise ValueError(f"init {cfg.init!r}: gpt2 | unit_stream")
-    if cfg.kv_lora_rank or cfg.dense_layers or cfg.moe_shared or cfg.moe_held:
+    if cfg.kv_lora_rank or cfg.dense_layers or cfg.moe_shared or cfg.moe_held \
+            or cfg.ssm_layout:
         raise NotImplementedError(
             'init="gpt2" makes no latent attention, leading dense layers, shared '
-            'expert or held range of experts: such a preset states init="unit_stream"')
+            'expert, held range of experts or state-space layers: such a preset '
+            'states init="unit_stream"')
     E, L, F, V = cfg.d_model, cfg.n_layers, cfg.d_mlp, cfg.vocab_size
     H, Dh = cfg.n_heads, cfg.d_head
     k = jax.random.split(rng, 16)
@@ -895,8 +1047,10 @@ def _mlp(cfg: GPTConfig, p, router, block_in, mlp_in, stacks=None, layer=None,
         if cfg.activation == "swiglu":
             moe_params["w_gate"] = p["moe_w_gate"]
         y, aux = moe_forward(moe_params, mlp_in, cfg.moe_config)
-    else:
+    elif "b_in" in p:
         y = _dense_mlp(cfg, p, mlp_in)
+    else:       # a model without biases
+        y = _gated_mlp(cfg, mlp_in, p["w_gate"], p["w_in"], p["w_out"])
     return y, aux, load
 
 
@@ -944,9 +1098,9 @@ def _refuse_new_fields(cfg: GPTConfig, what: str):
     """A check on input for the programs that cannot take a field: the dense
     cache [L, B, H, M, Dh] has no K/V-head count and no window, and the
     stage split cuts no per-layer kinds and loops over no passes. Grouped-query
-    heads, per-layer kinds, a looped stack, latent attention and leading
-    dense layers run in `forward` (attn_impl="ref" but for the looped stack)
-    and in the paged programs."""
+    heads, per-layer kinds, a looped stack, latent attention, leading dense
+    layers and state-space layers run in `forward` (attn_impl="ref" but for
+    the looped stack) and in the paged programs."""
     bad = []
     if cfg.kv_heads != cfg.n_heads:
         bad.append("grouped-query heads (n_kv_heads)")
@@ -960,6 +1114,9 @@ def _refuse_new_fields(cfg: GPTConfig, what: str):
                    "compressed row a token")
     if cfg.dense_layers:
         bad.append("leading dense layers (dense_layers) beside the scanned stack")
+    if cfg.ssm_layout:
+        bad.append("state-space layers (ssm_layout): two stacks of mixers, and "
+                   "a state a sequence that no cache here keeps")
     if bad:
         raise NotImplementedError(f"{what} does not support " + ", ".join(bad))
 
@@ -1048,7 +1205,7 @@ def _project_latent(cfg: GPTConfig, p, h, rope_tables, positions):
 
 
 def _block(cfg: GPTConfig, rope_tables, attend, x, layer_params, positions,
-           kind=None, stacks=None, layer=None, valid=None):
+           kind=None, stacks=None, layer=None, valid=None, mixer=None):
     """One transformer block, the only one: x [B, S, E] in cfg.dtype ->
     (x, the state `attend` hands back, MoE aux loss, dropless load or None).
 
@@ -1058,13 +1215,18 @@ def _block(cfg: GPTConfig, rope_tables, attend, x, layer_params, positions,
     operands of `_project_latent` (one key row a token, `v` None) -> [B, H, S,
     kv_lora_rank]. `kind`: this layer's entry of `_layer_kind_xs` (traced
     scalars) for a model with layers of two kinds; `stacks`, `layer`,
-    `valid`: see `_mlp`."""
+    `valid`: see `_mlp`. `mixer(p, h)`, where the layer's mixer is not
+    attention (a state-space layer): the normed input [B, S, E] -> (what the
+    mixer adds to the stream [B, S, E], the state it hands back), in
+    `attend`'s place. A model without biases lacks their keys."""
     # Cast this layer's master weights to compute dtype (bf16 → MXU).
     p = jax.tree_util.tree_map(lambda a: a.astype(cfg.dtype), layer_params)
     block_in = x
 
-    h = _norm(x, p["ln1_w"], p["ln1_b"], cfg.norm)
-    if cfg.kv_lora_rank:
+    h = _norm(x, p["ln1_w"], p.get("ln1_b"), cfg.norm)
+    if mixer is not None:
+        attn_out, state = mixer(p, h)
+    elif cfg.kv_lora_rank:
         q, k, expand = _project_latent(cfg, p, h, rope_tables, positions)
         attn, state = attend(q, k, None, kind)
         attn = expand(attn)
@@ -1077,7 +1239,10 @@ def _block(cfg: GPTConfig, rope_tables, attend, x, layer_params, positions,
             q, k = (qr, kr) if kind is None else (
                 jnp.where(kind["rope"], qr, q), jnp.where(kind["rope"], kr, k))
         attn, state = attend(q, k, v, kind)
-    attn_out = jnp.einsum("bhsd,hde->bse", attn, p["w_o"]) + p["b_o"]
+    if mixer is None:
+        attn_out = jnp.einsum("bhsd,hde->bse", attn, p["w_o"])
+        if "b_o" in p:
+            attn_out = attn_out + p["b_o"]
     if cfg.sandwich_norm:
         attn_out = _norm(attn_out, p["ln1_post_w"], p["ln1_post_b"], cfg.norm)
 
@@ -1085,7 +1250,7 @@ def _block(cfg: GPTConfig, rope_tables, attend, x, layer_params, positions,
         mlp_in = h  # GPT-J: same normed input feeds attn and mlp
     else:
         x = x + attn_out
-        mlp_in = _norm(x, p["ln2_w"], p["ln2_b"], cfg.norm)
+        mlp_in = _norm(x, p["ln2_w"], p.get("ln2_b"), cfg.norm)
     mlp_out, aux, load = _mlp(cfg, p, layer_params.get("moe_router"), block_in,
                               mlp_in, stacks, layer, valid)
     if cfg.sandwich_norm:
@@ -1101,6 +1266,12 @@ _LAYER_KEYS = (
     "w_dq", "q_norm_w", "w_uq", "w_dkv", "kv_norm_w", "w_ukv",
     "shared_w_in", "shared_w_gate", "shared_w_out",
 )
+# A state-space mixer's weights, `ssm_<name>` here for `ops/ssm.py`'s <name>:
+# a stack of their own, one entry a state-space layer.
+_SSM_KEYS = tuple("ssm_" + name for name in (
+    "w_in", "conv_w", "conv_b", "w_x", "dt_norm_w", "b_norm_w", "c_norm_w",
+    "w_dt", "b_dt", "A_log", "D", "w_out"))
+_ATTN_KEYS = ("w_qkv", "b_qkv", "w_q", "w_kv", "w_o", "b_o")
 
 
 def _embed(params, tokens, positions, cfg: GPTConfig):
@@ -1143,7 +1314,47 @@ def _rope_tables(cfg: GPTConfig):
 
 def _layer_stack(params):
     """The stacked per-layer weights [L, ...]: what the layer scan cuts."""
-    return {k: params[k] for k in _LAYER_KEYS if k in params}
+    return {k: params[k] for k in _LAYER_KEYS + _SSM_KEYS if k in params}
+
+
+def _ssm_weights(p):
+    """A layer's state-space mixer weights under `ops/ssm.py`'s names."""
+    return {k[4:]: p[k] for k in _SSM_KEYS}
+
+
+def _mixed_layers(cfg: GPTConfig, layer_stack, carry, attn_layer, ssm_layer):
+    """The layer loop of a model whose `ssm_layout` deals its layers between
+    two mixers: cut, from the static layout, into runs of one kind. A run of
+    state-space layers is one `lax.scan` over (layer, its index in the
+    `ssm_*` stack); an attention layer between two runs is applied as it
+    stands. The stacks stay whole and a layer's weights are read where they
+    lie, by index (a slice the scan cuts out of a stack would be a copy of
+    it). `attn_layer(carry, a, p)` / `ssm_layer(carry, m, p)`: carry -> carry,
+    `a` / `m` the layer's index among its kind (traced in a scan), `p` its
+    weights: the norms and the MLP of layer l beside the mixer's own."""
+    mixers = _ATTN_KEYS + _SSM_KEYS
+    shared = {k: v for k, v in layer_stack.items() if k not in mixers}
+
+    def weights(l, i, keys):
+        return {**{k: v[l] for k, v in shared.items()},
+                **{k: layer_stack[k][i] for k in keys if k in layer_stack}}
+
+    l = a = m = 0
+    layout = cfg.ssm_layout
+    while l < len(layout):
+        if not layout[l]:
+            carry = attn_layer(carry, a, weights(l, a, _ATTN_KEYS))
+            l, a = l + 1, a + 1
+            continue
+        run = next((j for j in range(l, len(layout)) if not layout[j]), len(layout)) - l
+
+        def body(carry, idx):
+            return ssm_layer(carry, idx[1], weights(idx[0], idx[1], _SSM_KEYS)), None
+
+        carry, _ = jax.lax.scan(
+            body, carry, (jnp.arange(l, l + run), jnp.arange(m, m + run)))
+        l, m = l + run, m + run
+    return carry
 
 
 def _close_pass(params, x, cfg: GPTConfig):
@@ -1173,7 +1384,7 @@ def _logits(params, x, cfg: GPTConfig):
     cfg.dtype; the cache programs hand float32 on. A looped model's stream
     was normed when its last pass closed (`_close_pass`): no second norm."""
     if cfg.ut_steps == 1:
-        x = _norm(x, params["ln_f_w"], params["ln_f_b"], cfg.norm)
+        x = _norm(x, params["ln_f_w"], params.get("ln_f_b"), cfg.norm)
     head = params["tok_embed"].T if cfg.tie_embeddings else params["lm_head"]
     return jnp.einsum("...e,ev->...v", x, head.astype(cfg.dtype))
 
@@ -1186,7 +1397,9 @@ def _layer_loop(cfg: GPTConfig, mesh, positions):
     looped model (`forward` alone) hands `close_pass` too, x -> x: the scan
     then runs `ut_steps` times over the same stack, closed over and not cut
     a pass, each pass ended by `close_pass`; aux is [passes x L]. A model
-    with leading dense layers hands their stack as `lead` (`_lead_stack`)."""
+    with leading dense layers hands their stack as `lead` (`_lead_stack`). A
+    model with state-space layers runs `_mixed_layers`, every sequence's
+    state starting from zero and dropped at the end."""
 
     def attend(q, k, v, kind):
         if kind is None and cfg.kv_heads == cfg.n_heads and not cfg.kv_lora_rank:
@@ -1208,7 +1421,27 @@ def _layer_loop(cfg: GPTConfig, mesh, positions):
         x, _, aux, _ = block(x, layer_params, positions, kind=kind)
         return x, aux
 
+    def mixed(x, layer_stack):
+        from ..ops import ssm
+
+        B, S, _ = x.shape
+        everyone = jnp.ones((B, S), bool)
+        tail = jnp.zeros((B, cfg.ssm_conv - 1, cfg.ssm_inner), cfg.dtype)
+        s0 = jnp.zeros((B, *ssm.state_shape(cfg.ssm_inner, cfg.ssm_state)), jnp.float32)
+
+        def mixer(p, h):
+            return ssm.mamba_mixer(_ssm_weights(p), h, tail, s0, everyone)[0], None
+
+        plain = functools.partial(_block, cfg, None, attend)    # no remat: served
+        x = _mixed_layers(
+            cfg, layer_stack, x,
+            lambda x, a, p: plain(x, p, positions)[0],
+            lambda x, m, p: plain(x, p, positions, mixer=mixer)[0])
+        return x, jnp.zeros((cfg.n_layers,), jnp.float32)
+
     def run(x, layer_stack, kinds=None, close_pass=None, lead=None):
+        if cfg.ssm_layout:
+            return mixed(x, layer_stack)
         if cfg.dense_layers:    # the leading dense layers, the same block
             lead_block = functools.partial(
                 _block, _lead_cfg(cfg), _rope_tables(cfg), attend)
@@ -1311,7 +1544,8 @@ def _refuse_looped_training(cfg: GPTConfig, what: str):
     So it refuses what only the serving programs were written for: latent
     attention, leading dense layers, a shared expert, a held range of
     experts (no gradient is exchanged for the absent ones), sigmoid scoring
-    (its balance term is not written)."""
+    (its balance term is not written), state-space layers (the scan's
+    backward pass is not written)."""
     if cfg.ut_steps > 1:
         raise NotImplementedError(
             f"{what} does not train a model whose layers run several times "
@@ -1320,7 +1554,8 @@ def _refuse_looped_training(cfg: GPTConfig, what: str):
     served = [name for name, on in (
         ("kv_lora_rank", cfg.kv_lora_rank), ("dense_layers", cfg.dense_layers),
         ("moe_shared", cfg.moe_shared), ("moe_held", cfg.moe_held),
-        ("moe_scoring", cfg.moe_scoring != "softmax")) if on]
+        ("moe_scoring", cfg.moe_scoring != "softmax"),
+        ("ssm_layout", cfg.ssm_layout)) if on]
     if served:
         raise NotImplementedError(
             f"{what} does not train a model with {', '.join(served)}: "
@@ -1807,8 +2042,10 @@ def decode_step(params, token, cache, cfg: GPTConfig):
 
 @dataclasses.dataclass(frozen=True)
 class KVLayout:
-    """How the layers share the one paged pool [per_group, NB, BS, row].
+    """What the layers keep between programs, and how they share it. Four
+    things are declared here, each once:
 
+    GROUPS: how the layers share the one paged pool [per_group, NB, BS, row].
     A model whose layers are all of one kind is ONE group: per_group = L,
     layer l keeps its rows at pool[l], one block table a sequence. With
     global and window layers the layers are dealt into groups of equal
@@ -1817,34 +2054,41 @@ class KVLayout:
     x block_size tokens -- has the same bytes whichever group holds it and
     every group draws from the same `num_blocks`. A sequence has one block
     table a group; a window group gives back the blocks that fell behind
-    its window while the sequence lives (serve/engine/kv_manager.py).
+    its window while the sequence lives (serve/engine/kv_manager.py). A
+    model with leading dense layers keeps their rows first: depth =
+    n_layers, the scanned stack's layer i at pool[dense_layers + i].
 
-    A looped model (`ut_steps` passes over the same layers) keeps keys and
-    values a (pass, layer) pair: the pool's leading dimension is `depth` =
-    passes x per_group CACHE layers, pass t of layer l at pool[t * per_group
-    + slot_of[l]]; block tables, groups and windows are the layers' own. A
-    block is `depth` rows deep, so whoever reckons a block's bytes or takes
-    the pool's depth takes it from here, not from `n_layers`.
+    PASSES: a looped model (`ut_steps` passes over the same layers) keeps
+    keys and values a (pass, layer) pair: the pool's leading dimension is
+    `depth` = passes x per_group CACHE layers, pass t of layer l at pool[t *
+    per_group + slot_of[l]]; block tables, groups and windows are the
+    layers' own. Whoever reckons a block's bytes or takes the pool's depth
+    takes it from here (`depth`, `block_bytes`), not from `n_layers`.
 
-    What a layer keeps a token is declared here too: a key row `key_row`
-    wide and a value row `value_row` wide, the pool's arrays "k" and "v". A
-    multi-head or grouped-query layer keeps kv_heads x d_head of each. A
-    LATENT layer (`kv_lora_rank`) keeps ONE row: the normed latent beside
-    the rotated key features every head shares, kv_lora_rank + rotary_dim
-    numbers, padded with zeros to a whole number of 128-column tiles (576 ->
-    640), and `value_row` is 0: its values are the key row's first
-    kv_lora_rank columns, and the pool has no "v". Why 640 in one array, not
-    576 and not two arrays (512 and 64): the compile-only rehearsal for the
-    v5e (`scripts/paged_rehearse.py`, PR 34) kept a pool of 576-wide rows
+    ROWS: what a layer keeps a TOKEN, the pool's arrays "k" and "v": a key
+    row `key_row` wide and a value row `value_row` wide. A multi-head or
+    grouped-query layer keeps kv_heads x d_head of each. A LATENT layer
+    (`kv_lora_rank`) keeps ONE row: the normed latent beside the rotated key
+    features every head shares, kv_lora_rank + rotary_dim numbers, padded
+    with zeros to a whole number of 128-column tiles (576 -> 640), and
+    `value_row` is 0: its values are the key row's first kv_lora_rank
+    columns, and the pool has no "v". Why 640 in one array, not 576 and not
+    two arrays (512 and 64): the compile-only rehearsal for the v5e
+    (`scripts/paged_rehearse.py`, PR 34) kept a pool of 576-wide rows
     block-index-minor and copied all of it into and out of that layout in
-    every program (two pool-sized `copy` operations, 2 GiB each: what PR 25
-    removed for K/V rows, which tile exactly when their width is a multiple
-    of 128); a second array of 64 would half-fill a tile and meet the same
-    copy; rows of 640 stay row-major and are updated in place. The price is
-    a ninth more depth in the score product (the zeros add nothing to a
-    score) and 1,280 B a token a layer for 1,152. A model with leading dense
-    layers keeps their rows first: depth = n_layers, the scanned stack's
-    layer i at pool[dense_layers + i]."""
+    every program (what PR 25 removed for K/V rows, which tile exactly when
+    their width is a multiple of 128); a second array of 64 would half-fill
+    a tile and meet the same copy; rows of 640 stay row-major and are
+    updated in place, for a ninth more depth in the score product.
+
+    STATE: what a layer keeps a SEQUENCE, whatever its length. A state-space
+    layer (`ssm_layout`) keeps no row: `state_layers` of them keep the
+    arrays `state` names, (name, shape a layer a slot, dtype) each, one
+    fixed slot a sequence, beside the pool (`init_paged_cache`): the scan's
+    float32 state in the shape `ops/ssm.py` keeps it and the convolution's
+    last inputs as one row. `slot_of` of such a layer is its index in those
+    arrays; the pool is as deep as the layers that DO keep rows (`per_group`
+    counts them alone)."""
 
     per_group: int                  # layers in a group
     windows: Tuple[int, ...]        # per group: 0 = keeps every token, else the window
@@ -1853,6 +2097,8 @@ class KVLayout:
     passes: int = 1                 # rows a layer keeps: one a pass
     key_row: int = 0                # width of the row in pool "k"
     value_row: int = 0              # width of the row in pool "v"; 0: no such pool
+    state_layers: int = 0           # layers that keep a state a sequence and no row
+    state: Tuple[Tuple[str, Tuple[int, ...], str], ...] = ()
 
     @property
     def depth(self) -> int:
@@ -1864,6 +2110,12 @@ class KVLayout:
         tokens x the rows' widths (what the device pads is not counted)."""
         return self.depth * block_size * (self.key_row + self.value_row) * itemsize
 
+    @property
+    def state_bytes(self) -> int:
+        """Bytes one sequence's slot holds, over all state layers."""
+        return self.state_layers * sum(
+            math.prod(shape) * np.dtype(dtype).itemsize for _, shape, dtype in self.state)
+
 
 @functools.lru_cache(maxsize=None)
 def kv_layout(cfg: GPTConfig) -> KVLayout:
@@ -1871,6 +2123,16 @@ def kv_layout(cfg: GPTConfig) -> KVLayout:
     kinds = cfg.layer_kinds
     rows = ((-(-(cfg.kv_lora_rank + cfg.rotary_dim) // 128) * 128, 0)
             if cfg.kv_lora_rank else (cfg.kv_heads * cfg.d_head,) * 2)
+    if cfg.ssm_layout:      # rows for the attention layers, a slot's state for the rest
+        from ..ops import ssm
+
+        kept = [sum(1 for j in cfg.ssm_layout[:l] if j == kind)
+                for l, kind in enumerate(cfg.ssm_layout)]
+        n_ssm = sum(cfg.ssm_layout)
+        return KVLayout(
+            L - n_ssm, (0,), (0,) * L, tuple(kept), 1, *rows, n_ssm,
+            (("conv", ((cfg.ssm_conv - 1) * cfg.ssm_inner,), jnp.dtype(cfg.dtype).name),
+             ("ssm", ssm.state_shape(cfg.ssm_inner, cfg.ssm_state), "float32")))
     win = kinds[1] if kinds is not None else (0,) * L
     glob = [l for l in range(L) if not win[l]]
     wind = [l for l in range(L) if win[l]]
@@ -1888,16 +2150,24 @@ def kv_layout(cfg: GPTConfig) -> KVLayout:
                     cfg.ut_steps, *rows)
 
 
-def init_paged_cache(cfg: GPTConfig, num_blocks: int, block_size: int):
+def init_paged_cache(cfg: GPTConfig, num_blocks: int, block_size: int,
+                     state_slots: int = 0):
     """Physical paged KV pool: {"k","v"} of [depth, NB, BS, Hkv*Dh] in
     cfg.dtype (`kv_layout`; depth = L for a one-pass model of one kind); for
     a latent model {"k"} alone, [depth, NB, BS, the latent row padded to
-    whole tiles]: the rows the layout declares."""
+    whole tiles]: the rows the layout declares. For a model whose layout
+    declares STATE, beside them {"state": {name: [state_layers, 1 +
+    state_slots, *shape]}}: `state_slots` sequences' slots behind slot 0, the
+    null slot that padding lanes read and write, as block 0 is."""
     lay = kv_layout(cfg)
     shape = (lay.depth, num_blocks, block_size)
     pool = {"k": jnp.zeros(shape + (lay.key_row,), cfg.dtype)}
     if lay.value_row:
         pool["v"] = jnp.zeros(shape + (lay.value_row,), cfg.dtype)
+    if lay.state:
+        pool["state"] = {
+            name: jnp.zeros((lay.state_layers, 1 + state_slots, *dims), dtype)
+            for name, dims, dtype in lay.state}
     return pool
 
 
@@ -1939,7 +2209,8 @@ def paged_attn_keys(lanes: int, width: int, block_size: int, last_pos, real):
     return lanes * int(trips) * tile * block_size, lanes * width * block_size
 
 
-def _paged_layers(params, tokens, pos, valid, block_tables, kv, cfg: GPTConfig):
+def _paged_layers(params, tokens, pos, valid, block_tables, kv, cfg: GPTConfig,
+                  state_slots=None):
     """Embedding and the layer loop of the three paged programs: lane b
     brings S new tokens, token j at global position pos[b, j], over its
     block table.
@@ -1985,6 +2256,15 @@ def _paged_layers(params, tokens, pos, valid, block_tables, kv, cfg: GPTConfig):
     `kv_lora_rank` columns: `_block` hands the absorbed operands, the pool
     is "k" alone. Leading dense layers run before the scan through the
     same `_block` and `attend`, their rows first in the pool.
+
+    A model with state-space layers (`ssm_layout`) runs `_mixed_layers`: its
+    attention layers as above over a pool as deep as they are many, its
+    state-space layers through `ops/ssm.py`'s mixer from the state of lane
+    b's slot `state_slots[b]` in kv["state"] (gathered, advanced over the
+    lane's real tokens, written back in place: the arrays ride the loop's
+    carry beside the pool). A lane whose first token sits at position 0
+    starts from a ZERO state, whatever its slot held: a new sequence, or a
+    preempted one that recomputes. Padding lanes name slot 0.
 
     Returns (hidden states [B, S, E] before the final norm -- after it for
     a looped model, kv, None or the mean over layers of (experts touched,
@@ -2172,6 +2452,44 @@ def _paged_layers(params, tokens, pos, valid, block_tables, kv, cfg: GPTConfig):
     def pool(kk, vv):
         return {"k": kk} if vv is None else {"k": kk, "v": vv}
 
+    if cfg.ssm_layout:
+        if state_slots is None:
+            raise NotImplementedError(
+                "a model with state-space layers (ssm_layout) is served by "
+                "prefill_paged and decode_step_paged, which name each lane's "
+                "state slot; a verify step would have to roll the state back "
+                "past the drafts it rejects")
+        from ..ops import ssm
+
+        fresh = (pos[:, 0] == 0)[:, None]
+        tail_shape = (B, cfg.ssm_conv - 1, cfg.ssm_inner)
+
+        def attn_layer(carry, a, p):
+            x, kk, vv, st = carry
+            x, (kk, vv), _, _ = _block(
+                cfg, rope_tables, functools.partial(attend, kk, vv, a, None), x, p,
+                pos, valid=real)
+            return x, kk, vv, st
+
+        def ssm_layer(carry, m, p):
+            x, kk, vv, st = carry
+
+            def mixer(p, h):
+                tail = jnp.where(fresh, 0, st["conv"][m, state_slots])
+                s0 = jnp.where(fresh[..., None, None], 0, st["ssm"][m, state_slots])
+                out, tail, s = ssm.mamba_mixer(
+                    _ssm_weights(p), h, tail.reshape(tail_shape), s0, real)
+                return out, (tail.reshape(B, -1), s)
+
+            x, (tail, s), _, _ = _block(cfg, None, None, x, p, pos, valid=real,
+                                        mixer=mixer)
+            return x, kk, vv, {"conv": st["conv"].at[m, state_slots].set(tail),
+                               "ssm": st["ssm"].at[m, state_slots].set(s)}
+
+        x, kk, vv, st = _mixed_layers(
+            cfg, layer_stack, (x, kv["k"], kv["v"], kv["state"]), attn_layer, ssm_layer)
+        return x, {"k": kk, "v": vv, "state": st}, None, None
+
     carry = (x, kv["k"], kv.get("v"))
     if cfg.dense_layers:    # the leading dense layers: pool rows 0 .. their count
         def lead_body(carry, inp):
@@ -2201,7 +2519,7 @@ def _paged_layers(params, tokens, pos, valid, block_tables, kv, cfg: GPTConfig):
 
 
 def prefill_paged(params, tokens, real_len, pos_offset, block_table, kv,
-                  cfg: GPTConfig):
+                  cfg: GPTConfig, state_slot=None):
     """Prompt prefill into the paged cache, one CHUNK of one sequence per
     call (chunked prefill: a long prompt lands a slice per engine step so
     decode streams keep emitting between slices).
@@ -2214,20 +2532,26 @@ def prefill_paged(params, tokens, real_len, pos_offset, block_table, kv,
     groups). Prefix-cache hits and earlier chunks' KV below
     `pos_offset` are read from the cache, never recomputed, and a
     monolithic prefill is just the pos_offset=0 chunk covering the whole
-    prompt. K/V of padded positions go to the null block. Returns
+    prompt. K/V of padded positions go to the null block. For a model with
+    state (`KVLayout.state`) `state_slot` (a traced scalar) names the
+    sequence's slot in kv["state"]: the chunk continues the state the chunk
+    before it left there (from zero where pos_offset is 0), and its padding
+    advances nothing. Returns
     (next-token logits [V] f32 at global position pos_offset + real_len -
     1, kv) — only meaningful on the FINAL chunk of a prompt.
     """
     rel = jnp.arange(tokens.shape[1])
     pos = (pos_offset + rel)[None]               # global token positions [1, Sp]
     x, kv, _, _ = _paged_layers(
-        params, tokens, pos, (rel < real_len)[None], block_table[None], kv, cfg
+        params, tokens, pos, (rel < real_len)[None], block_table[None], kv, cfg,
+        None if state_slot is None else state_slot[None]
     )
     h = x[0, jnp.maximum(real_len - 1, 0)]  # [E] — last REAL chunk position
     return _logits(params, h, cfg).astype(jnp.float32), kv
 
 
-def decode_step_paged(params, token, positions, block_tables, kv, cfg: GPTConfig):
+def decode_step_paged(params, token, positions, block_tables, kv, cfg: GPTConfig,
+                      state_slots=None):
     """One iteration-level decode step over the paged cache.
 
     token [B] int32 — each lane's current token (written at `positions[b]`,
@@ -2241,10 +2565,13 @@ def decode_step_paged(params, token, positions, block_tables, kv, cfg: GPTConfig
     mean over layers, padding lanes left out), kv. For a looped model
     (`cfg.ut_steps` > 1) what its exit gate read comes back the same way:
     (logits, [passes run] f32 = the exit distribution, one entry a pass the
-    program ran, mean over the real lanes), kv.
+    program ran, mean over the real lanes), kv. For a model with state,
+    `state_slots` [B] int32 names each lane's slot in kv["state"] (a padding
+    lane's is 0): the step is a chunk of one token from that state.
     """
     x, kv, load, exits = _paged_layers(
-        params, token[:, None], positions[:, None], True, block_tables, kv, cfg
+        params, token[:, None], positions[:, None], True, block_tables, kv, cfg,
+        state_slots
     )
     logits = _logits(params, x[:, 0], cfg).astype(jnp.float32)
     facts = tuple(a for a in (load, exits) if a is not None)

@@ -80,6 +80,13 @@ _STEP_BOOKS = (
     "loop_ns", "waited_ns", *flight.SERVE_STEP_PHASES,
     "decode_lanes", "decode_bucket_lanes", "decode_lanes_beside_chunk",
     "prefill_tokens", "prefill_tokens_padded",
+    # A model with state a sequence (`KVLayout.state`), else 0: tokens its
+    # scans ran over as the programs were shaped and those of them the mask
+    # held still (a chunk's padding, padding lanes); state bytes the decode
+    # programs' real lanes read and wrote; slot-nanoseconds held, and slots x
+    # nanoseconds they could have been (`_tick_slots`).
+    "ssm_tokens_scanned", "ssm_tokens_masked", "ssm_state_bytes",
+    "state_slot_held_ns", "state_slot_cap_ns",
 )
 
 
@@ -209,10 +216,11 @@ def _paged_jits():
                               sampling, cfg):
         """`prefill_paged`, then the chunk's next id [1] into `last` at
         `slot`: meta = (real_len, pos_offset, slot) int32; a chunk that
-        does not end its prompt names the spare slot."""
-        real_len, pos_offset, slot = meta
+        does not end its prompt names the spare slot. A model with state
+        brings a fourth entry, the sequence's state slot."""
+        real_len, pos_offset, slot, *state_slot = meta
         logits, kv = gpt.prefill_paged(
-            params, tokens, real_len, pos_offset, block_table, kv, cfg)
+            params, tokens, real_len, pos_offset, block_table, kv, cfg, *state_slot)
         ids, last = draw(logits[None], slot[None], last, sampling)
         return (ids,), kv, last
 
@@ -222,11 +230,12 @@ def _paged_jits():
         int32 = (slot, position, host id, known). A lane's input id is
         `last["ids"][slot]` unless the host knows it (`known`); its sample
         goes back to the same slot. Returns ((ids [B], *facts), kv, last),
-        `facts` what `decode_step_paged` hands back beside its logits."""
-        slot, positions, host_ids, known = lanes
+        `facts` what `decode_step_paged` hands back beside its logits. A
+        model with state brings a fifth row, the lanes' state slots."""
+        slot, positions, host_ids, known, *state_slots = lanes
         token = jnp.where(known > 0, host_ids, last["ids"][slot])
         out, kv = gpt.decode_step_paged(
-            params, token, positions, block_tables, kv, cfg)
+            params, token, positions, block_tables, kv, cfg, *state_slots)
         logits, *facts = out if isinstance(out, tuple) else (out,)
         ids, last = draw(logits, slot, last, sampling)
         return (ids, *facts), kv, last
@@ -361,14 +370,19 @@ class InferenceEngine:
         # tier, the disaggregated roles and block export/import move blocks
         # of ONE row shape (a K row and a V row) and one table: a model
         # whose layers form several groups, or whose layers keep one latent
-        # row and no V (`KVLayout.value_row` 0), is refused here, not served
-        # wrongly (ROADMAP D9).
+        # row and no V (`KVLayout.value_row` 0), or whose layers keep a state
+        # a sequence that no blob carries (`KVLayout.state`), is refused
+        # here, not served wrongly (ROADMAP D9). Such a state is not rolled
+        # back past a rejected draft either: no speculation.
         self._layout = kv_layout(self.cfg)
         self._groups = len(self._layout.windows)
+        self._stateful = bool(self._layout.state)
         self._unmoved = (
             f"a model of {self._groups} KV groups" if self._groups > 1
             else "a model whose layers cache one latent row (no V rows)"
-            if not self._layout.value_row else None)
+            if not self._layout.value_row
+            else "a model whose layers keep a state a sequence (no snapshot)"
+            if self._stateful else None)
         if self._unmoved:
             if self.opts.host_kv_bytes > 0 and self.opts.enable_prefix_caching:
                 raise ValueError(
@@ -378,6 +392,11 @@ class InferenceEngine:
                 raise ValueError(
                     f"{self._unmoved} serves role='mixed' only: the "
                     "prefill/decode hand-off exports K and V blocks of one group")
+            if self._stateful and self.opts.spec_tokens > 0:
+                raise ValueError(
+                    f"{self._unmoved} runs without speculation (spec_tokens=0): "
+                    "a verify step would have to roll the state back past "
+                    "rejected drafts")
         self._jnp = jax.numpy
         self._jax = jax
         # Where the kernels run, as JAX reports it — benches and the chip
@@ -387,8 +406,10 @@ class InferenceEngine:
         if params is None:
             params = init_params(jax.random.PRNGKey(self.opts.seed), cfg)
         self.params = params
+        # A model with state: one slot a lane behind the null slot.
+        state_slots = self.opts.max_num_seqs if self._stateful else 0
         self.kv = init_paged_cache(
-            self.cfg, self.opts.num_blocks, self.opts.block_size
+            self.cfg, self.opts.num_blocks, self.opts.block_size, state_slots
         )
         # One block's bytes, from what the layout declares a layer keeps.
         self.kv_block_bytes = self._layout.block_bytes(
@@ -404,6 +425,7 @@ class InferenceEngine:
             enable_prefix_caching=self.opts.enable_prefix_caching,
             host_tier=self.host_tier,
             group_windows=self._layout.windows,
+            state_slots=state_slots,
         )
         proposer = None
         if self.opts.spec_tokens > 0:
@@ -519,6 +541,7 @@ class InferenceEngine:
         self._books: Dict[str, int] = dict.fromkeys(_STEP_BOOKS, 0)
         self._host_mean = [0, 0]    # [steps seen, running mean host ns a step]
         self._step_chunks = [0, 0]      # this step's chunk tokens: [real, padded]
+        self._slots_t_ns = time.monotonic_ns()      # `_tick_slots`' last reading
         self._delivery = _Delivery()
         self._gc_hooked = True
         _GC.acquire()
@@ -1094,6 +1117,33 @@ class InferenceEngine:
             count[0] += run
             count[1] += padded
 
+    def _count_state(self, tokens: int, real: int, decode: bool):
+        """Add one program of a model with state to its books: `tokens` the
+        scan ran over as the program is shaped, `real` of them unmasked; a
+        decode program's real lanes each read and write their slot's state."""
+        if self._stateful:
+            b = self._books
+            b["ssm_tokens_scanned"] += tokens
+            b["ssm_tokens_masked"] += tokens - real
+            if decode:
+                b["ssm_state_bytes"] += 2 * real * self._layout.state_bytes
+
+    def _tick_slots(self):
+        """Add the time since the last reading to the state slots' books, at
+        the number held NOW: called at a step's start (the count stood since
+        the last step's end) and at its end."""
+        if self._stateful:
+            now = time.monotonic_ns()
+            dt, self._slots_t_ns = now - self._slots_t_ns, now
+            kv = self.block_manager
+            self._books["state_slot_held_ns"] += dt * kv.state_slots_held
+            self._books["state_slot_cap_ns"] += dt * kv.state_slots
+
+    def _state_slot(self, seq: Sequence) -> List[int]:
+        """[] or [the sequence's state slot]: the extra entry a lane of a
+        model with state carries into its program beside its block table."""
+        return [self.block_manager.state_slot(seq.request_id)] if self._stateful else []
+
     def _count_moe(self, tokens: int):
         """Add one program of `tokens` tokens (lanes x tokens a lane, padding
         and all) to the expert layers' count."""
@@ -1125,11 +1175,12 @@ class InferenceEngine:
             self._tables_into(bt, seq)
             self._count_attn(1, W, np.asarray([chunk.start + L - 1]), True)
             self._count_moe(Sp)
+            self._count_state(Sp, L, decode=False)
             self._step_chunks[0] += L
             self._step_chunks[1] += Sp
             meta = np.asarray(
-                [L, chunk.start, seq.slot if chunk.last else self._spare],
-                np.int32)
+                [L, chunk.start, seq.slot if chunk.last else self._spare,
+                 *self._state_slot(seq)], np.int32)
             args = (jnp.asarray(tokens), jnp.asarray(meta), jnp.asarray(bt))
         with flight.phase("engine.dispatch", ph, "dispatch_ns"):
             out, self.kv, self._last = self._prefill(
@@ -1321,9 +1372,10 @@ class InferenceEngine:
         with flight.phase("engine.build", ph, "build_ns"):
             B = out.batch_bucket
             W = out.width_bucket
-            # rows: slot, position, the id where the host knows it, known;
-            # padding lanes: the spare slot, token 0 at position 0
-            lanes = np.zeros((4, B), np.int32)
+            # rows: slot, position, the id where the host knows it, known
+            # (a model with state: and the lane's state slot); padding lanes:
+            # the spare slot, token 0 at position 0 (the null state slot)
+            lanes = np.zeros((4 + self._stateful, B), np.int32)
             lanes[0] = self._spare
             lanes[3] = 1
             tables = np.zeros(self._table_shape(B, W), np.int32)  # padding lanes -> null block
@@ -1334,11 +1386,13 @@ class InferenceEngine:
                     lanes[3, i] = 0
                 else:
                     lanes[2, i] = seq.output[-1]
+                lanes[4:, i] = self._state_slot(seq)
                 self._tables_into(tables[i], seq)
             self._step_chained = int(not lanes[3].all())
             self.total_decode_chained += self._step_chained
             self._count_attn(B, W, lanes[1], np.arange(B) < len(seqs))
             self._count_moe(B)
+            self._count_state(B, len(seqs), decode=True)
             args = (jnp.asarray(lanes), jnp.asarray(tables))
         with flight.phase("engine.dispatch", ph, "dispatch_ns"):
             sampled, self.kv, self._last = self._decode(
@@ -1374,6 +1428,7 @@ class InferenceEngine:
         # ≤5% of decode-step time (test_flight_perf_smoke).
         fl_on = flight.enabled()
         ph = self._phases = dict.fromkeys(flight.SERVE_STEP_PHASES, 0)
+        self._tick_slots()
         t0_ns = time.monotonic_ns()
         with flight.phase("engine.schedule", ph, "sched_ns"):
             self._step_ttfts, self._step_tpots = [], []
@@ -1427,6 +1482,7 @@ class InferenceEngine:
         # The span ends where the step's own work does; building `stats`
         # and the metrics export follow it as `export_ns`.
         t1_ns = time.monotonic_ns()
+        self._tick_slots()
         with flight.phase("engine.export_metrics", ph, "export_ns"):
             now = time.monotonic()
             self._tok_window = [t for t in self._tok_window if now - t <= 10.0]
@@ -1575,6 +1631,10 @@ class InferenceEngine:
             "moe_tokens_expert": self.total_moe_tokens,
             "decode_dispatched": self.total_decode_dispatched,
             "decode_chained": self.total_decode_chained,
+            "state_slots": kv_stats.state_slots,
+            "state_slots_held": kv_stats.state_slots_held,
+            "state_slots_claimed": self.block_manager.states_claimed,
+            "state_slots_released": self.block_manager.states_released,
             **self._books,
             **dict(zip(("stream_tokens", "stream_wake_ns", "stream_send_ns",
                         "stream_behind"), self._delivery.read())),

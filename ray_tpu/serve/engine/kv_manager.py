@@ -83,6 +83,21 @@ is reclaimed for new content the whole entry leaves the index and its other
 copies lose their registration, so a prefix hit can never hand out a block
 whose rows were overwritten. `fork`, the host tier, `adopt_block` and
 `export_sources` are the one-group manager's: with G > 1 they refuse.
+
+State a SEQUENCE (`state_slots`, from the model's `kv_layout`): a model whose
+layers keep one fixed state a sequence beside (or in place of) rows a token
+brings a second counted resource, the state slot. A sequence claims one with
+its first allocation (`allocate_cached`), holds it while it lives and gives
+it back with `free`, so a preempted sequence's slot goes with its blocks and
+its readmission claims another, whose state the program starts from zero.
+`can_allocate`, `fits_ever`, `stats` and `check_invariants` count slots beside
+blocks. Slot 0 is the null slot (padding lanes), never handed out. A cached
+block holds the row layers' rows and NOTHING of the state at that boundary,
+so for such a model a prefix hit would be wrong: the manager runs with the
+index off whatever `enable_prefix_caching` says (`allocate_cached` reports no
+cached token, `register_computed` registers no block, nothing is hashed),
+and `fork` refuses. Snapshots of the state at block boundaries would lift
+both.
 """
 
 from __future__ import annotations
@@ -137,6 +152,8 @@ class KVStats:
     host_hits: int = 0       # hits served from the host-RAM tier (subset)
     host_blocks: int = 0     # blocks resident in the host tier
     host_bytes: int = 0      # bytes resident in the host tier
+    state_slots: int = 0     # state slots a model with state has (null slot apart)
+    state_slots_held: int = 0  # held by live sequences
 
 
 class KVBlockManager:
@@ -152,6 +169,7 @@ class KVBlockManager:
         enable_prefix_caching: bool = True,
         host_tier=None,
         group_windows: Sequence[int] = (0,),
+        state_slots: int = 0,
     ):
         self.group_windows = tuple(int(w) for w in group_windows)
         if not self.group_windows or min(self.group_windows) < 0:
@@ -168,7 +186,14 @@ class KVBlockManager:
             raise ValueError("block_size must be >= 1")
         self.block_size = block_size
         self.num_blocks = num_blocks
-        self.caching = enable_prefix_caching
+        # State slots 1..state_slots (0: the model keeps no state a sequence).
+        self.state_slots = int(state_slots)
+        self._free_states: List[int] = list(range(self.state_slots, 0, -1))
+        self._state_of: Dict[str, int] = {}
+        self.states_claimed = 0
+        self.states_released = 0
+        # A model with state runs with the index off (module docstring).
+        self.caching = enable_prefix_caching and not self.state_slots
         # Host-RAM tier below HBM (kv_tier.HostKVTier, None = off). Accessed
         # only under the engine lock, like every other mutation here.
         self._tier = host_tier if enable_prefix_caching else None
@@ -258,12 +283,24 @@ class KVBlockManager:
         return sum(min(nb, self.blocks_for(w) + 1) if w else nb
                    for w in self.group_windows)
 
+    def _state_free(self) -> bool:
+        """A state slot is to be had (or the model keeps no state)."""
+        return not self.state_slots or bool(self._free_states)
+
     def can_allocate(self, num_tokens: int) -> bool:
-        return self.blocks_needed(num_tokens) <= self.free_blocks
+        return self.blocks_needed(num_tokens) <= self.free_blocks and self._state_free()
 
     def fits_ever(self, num_tokens: int) -> bool:
         """Could this many tokens fit an EMPTY pool? (submit-time sanity)"""
         return self.blocks_needed(num_tokens) <= self.num_blocks - 1
+
+    @property
+    def state_slots_held(self) -> int:
+        return len(self._state_of)
+
+    def state_slot(self, seq_id: str) -> int:
+        """The state slot `seq_id` holds (a model with state)."""
+        return self._state_of[seq_id]
 
     def block_table(self, seq_id: str) -> List[int]:
         """The first group's table (THE table of a one-group model; with
@@ -326,6 +363,8 @@ class KVBlockManager:
             host_hits=self.host_hits,
             host_blocks=self._tier.blocks if self._tier is not None else 0,
             host_bytes=self._tier.bytes_used if self._tier is not None else 0,
+            state_slots=self.state_slots,
+            state_slots_held=self.state_slots_held,
         )
 
     # ------------------------------------------------------- block plumbing
@@ -428,6 +467,8 @@ class KVBlockManager:
             raise ValueError("allocate needs >= 1 token")
         if token_ids is not None and len(token_ids) > num_tokens:
             raise ValueError("token_ids longer than the allocation")
+        if not self._state_free():
+            raise KVCacheExhausted("every state slot is held")
         nb = self.blocks_for(num_tokens)
         # Chain walk: per leading full block, an HBM index hit ("idx",
         # blocks, one a group), a host-tier hit ("tier", h, bytes) — acquired
@@ -519,6 +560,9 @@ class KVBlockManager:
         self._lens[seq_id] = num_tokens
         self._chain[seq_id] = chain
         self._landed[seq_id] = cached_tokens
+        if self.state_slots:
+            self._state_of[seq_id] = self._free_states.pop()
+            self.states_claimed += 1
         return list(tables[0]), cached_tokens
 
     def fork(self, parent_id: str, child_id: str) -> List[int]:
@@ -543,6 +587,10 @@ class KVBlockManager:
         manager cannot tell its content from speculative garbage."""
         if self._G > 1:
             raise NotImplementedError("fork of a sequence over several KV groups")
+        if self.state_slots:
+            raise NotImplementedError(
+                "fork of a sequence with state: the shared blocks hold nothing of "
+                "the state at their boundary (no snapshot is kept)")
         if child_id in self._tables:
             raise ValueError(f"sequence {child_id!r} already has an allocation")
         table = self._tables[parent_id][0]  # KeyError = unknown parent
@@ -830,6 +878,9 @@ class KVBlockManager:
         held = [b for t in tables for b in t if b != self.NULL_BLOCK]
         for b in held:
             self._release_one(b)
+        if self.state_slots:
+            self._free_states.append(self._state_of.pop(seq_id))
+            self.states_released += 1
         return len(held)
 
     def check_invariants(self) -> None:
@@ -883,6 +934,12 @@ class KVBlockManager:
                 f"{sid!r}: landed watermark {landed} past allocation "
                 f"{self._lens[sid]}"
             )
+        held = sorted(self._state_of.values())
+        assert len(set(held)) == len(held), "a state slot held twice"
+        assert sorted(held + self._free_states) == list(range(1, self.state_slots + 1)), (
+            "lost/leaked state slots")
+        assert set(self._state_of) == (set(self._tables) if self.state_slots else set()), (
+            "state slots and block tables name different sequences")
         for b, (h, *_rest) in self._pending_loads.items():
             assert b not in self._free, f"pending-load block {b} on free list"
             assert self._hash_of.get(b) == h, (

@@ -43,6 +43,12 @@ read step n's sampled ids, so a step is planned from COUNTS. A sequence's
 everywhere a length is asked for; a sequence whose last token was just
 dispatched is retired at once (`retire`: lane, slot and blocks go back
 before the next `schedule()`, the stream closes when the id is read).
+A model with state a sequence (`KVBlockManager.state_slots`) changes nothing
+here: the manager claims the sequence's state slot with its first allocation
+and frees it with its blocks, so admission waits for a slot as it waits for
+blocks, and `_preempt` gives the slot back (the readmitted sequence
+recomputes from zero: its first chunk starts at position 0).
+
 Only two things here need token VALUES, and both raise `NeedsValues` so
 that the engine reads the step in flight and asks again: folding a
 preempted sequence's output into its prompt, and the n-gram proposer's

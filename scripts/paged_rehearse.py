@@ -115,8 +115,9 @@ def rehearse(cfg, device, num_blocks: int, block_size: int, lanes: int = 8,
 
     params = on_chip(jax.eval_shape(
         lambda k: init_params(k, cfg), jax.random.PRNGKey(0)))
+    stateful = int(bool(kv_layout(cfg).state))   # a state slot a lane beside the pool
     kv = on_chip(jax.eval_shape(
-        lambda: init_paged_cache(cfg, num_blocks, block_size)))
+        lambda: init_paged_cache(cfg, num_blocks, block_size, lanes * stateful)))
     # the last ids of `lanes` slots and the sampler's constants, as the
     # engine carries them beside the pool
     last, sampling = on_chip(jax.eval_shape(lambda: init_sampler(lanes, 0, 0.0)))
@@ -130,12 +131,13 @@ def rehearse(cfg, device, num_blocks: int, block_size: int, lanes: int = 8,
 
     def decode_at(n):
         return lambda: decode.lower(
-            params, i32(4, n), i32(n, *table), kv, last, sampling, cfg)
+            params, i32(4 + stateful, n), i32(n, *table), kv, last, sampling, cfg)
 
     programs = {
         "decode_step_paged": decode_at(lanes),
         "prefill_paged": lambda: prefill.lower(
-            params, i32(1, chunk), i32(3), i32(*table), kv, last, sampling, cfg),
+            params, i32(1, chunk), i32(3 + stateful), i32(*table), kv, last, sampling,
+            cfg),
         "verify_step_paged": lambda: verify.lower(
             params, i32(lanes, spec + 1), i32(lanes), i32(lanes),
             i32(lanes, *table), kv, cfg),
@@ -150,7 +152,9 @@ def rehearse(cfg, device, num_blocks: int, block_size: int, lanes: int = 8,
     report = {
         "n_layers": cfg.n_layers,
         "pool_shape": list(kv["k"].shape), "pool_dtype": str(kv["k"].dtype),
-        "pool_GiB": len(kv) * kv["k"].shape[0] * layer_pool / 2**30,
+        "pool_GiB": len(set(kv) & {"k", "v"}) * kv["k"].shape[0] * layer_pool / 2**30,
+        "state_GiB": sum(a.size * a.dtype.itemsize
+                         for a in kv.get("state", {}).values()) / 2**30,
         "weights_GiB": sum(a.size * a.dtype.itemsize for a in params.values()) / 2**30,
         "layer_pool_MiB": layer_pool / 2**20,
         "lanes": lanes, "width": width, "chunk": chunk, "spec": spec,

@@ -54,19 +54,30 @@ def main(argv=None) -> int:
         argv, __doc__)
 
 
-def readings(config_name, wrongs_of, row_of, argv, doc) -> int:
+def readings(config_name, wrongs_of, row_of, argv, doc, faults=None) -> int:
     """The readings of one configuration (`benchmarks/configs/<config_name>.json`):
     `wrongs_of(dims, engine options)` names the wrong references as changes to
-    the dims, `row_of(engine.stats())` adds what the sound engine counted."""
+    the dims, `row_of(engine.stats())` adds what the sound engine counted.
+    `faults`: {name: context manager under which the PROGRAM is wrong}; the
+    engine built and run inside it is held to the right reference."""
     ap = argparse.ArgumentParser(description=doc.split("\n\n")[0])
     ap.add_argument("--seeds", default="3000000001")
     ap.add_argument("--rehearse", action="store_true")
     ap.add_argument("--parts", default="wrong,float8",
                     help="what to read beside the sound engine's own token error: "
-                         "wrong (every wrong reference) or their names, float8")
+                         "wrong (every wrong reference) or their names, float8, "
+                         "faults (every faulty program) or their names")
     ap.add_argument("--init-gains", default="",
                     help="name=gain,...: entries of the preset's init_gains to "
                          "replace (how a preset's gains were chosen; shapes unmoved)")
+    ap.add_argument("--check", default="",
+                    help="prompt_len:new_tokens in place of the configuration's "
+                         "token_check (how a cell's check was sized)")
+    ap.add_argument("--sizes", default="",
+                    help="key=int,...: published keys of the configuration to replace. "
+                         "On the CPU at a quarter of the widths (all layers, the whole "
+                         "vocabulary) the readings come within a fifth of the chip's, "
+                         "two minutes a seed: settle gains there (PERF.md 6, PR 40)")
     a = ap.parse_args(argv)
     parts = set(a.parts.split(","))
     import jax
@@ -77,11 +88,14 @@ def readings(config_name, wrongs_of, row_of, argv, doc) -> int:
     from ray_tpu.serve.engine import EngineOptions, InferenceEngine
 
     config = harness.load_json(harness.ROOT, f"benchmarks/configs/{config_name}.json")
+    config.update((k, int(v)) for k, v in (kv.split("=") for kv in a.sizes.split(",") if kv))
     arch = harness.arch(config["arch"])
     m = arch.dims(config, a.rehearse)
     part = config["rehearsal"]["requests"] if a.rehearse else config["runners"]["requests"]
     opts = EngineOptions(**part["engine_options"])
     n_prompt, n_new = part["token_check"]["prompt_len"], part["token_check"]["new_tokens"]
+    if a.check:
+        n_prompt, n_new = (int(v) for v in a.check.split(":"))
     name, overrides = arch.program(config, m)
     cfg = gpt.CONFIGS[name](**overrides)
     if a.init_gains:
@@ -106,12 +120,23 @@ def readings(config_name, wrongs_of, row_of, argv, doc) -> int:
         held = _Held(params, eng.generate)
         row = {"seed": seed, "sound": check(held, seed)}
         row["distinct_tokens"] = len(set(held.tokens))
+        if "growth" in parts:
+            row["growth"] = growth(arch.make_logits(m), params, _prompt(seed, m, n_prompt))
         row.update(row_of(eng.stats()))
         for wname, change in wrongs.items():
             if wname in parts or "wrong" in parts:
                 row[wname] = check(held, seed, {**m, **change})
         eng.shutdown()
         del eng, held
+        for fname, fault in (faults or {}).items():
+            if fname in parts or "faults" in parts:
+                with fault():
+                    eng = InferenceEngine(cfg, params=params, options=opts)
+                    eng.start()
+                    got = eng.generate(_prompt(seed, m, n_prompt), n_new)
+                    eng.shutdown()
+                    del eng
+                row[fname] = check(_Held(params, lambda prompt, n, got=got: got), seed)
         if "float8" in parts:
             eng = InferenceEngine(cfg, params=degrade(params), options=opts)
             eng.start()
@@ -139,6 +164,22 @@ def _prompt(seed, m, n_prompt):
     import numpy as np
 
     return np.random.default_rng([seed, 1]).integers(1, m["vocab_size"], n_prompt).tolist()
+
+
+def growth(logits, params, tokens, eps=1e-3):
+    """How many times over a perturbation of the embedding, `eps` of its size,
+    has grown when it reaches the float32 reference's logits: the rounding
+    of a sound bfloat16 engine grows alike, and past some 50 it reads as
+    float8 weights do (PERF.md 6, PR 40: 64-83 under gains that failed, 8
+    under those that hold). The perturbed rows stay float32."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    e = params["tok_embed"].astype(jnp.float32)
+    moved = e + jax.random.normal(jax.random.PRNGKey(0), e.shape) * eps * e.std()
+    a, b = logits(params, tokens), logits({**params, "tok_embed": moved}, tokens)
+    return float(np.sqrt(((b - a) ** 2).mean() / (a ** 2).mean()) / eps)
 
 
 def degrade(params):

@@ -135,12 +135,12 @@ def test_the_cell_and_its_files_are_in_the_benchmark():
 
     resolves()
     bench = harness.benchmark()
-    assert len(bench["workloads"]) == 7 and len(bench["configs"]) == 5
+    assert len(bench["workloads"]) >= 7 and len(bench["configs"]) >= 5   # later PRs append
     assert sum(w["chips"] == 4 for w in bench["workloads"]) == 1
-    assert bench["workloads"][-1]["name"] == CELL and bench["configs"][-1]["name"] == "ax-k1"
-    cell = bench["workloads"][-1]
+    assert bench["workloads"][6]["name"] == CELL and bench["configs"][4]["name"] == "ax-k1"
+    cell = bench["workloads"][6]
     assert cell["chips"] == 1 and cell["traffic"] == "longdoc-steady" and len(cell["why"]) <= 200
-    entry = bench["configs"][-1]
+    entry = bench["configs"][4]
     assert entry["reduced"] == ["num_hidden_layers", "n_routed_experts", "vocab_size"]
     assert entry["file"] == CONFIG and len(entry["why"]) <= 200
     assert entry["source"] == "https://huggingface.co/skt/A.X-K1/blob/main/config.json"

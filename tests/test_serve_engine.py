@@ -2047,3 +2047,28 @@ class TestEngineBooks:
         eng.shutdown()
         assert pauses._engines == held
         assert gc.callbacks.count(pauses._hook) == (1 if held else 0)
+
+
+@pytest.mark.parametrize("lanes, tokens", [(1, 256), (64, 1)])
+def test_the_selective_scan_kernel_compiles_for_v5e_at_the_published_width(
+        v5e_chip, lanes, tokens):
+    """Compile-only: `ops/ssm.py`'s `ssm_scan` at AI21-Jamba2-3B's inner width
+    (5120 channels x 16 states) for a 256-token chunk and for a decode step
+    of 64 lanes: what the chip's compiler refuses of a kernel (tiling, VMEM,
+    an SMEM block) shows here, and the state comes back in place."""
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import SingleDeviceSharding
+
+    from ray_tpu.ops import ssm
+
+    one_chip = SingleDeviceSharding(v5e_chip)
+    f32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.float32, sharding=one_chip)
+    tiled = (lanes, tokens, 40, 128)
+    compiled = _within(120, lambda: jax.jit(ssm._scan_pallas, donate_argnums=5).lower(
+        f32(*tiled), f32(*tiled), f32(16, 40, 128), f32(lanes, 1, tokens * 16),
+        f32(lanes, 1, tokens * 16), f32(lanes, 16, 40, 128)).compile())
+    assert "ssm_scan" in compiled.as_text()
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes == lanes * 16 * 5120 * 4     # the state, in place
+    assert mem.temp_size_in_bytes < 1 << 20
