@@ -34,7 +34,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..ops import flash_attention, layernorm, ring_attention, rmsnorm, rope_frequencies, rotate_half
+from ..ops import attention, flash_attention, layernorm, ring_attention, rmsnorm, rope_frequencies, rotate_half
 from ..ops.attention import attention_reference, ulysses_attention
 from ..parallel.mesh import ShardingRules
 
@@ -2184,6 +2184,19 @@ def paged_attn_tiling(width: int, block_size: int) -> Tuple[int, int]:
     return (width, 1) if width <= tile else (tile, -(-width // tile))
 
 
+def paged_attn_kernel(cfg: GPTConfig, tokens: int, width: int, block_size: int) -> bool:
+    """Whether a paged program of `tokens` tokens a lane over tables `width`
+    blocks wide runs its attention as ONE kernel (`ops/attention.py`
+    `paged_chunk_attention`) and not as the key loop: a chunk, a table wider
+    than one tile, on the chip, key and value rows of whole lane tiles. From
+    shapes alone: the program decides with it, and the host counts with it
+    (`engine_stats()`' `attn_chunks_kernel`)."""
+    key_row, value_row = ((kv_layout(cfg).key_row, cfg.kv_lora_rank)
+                          if cfg.kv_lora_rank else (cfg.d_head, cfg.d_head))
+    return (tokens > 1 and paged_attn_tiling(width, block_size)[1] > 1
+            and attention._on_tpu() and key_row % 128 == 0 and value_row % 128 == 0)
+
+
 def paged_attn_trips(xp, first_pos, last_pos, real, window, tile_keys, tiles):
     """Run-time bounds of the key loop over a table of `tiles` tiles of
     `tile_keys` keys: (each lane's first tile [B], trips). Lane b's real
@@ -2241,8 +2254,13 @@ def _paged_layers(params, tokens, pos, valid, block_tables, kv, cfg: GPTConfig,
     window (`paged_attn_trips`: padding lanes and invalid slots take no
     part), so shapes and program keys depend on (S, W) alone. The mask, the
     same in both forms, decides what a query sees; the bounds only skip
-    tiles in which it is false everywhere. An expert MLP is the dropless
-    layer of `_dropless_mlp`.
+    tiles in which it is false everywhere. Where `paged_attn_kernel` says so
+    (a chunk over a wide table, on the chip) the loop is ONE kernel,
+    `ops/attention.py` `paged_chunk_attention`: the same tiles, bounds, mask
+    and online softmax, the scores and the accumulator in fast memory, the
+    table's rows gathered once before it; the loop below stays as what the
+    CPU runs, the tests' reference and the decode step's form. An expert MLP
+    is the dropless layer of `_dropless_mlp`.
 
     A looped model (`ut_steps` > 1) runs that layer scan `ut_steps` times
     in an outer scan, the pool still the carry and written in place, the
@@ -2320,6 +2338,9 @@ def _paged_layers(params, tokens, pos, valid, block_tables, kv, cfg: GPTConfig,
         first_pos = jnp.where(real, pos, _NO_WINDOW).min(axis=1)
         last_pos = jnp.where(real, pos, 0).max(axis=1)
         real_lane = real.any(axis=1)
+    by_kernel = paged_attn_kernel(cfg, S, W, BS)
+    if by_kernel:   # [B, R*S]: the position of each folded query row
+        row_pos = jnp.tile(pos, (1, R))
 
     # One query a K/V head (a decode step of a multi-head model) whose
     # features fill whole lane tiles: attention as two matrix products over
@@ -2374,10 +2395,15 @@ def _paged_layers(params, tokens, pos, valid, block_tables, kv, cfg: GPTConfig,
             scores, gv = scores_of(q, kk, vv, slot, table, kpos, seen, window)
             probs = jax.nn.softmax(scores, axis=-1)
             return mixed(probs, gv)
+        reach = _NO_WINDOW if window is None else window
         first, trips = paged_attn_trips(
-            jnp, first_pos, last_pos, real_lane,
-            _NO_WINDOW if window is None else window, T, NT)
+            jnp, first_pos, last_pos, real_lane, reach, T, NT)
         table = jnp.pad(table, ((0, 0), (0, NT * TB - W)))
+        if by_kernel:   # the table's rows gathered once, densely: 0.05 ms a layer
+            return attention.paged_chunk_attention(
+                q, kk[slot, table].reshape(B, NT * T, Hkv * Dh),
+                None if vv is None else vv[slot, table].reshape(B, NT * T, Hkv * Dv),
+                row_pos, first, trips, reach, tile_keys=T, dv=Dv, sm_scale=scale)
 
         def trip(j, carry):
             m, l, acc = carry

@@ -540,6 +540,132 @@ def _flash_bwd_pallas(q, k, v, o, lse, g, causal: bool, sm_scale: float,
     return dq, dk, dv
 
 
+# ----------------------------------------------------- paged chunk kernel
+#
+# A prefill chunk's attention over a paged table wider than one key tile
+# (`models/gpt.py` `_paged_layers`): the flash forward's tiling and online
+# softmax with the run-time bounds of the paged key loop. A tile of query rows
+# keeps its running maximum, sum and accumulator in VMEM across all key
+# tiles; the scores never reach HBM; the mask is made from positions a tile
+# at a time.
+
+PAGED_CHUNK_KERNEL = "paged_chunk_attn"
+_CHUNK_Q_ROWS = 1024    # query rows a grid step holds (PERF.md §6, PR 41)
+
+
+def _chunk_q_tile(rows: int) -> int:
+    """Query rows a grid step: the largest whole number of sublane tiles of
+    at most `_CHUNK_Q_ROWS` rows that divides `rows` (a multiple of 16)."""
+    return max(d for d in range(16, min(rows, _CHUNK_Q_ROWS) + 1, 16) if rows % d == 0)
+
+
+def _paged_chunk_kernel(first_ref, trips_ref, window_ref, qpos_ref, q_ref, k_ref,
+                        *rest, tile_keys: int, nt: int, dv: int, sm_scale: float):
+    """One (query tile, key tile) grid step. Scalar prefetch: each lane's
+    first key tile, the step's trips, the layer's window. `rest` is ([v_ref,]
+    o_ref, acc, m, l): without a value operand the key rows' first `dv`
+    columns are the values (a latent pool)."""
+    from jax.experimental import pallas as pl
+
+    v_ref = rest[0] if len(rest) == 5 else None
+    o_ref, acc_ref, m_ref, l_ref = rest[-4:]
+    j = pl.program_id(3)
+    tile = first_ref[pl.program_id(0)] + j
+
+    @pl.when(j == 0)
+    def _init():
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+        m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
+        l_ref[...] = jnp.zeros_like(l_ref)
+
+    @pl.when(j < trips_ref[0])
+    def _tile():
+        k = k_ref[...]                                      # [T, Dh]
+        s = jax.lax.dot_general(
+            q_ref[...], k, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32) * sm_scale  # [Tq, T] f32
+        # a tile past the table is the last one again under positions no
+        # query reaches: masked whole, as in the plain loop
+        kp = tile * tile_keys + jax.lax.broadcasted_iota(
+            jnp.int32, (1, tile_keys), 1)
+        qp = qpos_ref[...]                                  # [Tq, 1]
+        seen = jnp.logical_and(kp <= qp, kp > qp - window_ref[0])
+        s = jnp.where(seen, s, _NEG_INF)
+        m_prev = m_ref[...]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+        p = jnp.exp(s - m_new)
+        fade = jnp.exp(m_prev - m_new)
+        l_ref[...] = l_ref[...] * fade + jnp.sum(p, axis=-1, keepdims=True)
+        m_ref[...] = m_new
+        v = k[:, :dv] if v_ref is None else v_ref[...]
+        acc_ref[...] = acc_ref[...] * fade + jax.lax.dot_general(
+            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+
+    @pl.when(j == nt - 1)
+    def _flush():
+        o_ref[...] = (acc_ref[...] / l_ref[...]).astype(o_ref.dtype)
+
+
+def paged_chunk_attention(q, keys, values, qpos, first, trips, window, *,
+                          tile_keys: int, dv: int, sm_scale: float,
+                          interpret: bool = False):
+    """Causal (and windowed) attention of a chunk's folded query rows over
+    the rows of its table, gathered densely: q [B, Hkv, rows, Dh] (row i of
+    lane b at position qpos[b, i]); keys [B, NT * tile_keys, Hkv * Dh] as the
+    pool lays them, values [B, NT * tile_keys, Hkv * dv] or None (a latent
+    pool: the key rows' first `dv` columns); first [B] int32 and trips
+    (`paged_attn_trips`): lane b attends key tiles first[b] .. first[b] +
+    trips - 1 and no other is fetched or computed; window an int32 scalar (a
+    global layer's is a window no sequence reaches). Query row i sees key
+    position p where qpos - window < p <= qpos. bf16 products summed in
+    float32, float32 online softmax -> [B, Hkv, rows, dv] in the keys' dtype.
+    Dh and dv fill whole lane tiles."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    B, Hkv, rows, Dh = q.shape
+    nt = keys.shape[1] // tile_keys
+    rows_p = -(-rows // 16) * 16
+    if rows_p != rows:
+        q = jnp.pad(q, ((0, 0), (0, 0), (0, rows_p - rows), (0, 0)))
+        qpos = jnp.pad(qpos, ((0, 0), (0, rows_p - rows)))
+    tq = _chunk_q_tile(rows_p)
+
+    def key_tile(b, h, i, j, first, trips, window):
+        # past the trips: the last tile fetched again, which is no fetch
+        return (b, jnp.minimum(first[b] + jnp.minimum(j, trips[0] - 1), nt - 1), h)
+
+    def query_tile(b, h, i, j, *_):
+        return (b, h, i, 0)
+
+    operands = [qpos[..., None], q, keys]
+    in_specs = [pl.BlockSpec((None, tq, 1), lambda b, h, i, j, *_: (b, i, 0)),
+                pl.BlockSpec((None, None, tq, Dh), query_tile),
+                pl.BlockSpec((None, tile_keys, Dh), key_tile)]
+    if values is not None:
+        operands.append(values)
+        in_specs.append(pl.BlockSpec((None, tile_keys, dv), key_tile))
+    out = pl.pallas_call(
+        functools.partial(_paged_chunk_kernel, tile_keys=tile_keys, nt=nt, dv=dv,
+                          sm_scale=sm_scale),
+        out_shape=jax.ShapeDtypeStruct((B, Hkv, rows_p, dv), keys.dtype),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3, grid=(B, Hkv, rows_p // tq, nt),
+            in_specs=in_specs,
+            out_specs=pl.BlockSpec((None, None, tq, dv), query_tile),
+            scratch_shapes=[pltpu.VMEM((tq, dv), jnp.float32),
+                            pltpu.VMEM((tq, 1), jnp.float32),
+                            pltpu.VMEM((tq, 1), jnp.float32)]),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"),
+            vmem_limit_bytes=64 << 20),
+        interpret=interpret, name=PAGED_CHUNK_KERNEL,
+    )(first.astype(jnp.int32), jnp.asarray(trips, jnp.int32).reshape(1),
+      jnp.asarray(window, jnp.int32).reshape(1), *operands)
+    return out[:, :, :rows]
+
+
 def _on_tpu() -> bool:
     return jax.default_backend() == "tpu"
 

@@ -357,7 +357,8 @@ class InferenceEngine:
         import jax
 
         from ...models.gpt import (
-            init_paged_cache, init_params, kv_layout, paged_attn_keys,
+            init_paged_cache, init_params, kv_layout, paged_attn_kernel,
+            paged_attn_keys,
         )
 
         self.cfg = dataclasses.replace(cfg, remat=False, remat_policy=None)
@@ -500,6 +501,10 @@ class InferenceEngine:
         # and keys of their padded tables (`models.gpt.paged_attn_keys`).
         self._attn_keys = paged_attn_keys
         self.total_attn_keys = [0, 0]
+        # Prefill chunk programs dispatched and, of them, those whose shapes
+        # send their attention to the chunk kernel: the program's own rule.
+        self._attn_kernel = paged_attn_kernel
+        self.total_attn_chunks = [0, 0]
         self._step_attn = [0, 0]
         # Expert routing: (experts touched, busiest expert's share) of the
         # decode step whose ids this step read, which came back with them;
@@ -1174,6 +1179,9 @@ class InferenceEngine:
             bt = np.zeros(self._table_shape(W), np.int32)
             self._tables_into(bt, seq)
             self._count_attn(1, W, np.asarray([chunk.start + L - 1]), True)
+            self.total_attn_chunks[0] += 1
+            self.total_attn_chunks[1] += self._attn_kernel(
+                self.cfg, Sp, W, self.opts.block_size)
             self._count_moe(Sp)
             self._count_state(Sp, L, decode=False)
             self._step_chunks[0] += L
@@ -1620,6 +1628,8 @@ class InferenceEngine:
             "window_blocks_released": self.block_manager.window_released,
             "attn_keys_run": self.total_attn_keys[0],
             "attn_keys_padded": self.total_attn_keys[1],
+            "attn_chunks": self.total_attn_chunks[0],
+            "attn_chunks_kernel": self.total_attn_chunks[1],
             "ut_passes_run": self.total_ut_passes[0],
             "ut_passes_full": self.total_ut_passes[1],
             "moe_assign_held": self.total_moe_assign[0],

@@ -22,11 +22,15 @@ lane b the id 7 + b and a chunk's token j the id 7 + j instead of 7 for all
 (an expert model's step reads the experts its tokens choose: alike, a chunk's
 tokens would all be rows of the same few experts). `--latent-forms 64,256` (a latent model) times ONE
 layer's attention of one chunk over a table of W blocks, outside any program,
-in the two forms the mathematics allows: ABSORBED (what `_paged_layers` runs:
-the key up-projection on the query, every head over the one cached row) and
-EXPANDED (each tile's rows widened to per-head keys and values first), the
-same gathers, tiles, mask and online softmax (PERF.md §6, PR 34: the next
-`perf_opt`'s starting point, not a path of the program).
+in the forms the mathematics allows: ABSORBED (what `_paged_layers` runs off
+the chip and ran on it until PR 41: the key up-projection on the query, every
+head over the one cached row, a loop over key tiles), EXPANDED (each tile's
+rows widened to per-head keys and values first; PERF.md §6, PR 34: a starting
+point, not a path of the program), the same gathers, tiles, mask and online
+softmax, and KERNEL (the absorbed operands through `ops/attention.py`
+`paged_chunk_attention`, the table's rows gathered once: what a chunk program
+runs on the chip since PR 41; `--q-rows 512,1024` times it once for every
+value of its query tile, `ops.attention._CHUNK_Q_ROWS`).
 
 Milliseconds a call, mean over `--reps` calls dispatched back to back and
 waited for once. A chip run or nothing: on the CPU (`--rehearse`, the
@@ -50,6 +54,7 @@ def main(argv=None) -> int:
     ap.add_argument("--sampled", action="store_true")
     ap.add_argument("--ids", choices=("same", "distinct"), default="same")
     ap.add_argument("--latent-forms", default="")
+    ap.add_argument("--q-rows", default="")
     ap.add_argument("--reps", type=int, default=10)
     ap.add_argument("--rehearse", action="store_true")
     a = ap.parse_args(argv)
@@ -162,7 +167,9 @@ def main(argv=None) -> int:
                 timed({**row, "sampled": True}, sampled_decode,
                       (jnp.asarray(lanes), args[2]))
     for W in (int(w) for w in a.latent_forms.split(",") if w):
-        for form, ms in latent_forms(cfg, params, kv["k"], table(W), chunk, BS, reps).items():
+        q_rows = [int(r) for r in a.q_rows.split(",") if r]
+        for form, ms in latent_forms(cfg, params, kv["k"], table(W), chunk, BS, reps,
+                                     on_chip, q_rows).items():
             row = {"program": "latent_attention", "form": form, "chunk": chunk,
                    "W": W, "keys": W * BS}
             if on_chip:
@@ -180,15 +187,18 @@ def main(argv=None) -> int:
     return 0
 
 
-def latent_forms(cfg, params, pool, table, chunk, BS, reps):
+def latent_forms(cfg, params, pool, table, chunk, BS, reps, kernel=False, q_rows=()):
     """{form: ms a call} of one layer's latent attention, `chunk` queries at
     the END of a table of W blocks (every key seen by the last query), in
     tiles of `_ATTN_TILE_KEYS` keys with an online softmax. Queries and
-    weights are the model's shapes with layer 0's up-projections."""
+    weights are the model's shapes with layer 0's up-projections. `kernel`
+    (the chip): the chunk kernel too, once for each query tile of `q_rows`
+    (the module's own if none)."""
     import jax
     import jax.numpy as jnp
 
     from ray_tpu.models import gpt
+    from ray_tpu.ops import attention
 
     H, R, Dn, Dr = cfg.n_heads, cfg.kv_lora_rank, cfg.d_head, cfg.rotary_dim
     T = min(gpt._ATTN_TILE_KEYS, len(table) * BS)
@@ -252,8 +262,31 @@ def latent_forms(cfg, params, pool, table, chunk, BS, reps):
 
         return run(online(pool, sv), Dn)
 
+    def by_kernel(q, pool, w_ukv):
+        w_uk, w_uv = w_ukv[..., :Dn], w_ukv[..., Dn:]
+        qa = jnp.concatenate([jnp.einsum("hsd,rhd->hsr", q[..., :Dn], w_uk),
+                              q[..., Dn:]], -1)
+        width = pool.shape[-1]
+        qa = jnp.pad(qa, ((0, 0), (0, 0), (0, width - qa.shape[-1])))
+        out = attention.paged_chunk_attention(
+            qa.reshape(1, 1, H * chunk, width),
+            pool[0, table.reshape(-1)].reshape(1, tiles * T, width), None,
+            jnp.tile(qpos[:, :, 0], (1, H)), jnp.zeros((1,), jnp.int32), tiles,
+            gpt._NO_WINDOW, tile_keys=T, dv=R, sm_scale=scale)
+        return jnp.einsum("hsr,rhd->hsd", out.reshape(H, chunk, R), w_uv)
+
+    forms = [("absorbed", absorbed), ("expanded", expanded)]
+    own = attention._CHUNK_Q_ROWS
+    for rows in (q_rows or [own]) if kernel else ():
+        def at_tile(*args, rows=rows):
+            attention._CHUNK_Q_ROWS = rows      # read while tracing
+            try:
+                return by_kernel(*args)
+            finally:
+                attention._CHUNK_Q_ROWS = own
+        forms.append((f"kernel@{rows}", jax.jit(at_tile)))
     out = {}
-    for name, fn in (("absorbed", absorbed), ("expanded", expanded)):
+    for name, fn in forms:
         jax.block_until_ready(fn(q, pool, w_ukv))
         t = time.perf_counter()
         for _ in range(reps):

@@ -12,7 +12,11 @@ with the sampling epilogue the engine puts behind it and jitted and donated
 exactly as the engine does (`serve/engine/engine.py: _paged_jits`: the
 programs it dispatches), and prints for each the GiB of arguments, temporaries and
 output, and every operation of the compiled program whose result is at
-least half of one layer's pool (K or V), with its layout. In a healthy
+least half of one layer's pool (K or V), with its layout, and the bytes its
+`copy-start` / `copy-done` pairs move by loop depth (`loop_copy_bytes`: what a
+chunk program's key loop moved between HBM and the compiler's scoped memory on
+every key tile before PR 41, PERF.md §5; 0 inside a layer's loops with the
+chunk kernel, which has no key loop). In a healthy
 paged program the pool enters in the layout the device keeps, is the layer
 scan's carry, and only the in-place row update names it (`fusion(scatter)`
 or `dynamic-update-slice` with the pool's own shape, aliased to the
@@ -36,6 +40,7 @@ from __future__ import annotations
 
 import argparse
 import json
+import math
 import os
 import re
 import sys
@@ -52,6 +57,12 @@ _SHAPE = re.compile(r"(\w+)\[([\d,]*)\](\{[^}]*\})?")
 
 
 _PASSES_ALONG = ("parameter", "tuple", "get-tuple-element", "bitcast", "while")
+
+
+def _array_bytes(dtype: str, dims: str) -> int:
+    """Bytes of an array the text writes as `dtype[dims]`."""
+    return _DTYPE_BYTES.get(dtype, 0) * math.prod(
+        int(d) for d in filter(None, dims.split(",")))
 
 
 def big_ops(hlo_text: str, min_bytes: int):
@@ -83,12 +94,56 @@ def big_ops(hlo_text: str, min_bytes: int):
             called = re.search(r"calls=%([\w.\-]+)", line)
             opcode = f"fusion({roots.get(called.group(1), '?') if called else '?'})"
         for dtype, dims, layout in _SHAPE.findall(result):
-            n = _DTYPE_BYTES.get(dtype, 0)
-            for d in filter(None, dims.split(",")):
-                n *= int(d)
+            n = _array_bytes(dtype, dims)
             if n >= min_bytes:
                 out.append((opcode, name, f"{dtype}[{dims}]{layout}", n))
     return out
+
+
+_COMPUTATION = re.compile(r"^(?:ENTRY\s+)?%?([\w.\-]+)\s.*\{\s*$")
+_CALLED = re.compile(r"(body|condition|calls|to_apply|true_computation|"
+                     r"false_computation)=%?([\w.\-]+)|branch_computations=\{([^}]*)\}")
+
+
+def loop_copy_bytes(hlo_text: str) -> dict:
+    """{loop depth: bytes} that the `copy-start` / `copy-done` pairs of a
+    compiled program move, by how many `while` bodies enclose them (0: outside
+    any loop; 1: the layer scan of a one-pass model; one deeper: a loop inside
+    a layer, which in a chunk program is the key loop of `_paged_layers`).
+    Static text: each pair once, whatever the loops' trip counts. The bytes
+    are those of the pair's first result, the array that is moved."""
+    calls, copies, name, entry = {}, {}, None, None
+    for line in hlo_text.splitlines():
+        if name is None:
+            m = _COMPUTATION.match(line)
+            if m and "=" not in line.split("{")[0].split("(")[0]:
+                name = m.group(1)
+                calls[name], copies[name] = [], 0
+                if line.startswith("ENTRY"):
+                    entry = name
+            continue
+        if line.startswith("}"):
+            name = None
+            continue
+        m = _INSTR.match(line)
+        if m and m.group(3) == "copy-start":
+            copies[name] += _array_bytes(*_SHAPE.search(m.group(2)).groups()[:2])
+        for kind, one, many in _CALLED.findall(line):
+            for callee in ([one] if one else re.findall(r"%?([\w.\-]+)", many)):
+                calls[name].append((callee, kind == "body"))
+    by_depth, seen = {}, set()
+
+    def walk(comp, depth):
+        if (comp, depth) in seen or comp not in calls:
+            return
+        seen.add((comp, depth))
+        if copies[comp]:
+            by_depth[depth] = by_depth.get(depth, 0) + copies[comp]
+        for callee, is_body in calls[comp]:
+            walk(callee, depth + is_body)
+
+    walk(entry, 0)
+    return dict(sorted(by_depth.items()))
 
 
 def rehearse(cfg, device, num_blocks: int, block_size: int, lanes: int = 8,
@@ -167,8 +222,15 @@ def rehearse(cfg, device, num_blocks: int, block_size: int, lanes: int = 8,
             report["programs"][name] = {"refused": str(e).splitlines()[0][:300]}
             continue
         mem = compiled.memory_analysis()
-        ops = big_ops(compiled.as_text(), layer_pool // 2)
+        text = compiled.as_text()
+        ops = big_ops(text, layer_pool // 2)
+        copies = loop_copy_bytes(text)
+        # a loop inside a layer: under the layer scan, itself under the pass
+        # scan of a looped model
+        in_layer = sum(b for d, b in copies.items() if d > 1 + (cfg.ut_steps > 1))
         report["programs"][name] = {
+            "loop_copy_MiB": {str(d): b / 2**20 for d, b in copies.items()},
+            "key_loop_copy_MiB": in_layer / 2**20,
             "arguments_GiB": mem.argument_size_in_bytes / 2**30,
             "temp_GiB": mem.temp_size_in_bytes / 2**30,
             "output_GiB": mem.output_size_in_bytes / 2**30,
@@ -182,7 +244,10 @@ def rehearse(cfg, device, num_blocks: int, block_size: int, lanes: int = 8,
               f"temp {mem.temp_size_in_bytes / 2**30:.4f}, "
               f"output {mem.output_size_in_bytes / 2**30:.2f}, "
               f"aliased {mem.alias_size_in_bytes / 2**30:.2f}; "
-              f"{len(ops)} operation(s) of >= {layer_pool / 2**21:.1f} MiB",
+              f"{len(ops)} operation(s) of >= {layer_pool / 2**21:.1f} MiB; "
+              f"copy-start/copy-done pairs inside a loop of a layer (a chunk's key "
+              f"loop) move {in_layer / 2**20:.1f} MiB a trip, by loop depth "
+              + (", ".join(f"{d}: {b / 2**20:.1f}" for d, b in copies.items()) or "none"),
               flush=True)
         for op, n, shape, b in ops:
             print(f"    {op:28s} {shape}  {b / 2**20:.1f} MiB  %{n}", flush=True)

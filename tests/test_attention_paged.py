@@ -1,0 +1,348 @@
+"""A chunk's attention over a wide table as ONE kernel (`ops/attention.py`
+`paged_chunk_attention`): in interpret mode against the plain key loop of
+`models/gpt.py` `_paged_layers` it stands in for, through the paged programs
+themselves, and the rule on shapes that sends a program to it, as the
+program reads it and as the engine's host counts with it."""
+
+import contextlib
+import functools
+
+import numpy as np
+import pytest
+
+BS = 8          # tokens a block
+NB = 96         # blocks of the pool
+TILE = 32       # keys a tile of the key loop here: a table of 16 blocks is 4 tiles
+W = 16
+TOL = 3e-5      # float32 programs, logits near 1
+
+_COMMON = dict(vocab_size=64, n_layers=2, d_model=64, d_mlp=96, max_seq=256,
+               attn_impl="ref", remat=False, norm="rmsnorm", activation="swiglu",
+               pos="rotary", tie_embeddings=False)
+MODELS = {
+    # ONE K/V head, the values inside the key row: 128 + 8 features in a row
+    # of 256, the last 120 columns padding
+    "latent": dict(n_heads=4, d_head=16, q_lora_rank=24, kv_lora_rank=128,
+                   rotary_dim=8, init="unit_stream",
+                   init_gains=(("embed", 1.5), ("dq", 1.0), ("q", 1.5), ("dkv", 1.0),
+                               ("k", 1.5), ("v", 1.0), ("o", 0.9), ("mlp_in", 1.0),
+                               ("mlp_out", 0.5), ("head", 1.0))),
+    "grouped": dict(n_heads=4, n_kv_heads=2, d_head=128, rotary_dim=32),
+    # layer 0 global without positions, layer 1 rotary under a window of 40
+    # keys: two KV groups, two tables, the window a traced scalar a layer
+    "grouped-window": dict(n_heads=4, n_kv_heads=2, d_head=128, rotary_dim=32,
+                           rope_layout=(0, 1), sliding_window_layout=(0, 1),
+                           sliding_window=40),
+    "multi-head": dict(n_heads=2, d_head=128, rotary_dim=32),
+}
+# A table is a map, not a range: scattered, unordered physical blocks.
+TABLES = np.asarray([
+    [7, 3, 21, 12, 5, 30, 9, 18, 40, 2, 33, 27, 14, 36, 1, 25],
+    [44, 8, 19, 31, 6, 38, 11, 29, 47, 16, 4, 35, 23, 42, 10, 20],
+    [50, 61, 52, 77, 54, 69, 56, 83, 58, 71, 60, 51, 62, 79, 64, 53],
+    [90, 66, 81, 68, 73, 70, 85, 72, 55, 74, 87, 76, 57, 78, 89, 80],
+], np.int32)
+
+
+def _cfg(model):
+    import jax.numpy as jnp
+
+    from ray_tpu.models.gpt import GPTConfig
+
+    return GPTConfig(**_COMMON, **MODELS[model], dtype=jnp.float32)
+
+
+@contextlib.contextmanager
+def _programs(by_kernel: bool):
+    """The three paged programs jitted anew, their key loop in tiles of
+    `TILE` keys; with `by_kernel` as the chip traces them (`_on_tpu`), the
+    chunk kernel in interpret mode and the norms' kernels left to their plain
+    forms (they are not what is under test)."""
+    import jax
+
+    from ray_tpu.models import gpt
+    from ray_tpu.ops import attention, norms
+    from ray_tpu.serve.engine import engine as engine_module
+
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(gpt, "_ATTN_TILE_KEYS", TILE)
+        mp.setattr(engine_module, "_JITS", None)    # the engine's traces too
+        if by_kernel:
+            mp.setattr(attention, "_on_tpu", lambda: True)
+            mp.setattr(attention, "paged_chunk_attention", functools.partial(
+                attention.paged_chunk_attention, interpret=True))
+            mp.setattr(norms, "_rmsnorm_pallas", norms._rmsnorm_ref)
+        yield (jax.jit(lambda *a: gpt.prefill_paged(*a), static_argnums=(6,)),
+               jax.jit(lambda *a: gpt.verify_step_paged(*a), static_argnums=(6,)))
+
+
+def _tables(cfg, lanes):
+    """[lanes, W] or [lanes, G, W]: every group's table its own blocks."""
+    from ray_tpu.models.gpt import kv_layout
+
+    G = len(kv_layout(cfg).windows)
+    t = np.stack([TABLES[2 * b:2 * b + G] for b in range(lanes)])
+    return t if G > 1 else t[:, 0]
+
+
+def _prefill(prefill, params, cfg, tokens, table, kv, chunks):
+    """The prompt `tokens` in chunks of the given lengths, each padded to a
+    bucket of 32: (the last chunk's logits, kv)."""
+    import jax.numpy as jnp
+
+    start = 0
+    for n in chunks:
+        padded = np.zeros((1, 32), np.int32)
+        padded[0, :n] = tokens[start:start + n]
+        logits, kv = prefill(params, jnp.asarray(padded), jnp.int32(n),
+                             jnp.int32(start), jnp.asarray(table), kv, cfg)
+        start += n
+    return logits, kv
+
+
+def _poison(kv, blocks):
+    """NaN in every row of `blocks` in every layer: a tile fetched and
+    multiplied, even under a mask that is false everywhere, would show
+    (0 x NaN)."""
+    import jax.numpy as jnp
+
+    blocks = jnp.asarray(np.asarray(blocks).reshape(-1))
+    return {name: rows.at[:, blocks].set(jnp.nan) for name, rows in kv.items()}
+
+
+def _chunks(programs, cfg, params, tokens):
+    """B = 1: a 75-token prompt in chunks of 30, 20 and 25 over a table of 4
+    tiles; the second chunk's queries (positions 30..49) straddle the edge of
+    tile 0, the third's (50..74) that of tile 1, and the sequence never
+    reaches tile 3, whose blocks hold NaN: `trips` < `NT`."""
+    from ray_tpu.models.gpt import init_paged_cache
+
+    prefill, _ = programs
+    table = _tables(cfg, 1)[0]
+    kv = _poison(init_paged_cache(cfg, NB, BS), table[..., 12:])
+    logits, kv = _prefill(prefill, params, cfg, tokens, table, kv, (30, 20, 25))
+    return logits, kv, table[..., :12]
+
+
+def _lanes(programs, cfg, params, tokens):
+    """B = 2 and a padding lane between them: a verify step of 3 tokens a
+    lane (rows padded to a sublane tile), lane 0 at position 100 (its window
+    layer starts at tile 1: `first` > 0, and tile 0's blocks hold NaN by
+    then), lane 2 at position 37 with 2 real tokens (it needs tiles 0 and 1
+    of the 4 the step's trips cover)."""
+    import jax.numpy as jnp
+
+    from ray_tpu.models.gpt import init_paged_cache, kv_layout
+
+    prefill, verify = programs
+    tables = _tables(cfg, 2)
+    kv = init_paged_cache(cfg, NB, BS)
+    _, kv = _prefill(prefill, params, cfg, tokens, tables[0], kv, (32, 32, 32, 4))
+    _, kv = _prefill(prefill, params, cfg, tokens[50:], tables[1], kv, (32, 5))
+    windows = kv_layout(cfg).windows
+    if any(windows):    # the window group's first tile: no query reaches back
+        kv = _poison(kv, tables[0][list(windows).index(max(windows))][:4])
+    tables = np.stack([tables[0], np.zeros_like(tables[0]), tables[1]])
+    logits, kv = verify(
+        params, jnp.asarray(np.stack([tokens[100:103], [0, 0, 0], tokens[87:90]])),
+        jnp.asarray([100, 0, 37], jnp.int32), jnp.asarray([3, 0, 2], jnp.int32),
+        jnp.asarray(tables), kv, cfg)
+    rows = np.concatenate([tables[0][..., 4:13].reshape(-1), tables[2][..., :5].reshape(-1)])
+    return (logits[0], logits[2, :2]), kv, rows
+
+
+STEPS = {"chunks-B1": _chunks, "lanes-B2-and-padding": _lanes}
+
+
+@pytest.mark.parametrize("step", list(STEPS))
+@pytest.mark.parametrize("model", list(MODELS))
+def test_the_chunk_kernel_is_the_key_loop(model, step):
+    """The same programs on the same inputs by the plain loop and by the
+    kernel: the logits of every real token and the pool's rows of every
+    block the step may read or write agree, NaN in tiles outside the bounds
+    reaches neither, and the kernel is in the traced program."""
+    import jax
+
+    from ray_tpu.models import gpt
+
+    cfg = _cfg(model)
+    params = gpt.init_params(jax.random.PRNGKey(5), cfg)
+    tokens = np.random.default_rng(11).integers(1, cfg.vocab_size, 128).astype(np.int32)
+    got = {}
+    for by_kernel in (False, True):
+        with _programs(by_kernel) as programs:
+            assert gpt.paged_attn_kernel(cfg, 32, W, BS) is by_kernel
+            logits, kv, blocks = STEPS[step](programs, cfg, params, tokens)
+            got[by_kernel] = jax.tree_util.tree_map(
+                np.asarray, (logits, {k: v[:, blocks.reshape(-1)] for k, v in kv.items()}))
+    for plain, kernel in zip(*map(jax.tree_util.tree_leaves, (got[False], got[True]))):
+        assert np.isfinite(plain).all() and np.isfinite(kernel).all()
+        assert np.abs(plain - kernel).max() < TOL
+
+
+def test_the_kernel_is_named_in_the_program_and_the_loop_is_gone():
+    import jax
+    import jax.numpy as jnp
+
+    from ray_tpu.models import gpt
+    from ray_tpu.ops import attention
+
+    cfg = _cfg("latent")
+    params = jax.eval_shape(lambda k: gpt.init_params(k, cfg), jax.random.PRNGKey(0))
+    kv = jax.eval_shape(lambda: gpt.init_paged_cache(cfg, NB, BS))
+    i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32)  # noqa: E731
+    calls = {}
+    for by_kernel in (False, True):
+        with _programs(by_kernel) as (prefill, _):
+            jaxpr = str(jax.make_jaxpr(
+                lambda *a: gpt.prefill_paged(*a, cfg)
+            )(params, i32(1, 32), i32(), i32(), i32(W), kv))
+        calls[by_kernel] = (jaxpr.count(attention.PAGED_CHUNK_KERNEL), "while" in jaxpr)
+    # once, in the layer scan's body; the plain form's one loop a layer is gone
+    assert calls == {False: (0, True), True: (1, False)}
+
+
+# (tokens a lane, table width in blocks, block size) at the module's own tile
+RULE = [
+    ("ax-k1", {}, 512, 256, 64, True),
+    ("ax-k1", {}, 512, 16, 64, False),          # 1,024 keys: one tile
+    ("ax-k1", {}, 1, 256, 64, False),           # the decode step
+    ("smallthinker-21b-a3b", {}, 512, 128, 64, True),
+    ("smallthinker-21b-a3b", {}, 256, 8, 64, False),
+    ("ouro-2.6b", {}, 256, 128, 16, True),
+    ("ouro-2.6b", {}, 256, 64, 16, False),
+    ("jamba2-3b", {}, 256, 16, 128, True),
+    ("jamba2-3b", {}, 256, 8, 128, False),
+    ("gpt2-large", {}, 64, 64, 16, False),      # 1,024 positions: never two tiles
+    ("gpt2-large", {}, 64, 128, 16, False),     # and heads of 64 fill no lane tile
+    ("gptj-6b", {}, 64, 128, 16, True),
+]
+
+
+@pytest.mark.parametrize("model,overrides,tokens,width,block,want", RULE,
+                         ids=[f"{r[0]}-{r[2]}x{r[3]}x{r[4]}" for r in RULE])
+def test_the_rule_is_a_function_of_shapes(model, overrides, tokens, width, block, want,
+                                          monkeypatch):
+    """On the chip the rule says what the shapes say; off it, never."""
+    from ray_tpu.models import gpt
+    from ray_tpu.ops import attention
+
+    cfg = gpt.CONFIGS[model](**overrides)
+    assert gpt.paged_attn_kernel(cfg, tokens, width, block) is False
+    monkeypatch.setattr(attention, "_on_tpu", lambda: True)
+    assert gpt.paged_attn_kernel(cfg, tokens, width, block) is want
+
+
+def test_the_engine_counts_chunks_with_the_programs_rule():
+    """`attn_chunks` counts every prefill chunk program dispatched and
+    `attn_chunks_kernel` those whose shapes the rule sends to the kernel: a
+    100-token prompt's table is 16 blocks = 4 tiles wide (every one of its
+    chunks), a 20-token prompt's 4 blocks = one tile (none). The kernel runs
+    (interpret mode) and the tokens are the plain loop's."""
+    import jax
+
+    from ray_tpu.models import gpt
+    from ray_tpu.serve.engine import EngineOptions, InferenceEngine
+
+    cfg = _cfg("multi-head")
+    params = jax.tree_util.tree_map(
+        lambda a: a * 3.0, gpt.init_params(jax.random.PRNGKey(3), cfg))
+    rng = np.random.default_rng(2)
+    prompts = [rng.integers(1, 64, 100).tolist(), rng.integers(1, 64, 20).tolist()]
+    seen = {}
+    for by_kernel in (False, True):
+        with _programs(by_kernel):
+            engine = InferenceEngine(cfg, params=params, options=EngineOptions(
+                num_blocks=NB, block_size=BS, max_num_seqs=4, prefill_chunk_tokens=32,
+                max_step_tokens=64, enable_prefix_caching=False))
+            ids = [engine.submit(prompt, max_new_tokens=4) for prompt in prompts]
+            for _ in range(200):
+                if not engine.scheduler.has_work():
+                    break
+                engine.step()
+            stats = engine.stats()
+            seen[by_kernel] = ([list(engine.stream(rid)) for rid in ids],
+                               stats["attn_chunks"], stats["attn_chunks_kernel"])
+    assert seen[False][1:] == (5, 0)        # 100 tokens in 4 chunks of 32, 20 in one
+    assert seen[True][1:] == (5, 4)
+    assert seen[True][0] == seen[False][0] and all(len(t) == 4 for t in seen[True][0])
+
+
+@pytest.mark.parametrize("width", [8, 128])
+def test_gpt2_larges_programs_never_hold_the_kernel(width, monkeypatch):
+    """The control: 1,024 positions are one tile and heads of 64 fill no
+    lane tile, so even traced as the chip traces them, over a table wider
+    than the model can fill, `gpt2-large`'s three programs hold no chunk
+    kernel (off the chip their text is the parent's to the byte:
+    `tests/test_axk1.py` pins it)."""
+    import jax
+    import jax.numpy as jnp
+
+    from ray_tpu.models import gpt
+    from ray_tpu.ops import attention
+
+    monkeypatch.setattr(attention, "_on_tpu", lambda: True)
+    cfg = gpt.CONFIGS["gpt2-large"](remat=False, remat_policy=None)
+    params = jax.eval_shape(lambda k: gpt.init_params(k, cfg), jax.random.PRNGKey(0))
+    kv = jax.eval_shape(lambda: gpt.init_paged_cache(cfg, 256, 16))
+    i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32)  # noqa: E731
+    programs = {
+        "prefill": jax.make_jaxpr(lambda *a: gpt.prefill_paged(*a, cfg))(
+            params, i32(1, 64), i32(), i32(), i32(width), kv),
+        "decode": jax.make_jaxpr(lambda *a: gpt.decode_step_paged(*a, cfg))(
+            params, i32(4), i32(4), i32(4, width), kv),
+        "verify": jax.make_jaxpr(lambda *a: gpt.verify_step_paged(*a, cfg))(
+            params, i32(4, 3), i32(4), i32(4), i32(4, width), kv),
+    }
+    for name, jaxpr in programs.items():
+        assert attention.PAGED_CHUNK_KERNEL not in str(jaxpr), name
+
+
+def test_the_rehearsal_finds_the_copies_inside_a_layers_loop():
+    """`scripts.paged_rehearse.loop_copy_bytes` on a program's text: the
+    bytes `copy-start` / `copy-done` pairs move, by how many `while` bodies
+    enclose them (what found the key loop's 64 MiB carry, and says that it
+    is gone)."""
+    from scripts.paged_rehearse import loop_copy_bytes
+
+    text = """HloModule jit_prefill
+
+%fused_computation.1 (p: f32[8]) -> f32[8] {
+  %p = f32[8]{0} parameter(0)
+  ROOT %n = f32[8]{0} negate(%p)
+}
+
+%key_loop_body (c: (f32[32768,512])) -> (f32[32768,512]) {
+  %c = (f32[32768,512]{1,0}) parameter(0)
+  %acc = f32[32768,512]{1,0} get-tuple-element(%c), index=0
+  %copy-start.1 = (f32[32768,512]{1,0:S(1)}, f32[32768,512]{1,0}, u32[]) copy-start(%acc)
+  %copy-done.1 = f32[32768,512]{1,0:S(1)} copy-done(%copy-start.1)
+  ROOT %t = (f32[32768,512]{1,0}) tuple(%copy-done.1)
+}
+
+%key_loop_cond (c: (f32[32768,512])) -> pred[] {
+  %c = (f32[32768,512]{1,0}) parameter(0)
+  ROOT %lt = pred[] constant(true)
+}
+
+%layer_body (c: (f32[32768,512])) -> (f32[32768,512]) {
+  %c = (f32[32768,512]{1,0}) parameter(0)
+  %w = bf16[1024,640]{1,0} constant(0)
+  %copy-start.2 = (bf16[1024,640]{1,0:S(1)}, bf16[1024,640]{1,0}, u32[]) copy-start(%w)
+  %copy-done.2 = bf16[1024,640]{1,0:S(1)} copy-done(%copy-start.2)
+  ROOT %while.2 = (f32[32768,512]{1,0}) while(%c), condition=%key_loop_cond, body=%key_loop_body
+}
+
+%layer_cond (c: (f32[32768,512])) -> pred[] {
+  %c = (f32[32768,512]{1,0}) parameter(0)
+  ROOT %lt = pred[] constant(true)
+}
+
+ENTRY %main (a: f32[32768,512]) -> (f32[32768,512]) {
+  %a = f32[32768,512]{1,0} parameter(0)
+  %t = (f32[32768,512]{1,0}) tuple(%a)
+  ROOT %while.1 = (f32[32768,512]{1,0}) while(%t), condition=%layer_cond, body=%layer_body
+}
+"""
+    assert loop_copy_bytes(text) == {1: 1024 * 640 * 2, 2: 32768 * 512 * 4}
+    assert loop_copy_bytes(text.replace("copy-start", "copy-begin")) == {}

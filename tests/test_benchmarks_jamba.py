@@ -176,7 +176,9 @@ def test_the_cell_and_its_files_are_in_the_benchmark():
     per_layer = {m["name"]: m for m in bench["per_layer"]}
     assert all(per_layer[name]["moves"] in e2e for name in layer)
     names = [m["name"] for m in bench["per_layer"]]
-    assert names[-4:] == list(NEW_METRICS)              # appended, in the issue's order
+    first = names.index(next(iter(NEW_METRICS)))        # appended, in the issue's order;
+    assert names[first:first + 4] == list(NEW_METRICS)  # PR 41's two behind them
+    assert names[first + 4:] == ["chunk_attn_kernel_share", "chunk_attn_time_share"]
     for name in NEW_METRICS:
         assert per_layer[name]["workloads"] == [CELL]
     assert per_layer["state_slot_util_share"]["layer"] == "engine scheduler and KV"

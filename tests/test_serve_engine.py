@@ -2072,3 +2072,48 @@ def test_the_selective_scan_kernel_compiles_for_v5e_at_the_published_width(
     mem = compiled.memory_analysis()
     assert mem.alias_size_in_bytes == lanes * 16 * 5120 * 4     # the state, in place
     assert mem.temp_size_in_bytes < 1 << 20
+
+
+CHUNK_KERNEL_SHAPES = {
+    # K/V heads, folded query rows, key row, value row (None: inside the key
+    # row, its first 512 columns), lanes
+    "ax-k1-latent-64x512": (1, 64 * 512, 640, None, 1),
+    "smallthinker-grouped-7x512": (4, 7 * 512, 128, 128, 1),
+    "ouro-multi-head-verify-3": (16, 3, 128, 128, 4),
+}
+
+
+@pytest.mark.parametrize("shape", list(CHUNK_KERNEL_SHAPES))
+def test_the_chunk_attention_kernel_compiles_for_v5e_at_the_cells_widths(v5e_chip, shape):
+    """Compile-only: `ops/attention.py`'s `paged_chunk_attention` over a
+    table of 4 tiles of 1,024 keys at the widths the serving cells bring it
+    (a latent chunk's 32,768 folded rows of 640, a grouped chunk's 3,584 of
+    128, a verify step's 3 rows a head padded to a sublane tile): its tiles
+    fit the chip's fast memory, and the scores are no temporary of the
+    program (the plain loop's were 128 MiB a tile for the first)."""
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import SingleDeviceSharding
+
+    from ray_tpu.models.gpt import _ATTN_TILE_KEYS, _NO_WINDOW
+    from ray_tpu.ops import attention
+
+    heads, rows, key_row, value_row, lanes = CHUNK_KERNEL_SHAPES[shape]
+    one_chip = SingleDeviceSharding(v5e_chip)
+    arr = lambda dt, *s: jax.ShapeDtypeStruct(s, dt, sharding=one_chip)  # noqa: E731
+    keys = 4 * _ATTN_TILE_KEYS
+    dv = value_row or 512
+
+    def run(q, k, v, qpos, first, trips):
+        return attention.paged_chunk_attention(
+            q, k, v, qpos, first, trips, _NO_WINDOW, tile_keys=_ATTN_TILE_KEYS,
+            dv=dv, sm_scale=0.1)
+
+    compiled = _within(120, lambda: jax.jit(run).lower(
+        arr(jnp.bfloat16, lanes, heads, rows, key_row),
+        arr(jnp.bfloat16, lanes, keys, heads * key_row),
+        None if value_row is None else arr(jnp.bfloat16, lanes, keys, heads * dv),
+        arr(jnp.int32, lanes, rows), arr(jnp.int32, lanes), arr(jnp.int32)).compile())
+    assert attention.PAGED_CHUNK_KERNEL in compiled.as_text()
+    out = lanes * heads * rows * dv * 2
+    assert compiled.memory_analysis().temp_size_in_bytes <= 2 * out + (1 << 20)
