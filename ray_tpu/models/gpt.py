@@ -20,7 +20,10 @@ over one compressed cache row a token, a leading dense layer, sigmoid-routed
 experts of which this chip holds a range, beside a shared expert; served
 through the paged programs), `jamba2_3b` (state-space layers whose state is
 one fixed slot a sequence, an attention layer every fourteenth; served
-through the paged programs).
+through the paged programs), `laguna_xs2` (window layers of 64 query heads
+beside full layers of 48 over the same 8 K/V heads, a rotary table a kind, a
+gate a head, a leading dense layer, 256 sigmoid-routed experts beside a
+shared one; served through the paged programs).
 """
 
 from __future__ import annotations
@@ -61,11 +64,27 @@ class GPTConfig:
     # published configs give them: rope_layout 1 = rotary, 0 = NO positional
     # term at all (needs pos="rotary"); sliding_window_layout 1 = the layer
     # attends to the last `sliding_window` keys (query i sees keys j with
-    # i - window < j <= i), 0 = global. All layers share parameter shapes,
-    # so the kind rides the layer scan as data. JSON lists become tuples.
+    # i - window < j <= i), 0 = global. Where all layers share parameter
+    # shapes the kind rides the layer scan as data. JSON lists become tuples.
     rope_layout: Optional[Tuple[int, ...]] = None
     sliding_window_layout: Optional[Tuple[int, ...]] = None
     sliding_window: int = 0
+    # Window layers whose SHAPES are their own, on where `n_heads_window` > 0:
+    # a window layer has that many query heads (over the same K/V heads) and
+    # its own rotary table (`window_rotary_dim` features of a head at
+    # `window_rope_theta`, never scaled; 0 = as the global layers'), while
+    # `n_heads`, `rotary_dim`, `rope_theta` and `rope_scaling` describe the
+    # global layers. The attention weights are then two stacks BY KIND (`w_q`,
+    # `w_kv`, `w_o`, `w_head_gate` [global layers, ...] beside `win_<name>`
+    # [window layers, ...]) under one stack of norms and MLPs, and the layer
+    # loop is cut into runs of one kind (`_mixed_layers`). No bias anywhere.
+    n_heads_window: int = 0
+    window_rotary_dim: int = 0
+    window_rope_theta: float = 0.0
+    # One gate a head a token: head n's attention output times
+    # sigmoid(h W_g)[n] before the output projection, h the normed input
+    # that q, k and v read (`w_head_gate` [L, E, heads]).
+    attn_gate: bool = False
     parallel_block: bool = False     # GPT-J: attn and mlp in parallel
     # Sandwich norms: a second norm on each sublayer's OUTPUT before it joins
     # the stream, x + N2(Attn(N1 x)) then a + N4(Mlp(N3 a)).
@@ -199,10 +218,26 @@ class GPTConfig:
                              "and a query bottleneck (q_lora_rank)")
         if self.dense_layers and (
                 not 0 < self.dense_layers < self.n_layers or self.d_dense_mlp < 1
-                or self.ut_steps > 1 or self.layer_kinds is not None):
+                or self.ut_steps > 1
+                or (self.layer_kinds is not None and not self.n_heads_window)):
             raise ValueError(
                 "dense_layers: fewer than n_layers, of width d_dense_mlp, in a "
-                "one-pass model whose layers are of one attention kind")
+                "one-pass model whose layers are of one attention kind (or of "
+                "two kinds by stack, n_heads_window)")
+        if self.n_heads_window and (
+                self.rope_layout is not None or self.pos != "rotary"
+                or self.ut_steps > 1 or self.kv_lora_rank or self.ssm_layout
+                or self.kv_heads == self.n_heads or self.n_heads_window % self.kv_heads
+                or self.sandwich_norm or self.parallel_block
+                or self.activation not in ("swiglu", "reglu")
+                or len(set((self.sliding_window_layout or (0,))[self.dense_layers:])) < 2
+                or any(self.sliding_window_layout[:self.dense_layers])):
+            raise ValueError(
+                "n_heads_window: a one-pass rotary model of grouped-query heads "
+                "with a gated MLP or gated experts, window layers and global "
+                "layers both behind global leading dense layers")
+        if (self.window_rotary_dim or self.window_rope_theta) and not self.n_heads_window:
+            raise ValueError("a window layer's own rotary table needs n_heads_window")
         if self.ssm_layout and (
                 self.layer_kinds is not None or self.ut_steps > 1 or self.kv_lora_rank
                 or self.dense_layers or self.mlp_type != "dense" or self.sandwich_norm
@@ -222,6 +257,13 @@ class GPTConfig:
     @property
     def ssm_inner(self) -> int:
         return self.ssm_expand * self.d_model
+
+    @property
+    def layer_heads(self) -> Tuple[int, ...]:
+        """[L] query heads of each layer."""
+        win = self.sliding_window_layout or (0,) * self.n_layers
+        return tuple(self.n_heads_window if w and self.n_heads_window else self.n_heads
+                     for w in win)
 
     @property
     def held_experts(self) -> int:
@@ -279,11 +321,17 @@ class GPTConfig:
             n_ssm = sum(self.ssm_layout)
             return (n_ssm * ssm + (L - n_ssm) * attn + L * (3 * E * F + 2 * E)
                     + V * E + E + (0 if self.tie_embeddings else E * V))
-        total = (L * (attn + norms) + (L - self.dense_layers) * mlp_params
+        attn_all = L * attn
+        if self.n_heads_window or self.attn_gate:   # each layer's own heads, the gate
+            attn_all = sum(2 * E * self.d_head * (h + self.kv_heads)
+                           + self.attn_gate * E * h for h in self.layer_heads)
+        total = (attn_all + L * norms + (L - self.dense_layers) * mlp_params
                  + self.dense_layers * 3 * E * self.d_dense_mlp
                  + V * E + (0 if self.tie_embeddings else E * V))
         if self.ut_steps > 1:
             total += E + 1  # the exit gate
+        if self.n_heads_window:
+            total += E      # counted whole: the final norm too
         if self.pos == "learned":
             total += self.max_seq * E
         return total
@@ -605,6 +653,103 @@ def jamba2_3b(**kw):
     )
 
 
+def laguna_xs2(**kw):
+    """Laguna-XS.2 (huggingface.co/poolside/Laguna-XS.2, `model_type:
+    "laguna"`, 33.4 B parameters, 3 B active): 40 layers of 2048, 8 K/V heads
+    of 128 in every layer; layer l a FULL attention layer of 48 query heads
+    where l % 4 == 0 (rotary over the first 64 features of a head, theta
+    500,000, YaRN factor 64 over 4,096 positions, cos and sin times 1.4159)
+    and else a window layer of 64 query heads (the last 512 keys; rotary over
+    the whole head, theta 10,000, unscaled); one sigmoid gate a head a token
+    on the attention output; layer 0's MLP dense SiLU-gated of 8192, every
+    other layer 256 sigmoid-routed experts of 512 (top-8, the kept scores
+    normalised, x 2.5, the router on the normed MLP input) beside one shared
+    expert; RMSNorm 1e-6, untied head, no bias, bfloat16. Serving only:
+    `forward` (attn_impl="ref") and the paged programs; a chip serves a
+    pipeline stage of it (`n_layers`, which the caller states)."""
+    L = kw.get("n_layers", 40)
+    return GPTConfig(
+        **{
+            **dict(
+                n_layers=L,
+                dense_layers=1,
+                d_model=2048,
+                n_heads=48,
+                n_heads_window=64,
+                n_kv_heads=8,
+                d_head=128,
+                d_mlp=512,
+                d_dense_mlp=8192,
+                vocab_size=100352,
+                max_seq=262144,
+                norm="rmsnorm",
+                activation="swiglu",
+                pos="rotary",
+                rotary_dim=64,
+                rope_theta=500000.0,
+                rope_scaling=(("attention_factor", 1.4158883083359672),
+                              ("beta_fast", 64.0), ("beta_slow", 1.0), ("factor", 64.0),
+                              ("original_max_position_embeddings", 4096.0)),
+                window_rotary_dim=128,
+                window_rope_theta=10000.0,
+                sliding_window_layout=tuple(int(l % 4 != 0) for l in range(L)),
+                sliding_window=512,
+                attn_gate=True,
+                tie_embeddings=False,
+                mlp_type="moe",
+                moe_experts=256,
+                moe_top_k=8,
+                moe_routing="dropless",
+                moe_scoring="sigmoid",
+                moe_route_scale=2.5,
+                moe_router_in="mlp",
+                moe_shared=1,
+                param_dtype=jnp.bfloat16,
+                # `ax_k1`'s gains (the same router, the same shared expert,
+                # the same scale 2.5) but for the ROUTED experts' output, a
+                # gain of its own (`expert_out` 0.1 where the dense MLP and
+                # the shared expert keep `mlp_out` 0.5), and a gate logit of
+                # std 1, so that a head's gate lies between 0.27 and 0.73 for
+                # two tokens of three and a model without it reads otherwise.
+                # Scores of std 1.5^2 = 2.25 in a window layer and, with cos
+                # and sin times 1.416 over the rotated half of a head, 3.6 in
+                # a full one: a handful of keys carry a query's weight, so a
+                # key at the window's edge and a rotation by the other kind's
+                # table move tokens. Why `expert_out`: of 256 sigmoid scores
+                # the eighth and the ninth largest lie 0.1 apart in the mean,
+                # so bfloat16's rounding of the router's input swaps them in
+                # one token-layer of five to ten; all 256 experts are held,
+                # so every swap lands, and the kept scores are all near 1, so
+                # a swap moves an eighth of the routed sum whatever the
+                # rounding's size. At `expert_out` 0.5 that moved the sound
+                # engine's tokens as far as float8 weights, a window a block
+                # too wide or top-7 do (the benchmark's own token check on
+                # the chip at the published widths, my chip runs, PR 43:
+                # sound 0.004-0.116 over 26 seeds, float8 0.050-0.158, the
+                # window 0.041-0.099, top-7 0.063-0.116); at 0.1 a swap moves
+                # a fifth of that and the sound engine reads 0.002-0.006 where
+                # float8 reads 0.019-0.051 and the window 0.019-0.032 (8
+                # seeds). Top-7 for top-8 IS such a swap and is no longer
+                # told apart (PERF.md 7 (2)). Tried beside it, 8 seeds each:
+                # q, k 1.9 with `expert_out` 0.15 (sound up to 0.016, float8
+                # from 0.026), embed 1.0 (0.010 | 0.022), `expert_out` 0.05
+                # with router 1.0 (the window from 0.007). A rounding of 1e-3
+                # at the embedding grows 34-35fold through the float32
+                # reference's five layers at 0.5 (`--parts growth`).
+                # `scripts/laguna_tolerance.py` reads each on the chip. No
+                # program's shape or time depends on the numbers.
+                init="unit_stream",
+                init_gains=(("embed", 1.5), ("q", 1.5), ("k", 1.5), ("v", 1.0),
+                            ("o", 0.9), ("gate", 1.0), ("router", 2.0),
+                            ("mlp_in", 1.0), ("mlp_out", 0.5), ("expert_out", 0.1),
+                            ("head", 1.0)),
+                attn_impl="ref",
+            ),
+            **kw,
+        }
+    )
+
+
 CONFIGS = {
     "gpt2-small": gpt2_small,
     "gpt2-medium": gpt2_medium,
@@ -615,6 +760,7 @@ CONFIGS = {
     "ouro-2.6b": ouro_2_6b,
     "ax-k1": ax_k1,
     "jamba2-3b": jamba2_3b,
+    "laguna-xs2": laguna_xs2,
 }
 
 
@@ -640,11 +786,14 @@ def param_logical_dims(cfg: GPTConfig) -> Dict[str, Tuple[Optional[str], ...]]:
         del dims["w_qkv"], dims["b_qkv"]
         dims["w_q"] = ("layers", "embed", "heads", "head_dim")
         dims["w_kv"] = ("layers", "embed", None, "heads", "head_dim")
-    if cfg.kv_lora_rank or cfg.dense_layers or cfg.moe_shared or cfg.ssm_layout:
+    if cfg.kv_lora_rank or cfg.dense_layers or cfg.moe_shared or cfg.ssm_layout \
+            or cfg.n_heads_window or cfg.attn_gate:
         raise NotImplementedError(
             "no sharding is written for latent attention (kv_lora_rank), "
-            "leading dense layers (dense_layers), a shared expert (moe_shared) "
-            "or state-space layers (ssm_layout): they are served on one chip")
+            "leading dense layers (dense_layers), a shared expert (moe_shared), "
+            "state-space layers (ssm_layout), attention stacks by kind "
+            "(n_heads_window) or a gate a head (attn_gate): they are served on "
+            "one chip")
     if cfg.mlp_type == "moe":
         dims["moe_router"] = ("layers", "embed", "experts")
         dims["moe_w_in"] = ("layers", "experts", "embed", "mlp")
@@ -703,6 +852,8 @@ def _init_unit_stream(rng, cfg: GPTConfig) -> Dict[str, jnp.ndarray]:
     E, F, V, X = cfg.d_model, cfg.d_mlp, cfg.vocab_size, cfg.held_experts
     L = cfg.n_layers - cfg.dense_layers         # the scanned stack's layers
     La = L - sum(cfg.ssm_layout or ())          # the attention stack's layers
+    Lw = sum(cfg.sliding_window_layout) if cfg.n_heads_window else 0
+    La -= Lw                                    # window layers: a stack of their own
     H, Hkv, Dh = cfg.n_heads, cfg.kv_heads, cfg.d_head
     k = jax.random.split(rng, 16)
     dt, g = cfg.param_dtype, dict(cfg.init_gains)
@@ -747,12 +898,24 @@ def _init_unit_stream(rng, cfg: GPTConfig) -> Dict[str, jnp.ndarray]:
         else:
             params.update({"w_qkv": jnp.stack([q, *kv], axis=2),
                            "b_qkv": jnp.zeros((L, 3, H, Dh), dt)})
+    if cfg.attn_gate:
+        params["w_head_gate"] = n(k[13], (La, E, H), g["gate"], E)
+    if cfg.n_heads_window:      # the window layers' attention, their own shapes
+        kw, Hw = jax.random.split(k[14], 5), cfg.n_heads_window
+        params.update({
+            "win_w_q": n(kw[0], (Lw, E, Hw, Dh), g["q"], E),
+            "win_w_kv": jnp.stack([n(kw[1], (Lw, E, Hkv, Dh), g["k"], E),
+                                   n(kw[2], (Lw, E, Hkv, Dh), g["v"], E)], axis=2),
+            "win_w_o": n(kw[3], (Lw, Hw, Dh, E), g["o"], Hw * Dh),
+        })
+        if cfg.attn_gate:
+            params["win_w_head_gate"] = n(kw[4], (Lw, E, Hw), g["gate"], E)
     if cfg.mlp_type == "moe":
         params.update({
             "moe_router": n(k[3], (L, E, cfg.moe_experts), g["router"], E),
             "moe_w_in": n(k[4], (L, X, E, F), g["mlp_in"], E),
             "moe_w_gate": n(k[8], (L, X, E, F), g["mlp_in"], E),
-            "moe_w_out": n(k[5], (L, X, F, E), g["mlp_out"], F),
+            "moe_w_out": n(k[5], (L, X, F, E), g.get("expert_out", g["mlp_out"]), F),
         })
         if cfg.moe_shared:
             ks, Fs = jax.random.split(k[12], 3), cfg.moe_shared * F
@@ -777,8 +940,9 @@ def _init_unit_stream(rng, cfg: GPTConfig) -> Dict[str, jnp.ndarray]:
     if cfg.dense_layers:    # the leading layers: the same block, a dense MLP
         lead = _init_unit_stream(jax.random.fold_in(k[12], 1), _lead_cfg(cfg))
         params.update({"lead_" + name: a for name, a in _layer_stack(lead).items()})
-    if cfg.ssm_layout:      # as published: no bias anywhere
-        params = {name: a for name, a in params.items() if name not in _BIAS_KEYS}
+    if cfg.ssm_layout or cfg.n_heads_window:    # as published: no bias anywhere
+        params = {name: a for name, a in params.items()
+                  if name.removeprefix("lead_") not in _BIAS_KEYS}
     return params
 
 
@@ -816,10 +980,24 @@ def _init_ssm_stack(rng, cfg: GPTConfig, n) -> Dict[str, jnp.ndarray]:
 
 def _lead_cfg(cfg: GPTConfig) -> GPTConfig:
     """The config of the `dense_layers` leading layers as a model of their
-    own: the same attention, a dense MLP of `d_dense_mlp`."""
+    own: the same attention (the global layers', where the window layers
+    have shapes of their own), a dense MLP of `d_dense_mlp`."""
     return dataclasses.replace(
         cfg, n_layers=cfg.dense_layers, dense_layers=0, mlp_type="dense",
-        d_mlp=cfg.d_dense_mlp)
+        d_mlp=cfg.d_dense_mlp, sliding_window_layout=None, n_heads_window=0,
+        window_rotary_dim=0, window_rope_theta=0.0)
+
+
+def _window_cfg(cfg: GPTConfig) -> GPTConfig:
+    """What `_block` and `_rope_tables` read of a WINDOW layer of a model
+    whose kinds have shapes of their own (`n_heads_window`): its heads and
+    its rotary table in the global layers' fields, everything else alike."""
+    return dataclasses.replace(
+        cfg, n_heads=cfg.n_heads_window, n_heads_window=0, dense_layers=0,
+        rotary_dim=cfg.window_rotary_dim or cfg.rotary_dim,
+        rope_theta=cfg.window_rope_theta or cfg.rope_theta,
+        rope_scaling=None if cfg.window_rope_theta else cfg.rope_scaling,
+        window_rotary_dim=0, window_rope_theta=0.0)
 
 
 def _lead_stack(params):
@@ -834,11 +1012,11 @@ def init_params(rng, cfg: GPTConfig) -> Dict[str, jnp.ndarray]:
     if cfg.init != "gpt2":
         raise ValueError(f"init {cfg.init!r}: gpt2 | unit_stream")
     if cfg.kv_lora_rank or cfg.dense_layers or cfg.moe_shared or cfg.moe_held \
-            or cfg.ssm_layout:
+            or cfg.ssm_layout or cfg.n_heads_window or cfg.attn_gate:
         raise NotImplementedError(
             'init="gpt2" makes no latent attention, leading dense layers, shared '
-            'expert, held range of experts or state-space layers: such a preset '
-            'states init="unit_stream"')
+            'expert, held range of experts, state-space layers, attention stacks '
+            'by kind or gate a head: such a preset states init="unit_stream"')
     E, L, F, V = cfg.d_model, cfg.n_layers, cfg.d_mlp, cfg.vocab_size
     H, Dh = cfg.n_heads, cfg.d_head
     k = jax.random.split(rng, 16)
@@ -1117,6 +1295,11 @@ def _refuse_new_fields(cfg: GPTConfig, what: str):
     if cfg.ssm_layout:
         bad.append("state-space layers (ssm_layout): two stacks of mixers, and "
                    "a state a sequence that no cache here keeps")
+    if cfg.n_heads_window:
+        bad.append("attention stacks by kind (n_heads_window): one head count "
+                   "and one rotary table")
+    if cfg.attn_gate:
+        bad.append("a gate a head (attn_gate)")
     if bad:
         raise NotImplementedError(f"{what} does not support " + ", ".join(bad))
 
@@ -1218,7 +1401,8 @@ def _block(cfg: GPTConfig, rope_tables, attend, x, layer_params, positions,
     `valid`: see `_mlp`. `mixer(p, h)`, where the layer's mixer is not
     attention (a state-space layer): the normed input [B, S, E] -> (what the
     mixer adds to the stream [B, S, E], the state it hands back), in
-    `attend`'s place. A model without biases lacks their keys."""
+    `attend`'s place. A model without biases lacks their keys. The query
+    heads are the weights' own count (`cfg` may be `_window_cfg`'s)."""
     # Cast this layer's master weights to compute dtype (bf16 → MXU).
     p = jax.tree_util.tree_map(lambda a: a.astype(cfg.dtype), layer_params)
     block_in = x
@@ -1236,9 +1420,13 @@ def _block(cfg: GPTConfig, rope_tables, attend, x, layer_params, positions,
         if cfg.pos == "rotary":
             qr, kr = _rotary(cfg, rope_tables, positions, q, k)
             # A layer without positional encoding keeps q and k as projected.
-            q, k = (qr, kr) if kind is None else (
+            q, k = (qr, kr) if kind is None or "rope" not in kind else (
                 jnp.where(kind["rope"], qr, q), jnp.where(kind["rope"], kr, k))
         attn, state = attend(q, k, v, kind)
+        if "w_head_gate" in p:      # one gate a head a token, from the normed input
+            gate = jax.nn.sigmoid(jnp.einsum(      # float32, as the router's logits
+                "bse,eh->bhs", h.astype(jnp.float32), p["w_head_gate"].astype(jnp.float32)))
+            attn = (attn * gate[..., None]).astype(attn.dtype)
     if mixer is None:
         attn_out = jnp.einsum("bhsd,hde->bse", attn, p["w_o"])
         if "b_o" in p:
@@ -1260,7 +1448,8 @@ def _block(cfg: GPTConfig, rope_tables, attend, x, layer_params, positions,
 
 
 _LAYER_KEYS = (
-    "w_qkv", "b_qkv", "w_q", "w_kv", "w_o", "b_o", "w_in", "b_in", "w_out", "b_out",
+    "w_qkv", "b_qkv", "w_q", "w_kv", "w_o", "b_o", "w_head_gate",
+    "w_in", "b_in", "w_out", "b_out",
     "ln1_w", "ln1_b", "ln2_w", "ln2_b", "w_gate",
     "moe_router", "moe_w_in", "moe_w_out", "moe_w_gate", *_POST_NORM_KEYS,
     "w_dq", "q_norm_w", "w_uq", "w_dkv", "kv_norm_w", "w_ukv",
@@ -1271,7 +1460,10 @@ _LAYER_KEYS = (
 _SSM_KEYS = tuple("ssm_" + name for name in (
     "w_in", "conv_w", "conv_b", "w_x", "dt_norm_w", "b_norm_w", "c_norm_w",
     "w_dt", "b_dt", "A_log", "D", "w_out"))
-_ATTN_KEYS = ("w_qkv", "b_qkv", "w_q", "w_kv", "w_o", "b_o")
+_ATTN_KEYS = ("w_qkv", "b_qkv", "w_q", "w_kv", "w_o", "b_o", "w_head_gate")
+# The window layers' attention where it is a stack of its own (`n_heads_window`).
+# {its name in the tree: the name `_block` reads}.
+_WINDOW_KEYS = {"win_" + name: name for name in ("w_q", "w_kv", "w_o", "w_head_gate")}
 
 
 def _embed(params, tokens, positions, cfg: GPTConfig):
@@ -1288,7 +1480,10 @@ def _rope_tables(cfg: GPTConfig):
     Under `rope_scaling` (YaRN) a dimension that turns fewer than
     `beta_slow` times over the original positions is interpolated by
     `factor`, one that turns more than `beta_fast` times is left, a linear
-    ramp between; cos and sin carry mscale(`mscale`) / mscale(`mscale_all_dim`)."""
+    ramp between; cos and sin carry the group's `attention_factor` where it
+    states one, else mscale(`mscale`) / mscale(`mscale_all_dim`). The tables
+    of the GLOBAL layers where the window layers have their own (those are
+    `_rope_tables(_window_cfg(cfg))`)."""
     if cfg.pos != "rotary":
         return None
     if not cfg.rope_scaling:
@@ -1305,8 +1500,8 @@ def _rope_tables(cfg: GPTConfig):
     ramp = np.clip((np.arange(d // 2) - low) / max(high - low, 0.001), 0.0, 1.0)
     inv = cfg.rope_theta ** (-np.arange(0, d, 2) / d)
     inv = inv * (1.0 - ramp) + inv / factor * ramp
-    amp = _yarn_mscale(factor, _yarn(cfg, "mscale")) / _yarn_mscale(
-        factor, _yarn(cfg, "mscale_all_dim"))
+    amp = dict(cfg.rope_scaling).get("attention_factor") or _yarn_mscale(
+        factor, _yarn(cfg, "mscale")) / _yarn_mscale(factor, _yarn(cfg, "mscale_all_dim"))
     ang = jnp.outer(jnp.arange(cfg.max_seq, dtype=jnp.float32),
                     jnp.asarray(inv, jnp.float32))
     return jnp.cos(ang) * amp, jnp.sin(ang) * amp
@@ -1314,7 +1509,7 @@ def _rope_tables(cfg: GPTConfig):
 
 def _layer_stack(params):
     """The stacked per-layer weights [L, ...]: what the layer scan cuts."""
-    return {k: params[k] for k in _LAYER_KEYS + _SSM_KEYS if k in params}
+    return {k: params[k] for k in (*_LAYER_KEYS, *_SSM_KEYS, *_WINDOW_KEYS) if k in params}
 
 
 def _ssm_weights(p):
@@ -1322,38 +1517,52 @@ def _ssm_weights(p):
     return {k[4:]: p[k] for k in _SSM_KEYS}
 
 
-def _mixed_layers(cfg: GPTConfig, layer_stack, carry, attn_layer, ssm_layer):
-    """The layer loop of a model whose `ssm_layout` deals its layers between
-    two mixers: cut, from the static layout, into runs of one kind. A run of
-    state-space layers is one `lax.scan` over (layer, its index in the
-    `ssm_*` stack); an attention layer between two runs is applied as it
-    stands. The stacks stay whole and a layer's weights are read where they
-    lie, by index (a slice the scan cuts out of a stack would be a copy of
-    it). `attn_layer(carry, a, p)` / `ssm_layer(carry, m, p)`: carry -> carry,
-    `a` / `m` the layer's index among its kind (traced in a scan), `p` its
-    weights: the norms and the MLP of layer l beside the mixer's own."""
-    mixers = _ATTN_KEYS + _SSM_KEYS
-    shared = {k: v for k, v in layer_stack.items() if k not in mixers}
+def _pop_expert_stacks(cfg: GPTConfig, layer_stack):
+    """None, or an expert model's three stacks (gate, in, out) taken OUT of
+    `layer_stack`: a step reads only the experts its tokens chose, so they
+    stay whole (a slice a scan cuts would be a copy of every expert of the
+    layer) and the layer number finds the expert where it lies."""
+    if cfg.mlp_type != "moe":
+        return None
+    return tuple(layer_stack.pop(k) for k in ("moe_w_gate", "moe_w_in", "moe_w_out"))
 
-    def weights(l, i, keys):
+
+def _mixed_layers(layout, layer_stack, carry, lone, run):
+    """The layer loop of a model whose layers are of two kinds with weights of
+    their own (`ssm_layout`: attention and state-space mixers; `n_heads_window`:
+    global and window attention): cut, from the static `layout` (one entry a
+    layer of the stack, 0 or 1), into runs of one kind. A run of layers of
+    kind 1 is one `lax.scan` over (layer, its index among its kind); a layer
+    of kind 0 between two runs is applied as it stands. The stacks stay whole
+    and a layer's weights are read where they lie, by index (a slice the scan
+    cuts out of a stack would be a copy of it). `lone` and `run` = (the
+    kind's own weights as names in the stack, or {name in the stack: name the
+    layer reads}, layer): `layer(carry, l, i, p)`
+    -> carry, `l` the layer's index in the stack and `i` its index among its
+    kind (traced in a scan), `p` its weights under the names `_block` reads:
+    the norms and the MLP of layer l beside the kind's own."""
+    own = (*lone[0], *run[0])
+    shared = {k: v for k, v in layer_stack.items() if k not in own}
+
+    def weights(l, i, names):
+        read = names.items() if isinstance(names, dict) else zip(names, names)
         return {**{k: v[l] for k, v in shared.items()},
-                **{k: layer_stack[k][i] for k in keys if k in layer_stack}}
+                **{as_read: layer_stack[k][i] for k, as_read in read if k in layer_stack}}
 
     l = a = m = 0
-    layout = cfg.ssm_layout
     while l < len(layout):
         if not layout[l]:
-            carry = attn_layer(carry, a, weights(l, a, _ATTN_KEYS))
+            carry = lone[1](carry, l, a, weights(l, a, lone[0]))
             l, a = l + 1, a + 1
             continue
-        run = next((j for j in range(l, len(layout)) if not layout[j]), len(layout)) - l
+        n = next((j for j in range(l, len(layout)) if not layout[j]), len(layout)) - l
 
         def body(carry, idx):
-            return ssm_layer(carry, idx[1], weights(idx[0], idx[1], _SSM_KEYS)), None
+            return run[1](carry, idx[0], idx[1], weights(idx[0], idx[1], run[0])), None
 
         carry, _ = jax.lax.scan(
-            body, carry, (jnp.arange(l, l + run), jnp.arange(m, m + run)))
-        l, m = l + run, m + run
+            body, carry, (jnp.arange(l, l + n), jnp.arange(m, m + n)))
+        l, m = l + n, m + n
     return carry
 
 
@@ -1399,7 +1608,8 @@ def _layer_loop(cfg: GPTConfig, mesh, positions):
     a pass, each pass ended by `close_pass`; aux is [passes x L]. A model
     with leading dense layers hands their stack as `lead` (`_lead_stack`). A
     model with state-space layers runs `_mixed_layers`, every sequence's
-    state starting from zero and dropped at the end."""
+    state starting from zero and dropped at the end; so does a model whose
+    window layers have shapes of their own (`by_kind`)."""
 
     def attend(q, k, v, kind):
         if kind is None and cfg.kv_heads == cfg.n_heads and not cfg.kv_lora_rank:
@@ -1434,9 +1644,26 @@ def _layer_loop(cfg: GPTConfig, mesh, positions):
 
         plain = functools.partial(_block, cfg, None, attend)    # no remat: served
         x = _mixed_layers(
-            cfg, layer_stack, x,
-            lambda x, a, p: plain(x, p, positions)[0],
-            lambda x, m, p: plain(x, p, positions, mixer=mixer)[0])
+            cfg.ssm_layout, layer_stack, x,
+            (_ATTN_KEYS, lambda x, l, a, p: plain(x, p, positions)[0]),
+            (_SSM_KEYS, lambda x, l, m, p: plain(x, p, positions, mixer=mixer)[0]))
+        return x, jnp.zeros((cfg.n_layers,), jnp.float32)
+
+    def by_kind(x, layer_stack):
+        """The stack behind the leading layers of a model whose window layers
+        have shapes of their own: each kind through `_block` under its own
+        config and rotary table, the experts read where they lie."""
+        wcfg = _window_cfg(cfg)
+        stacks = _pop_expert_stacks(cfg, layer_stack)
+        glob = functools.partial(_block, cfg, _rope_tables(cfg), attend)
+        wind = functools.partial(_block, wcfg, _rope_tables(wcfg), attend)
+        window = {"window": cfg.sliding_window}
+        x = _mixed_layers(
+            cfg.sliding_window_layout[cfg.dense_layers:], layer_stack, x,
+            (_ATTN_KEYS, lambda x, l, i, p: glob(
+                x, p, positions, stacks=stacks, layer=l)[0]),
+            (_WINDOW_KEYS, lambda x, l, i, p: wind(
+                x, p, positions, kind=window, stacks=stacks, layer=l)[0]))
         return x, jnp.zeros((cfg.n_layers,), jnp.float32)
 
     def run(x, layer_stack, kinds=None, close_pass=None, lead=None):
@@ -1450,6 +1677,8 @@ def _layer_loop(cfg: GPTConfig, mesh, positions):
                 return lead_block(x, layer_params, positions)[0], None
 
             x, _ = jax.lax.scan(lead_body, x, lead)
+        if cfg.n_heads_window:
+            return by_kind(x, dict(layer_stack))
         if cfg.ut_steps == 1:
             return jax.lax.scan(scan_body, x, (layer_stack, kinds))
 
@@ -1545,7 +1774,8 @@ def _refuse_looped_training(cfg: GPTConfig, what: str):
     attention, leading dense layers, a shared expert, a held range of
     experts (no gradient is exchanged for the absent ones), sigmoid scoring
     (its balance term is not written), state-space layers (the scan's
-    backward pass is not written)."""
+    backward pass is not written), attention stacks by kind and a gate a head
+    (no sharding is written for them)."""
     if cfg.ut_steps > 1:
         raise NotImplementedError(
             f"{what} does not train a model whose layers run several times "
@@ -1555,7 +1785,8 @@ def _refuse_looped_training(cfg: GPTConfig, what: str):
         ("kv_lora_rank", cfg.kv_lora_rank), ("dense_layers", cfg.dense_layers),
         ("moe_shared", cfg.moe_shared), ("moe_held", cfg.moe_held),
         ("moe_scoring", cfg.moe_scoring != "softmax"),
-        ("ssm_layout", cfg.ssm_layout)) if on]
+        ("ssm_layout", cfg.ssm_layout), ("n_heads_window", cfg.n_heads_window),
+        ("attn_gate", cfg.attn_gate)) if on]
     if served:
         raise NotImplementedError(
             f"{what} does not train a model with {', '.join(served)}: "
@@ -2050,13 +2281,16 @@ class KVLayout:
     layer l keeps its rows at pool[l], one block table a sequence. With
     global and window layers the layers are dealt into groups of equal
     size, each of one kind (12 layers = 3 global + 9 window: four groups
-    of 3; 52 = 13 + 39: four of 13), so that a block -- `per_group` layers
+    of 3; 52 = 13 + 39: four of 13; 5 = 2 + 3, a leading dense layer among
+    the global ones: FIVE groups of one layer, a pool one layer deep and five
+    block tables a sequence), so that a block -- `per_group` layers
     x block_size tokens -- has the same bytes whichever group holds it and
     every group draws from the same `num_blocks`. A sequence has one block
     table a group; a window group gives back the blocks that fell behind
     its window while the sequence lives (serve/engine/kv_manager.py). A
-    model with leading dense layers keeps their rows first: depth =
-    n_layers, the scanned stack's layer i at pool[dense_layers + i].
+    model of one kind with leading dense layers keeps their rows first:
+    depth = n_layers, the scanned stack's layer i at pool[dense_layers + i];
+    where the layers form groups a leading layer is dealt like any other.
 
     PASSES: a looped model (`ut_steps` passes over the same layers) keeps
     keys and values a (pass, layer) pair: the pool's leading dimension is
@@ -2222,6 +2456,41 @@ def paged_attn_keys(lanes: int, width: int, block_size: int, last_pos, real):
     return lanes * int(trips) * tile * block_size, lanes * width * block_size
 
 
+def attn_heads_by_window(cfg: GPTConfig) -> Tuple[Tuple[int, int], ...]:
+    """((window or 0, query heads x passes summed over the attention layers
+    of that window), ...): what `paged_attn_head_keys` weighs keys by (the
+    engine works it out once)."""
+    win = (cfg.layer_kinds or (None, (0,) * cfg.n_layers))[1]
+    heads: Dict[int, int] = {}
+    for h, w, ssm in zip(cfg.layer_heads, win, cfg.ssm_layout or (0,) * cfg.n_layers):
+        if not ssm:
+            heads[w] = heads.get(w, 0) + h * cfg.ut_steps
+    return tuple(heads.items())
+
+
+def paged_attn_head_keys(heads_by_window, run: int, width: int, block_size: int,
+                         first_pos, last_pos, real):
+    """Query heads x keys the attention of one dispatched paged program
+    covers, summed over the layers, counted on the host: (in the window
+    layers, in all layers). `run`: the keys a global layer covers, as
+    `paged_attn_keys` just counted them for the same program; a window layer
+    covers lanes x trips x tile keys under ITS window (`paged_attn_trips`; a
+    table of one tile is covered whole), so only a model with window layers
+    counts trips again. Each kind weighs by its own count of query heads
+    (`GPTConfig.layer_heads`); a looped model's layers once a pass."""
+    window = every = 0
+    for w, heads in heads_by_window:
+        keys = run
+        if w:
+            tile, tiles = paged_attn_tiling(width, block_size)
+            _, trips = paged_attn_trips(
+                np, first_pos, last_pos, real, w, tile * block_size, tiles)
+            keys = np.size(last_pos) * int(trips) * tile * block_size
+            window += heads * keys
+        every += heads * keys
+    return window, every
+
+
 def _paged_layers(params, tokens, pos, valid, block_tables, kv, cfg: GPTConfig,
                   state_slots=None):
     """Embedding and the layer loop of the three paged programs: lane b
@@ -2310,7 +2579,6 @@ def _paged_layers(params, tokens, pos, valid, block_tables, kv, cfg: GPTConfig,
     scale = 1.0 / math.sqrt(Dh)
     if cfg.kv_lora_rank:    # ONE row a token, every head's key, its values inside
         Hkv, Dh, Dv, scale = 1, lay.key_row, cfg.kv_lora_rank, _latent_scale(cfg)
-    R = H // Hkv
     x = _embed(params, tokens, pos, cfg)               # [B, S, E]
     rope_tables = _rope_tables(cfg)
     blk = jnp.minimum(pos // BS, W - 1)
@@ -2326,6 +2594,8 @@ def _paged_layers(params, tokens, pos, valid, block_tables, kv, cfg: GPTConfig,
     if kinds is not None:
         kinds["group"] = jnp.asarray(lay.group_of, jnp.int32)
         kinds["slot"] = jnp.asarray(lay.slot_of, jnp.int32)
+        if cfg.n_heads_window:      # every layer rotary, each kind by its own table
+            del kinds["rope"]
     TB, NT = paged_attn_tiling(W, BS)
     T = TB * BS                                        # keys a tile
     # [B, S]: a real lane's valid slots (a padding lane's table is all null)
@@ -2339,127 +2609,130 @@ def _paged_layers(params, tokens, pos, valid, block_tables, kv, cfg: GPTConfig,
         last_pos = jnp.where(real, pos, 0).max(axis=1)
         real_lane = real.any(axis=1)
     by_kernel = paged_attn_kernel(cfg, S, W, BS)
-    if by_kernel:   # [B, R*S]: the position of each folded query row
-        row_pos = jnp.tile(pos, (1, R))
 
-    # One query a K/V head (a decode step of a multi-head model) whose
-    # features fill whole lane tiles: attention as two matrix products over
-    # the gathered rows AS THE POOL LAYS THEM, [tokens, Hkv*Dh]. The scores
-    # are rows x the queries set block-diagonally ([Hkv*Dh, Hkv], head h's
-    # query in column h), the result is weights x rows ([Hkv, Hkv*Dh]) of
-    # which head h keeps its own Dh columns: the same bf16 products summed in
-    # float32 as the einsums below, the zeros adding nothing. One query row
-    # a head is no matrix product for the MXU, and what the compiler makes
-    # of that dot_general first copies the gathered rows into float32 and
-    # head-major, two more round trips of every row in every layer.
-    lone = R * S == 1 and Dh % 128 == 0
-    own_head = jnp.eye(Hkv, dtype=jnp.float32) if lone else None
+    def attention_of(R):
+        """`attend` for layers of R query heads a K/V head: everything below
+        that the head count shapes. Called once, here, for a model of one head
+        count; once a kind where the window layers have their own."""
+        # [B, R*S]: the position of each folded query row
+        row_pos = jnp.tile(pos, (1, R)) if by_kernel else None
 
-    def scores_of(q, kk, vv, slot, blocks, kp, seen, window):
-        """Masked float32 scores [B, Hkv, R*S, n*BS] of q [B, Hkv, R*S, Dh]
-        against the rows of `blocks` [B, n] (key positions `kp`), and those
-        blocks' V rows [B, n*BS, Hkv, Dv] ([B, n*BS, Hkv*Dh] for `lone`): of
-        a latent pool (`vv` None) the gathered key rows' first Dv columns."""
-        rows = (B, -1, Hkv * Dh) if lone else (B, -1, Hkv, Dh)
-        gk = kk[slot, blocks].reshape(rows)
-        gv = gk[..., :Dv] if vv is None else vv[slot, blocks].reshape(rows)
-        mask = seen if window is None else seen & (kp > qpos - window)
-        if R > 1:   # the R query heads of a K/V head ride its query axis
-            mask = jnp.tile(mask, (1, 1, R, 1))
-        if lone:
-            qd = q[:, :, 0, :, None] * own_head.astype(q.dtype)[None, :, None, :]
-            scores = jnp.einsum(
-                "bte,beh->bht", gk, qd.reshape(B, Hkv * Dh, Hkv),
-                preferred_element_type=jnp.float32)[:, :, None] * scale
-        else:
-            scores = jnp.einsum(
-                "bhsd,bthd->bhst", q, gk, preferred_element_type=jnp.float32
-            ) * scale
-        return jnp.where(mask, scores, -1e30), gv
+        # One query a K/V head (a decode step of a multi-head model) whose
+        # features fill whole lane tiles: attention as two matrix products over
+        # the gathered rows AS THE POOL LAYS THEM, [tokens, Hkv*Dh]. The scores
+        # are rows x the queries set block-diagonally ([Hkv*Dh, Hkv], head h's
+        # query in column h), the result is weights x rows ([Hkv, Hkv*Dh]) of
+        # which head h keeps its own Dh columns: the same bf16 products summed in
+        # float32 as the einsums below, the zeros adding nothing. One query row
+        # a head is no matrix product for the MXU, and what the compiler makes
+        # of that dot_general first copies the gathered rows into float32 and
+        # head-major, two more round trips of every row in every layer.
+        lone = R * S == 1 and Dh % 128 == 0
+        own_head = jnp.eye(Hkv, dtype=jnp.float32) if lone else None
 
-    def mixed(p, gv, out_dtype=None):
-        """Weights p [B, Hkv, R*S, T] f32 over the V rows `scores_of` gave
-        -> [B, Hkv, R*S, Dh] in `out_dtype` (the rows' own if None)."""
-        if lone:
-            every = jnp.einsum("bht,bte->bhe", p[:, :, 0].astype(gv.dtype), gv,
-                               preferred_element_type=jnp.float32)
-            out = (every.reshape(B, Hkv, Hkv, Dh)
-                   * own_head[None, :, :, None]).sum(axis=2)
-            return out[:, :, None].astype(out_dtype or gv.dtype)
-        return jnp.einsum("bhst,bthd->bhsd", p.astype(gv.dtype), gv,
-                          preferred_element_type=out_dtype)
+        def scores_of(q, kk, vv, slot, blocks, kp, seen, window):
+            """Masked float32 scores [B, Hkv, R*S, n*BS] of q [B, Hkv, R*S, Dh]
+            against the rows of `blocks` [B, n] (key positions `kp`), and those
+            blocks' V rows [B, n*BS, Hkv, Dv] ([B, n*BS, Hkv*Dh] for `lone`): of
+            a latent pool (`vv` None) the gathered key rows' first Dv columns."""
+            rows = (B, -1, Hkv * Dh) if lone else (B, -1, Hkv, Dh)
+            gk = kk[slot, blocks].reshape(rows)
+            gv = gk[..., :Dv] if vv is None else vv[slot, blocks].reshape(rows)
+            mask = seen if window is None else seen & (kp > qpos - window)
+            if R > 1:   # the R query heads of a K/V head ride its query axis
+                mask = jnp.tile(mask, (1, 1, R, 1))
+            if lone:
+                qd = q[:, :, 0, :, None] * own_head.astype(q.dtype)[None, :, None, :]
+                scores = jnp.einsum(
+                    "bte,beh->bht", gk, qd.reshape(B, Hkv * Dh, Hkv),
+                    preferred_element_type=jnp.float32)[:, :, None] * scale
+            else:
+                scores = jnp.einsum(
+                    "bhsd,bthd->bhst", q, gk, preferred_element_type=jnp.float32
+                ) * scale
+            return jnp.where(mask, scores, -1e30), gv
 
-    def gathered(q, kk, vv, slot, table, window):
-        """Attention of q [B, Hkv, R*S, Dh] over the rows `table` names."""
-        if NT == 1:
-            scores, gv = scores_of(q, kk, vv, slot, table, kpos, seen, window)
-            probs = jax.nn.softmax(scores, axis=-1)
-            return mixed(probs, gv)
-        reach = _NO_WINDOW if window is None else window
-        first, trips = paged_attn_trips(
-            jnp, first_pos, last_pos, real_lane, reach, T, NT)
-        table = jnp.pad(table, ((0, 0), (0, NT * TB - W)))
-        if by_kernel:   # the table's rows gathered once, densely: 0.05 ms a layer
-            return attention.paged_chunk_attention(
-                q, kk[slot, table].reshape(B, NT * T, Hkv * Dh),
-                None if vv is None else vv[slot, table].reshape(B, NT * T, Hkv * Dv),
-                row_pos, first, trips, reach, tile_keys=T, dv=Dv, sm_scale=scale)
+        def mixed(p, gv, out_dtype=None):
+            """Weights p [B, Hkv, R*S, T] f32 over the V rows `scores_of` gave
+            -> [B, Hkv, R*S, Dh] in `out_dtype` (the rows' own if None)."""
+            if lone:
+                every = jnp.einsum("bht,bte->bhe", p[:, :, 0].astype(gv.dtype), gv,
+                                   preferred_element_type=jnp.float32)
+                out = (every.reshape(B, Hkv, Hkv, Dh)
+                       * own_head[None, :, :, None]).sum(axis=2)
+                return out[:, :, None].astype(out_dtype or gv.dtype)
+            return jnp.einsum("bhst,bthd->bhsd", p.astype(gv.dtype), gv,
+                              preferred_element_type=out_dtype)
 
-        def trip(j, carry):
-            m, l, acc = carry
-            tile = first + j                           # [B]; past the table: masked
-            cols = jnp.minimum(tile, NT - 1)[:, None] * TB + jnp.arange(TB)
-            kp = (tile[:, None] * T + jnp.arange(T))[:, None, None, :]
-            scores, gv = scores_of(
-                q, kk, vv, slot, jnp.take_along_axis(table, cols, axis=1),
-                kp, kp <= qpos, window)
-            m_new = jnp.maximum(m, scores.max(axis=-1))
-            p = jnp.exp(scores - m_new[..., None])
-            fade = jnp.exp(m - m_new)
-            acc = acc * fade[..., None] + mixed(p, gv, jnp.float32)
-            return m_new, l * fade + p.sum(axis=-1), acc
+        def gathered(q, kk, vv, slot, table, window):
+            """Attention of q [B, Hkv, R*S, Dh] over the rows `table` names."""
+            if NT == 1:
+                scores, gv = scores_of(q, kk, vv, slot, table, kpos, seen, window)
+                probs = jax.nn.softmax(scores, axis=-1)
+                return mixed(probs, gv)
+            reach = _NO_WINDOW if window is None else window
+            first, trips = paged_attn_trips(
+                jnp, first_pos, last_pos, real_lane, reach, T, NT)
+            table = jnp.pad(table, ((0, 0), (0, NT * TB - W)))
+            if by_kernel:   # the table's rows gathered once, densely: 0.05 ms a layer
+                return attention.paged_chunk_attention(
+                    q, kk[slot, table].reshape(B, NT * T, Hkv * Dh),
+                    None if vv is None else vv[slot, table].reshape(B, NT * T, Hkv * Dv),
+                    row_pos, first, trips, reach, tile_keys=T, dv=Dv, sm_scale=scale)
 
-        rows = q.shape[:3]
-        _, l, acc = jax.lax.fori_loop(0, trips, trip, (
-            jnp.full(rows, -1e30, jnp.float32), jnp.zeros(rows, jnp.float32),
-            jnp.zeros(rows + (Dv,), jnp.float32)))
-        return (acc / l[..., None]).astype(kk.dtype)
+            def trip(j, carry):
+                m, l, acc = carry
+                tile = first + j                           # [B]; past the table: masked
+                cols = jnp.minimum(tile, NT - 1)[:, None] * TB + jnp.arange(TB)
+                kp = (tile[:, None] * T + jnp.arange(T))[:, None, None, :]
+                scores, gv = scores_of(
+                    q, kk, vv, slot, jnp.take_along_axis(table, cols, axis=1),
+                    kp, kp <= qpos, window)
+                m_new = jnp.maximum(m, scores.max(axis=-1))
+                p = jnp.exp(scores - m_new[..., None])
+                fade = jnp.exp(m - m_new)
+                acc = acc * fade[..., None] + mixed(p, gv, jnp.float32)
+                return m_new, l * fade + p.sum(axis=-1), acc
 
-    def attend(kk, vv, l, base, q, k, v, kind):
-        """The new rows into the pool (kk, vv) at the layer's slot (past
-        `base`: the first row of a looped model's pass, or of the scanned
-        stack behind leading dense layers), then attention over the layer's
-        table; the pool is the state. A latent pool is `kk` alone, its row
-        and the queries padded with zeros to the declared width."""
-        if q.shape[-1] < Dh:
-            q, k = (jnp.pad(a, ((0, 0),) * 3 + ((0, Dh - a.shape[-1]),)) for a in (q, k))
-        k = k.transpose(0, 2, 1, 3).reshape(B, S, Hkv * Dh)
-        if vv is not None:
-            v = v.transpose(0, 2, 1, 3).reshape(B, S, Hkv * Dh)
-        if G == 1:
-            slot, table, ph = l, block_tables, phys
-        else:
-            slot = kind["slot"]
-            table = jnp.take(block_tables, kind["group"], axis=1)
-            ph = physical(table)
-        if base is not None:
-            slot = base + slot
-        kk = kk.at[slot, ph, off].set(k.astype(kk.dtype))
-        if vv is not None:
-            vv = vv.at[slot, ph, off].set(v.astype(vv.dtype))
-        if R > 1:   # the R query heads of a K/V head ride its query axis
-            q = q.reshape(B, Hkv, R * S, Dh)
-        attn = gathered(q, kk, vv, slot, table,
-                        None if kind is None else kind["window"])
-        return attn.reshape(B, H, S, Dv) if R > 1 else attn, (kk, vv)
+            rows = q.shape[:3]
+            _, l, acc = jax.lax.fori_loop(0, trips, trip, (
+                jnp.full(rows, -1e30, jnp.float32), jnp.zeros(rows, jnp.float32),
+                jnp.zeros(rows + (Dv,), jnp.float32)))
+            return (acc / l[..., None]).astype(kk.dtype)
 
-    # A step reads only the experts its tokens chose (the grouped tiles): the
-    # expert stacks stay whole (a slice the scan cuts would be a copy of every
-    # expert of the layer) and the layer number finds the expert where it lies.
-    stacks = None
-    if moe:
-        stacks = tuple(layer_stack.pop(k) for k in
-                       ("moe_w_gate", "moe_w_in", "moe_w_out"))
+        def attend(kk, vv, l, base, q, k, v, kind):
+            """The new rows into the pool (kk, vv) at the layer's slot (past
+            `base`: the first row of a looped model's pass, or of the scanned
+            stack behind leading dense layers), then attention over the layer's
+            table; the pool is the state. A latent pool is `kk` alone, its row
+            and the queries padded with zeros to the declared width."""
+            if q.shape[-1] < Dh:
+                q, k = (jnp.pad(a, ((0, 0),) * 3 + ((0, Dh - a.shape[-1]),)) for a in (q, k))
+            k = k.transpose(0, 2, 1, 3).reshape(B, S, Hkv * Dh)
+            if vv is not None:
+                v = v.transpose(0, 2, 1, 3).reshape(B, S, Hkv * Dh)
+            if G == 1:
+                slot, table, ph = l, block_tables, phys
+            else:
+                slot = kind["slot"]
+                table = jnp.take(block_tables, kind["group"], axis=1)
+                ph = physical(table)
+            if base is not None:
+                slot = base + slot
+            kk = kk.at[slot, ph, off].set(k.astype(kk.dtype))
+            if vv is not None:
+                vv = vv.at[slot, ph, off].set(v.astype(vv.dtype))
+            if R > 1:   # the R query heads of a K/V head ride its query axis
+                q = q.reshape(B, Hkv, R * S, Dh)
+            attn = gathered(q, kk, vv, slot, table,
+                            None if kind is None else kind["window"])
+            return attn.reshape(B, R * Hkv, S, Dv) if R > 1 else attn, (kk, vv)
+
+        return attend
+
+    attend = attention_of(H // Hkv)
+
+    stacks = _pop_expert_stacks(cfg, layer_stack)
 
     def layers(carry, base=None):
         """The layer scan, once: (x, pool k, pool v) -> the same, [L] loads."""
@@ -2490,14 +2763,14 @@ def _paged_layers(params, tokens, pos, valid, block_tables, kv, cfg: GPTConfig,
         fresh = (pos[:, 0] == 0)[:, None]
         tail_shape = (B, cfg.ssm_conv - 1, cfg.ssm_inner)
 
-        def attn_layer(carry, a, p):
+        def attn_layer(carry, l, a, p):
             x, kk, vv, st = carry
             x, (kk, vv), _, _ = _block(
                 cfg, rope_tables, functools.partial(attend, kk, vv, a, None), x, p,
                 pos, valid=real)
             return x, kk, vv, st
 
-        def ssm_layer(carry, m, p):
+        def ssm_layer(carry, l, m, p):
             x, kk, vv, st = carry
 
             def mixer(p, h):
@@ -2513,8 +2786,13 @@ def _paged_layers(params, tokens, pos, valid, block_tables, kv, cfg: GPTConfig,
                                "ssm": st["ssm"].at[m, state_slots].set(s)}
 
         x, kk, vv, st = _mixed_layers(
-            cfg, layer_stack, (x, kv["k"], kv["v"], kv["state"]), attn_layer, ssm_layer)
+            cfg.ssm_layout, layer_stack, (x, kv["k"], kv["v"], kv["state"]),
+            (_ATTN_KEYS, attn_layer), (_SSM_KEYS, ssm_layer))
         return x, {"k": kk, "v": vv, "state": st}, None, None
+
+    def kind_of(l):
+        """Layer l's kind (its window, group and slot) out of `kinds`."""
+        return None if kinds is None else {k: v[l] for k, v in kinds.items()}
 
     carry = (x, kv["k"], kv.get("v"))
     if cfg.dense_layers:    # the leading dense layers: pool rows 0 .. their count
@@ -2523,11 +2801,34 @@ def _paged_layers(params, tokens, pos, valid, block_tables, kv, cfg: GPTConfig,
             l, layer_params = inp
             x, (kk, vv), _, _ = _block(
                 _lead_cfg(cfg), rope_tables, functools.partial(attend, kk, vv, l, None),
-                x, layer_params, pos, valid=real)
+                x, layer_params, pos, kind_of(l), valid=real)
             return (x, kk, vv), None
 
         carry, _ = jax.lax.scan(lead_body, carry, (
             jnp.arange(cfg.dense_layers), _lead_stack(params)))
+    if cfg.n_heads_window:
+        # Window layers with shapes of their own: each kind through `_block`
+        # under its own config, rotary table and `attend`, a layer's table
+        # and pool row by its group and slot, the routing's load summed in
+        # the carry (`_mixed_layers` hands nothing else on).
+        wcfg, D = _window_cfg(cfg), cfg.dense_layers
+        by_kind = ((cfg, rope_tables, attend),
+                   (wcfg, _rope_tables(wcfg), attention_of(cfg.n_heads_window // Hkv)))
+
+        def layer_of(kind_cfg, tables, attend):
+            def layer(carry, l, i, p):
+                x, kk, vv, loads = carry
+                x, (kk, vv), _, load = _block(
+                    kind_cfg, tables, functools.partial(attend, kk, vv, l, None), x, p,
+                    pos, kind_of(D + l), stacks, l, real)
+                return x, kk, vv, (loads + load if moe else loads)
+            return layer
+
+        loads = jnp.zeros((5 if cfg.moe_held else 2,), jnp.float32) if moe else None
+        x, kk, vv, loads = _mixed_layers(
+            cfg.sliding_window_layout[D:], layer_stack, (*carry, loads),
+            (_ATTN_KEYS, layer_of(*by_kind[0])), (_WINDOW_KEYS, layer_of(*by_kind[1])))
+        return x, pool(kk, vv), (loads / (cfg.n_layers - D) if moe else None), None
     if cfg.ut_steps == 1:
         (x, kk, vv), loads = layers(carry, cfg.dense_layers or None)
         return x, pool(kk, vv), (loads.mean(axis=0) if moe else None), None

@@ -87,6 +87,13 @@ _STEP_BOOKS = (
     # nanoseconds they could have been (`_tick_slots`).
     "ssm_tokens_scanned", "ssm_tokens_masked", "ssm_state_bytes",
     "state_slot_held_ns", "state_slot_cap_ns",
+    # The pool over TIME (`_tick_slots`): block-nanoseconds live sequences
+    # held, and of them those in window groups' tables.
+    "kv_block_held_ns", "kv_window_block_ns",
+    # Query heads x keys the dispatched programs' attention covered, summed
+    # over the layers, each under its own window and head count
+    # (`models.gpt.paged_attn_head_keys`), and of them the window layers'.
+    "attn_head_keys", "attn_head_keys_window",
 )
 
 
@@ -357,8 +364,8 @@ class InferenceEngine:
         import jax
 
         from ...models.gpt import (
-            init_paged_cache, init_params, kv_layout, paged_attn_kernel,
-            paged_attn_keys,
+            attn_heads_by_window, init_paged_cache, init_params, kv_layout,
+            paged_attn_head_keys, paged_attn_kernel, paged_attn_keys,
         )
 
         self.cfg = dataclasses.replace(cfg, remat=False, remat_policy=None)
@@ -500,6 +507,8 @@ class InferenceEngine:
         # Keys the dispatched programs' attention covered in a global layer
         # and keys of their padded tables (`models.gpt.paged_attn_keys`).
         self._attn_keys = paged_attn_keys
+        self._attn_head_keys = paged_attn_head_keys
+        self._attn_heads = attn_heads_by_window(self.cfg)
         self.total_attn_keys = [0, 0]
         # Prefill chunk programs dispatched and, of them, those whose shapes
         # send their attention to the chunk kernel: the program's own rule.
@@ -1113,14 +1122,21 @@ class InferenceEngine:
             except Exception as e:  # noqa: BLE001 — fail the waiter, not the loop
                 fut.set_exception(e)
 
-    def _count_attn(self, lanes: int, width: int, last_pos, real):
+    def _count_attn(self, lanes: int, width: int, last_pos, real, first_pos=None):
         """Add one program's (keys run, keys padded) to the step's and the
-        engine's counts, from the helper its own loop bounds come from."""
+        engine's counts, and its heads x keys by layer kind to the books, from
+        the helpers its own loop bounds come from. `first_pos`: each lane's
+        first query, where it is not its last (a chunk)."""
         run, padded = self._attn_keys(
             lanes, width, self.opts.block_size, last_pos, real)
         for count in (self._step_attn, self.total_attn_keys):
             count[0] += run
             count[1] += padded
+        window, every = self._attn_head_keys(
+            self._attn_heads, run, width, self.opts.block_size,
+            last_pos if first_pos is None else first_pos, last_pos, real)
+        self._books["attn_head_keys_window"] += window
+        self._books["attn_head_keys"] += every
 
     def _count_state(self, tokens: int, real: int, decode: bool):
         """Add one program of a model with state to its books: `tokens` the
@@ -1134,15 +1150,18 @@ class InferenceEngine:
                 b["ssm_state_bytes"] += 2 * real * self._layout.state_bytes
 
     def _tick_slots(self):
-        """Add the time since the last reading to the state slots' books, at
-        the number held NOW: called at a step's start (the count stood since
-        the last step's end) and at its end."""
+        """Add the time since the last reading to the books of what is held
+        over time (the pool's blocks, a model with state's slots), at the
+        number held NOW: called at a step's start (the count stood since the
+        last step's end) and at its end."""
+        now = time.monotonic_ns()
+        dt, self._slots_t_ns = now - self._slots_t_ns, now
+        kv, b = self.block_manager, self._books
+        b["kv_block_held_ns"] += dt * kv.blocks_held
+        b["kv_window_block_ns"] += dt * kv.window_blocks_held
         if self._stateful:
-            now = time.monotonic_ns()
-            dt, self._slots_t_ns = now - self._slots_t_ns, now
-            kv = self.block_manager
-            self._books["state_slot_held_ns"] += dt * kv.state_slots_held
-            self._books["state_slot_cap_ns"] += dt * kv.state_slots
+            b["state_slot_held_ns"] += dt * kv.state_slots_held
+            b["state_slot_cap_ns"] += dt * kv.state_slots
 
     def _state_slot(self, seq: Sequence) -> List[int]:
         """[] or [the sequence's state slot]: the extra entry a lane of a
@@ -1178,7 +1197,8 @@ class InferenceEngine:
             tokens[0, :L] = seq.prompt[chunk.start:chunk.start + L]
             bt = np.zeros(self._table_shape(W), np.int32)
             self._tables_into(bt, seq)
-            self._count_attn(1, W, np.asarray([chunk.start + L - 1]), True)
+            self._count_attn(1, W, np.asarray([chunk.start + L - 1]), True,
+                             np.asarray([chunk.start]))
             self.total_attn_chunks[0] += 1
             self.total_attn_chunks[1] += self._attn_kernel(
                 self.cfg, Sp, W, self.opts.block_size)
@@ -1319,7 +1339,7 @@ class InferenceEngine:
                 positions[i] = seq.num_tokens - 1
                 valid_len[i] = 1 + len(d)
                 self._tables_into(tables[i], seq)
-            self._count_attn(B, W, positions + valid_len - 1, valid_len > 0)
+            self._count_attn(B, W, positions + valid_len - 1, valid_len > 0, positions)
             self._count_moe(B * K1)
             args = (
                 jnp.asarray(tokens),

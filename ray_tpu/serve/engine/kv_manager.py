@@ -247,6 +247,9 @@ class KVBlockManager:
         self.cow_copies = 0
         self.host_hits = 0
         self.window_released = 0   # blocks window groups gave back while live
+        # Live blocks that stand in a window group's table (a block is in
+        # tables of one group at a time: an index entry names one a group).
+        self._window_live: set = set()
 
     # ------------------------------------------------------------- queries
     @property
@@ -297,6 +300,16 @@ class KVBlockManager:
     @property
     def state_slots_held(self) -> int:
         return len(self._state_of)
+
+    @property
+    def blocks_held(self) -> int:
+        """Blocks some live sequence references (`KVStats.used_blocks`)."""
+        return len(self._ref)
+
+    @property
+    def window_blocks_held(self) -> int:
+        """Of them, those that stand in a window group's table."""
+        return len(self._window_live)
 
     def state_slot(self, seq_id: str) -> int:
         """The state slot `seq_id` holds (a model with state)."""
@@ -427,6 +440,7 @@ class KVBlockManager:
             self._ref[b] = r
             return
         del self._ref[b]
+        self._window_live.discard(b)
         if b in self._hash_of:
             # Content stays findable: most-recently-freed lands at the LRU
             # tail, so eviction takes the coldest prefix first.
@@ -555,6 +569,8 @@ class KVBlockManager:
                     self._hash_of[fresh] = h
                     self._pending_loads[fresh] = (h, blob, False)
             tables.append(table)
+            if self.group_windows[g]:
+                self._window_live.update(table[lo:hi])
         self._tables[seq_id] = tables
         self._held_lo[seq_id] = [lo for lo, _ in spans]
         self._lens[seq_id] = num_tokens
@@ -706,6 +722,7 @@ class KVBlockManager:
             nb = self._acquire()
             self._ref[nb] = 1
             table[i] = nb
+            self._window_live.add(nb)
         return released
 
     def _release_behind(self, seq_id: str, first_query: int) -> int:
@@ -772,10 +789,12 @@ class KVBlockManager:
             )
             canon = self._index.get(h)
             if canon is not None and canon != mine:
-                for table, b, c in zip(tables, mine, canon):
+                for w, table, b, c in zip(self.group_windows, tables, mine, canon):
                     if b != c:
                         self._incref(c)
                         table[i] = c
+                        if w:
+                            self._window_live.add(c)
                         self._release_one(b)
             elif canon is None:
                 self._index[h] = mine
@@ -920,6 +939,12 @@ class KVBlockManager:
                     refs[b] = refs.get(b, 0) + 1
         assert refs == self._ref, (
             f"refcount drift: counted {refs}, recorded {self._ref}"
+        )
+        in_window = {b for tables in self._tables.values()
+                     for w, table in zip(self.group_windows, tables) if w
+                     for b in table if b != self.NULL_BLOCK}
+        assert in_window == self._window_live, (
+            f"window blocks drift: counted {in_window}, recorded {self._window_live}"
         )
         seen.update(refs)
         assert len(seen) == self.num_blocks - 1, "lost/leaked blocks"

@@ -17,9 +17,12 @@ from __future__ import annotations
 import fcntl
 import functools
 import glob
+import logging
 import math
 import os
 from typing import Dict, List, MutableMapping, Optional
+
+logger = logging.getLogger(__name__)
 
 TPU_VISIBLE_CHIPS_ENV = "TPU_VISIBLE_CHIPS"
 COMPILE_CACHE_ENV = "JAX_COMPILATION_CACHE_DIR"
@@ -99,6 +102,44 @@ _SUBSET_BOUNDS = {1: "1,1,1", 2: "1,2,1"}
 _claim: Optional[tuple] = None  # (chip ids, open lock files) held for life
 
 
+def _wait_nodes_free(ids: List[int], timeout_s: float = 60.0) -> None:
+    """Wait until the claimed chips' vfio device nodes can be opened. A vfio
+    group takes one opener at a time, and the kernel lets it go only when the
+    last holder's exit has run its course: a process that is already gone
+    from `/proc` can keep the node busy for a moment, and libtpu then fails
+    at the first device query with "open(/dev/vfio/N): Device or resource
+    busy" (what a run that starts right behind another one on the same chips
+    met: PERF.md 7, PR 43). The probe opens and closes the node; anything but
+    EBUSY (no such node, no permission, another kind of node) is libtpu's to
+    report. After `timeout_s` the claim stands and libtpu says what it finds.
+    A wait and a timeout are logged: both are rare and explain a slow attach."""
+    import errno
+    import time
+
+    nodes = _chip_device_nodes()
+    start, busy = time.monotonic(), 0
+    for chip in ids:
+        node = nodes[chip] if chip < len(nodes) else ""
+        if os.sep + "vfio" + os.sep not in node:
+            continue
+        while True:
+            try:
+                os.close(os.open(node, os.O_RDWR))
+                break
+            except OSError as e:
+                if e.errno != errno.EBUSY:
+                    break
+                if time.monotonic() - start > timeout_s:
+                    logger.warning("%s still busy after %.0f s: claiming it as it is",
+                                   node, timeout_s)
+                    break
+                busy += 1
+                time.sleep(0.2)
+    if busy:
+        logger.warning("chips %s: vfio nodes busy at %d opens, %.1f s until let go",
+                       ids, busy, time.monotonic() - start)
+
+
 def claim_chips(quantity: float, lock_dir: str) -> List[int]:
     """Reserve `quantity` local chips for THIS process until it exits and pin
     libtpu to them; call before the first JAX device query.
@@ -153,6 +194,7 @@ def claim_chips(quantity: float, lock_dir: str) -> List[int]:
             f"tpu-chip-*.lock)"
         )
     _claim = (ids, locks)
+    _wait_nodes_free(ids)
     if want < total:
         os.environ[TPU_VISIBLE_CHIPS_ENV] = ",".join(map(str, ids))
         os.environ["TPU_CHIPS_PER_HOST_BOUNDS"] = _SUBSET_BOUNDS[want]

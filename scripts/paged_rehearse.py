@@ -33,12 +33,21 @@ the grouped form's hidden rows between its two kernels, `[T x 128, F]` with
 `T = lanes x top_k // 128 + experts held` (one layer's at a time; the chip's
 compiler keeps them out of the temporaries it counts: PERF.md §6, PR 39).
 
+`--digest` compiles nothing: it prints a hash of each program AS LOWERED
+(StableHLO; the serialized bodies of the Pallas calls left out: they carry
+the checkout's path and the call sites' line numbers, which is also why a
+parent's compile cache misses for a change that moved a line of `gpt.py`). Run
+with the same flags from the roots of two checkouts (`PYTHONPATH=$PWD python3
+<this file> --digest ...` in a checkout whose script lacks the flag): equal
+digests say the change left that model's served programs alone.
+
 Nothing runs: a compile that passes is not a chip run, and no time, rate or
 share comes from here. The last line of stdout is one JSON object."""
 
 from __future__ import annotations
 
 import argparse
+import hashlib
 import json
 import math
 import os
@@ -148,7 +157,7 @@ def loop_copy_bytes(hlo_text: str) -> dict:
 
 def rehearse(cfg, device, num_blocks: int, block_size: int, lanes: int = 8,
              width: int = 16, chunk: int = 64, spec: int = 4,
-             init: bool = False, decode_lanes=()) -> dict:
+             init: bool = False, decode_lanes=(), digest: bool = False) -> dict:
     """Compile the three paged programs of `cfg` for `device` (a described
     device of `jax.experimental.topologies`) at one shape bucket each; with
     `init`, also `init_params` under one jit (what making the weights in a
@@ -217,7 +226,13 @@ def rehearse(cfg, device, num_blocks: int, block_size: int, lanes: int = 8,
     }
     for name, lower in programs.items():
         try:
-            compiled = lower().compile()
+            lowered = lower()
+            if digest:
+                text = re.sub(r'\\22body\\22: \\22[^\\]*\\22', "BODY", lowered.as_text())
+                report["programs"][name] = hashlib.sha256(text.encode()).hexdigest()[:16]
+                print(f"{name}: {report['programs'][name]}", flush=True)
+                continue
+            compiled = lowered.compile()
         except Exception as e:  # noqa: BLE001 — a refusal is the answer
             report["programs"][name] = {"refused": str(e).splitlines()[0][:300]}
             continue
@@ -267,6 +282,9 @@ def main() -> int:
                     help="cut the preset's depth (a 6 B model on one chip)")
     ap.add_argument("--decode-lanes", default="",
                     help="lane buckets to compile the decode program at besides --lanes")
+    ap.add_argument("--digest", action="store_true",
+                    help="hash each program as lowered and compile nothing: equal in "
+                         "two checkouts = the served programs did not change")
     ap.add_argument("--config", default=None,
                     help="a benchmark configuration (benchmarks/configs/<name>.json): "
                          "its program model and overrides instead of --model")
@@ -294,7 +312,8 @@ def main() -> int:
     topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
     report = rehearse(cfg, topo.devices[0], a.num_blocks, a.block_size,
                       a.lanes, a.width, a.chunk, a.spec, init=True,
-                      decode_lanes=[int(n) for n in a.decode_lanes.split(",") if n])
+                      decode_lanes=[int(n) for n in a.decode_lanes.split(",") if n],
+                      digest=a.digest)
     if cfg.mlp_type == "moe":   # what the grouped form's hidden rows take, by shape
         from ray_tpu.ops.moe import GROUP_ROWS
 

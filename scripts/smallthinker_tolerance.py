@@ -30,6 +30,26 @@ import sys
 import time
 
 
+class _KeepLogits:
+    """An architecture module whose reference keeps the logits it last gave,
+    for `--curve`: one forward pass serves every prefix of a check."""
+
+    def __init__(self, module):
+        self._module, self.last = module, None
+
+    def __getattr__(self, name):
+        return getattr(self._module, name)
+
+    def make_logits(self, dims):
+        logits = self._module.make_logits(dims)
+
+        def keep(params, tokens):
+            self.last = logits(params, tokens)
+            return self.last
+
+        return keep
+
+
 class _Held:
     """What `bench_check_tokens` reads of a replica."""
 
@@ -73,6 +93,10 @@ def readings(config_name, wrongs_of, row_of, argv, doc, faults=None) -> int:
     ap.add_argument("--check", default="",
                     help="prompt_len:new_tokens in place of the configuration's "
                          "token_check (how a cell's check was sized)")
+    ap.add_argument("--curve", default="",
+                    help="n1,n2,...: beside each reading, what the check would read "
+                         "over the first n new tokens alone (how a check's length "
+                         "was chosen: bench_check_tokens' arithmetic on its own logits)")
     ap.add_argument("--sizes", default="",
                     help="key=int,...: published keys of the configuration to replace. "
                          "On the CPU at a quarter of the widths (all layers, the whole "
@@ -81,6 +105,7 @@ def readings(config_name, wrongs_of, row_of, argv, doc, faults=None) -> int:
     a = ap.parse_args(argv)
     parts = set(a.parts.split(","))
     import jax
+    import numpy as np
 
     from benchmarks import harness
     from benchmarks.runners.serve import BenchReplica
@@ -90,6 +115,9 @@ def readings(config_name, wrongs_of, row_of, argv, doc, faults=None) -> int:
     config = harness.load_json(harness.ROOT, f"benchmarks/configs/{config_name}.json")
     config.update((k, int(v)) for k, v in (kv.split("=") for kv in a.sizes.split(",") if kv))
     arch = harness.arch(config["arch"])
+    if a.curve:     # a script's process reads one configuration: keep its logits
+        arch = _KeepLogits(arch)
+        harness.arch = lambda name: arch
     m = arch.dims(config, a.rehearse)
     part = config["rehearsal"]["requests"] if a.rehearse else config["runners"]["requests"]
     opts = EngineOptions(**part["engine_options"])
@@ -109,7 +137,13 @@ def readings(config_name, wrongs_of, row_of, argv, doc, faults=None) -> int:
     def check(held, seed, dims=m):
         err, agree = BenchReplica.bench_check_tokens(
             held, config["arch"], dims, seed, n_prompt, n_new)
-        return {"token_err": err, "argmax_agree": agree}
+        out = {"token_err": err, "argmax_agree": agree}
+        if a.curve:
+            want, got = arch.last[n_prompt - 1:], np.asarray(held.tokens)
+            short = want.max(-1) - want[np.arange(n_new), got]
+            out["curve"] = {n: float(short[:n].max() / np.abs(want[:n]).max())
+                            for n in (int(v) for v in a.curve.split(","))}
+        return out
 
     rows = []
     for seed in (int(s) for s in a.seeds.split(",")):

@@ -156,7 +156,7 @@ def test_the_cell_and_its_files_are_in_the_benchmark():
 
     resolves()
     bench = harness.benchmark()
-    assert len(bench["workloads"]) == 8 and len(bench["configs"]) == 6
+    assert len(bench["workloads"]) == 9 and len(bench["configs"]) == 7     # PR 43 appended one each
     assert sum(w["chips"] == 4 for w in bench["workloads"]) == 1
     cell, entry = bench["workloads"][7], bench["configs"][5]
     assert cell["name"] == CELL and entry["name"] == "jamba2-3b"
@@ -178,7 +178,7 @@ def test_the_cell_and_its_files_are_in_the_benchmark():
     names = [m["name"] for m in bench["per_layer"]]
     first = names.index(next(iter(NEW_METRICS)))        # appended, in the issue's order;
     assert names[first:first + 4] == list(NEW_METRICS)  # PR 41's two behind them
-    assert names[first + 4:] == ["chunk_attn_kernel_share", "chunk_attn_time_share"]
+    assert names[first + 4:first + 6] == ["chunk_attn_kernel_share", "chunk_attn_time_share"]
     for name in NEW_METRICS:
         assert per_layer[name]["workloads"] == [CELL]
     assert per_layer["state_slot_util_share"]["layer"] == "engine scheduler and KV"

@@ -135,3 +135,43 @@ def test_on_tpu_is_exact(monkeypatch):
     for backend, want in (("tpu", True), ("tpu-proxy", False), ("cpu", False)):
         monkeypatch.setattr(jax, "default_backend", lambda b=backend: b)
         assert attention._on_tpu() is want
+
+
+@pytest.mark.parametrize("busy_opens, waited", [(0, False), (3, True)])
+def test_claim_waits_for_a_vfio_node_its_last_holder_is_still_letting_go(
+        fake_dev, monkeypatch, caplog, busy_opens, waited):
+    """A vfio group takes one opener at a time and its last holder can be gone
+    from /proc before the kernel lets the node go: the claim probes each
+    claimed node until it opens, so libtpu's first device query does not meet
+    "Device or resource busy"; anything but EBUSY is libtpu's to report."""
+    import errno
+
+    (fake_dev / "vfio").mkdir()
+    for name in ("vfio", "0", "1"):
+        (fake_dev / "vfio" / name).touch()
+    lock_dir = fake_dev / "session"
+    lock_dir.mkdir()
+    real_open, calls, naps = os.open, [], []
+
+    def flaky_open(path, flags, *a):
+        if os.sep + "vfio" + os.sep in str(path):
+            calls.append(path)
+            if len(calls) <= busy_opens:
+                raise OSError(errno.EBUSY, "Device or resource busy")
+        return real_open(path, flags, *a)
+
+    monkeypatch.setattr(os, "open", flaky_open)
+    monkeypatch.setattr("time.sleep", naps.append)
+    assert tpu.claim_chips(2, str(lock_dir)) == [0, 1]
+    assert len(calls) == 2 + busy_opens and bool(naps) == waited
+    assert any("vfio nodes busy at 3 opens" in r.message for r in caplog.records) == waited
+    for f in tpu._claim[1]:
+        f.close()
+    # a node that cannot be opened for another reason does not hold the claim up
+    monkeypatch.setattr(tpu, "_claim", None)
+    monkeypatch.setattr(os, "open", lambda path, flags, *a: (_ for _ in ()).throw(
+        OSError(errno.EACCES, "Permission denied")) if "vfio" in str(path)
+        else real_open(path, flags, *a))
+    assert tpu.claim_chips(2, str(lock_dir)) == [0, 1]
+    for f in tpu._claim[1]:
+        f.close()
