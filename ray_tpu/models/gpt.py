@@ -2425,10 +2425,30 @@ def paged_attn_kernel(cfg: GPTConfig, tokens: int, width: int, block_size: int) 
     than one tile, on the chip, key and value rows of whole lane tiles. From
     shapes alone: the program decides with it, and the host counts with it
     (`engine_stats()`' `attn_chunks_kernel`)."""
+    return (tokens > 1 and paged_attn_tiling(width, block_size)[1] > 1
+            and attention._on_tpu() and _rows_fill_lane_tiles(cfg))
+
+
+def _rows_fill_lane_tiles(cfg: GPTConfig) -> bool:
+    """Whether a K/V head's key and value rows are whole 128-column tiles (a
+    latent model's: the padded row and the values inside it)."""
     key_row, value_row = ((kv_layout(cfg).key_row, cfg.kv_lora_rank)
                           if cfg.kv_lora_rank else (cfg.d_head, cfg.d_head))
-    return (tokens > 1 and paged_attn_tiling(width, block_size)[1] > 1
-            and attention._on_tpu() and key_row % 128 == 0 and value_row % 128 == 0)
+    return key_row % 128 == 0 and value_row % 128 == 0
+
+
+def paged_decode_kernel(cfg: GPTConfig, tokens: int, block_size: int) -> bool:
+    """Whether a paged program of `tokens` tokens a lane runs its attention
+    as the DECODE kernel (`ops/attention.py` `paged_decode_attention`: each
+    lane's own blocks fetched through its table, to its own length and
+    window) and not as the gather at the table's width: one token a lane,
+    on the chip, key and value rows of whole lane tiles, a block whole
+    sublane tiles of the pool's dtype. From shapes alone, whatever the
+    table's width: the program decides with it, and the host counts with it
+    (`engine_stats()`' `attn_decodes_kernel`, and what `paged_attn_keys`
+    counts for such a program)."""
+    return (tokens == 1 and attention._on_tpu() and _rows_fill_lane_tiles(cfg)
+            and block_size % (32 // jnp.dtype(cfg.dtype).itemsize) == 0)
 
 
 def paged_attn_trips(xp, first_pos, last_pos, real, window, tile_keys, tiles):
@@ -2445,15 +2465,29 @@ def paged_attn_trips(xp, first_pos, last_pos, real, window, tile_keys, tiles):
     return first, xp.where(real, last - first + 1, 1).max()
 
 
-def paged_attn_keys(lanes: int, width: int, block_size: int, last_pos, real):
+def paged_attn_keys(lanes: int, width: int, block_size: int, last_pos, real,
+                    by_lane: bool = False):
     """What one dispatched paged program computes attention over in a
     global layer, counted on the host (numpy [B] arguments as
     `paged_attn_trips` takes them): (keys its bounds cover = lanes x trips
-    x tile, keys of the padded tables = lanes x width x block_size)."""
+    x tile, keys of the padded tables = lanes x width x block_size).
+    `by_lane`: a decode program under `paged_decode_kernel`, which covers the
+    blocks each real lane's own position reaches and no other
+    (`ops.attention.paged_decode_span`, where the kernel's bounds come from)."""
+    padded = lanes * width * block_size
+    if by_lane:
+        return _lane_keys(last_pos, real, _NO_WINDOW, block_size, width), padded
     tile, tiles = paged_attn_tiling(width, block_size)
     _, trips = paged_attn_trips(    # no window: where a lane's queries start is moot
         np, last_pos, last_pos, real, _NO_WINDOW, tile * block_size, tiles)
-    return lanes * int(trips) * tile * block_size, lanes * width * block_size
+    return lanes * int(trips) * tile * block_size, padded
+
+
+def _lane_keys(pos, real, window: int, block_size: int, width: int) -> int:
+    """Keys the decode kernel fetches in a layer of `window`, over the lanes."""
+    _, blocks = attention.paged_decode_span(
+        np, np.asarray(pos), real, window, block_size, width)
+    return int(np.sum(blocks)) * block_size
 
 
 def attn_heads_by_window(cfg: GPTConfig) -> Tuple[Tuple[int, int], ...]:
@@ -2469,7 +2503,7 @@ def attn_heads_by_window(cfg: GPTConfig) -> Tuple[Tuple[int, int], ...]:
 
 
 def paged_attn_head_keys(heads_by_window, run: int, width: int, block_size: int,
-                         first_pos, last_pos, real):
+                         first_pos, last_pos, real, by_lane: bool = False):
     """Query heads x keys the attention of one dispatched paged program
     covers, summed over the layers, counted on the host: (in the window
     layers, in all layers). `run`: the keys a global layer covers, as
@@ -2477,15 +2511,20 @@ def paged_attn_head_keys(heads_by_window, run: int, width: int, block_size: int,
     covers lanes x trips x tile keys under ITS window (`paged_attn_trips`; a
     table of one tile is covered whole), so only a model with window layers
     counts trips again. Each kind weighs by its own count of query heads
-    (`GPTConfig.layer_heads`); a looped model's layers once a pass."""
+    (`GPTConfig.layer_heads`); a looped model's layers once a pass. `by_lane`
+    as `paged_attn_keys` takes it: a window layer covers each real lane's
+    blocks from its window's first."""
     window = every = 0
     for w, heads in heads_by_window:
         keys = run
-        if w:
+        if w and by_lane:
+            keys = _lane_keys(last_pos, real, w, block_size, width)
+        elif w:
             tile, tiles = paged_attn_tiling(width, block_size)
             _, trips = paged_attn_trips(
                 np, first_pos, last_pos, real, w, tile * block_size, tiles)
             keys = np.size(last_pos) * int(trips) * tile * block_size
+        if w:
             window += heads * keys
         every += heads * keys
     return window, every
@@ -2527,9 +2566,15 @@ def _paged_layers(params, tokens, pos, valid, block_tables, kv, cfg: GPTConfig,
     (a chunk over a wide table, on the chip) the loop is ONE kernel,
     `ops/attention.py` `paged_chunk_attention`: the same tiles, bounds, mask
     and online softmax, the scores and the accumulator in fast memory, the
-    table's rows gathered once before it; the loop below stays as what the
-    CPU runs, the tests' reference and the decode step's form. An expert MLP
-    is the dropless layer of `_dropless_mlp`.
+    table's rows gathered once before it. Where `paged_decode_kernel` says so
+    (one token a lane, on the chip) nothing is gathered at all: ONE kernel,
+    `ops/attention.py` `paged_decode_attention`, reads the pool as it lies,
+    each lane's own blocks through its table from its window's first block
+    to the block of its own position, whatever the other lanes of the bucket
+    hold and however wide the table; a padding lane fetches nothing. The
+    forms below stay as what the CPU runs, the tests' reference and the form
+    of rows that fill no lane tile (heads of 64). An expert MLP is the
+    dropless layer of `_dropless_mlp`.
 
     A looped model (`ut_steps` > 1) runs that layer scan `ut_steps` times
     in an outer scan, the pool still the carry and written in place, the
@@ -2609,6 +2654,7 @@ def _paged_layers(params, tokens, pos, valid, block_tables, kv, cfg: GPTConfig,
         last_pos = jnp.where(real, pos, 0).max(axis=1)
         real_lane = real.any(axis=1)
     by_kernel = paged_attn_kernel(cfg, S, W, BS)
+    by_lane = paged_decode_kernel(cfg, S, BS)
 
     def attention_of(R):
         """`attend` for layers of R query heads a K/V head: everything below
@@ -2618,7 +2664,8 @@ def _paged_layers(params, tokens, pos, valid, block_tables, kv, cfg: GPTConfig,
         row_pos = jnp.tile(pos, (1, R)) if by_kernel else None
 
         # One query a K/V head (a decode step of a multi-head model) whose
-        # features fill whole lane tiles: attention as two matrix products over
+        # features fill whole lane tiles, OFF the chip (on it such a step takes
+        # `by_lane`'s kernel): attention as two matrix products over
         # the gathered rows AS THE POOL LAYS THEM, [tokens, Hkv*Dh]. The scores
         # are rows x the queries set block-diagonally ([Hkv*Dh, Hkv], head h's
         # query in column h), the result is weights x rows ([Hkv, Hkv*Dh]) of
@@ -2666,6 +2713,10 @@ def _paged_layers(params, tokens, pos, valid, block_tables, kv, cfg: GPTConfig,
 
         def gathered(q, kk, vv, slot, table, window):
             """Attention of q [B, Hkv, R*S, Dh] over the rows `table` names."""
+            if by_lane:     # the pool as it lies: each lane's own blocks
+                return attention.paged_decode_attention(
+                    q, kk, vv, slot, table, pos[:, 0], real[:, 0],
+                    _NO_WINDOW if window is None else window, dv=Dv, sm_scale=scale)
             if NT == 1:
                 scores, gv = scores_of(q, kk, vv, slot, table, kpos, seen, window)
                 probs = jax.nn.softmax(scores, axis=-1)

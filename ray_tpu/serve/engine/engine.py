@@ -366,6 +366,7 @@ class InferenceEngine:
         from ...models.gpt import (
             attn_heads_by_window, init_paged_cache, init_params, kv_layout,
             paged_attn_head_keys, paged_attn_kernel, paged_attn_keys,
+            paged_decode_kernel,
         )
 
         self.cfg = dataclasses.replace(cfg, remat=False, remat_policy=None)
@@ -514,6 +515,11 @@ class InferenceEngine:
         # send their attention to the chunk kernel: the program's own rule.
         self._attn_kernel = paged_attn_kernel
         self.total_attn_chunks = [0, 0]
+        # Decode programs whose shapes send their attention to the decode
+        # kernel (each lane's own blocks through its table): the program's
+        # own rule, which also says what such a program's keys count.
+        self._decode_kernel = paged_decode_kernel
+        self.total_attn_decodes_kernel = 0
         self._step_attn = [0, 0]
         # Expert routing: (experts touched, busiest expert's share) of the
         # decode step whose ids this step read, which came back with them;
@@ -1122,19 +1128,22 @@ class InferenceEngine:
             except Exception as e:  # noqa: BLE001 — fail the waiter, not the loop
                 fut.set_exception(e)
 
-    def _count_attn(self, lanes: int, width: int, last_pos, real, first_pos=None):
+    def _count_attn(self, lanes: int, width: int, last_pos, real, first_pos=None,
+                    by_lane: bool = False):
         """Add one program's (keys run, keys padded) to the step's and the
         engine's counts, and its heads x keys by layer kind to the books, from
         the helpers its own loop bounds come from. `first_pos`: each lane's
-        first query, where it is not its last (a chunk)."""
+        first query, where it is not its last (a chunk). `by_lane`: a decode
+        program whose attention is the decode kernel's, each real lane's own
+        blocks under the layer's window."""
         run, padded = self._attn_keys(
-            lanes, width, self.opts.block_size, last_pos, real)
+            lanes, width, self.opts.block_size, last_pos, real, by_lane)
         for count in (self._step_attn, self.total_attn_keys):
             count[0] += run
             count[1] += padded
         window, every = self._attn_head_keys(
             self._attn_heads, run, width, self.opts.block_size,
-            last_pos if first_pos is None else first_pos, last_pos, real)
+            last_pos if first_pos is None else first_pos, last_pos, real, by_lane)
         self._books["attn_head_keys_window"] += window
         self._books["attn_head_keys"] += every
 
@@ -1418,7 +1427,9 @@ class InferenceEngine:
                 self._tables_into(tables[i], seq)
             self._step_chained = int(not lanes[3].all())
             self.total_decode_chained += self._step_chained
-            self._count_attn(B, W, lanes[1], np.arange(B) < len(seqs))
+            by_lane = self._decode_kernel(self.cfg, 1, self.opts.block_size)
+            self.total_attn_decodes_kernel += by_lane
+            self._count_attn(B, W, lanes[1], np.arange(B) < len(seqs), by_lane=by_lane)
             self._count_moe(B)
             self._count_state(B, len(seqs), decode=True)
             args = (jnp.asarray(lanes), jnp.asarray(tables))
@@ -1650,6 +1661,7 @@ class InferenceEngine:
             "attn_keys_padded": self.total_attn_keys[1],
             "attn_chunks": self.total_attn_chunks[0],
             "attn_chunks_kernel": self.total_attn_chunks[1],
+            "attn_decodes_kernel": self.total_attn_decodes_kernel,
             "ut_passes_run": self.total_ut_passes[0],
             "ut_passes_full": self.total_ut_passes[1],
             "moe_assign_held": self.total_moe_assign[0],

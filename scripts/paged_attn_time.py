@@ -13,7 +13,15 @@ on the device, `engine_options`), the programs jitted and donated as the
 engine's are. `--tile-keys` times each shape once for every value of
 `models.gpt._ATTN_TILE_KEYS` (a program without that constant runs each
 shape once): how the tile of `_paged_layers`' key loop was chosen (PERF.md
-§6, PR 29). `--sampled` times each shape a second time as the program the
+§6, PR 29). `--decode-forms gather,kernel` times each decode shape once
+in each form of the step's attention: GATHER, every lane's rows gathered at the
+table's width (what every decode program ran until PR 44 and what heads of 64
+and the CPU still run), and KERNEL (`ops/attention.py`
+`paged_decode_attention`: each lane's own blocks through its table; the form
+`models.gpt.paged_decode_kernel` sends the shape to is the default);
+`--group-kib 512,1024,2048` times the kernel once for every value of its DMA
+group, `ops.attention._DECODE_GROUP_BYTES` (PERF.md §6, PR 44).
+`--sampled` times each shape a second time as the program the
 engine dispatches (`serve/engine/engine.py: _paged_jits`: the same function
 with the sampler behind it, ids for logits, the last ids carried beside the
 pool), on the same input ids (an expert model routes by them): what the
@@ -39,6 +47,7 @@ configuration's tiny preset) it prints shapes only."""
 from __future__ import annotations
 
 import argparse
+import itertools
 import json
 import os
 import sys
@@ -51,6 +60,8 @@ def main(argv=None) -> int:
     ap.add_argument("--prefill", default="8:0,32:0,128:0,256:0")
     ap.add_argument("--decode", default="")
     ap.add_argument("--tile-keys", default="")
+    ap.add_argument("--decode-forms", default="")
+    ap.add_argument("--group-kib", default="")
     ap.add_argument("--sampled", action="store_true")
     ap.add_argument("--ids", choices=("same", "distinct"), default="same")
     ap.add_argument("--latent-forms", default="")
@@ -64,6 +75,7 @@ def main(argv=None) -> int:
 
     from benchmarks import harness
     from ray_tpu.models import gpt
+    from ray_tpu.ops import attention
 
     config = harness.load_json(harness.ROOT, f"benchmarks/configs/{a.config}.json")
     arch = harness.arch(config["arch"])
@@ -79,7 +91,8 @@ def main(argv=None) -> int:
     on_chip = dev.platform == "tpu"
     reps = a.reps if on_chip else 1
     params = jax.jit(lambda k: gpt.init_params(k, cfg))(jax.random.PRNGKey(0))
-    kv = gpt.init_paged_cache(cfg, NB, BS)
+    stateful = bool(gpt.kv_layout(cfg).state)   # a state slot a lane beside the pool
+    kv = gpt.init_paged_cache(cfg, NB, BS, opts["max_num_seqs"] * stateful)
 
     def table(W):
         """[G, W] (or [W]): group g holds blocks 1 + g*W .. of its own."""
@@ -88,6 +101,13 @@ def main(argv=None) -> int:
             raise SystemExit(f"{G} tables of {W} blocks do not fit {NB} blocks")
         return t if G > 1 else t[0]
 
+    gathered_logits = {}
+    if a.decode_forms:      # rows that are not all alike, so that the forms can differ
+        for name in set(kv) & {"k", "v"}:   # one layer's random rows in every layer,
+            shape, dtype = kv[name].shape, kv[name].dtype   # and no second pool beside it
+            del kv[name]
+            kv[name] = jax.jit(lambda: jnp.broadcast_to(
+                jax.random.normal(jax.random.PRNGKey(2), shape[1:], dtype), shape))()
     tiles = [int(t) for t in a.tile_keys.split(",") if t]
     if not hasattr(gpt, "_ATTN_TILE_KEYS"):
         tiles = []
@@ -103,6 +123,12 @@ def main(argv=None) -> int:
         jax.block_until_ready(out)
         if on_chip:
             row["ms"] = 1e3 * (time.perf_counter() - t) / reps
+        if "form" in row and not row.get("sampled"):    # the forms agree at the logits
+            logits = np.asarray(out[0] if isinstance(out, tuple) else out,
+                                np.float32)[:len(row["positions"])]    # the real lanes
+            first = gathered_logits.setdefault(json.dumps(row["positions"]), logits)
+            row["logits_off_first_form"] = float(np.abs(logits - first).max())
+            row["logits_absmax"] = float(np.abs(logits).max())
         rows.append(row)
         print(json.dumps(row), flush=True)
 
@@ -131,8 +157,6 @@ def main(argv=None) -> int:
         # key; jit keeps traces by function, so each value gets its own.
         prefill = jax.jit(lambda *args: gpt.prefill_paged(*args),
                           static_argnums=(6,), donate_argnums=(5,))
-        decode = jax.jit(lambda *args: gpt.decode_step_paged(*args),
-                         static_argnums=(5,), donate_argnums=(4,))
         for spec in filter(None, a.prefill.split(",")):
             W, offset = (int(x) for x in spec.split(":"))
             n = min(chunk, W * BS - offset)
@@ -146,7 +170,24 @@ def main(argv=None) -> int:
                 meta = jnp.asarray([n, offset, 0], jnp.int32)
                 timed({**row, "sampled": True}, sampled_prefill,
                       (args[0], meta, args[3]))
-        for spec in filter(None, a.decode.split(",")):
+        rule, own_group = gpt.paged_decode_kernel, attention._DECODE_GROUP_BYTES
+        groups = [int(g) << 10 for g in a.group_kib.split(",") if g]
+        forms = [(form, group) for form in a.decode_forms.split(",") if form
+                 for group in (groups if form == "kernel" and groups else [None])]
+        for spec, (form, group) in itertools.product(
+                filter(None, a.decode.split(",")), forms or [(None, None)]):
+            # both are read while the program is traced: a jit a form
+            gpt.paged_decode_kernel = rule if form is None else (
+                lambda cfg, tokens, bs, form=form: (
+                    tokens == 1 and form == "kernel" and on_chip))
+            attention._DECODE_GROUP_BYTES = group or own_group
+            decode = jax.jit(      # (.., tables, state slots or None, kv, cfg)
+                lambda p, ids, pos, tables, slots, kv, cfg: gpt.decode_step_paged(
+                    p, ids, pos, tables, kv, cfg, slots),
+                static_argnums=(6,), donate_argnums=(5,))
+            if a.sampled:
+                engine_module._JITS = None
+                _, sampled_decode, _ = map(carrying, engine_module._paged_jits())
             B, W, poss = spec.split(":")
             B, W = int(B), int(W)
             pos = [int(p) for p in poss.split("/")]
@@ -156,9 +197,14 @@ def main(argv=None) -> int:
             tables = np.zeros(shape, np.int32)
             tables[:len(pos)] = table(W)    # lanes share blocks: reads only matter
             ids = 7 + np.arange(B, dtype=np.int32) * (a.ids == "distinct")
-            args = (jnp.asarray(ids), jnp.asarray(positions), jnp.asarray(tables))
+            state = ((np.arange(B) < len(pos)) * (1 + np.arange(B) % opts["max_num_seqs"])
+                     ).astype(np.int32)
+            args = (jnp.asarray(ids), jnp.asarray(positions), jnp.asarray(tables),
+                    jnp.asarray(state) if stateful else None)
             row = {"program": "decode_step_paged", "tile_keys": tile, "lanes": B,
-                   "W": W, "keys": W * BS, "positions": pos}
+                   "W": W, "keys": W * BS, "positions": pos,
+                   "form": form or ("kernel" if rule(cfg, 1, BS) else "gather"),
+                   "group_kib": attention._DECODE_GROUP_BYTES >> 10}
             timed(dict(row), decode, args)
             if a.sampled:     # rows: slot, position, host id, known: the same ids
                 lanes = np.zeros((4, B), np.int32)      # (experts route by them)
@@ -166,6 +212,7 @@ def main(argv=None) -> int:
                 lanes[2], lanes[3] = ids, 1
                 timed({**row, "sampled": True}, sampled_decode,
                       (jnp.asarray(lanes), args[2]))
+        gpt.paged_decode_kernel, attention._DECODE_GROUP_BYTES = rule, own_group
     for W in (int(w) for w in a.latent_forms.split(",") if w):
         q_rows = [int(r) for r in a.q_rows.split(",") if r]
         for form, ms in latent_forms(cfg, params, kv["k"], table(W), chunk, BS, reps,

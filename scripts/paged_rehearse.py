@@ -33,6 +33,12 @@ the grouped form's hidden rows between its two kernels, `[T x 128, F]` with
 `T = lanes x top_k // 128 + experts held` (one layer's at a time; the chip's
 compiler keeps them out of the temporaries it counts: PERF.md §6, PR 39).
 
+Each program's paged attention kernels are counted (`kernels`: the chunk's
+`paged_chunk_attn`, the decode step's `paged_decode_attn`; once a layer kind
+where it stands in a layer scan's body). `--fusion bitcast_add_fusion` prints
+the decode program's fusions of that name, with operands, bytes and body: what
+an operation a trace names IS (ROADMAP S13).
+
 `--digest` compiles nothing: it prints a hash of each program AS LOWERED
 (StableHLO; the serialized bodies of the Pallas calls left out: they carry
 the checkout's path and the call sites' line numbers, which is also why a
@@ -53,6 +59,7 @@ import math
 import os
 import re
 import sys
+import time
 
 os.environ.setdefault("TPU_LOG_DIR", "disabled")
 
@@ -155,14 +162,57 @@ def loop_copy_bytes(hlo_text: str) -> dict:
     return dict(sorted(by_depth.items()))
 
 
+def kernels_in(hlo_text: str) -> dict:
+    """{name: calls} of the paged attention kernels (`ops/attention.py`: the
+    chunk's, the decode step's) in a compiled program's text, by the name its
+    `pallas_call` was given: which programs hold which, and how often."""
+    from ray_tpu.ops import attention
+
+    calls = [line for line in hlo_text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    return {name: sum(f"/{name}/" in line or f"/{name}\"" in line for line in calls)
+            for name in (attention.PAGED_CHUNK_KERNEL, attention.PAGED_DECODE_KERNEL)}
+
+
+def fusions_named(hlo_text: str, name: str):
+    """[(instruction, its line, the called computation's body)] of every
+    fusion `name` or `name.<n>` of a compiled program: what a trace's
+    operation of that name IS (its operands with their shapes, the product or
+    copy inside it). Metadata and backend configuration are cut out."""
+    def clean(line):
+        line = re.sub(r', metadata=\{[^}]*\}', "", line)
+        return re.sub(r', backend_config=\{.*$', "", line)
+
+    bodies, inside = {}, None
+    for line in hlo_text.splitlines():
+        m = _COMPUTATION.match(line)
+        if m:
+            inside = m.group(1)
+            bodies[inside] = []
+        elif line.startswith("}"):
+            inside = None
+        elif inside:
+            bodies[inside].append(clean(line))
+    out = []
+    for line in hlo_text.splitlines():
+        m = _INSTR.match(line)
+        if m and re.fullmatch(re.escape(name) + r"(\.\d+)?", m.group(1)):
+            called = re.search(r"calls=%([\w.\-]+)", line)
+            out.append((m.group(1), clean(line).strip(),
+                        bodies.get(called.group(1), []) if called else []))
+    return out
+
+
 def rehearse(cfg, device, num_blocks: int, block_size: int, lanes: int = 8,
              width: int = 16, chunk: int = 64, spec: int = 4,
-             init: bool = False, decode_lanes=(), digest: bool = False) -> dict:
+             init: bool = False, decode_lanes=(), digest: bool = False,
+             fusion: str = "") -> dict:
     """Compile the three paged programs of `cfg` for `device` (a described
     device of `jax.experimental.topologies`) at one shape bucket each; with
     `init`, also `init_params` under one jit (what making the weights in a
     stated dtype keeps beside them); the decode program again at each lane
-    bucket of `decode_lanes`."""
+    bucket of `decode_lanes`; with `fusion`, print the decode program's fusions
+    of that name (`fusions_named`)."""
     import jax
     import jax.numpy as jnp
     from jax.sharding import SingleDeviceSharding
@@ -226,11 +276,14 @@ def rehearse(cfg, device, num_blocks: int, block_size: int, lanes: int = 8,
     }
     for name, lower in programs.items():
         try:
+            began = time.perf_counter()
             lowered = lower()
+            lower_s = time.perf_counter() - began   # what every set-up pays a program anew
             if digest:
                 text = re.sub(r'\\22body\\22: \\22[^\\]*\\22', "BODY", lowered.as_text())
                 report["programs"][name] = hashlib.sha256(text.encode()).hexdigest()[:16]
-                print(f"{name}: {report['programs'][name]}", flush=True)
+                print(f"{name}: {report['programs'][name]} "
+                      f"(traced and lowered in {lower_s:.2f} s)", flush=True)
                 continue
             compiled = lowered.compile()
         except Exception as e:  # noqa: BLE001 — a refusal is the answer
@@ -254,6 +307,8 @@ def rehearse(cfg, device, num_blocks: int, block_size: int, lanes: int = 8,
                 {"op": op, "name": n, "result": shape, "MiB": b / 2**20}
                 for op, n, shape, b in ops
             ],
+            "kernels": kernels_in(text),
+            "lower_s": lower_s,
         }
         print(f"{name}: args {mem.argument_size_in_bytes / 2**30:.2f} GiB, "
               f"temp {mem.temp_size_in_bytes / 2**30:.4f}, "
@@ -266,6 +321,15 @@ def rehearse(cfg, device, num_blocks: int, block_size: int, lanes: int = 8,
               flush=True)
         for op, n, shape, b in ops:
             print(f"    {op:28s} {shape}  {b / 2**20:.1f} MiB  %{n}", flush=True)
+        print(f"    attention kernels: {report['programs'][name]['kernels']}; "
+              f"traced and lowered in {lower_s:.2f} s", flush=True)
+        if fusion and name == "decode_step_paged":
+            for instr, line, body in fusions_named(text, fusion):
+                operands = sum(     # a stacked weight counts whole: a layer reads its slice
+                    _array_bytes(d, dims) for b in body if " parameter(" in b
+                    for d, dims, _ in _SHAPE.findall(b.split(" parameter(")[0]))
+                print(f"  %{instr}: parameters {operands / 2**20:.2f} MiB\n    {line}")
+                print("\n".join("      " + b.strip() for b in body), flush=True)
     return report
 
 
@@ -285,6 +349,9 @@ def main() -> int:
     ap.add_argument("--digest", action="store_true",
                     help="hash each program as lowered and compile nothing: equal in "
                          "two checkouts = the served programs did not change")
+    ap.add_argument("--fusion", default="",
+                    help="print the decode program's fusions of this name (and "
+                         "name.<n>): operands, bytes, body")
     ap.add_argument("--config", default=None,
                     help="a benchmark configuration (benchmarks/configs/<name>.json): "
                          "its program model and overrides instead of --model")
@@ -313,7 +380,7 @@ def main() -> int:
     report = rehearse(cfg, topo.devices[0], a.num_blocks, a.block_size,
                       a.lanes, a.width, a.chunk, a.spec, init=True,
                       decode_lanes=[int(n) for n in a.decode_lanes.split(",") if n],
-                      digest=a.digest)
+                      digest=a.digest, fusion=a.fusion)
     if cfg.mlp_type == "moe":   # what the grouped form's hidden rows take, by shape
         from ray_tpu.ops.moe import GROUP_ROWS
 

@@ -2,7 +2,9 @@
 `paged_chunk_attention`): in interpret mode against the plain key loop of
 `models/gpt.py` `_paged_layers` it stands in for, through the paged programs
 themselves, and the rule on shapes that sends a program to it, as the
-program reads it and as the engine's host counts with it."""
+program reads it and as the engine's host counts with it. Below it the
+DECODE step's kernel (`paged_decode_attention`: each lane's own blocks
+through its table) the same way against the gather it stands in for."""
 
 import contextlib
 import functools
@@ -60,6 +62,8 @@ def _programs(by_kernel: bool):
     forms (they are not what is under test)."""
     import jax
 
+    from jax.experimental.pallas import tpu as pltpu
+
     from ray_tpu.models import gpt
     from ray_tpu.ops import attention, norms
     from ray_tpu.serve.engine import engine as engine_module
@@ -71,6 +75,11 @@ def _programs(by_kernel: bool):
             mp.setattr(attention, "_on_tpu", lambda: True)
             mp.setattr(attention, "paged_chunk_attention", functools.partial(
                 attention.paged_chunk_attention, interpret=True))
+            # a decode step traced so takes ITS kernel (uninitialised rows NaN),
+            # a few blocks a DMA group so that a lane here has several
+            mp.setattr(attention, "_DECODE_GROUP_BYTES", 128 << 10)
+            mp.setattr(attention, "paged_decode_attention", functools.partial(
+                attention.paged_decode_attention, interpret=pltpu.InterpretParams()))
             mp.setattr(norms, "_rmsnorm_pallas", norms._rmsnorm_ref)
         yield (jax.jit(lambda *a: gpt.prefill_paged(*a), static_argnums=(6,)),
                jax.jit(lambda *a: gpt.verify_step_paged(*a), static_argnums=(6,)))
@@ -346,3 +355,279 @@ ENTRY %main (a: f32[32768,512]) -> (f32[32768,512]) {
 """
     assert loop_copy_bytes(text) == {1: 1024 * 640 * 2, 2: 32768 * 512 * 4}
     assert loop_copy_bytes(text.replace("copy-start", "copy-begin")) == {}
+
+
+# ------------------------------------------------------- the decode kernel
+#
+# One decode step over a pool of random rows (the history a prefill would
+# have left), by the gather at the table's width and by the kernel, through
+# `decode_step_paged` itself. `None` is a padding lane: a null table.
+
+_WINDOWED = dict(rope_layout=(0, 1), sliding_window_layout=(0, 1), sliding_window=150)
+DECODE = {
+    # model, block size, table width in blocks, the lanes' positions
+    # R = 1 over two heads; a lane of ONE key, lanes at a block's edge
+    "R1-blocks-of-16": (dict(n_heads=2, d_head=128, rotary_dim=32), 16, 8,
+                        (100, 0, None, 15, 16, 37)),
+    # a table of two 1,024-key tiles (the gather's form is the key loop):
+    # lanes at the tile's edge either side, a short lane beside them
+    "R1-tile-edge": (dict(n_heads=2, d_head=128, rotary_dim=32), 16, 128,
+                     (1023, 1024, None, 300, 2047)),
+    "R6-blocks-of-64": (dict(n_heads=6, n_kv_heads=1, d_head=128, rotary_dim=32), 64, 8,
+                        (400, 63, 64, None)),
+    # layer 0 global, layer 1 under a window of 150 keys shorter than the lanes:
+    # two KV groups, two tables, the window group's entries below it RELEASED
+    "R7-window-released": (dict(n_heads=14, n_kv_heads=2, d_head=128, rotary_dim=32,
+                                **_WINDOWED), 64, 16, (900, None, 149, 150, 700)),
+    "R8-blocks-of-128": (dict(n_heads=8, n_kv_heads=1, d_head=128, rotary_dim=32), 128, 4,
+                         (500, 127, 128)),
+    "R20-blocks-of-128": (dict(n_heads=20, n_kv_heads=1, d_head=128, rotary_dim=32), 128, 4,
+                          (300, None, 5)),
+    # a looped model: pass t of a layer reads the pool rows of ITS (pass, layer)
+    "looped-pool": (dict(n_heads=2, d_head=128, rotary_dim=32, ut_steps=3), 16, 8,
+                    (100, 3, None, 64)),
+    # ONE K/V head whose values lie inside the key row, R = 4
+    "latent": (MODELS["latent"], 8, 16, (100, 0, None, 8, 127)),
+}
+
+
+def _decode_case(name):
+    """(cfg, params, pool of random rows, args of `decode_step_paged`, the
+    blocks each real lane's position reaches a group)."""
+    import jax
+    import jax.numpy as jnp
+
+    from ray_tpu.models import gpt
+
+    model, bs, width, lanes = DECODE[name]
+    cfg = gpt.GPTConfig(**{**_COMMON, "max_seq": 2048}, **model, dtype=jnp.float32)
+    params = gpt.init_params(jax.random.PRNGKey(5), cfg)
+    windows = gpt.kv_layout(cfg).windows
+    G, B = len(windows), len(lanes)
+    nb = 1 + B * G * width
+    rng = np.random.default_rng(3)
+    kv = {k: jnp.asarray(rng.normal(size=rows.shape), jnp.float32)
+          for k, rows in gpt.init_paged_cache(cfg, nb, bs).items()}
+    tables = (1 + rng.permutation(nb - 1)).astype(np.int32).reshape(B, G, width)
+    for b, pos in enumerate(lanes):
+        for g, w in enumerate(windows):
+            if pos is None:
+                tables[b, g] = 0
+            else:       # not yet allocated above the lane, released below its window
+                tables[b, g, pos // bs + 1:] = 0
+                tables[b, g, :max(pos - w + 1, 0) // bs if w else 0] = 0
+    positions = np.asarray([pos or 0 for pos in lanes], np.int32)
+    tokens = rng.integers(1, cfg.vocab_size, B).astype(np.int32)
+    args = (jnp.asarray(tokens), jnp.asarray(positions),
+            jnp.asarray(tables if G > 1 else tables[:, 0]))
+    return cfg, params, kv, args, tables
+
+
+@contextlib.contextmanager
+def _decode_program(by_kernel: bool):
+    """`decode_step_paged` jitted anew at the module's own tile of keys; with
+    `by_kernel` as the chip traces it, the kernel in interpret mode."""
+    import jax
+
+    from ray_tpu.models import gpt
+
+    with _programs(by_kernel), pytest.MonkeyPatch.context() as mp:
+        mp.setattr(gpt, "_ATTN_TILE_KEYS", 1024)
+        yield jax.jit(lambda *a: gpt.decode_step_paged(*a), static_argnums=(5,))
+
+
+@pytest.mark.parametrize("case", list(DECODE))
+def test_the_decode_kernel_is_the_gather(case):
+    """The same decode step on the same pool by the gather at the table's
+    width and by the kernel: the logits of every real lane and the pool's
+    rows of every block a real lane holds agree, and the kernel is in the
+    traced program."""
+    import jax
+
+    from ray_tpu.models import gpt
+    from ray_tpu.ops import attention
+
+    cfg, params, kv, args, tables = _decode_case(case)
+    real = np.asarray([pos is not None for pos in DECODE[case][3]])
+    blocks = np.setdiff1d(tables[real], [0])    # block 0 takes the padding lanes' rows
+    got = {}
+    for by_kernel in (False, True):
+        with _decode_program(by_kernel) as decode:
+            assert gpt.paged_decode_kernel(cfg, 1, DECODE[case][1]) is by_kernel
+            text = str(jax.make_jaxpr(lambda *a: gpt.decode_step_paged(*a, cfg))(
+                params, *args, kv))
+            assert (attention.PAGED_DECODE_KERNEL in text) is by_kernel
+            out, pool = decode(params, *args, kv, cfg)
+            logits = out[0] if isinstance(out, tuple) else out
+            got[by_kernel] = jax.tree_util.tree_map(
+                np.asarray, (logits[real], {k: v[:, blocks] for k, v in pool.items()}))
+    for plain, kernel in zip(*map(jax.tree_util.tree_leaves, (got[False], got[True]))):
+        assert np.isfinite(plain).all() and np.isfinite(kernel).all()
+        assert np.abs(plain - kernel).max() < TOL
+
+
+@pytest.mark.parametrize("window", [None, 100, 16], ids=["global", "window-100", "window-16"])
+def test_the_decode_kernel_fetches_a_lanes_own_blocks_and_no_other(window, monkeypatch):
+    """NaN in every row of every block outside a lane's span (the null block,
+    the blocks above its position, those a window has left behind, a padding
+    lane's whole table): a block fetched and multiplied, even under a weight
+    of 0, would show. Against the dense softmax over the clean pool."""
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental.pallas import tpu as pltpu
+
+    from ray_tpu.models.gpt import _NO_WINDOW
+    from ray_tpu.ops import attention
+
+    monkeypatch.setattr(attention, "_DECODE_GROUP_BYTES", 128 << 10)    # 4 blocks a group
+    B, heads, R, dh, bs, width, depth, slot = 5, 2, 3, 128, 16, 24, 3, 2
+    positions = np.asarray([383, 0, 200, 16, 15], np.int32)
+    real = np.asarray([True, True, False, True, True])
+    reach = _NO_WINDOW if window is None else window
+    rng = np.random.default_rng(7)
+    nb = 1 + B * width
+    clean = rng.normal(size=(2, depth, nb, bs, heads * dh)).astype(np.float32)
+    table = (1 + rng.permutation(nb - 1)).astype(np.int32).reshape(B, width)
+    first, blocks = attention.paged_decode_span(np, positions, real, reach, bs, width)
+    assert blocks[2] == 0 and (blocks[real] >= 1).all()
+    poisoned = clean.copy()
+    held = np.concatenate([table[b, first[b]:first[b] + blocks[b]] for b in range(B)])
+    poisoned[:, :, np.setdiff1d(np.arange(nb), held)] = np.nan
+    poisoned[:, np.arange(depth) != slot] = np.nan          # and every other layer's rows
+    q = jnp.asarray(rng.normal(size=(B, heads, R, dh)), jnp.float32)
+    out = attention.paged_decode_attention(
+        q, jnp.asarray(poisoned[0]), jnp.asarray(poisoned[1]), slot, jnp.asarray(table),
+        jnp.asarray(positions), jnp.asarray(real), reach, dv=dh, sm_scale=0.1,
+        interpret=pltpu.InterpretParams())
+    k, v = (clean[i, slot][table].reshape(B, width * bs, heads, dh) for i in (0, 1))
+    scores = np.einsum("bhrd,bthd->bhrt", np.asarray(q), k) * 0.1
+    kp, qp = np.arange(width * bs)[None, None, None], positions[:, None, None, None]
+    scores = np.where((kp <= qp) & (kp > qp - reach), scores, -1e30)
+    want = np.einsum("bhrt,bthd->bhrd", jax.nn.softmax(scores, axis=-1), v)
+    out = np.asarray(out)
+    assert np.isfinite(out).all() and (out[2] == 0).all()       # a padding lane reads 0
+    assert np.abs(out[real] - want[real]).max() < TOL
+
+
+@pytest.mark.parametrize("lanes,widths,pool_blocks,traces", [
+    (4, (2, 8, 32), 64, 1),         # every width padded to the pool's 64 blocks
+    (4, (8, 128), 72, 2),           # a table wider than the pool has blocks stays as it is
+    (64, (4, 16), 4096, 1),         # 64 lanes: to the 128 entries 32 KiB hold
+    (64, (64, 256), 4104, 2),       # past them each width is its own
+], ids=["to-the-pool", "past-the-pool", "to-the-bytes", "past-the-bytes"])
+def test_decode_programs_of_one_lane_count_share_one_trace_of_the_kernel(
+        lanes, widths, pool_blocks, traces, monkeypatch):
+    """Tables reach the kernel padded to one width a lane count, so the decode
+    programs a server warms for one lane bucket (a program a table width)
+    trace the kernel body ONCE between them; another lane count is another
+    trace."""
+    import jax
+    import jax.numpy as jnp
+
+    from ray_tpu.models.gpt import _NO_WINDOW
+    from ray_tpu.ops import attention
+
+    heads, R, dh, bs, depth = 2, 1, 128, 16, 2
+
+    def program(B, width):
+        arr = lambda dt, *shape: jax.ShapeDtypeStruct(shape, dt)  # noqa: E731
+        pool = arr(jnp.bfloat16, depth, pool_blocks, bs, heads * dh)
+        return jax.make_jaxpr(lambda q, k, v, slot, table, pos, real: (
+            attention.paged_decode_attention(q, k, v, slot, table, pos, real, _NO_WINDOW,
+                                             dv=dh, sm_scale=0.1)))(
+            arr(jnp.bfloat16, B, heads, R, dh), pool, pool, arr(jnp.int32),
+            arr(jnp.int32, B, width), arr(jnp.int32, B), arr(jnp.bool_, B))
+
+    traced, body = [], attention._paged_decode_kernel
+    monkeypatch.setattr(attention, "_paged_decode_kernel",
+                        lambda *refs, **sizes: traced.append(sizes) or body(*refs, **sizes))
+    texts = [str(program(lanes, width)) for width in widths]
+    assert all(attention.PAGED_DECODE_KERNEL in text for text in texts)
+    assert len(traced) == traces
+    program(lanes // 2, widths[0])
+    assert len(traced) == traces + 1
+
+
+# (tokens a lane, block size) -> the decode kernel; on the chip
+DECODE_RULE = [
+    ("ouro-2.6b", 1, 16, True),                 # R = 1, 16 heads of 128
+    ("smallthinker-21b-a3b", 1, 64, True),      # R = 7, window and global groups
+    ("laguna-xs2", 1, 64, True),                # R = 6 and 8 by layer kind
+    ("jamba2-3b", 1, 128, True),                # R = 20 over one head
+    ("ax-k1", 1, 64, True),                     # the latent pool: values inside the row
+    ("gptj-6b", 1, 16, True),
+    ("gpt2-large", 1, 16, False),               # heads of 64 fill no lane tile
+    ("ouro-2.6b", 2, 16, False),                # a verify step, a chunk: not one token
+    ("ouro-2.6b", 256, 16, False),
+    ("ouro-2.6b", 1, 8, False),                 # bfloat16 blocks of half a sublane tile
+]
+
+
+@pytest.mark.parametrize("model,tokens,block,want", DECODE_RULE,
+                         ids=[f"{r[0]}-{r[1]}x{r[2]}" for r in DECODE_RULE])
+def test_the_decode_rule_is_a_function_of_shapes(model, tokens, block, want, monkeypatch):
+    """On the chip the rule says what the shapes say, whatever the table's
+    width; off it, never (the CPU keeps the gather)."""
+    from ray_tpu.models import gpt
+    from ray_tpu.ops import attention
+
+    cfg = gpt.CONFIGS[model]()
+    assert gpt.paged_decode_kernel(cfg, tokens, block) is False
+    monkeypatch.setattr(attention, "_on_tpu", lambda: True)
+    assert gpt.paged_decode_kernel(cfg, tokens, block) is want
+
+
+def test_the_decode_kernel_is_in_the_decode_program_and_in_no_other():
+    """Traced as the chip traces them: once a layer kind in the decode
+    program (the layer scan's body), in no prefill and no verify program;
+    `gpt2-large`'s decode program holds none (heads of 64)."""
+    import jax
+    import jax.numpy as jnp
+
+    from ray_tpu.models import gpt
+    from ray_tpu.ops import attention
+
+    i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32)  # noqa: E731
+    counts = {}
+    with _programs(True):
+        for name in ("grouped", "grouped-window"):
+            cfg = _cfg(name)
+            G = len(gpt.kv_layout(cfg).windows)
+            table = (W,) if G == 1 else (G, W)
+            params = jax.eval_shape(lambda k: gpt.init_params(k, cfg), jax.random.PRNGKey(0))
+            kv = jax.eval_shape(lambda: gpt.init_paged_cache(cfg, NB, BS))
+            counts[name] = tuple(
+                str(jaxpr).count(attention.PAGED_DECODE_KERNEL) for jaxpr in (
+                    jax.make_jaxpr(lambda *a: gpt.decode_step_paged(*a, cfg))(
+                        params, i32(4), i32(4), i32(4, *table), kv),
+                    jax.make_jaxpr(lambda *a: gpt.prefill_paged(*a, cfg))(
+                        params, i32(1, 32), i32(), i32(), i32(*table), kv),
+                    jax.make_jaxpr(lambda *a: gpt.verify_step_paged(*a, cfg))(
+                        params, i32(4, 3), i32(4), i32(4), i32(4, *table), kv)))
+        cfg = gpt.CONFIGS["gpt2-large"](remat=False, remat_policy=None)
+        params = jax.eval_shape(lambda k: gpt.init_params(k, cfg), jax.random.PRNGKey(0))
+        kv = jax.eval_shape(lambda: gpt.init_paged_cache(cfg, 256, 16))
+        counts["gpt2-large"] = str(jax.make_jaxpr(
+            lambda *a: gpt.decode_step_paged(*a, cfg))(
+                params, i32(4), i32(4), i32(4, 8), kv)).count(attention.PAGED_DECODE_KERNEL)
+    assert counts == {"grouped": (1, 0, 0), "grouped-window": (1, 0, 0), "gpt2-large": 0}
+
+
+def test_the_host_counts_a_decode_programs_keys_by_lane():
+    """`paged_attn_keys` / `paged_attn_head_keys` with `by_lane`: each real
+    lane's own blocks under the layer's window, from the function the kernel
+    takes its bounds from; without it, the gather's lanes x trips x tile."""
+    from ray_tpu.models import gpt
+    from ray_tpu.ops import attention
+
+    pos = np.asarray([2047, 300, 0, 0])
+    real = np.asarray([True, True, False, False])
+    assert gpt.paged_attn_keys(4, 128, 16, pos, real) == (4 * 2048, 4 * 2048)
+    assert gpt.paged_attn_keys(4, 128, 16, pos, real, by_lane=True) == (
+        2048 + 304, 4 * 2048)       # 128 blocks and 19: the padding lanes none
+    heads = ((0, 12), (512, 36))    # a global kind and a window kind
+    run = 2048 + 304
+    assert gpt.paged_attn_head_keys(heads, run, 128, 16, pos, pos, real, by_lane=True) == (
+        36 * (32 * 16 + 304), 12 * run + 36 * (32 * 16 + 304))
+    first, blocks = attention.paged_decode_span(np, pos, real, 512, 16, 128)
+    assert first.tolist() == [96, 0, 0, 0] and blocks.tolist() == [32, 19, 0, 0]

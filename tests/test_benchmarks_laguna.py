@@ -207,8 +207,11 @@ def test_the_cell_and_its_files_are_in_the_benchmark():
     per_layer = {m["name"]: m for m in bench["per_layer"]}
     assert all(per_layer[name]["moves"] in e2e for name in layer)
     names = [m["name"] for m in bench["per_layer"]]
-    first = names.index(next(iter(NEW_METRICS)))        # appended, in the issue's order
-    assert names[first:] == list(NEW_METRICS)
+    first = names.index(next(iter(NEW_METRICS)))        # appended, in the issue's order;
+    last = first + len(NEW_METRICS)                     # PR 44's two behind them
+    assert names[first:last] == list(NEW_METRICS)
+    assert names[last:] == ["decode_attn_kernel_share", "decode_attn_time_share"]
+    assert {"decode_attn_kernel_share", "decode_attn_time_share"} <= set(layer)
     for name in NEW_METRICS:
         assert per_layer[name]["workloads"] == [CELL]
     assert per_layer["kv_window_block_share"]["layer"] == "engine scheduler and KV"

@@ -2117,3 +2117,136 @@ def test_the_chunk_attention_kernel_compiles_for_v5e_at_the_cells_widths(v5e_chi
     assert attention.PAGED_CHUNK_KERNEL in compiled.as_text()
     out = lanes * heads * rows * dv * 2
     assert compiled.memory_analysis().temp_size_in_bytes <= 2 * out + (1 << 20)
+
+
+# (K/V heads, query heads a K/V head, key row, value row or None, block, table, lanes)
+DECODE_KERNEL_SHAPES = {
+    "ouro-R1-16-heads-blocks-of-16": (16, 1, 128, 128, 16, 128, 16),
+    "smallthinker-R7-blocks-of-64": (4, 7, 128, 128, 64, 256, 16),
+    "laguna-R8-blocks-of-64": (8, 8, 128, 128, 64, 256, 32),
+    "jamba-R20-blocks-of-128": (1, 20, 128, 128, 128, 16, 64),
+    "ax-k1-latent-R64-rows-of-640": (1, 64, 640, None, 64, 256, 16),
+}
+
+
+@pytest.mark.parametrize("shape", list(DECODE_KERNEL_SHAPES))
+def test_the_decode_attention_kernel_compiles_for_v5e_at_the_cells_widths(v5e_chip, shape):
+    """Compile-only: `ops/attention.py`'s `paged_decode_attention` at the
+    widths the serving cells bring it, in bfloat16, by the chip's compiler:
+    its two buffers of a DMA group fit the chip's fast memory, the pool is an
+    operand as it lies (no copy of it among the temporaries)."""
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import SingleDeviceSharding
+
+    from ray_tpu.models.gpt import _NO_WINDOW
+    from ray_tpu.ops import attention
+
+    heads, R, key_row, value_row, bs, width, lanes = DECODE_KERNEL_SHAPES[shape]
+    one_chip = SingleDeviceSharding(v5e_chip)
+    arr = lambda dt, *s: jax.ShapeDtypeStruct(s, dt, sharding=one_chip)  # noqa: E731
+    dv = value_row or 512
+    pool = (3, 512, bs)
+
+    def run(q, k, v, slot, table, pos, real):
+        return attention.paged_decode_attention(
+            q, k, v, slot, table, pos, real, _NO_WINDOW, dv=dv, sm_scale=0.1)
+
+    compiled = _within(120, lambda: jax.jit(run).lower(
+        arr(jnp.bfloat16, lanes, heads, R, key_row),
+        arr(jnp.bfloat16, *pool, heads * key_row),
+        None if value_row is None else arr(jnp.bfloat16, *pool, heads * dv),
+        arr(jnp.int32), arr(jnp.int32, lanes, width), arr(jnp.int32, lanes),
+        arr(jnp.bool_, lanes)).compile())
+    assert attention.PAGED_DECODE_KERNEL in compiled.as_text()
+    layer = pool[1] * bs * heads * key_row * 2
+    assert compiled.memory_analysis().temp_size_in_bytes < layer // 2
+
+
+# ------------------------------------------- the decode step's attention kernel
+@pytest.mark.parametrize("by_lane", [False, True], ids=["gather", "kernel-rule"])
+def test_decode_programs_are_counted_on_the_programs_rule(tiny_engine_parts, by_lane):
+    """`attn_decodes_kernel` counts the decode programs whose shapes the rule
+    sends to the decode kernel, beside `decode_dispatched`; such a program's
+    keys are each real lane's own blocks (`attn_keys_run` falls, the padded
+    tables' keys do not move). The rule is stubbed on the HOST alone: the
+    tokens are the same programs'."""
+    cfg, params = tiny_engine_parts
+    eng = _make_engine(cfg, params)
+    assert eng._decode_kernel(cfg, 1, eng.opts.block_size) is False    # off the chip
+    asked = []
+    eng._decode_kernel = lambda *shapes: asked.append(shapes) or by_lane
+    import numpy as np
+
+    by_hand = [0, 0]
+    count = eng._count_attn
+
+    def counted(lanes, width, last_pos, real, first_pos=None, by_lane=False):
+        if first_pos is None:       # a decode program
+            real = np.asarray(real) & np.ones(lanes, bool)
+            by_hand[0] += int((np.asarray(last_pos)[real] // eng.opts.block_size + 1).sum()
+                              ) * eng.opts.block_size
+            by_hand[1] += lanes * width * eng.opts.block_size
+        return count(lanes, width, last_pos, real, first_pos, by_lane)
+
+    eng._count_attn = counted
+    before = eng.stats()
+    ids = [eng.submit(prompt, new) for prompt, new in MIXED]
+    _drive(eng)
+    st = eng.stats()
+    assert st["decode_dispatched"] > 10 and type(st["attn_decodes_kernel"]) is int
+    assert st["attn_decodes_kernel"] == (st["decode_dispatched"] if by_lane else 0)
+    assert asked and set(asked) == {(eng.cfg, 1, eng.opts.block_size)}
+    prefill_keys = sum(      # what the chunk programs counted, the same either way
+        -(-len(prompt) // 4) * 4 for prompt, _ in MIXED)
+    run = st["attn_keys_run"] - before["attn_keys_run"]
+    if by_lane:
+        assert run == by_hand[0] + prefill_keys
+    else:
+        assert run > by_hand[0] + prefill_keys
+    assert [len(list(eng.stream(rid))) for rid in ids] == [new for _, new in MIXED]
+
+
+def test_a_mixed_run_compiles_the_parents_programs_with_the_decode_kernel(monkeypatch):
+    """Shapes and program keys depend on (lanes, width) alone: a mixed run
+    whose decode steps take the kernel (interpret mode, traced as the chip
+    traces them) compiles one decode program a (lanes, width) bucket it met,
+    the buckets and the count those of the run that gathers, and emits its
+    tokens."""
+    import functools
+
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental.pallas import tpu as pltpu
+
+    from ray_tpu.models import gpt
+    from ray_tpu.ops import attention, norms
+    from ray_tpu.serve.engine import engine as engine_module
+
+    cfg = gpt.GPTConfig(**{**TINY, "n_heads": 2, "d_head": 128, "rotary_dim": 32},
+                        dtype=jnp.float32)
+    params = jax.tree_util.tree_map(
+        lambda a: a * 3.0, gpt.init_params(jax.random.PRNGKey(3), cfg))
+    seen = {}
+    for by_kernel in (False, True):
+        with monkeypatch.context() as mp:
+            mp.setattr(engine_module, "_JITS", None)
+            if by_kernel:
+                mp.setattr(attention, "_on_tpu", lambda: True)
+                mp.setattr(attention, "paged_decode_attention", functools.partial(
+                    attention.paged_decode_attention, interpret=pltpu.InterpretParams()))
+                mp.setattr(norms, "_rmsnorm_pallas", norms._rmsnorm_ref)
+            eng = _make_engine(cfg, params, block_size=8, num_blocks=32,
+                               prefill_chunk_tokens=8, max_step_tokens=32)
+            buckets = set()
+            ids = [eng.submit(prompt, new) for prompt, new in MIXED[:3]]
+            for plan, dispatched, _ in _walk(eng):
+                if dispatched:
+                    buckets.add((plan.batch_bucket, plan.width_bucket))
+            st = eng.stats()
+            seen[by_kernel] = (buckets, eng._decode._cache_size(), eng._prefill._cache_size(),
+                               [list(eng.stream(rid)) for rid in ids],
+                               st["attn_decodes_kernel"] == st["decode_dispatched"])
+    assert seen[True][:4] == seen[False][:4] and len(seen[True][0]) >= 3
+    assert seen[True][1] == len(seen[True][0])      # one decode program a bucket
+    assert (seen[False][4], seen[True][4]) == (False, True)
