@@ -58,6 +58,13 @@ def attention_reference(q, k, v, causal: bool = True, sm_scale: Optional[float] 
 # Causal skipping: the streamed index map clamps past-diagonal steps to the
 # last relevant block — Pallas skips the DMA when consecutive steps map to the
 # same block — and `pl.when` skips the compute.
+#
+# INSIDE a grid step the kernels walk sub-tiles (`_sub_tile`), enumerated at
+# trace time (`_walks`): a pair of sub-tiles wholly above the diagonal or
+# wholly in the padding is not emitted, a pair the diagonal or a ragged tail
+# crosses takes its mask, every other pair runs with none, and a sub-tile's
+# pairs side by side are ONE product (`_runs`). A grid step costs some
+# 0.35 us, more than a 256 x 256 pair's work, so the walk is not a finer grid.
 
 
 def _causal_last_kv(qi, block_q, block_k, row_offset, nk):
@@ -72,13 +79,173 @@ def _causal_first_q(ki, block_q, block_k, row_offset, nq):
     return jnp.clip(first, 0, nq - 1)
 
 
+def _sub_tile(kernel: str, head_dim: int) -> int:
+    """Rows and columns of a sub-tile of `kernel`'s walk inside a grid step,
+    as the chip timed the kernels alone at the train cells' shapes
+    (`scripts/flash_time.py`; PERF.md §6, PR 45). The backward kernels are
+    bound by their products: the smaller the sub-tile, the closer to the
+    triangle (128 gains 3% more and compiles four times as long). The
+    forward's Vᵀ·Pᵀ streams only head_dim rows through each tile of Pᵀ it
+    loads, and a sub-tile's fixed costs weigh more the narrower the head."""
+    if kernel == "flash_fwd" and head_dim < 128:
+        return 512
+    return 256
+
+
+def _flash_blocks(S: int, Skv: int, D: int, block_q: int, block_k: int):
+    """The grid's (block_q, block_k), clamped to the sequence: one longer than
+    the kernels' largest sub-tile pads to whole ones."""
+    t = max(_sub_tile(kernel, D) for kernel in FLASH_KERNELS)
+
+    def clamp(block, n):
+        return min(block, n if n <= t else -(-n // t) * t)
+
+    return clamp(block_q, max(S, 8)), clamp(block_k, Skv)
+
+
+def _sub_tiles(kernel: str, D: int, block_q: int, block_k: int):
+    """(tq, tk) of `kernel`'s walk: a block that whole sub-tiles do not fill
+    is its own sub-tile."""
+    t = _sub_tile(kernel, D)
+    return tuple(t if block % t == 0 else block for block in (block_q, block_k))
+
+
+def _walks(nq, nk, block_q, block_k, tq, tk, causal, seq_q, seq_kv, guard_rows):
+    """The distinct walks of a kernel's grid steps, found at trace time:
+    ({key: pairs}, whether every step has one).
+
+    A step (q block i, k block j) is keyed by all its masks depend on,
+    (shift, cols, rows): local row r sees local column c iff r - c >= shift
+    (None: the whole block is below the diagonal, or there is none), padding
+    starts at local column `cols` and, where the kernel guards rows, at local
+    row `rows` (None: none in this block). A step wholly above the diagonal
+    has no key. `pairs` lists the sub-tile pairs (a, b, mask) the step
+    computes; mask = (diag, cols, rows), local to the pair as the key is to
+    the block, None for each term the pair does not need."""
+    # When S != Skv (decode over a cached prefix) queries are END-aligned
+    # with keys, matching attention_reference's (Skv - S) offset.
+    row_offset = seq_kv - seq_q
+    keys, whole = set(), True
+    for i in range(nq):
+        for j in range(nk):
+            shift = j * block_k - i * block_q - row_offset if causal else None
+            if shift is not None and shift >= block_q:
+                whole = False
+                continue
+            if shift is not None and shift <= -(block_k - 1):
+                shift = None
+            cols = seq_kv - j * block_k if (j + 1) * block_k > seq_kv else None
+            rows = (seq_q - i * block_q
+                    if guard_rows and (i + 1) * block_q > seq_q else None)
+            keys.add((shift, cols, rows))
+    walks = {}
+    for shift, cols, rows in keys:
+        pairs = walks[shift, cols, rows] = []
+        for a in range(block_q // tq):
+            for b in range(block_k // tk):
+                d = None if shift is None else shift - a * tq + b * tk
+                c = None if cols is None else cols - b * tk
+                r = None if rows is None else rows - a * tq
+                if ((d is not None and d >= tq) or (c is not None and c <= 0)
+                        or (r is not None and r <= 0)):
+                    continue  # nothing of the pair is seen
+                pairs.append((a, b, (
+                    None if d is None or d <= -(tk - 1) else d,
+                    None if c is None or c >= tk else c,
+                    None if r is None or r >= tq else r)))
+    return walks, whole
+
+
+def _walk_step(i, j, nq, nk, block_q, block_k, tq, tk, causal, seq_q, seq_kv,
+               guard_rows, body):
+    """`body(pairs)` of the walk that grid step (i, j) has, if it has one:
+    which walk it is is a traced value, so `pl.when` picks among them."""
+    from jax.experimental import pallas as pl
+
+    walks, whole = _walks(nq, nk, block_q, block_k, tq, tk, causal, seq_q, seq_kv,
+                          guard_rows)
+    if whole and len(walks) == 1:
+        return body(*walks.values())
+    shift = j * block_k - i * block_q - (seq_kv - seq_q)
+    ragged_k = nk > 1 and any(c is not None for _, c, _ in walks)
+    ragged_q = nq > 1 and any(r is not None for _, _, r in walks)
+    for (s, c, r), pairs in walks.items():
+        terms = []
+        if causal:
+            terms.append(shift <= -(block_k - 1) if s is None else shift == s)
+        if ragged_k:
+            terms.append(j == nk - 1 if c is not None else j != nk - 1)
+        if ragged_q:
+            terms.append(i == nq - 1 if r is not None else i != nq - 1)
+        pl.when(functools.reduce(jnp.logical_and, terms))(
+            functools.partial(body, pairs))
+
+
+_NO_MASK = (None, None, None)
+
+
+def _runs(pairs, outer, n):
+    """For each of the n sub-tiles along axis `outer` (0: q, 1: k), the
+    sub-tiles of the other axis it is paired with, as runs [first, end,
+    mask]: neighbours that need no mask are ONE run, so one product."""
+    runs = [[] for _ in range(n)]
+    for pair in pairs:
+        o, i, mask = pair[outer], pair[1 - outer], pair[2]
+        run = runs[o]
+        assert not run or run[-1][1] == i, "a sub-tile's pairs lie side by side"
+        if run and mask == run[-1][2] == _NO_MASK:
+            run[-1][1] = i + 1
+        else:
+            run.append([i, i + 1, mask])
+    return runs
+
+
+def _mask_runs(cache, x, runs, t, fill, q_axis, run_axis):
+    """Scores x with each run's mask laid on its own part of axis `run_axis`
+    (the runs' sub-tiles of t), `fill` where a score is not seen. Query rows
+    lie along `q_axis`; `cache` holds the masks one body has built."""
+    pieces = []
+    for first, end, mask in runs:
+        part = slice((first - runs[0][0]) * t, (end - runs[0][0]) * t)
+        piece = x[:, part] if run_axis else x[part]
+        if mask != _NO_MASK:
+            if (piece.shape, mask) not in cache:
+                diag, cols, rows = mask
+                r = jax.lax.broadcasted_iota(jnp.int32, piece.shape, q_axis)
+                c = jax.lax.broadcasted_iota(jnp.int32, piece.shape, 1 - q_axis)
+                terms = ([r - c >= diag] if diag is not None else []) + (
+                    [c < cols] if cols is not None else []) + (
+                    [r < rows] if rows is not None else [])
+                cache[piece.shape, mask] = functools.reduce(jnp.logical_and, terms)
+            piece = jnp.where(cache[piece.shape, mask], piece, fill)
+        pieces.append(piece)
+    return pieces[0] if len(pieces) == 1 else jnp.concatenate(pieces, axis=run_axis)
+
+
+def _nt(x, y):
+    """x · yᵀ in float32: operands stay in the input dtype (bf16 runs the MXU
+    at full rate; an f32 upcast quarters matmul throughput)."""
+    return jax.lax.dot_general(x, y, (((1,), (1,)), ((), ())),
+                               preferred_element_type=jnp.float32)
+
+
+def _nn(x, y):
+    return jax.lax.dot_general(x, y, (((1,), (0,)), ((), ())),
+                               preferred_element_type=jnp.float32)
+
+
+def _tn(x, y):
+    return jax.lax.dot_general(x, y, (((0,), (0,)), ((), ())),
+                               preferred_element_type=jnp.float32)
+
+
 def _flash_fwd_kernel(
     q_ref,
     k_ref,
     v_ref,
     o_ref,
     *rest,  # ([lse_ref,] acc_ref, m_ref, l_ref) — lse only on the training path
-    block_k: int,
+    nq: int,
     nk: int,
     causal: bool,
     sm_scale: float,
@@ -101,10 +268,8 @@ def _flash_fwd_kernel(
 
     qi = pl.program_id(1)
     j = pl.program_id(2)
-    block_q = q_ref.shape[1]
-    # When S != Skv (decode over a cached prefix) queries are END-aligned
-    # with keys, matching attention_reference's (Skv - S) offset.
-    row_offset = seq_kv - seq_q
+    block_q, block_k = q_ref.shape[1], k_ref.shape[1]
+    tq, tk = _sub_tiles("flash_fwd", q_ref.shape[2], block_q, block_k)
 
     if nk != 1:
         @pl.when(j == 0)
@@ -113,104 +278,53 @@ def _flash_fwd_kernel(
             m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
             l_ref[...] = jnp.zeros_like(l_ref)
 
-    block_k_pad = k_ref.shape[1]
-    # Masking is pure VPU cost (2 iotas + 2 compares + where per element) and
-    # only EDGE blocks need it: the diagonal block (causal) and the ragged
-    # tail (padding). Interior blocks take the unmasked fast path — at long S
-    # that's nearly all of them, and the kernel is VPU-bound (VERDICT r3).
-    kv_ragged = (seq_kv % block_k_pad) != 0
-    last_kv_block = (seq_kv + block_k_pad - 1) // block_k_pad - 1
-
-    def _softmax_update(s, v_blk):
-        m_prev = m_ref[...]
-        m_cur = jnp.max(s, axis=-1, keepdims=True)  # [Bq, 1]
-        m_new = jnp.maximum(m_prev, m_cur)
-        p = jnp.exp(s - m_new)
-        alpha = jnp.exp(m_prev - m_new)
-        l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=-1, keepdims=True)
-        m_ref[...] = m_new
-        acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
-            p.astype(v_blk.dtype), v_blk, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-
-    def _logits():
-        # Keep MXU operands in the input dtype (bf16 runs the MXU at full
-        # rate; an f32 upcast quarters matmul throughput). f32 only for stats.
-        q = q_ref[0]      # [Bq, D]
-        k_blk = k_ref[0]  # [Bk, D]
-        return jax.lax.dot_general(
-            q, k_blk, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        ) * sm_scale  # [Bq, Bk] f32
-
-    # A block needs a mask iff the causal diagonal crosses it or it holds the
-    # padded tail. Below-diagonal interior blocks are fully valid.
-    if causal:
-        diag = _causal_last_kv(qi, block_q, block_k, row_offset, nk)
-        # Fully valid iff the block's last col is ≤ the q block's FIRST row —
-        # with block_k < block_q several blocks straddle the diagonal band.
-        below_band = ((j + 1) * block_k - 1) <= (row_offset + qi * block_q)
-        on_edge = jnp.logical_or(
-            jnp.logical_not(below_band),
-            jnp.logical_and(kv_ragged, j == last_kv_block),
-        )
-        in_range = j <= diag
-    else:
-        on_edge = jnp.logical_and(kv_ragged, j == last_kv_block) if kv_ragged else False
-        in_range = True
-
-    def _masked_logits():
-        s = _logits()
-        cols = j * block_k + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        valid = cols < seq_kv  # mask the zero-padded tail
-        if causal:
-            rows = (
-                row_offset + qi * block_q
-                + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
-            )
-            valid = jnp.logical_and(valid, rows >= cols)
-        return jnp.where(valid, s, _NEG_INF)
-
-    if nk == 1:
-        # Whole K/V fits one grid step (short sequences): skip the online-
-        # softmax scratch round-trips entirely — plain softmax in registers.
-        s = _masked_logits() if (causal or kv_ragged) else _logits()
-        m = jnp.max(s, axis=-1, keepdims=True)
-        p = jnp.exp(s - m)
-        l = jnp.sum(p, axis=-1, keepdims=True)
-        acc = jax.lax.dot_general(
-            p.astype(v_ref.dtype), v_ref[0], (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        o_ref[0] = (acc / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
-        if lse_ref is not None:
-            lse_ref[0, 0, pl.ds(qi * block_q, block_q)] = (
-                m + jnp.log(jnp.maximum(l, 1e-30))
-            )[:, 0]
-        return
-
-    if causal or kv_ragged:
-        @pl.when(jnp.logical_and(in_range, jnp.logical_not(on_edge)))
-        def _fast():
-            _softmax_update(_logits(), v_ref[0])
-
-        @pl.when(jnp.logical_and(in_range, on_edge))
-        def _masked():
-            _softmax_update(_masked_logits(), v_ref[0])
-    else:
-        _softmax_update(_logits(), v_ref[0])
-
-    @pl.when(j == nk - 1)
-    def _flush():
-        l = l_ref[...]
-        o_ref[0] = (acc_ref[...] / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
+    def _flush(rows, start, acc, m, l):
+        o_ref[0, rows] = (acc / jnp.maximum(l, 1e-30)).T.astype(o_ref.dtype)
         if lse_ref is not None:
             # logsumexp per row — the only softmax statistic backward needs.
             # The lse block is the full (1, 1, S_p) row; each qi writes its
             # slice, covering S_p by the time the bh block flushes.
-            lse_ref[0, 0, pl.ds(qi * block_q, block_q)] = (
-                m_ref[...] + jnp.log(jnp.maximum(l, 1e-30))
-            )[:, 0]
+            lse_ref[0, :, pl.ds(qi * block_q + start, acc.shape[1])] = (
+                m + jnp.log(jnp.maximum(l, 1e-30)))
+
+    # The scores are computed TRANSPOSED, [keys, queries]: a row's maximum and
+    # sum run ACROSS registers, not along the lanes of each (1.5 us of a
+    # head's 3.5 on the chip, PERF.md §6, PR 45). A q sub-tile's keys are ONE
+    # product and one plain softmax; only pairs an edge crosses pay a mask.
+    def _step(pairs):
+        masks = {}
+        for a, runs in enumerate(_runs(pairs, 0, block_q // tq)):
+            rows = slice(a * tq, (a + 1) * tq)
+            if not runs:  # rows that see no key of this block (Skv < S)
+                if nk == 1:
+                    _flush(rows, a * tq, jnp.zeros((q_ref.shape[2], tq), jnp.float32),
+                           jnp.full((1, tq), _NEG_INF), jnp.zeros((1, tq), jnp.float32))
+                continue
+            cols = slice(runs[0][0] * tk, runs[-1][1] * tk)
+            st = _nt(k_ref[0, cols], q_ref[0, rows]) * sm_scale  # [keys, tq] f32
+            st = _mask_runs(masks, st, runs, tk, _NEG_INF, 1, 0)
+            m = jnp.max(st, axis=0, keepdims=True)  # [1, tq]
+            if nk != 1:
+                m_prev = m_ref[:, rows]
+                m = jnp.maximum(m_prev, m)
+            pt = jnp.exp(st - m)
+            l = jnp.sum(pt, axis=0, keepdims=True)
+            acc = _tn(v_ref[0, cols], pt.astype(v_ref.dtype))  # Vᵀ·Pᵀ: [D, tq]
+            if nk == 1:
+                # One grid step a q block: its state never leaves values.
+                _flush(rows, a * tq, acc, m, l)
+            else:
+                alpha = jnp.exp(m_prev - m)
+                m_ref[:, rows] = m
+                l_ref[:, rows] = l_ref[:, rows] * alpha + l
+                acc_ref[:, rows] = acc_ref[:, rows] * alpha + acc
+
+    _walk_step(qi, j, nq, nk, block_q, block_k, tq, tk, causal, seq_q, seq_kv, False, _step)
+
+    if nk != 1:
+        @pl.when(j == nk - 1)
+        def _last():
+            _flush(slice(None), 0, acc_ref[...], m_ref[...], l_ref[...])
 
 
 def _flash_fwd_pallas(q, k, v, causal: bool, sm_scale: float, block_q: int, block_k: int,
@@ -220,8 +334,7 @@ def _flash_fwd_pallas(q, k, v, causal: bool, sm_scale: float, block_q: int, bloc
 
     B, H, S, D = q.shape
     Skv = k.shape[2]
-    block_q = min(block_q, max(S, 8))
-    block_k = min(block_k, Skv)
+    block_q, block_k = _flash_blocks(S, Skv, D, block_q, block_k)
     # Pad to block multiples (see kernel docstring for why).
     S_p = -(-S // block_q) * block_q
     Skv_p = -(-Skv // block_k) * block_k
@@ -259,7 +372,7 @@ def _flash_fwd_pallas(q, k, v, causal: bool, sm_scale: float, block_q: int, bloc
     res = pl.pallas_call(
         functools.partial(
             _flash_fwd_kernel,
-            block_k=block_k,
+            nq=nq,
             nk=nk,
             causal=causal,
             sm_scale=sm_scale,
@@ -274,10 +387,10 @@ def _flash_fwd_pallas(q, k, v, causal: bool, sm_scale: float, block_q: int, bloc
             pl.BlockSpec((1, block_k, D), kv_index),
         ],
         out_specs=tuple(out_specs),
-        scratch_shapes=[
-            pltpu.VMEM((block_q, D), jnp.float32),
-            pltpu.VMEM((block_q, 1), jnp.float32),
-            pltpu.VMEM((block_q, 1), jnp.float32),
+        scratch_shapes=[  # a q block's state across its k blocks, transposed
+            pltpu.VMEM((D, block_q), jnp.float32),
+            pltpu.VMEM((1, block_q), jnp.float32),
+            pltpu.VMEM((1, block_q), jnp.float32),
         ],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", q_dim_semantics, "arbitrary")
@@ -298,8 +411,7 @@ def _flash_fwd_pallas(q, k, v, causal: bool, sm_scale: float, block_q: int, bloc
 
 def _flash_bwd_dq_kernel(
     q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref, dq_acc_ref,
-    *, block_k: int, nk: int, causal: bool, sm_scale: float, seq_q: int,
-    seq_kv: int,
+    *, nq: int, nk: int, causal: bool, sm_scale: float, seq_q: int, seq_kv: int,
 ):
     """dQ for one q block: stream k blocks up to the causal diagonal.
 
@@ -310,56 +422,48 @@ def _flash_bwd_dq_kernel(
 
     qi = pl.program_id(1)
     j = pl.program_id(2)
-    block_q = q_ref.shape[1]
-    row_offset = seq_kv - seq_q
+    block_q, block_k = q_ref.shape[1], k_ref.shape[1]
+    tq, tk = _sub_tiles("flash_bwd_dq", q_ref.shape[2], block_q, block_k)
 
-    @pl.when(j == 0)
-    def _init():
-        dq_acc_ref[...] = jnp.zeros_like(dq_acc_ref)
+    if nk != 1:
+        @pl.when(j == 0)
+        def _init():
+            dq_acc_ref[...] = jnp.zeros_like(dq_acc_ref)
 
-    def _guard(fn):
-        if causal:
-            return pl.when(j <= _causal_last_kv(qi, block_q, block_k, row_offset, nk))(fn)
-        return fn()
+    def _step(pairs):
+        masks = {}
+        for a, runs in enumerate(_runs(pairs, 0, block_q // tq)):
+            rows = slice(a * tq, (a + 1) * tq)
+            if not runs:
+                if nk == 1:
+                    dq_ref[0, rows] = jnp.zeros((tq, q_ref.shape[2]), dq_ref.dtype)
+                continue
+            cols = slice(runs[0][0] * tk, runs[-1][1] * tk)
+            do = do_ref[0, rows]  # bf16 — MXU operands stay in input dtype
+            k_blk = k_ref[0, cols]
+            lse = lse_ref[0, 0, rows][:, None]      # [tq, 1]
+            delta = delta_ref[0, 0, rows][:, None]  # [tq, 1]
+            p = jnp.exp(_nt(q_ref[0, rows], k_blk) * sm_scale - lse)  # [tq, keys]
+            p = _mask_runs(masks, p, runs, tk, 0.0, 0, 1)
+            ds = (p * (_nt(do, v_ref[0, cols]) - delta)).astype(k_blk.dtype)
+            dq = _nn(ds, k_blk)
+            if nk == 1:
+                dq_ref[0, rows] = (dq * sm_scale).astype(dq_ref.dtype)
+            else:
+                dq_acc_ref[rows] = dq_acc_ref[rows] + dq
 
-    @_guard
-    def _body():
-        q = q_ref[0]    # bf16 — MXU operands stay in input dtype
-        do = do_ref[0]
-        lse = lse_ref[0, 0][:, None]      # [Bq, 1]
-        delta = delta_ref[0, 0][:, None]  # [Bq, 1]
-        k_blk = k_ref[0]
-        v_blk = v_ref[0]
-        s = jax.lax.dot_general(
-            q, k_blk, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        ) * sm_scale
-        cols = j * block_k + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        valid = cols < seq_kv
-        if causal:
-            rows = (
-                row_offset + qi * block_q
-                + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
-            )
-            valid = jnp.logical_and(valid, rows >= cols)
-        p = jnp.where(valid, jnp.exp(s - lse), 0.0)
-        dp = jax.lax.dot_general(
-            do, v_blk, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        )
-        ds = (p * (dp - delta)).astype(k_blk.dtype)
-        dq_acc_ref[...] = dq_acc_ref[...] + jax.lax.dot_general(
-            ds, k_blk, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
-        )
+    _walk_step(qi, j, nq, nk, block_q, block_k, tq, tk, causal, seq_q, seq_kv, False, _step)
 
-    @pl.when(j == nk - 1)
-    def _flush():
-        dq_ref[0] = (dq_acc_ref[...] * sm_scale).astype(dq_ref.dtype)
+    if nk != 1:
+        @pl.when(j == nk - 1)
+        def _flush():
+            dq_ref[0] = (dq_acc_ref[...] * sm_scale).astype(dq_ref.dtype)
 
 
 def _flash_bwd_dkv_kernel(
     q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_ref, dv_ref,
     dk_acc_ref, dv_acc_ref,
-    *, block_q: int, nq: int, causal: bool, sm_scale: float, seq_q: int,
-    seq_kv: int,
+    *, nq: int, nk: int, causal: bool, sm_scale: float, seq_q: int, seq_kv: int,
 ):
     """dK/dV for one k block: stream q blocks from the causal diagonal down.
 
@@ -369,55 +473,49 @@ def _flash_bwd_dkv_kernel(
 
     ki = pl.program_id(1)
     i = pl.program_id(2)
-    block_k = k_ref.shape[1]
-    row_offset = seq_kv - seq_q
+    block_q, block_k = q_ref.shape[1], k_ref.shape[1]
+    tq, tk = _sub_tiles("flash_bwd_dkv", q_ref.shape[2], block_q, block_k)
 
-    @pl.when(i == 0)
-    def _init():
-        dk_acc_ref[...] = jnp.zeros_like(dk_acc_ref)
-        dv_acc_ref[...] = jnp.zeros_like(dv_acc_ref)
+    if nq != 1:
+        @pl.when(i == 0)
+        def _init():
+            dk_acc_ref[...] = jnp.zeros_like(dk_acc_ref)
+            dv_acc_ref[...] = jnp.zeros_like(dv_acc_ref)
 
-    def _guard(fn):
-        if causal:
-            return pl.when(i >= _causal_first_q(ki, block_q, block_k, row_offset, nq))(fn)
-        return fn()
+    # The scores are computed TRANSPOSED, [keys, queries]: every product is
+    # then plain or contracts both operands' last axis (no [tq, tk] transpose
+    # through the XLU), and lse and Δ are rows as they lie in memory.
+    def _step(pairs):
+        masks = {}
+        for b, runs in enumerate(_runs(pairs, 1, block_k // tk)):
+            cols = slice(b * tk, (b + 1) * tk)
+            if not runs:  # padding past the last key: the caller cuts it off
+                continue
+            rows = slice(runs[0][0] * tq, runs[-1][1] * tq)
+            q_blk = q_ref[0, rows]  # bf16 — MXU operands stay in input dtype
+            do_blk = do_ref[0, rows]
+            pt = jnp.exp(_nt(k_ref[0, cols], q_blk) * sm_scale - lse_ref[0, :, rows])
+            # The mask's row term: padded q rows must not reach p (exp against
+            # a padded-row lse can overflow to inf, and inf · 0, the
+            # zero-padded dO, would make NaNs).
+            pt = _mask_runs(masks, pt, runs, tq, 0.0, 1, 1)  # [tk, queries]
+            dv = _nn(pt.astype(do_blk.dtype), do_blk)
+            dst = pt * (_nt(v_ref[0, cols], do_blk) - delta_ref[0, :, rows])
+            dk = _nn(dst.astype(q_blk.dtype), q_blk)
+            if nq == 1:
+                dk_ref[0, cols] = (dk * sm_scale).astype(dk_ref.dtype)
+                dv_ref[0, cols] = dv.astype(dv_ref.dtype)
+            else:
+                dk_acc_ref[cols] = dk_acc_ref[cols] + dk
+                dv_acc_ref[cols] = dv_acc_ref[cols] + dv
 
-    @_guard
-    def _body():
-        k = k_ref[0]  # bf16 — MXU operands stay in input dtype
-        v = v_ref[0]
-        q_blk = q_ref[0]
-        do_blk = do_ref[0]
-        lse = lse_ref[0, 0][:, None]
-        delta = delta_ref[0, 0][:, None]
-        s = jax.lax.dot_general(
-            q_blk, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        ) * sm_scale  # [Bq, Bk]
-        cols = ki * block_k + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        valid = cols < seq_kv
-        rows_abs = i * block_q + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
-        # Padded q rows must not reach p: exp against a padded-row lse can
-        # overflow to inf, and inf · 0 (zero-padded dO) would make NaNs.
-        valid = jnp.logical_and(valid, rows_abs < seq_q)
-        if causal:
-            valid = jnp.logical_and(valid, rows_abs + row_offset >= cols)
-        p = jnp.where(valid, jnp.exp(s - lse), 0.0)
-        pb = p.astype(do_blk.dtype)
-        dv_acc_ref[...] = dv_acc_ref[...] + jax.lax.dot_general(
-            pb, do_blk, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32
-        )
-        dp = jax.lax.dot_general(
-            do_blk, v, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        )
-        ds = (p * (dp - delta)).astype(q_blk.dtype)
-        dk_acc_ref[...] = dk_acc_ref[...] + jax.lax.dot_general(
-            ds, q_blk, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32
-        )
+    _walk_step(i, ki, nq, nk, block_q, block_k, tq, tk, causal, seq_q, seq_kv, True, _step)
 
-    @pl.when(i == nq - 1)
-    def _flush():
-        dk_ref[0] = (dk_acc_ref[...] * sm_scale).astype(dk_ref.dtype)
-        dv_ref[0] = dv_acc_ref[...].astype(dv_ref.dtype)
+    if nq != 1:
+        @pl.when(i == nq - 1)
+        def _flush():
+            dk_ref[0] = (dk_acc_ref[...] * sm_scale).astype(dk_ref.dtype)
+            dv_ref[0] = dv_acc_ref[...].astype(dv_ref.dtype)
 
 
 def _flash_bwd_pallas(q, k, v, o, lse, g, causal: bool, sm_scale: float,
@@ -429,8 +527,7 @@ def _flash_bwd_pallas(q, k, v, o, lse, g, causal: bool, sm_scale: float,
 
     B, H, S, D = q.shape
     Skv = k.shape[2]
-    block_q = min(block_q, max(S, 8))
-    block_k = min(block_k, Skv)
+    block_q, block_k = _flash_blocks(S, Skv, D, block_q, block_k)
     S_p = -(-S // block_q) * block_q
     Skv_p = -(-Skv // block_k) * block_k
 
@@ -455,7 +552,7 @@ def _flash_bwd_pallas(q, k, v, o, lse, g, causal: bool, sm_scale: float,
     nq = S_p // block_q
     nk = Skv_p // block_k
     row_offset = Skv - S
-    kwargs = dict(causal=causal, sm_scale=sm_scale, seq_q=S, seq_kv=Skv)
+    kwargs = dict(nq=nq, nk=nk, causal=causal, sm_scale=sm_scale, seq_q=S, seq_kv=Skv)
 
     if causal:
         def kv_index(bh, i, j):
@@ -474,7 +571,7 @@ def _flash_bwd_pallas(q, k, v, o, lse, g, causal: bool, sm_scale: float,
         return (bh, 0, q_index(bh, ki, i)[1])
 
     dq = pl.pallas_call(
-        functools.partial(_flash_bwd_dq_kernel, block_k=block_k, nk=nk, **kwargs),
+        functools.partial(_flash_bwd_dq_kernel, **kwargs),
         out_shape=jax.ShapeDtypeStruct((B * H, S_p, D), q.dtype),
         grid=(B * H, nq, nk),
         in_specs=[
@@ -500,7 +597,7 @@ def _flash_bwd_pallas(q, k, v, o, lse, g, causal: bool, sm_scale: float,
     )(qr, kr, vr, gr, lr, dr)
 
     dk, dv = pl.pallas_call(
-        functools.partial(_flash_bwd_dkv_kernel, block_q=block_q, nq=nq, **kwargs),
+        functools.partial(_flash_bwd_dkv_kernel, **kwargs),
         out_shape=(
             jax.ShapeDtypeStruct((B * H, Skv_p, D), k.dtype),
             jax.ShapeDtypeStruct((B * H, Skv_p, D), v.dtype),
@@ -1032,9 +1129,9 @@ def flash_attention(
 ):
     """Blockwise attention. Pallas on TPU; XLA reference elsewhere.
 
-    Default blocks (1024, 1024) come from v5e sweeps on an earlier
-    installation (round 5, see git history): blocks ≥2048 failed to
-    compile, and at S=1024 the single-KV-block forward beat block_k=512.
+    Default blocks (1024, 1024): blocks ≥2048 failed to compile on an
+    earlier installation, and a grid step costs more than a small block's
+    work, so the causal triangle is walked INSIDE a block (`_sub_tile`).
     What the kernels reach today is read per cell (`flash_*_roofline`,
     PERF.md). Note the D=64 head dim caps attention matmuls at ~50% MXU
     utilization (the contraction or output dim is half the 128-wide

@@ -37,22 +37,52 @@ def test_flash_pallas_interpret_matches_reference(causal):
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-3, rtol=2e-3)
 
 
+def _lse_reference(q, k, causal, scale):
+    S, Skv = q.shape[2], k.shape[2]
+    logits = jnp.einsum("bhsd,bhtd->bhst", q, k) * scale
+    if causal:
+        seen = jnp.arange(S)[:, None] + (Skv - S) >= jnp.arange(Skv)[None, :]
+        logits = jnp.where(seen, logits, -1e30)
+    return jax.nn.logsumexp(logits, axis=-1).reshape(-1, 1, S)
+
+
+# The walk inside a grid step (default blocks of 1,024, sub-tiles by the head
+# width): the two train cells' shapes, a ragged tail inside the last sub-tile,
+# a sequence shorter than one sub-tile, a diagonal that starts at no tile
+# corner (S != Skv), and no diagonal at all.
+WALK_SHAPES = [
+    (1024, 1024, True, 64, 1024),
+    (2048, 2048, True, 256, 1024),
+    (1000, 1000, True, 64, 1024),
+    (100, 100, True, 64, 1024),
+    (300, 1000, True, 64, 1024),
+    (1024, 1024, False, 64, 1024),
+    (1000, 1000, False, 64, 1024),
+    (640, 640, True, 64, 256),     # a 3 x 3 grid of blocks of one sub-tile, ragged
+]
+
+
 @pytest.mark.parametrize(
-    "S,Skv,causal",
+    "S,Skv,causal,D,block",
     [
-        (200, 200, False),  # ragged vs 128 blocks
-        (200, 200, True),
-        (1, 128, True),     # decode over cached prefix (end-aligned)
-        (64, 192, True),    # chunked prefill
+        (200, 200, False, 32, 128),  # ragged vs 128 blocks
+        (200, 200, True, 32, 128),
+        (1, 128, True, 32, 128),     # decode over cached prefix (end-aligned)
+        (64, 192, True, 32, 128),    # chunked prefill
+        *WALK_SHAPES,
     ],
 )
-def test_flash_ragged_and_decode_shapes(S, Skv, causal):
-    q = _rand(1, 2, S, 32, key=0)
-    k = _rand(1, 2, Skv, 32, key=1)
-    v = _rand(1, 2, Skv, 32, key=2)
+def test_flash_ragged_and_decode_shapes(S, Skv, causal, D, block):
+    q = _rand(1, 2, S, D, key=0)
+    k = _rand(1, 2, Skv, D, key=1)
+    v = _rand(1, 2, Skv, D, key=2)
     ref = attention_reference(q, k, v, causal)
-    out = _flash_fwd_pallas(q, k, v, causal, 32**-0.5, 128, 128, interpret=True)
+    out, lse = _flash_fwd_pallas(q, k, v, causal, D**-0.5, block, block,
+                                 interpret=True, return_lse=True)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-3, rtol=2e-3)
+    np.testing.assert_allclose(np.asarray(lse[:, :, :S]),
+                               np.asarray(_lse_reference(q, k, causal, D**-0.5)),
+                               atol=2e-3, rtol=2e-3)
 
 
 def test_flash_fallback_grad():
@@ -67,15 +97,16 @@ def test_flash_fallback_grad():
 
 
 @pytest.mark.parametrize(
-    "causal,S,Skv,D",
+    "S,Skv,causal,D,block",
     [
-        (True, 256, 256, 64),
-        (False, 256, 256, 64),
-        (True, 200, 200, 32),   # ragged vs 128 blocks
-        (True, 64, 192, 32),    # chunked prefill (end-aligned rows)
+        (256, 256, True, 64, 128),
+        (256, 256, False, 64, 128),
+        (200, 200, True, 32, 128),   # ragged vs 128 blocks
+        (64, 192, True, 32, 128),    # chunked prefill (end-aligned rows)
+        *WALK_SHAPES,
     ],
 )
-def test_flash_bwd_kernel_matches_reference(causal, S, Skv, D):
+def test_flash_bwd_kernel_matches_reference(S, Skv, causal, D, block):
     from ray_tpu.ops.attention import _flash_bwd_pallas
 
     scale = 1.0 / D**0.5
@@ -88,13 +119,82 @@ def test_flash_bwd_kernel_matches_reference(causal, S, Skv, D):
         lambda q_, k_, v_: attention_reference(q_, k_, v_, causal, scale), q, k, v
     )[1](g)
 
-    o, lse = _flash_fwd_pallas(q, k, v, causal, scale, 128, 128,
+    o, lse = _flash_fwd_pallas(q, k, v, causal, scale, block, block,
                                interpret=True, return_lse=True)
-    dq, dk, dv = _flash_bwd_pallas(q, k, v, o, lse, g, causal, scale, 128, 128,
+    dq, dk, dv = _flash_bwd_pallas(q, k, v, o, lse, g, causal, scale, block, block,
                                    interpret=True)
     for got, want in zip((dq, dk, dv), ref_grads):
         np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                    atol=2e-2, rtol=2e-2)
+
+
+@pytest.mark.parametrize(
+    "grid,tile,seqs,causal,want",
+    [
+        # one block a side at 1,024: 10 of 16 pairs, the 4 on the diagonal masked
+        ((1, 1), 256, (1024, 1024), True, {(0, None, None): (10, 4)}),
+        # a 2 x 2 grid: the diagonal blocks walk the triangle, the block below
+        # them runs whole with no mask, the one above has no walk at all
+        ((2, 2), 256, (2048, 2048), True, {(0, None, None): (10, 4), (None,) * 3: (16, 0)}),
+        # no diagonal, a ragged tail of 24 columns: every pair, the last column's masked
+        ((1, 1), 256, (1000, 1000), False, {(None, 1000, None): (16, 4)}),
+        # S != Skv: the diagonal starts 700 columns in, at no tile corner
+        ((1, 1), 256, (300, 1000), True, {(-700, 1000, None): (8, 3)}),
+    ],
+)
+def test_flash_walk_enumerates_the_pairs_at_trace_time(grid, tile, seqs, causal, want):
+    from ray_tpu.ops.attention import _NO_MASK, _walks
+
+    bq, bk = (min(1024, -(-n // tile) * tile) for n in seqs)
+    walks, whole = _walks(*grid, bq, bk, tile, tile, causal, *seqs, False)
+    got = {key: (len(pairs), sum(mask != _NO_MASK for *_, mask in pairs))
+           for key, pairs in walks.items()}
+    assert got == want
+    assert whole == (not causal or grid == (1, 1))
+
+
+@pytest.mark.parametrize("kernel", ["fwd", "dq", "dkv"])
+def test_flash_walk_skips_the_pairs_above_the_diagonal(kernel):
+    """NaN in every row that only a skipped sub-tile pair would read: a mask
+    alone does not keep it out (0 x NaN in the product behind it), a pair
+    that is never computed does. Keys and values past a q sub-tile's own
+    rows for the forward and dq; queries and dO before a k sub-tile's own
+    rows for dk and dv."""
+    from ray_tpu.ops.attention import _flash_bwd_pallas, _sub_tile
+
+    S, D = 1024, 64
+    scale = D**-0.5
+    tile = _sub_tile({"fwd": "flash_fwd", "dq": "flash_bwd_dq", "dkv": "flash_bwd_dkv"}[kernel], D)
+    assert S // tile > 1, "one sub-tile a block: nothing to skip"
+    q, k, v, g = (_rand(1, 2, S, D, key=i) for i in range(4))
+    want, vjp = jax.vjp(lambda q_, k_, v_: attention_reference(q_, k_, v_, True, scale),
+                        q, k, v)
+    want = dict(zip(("o", "dq", "dk", "dv"), (want, *vjp(g))))
+    o, lse = _flash_fwd_pallas(q, k, v, True, scale, 1024, 1024, interpret=True,
+                               return_lse=True)
+    for a in range(S // tile):
+        mine = slice(a * tile, (a + 1) * tile)
+        if kernel == "dkv":
+            poison = (jnp.arange(S) < a * tile)[:, None]
+            got = dict(zip(("dq", "dk", "dv"), _flash_bwd_pallas(
+                jnp.where(poison, jnp.nan, q), k, v, o, lse, jnp.where(poison, jnp.nan, g),
+                True, scale, 1024, 1024, interpret=True)))
+            names = ("dk", "dv")
+        else:
+            poison = (jnp.arange(S) >= (a + 1) * tile)[:, None]
+            kp, vp = jnp.where(poison, jnp.nan, k), jnp.where(poison, jnp.nan, v)
+            if kernel == "fwd":
+                got = {"o": _flash_fwd_pallas(q, kp, vp, True, scale, 1024, 1024,
+                                              interpret=True)}
+                names = ("o",)
+            else:
+                got = dict(zip(("dq", "dk", "dv"), _flash_bwd_pallas(
+                    q, kp, vp, o, lse, g, True, scale, 1024, 1024, interpret=True)))
+                names = ("dq",)
+        for name in names:
+            np.testing.assert_allclose(np.asarray(got[name][:, :, mine]),
+                                       np.asarray(want[name][:, :, mine]),
+                                       atol=2e-2, rtol=2e-2)
 
 
 @pytest.mark.parametrize("causal", [True, False])

@@ -2119,6 +2119,42 @@ def test_the_chunk_attention_kernel_compiles_for_v5e_at_the_cells_widths(v5e_chi
     assert compiled.memory_analysis().temp_size_in_bytes <= 2 * out + (1 << 20)
 
 
+# One device's call of a layer in the two train cells: [B * H, S, D]
+FLASH_KERNEL_SHAPES = {
+    "gpt2-large.train-13x20-heads-of-64": (260, 1024, 64),
+    "gptj-6b.train-fsdp4-2x16-heads-of-256": (32, 2048, 256),
+}
+
+
+@pytest.mark.parametrize("shape", list(FLASH_KERNEL_SHAPES))
+def test_the_flash_kernels_compile_for_v5e_at_the_train_cells_shapes(v5e_chip, shape):
+    """Compile-only: `ops/attention.py`'s forward and two backward kernels,
+    causal, bfloat16, default blocks, at the shapes the two train cells bring
+    them (one grid step a head of 64 at 1,024 tokens; a 2 x 2 grid a head of
+    256 at 2,048), by the chip's compiler: the unrolled walk over sub-tiles
+    lowers at both head widths, and each kernel keeps the name the
+    benchmark's `flash_*_roofline` finds it by."""
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import SingleDeviceSharding
+
+    from ray_tpu.ops import attention
+
+    bh, seq, dh = FLASH_KERNEL_SHAPES[shape]
+    one_chip = SingleDeviceSharding(v5e_chip)
+    x = jax.ShapeDtypeStruct((1, bh, seq, dh), jnp.bfloat16, sharding=one_chip)
+
+    def run(q, k, v, g):
+        o, lse = attention._flash_fwd_pallas(q, k, v, True, dh ** -0.5, 1024, 1024,
+                                             return_lse=True)
+        return o, attention._flash_bwd_pallas(q, k, v, o, lse, g, True, dh ** -0.5,
+                                              1024, 1024)
+
+    compiled = _within(240, lambda: jax.jit(run).lower(x, x, x, x).compile())
+    assert attention.flash_kernels_in(compiled.as_text()) == dict.fromkeys(
+        attention.FLASH_KERNELS, 1)
+
+
 # (K/V heads, query heads a K/V head, key row, value row or None, block, table, lanes)
 DECODE_KERNEL_SHAPES = {
     "ouro-R1-16-heads-blocks-of-16": (16, 1, 128, 128, 16, 128, 16),
