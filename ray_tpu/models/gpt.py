@@ -37,7 +37,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..ops import attention, flash_attention, layernorm, ring_attention, rmsnorm, rope_frequencies, rotate_half
+from ..ops import flash_attention, layernorm, paged_attention, ring_attention, rmsnorm, rope_frequencies, rotate_half
 from ..ops.attention import attention_reference, ulysses_attention
 from ..parallel.mesh import ShardingRules
 
@@ -1258,7 +1258,7 @@ def _attention_plain(cfg: GPTConfig, q, k, v, positions, window=None):
     return out.reshape(B, H, S, v.shape[-1])
 
 
-_NO_WINDOW = 1 << 30    # a window no sequence reaches: a global layer's
+_NO_WINDOW = paged_attention.NO_WINDOW  # a window no sequence reaches: a global layer's
 
 
 def _layer_kind_xs(cfg: GPTConfig):
@@ -2405,129 +2405,25 @@ def init_paged_cache(cfg: GPTConfig, num_blocks: int, block_size: int,
     return pool
 
 
-# Keys one trip of the paged key loop covers (`_paged_layers`). A table of
-# at most this many keys is attended in one shot, with no loop at all. Set
-# on the chip (PERF.md §6, PR 29); a constant, not a field of `GPTConfig`.
-_ATTN_TILE_KEYS = 1024
-
-
-def paged_attn_tiling(width: int, block_size: int) -> Tuple[int, int]:
-    """(blocks a tile, tiles) into which `_paged_layers` cuts a block table
-    `width` blocks wide: from static shapes alone."""
-    tile = max(1, _ATTN_TILE_KEYS // block_size)
-    return (width, 1) if width <= tile else (tile, -(-width // tile))
-
-
-def paged_attn_kernel(cfg: GPTConfig, tokens: int, width: int, block_size: int) -> bool:
-    """Whether a paged program of `tokens` tokens a lane over tables `width`
-    blocks wide runs its attention as ONE kernel (`ops/attention.py`
-    `paged_chunk_attention`) and not as the key loop: a chunk, a table wider
-    than one tile, on the chip, key and value rows of whole lane tiles. From
-    shapes alone: the program decides with it, and the host counts with it
-    (`engine_stats()`' `attn_chunks_kernel`)."""
-    return (tokens > 1 and paged_attn_tiling(width, block_size)[1] > 1
-            and attention._on_tpu() and _rows_fill_lane_tiles(cfg))
-
-
-def _rows_fill_lane_tiles(cfg: GPTConfig) -> bool:
-    """Whether a K/V head's key and value rows are whole 128-column tiles (a
-    latent model's: the padded row and the values inside it)."""
-    key_row, value_row = ((kv_layout(cfg).key_row, cfg.kv_lora_rank)
-                          if cfg.kv_lora_rank else (cfg.d_head, cfg.d_head))
-    return key_row % 128 == 0 and value_row % 128 == 0
-
-
-def paged_decode_kernel(cfg: GPTConfig, tokens: int, block_size: int) -> bool:
-    """Whether a paged program of `tokens` tokens a lane runs its attention
-    as the DECODE kernel (`ops/attention.py` `paged_decode_attention`: each
-    lane's own blocks fetched through its table, to its own length and
-    window) and not as the gather at the table's width: one token a lane,
-    on the chip, key and value rows of whole lane tiles, a block whole
-    sublane tiles of the pool's dtype. From shapes alone, whatever the
-    table's width: the program decides with it, and the host counts with it
-    (`engine_stats()`' `attn_decodes_kernel`, and what `paged_attn_keys`
-    counts for such a program)."""
-    return (tokens == 1 and attention._on_tpu() and _rows_fill_lane_tiles(cfg)
-            and block_size % (32 // jnp.dtype(cfg.dtype).itemsize) == 0)
-
-
-def paged_attn_trips(xp, first_pos, last_pos, real, window, tile_keys, tiles):
-    """Run-time bounds of the key loop over a table of `tiles` tiles of
-    `tile_keys` keys: (each lane's first tile [B], trips). Lane b's real
-    queries lie at positions first_pos[b]..last_pos[b] and see no key
-    outside tiles first[b]..first[b] + trips - 1: its last tile holds
-    last_pos[b], its first one first_pos[b] - window + 1 (tile 0 under a
-    global layer's `_NO_WINDOW`), and the trips are the most any `real`
-    lane needs. `xp` is `jax.numpy` inside the program and `numpy` on the
-    host, which counts with the same arithmetic (`paged_attn_keys`)."""
-    last = xp.minimum(last_pos // tile_keys, tiles - 1)
-    first = xp.clip((first_pos - window + 1) // tile_keys, 0, last)
-    return first, xp.where(real, last - first + 1, 1).max()
-
-
-def paged_attn_keys(lanes: int, width: int, block_size: int, last_pos, real,
-                    by_lane: bool = False):
-    """What one dispatched paged program computes attention over in a
-    global layer, counted on the host (numpy [B] arguments as
-    `paged_attn_trips` takes them): (keys its bounds cover = lanes x trips
-    x tile, keys of the padded tables = lanes x width x block_size).
-    `by_lane`: a decode program under `paged_decode_kernel`, which covers the
-    blocks each real lane's own position reaches and no other
-    (`ops.attention.paged_decode_span`, where the kernel's bounds come from)."""
-    padded = lanes * width * block_size
-    if by_lane:
-        return _lane_keys(last_pos, real, _NO_WINDOW, block_size, width), padded
-    tile, tiles = paged_attn_tiling(width, block_size)
-    _, trips = paged_attn_trips(    # no window: where a lane's queries start is moot
-        np, last_pos, last_pos, real, _NO_WINDOW, tile * block_size, tiles)
-    return lanes * int(trips) * tile * block_size, padded
-
-
-def _lane_keys(pos, real, window: int, block_size: int, width: int) -> int:
-    """Keys the decode kernel fetches in a layer of `window`, over the lanes."""
-    _, blocks = attention.paged_decode_span(
-        np, np.asarray(pos), real, window, block_size, width)
-    return int(np.sum(blocks)) * block_size
+def kv_head_rows(cfg: GPTConfig) -> Tuple[int, int, int]:
+    """(K/V heads, a head's key row, a head's value row) as attention over
+    the paged pool sees them: a latent model's ONE head (`_paged_layers`)."""
+    if cfg.kv_lora_rank:
+        return 1, kv_layout(cfg).key_row, cfg.kv_lora_rank
+    return cfg.kv_heads, cfg.d_head, cfg.d_head
 
 
 def attn_heads_by_window(cfg: GPTConfig) -> Tuple[Tuple[int, int], ...]:
     """((window or 0, query heads x passes summed over the attention layers
-    of that window), ...): what `paged_attn_head_keys` weighs keys by (the
-    engine works it out once)."""
+    of that window), ...): what the host's count of a paged program's
+    attention weighs keys by (`ops/paged_attention.py`; the engine works it
+    out once)."""
     win = (cfg.layer_kinds or (None, (0,) * cfg.n_layers))[1]
     heads: Dict[int, int] = {}
     for h, w, ssm in zip(cfg.layer_heads, win, cfg.ssm_layout or (0,) * cfg.n_layers):
         if not ssm:
             heads[w] = heads.get(w, 0) + h * cfg.ut_steps
     return tuple(heads.items())
-
-
-def paged_attn_head_keys(heads_by_window, run: int, width: int, block_size: int,
-                         first_pos, last_pos, real, by_lane: bool = False):
-    """Query heads x keys the attention of one dispatched paged program
-    covers, summed over the layers, counted on the host: (in the window
-    layers, in all layers). `run`: the keys a global layer covers, as
-    `paged_attn_keys` just counted them for the same program; a window layer
-    covers lanes x trips x tile keys under ITS window (`paged_attn_trips`; a
-    table of one tile is covered whole), so only a model with window layers
-    counts trips again. Each kind weighs by its own count of query heads
-    (`GPTConfig.layer_heads`); a looped model's layers once a pass. `by_lane`
-    as `paged_attn_keys` takes it: a window layer covers each real lane's
-    blocks from its window's first."""
-    window = every = 0
-    for w, heads in heads_by_window:
-        keys = run
-        if w and by_lane:
-            keys = _lane_keys(last_pos, real, w, block_size, width)
-        elif w:
-            tile, tiles = paged_attn_tiling(width, block_size)
-            _, trips = paged_attn_trips(
-                np, first_pos, last_pos, real, w, tile * block_size, tiles)
-            keys = np.size(last_pos) * int(trips) * tile * block_size
-        if w:
-            window += heads * keys
-        every += heads * keys
-    return window, every
 
 
 def _paged_layers(params, tokens, pos, valid, block_tables, kv, cfg: GPTConfig,
@@ -2549,32 +2445,11 @@ def _paged_layers(params, tokens, pos, valid, block_tables, kv, cfg: GPTConfig,
     tokens themselves all come back through one path. The pool rides the
     scan as its carry, indexed by the layer's slot; the stacked weights
     and the layer's kind (rotary or not, its window, its group) are the
-    xs. K/V heads are shared by groups of query heads by folding the group
-    into the query axis, so multi-head attention is the same operations
-    with a group of one.
-
-    Attention runs over the keys the step can see, not the table's padded
-    width. A table of one tile (`paged_attn_tiling`) is gathered whole and
-    soft-maxed in one shot. A wider one is a loop over key tiles with an
-    online softmax (running maximum, sum and accumulator in float32), each
-    trip gathering only its tile's blocks through the table; the loop's
-    bounds are run-time values from the step's positions and the layer's
-    window (`paged_attn_trips`: padding lanes and invalid slots take no
-    part), so shapes and program keys depend on (S, W) alone. The mask, the
-    same in both forms, decides what a query sees; the bounds only skip
-    tiles in which it is false everywhere. Where `paged_attn_kernel` says so
-    (a chunk over a wide table, on the chip) the loop is ONE kernel,
-    `ops/attention.py` `paged_chunk_attention`: the same tiles, bounds, mask
-    and online softmax, the scores and the accumulator in fast memory, the
-    table's rows gathered once before it. Where `paged_decode_kernel` says so
-    (one token a lane, on the chip) nothing is gathered at all: ONE kernel,
-    `ops/attention.py` `paged_decode_attention`, reads the pool as it lies,
-    each lane's own blocks through its table from its window's first block
-    to the block of its own position, whatever the other lanes of the bucket
-    hold and however wide the table; a padding lane fetches nothing. The
-    forms below stay as what the CPU runs, the tests' reference and the form
-    of rows that fill no lane tile (heads of 64). An expert MLP is the
-    dropless layer of `_dropless_mlp`.
+    xs. Attention runs over the keys the step can see, not the table's
+    padded width, in the form `ops/paged_attention.py` gives the program's
+    shapes (its rule, its four forms, its kernels, the fold of a K/V head's
+    query heads): `PagedAttention`, built once here and called once a
+    layer. An expert MLP is the dropless layer of `_dropless_mlp`.
 
     A looped model (`ut_steps` > 1) runs that layer scan `ut_steps` times
     in an outer scan, the pool still the carry and written in place, the
@@ -2619,11 +2494,8 @@ def _paged_layers(params, tokens, pos, valid, block_tables, kv, cfg: GPTConfig,
     B, S = tokens.shape
     W = block_tables.shape[-1]
     BS = kv["k"].shape[2]
-    H, Hkv, Dh = cfg.n_heads, cfg.kv_heads, cfg.d_head
-    Dv = Dh                                            # a value row's width a head
-    scale = 1.0 / math.sqrt(Dh)
-    if cfg.kv_lora_rank:    # ONE row a token, every head's key, its values inside
-        Hkv, Dh, Dv, scale = 1, lay.key_row, cfg.kv_lora_rank, _latent_scale(cfg)
+    Hkv, Dh, Dv = kv_head_rows(cfg)
+    scale = _latent_scale(cfg) if cfg.kv_lora_rank else 1.0 / math.sqrt(Dh)
     x = _embed(params, tokens, pos, cfg)               # [B, S, E]
     rope_tables = _rope_tables(cfg)
     blk = jnp.minimum(pos // BS, W - 1)
@@ -2633,7 +2505,9 @@ def _paged_layers(params, tokens, pos, valid, block_tables, kv, cfg: GPTConfig,
 
     phys = physical(block_tables) if G == 1 else None
     off = pos % BS
-    qpos = pos[:, None, :, None]
+    attention_over = paged_attention.PagedAttention(
+        pos, valid, block_tables, BS, Hkv, Dh, Dv, scale, kv["k"].dtype, cfg.n_heads)
+    real = attention_over.real      # [B, S]: a real lane's valid slots
     layer_stack = _layer_stack(params)
     kinds = _layer_kind_xs(cfg)
     if kinds is not None:
@@ -2641,147 +2515,32 @@ def _paged_layers(params, tokens, pos, valid, block_tables, kv, cfg: GPTConfig,
         kinds["slot"] = jnp.asarray(lay.slot_of, jnp.int32)
         if cfg.n_heads_window:      # every layer rotary, each kind by its own table
             del kinds["rope"]
-    TB, NT = paged_attn_tiling(W, BS)
-    T = TB * BS                                        # keys a tile
-    # [B, S]: a real lane's valid slots (a padding lane's table is all null)
-    real = jnp.broadcast_to(jnp.logical_and(valid, (block_tables != 0).any(
-        axis=tuple(range(1, block_tables.ndim)))[:, None]), (B, S))
-    if NT == 1:
-        kpos = jnp.arange(T)[None, None, None, :]
-        seen = kpos <= qpos                            # [B, 1, S, W*BS]
-    else:
-        first_pos = jnp.where(real, pos, _NO_WINDOW).min(axis=1)
-        last_pos = jnp.where(real, pos, 0).max(axis=1)
-        real_lane = real.any(axis=1)
-    by_kernel = paged_attn_kernel(cfg, S, W, BS)
-    by_lane = paged_decode_kernel(cfg, S, BS)
 
-    def attention_of(R):
-        """`attend` for layers of R query heads a K/V head: everything below
-        that the head count shapes. Called once, here, for a model of one head
-        count; once a kind where the window layers have their own."""
-        # [B, R*S]: the position of each folded query row
-        row_pos = jnp.tile(pos, (1, R)) if by_kernel else None
+    def attend(kk, vv, l, base, q, k, v, kind):
+        """The new rows into the pool (kk, vv) at the layer's slot (past
+        `base`: the first row of a looped model's pass, or of the scanned
+        stack behind leading dense layers), then attention over the layer's
+        table; the pool is the state. A latent pool is `kk` alone, its row
+        and the queries padded with zeros to the declared width."""
+        if q.shape[-1] < Dh:
+            q, k = (jnp.pad(a, ((0, 0),) * 3 + ((0, Dh - a.shape[-1]),)) for a in (q, k))
+        k = k.transpose(0, 2, 1, 3).reshape(B, S, Hkv * Dh)
+        if vv is not None:
+            v = v.transpose(0, 2, 1, 3).reshape(B, S, Hkv * Dh)
+        if G == 1:
+            slot, table, ph = l, block_tables, phys
+        else:
+            slot = kind["slot"]
+            table = jnp.take(block_tables, kind["group"], axis=1)
+            ph = physical(table)
+        if base is not None:
+            slot = base + slot
+        kk = kk.at[slot, ph, off].set(k.astype(kk.dtype))
+        if vv is not None:
+            vv = vv.at[slot, ph, off].set(v.astype(vv.dtype))
+        return attention_over(q, kk, vv, slot, table,
+                              None if kind is None else kind["window"]), (kk, vv)
 
-        # One query a K/V head (a decode step of a multi-head model) whose
-        # features fill whole lane tiles, OFF the chip (on it such a step takes
-        # `by_lane`'s kernel): attention as two matrix products over
-        # the gathered rows AS THE POOL LAYS THEM, [tokens, Hkv*Dh]. The scores
-        # are rows x the queries set block-diagonally ([Hkv*Dh, Hkv], head h's
-        # query in column h), the result is weights x rows ([Hkv, Hkv*Dh]) of
-        # which head h keeps its own Dh columns: the same bf16 products summed in
-        # float32 as the einsums below, the zeros adding nothing. One query row
-        # a head is no matrix product for the MXU, and what the compiler makes
-        # of that dot_general first copies the gathered rows into float32 and
-        # head-major, two more round trips of every row in every layer.
-        lone = R * S == 1 and Dh % 128 == 0
-        own_head = jnp.eye(Hkv, dtype=jnp.float32) if lone else None
-
-        def scores_of(q, kk, vv, slot, blocks, kp, seen, window):
-            """Masked float32 scores [B, Hkv, R*S, n*BS] of q [B, Hkv, R*S, Dh]
-            against the rows of `blocks` [B, n] (key positions `kp`), and those
-            blocks' V rows [B, n*BS, Hkv, Dv] ([B, n*BS, Hkv*Dh] for `lone`): of
-            a latent pool (`vv` None) the gathered key rows' first Dv columns."""
-            rows = (B, -1, Hkv * Dh) if lone else (B, -1, Hkv, Dh)
-            gk = kk[slot, blocks].reshape(rows)
-            gv = gk[..., :Dv] if vv is None else vv[slot, blocks].reshape(rows)
-            mask = seen if window is None else seen & (kp > qpos - window)
-            if R > 1:   # the R query heads of a K/V head ride its query axis
-                mask = jnp.tile(mask, (1, 1, R, 1))
-            if lone:
-                qd = q[:, :, 0, :, None] * own_head.astype(q.dtype)[None, :, None, :]
-                scores = jnp.einsum(
-                    "bte,beh->bht", gk, qd.reshape(B, Hkv * Dh, Hkv),
-                    preferred_element_type=jnp.float32)[:, :, None] * scale
-            else:
-                scores = jnp.einsum(
-                    "bhsd,bthd->bhst", q, gk, preferred_element_type=jnp.float32
-                ) * scale
-            return jnp.where(mask, scores, -1e30), gv
-
-        def mixed(p, gv, out_dtype=None):
-            """Weights p [B, Hkv, R*S, T] f32 over the V rows `scores_of` gave
-            -> [B, Hkv, R*S, Dh] in `out_dtype` (the rows' own if None)."""
-            if lone:
-                every = jnp.einsum("bht,bte->bhe", p[:, :, 0].astype(gv.dtype), gv,
-                                   preferred_element_type=jnp.float32)
-                out = (every.reshape(B, Hkv, Hkv, Dh)
-                       * own_head[None, :, :, None]).sum(axis=2)
-                return out[:, :, None].astype(out_dtype or gv.dtype)
-            return jnp.einsum("bhst,bthd->bhsd", p.astype(gv.dtype), gv,
-                              preferred_element_type=out_dtype)
-
-        def gathered(q, kk, vv, slot, table, window):
-            """Attention of q [B, Hkv, R*S, Dh] over the rows `table` names."""
-            if by_lane:     # the pool as it lies: each lane's own blocks
-                return attention.paged_decode_attention(
-                    q, kk, vv, slot, table, pos[:, 0], real[:, 0],
-                    _NO_WINDOW if window is None else window, dv=Dv, sm_scale=scale)
-            if NT == 1:
-                scores, gv = scores_of(q, kk, vv, slot, table, kpos, seen, window)
-                probs = jax.nn.softmax(scores, axis=-1)
-                return mixed(probs, gv)
-            reach = _NO_WINDOW if window is None else window
-            first, trips = paged_attn_trips(
-                jnp, first_pos, last_pos, real_lane, reach, T, NT)
-            table = jnp.pad(table, ((0, 0), (0, NT * TB - W)))
-            if by_kernel:   # the table's rows gathered once, densely: 0.05 ms a layer
-                return attention.paged_chunk_attention(
-                    q, kk[slot, table].reshape(B, NT * T, Hkv * Dh),
-                    None if vv is None else vv[slot, table].reshape(B, NT * T, Hkv * Dv),
-                    row_pos, first, trips, reach, tile_keys=T, dv=Dv, sm_scale=scale)
-
-            def trip(j, carry):
-                m, l, acc = carry
-                tile = first + j                           # [B]; past the table: masked
-                cols = jnp.minimum(tile, NT - 1)[:, None] * TB + jnp.arange(TB)
-                kp = (tile[:, None] * T + jnp.arange(T))[:, None, None, :]
-                scores, gv = scores_of(
-                    q, kk, vv, slot, jnp.take_along_axis(table, cols, axis=1),
-                    kp, kp <= qpos, window)
-                m_new = jnp.maximum(m, scores.max(axis=-1))
-                p = jnp.exp(scores - m_new[..., None])
-                fade = jnp.exp(m - m_new)
-                acc = acc * fade[..., None] + mixed(p, gv, jnp.float32)
-                return m_new, l * fade + p.sum(axis=-1), acc
-
-            rows = q.shape[:3]
-            _, l, acc = jax.lax.fori_loop(0, trips, trip, (
-                jnp.full(rows, -1e30, jnp.float32), jnp.zeros(rows, jnp.float32),
-                jnp.zeros(rows + (Dv,), jnp.float32)))
-            return (acc / l[..., None]).astype(kk.dtype)
-
-        def attend(kk, vv, l, base, q, k, v, kind):
-            """The new rows into the pool (kk, vv) at the layer's slot (past
-            `base`: the first row of a looped model's pass, or of the scanned
-            stack behind leading dense layers), then attention over the layer's
-            table; the pool is the state. A latent pool is `kk` alone, its row
-            and the queries padded with zeros to the declared width."""
-            if q.shape[-1] < Dh:
-                q, k = (jnp.pad(a, ((0, 0),) * 3 + ((0, Dh - a.shape[-1]),)) for a in (q, k))
-            k = k.transpose(0, 2, 1, 3).reshape(B, S, Hkv * Dh)
-            if vv is not None:
-                v = v.transpose(0, 2, 1, 3).reshape(B, S, Hkv * Dh)
-            if G == 1:
-                slot, table, ph = l, block_tables, phys
-            else:
-                slot = kind["slot"]
-                table = jnp.take(block_tables, kind["group"], axis=1)
-                ph = physical(table)
-            if base is not None:
-                slot = base + slot
-            kk = kk.at[slot, ph, off].set(k.astype(kk.dtype))
-            if vv is not None:
-                vv = vv.at[slot, ph, off].set(v.astype(vv.dtype))
-            if R > 1:   # the R query heads of a K/V head ride its query axis
-                q = q.reshape(B, Hkv, R * S, Dh)
-            attn = gathered(q, kk, vv, slot, table,
-                            None if kind is None else kind["window"])
-            return attn.reshape(B, R * Hkv, S, Dv) if R > 1 else attn, (kk, vv)
-
-        return attend
-
-    attend = attention_of(H // Hkv)
 
     stacks = _pop_expert_stacks(cfg, layer_stack)
 
@@ -2859,14 +2618,14 @@ def _paged_layers(params, tokens, pos, valid, block_tables, kv, cfg: GPTConfig,
             jnp.arange(cfg.dense_layers), _lead_stack(params)))
     if cfg.n_heads_window:
         # Window layers with shapes of their own: each kind through `_block`
-        # under its own config, rotary table and `attend`, a layer's table
+        # under its own config and rotary table, a layer's table
         # and pool row by its group and slot, the routing's load summed in
         # the carry (`_mixed_layers` hands nothing else on).
         wcfg, D = _window_cfg(cfg), cfg.dense_layers
-        by_kind = ((cfg, rope_tables, attend),
-                   (wcfg, _rope_tables(wcfg), attention_of(cfg.n_heads_window // Hkv)))
+        by_kind = ((cfg, rope_tables), (wcfg, _rope_tables(wcfg)))
+        attention_over.fold(cfg.n_heads_window)
 
-        def layer_of(kind_cfg, tables, attend):
+        def layer_of(kind_cfg, tables):
             def layer(carry, l, i, p):
                 x, kk, vv, loads = carry
                 x, (kk, vv), _, load = _block(

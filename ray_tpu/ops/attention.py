@@ -637,369 +637,6 @@ def _flash_bwd_pallas(q, k, v, o, lse, g, causal: bool, sm_scale: float,
     return dq, dk, dv
 
 
-# ----------------------------------------------------- paged chunk kernel
-#
-# A prefill chunk's attention over a paged table wider than one key tile
-# (`models/gpt.py` `_paged_layers`): the flash forward's tiling and online
-# softmax with the run-time bounds of the paged key loop. A tile of query rows
-# keeps its running maximum, sum and accumulator in VMEM across all key
-# tiles; the scores never reach HBM; the mask is made from positions a tile
-# at a time.
-
-PAGED_CHUNK_KERNEL = "paged_chunk_attn"
-_CHUNK_Q_ROWS = 1024    # query rows a grid step holds (PERF.md §6, PR 41)
-
-
-def _chunk_q_tile(rows: int) -> int:
-    """Query rows a grid step: the largest whole number of sublane tiles of
-    at most `_CHUNK_Q_ROWS` rows that divides `rows` (a multiple of 16)."""
-    return max(d for d in range(16, min(rows, _CHUNK_Q_ROWS) + 1, 16) if rows % d == 0)
-
-
-def _paged_chunk_kernel(first_ref, trips_ref, window_ref, qpos_ref, q_ref, k_ref,
-                        *rest, tile_keys: int, nt: int, dv: int, sm_scale: float):
-    """One (query tile, key tile) grid step. Scalar prefetch: each lane's
-    first key tile, the step's trips, the layer's window. `rest` is ([v_ref,]
-    o_ref, acc, m, l): without a value operand the key rows' first `dv`
-    columns are the values (a latent pool)."""
-    from jax.experimental import pallas as pl
-
-    v_ref = rest[0] if len(rest) == 5 else None
-    o_ref, acc_ref, m_ref, l_ref = rest[-4:]
-    j = pl.program_id(3)
-    tile = first_ref[pl.program_id(0)] + j
-
-    @pl.when(j == 0)
-    def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-        m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
-
-    @pl.when(j < trips_ref[0])
-    def _tile():
-        k = k_ref[...]                                      # [T, Dh]
-        s = jax.lax.dot_general(
-            q_ref[...], k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * sm_scale  # [Tq, T] f32
-        # a tile past the table is the last one again under positions no
-        # query reaches: masked whole, as in the plain loop
-        kp = tile * tile_keys + jax.lax.broadcasted_iota(
-            jnp.int32, (1, tile_keys), 1)
-        qp = qpos_ref[...]                                  # [Tq, 1]
-        seen = jnp.logical_and(kp <= qp, kp > qp - window_ref[0])
-        s = jnp.where(seen, s, _NEG_INF)
-        m_prev = m_ref[...]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-        p = jnp.exp(s - m_new)
-        fade = jnp.exp(m_prev - m_new)
-        l_ref[...] = l_ref[...] * fade + jnp.sum(p, axis=-1, keepdims=True)
-        m_ref[...] = m_new
-        v = k[:, :dv] if v_ref is None else v_ref[...]
-        acc_ref[...] = acc_ref[...] * fade + jax.lax.dot_general(
-            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-
-    @pl.when(j == nt - 1)
-    def _flush():
-        o_ref[...] = (acc_ref[...] / l_ref[...]).astype(o_ref.dtype)
-
-
-def paged_chunk_attention(q, keys, values, qpos, first, trips, window, *,
-                          tile_keys: int, dv: int, sm_scale: float,
-                          interpret: bool = False):
-    """Causal (and windowed) attention of a chunk's folded query rows over
-    the rows of its table, gathered densely: q [B, Hkv, rows, Dh] (row i of
-    lane b at position qpos[b, i]); keys [B, NT * tile_keys, Hkv * Dh] as the
-    pool lays them, values [B, NT * tile_keys, Hkv * dv] or None (a latent
-    pool: the key rows' first `dv` columns); first [B] int32 and trips
-    (`paged_attn_trips`): lane b attends key tiles first[b] .. first[b] +
-    trips - 1 and no other is fetched or computed; window an int32 scalar (a
-    global layer's is a window no sequence reaches). Query row i sees key
-    position p where qpos - window < p <= qpos. bf16 products summed in
-    float32, float32 online softmax -> [B, Hkv, rows, dv] in the keys' dtype.
-    Dh and dv fill whole lane tiles."""
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-
-    B, Hkv, rows, Dh = q.shape
-    nt = keys.shape[1] // tile_keys
-    rows_p = -(-rows // 16) * 16
-    if rows_p != rows:
-        q = jnp.pad(q, ((0, 0), (0, 0), (0, rows_p - rows), (0, 0)))
-        qpos = jnp.pad(qpos, ((0, 0), (0, rows_p - rows)))
-    tq = _chunk_q_tile(rows_p)
-
-    def key_tile(b, h, i, j, first, trips, window):
-        # past the trips: the last tile fetched again, which is no fetch
-        return (b, jnp.minimum(first[b] + jnp.minimum(j, trips[0] - 1), nt - 1), h)
-
-    def query_tile(b, h, i, j, *_):
-        return (b, h, i, 0)
-
-    operands = [qpos[..., None], q, keys]
-    in_specs = [pl.BlockSpec((None, tq, 1), lambda b, h, i, j, *_: (b, i, 0)),
-                pl.BlockSpec((None, None, tq, Dh), query_tile),
-                pl.BlockSpec((None, tile_keys, Dh), key_tile)]
-    if values is not None:
-        operands.append(values)
-        in_specs.append(pl.BlockSpec((None, tile_keys, dv), key_tile))
-    out = pl.pallas_call(
-        functools.partial(_paged_chunk_kernel, tile_keys=tile_keys, nt=nt, dv=dv,
-                          sm_scale=sm_scale),
-        out_shape=jax.ShapeDtypeStruct((B, Hkv, rows_p, dv), keys.dtype),
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=3, grid=(B, Hkv, rows_p // tq, nt),
-            in_specs=in_specs,
-            out_specs=pl.BlockSpec((None, None, tq, dv), query_tile),
-            scratch_shapes=[pltpu.VMEM((tq, dv), jnp.float32),
-                            pltpu.VMEM((tq, 1), jnp.float32),
-                            pltpu.VMEM((tq, 1), jnp.float32)]),
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"),
-            vmem_limit_bytes=64 << 20),
-        interpret=interpret, name=PAGED_CHUNK_KERNEL,
-    )(first.astype(jnp.int32), jnp.asarray(trips, jnp.int32).reshape(1),
-      jnp.asarray(window, jnp.int32).reshape(1), *operands)
-    return out[:, :, :rows]
-
-
-# ---------------------------------------------------- paged decode kernel
-#
-# A decode step's attention (`models/gpt.py` `_paged_layers`, one token a
-# lane): the pool stays in HBM as it lies and a lane's program fetches ITS
-# blocks through its table, from its window's first block to the block of its
-# own position, a group of blocks a double-buffered DMA; scores and the online
-# softmax's state in VMEM. A padding lane fetches nothing. The multi-page
-# copy scheme is `jax.experimental.pallas.ops.tpu.paged_attention`'s; the
-# layout (whole block rows, every K/V head at once, the R query heads of a
-# K/V head as R rows), the window and the latent pool are this repo's.
-
-PAGED_DECODE_KERNEL = "paged_decode_attn"
-# A DMA group: at most this many bytes of key and value rows and at most this
-# many keys (narrow rows: a group is multiplied whole, however few of its keys
-# a short lane holds). Set on the chip (PERF.md §6, PR 44).
-_DECODE_GROUP_BYTES = 1 << 20
-_DECODE_GROUP_KEYS = 512
-# Tables reach the kernel padded to ONE width a lane count, as wide as the pool
-# has blocks or as a scalar operand of this many bytes holds: the kernel is
-# then the same for every table width a server warms, and those programs
-# share one trace of it (`_paged_decode_call` is jitted for that).
-_DECODE_TABLE_BYTES = 32 << 10
-
-
-def paged_decode_span(xp, pos, real, window, block_size: int, width: int):
-    """(first block, blocks) of its table that a decode lane at position `pos`
-    attends under `window` (a global layer's: one no sequence reaches): from
-    the block of pos - window + 1 to the block of pos, and none for a lane
-    that is not `real`. `xp` is `jax.numpy` in the program, which hands the
-    kernel the step's [B] of each as scalar operands, and `numpy` on the host,
-    which counts keys with the same arithmetic (`models.gpt.paged_attn_keys`)."""
-    last = xp.minimum(pos // block_size, width - 1)
-    first = xp.minimum(xp.maximum(pos - window + 1, 0) // block_size, last)
-    return first, xp.where(real, last - first + 1, 0)
-
-
-def _paged_decode_kernel(slot_ref, table_ref, first_ref, blocks_ref, pos_ref, window_ref,
-                         q_ref, k_hbm, *rest, bs: int, gb: int, width: int,
-                         dv: int, sm_scale: float):
-    """One lane. Scalar prefetch: the layer's pool slot, the tables [B * W],
-    each lane's first block and blocks (`paged_decode_span`), its position,
-    the layer's window. `q_ref` [M, key row]: the lane's query rows, each in
-    its own K/V head's columns. `rest` is ([v_hbm,] o_ref, kbuf, [vbuf,] sem,
-    turn, m, l, acc): without a value pool the key rows' first `dv` columns
-    are the values (a latent pool). While a lane's last group is multiplied
-    the NEXT lane's first is on its way: `turn` carries, from lane to lane,
-    which of the two buffers that group is in."""
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-
-    latent = len(rest) == 7
-    if latent:
-        (o_ref, kbuf, *scratch), v_hbm, vbuf = rest, None, None
-    else:
-        v_hbm, o_ref, kbuf, vbuf, *scratch = rest
-    sem, turn, m_ref, l_ref, acc_ref = scratch
-    b, lanes = pl.program_id(0), pl.num_programs(0)
-    window, slot = window_ref[0], slot_ref[0]
-    # The body is traced once a lane count and lowered in every decode program
-    # a server warms, inside its set-up, so it is written to be cheap there:
-    # `lax` called by name (an operator on a traced value, like a `jax.numpy`
-    # call, is a dispatch of its own, five times a `lax` call's cost), and ONE
-    # branch (a padding lane's loops run no trip, a copy nobody is to start
-    # is a loop of no block).
-    lax = jax.lax
-    add, sub, mul = lax.add, lax.sub, lax.mul
-
-    def wide(col, like):        # [M, 1] beside [M, n]
-        return lax.broadcast_in_dim(col, like.shape, (0, 1))
-
-    def held(lane, g):          # blocks of the lane's group g
-        return lax.min(sub(blocks_ref[lane], mul(g, gb)), gb)
-
-    def each(lane, g, buf, blocks, act):
-        """`act` on the copy of each of the first `blocks` blocks of the
-        lane's group g: a start and its wait name the same copies."""
-        entry = add(add(mul(lane, width), first_ref[lane]), mul(g, gb))
-
-        def block(i, _):
-            phys = table_ref[add(entry, i)]
-            act(pltpu.make_async_copy(
-                k_hbm.at[slot, phys], kbuf.at[buf, i], sem.at[buf, 0]))
-            if not latent:
-                act(pltpu.make_async_copy(
-                    v_hbm.at[slot, phys], vbuf.at[buf, i], sem.at[buf, 1]))
-            return 0
-
-        lax.fori_loop(0, blocks, block, 0)
-
-    def start(copy):
-        copy.start()
-
-    def wait(copy):
-        copy.wait()
-
-    @pl.when(lax.eq(b, 0))
-    def _first_lane():      # rows no copy has written yet are multiplied under a weight of 0
-        for rows in [kbuf] if latent else [kbuf, vbuf]:
-            rows[...] = lax.full(rows.shape, 0, rows.dtype)
-        turn[0] = 0
-
-    first, blocks, pos, buf0 = first_ref[b], blocks_ref[b], pos_ref[b], turn[0]
-    groups = lax.div(add(blocks, gb - 1), gb)
-    after = lax.min(add(b, 1), sub(lanes, 1))
-    last_lane = lax.eq(add(b, 1), lanes)
-    # a lane before this one sent for its first group; else it does, here
-    sent = lax.bitwise_and(lax.gt(b, 0), lax.gt(blocks_ref[lax.max(sub(b, 1), 0)], 0))
-    each(b, 0, buf0, lax.select(sent, 0, lax.min(blocks, gb)), start)
-    m_ref[...] = lax.full(m_ref.shape, _NEG_INF, m_ref.dtype)
-    l_ref[...] = lax.full(l_ref.shape, 0, l_ref.dtype)
-    acc_ref[...] = lax.full(acc_ref.shape, 0, acc_ref.dtype)
-
-    def group(g, _):
-        buf = lax.rem(add(buf0, g), 2)
-        # on its way while this group is multiplied: the lane's next group,
-        # or behind its last the next lane's first (of no block: a padding lane)
-        more = lax.lt(add(g, 1), groups)
-        lane, nxt = lax.select(more, b, after), lax.select(more, add(g, 1), 0)
-        each(lane, nxt, sub(1, buf), lax.select(
-            lax.bitwise_and(lax.bitwise_not(more), last_lane), 0, held(lane, nxt)), start)
-        each(b, g, buf, held(b, g), wait)
-        k = lax.reshape(kbuf[buf], (gb * bs, kbuf.shape[-1]))          # [T, key row]
-        s = mul(lax.dot_general(q_ref[...], k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32),
-                jnp.float32(sm_scale))                                  # [M, T] f32
-        kp = add(lax.broadcasted_iota(jnp.int32, (1, gb * bs), 1),
-                 mul(add(first, mul(g, gb)), bs))
-        seen = lax.bitwise_and(lax.le(kp, pos), lax.gt(kp, sub(pos, window)))
-        s = lax.select(wide(seen, s), s, lax.full(s.shape, _NEG_INF, s.dtype))
-        m_prev = m_ref[...]
-        m_new = lax.max(m_prev, lax.reduce_max(s, (1,))[:, None])
-        p = lax.exp(sub(s, wide(m_new, s)))
-        fade = lax.exp(sub(m_prev, m_new))
-        l_ref[...] = add(mul(l_ref[...], fade), lax.reduce_sum(p, (1,))[:, None])
-        m_ref[...] = m_new
-        v = (lax.slice_in_dim(k, 0, dv, axis=1) if latent
-             else lax.reshape(vbuf[buf], (gb * bs, vbuf.shape[-1])))
-        acc = acc_ref[...]
-        acc_ref[...] = add(mul(acc, wide(fade, acc)), lax.dot_general(
-            lax.convert_element_type(p, v.dtype), v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32))
-        return 0
-
-    lax.fori_loop(0, groups, group, 0)
-    turn[0] = lax.rem(add(buf0, groups), 2)
-    # (a padding lane's sum is 0 over an accumulator of 0: it reads 0)
-    acc = acc_ref[...]
-    o_ref[...] = lax.convert_element_type(lax.div(acc, wide(lax.max(
-        l_ref[...], lax.full(l_ref.shape, 1e-30, l_ref.dtype)), acc)), o_ref.dtype)
-
-
-def paged_decode_attention(q, keys, values, slot, table, pos, real, window, *,
-                           dv: int, sm_scale: float, interpret=False):
-    """Attention of one query token a lane over the rows its table names in
-    the pool AS IT LIES: q [B, Hkv, R, Dh] (the R query heads of a K/V head as
-    R rows), lane b at position pos[b]; keys [depth, NB, BS, Hkv * Dh], values
-    [depth, NB, BS, Hkv * dv] or None (a latent pool: the key rows' first `dv`
-    columns), read at the layer's `slot`; table [B, W] int32; real [B] bool;
-    window an int32 scalar (a global layer's is a window no sequence
-    reaches). Lane b sees key position p where pos[b] - window < p <= pos[b],
-    and only the blocks that hold such keys are fetched
-    (`paged_decode_span`); a lane that is not real fetches none and reads 0.
-
-    A lane's program takes whole block rows, every K/V head at once, and
-    multiplies them as they lie: its Hkv x R query rows are laid out each in
-    its own head's Dh columns of a key row, zeros elsewhere, so that ONE
-    product a group of blocks gives every head's scores ([Hkv * R, keys]) and
-    one more every head's weighted values, of which a head keeps its own dv
-    columns: the products a head at a time would sum, the zeros adding
-    nothing, in two matrix products the matrix units share instead of 2 x Hkv
-    small ones in a row. bf16 products summed in float32, float32 online
-    softmax -> [B, Hkv, R, dv] in the pool's dtype. Dh and dv fill whole lane
-    tiles, a block whole sublane tiles."""
-    B, width = table.shape
-    nb, bs = keys.shape[1:3]
-    window = jnp.asarray(window, jnp.int32)
-    first, blocks = paged_decode_span(jnp, pos, real, window, bs, width)
-    wide = max(width, min(nb, _DECODE_TABLE_BYTES // (4 * B)))
-    block_bytes = bs * sum(pool.shape[-1] * pool.dtype.itemsize
-                           for pool in (keys, values) if pool is not None)
-    gb = max(1, min(wide, _DECODE_GROUP_BYTES // block_bytes,      # blocks a group
-                    _DECODE_GROUP_KEYS // bs))
-    return _paged_decode_call(
-        q, keys, values, jnp.asarray(slot, jnp.int32),
-        jnp.pad(table.astype(jnp.int32), ((0, 0), (0, wide - width))),
-        first.astype(jnp.int32), blocks.astype(jnp.int32), pos.astype(jnp.int32), window,
-        gb=gb, dv=dv, sm_scale=sm_scale, interpret=interpret)
-
-
-@functools.partial(jax.jit, static_argnames=("gb", "dv", "sm_scale", "interpret"))
-def _paged_decode_call(q, keys, values, slot, table, first, blocks, pos, window, *,
-                       gb: int, dv: int, sm_scale: float, interpret):
-    """`paged_decode_attention` behind its scalar operands: the rows laid out,
-    the kernel over the lanes, each head's own columns kept. Jitted on its own
-    so that the programs of a server (and the layer kinds of a program) that
-    bring it the same shapes share its trace."""
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-
-    B, heads, R, dh = q.shape
-    bs, width = keys.shape[2], table.shape[1]
-    pools = [keys] if values is None else [keys, values]
-    M = heads * R
-    mp = -(-M // 8) * 8
-    # row (h, r) holds q[h, r] in columns h * Dh .. of a key row
-    own = jnp.eye(heads, dtype=q.dtype)
-    rows = (q[:, :, :, None, :] * own[None, :, None, :, None]).reshape(B, M, heads * dh)
-    rows = jnp.pad(rows.astype(keys.dtype), ((0, 0), (0, mp - M), (0, 0)))
-    buffers = [pltpu.VMEM((2, gb, bs, pool.shape[-1]), pool.dtype) for pool in pools]
-
-    def lane(b, *_):
-        return (b, 0, 0)
-
-    out = pl.pallas_call(
-        functools.partial(_paged_decode_kernel, bs=bs, gb=gb, width=width,
-                          dv=heads * dv, sm_scale=sm_scale),
-        out_shape=jax.ShapeDtypeStruct((B, mp, heads * dv), keys.dtype),
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=6, grid=(B,),
-            in_specs=[pl.BlockSpec((None, mp, heads * dh), lane)]
-            + [pl.BlockSpec(memory_space=pl.ANY)] * len(pools),
-            out_specs=pl.BlockSpec((None, mp, heads * dv), lane),
-            scratch_shapes=[*buffers, pltpu.SemaphoreType.DMA((2, len(pools))),
-                            pltpu.SMEM((1,), jnp.int32),
-                            pltpu.VMEM((mp, 1), jnp.float32),
-                            pltpu.VMEM((mp, 1), jnp.float32),
-                            pltpu.VMEM((mp, heads * dv), jnp.float32)]),
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("arbitrary",), vmem_limit_bytes=64 << 20),
-        interpret=interpret, name=PAGED_DECODE_KERNEL,
-    )(slot.reshape(1), table.reshape(-1), first, blocks, pos, window.reshape(1),
-      rows, *pools)
-    # head h's own dv columns of its R rows
-    out = out[:, :M].reshape(B, heads, R, heads, dv)
-    return jnp.einsum("bhrhd->bhrd", out) if heads > 1 else out[:, :, :, 0]
-
-
 def _on_tpu() -> bool:
     return jax.default_backend() == "tpu"
 

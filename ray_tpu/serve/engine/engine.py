@@ -92,7 +92,7 @@ _STEP_BOOKS = (
     "kv_block_held_ns", "kv_window_block_ns",
     # Query heads x keys the dispatched programs' attention covered, summed
     # over the layers, each under its own window and head count
-    # (`models.gpt.paged_attn_head_keys`), and of them the window layers'.
+    # (`ops.paged_attention.paged_attn_cover`), and of them the window layers'.
     "attn_head_keys", "attn_head_keys_window",
 )
 
@@ -364,10 +364,9 @@ class InferenceEngine:
         import jax
 
         from ...models.gpt import (
-            attn_heads_by_window, init_paged_cache, init_params, kv_layout,
-            paged_attn_head_keys, paged_attn_kernel, paged_attn_keys,
-            paged_decode_kernel,
+            attn_heads_by_window, init_paged_cache, init_params, kv_head_rows, kv_layout,
         )
+        from ...ops import paged_attention
 
         self.cfg = dataclasses.replace(cfg, remat=False, remat_policy=None)
         self.opts = options or EngineOptions()
@@ -506,19 +505,17 @@ class InferenceEngine:
         self.total_decode_chained = 0
         self._step_chained = 0
         # Keys the dispatched programs' attention covered in a global layer
-        # and keys of their padded tables (`models.gpt.paged_attn_keys`).
-        self._attn_keys = paged_attn_keys
-        self._attn_head_keys = paged_attn_head_keys
+        # and keys of their padded tables, counted in the form the programs'
+        # own rule gives their shapes (`ops/paged_attention.py`, `_count_attn`).
+        self._paged_attention = paged_attention
         self._attn_heads = attn_heads_by_window(self.cfg)
-        self.total_attn_keys = [0, 0]
-        # Prefill chunk programs dispatched and, of them, those whose shapes
-        # send their attention to the chunk kernel: the program's own rule.
-        self._attn_kernel = paged_attn_kernel
+        self._attn_shapes = (    # what the rule asks beside tokens and width
+            self.opts.block_size, *kv_head_rows(self.cfg)[1:], self.cfg.dtype)
+        self.total_attn_covered = [0, 0]
+        # Prefill chunk programs dispatched and, of them, those whose form is
+        # the chunk kernel; decode programs whose form is the decode kernel
+        # (each lane's own blocks through its table).
         self.total_attn_chunks = [0, 0]
-        # Decode programs whose shapes send their attention to the decode
-        # kernel (each lane's own blocks through its table): the program's
-        # own rule, which also says what such a program's keys count.
-        self._decode_kernel = paged_decode_kernel
         self.total_attn_decodes_kernel = 0
         self._step_attn = [0, 0]
         # Expert routing: (experts touched, busiest expert's share) of the
@@ -1128,24 +1125,24 @@ class InferenceEngine:
             except Exception as e:  # noqa: BLE001 — fail the waiter, not the loop
                 fut.set_exception(e)
 
-    def _count_attn(self, lanes: int, width: int, last_pos, real, first_pos=None,
-                    by_lane: bool = False):
-        """Add one program's (keys run, keys padded) to the step's and the
-        engine's counts, and its heads x keys by layer kind to the books, from
-        the helpers its own loop bounds come from. `first_pos`: each lane's
-        first query, where it is not its last (a chunk). `by_lane`: a decode
-        program whose attention is the decode kernel's, each real lane's own
-        blocks under the layer's window."""
-        run, padded = self._attn_keys(
-            lanes, width, self.opts.block_size, last_pos, real, by_lane)
-        for count in (self._step_attn, self.total_attn_keys):
+    def _count_attn(self, tokens: int, width: int, last_pos, real, first_pos=None) -> str:
+        """Ask the programs' own rule, once, for the form of the attention of
+        a program of `tokens` tokens a lane over tables `width` blocks wide,
+        add its (keys run, keys padded) to the step's and the engine's counts
+        and its heads x keys by layer kind to the books, from the arithmetic
+        its own bounds come from, and return the form. `first_pos`: each
+        lane's first query, where it is not its last (a chunk)."""
+        form = self._paged_attention.paged_attn_form(
+            tokens, width, *self._attn_shapes)
+        run, padded, window, every = self._paged_attention.paged_attn_cover(
+            form, self._attn_heads, width, self.opts.block_size,
+            last_pos if first_pos is None else first_pos, last_pos, real)
+        for count in (self._step_attn, self.total_attn_covered):
             count[0] += run
             count[1] += padded
-        window, every = self._attn_head_keys(
-            self._attn_heads, run, width, self.opts.block_size,
-            last_pos if first_pos is None else first_pos, last_pos, real, by_lane)
         self._books["attn_head_keys_window"] += window
         self._books["attn_head_keys"] += every
+        return form
 
     def _count_state(self, tokens: int, real: int, decode: bool):
         """Add one program of a model with state to its books: `tokens` the
@@ -1206,11 +1203,10 @@ class InferenceEngine:
             tokens[0, :L] = seq.prompt[chunk.start:chunk.start + L]
             bt = np.zeros(self._table_shape(W), np.int32)
             self._tables_into(bt, seq)
-            self._count_attn(1, W, np.asarray([chunk.start + L - 1]), True,
-                             np.asarray([chunk.start]))
+            form = self._count_attn(Sp, W, np.asarray([chunk.start + L - 1]), True,
+                                    np.asarray([chunk.start]))
             self.total_attn_chunks[0] += 1
-            self.total_attn_chunks[1] += self._attn_kernel(
-                self.cfg, Sp, W, self.opts.block_size)
+            self.total_attn_chunks[1] += form == self._paged_attention.CHUNK_KERNEL
             self._count_moe(Sp)
             self._count_state(Sp, L, decode=False)
             self._step_chunks[0] += L
@@ -1348,7 +1344,7 @@ class InferenceEngine:
                 positions[i] = seq.num_tokens - 1
                 valid_len[i] = 1 + len(d)
                 self._tables_into(tables[i], seq)
-            self._count_attn(B, W, positions + valid_len - 1, valid_len > 0, positions)
+            self._count_attn(K1, W, positions + valid_len - 1, valid_len > 0, positions)
             self._count_moe(B * K1)
             args = (
                 jnp.asarray(tokens),
@@ -1427,9 +1423,8 @@ class InferenceEngine:
                 self._tables_into(tables[i], seq)
             self._step_chained = int(not lanes[3].all())
             self.total_decode_chained += self._step_chained
-            by_lane = self._decode_kernel(self.cfg, 1, self.opts.block_size)
-            self.total_attn_decodes_kernel += by_lane
-            self._count_attn(B, W, lanes[1], np.arange(B) < len(seqs), by_lane=by_lane)
+            form = self._count_attn(1, W, lanes[1], np.arange(B) < len(seqs))
+            self.total_attn_decodes_kernel += form == self._paged_attention.DECODE_KERNEL
             self._count_moe(B)
             self._count_state(B, len(seqs), decode=True)
             args = (jnp.asarray(lanes), jnp.asarray(tables))
@@ -1657,8 +1652,8 @@ class InferenceEngine:
             "blocks_imported": self.total_blocks_imported,
             "blocks_exported": self.total_blocks_exported,
             "window_blocks_released": self.block_manager.window_released,
-            "attn_keys_run": self.total_attn_keys[0],
-            "attn_keys_padded": self.total_attn_keys[1],
+            "attn_keys_run": self.total_attn_covered[0],
+            "attn_keys_padded": self.total_attn_covered[1],
             "attn_chunks": self.total_attn_chunks[0],
             "attn_chunks_kernel": self.total_attn_chunks[1],
             "attn_decodes_kernel": self.total_attn_decodes_kernel,

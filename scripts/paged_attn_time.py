@@ -11,16 +11,15 @@ lanes. Every KV group's table holds all W blocks, so the masks alone decide
 what a query sees. Weights and pool are the cell's own sizes (`init_params`
 on the device, `engine_options`), the programs jitted and donated as the
 engine's are. `--tile-keys` times each shape once for every value of
-`models.gpt._ATTN_TILE_KEYS` (a program without that constant runs each
-shape once): how the tile of `_paged_layers`' key loop was chosen (PERF.md
-§6, PR 29). `--decode-forms gather,kernel` times each decode shape once
+`ops.paged_attention._ATTN_TILE_KEYS`: how the tile of the paged key loop was
+chosen (PERF.md §6, PR 29). `--decode-forms gather,kernel` times each decode shape once
 in each form of the step's attention: GATHER, every lane's rows gathered at the
 table's width (what every decode program ran until PR 44 and what heads of 64
-and the CPU still run), and KERNEL (`ops/attention.py`
+and the CPU still run), and KERNEL (`ops/paged_attention.py`
 `paged_decode_attention`: each lane's own blocks through its table; the form
-`models.gpt.paged_decode_kernel` sends the shape to is the default);
+the rule, `paged_attn_form`, gives the shape is the default);
 `--group-kib 512,1024,2048` times the kernel once for every value of its DMA
-group, `ops.attention._DECODE_GROUP_BYTES` (PERF.md §6, PR 44).
+group, `ops.paged_attention._DECODE_GROUP_BYTES` (PERF.md §6, PR 44).
 `--sampled` times each shape a second time as the program the
 engine dispatches (`serve/engine/engine.py: _paged_jits`: the same function
 with the sampler behind it, ids for logits, the last ids carried beside the
@@ -35,10 +34,10 @@ the chip and ran on it until PR 41: the key up-projection on the query, every
 head over the one cached row, a loop over key tiles), EXPANDED (each tile's
 rows widened to per-head keys and values first; PERF.md §6, PR 34: a starting
 point, not a path of the program), the same gathers, tiles, mask and online
-softmax, and KERNEL (the absorbed operands through `ops/attention.py`
+softmax, and KERNEL (the absorbed operands through `ops/paged_attention.py`
 `paged_chunk_attention`, the table's rows gathered once: what a chunk program
 runs on the chip since PR 41; `--q-rows 512,1024` times it once for every
-value of its query tile, `ops.attention._CHUNK_Q_ROWS`).
+value of its query tile, `ops.paged_attention._CHUNK_Q_ROWS`).
 
 Milliseconds a call, mean over `--reps` calls dispatched back to back and
 waited for once. A chip run or nothing: on the CPU (`--rehearse`, the
@@ -75,7 +74,7 @@ def main(argv=None) -> int:
 
     from benchmarks import harness
     from ray_tpu.models import gpt
-    from ray_tpu.ops import attention
+    from ray_tpu.ops import paged_attention
 
     config = harness.load_json(harness.ROOT, f"benchmarks/configs/{a.config}.json")
     arch = harness.arch(config["arch"])
@@ -109,8 +108,6 @@ def main(argv=None) -> int:
             kv[name] = jax.jit(lambda: jnp.broadcast_to(
                 jax.random.normal(jax.random.PRNGKey(2), shape[1:], dtype), shape))()
     tiles = [int(t) for t in a.tile_keys.split(",") if t]
-    if not hasattr(gpt, "_ATTN_TILE_KEYS"):
-        tiles = []
     rows = []
 
     def timed(row, fn, args):
@@ -140,7 +137,7 @@ def main(argv=None) -> int:
 
     for tile in tiles or [None]:
         if tile is not None:
-            gpt._ATTN_TILE_KEYS = tile
+            paged_attention._ATTN_TILE_KEYS = tile
         if a.sampled:
             engine_module._JITS = None      # traced anew under this tile
 
@@ -170,17 +167,26 @@ def main(argv=None) -> int:
                 meta = jnp.asarray([n, offset, 0], jnp.int32)
                 timed({**row, "sampled": True}, sampled_prefill,
                       (args[0], meta, args[3]))
-        rule, own_group = gpt.paged_decode_kernel, attention._DECODE_GROUP_BYTES
+        rule, own_group = paged_attention.paged_attn_form, paged_attention._DECODE_GROUP_BYTES
+
+        def forced(form):       # the rule, a decode step's form forced
+            def answer(tokens, width, block, *rows):
+                if tokens != 1:
+                    return rule(tokens, width, block, *rows)
+                if form == "kernel" and on_chip:
+                    return paged_attention.DECODE_KERNEL
+                one_tile = paged_attention.paged_attn_tiling(width, block)[1] == 1
+                return paged_attention.ONE_SHOT if one_tile else paged_attention.KEY_LOOP
+            return answer
+
         groups = [int(g) << 10 for g in a.group_kib.split(",") if g]
         forms = [(form, group) for form in a.decode_forms.split(",") if form
                  for group in (groups if form == "kernel" and groups else [None])]
         for spec, (form, group) in itertools.product(
                 filter(None, a.decode.split(",")), forms or [(None, None)]):
             # both are read while the program is traced: a jit a form
-            gpt.paged_decode_kernel = rule if form is None else (
-                lambda cfg, tokens, bs, form=form: (
-                    tokens == 1 and form == "kernel" and on_chip))
-            attention._DECODE_GROUP_BYTES = group or own_group
+            paged_attention.paged_attn_form = rule if form is None else forced(form)
+            paged_attention._DECODE_GROUP_BYTES = group or own_group
             decode = jax.jit(      # (.., tables, state slots or None, kv, cfg)
                 lambda p, ids, pos, tables, slots, kv, cfg: gpt.decode_step_paged(
                     p, ids, pos, tables, kv, cfg, slots),
@@ -203,8 +209,10 @@ def main(argv=None) -> int:
                     jnp.asarray(state) if stateful else None)
             row = {"program": "decode_step_paged", "tile_keys": tile, "lanes": B,
                    "W": W, "keys": W * BS, "positions": pos,
-                   "form": form or ("kernel" if rule(cfg, 1, BS) else "gather"),
-                   "group_kib": attention._DECODE_GROUP_BYTES >> 10}
+                   "form": form or ("kernel" if rule(
+                       1, W, BS, *gpt.kv_head_rows(cfg)[1:], cfg.dtype
+                   ) == paged_attention.DECODE_KERNEL else "gather"),
+                   "group_kib": paged_attention._DECODE_GROUP_BYTES >> 10}
             timed(dict(row), decode, args)
             if a.sampled:     # rows: slot, position, host id, known: the same ids
                 lanes = np.zeros((4, B), np.int32)      # (experts route by them)
@@ -212,7 +220,7 @@ def main(argv=None) -> int:
                 lanes[2], lanes[3] = ids, 1
                 timed({**row, "sampled": True}, sampled_decode,
                       (jnp.asarray(lanes), args[2]))
-        gpt.paged_decode_kernel, attention._DECODE_GROUP_BYTES = rule, own_group
+        paged_attention.paged_attn_form, paged_attention._DECODE_GROUP_BYTES = rule, own_group
     for W in (int(w) for w in a.latent_forms.split(",") if w):
         q_rows = [int(r) for r in a.q_rows.split(",") if r]
         for form, ms in latent_forms(cfg, params, kv["k"], table(W), chunk, BS, reps,
@@ -245,10 +253,10 @@ def latent_forms(cfg, params, pool, table, chunk, BS, reps, kernel=False, q_rows
     import jax.numpy as jnp
 
     from ray_tpu.models import gpt
-    from ray_tpu.ops import attention
+    from ray_tpu.ops import paged_attention
 
     H, R, Dn, Dr = cfg.n_heads, cfg.kv_lora_rank, cfg.d_head, cfg.rotary_dim
-    T = min(gpt._ATTN_TILE_KEYS, len(table) * BS)
+    T = min(paged_attention._ATTN_TILE_KEYS, len(table) * BS)
     tiles = len(table) * BS // T
     table = jnp.asarray(table).reshape(tiles, T // BS)
     key = jax.random.PRNGKey(1)
@@ -315,22 +323,22 @@ def latent_forms(cfg, params, pool, table, chunk, BS, reps, kernel=False, q_rows
                               q[..., Dn:]], -1)
         width = pool.shape[-1]
         qa = jnp.pad(qa, ((0, 0), (0, 0), (0, width - qa.shape[-1])))
-        out = attention.paged_chunk_attention(
+        out = paged_attention.paged_chunk_attention(
             qa.reshape(1, 1, H * chunk, width),
             pool[0, table.reshape(-1)].reshape(1, tiles * T, width), None,
             jnp.tile(qpos[:, :, 0], (1, H)), jnp.zeros((1,), jnp.int32), tiles,
-            gpt._NO_WINDOW, tile_keys=T, dv=R, sm_scale=scale)
+            paged_attention.NO_WINDOW, tile_keys=T, dv=R, sm_scale=scale)
         return jnp.einsum("hsr,rhd->hsd", out.reshape(H, chunk, R), w_uv)
 
     forms = [("absorbed", absorbed), ("expanded", expanded)]
-    own = attention._CHUNK_Q_ROWS
+    own = paged_attention._CHUNK_Q_ROWS
     for rows in (q_rows or [own]) if kernel else ():
         def at_tile(*args, rows=rows):
-            attention._CHUNK_Q_ROWS = rows      # read while tracing
+            paged_attention._CHUNK_Q_ROWS = rows      # read while tracing
             try:
                 return by_kernel(*args)
             finally:
-                attention._CHUNK_Q_ROWS = own
+                paged_attention._CHUNK_Q_ROWS = own
         forms.append((f"kernel@{rows}", jax.jit(at_tile)))
     out = {}
     for name, fn in forms:

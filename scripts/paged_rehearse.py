@@ -163,15 +163,16 @@ def loop_copy_bytes(hlo_text: str) -> dict:
 
 
 def kernels_in(hlo_text: str) -> dict:
-    """{name: calls} of the paged attention kernels (`ops/attention.py`: the
-    chunk's, the decode step's) in a compiled program's text, by the name its
-    `pallas_call` was given: which programs hold which, and how often."""
-    from ray_tpu.ops import attention
+    """{name: calls} of the paged attention kernels (`ops/paged_attention.py`:
+    the chunk's, the decode step's) in a compiled program's text, by the name
+    its `pallas_call` was given: which programs hold which, and how often."""
+    from ray_tpu.ops import paged_attention
 
     calls = [line for line in hlo_text.splitlines()
              if 'custom_call_target="tpu_custom_call"' in line]
     return {name: sum(f"/{name}/" in line or f"/{name}\"" in line for line in calls)
-            for name in (attention.PAGED_CHUNK_KERNEL, attention.PAGED_DECODE_KERNEL)}
+            for name in (paged_attention.PAGED_CHUNK_KERNEL,
+                         paged_attention.PAGED_DECODE_KERNEL)}
 
 
 def fusions_named(hlo_text: str, name: str):

@@ -39,9 +39,10 @@ def _paged_jits_with_tile(keys):
     import jax
 
     from ray_tpu.models import gpt
+    from ray_tpu.ops import paged_attention
 
     with pytest.MonkeyPatch.context() as mp:
-        mp.setattr(gpt, "_ATTN_TILE_KEYS", keys)
+        mp.setattr(paged_attention, "_ATTN_TILE_KEYS", keys)
         yield (
             jax.jit(lambda *a: gpt.prefill_paged(*a),
                     static_argnums=(6,), donate_argnums=(5,)),
@@ -56,7 +57,7 @@ def _paged_jits_with_tile(keys):
 def tile_keys():
     """`with tile_keys(n) as (prefill, decode, verify):` the paged programs
     jitted anew (and pool-donating, as the engine's are) and traced with
-    `models.gpt._ATTN_TILE_KEYS = n`, the keys a trip of `_paged_layers`'
+    `ops.paged_attention._ATTN_TILE_KEYS = n`, the keys a trip of the paged
     key loop covers: a table of at most n keys is attended in one shot,
     a wider one in tiles. The constant is read while tracing, so calls
     belong inside the `with`. Each program is wrapped in a function of its

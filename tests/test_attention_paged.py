@@ -1,10 +1,11 @@
-"""A chunk's attention over a wide table as ONE kernel (`ops/attention.py`
-`paged_chunk_attention`): in interpret mode against the plain key loop of
-`models/gpt.py` `_paged_layers` it stands in for, through the paged programs
-themselves, and the rule on shapes that sends a program to it, as the
-program reads it and as the engine's host counts with it. Below it the
-DECODE step's kernel (`paged_decode_attention`: each lane's own blocks
-through its table) the same way against the gather it stands in for."""
+"""Attention over the paged pool (`ops/paged_attention.py`). A chunk's
+attention over a wide table as ONE kernel (`paged_chunk_attention`): in
+interpret mode against the plain key loop it stands in for, through the paged
+programs themselves, and the rule on shapes that gives a program its form
+(`paged_attn_form`), as the program reads it and as the engine's host counts
+with it. Below it the DECODE step's kernel (`paged_decode_attention`: each
+lane's own blocks through its table) the same way against the gather it
+stands in for."""
 
 import contextlib
 import functools
@@ -54,6 +55,15 @@ def _cfg(model):
     return GPTConfig(**_COMMON, **MODELS[model], dtype=jnp.float32)
 
 
+def _form(cfg, tokens, width, block):
+    """The rule's answer for a program of `cfg`, as `_paged_layers` and the
+    engine ask it."""
+    from ray_tpu.models.gpt import kv_head_rows
+    from ray_tpu.ops.paged_attention import paged_attn_form
+
+    return paged_attn_form(tokens, width, block, *kv_head_rows(cfg)[1:], cfg.dtype)
+
+
 @contextlib.contextmanager
 def _programs(by_kernel: bool):
     """The three paged programs jitted anew, their key loop in tiles of
@@ -65,21 +75,21 @@ def _programs(by_kernel: bool):
     from jax.experimental.pallas import tpu as pltpu
 
     from ray_tpu.models import gpt
-    from ray_tpu.ops import attention, norms
+    from ray_tpu.ops import attention, norms, paged_attention
     from ray_tpu.serve.engine import engine as engine_module
 
     with pytest.MonkeyPatch.context() as mp:
-        mp.setattr(gpt, "_ATTN_TILE_KEYS", TILE)
+        mp.setattr(paged_attention, "_ATTN_TILE_KEYS", TILE)
         mp.setattr(engine_module, "_JITS", None)    # the engine's traces too
         if by_kernel:
             mp.setattr(attention, "_on_tpu", lambda: True)
-            mp.setattr(attention, "paged_chunk_attention", functools.partial(
-                attention.paged_chunk_attention, interpret=True))
+            mp.setattr(paged_attention, "paged_chunk_attention", functools.partial(
+                paged_attention.paged_chunk_attention, interpret=True))
             # a decode step traced so takes ITS kernel (uninitialised rows NaN),
             # a few blocks a DMA group so that a lane here has several
-            mp.setattr(attention, "_DECODE_GROUP_BYTES", 128 << 10)
-            mp.setattr(attention, "paged_decode_attention", functools.partial(
-                attention.paged_decode_attention, interpret=pltpu.InterpretParams()))
+            mp.setattr(paged_attention, "_DECODE_GROUP_BYTES", 128 << 10)
+            mp.setattr(paged_attention, "paged_decode_attention", functools.partial(
+                paged_attention.paged_decode_attention, interpret=pltpu.InterpretParams()))
             mp.setattr(norms, "_rmsnorm_pallas", norms._rmsnorm_ref)
         yield (jax.jit(lambda *a: gpt.prefill_paged(*a), static_argnums=(6,)),
                jax.jit(lambda *a: gpt.verify_step_paged(*a), static_argnums=(6,)))
@@ -173,6 +183,7 @@ def test_the_chunk_kernel_is_the_key_loop(model, step):
     import jax
 
     from ray_tpu.models import gpt
+    from ray_tpu.ops.paged_attention import CHUNK_KERNEL, KEY_LOOP
 
     cfg = _cfg(model)
     params = gpt.init_params(jax.random.PRNGKey(5), cfg)
@@ -180,7 +191,7 @@ def test_the_chunk_kernel_is_the_key_loop(model, step):
     got = {}
     for by_kernel in (False, True):
         with _programs(by_kernel) as programs:
-            assert gpt.paged_attn_kernel(cfg, 32, W, BS) is by_kernel
+            assert _form(cfg, 32, W, BS) == (CHUNK_KERNEL if by_kernel else KEY_LOOP)
             logits, kv, blocks = STEPS[step](programs, cfg, params, tokens)
             got[by_kernel] = jax.tree_util.tree_map(
                 np.asarray, (logits, {k: v[:, blocks.reshape(-1)] for k, v in kv.items()}))
@@ -194,7 +205,7 @@ def test_the_kernel_is_named_in_the_program_and_the_loop_is_gone():
     import jax.numpy as jnp
 
     from ray_tpu.models import gpt
-    from ray_tpu.ops import attention
+    from ray_tpu.ops import paged_attention
 
     cfg = _cfg("latent")
     params = jax.eval_shape(lambda k: gpt.init_params(k, cfg), jax.random.PRNGKey(0))
@@ -206,7 +217,7 @@ def test_the_kernel_is_named_in_the_program_and_the_loop_is_gone():
             jaxpr = str(jax.make_jaxpr(
                 lambda *a: gpt.prefill_paged(*a, cfg)
             )(params, i32(1, 32), i32(), i32(), i32(W), kv))
-        calls[by_kernel] = (jaxpr.count(attention.PAGED_CHUNK_KERNEL), "while" in jaxpr)
+        calls[by_kernel] = (jaxpr.count(paged_attention.PAGED_CHUNK_KERNEL), "while" in jaxpr)
     # once, in the layer scan's body; the plain form's one loop a layer is gone
     assert calls == {False: (0, True), True: (1, False)}
 
@@ -235,11 +246,12 @@ def test_the_rule_is_a_function_of_shapes(model, overrides, tokens, width, block
     """On the chip the rule says what the shapes say; off it, never."""
     from ray_tpu.models import gpt
     from ray_tpu.ops import attention
+    from ray_tpu.ops.paged_attention import CHUNK_KERNEL, KEY_LOOP, ONE_SHOT
 
     cfg = gpt.CONFIGS[model](**overrides)
-    assert gpt.paged_attn_kernel(cfg, tokens, width, block) is False
+    assert _form(cfg, tokens, width, block) in (KEY_LOOP, ONE_SHOT)
     monkeypatch.setattr(attention, "_on_tpu", lambda: True)
-    assert gpt.paged_attn_kernel(cfg, tokens, width, block) is want
+    assert (_form(cfg, tokens, width, block) == CHUNK_KERNEL) is want
 
 
 def test_the_engine_counts_chunks_with_the_programs_rule():
@@ -288,7 +300,7 @@ def test_gpt2_larges_programs_never_hold_the_kernel(width, monkeypatch):
     import jax.numpy as jnp
 
     from ray_tpu.models import gpt
-    from ray_tpu.ops import attention
+    from ray_tpu.ops import attention, paged_attention
 
     monkeypatch.setattr(attention, "_on_tpu", lambda: True)
     cfg = gpt.CONFIGS["gpt2-large"](remat=False, remat_policy=None)
@@ -304,7 +316,7 @@ def test_gpt2_larges_programs_never_hold_the_kernel(width, monkeypatch):
             params, i32(4, 3), i32(4), i32(4), i32(4, width), kv),
     }
     for name, jaxpr in programs.items():
-        assert attention.PAGED_CHUNK_KERNEL not in str(jaxpr), name
+        assert paged_attention.PAGED_CHUNK_KERNEL not in str(jaxpr), name
 
 
 def test_the_rehearsal_finds_the_copies_inside_a_layers_loop():
@@ -430,9 +442,10 @@ def _decode_program(by_kernel: bool):
     import jax
 
     from ray_tpu.models import gpt
+    from ray_tpu.ops import paged_attention
 
     with _programs(by_kernel), pytest.MonkeyPatch.context() as mp:
-        mp.setattr(gpt, "_ATTN_TILE_KEYS", 1024)
+        mp.setattr(paged_attention, "_ATTN_TILE_KEYS", 1024)
         yield jax.jit(lambda *a: gpt.decode_step_paged(*a), static_argnums=(5,))
 
 
@@ -445,7 +458,7 @@ def test_the_decode_kernel_is_the_gather(case):
     import jax
 
     from ray_tpu.models import gpt
-    from ray_tpu.ops import attention
+    from ray_tpu.ops import paged_attention
 
     cfg, params, kv, args, tables = _decode_case(case)
     real = np.asarray([pos is not None for pos in DECODE[case][3]])
@@ -453,10 +466,11 @@ def test_the_decode_kernel_is_the_gather(case):
     got = {}
     for by_kernel in (False, True):
         with _decode_program(by_kernel) as decode:
-            assert gpt.paged_decode_kernel(cfg, 1, DECODE[case][1]) is by_kernel
+            assert (_form(cfg, 1, *DECODE[case][2:0:-1])
+                    == paged_attention.DECODE_KERNEL) is by_kernel
             text = str(jax.make_jaxpr(lambda *a: gpt.decode_step_paged(*a, cfg))(
                 params, *args, kv))
-            assert (attention.PAGED_DECODE_KERNEL in text) is by_kernel
+            assert (paged_attention.PAGED_DECODE_KERNEL in text) is by_kernel
             out, pool = decode(params, *args, kv, cfg)
             logits = out[0] if isinstance(out, tuple) else out
             got[by_kernel] = jax.tree_util.tree_map(
@@ -476,26 +490,26 @@ def test_the_decode_kernel_fetches_a_lanes_own_blocks_and_no_other(window, monke
     import jax.numpy as jnp
     from jax.experimental.pallas import tpu as pltpu
 
-    from ray_tpu.models.gpt import _NO_WINDOW
-    from ray_tpu.ops import attention
+    from ray_tpu.ops import paged_attention
+    from ray_tpu.ops.paged_attention import NO_WINDOW
 
-    monkeypatch.setattr(attention, "_DECODE_GROUP_BYTES", 128 << 10)    # 4 blocks a group
+    monkeypatch.setattr(paged_attention, "_DECODE_GROUP_BYTES", 128 << 10)  # 4 blocks a group
     B, heads, R, dh, bs, width, depth, slot = 5, 2, 3, 128, 16, 24, 3, 2
     positions = np.asarray([383, 0, 200, 16, 15], np.int32)
     real = np.asarray([True, True, False, True, True])
-    reach = _NO_WINDOW if window is None else window
+    reach = NO_WINDOW if window is None else window
     rng = np.random.default_rng(7)
     nb = 1 + B * width
     clean = rng.normal(size=(2, depth, nb, bs, heads * dh)).astype(np.float32)
     table = (1 + rng.permutation(nb - 1)).astype(np.int32).reshape(B, width)
-    first, blocks = attention.paged_decode_span(np, positions, real, reach, bs, width)
+    first, blocks = paged_attention.paged_decode_span(np, positions, real, reach, bs, width)
     assert blocks[2] == 0 and (blocks[real] >= 1).all()
     poisoned = clean.copy()
     held = np.concatenate([table[b, first[b]:first[b] + blocks[b]] for b in range(B)])
     poisoned[:, :, np.setdiff1d(np.arange(nb), held)] = np.nan
     poisoned[:, np.arange(depth) != slot] = np.nan          # and every other layer's rows
     q = jnp.asarray(rng.normal(size=(B, heads, R, dh)), jnp.float32)
-    out = attention.paged_decode_attention(
+    out = paged_attention.paged_decode_attention(
         q, jnp.asarray(poisoned[0]), jnp.asarray(poisoned[1]), slot, jnp.asarray(table),
         jnp.asarray(positions), jnp.asarray(real), reach, dv=dh, sm_scale=0.1,
         interpret=pltpu.InterpretParams())
@@ -524,8 +538,8 @@ def test_decode_programs_of_one_lane_count_share_one_trace_of_the_kernel(
     import jax
     import jax.numpy as jnp
 
-    from ray_tpu.models.gpt import _NO_WINDOW
-    from ray_tpu.ops import attention
+    from ray_tpu.ops import paged_attention
+    from ray_tpu.ops.paged_attention import NO_WINDOW
 
     heads, R, dh, bs, depth = 2, 1, 128, 16, 2
 
@@ -533,16 +547,16 @@ def test_decode_programs_of_one_lane_count_share_one_trace_of_the_kernel(
         arr = lambda dt, *shape: jax.ShapeDtypeStruct(shape, dt)  # noqa: E731
         pool = arr(jnp.bfloat16, depth, pool_blocks, bs, heads * dh)
         return jax.make_jaxpr(lambda q, k, v, slot, table, pos, real: (
-            attention.paged_decode_attention(q, k, v, slot, table, pos, real, _NO_WINDOW,
-                                             dv=dh, sm_scale=0.1)))(
+            paged_attention.paged_decode_attention(q, k, v, slot, table, pos, real, NO_WINDOW,
+                                                   dv=dh, sm_scale=0.1)))(
             arr(jnp.bfloat16, B, heads, R, dh), pool, pool, arr(jnp.int32),
             arr(jnp.int32, B, width), arr(jnp.int32, B), arr(jnp.bool_, B))
 
-    traced, body = [], attention._paged_decode_kernel
-    monkeypatch.setattr(attention, "_paged_decode_kernel",
+    traced, body = [], paged_attention._paged_decode_kernel
+    monkeypatch.setattr(paged_attention, "_paged_decode_kernel",
                         lambda *refs, **sizes: traced.append(sizes) or body(*refs, **sizes))
     texts = [str(program(lanes, width)) for width in widths]
-    assert all(attention.PAGED_DECODE_KERNEL in text for text in texts)
+    assert all(paged_attention.PAGED_DECODE_KERNEL in text for text in texts)
     assert len(traced) == traces
     program(lanes // 2, widths[0])
     assert len(traced) == traces + 1
@@ -570,11 +584,14 @@ def test_the_decode_rule_is_a_function_of_shapes(model, tokens, block, want, mon
     width; off it, never (the CPU keeps the gather)."""
     from ray_tpu.models import gpt
     from ray_tpu.ops import attention
+    from ray_tpu.ops.paged_attention import DECODE_KERNEL, KEY_LOOP, ONE_SHOT
 
     cfg = gpt.CONFIGS[model]()
-    assert gpt.paged_decode_kernel(cfg, tokens, block) is False
+    for width in (8, 128):
+        assert _form(cfg, tokens, width, block) in (KEY_LOOP, ONE_SHOT)
     monkeypatch.setattr(attention, "_on_tpu", lambda: True)
-    assert gpt.paged_decode_kernel(cfg, tokens, block) is want
+    for width in (8, 128):
+        assert (_form(cfg, tokens, width, block) == DECODE_KERNEL) is want
 
 
 def test_the_decode_kernel_is_in_the_decode_program_and_in_no_other():
@@ -585,7 +602,7 @@ def test_the_decode_kernel_is_in_the_decode_program_and_in_no_other():
     import jax.numpy as jnp
 
     from ray_tpu.models import gpt
-    from ray_tpu.ops import attention
+    from ray_tpu.ops.paged_attention import PAGED_DECODE_KERNEL
 
     i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32)  # noqa: E731
     counts = {}
@@ -597,7 +614,7 @@ def test_the_decode_kernel_is_in_the_decode_program_and_in_no_other():
             params = jax.eval_shape(lambda k: gpt.init_params(k, cfg), jax.random.PRNGKey(0))
             kv = jax.eval_shape(lambda: gpt.init_paged_cache(cfg, NB, BS))
             counts[name] = tuple(
-                str(jaxpr).count(attention.PAGED_DECODE_KERNEL) for jaxpr in (
+                str(jaxpr).count(PAGED_DECODE_KERNEL) for jaxpr in (
                     jax.make_jaxpr(lambda *a: gpt.decode_step_paged(*a, cfg))(
                         params, i32(4), i32(4), i32(4, *table), kv),
                     jax.make_jaxpr(lambda *a: gpt.prefill_paged(*a, cfg))(
@@ -609,25 +626,70 @@ def test_the_decode_kernel_is_in_the_decode_program_and_in_no_other():
         kv = jax.eval_shape(lambda: gpt.init_paged_cache(cfg, 256, 16))
         counts["gpt2-large"] = str(jax.make_jaxpr(
             lambda *a: gpt.decode_step_paged(*a, cfg))(
-                params, i32(4), i32(4), i32(4, 8), kv)).count(attention.PAGED_DECODE_KERNEL)
+                params, i32(4), i32(4), i32(4, 8), kv)).count(PAGED_DECODE_KERNEL)
     assert counts == {"grouped": (1, 0, 0), "grouped-window": (1, 0, 0), "gpt2-large": 0}
 
 
 def test_the_host_counts_a_decode_programs_keys_by_lane():
-    """`paged_attn_keys` / `paged_attn_head_keys` with `by_lane`: each real
-    lane's own blocks under the layer's window, from the function the kernel
-    takes its bounds from; without it, the gather's lanes x trips x tile."""
-    from ray_tpu.models import gpt
-    from ray_tpu.ops import attention
+    """`paged_attn_cover` under the decode kernel's form: each real lane's own
+    blocks under the layer's window, from the function the kernel takes its
+    bounds from; under the key loop's, the gather's lanes x trips x tile."""
+    from ray_tpu.ops.paged_attention import (
+        DECODE_KERNEL, KEY_LOOP, paged_attn_cover, paged_decode_span)
 
     pos = np.asarray([2047, 300, 0, 0])
     real = np.asarray([True, True, False, False])
-    assert gpt.paged_attn_keys(4, 128, 16, pos, real) == (4 * 2048, 4 * 2048)
-    assert gpt.paged_attn_keys(4, 128, 16, pos, real, by_lane=True) == (
-        2048 + 304, 4 * 2048)       # 128 blocks and 19: the padding lanes none
     heads = ((0, 12), (512, 36))    # a global kind and a window kind
-    run = 2048 + 304
-    assert gpt.paged_attn_head_keys(heads, run, 128, 16, pos, pos, real, by_lane=True) == (
-        36 * (32 * 16 + 304), 12 * run + 36 * (32 * 16 + 304))
-    first, blocks = attention.paged_decode_span(np, pos, real, 512, 16, 128)
+    assert paged_attn_cover(KEY_LOOP, (), 128, 16, pos, pos, real)[:2] == (4 * 2048, 4 * 2048)
+    run = 2048 + 304                # 128 blocks and 19: the padding lanes none
+    assert paged_attn_cover(DECODE_KERNEL, heads, 128, 16, pos, pos, real) == (
+        run, 4 * 2048, 36 * (32 * 16 + 304), 12 * run + 36 * (32 * 16 + 304))
+    first, blocks = paged_decode_span(np, pos, real, 512, 16, 128)
     assert first.tolist() == [96, 0, 0, 0] and blocks.tolist() == [32, 19, 0, 0]
+
+
+# (program, table) -> the form on the chip, of every served configuration, at
+# the block size, chunk length and served positions of its file under
+# `benchmarks/configs/`: the decode program and a full prefill chunk, each
+# over the narrowest table it can meet (one block; the chunk's own blocks)
+# and the widest (the served positions, or the whole pool where that is less)
+_KERNELS = {("decode", "narrow"): "decode_kernel", ("decode", "wide"): "decode_kernel",
+            ("chunk", "narrow"): "one_shot", ("chunk", "wide"): "chunk_kernel"}
+SERVED = {
+    "gpt2-large": dict.fromkeys(_KERNELS, "one_shot"),  # heads of 64, 1,024 positions
+    "smallthinker-21b-a3b": _KERNELS,
+    "ouro-2.6b": _KERNELS,
+    "ax-k1": _KERNELS,
+    "jamba2-3b": _KERNELS,
+    "laguna-xs2": _KERNELS,
+}
+
+
+@pytest.mark.parametrize("table", ["narrow", "wide"])
+@pytest.mark.parametrize("program", ["decode", "chunk"])
+@pytest.mark.parametrize("config", list(SERVED))
+def test_no_served_program_takes_the_key_loop_on_the_chip(config, program, table, monkeypatch):
+    """The four forms against the six served configurations: on the chip
+    every program's form is the table's and the key loop is none's; off it the
+    same shapes take the key loop exactly where the table is wider than a
+    tile (the one form a CPU has for it)."""
+    from benchmarks import harness
+    from ray_tpu.models.gpt import CONFIGS
+    from ray_tpu.ops import attention
+    from ray_tpu.ops.paged_attention import KEY_LOOP, ONE_SHOT, paged_attn_tiling
+    from ray_tpu.serve.engine import EngineOptions
+
+    served = harness.load_json(harness.ROOT, f"benchmarks/configs/{config}.json")
+    arch = harness.arch(served["arch"])
+    model, overrides = arch.program(served, arch.dims(served, False))
+    cfg = CONFIGS[model](**overrides)
+    opts = EngineOptions(**served["runners"]["requests"]["engine_options"])
+    tokens = 1 if program == "decode" else opts.prefill_chunk_tokens
+    width = (-(-tokens // opts.block_size) if table == "narrow"
+             else min(cfg.max_seq // opts.block_size, opts.num_blocks))
+    tiled = paged_attn_tiling(width, opts.block_size)[1] > 1
+    assert tiled is (table == "wide" and config != "gpt2-large")
+    assert _form(cfg, tokens, width, opts.block_size) == (KEY_LOOP if tiled else ONE_SHOT)
+    monkeypatch.setattr(attention, "_on_tpu", lambda: True)
+    assert _form(cfg, tokens, width, opts.block_size) == SERVED[config][program, table]
+    assert SERVED[config][program, table] != KEY_LOOP

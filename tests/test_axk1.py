@@ -536,8 +536,12 @@ def test_config_and_architecture_module_refuse_what_is_not_the_model():
 # (one tile) and of 128 (the key loop). PR 39 re-took the six
 # `smallthinker-21b-a3b` entries (`decode`: 4 lanes took the loop over chosen
 # experts and take the grouped form; `prefill` and `verify`, grouped since
-# PR 35: their padding rows are now routed nowhere); the other 18 are still
-# the parent's, so no program of a model without experts moved.
+# PR 35: their padding rows are now routed nowhere). PR 46 re-took the
+# `decode` entry of `ouro-2.6b/8` and of `ouro-2.6b/128`: the two programs that
+# held `lone` (one query a K/V head over heads of 128 as two products over the
+# rows as the pool lays them), which went, and which take the per-head form
+# every other shape takes off the chip. The other 16 are still the parent's:
+# no other program of a model without experts moved.
 _PARENT = {
     "gpt2-small/8": {"decode": ["47e12eb441a8db6f", 56599], "prefill": ["3d712626f6741d9d", 56211], "verify": ["cabdde9d7cb27d1c", 46062]},
     "gpt2-small/128": {"decode": ["a469eadf508f0a50", 69719], "prefill": ["3eaeb73f7472490f", 69106], "verify": ["cdc8b418bab759b6", 59099]},
@@ -545,8 +549,8 @@ _PARENT = {
     "gpt2-large/128": {"decode": ["3e8b3a276dffa813", 70014], "prefill": ["d8ff5e567f857d44", 69393], "verify": ["325d1493576d59f0", 59382]},
     "smallthinker-21b-a3b/8": {"decode": ["4e37645107422994", 85872], "prefill": ["683caebcddddb256", 82697], "verify": ["2e56174898997513", 74321]},
     "smallthinker-21b-a3b/128": {"decode": ["1758aba14558b3c2", 97901], "prefill": ["fb52c1ec3674f57b", 94689], "verify": ["3184a5406968d88f", 86472]},
-    "ouro-2.6b/8": {"decode": ["daa65fea876c298e", 74846], "prefill": ["6f59bfc7e81f8afa", 65849], "verify": ["441542f5c0143299", 57230]},
-    "ouro-2.6b/128": {"decode": ["6614de8a7a764202", 87645], "prefill": ["11867b981e748ff7", 79107], "verify": ["90cb98c74573bd2c", 70644]},
+    "ouro-2.6b/8": {"decode": ["a5b6302e6c2b7ba7", 71869], "prefill": ["6f59bfc7e81f8afa", 65849], "verify": ["441542f5c0143299", 57230]},
+    "ouro-2.6b/128": {"decode": ["66e128546bb82d6a", 84626], "prefill": ["11867b981e748ff7", 79107], "verify": ["90cb98c74573bd2c", 70644]},
 }
 
 

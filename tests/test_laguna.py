@@ -405,19 +405,19 @@ def test_head_keys_are_counted_by_layer_kind_under_each_window(case, monkeypatch
     with tiles of 16 keys a window layer (window 16) covers tiles 6-7, a full
     layer tiles 0-7."""
     from ray_tpu.models import gpt
+    from ray_tpu.ops import paged_attention
 
     cfg = case[0]
-    monkeypatch.setattr(gpt, "_ATTN_TILE_KEYS", 16)
-    run, _ = gpt.paged_attn_keys(1, 16, BS, np.asarray([127]), True)
-    window, every = gpt.paged_attn_head_keys(
-        gpt.attn_heads_by_window(cfg), run, 16, BS, np.asarray([120]), np.asarray([127]), True)
+    heads = gpt.attn_heads_by_window(cfg)
+    monkeypatch.setattr(paged_attention, "_ATTN_TILE_KEYS", 16)
+    _, _, window, every = paged_attention.paged_attn_cover(
+        paged_attention.KEY_LOOP, heads, 16, BS, np.asarray([120]), np.asarray([127]), True)
     assert window == 3 * 6 * 2 * 16 and every == window + 2 * 4 * 8 * 16
     # one tile: every layer covers the table whole
-    monkeypatch.setattr(gpt, "_ATTN_TILE_KEYS", 1 << 20)
+    monkeypatch.setattr(paged_attention, "_ATTN_TILE_KEYS", 1 << 20)
     real = np.asarray([True, True])
-    run, _ = gpt.paged_attn_keys(2, 16, BS, np.asarray([5, 9]), real)
-    window, every = gpt.paged_attn_head_keys(
-        gpt.attn_heads_by_window(cfg), run, 16, BS, np.asarray([5, 9]), np.asarray([5, 9]), real)
+    _, _, window, every = paged_attention.paged_attn_cover(
+        paged_attention.ONE_SHOT, heads, 16, BS, np.asarray([5, 9]), np.asarray([5, 9]), real)
     assert (window, every) == (18 * 2 * 128, 26 * 2 * 128)
 
 

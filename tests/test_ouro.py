@@ -192,10 +192,10 @@ def test_verify_step_equals_sequential_decode(case, form, tile_keys):
 
 @pytest.mark.parametrize("form", list(FORMS))
 def test_decode_over_heads_of_a_whole_lane_tile_matches_the_reference(form, tile_keys):
-    """Heads of 128 features, one query a head: the decode step's attention
-    is two matrix products over the rows as the pool lays them
-    (`_paged_layers`, `lone`), beside a padding lane; prefill and the
-    reference keep the per-head form."""
+    """Heads of 128 features, one query a head: the decode step, the shape
+    the decode kernel takes on the chip, in the per-head form it takes off
+    it (one shot and key loop), beside a padding lane, against the
+    reference."""
     import jax
     import jax.numpy as jnp
 

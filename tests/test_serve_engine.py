@@ -1228,6 +1228,7 @@ class TestPagedPrograms:
         import jax.numpy as jnp
 
         from ray_tpu.models import gpt
+        from ray_tpu.ops import paged_attention
 
         cfg = _tiny_cfg()
         params = jax.eval_shape(
@@ -1237,7 +1238,7 @@ class TestPagedPrograms:
 
         def inner_loops(tile):
             with pytest.MonkeyPatch.context() as mp:
-                mp.setattr(gpt, "_ATTN_TILE_KEYS", tile)
+                mp.setattr(paged_attention, "_ATTN_TILE_KEYS", tile)
                 jaxpr = jax.make_jaxpr(
                     lambda *a: gpt.decode_step_paged(*a, cfg)
                 )(params, i32(4), i32(4), i32(4, 8), kv)
@@ -1245,8 +1246,8 @@ class TestPagedPrograms:
             return [e for e in scan.params["jaxpr"].jaxpr.eqns
                     if e.primitive.name == "while"]
 
-        assert gpt.paged_attn_tiling(8, _BS) == (8, 1)      # the module's own tile
-        assert inner_loops(gpt._ATTN_TILE_KEYS) == []
+        assert paged_attention.paged_attn_tiling(8, _BS) == (8, 1)      # the module's own tile
+        assert inner_loops(paged_attention._ATTN_TILE_KEYS) == []
         assert inner_loops(8 * _BS) == []
         assert len(inner_loops(TILED)) == 1
 
@@ -1278,7 +1279,7 @@ class TestPagedPrograms:
         assert finite == {ONE_SHOT: False, TILED: True}
 
     def test_host_counts_keys_with_the_programs_own_bounds(self):
-        """`paged_attn_keys`, what the engine counts a dispatched program's
+        """`paged_attn_cover`, what the engine counts a dispatched program's
         attention by, at the module's own tile: the trips of the longest
         REAL lane over every lane of the bucket, against the padded tables;
         a table of one tile counts whole. The bounds are one function for
@@ -1287,23 +1288,28 @@ class TestPagedPrograms:
         import numpy as np
 
         from ray_tpu.models import gpt
+        from ray_tpu.ops import paged_attention
+        from ray_tpu.ops.paged_attention import KEY_LOOP, paged_attn_trips
 
-        T = gpt._ATTN_TILE_KEYS
-        assert gpt.paged_attn_tiling(256, 64) == (T // 64, 256 * 64 // T)
+        def keys(form, width, block, pos, real):    # (run, padded) in a global layer
+            return paged_attention.paged_attn_cover(form, (), width, block, pos, pos, real)[:2]
+
+        T = paged_attention._ATTN_TILE_KEYS
+        assert paged_attention.paged_attn_tiling(256, 64) == (T // 64, 256 * 64 // T)
         pos = np.asarray([9 * T - 24, 300, 0, 0])
         real = np.asarray([True, True, False, False])
-        assert gpt.paged_attn_keys(4, 256, 64, pos, real) == (4 * 9 * T, 4 * 256 * 64)
-        assert gpt.paged_attn_keys(4, 256, 64, pos[::-1], real) == (4 * T, 4 * 256 * 64)
-        assert gpt.paged_attn_keys(1, 256, 64, np.asarray([511]), True) == (T, 256 * 64)
-        assert gpt.paged_attn_keys(2, T // 64, 64, pos[:2] % T, True) == (2 * T, 2 * T)
+        assert keys(KEY_LOOP, 256, 64, pos, real) == (4 * 9 * T, 4 * 256 * 64)
+        assert keys(KEY_LOOP, 256, 64, pos[::-1], real) == (4 * T, 4 * 256 * 64)
+        assert keys(KEY_LOOP, 256, 64, np.asarray([511]), True) == (T, 256 * 64)
+        assert keys(paged_attention.ONE_SHOT, T // 64, 64, pos[:2] % T, True) == (2 * T, 2 * T)
         rng = np.random.default_rng(0)
         last = rng.integers(0, 16 * T, (50, 8))
         first = last - rng.integers(0, 600, (50, 8))
         real = rng.random((50, 8)) < 0.7
         for window in (gpt._NO_WINDOW, 4 * T, T + 5):
             for f, l, r in zip(first, last, real):
-                want = gpt.paged_attn_trips(np, f, l, r, window, T, 16)
-                got = gpt.paged_attn_trips(
+                want = paged_attn_trips(np, f, l, r, window, T, 16)
+                got = paged_attn_trips(
                     jnp, jnp.asarray(f), jnp.asarray(l), jnp.asarray(r), window, T, 16)
                 assert (np.asarray(got[0]) == want[0]).all() and int(got[1]) == want[1]
                 # every key a real lane's queries may see lies inside the bounds
@@ -1322,8 +1328,9 @@ class TestPagedPrograms:
         import jax.numpy as jnp
 
         from ray_tpu.models import gpt
+        from ray_tpu.ops import paged_attention
 
-        monkeypatch.setattr(gpt, "_ATTN_TILE_KEYS", TILED)
+        monkeypatch.setattr(paged_attention, "_ATTN_TILE_KEYS", TILED)
         cfg = _tiny_cfg()
         params = jax.eval_shape(
             lambda k: gpt.init_params(k, cfg), jax.random.PRNGKey(0))
@@ -1470,7 +1477,8 @@ def test_two_kinds_of_layer_compile_for_v5e_over_one_pool_in_place(v5e_chip):
     chunk's scores are one tile's and not the lane's 256 blocks', and a
     decode step of a few lanes keeps the expert stacks whole (no copy of a
     layer's experts into the loop)."""
-    from ray_tpu.models.gpt import _ATTN_TILE_KEYS, CONFIGS, kv_layout
+    from ray_tpu.models.gpt import CONFIGS, kv_layout
+    from ray_tpu.ops.paged_attention import _ATTN_TILE_KEYS
     from scripts.paged_rehearse import rehearse
 
     cfg = CONFIGS["smallthinker-21b-a3b"](
@@ -2085,7 +2093,7 @@ CHUNK_KERNEL_SHAPES = {
 
 @pytest.mark.parametrize("shape", list(CHUNK_KERNEL_SHAPES))
 def test_the_chunk_attention_kernel_compiles_for_v5e_at_the_cells_widths(v5e_chip, shape):
-    """Compile-only: `ops/attention.py`'s `paged_chunk_attention` over a
+    """Compile-only: `ops/paged_attention.py`'s `paged_chunk_attention` over a
     table of 4 tiles of 1,024 keys at the widths the serving cells bring it
     (a latent chunk's 32,768 folded rows of 640, a grouped chunk's 3,584 of
     128, a verify step's 3 rows a head padded to a sublane tile): its tiles
@@ -2095,8 +2103,8 @@ def test_the_chunk_attention_kernel_compiles_for_v5e_at_the_cells_widths(v5e_chi
     import jax.numpy as jnp
     from jax.sharding import SingleDeviceSharding
 
-    from ray_tpu.models.gpt import _ATTN_TILE_KEYS, _NO_WINDOW
-    from ray_tpu.ops import attention
+    from ray_tpu.ops import paged_attention
+    from ray_tpu.ops.paged_attention import _ATTN_TILE_KEYS, NO_WINDOW
 
     heads, rows, key_row, value_row, lanes = CHUNK_KERNEL_SHAPES[shape]
     one_chip = SingleDeviceSharding(v5e_chip)
@@ -2105,8 +2113,8 @@ def test_the_chunk_attention_kernel_compiles_for_v5e_at_the_cells_widths(v5e_chi
     dv = value_row or 512
 
     def run(q, k, v, qpos, first, trips):
-        return attention.paged_chunk_attention(
-            q, k, v, qpos, first, trips, _NO_WINDOW, tile_keys=_ATTN_TILE_KEYS,
+        return paged_attention.paged_chunk_attention(
+            q, k, v, qpos, first, trips, NO_WINDOW, tile_keys=_ATTN_TILE_KEYS,
             dv=dv, sm_scale=0.1)
 
     compiled = _within(120, lambda: jax.jit(run).lower(
@@ -2114,7 +2122,7 @@ def test_the_chunk_attention_kernel_compiles_for_v5e_at_the_cells_widths(v5e_chi
         arr(jnp.bfloat16, lanes, keys, heads * key_row),
         None if value_row is None else arr(jnp.bfloat16, lanes, keys, heads * dv),
         arr(jnp.int32, lanes, rows), arr(jnp.int32, lanes), arr(jnp.int32)).compile())
-    assert attention.PAGED_CHUNK_KERNEL in compiled.as_text()
+    assert paged_attention.PAGED_CHUNK_KERNEL in compiled.as_text()
     out = lanes * heads * rows * dv * 2
     assert compiled.memory_analysis().temp_size_in_bytes <= 2 * out + (1 << 20)
 
@@ -2167,7 +2175,7 @@ DECODE_KERNEL_SHAPES = {
 
 @pytest.mark.parametrize("shape", list(DECODE_KERNEL_SHAPES))
 def test_the_decode_attention_kernel_compiles_for_v5e_at_the_cells_widths(v5e_chip, shape):
-    """Compile-only: `ops/attention.py`'s `paged_decode_attention` at the
+    """Compile-only: `ops/paged_attention.py`'s `paged_decode_attention` at the
     widths the serving cells bring it, in bfloat16, by the chip's compiler:
     its two buffers of a DMA group fit the chip's fast memory, the pool is an
     operand as it lies (no copy of it among the temporaries)."""
@@ -2175,8 +2183,8 @@ def test_the_decode_attention_kernel_compiles_for_v5e_at_the_cells_widths(v5e_ch
     import jax.numpy as jnp
     from jax.sharding import SingleDeviceSharding
 
-    from ray_tpu.models.gpt import _NO_WINDOW
-    from ray_tpu.ops import attention
+    from ray_tpu.ops import paged_attention
+    from ray_tpu.ops.paged_attention import NO_WINDOW
 
     heads, R, key_row, value_row, bs, width, lanes = DECODE_KERNEL_SHAPES[shape]
     one_chip = SingleDeviceSharding(v5e_chip)
@@ -2185,8 +2193,8 @@ def test_the_decode_attention_kernel_compiles_for_v5e_at_the_cells_widths(v5e_ch
     pool = (3, 512, bs)
 
     def run(q, k, v, slot, table, pos, real):
-        return attention.paged_decode_attention(
-            q, k, v, slot, table, pos, real, _NO_WINDOW, dv=dv, sm_scale=0.1)
+        return paged_attention.paged_decode_attention(
+            q, k, v, slot, table, pos, real, NO_WINDOW, dv=dv, sm_scale=0.1)
 
     compiled = _within(120, lambda: jax.jit(run).lower(
         arr(jnp.bfloat16, lanes, heads, R, key_row),
@@ -2194,36 +2202,49 @@ def test_the_decode_attention_kernel_compiles_for_v5e_at_the_cells_widths(v5e_ch
         None if value_row is None else arr(jnp.bfloat16, *pool, heads * dv),
         arr(jnp.int32), arr(jnp.int32, lanes, width), arr(jnp.int32, lanes),
         arr(jnp.bool_, lanes)).compile())
-    assert attention.PAGED_DECODE_KERNEL in compiled.as_text()
+    assert paged_attention.PAGED_DECODE_KERNEL in compiled.as_text()
     layer = pool[1] * bs * heads * key_row * 2
     assert compiled.memory_analysis().temp_size_in_bytes < layer // 2
 
 
 # ------------------------------------------- the decode step's attention kernel
-@pytest.mark.parametrize("by_lane", [False, True], ids=["gather", "kernel-rule"])
-def test_decode_programs_are_counted_on_the_programs_rule(tiny_engine_parts, by_lane):
-    """`attn_decodes_kernel` counts the decode programs whose shapes the rule
-    sends to the decode kernel, beside `decode_dispatched`; such a program's
+@pytest.mark.parametrize("kernel", [False, True], ids=["gather", "kernel-rule"])
+def test_decode_programs_are_counted_on_the_programs_rule(tiny_engine_parts, kernel):
+    """`attn_decodes_kernel` counts the decode programs whose form the rule
+    says is the decode kernel, beside `decode_dispatched`; such a program's
     keys are each real lane's own blocks (`attn_keys_run` falls, the padded
-    tables' keys do not move). The rule is stubbed on the HOST alone: the
-    tokens are the same programs'."""
-    cfg, params = tiny_engine_parts
-    eng = _make_engine(cfg, params)
-    assert eng._decode_kernel(cfg, 1, eng.opts.block_size) is False    # off the chip
-    asked = []
-    eng._decode_kernel = lambda *shapes: asked.append(shapes) or by_lane
+    tables' keys do not move). The engine asks the rule once a dispatched
+    program. The rule is stubbed on the HOST alone: the tokens are the same
+    programs'."""
+    import types
+
     import numpy as np
 
+    from ray_tpu.ops import paged_attention
+
+    cfg, params = tiny_engine_parts
+    eng = _make_engine(cfg, params)
+    rule, asked = paged_attention.paged_attn_form, []
+    assert rule(1, 8, *eng._attn_shapes) == paged_attention.ONE_SHOT       # off the chip
+
+    def form(tokens, width, *shapes):
+        asked.append((tokens, *shapes))
+        return (paged_attention.DECODE_KERNEL if kernel and tokens == 1
+                else rule(tokens, width, *shapes))
+
+    eng._paged_attention = types.SimpleNamespace(
+        **{**vars(paged_attention), "paged_attn_form": form})
     by_hand = [0, 0]
     count = eng._count_attn
 
-    def counted(lanes, width, last_pos, real, first_pos=None, by_lane=False):
+    def counted(tokens, width, last_pos, real, first_pos=None):
         if first_pos is None:       # a decode program
+            lanes = np.size(last_pos)
             real = np.asarray(real) & np.ones(lanes, bool)
             by_hand[0] += int((np.asarray(last_pos)[real] // eng.opts.block_size + 1).sum()
                               ) * eng.opts.block_size
             by_hand[1] += lanes * width * eng.opts.block_size
-        return count(lanes, width, last_pos, real, first_pos, by_lane)
+        return count(tokens, width, last_pos, real, first_pos)
 
     eng._count_attn = counted
     before = eng.stats()
@@ -2231,12 +2252,14 @@ def test_decode_programs_are_counted_on_the_programs_rule(tiny_engine_parts, by_
     _drive(eng)
     st = eng.stats()
     assert st["decode_dispatched"] > 10 and type(st["attn_decodes_kernel"]) is int
-    assert st["attn_decodes_kernel"] == (st["decode_dispatched"] if by_lane else 0)
-    assert asked and set(asked) == {(eng.cfg, 1, eng.opts.block_size)}
+    assert st["attn_decodes_kernel"] == (st["decode_dispatched"] if kernel else 0)
+    assert len(asked) == st["decode_dispatched"] + st["attn_chunks"]
+    assert {shapes[1:] for shapes in asked} == {eng._attn_shapes}
+    assert sum(shapes[0] == 1 for shapes in asked) == st["decode_dispatched"]
     prefill_keys = sum(      # what the chunk programs counted, the same either way
         -(-len(prompt) // 4) * 4 for prompt, _ in MIXED)
     run = st["attn_keys_run"] - before["attn_keys_run"]
-    if by_lane:
+    if kernel:
         assert run == by_hand[0] + prefill_keys
     else:
         assert run > by_hand[0] + prefill_keys
@@ -2256,7 +2279,7 @@ def test_a_mixed_run_compiles_the_parents_programs_with_the_decode_kernel(monkey
     from jax.experimental.pallas import tpu as pltpu
 
     from ray_tpu.models import gpt
-    from ray_tpu.ops import attention, norms
+    from ray_tpu.ops import attention, norms, paged_attention
     from ray_tpu.serve.engine import engine as engine_module
 
     cfg = gpt.GPTConfig(**{**TINY, "n_heads": 2, "d_head": 128, "rotary_dim": 32},
@@ -2269,8 +2292,8 @@ def test_a_mixed_run_compiles_the_parents_programs_with_the_decode_kernel(monkey
             mp.setattr(engine_module, "_JITS", None)
             if by_kernel:
                 mp.setattr(attention, "_on_tpu", lambda: True)
-                mp.setattr(attention, "paged_decode_attention", functools.partial(
-                    attention.paged_decode_attention, interpret=pltpu.InterpretParams()))
+                mp.setattr(paged_attention, "paged_decode_attention", functools.partial(
+                    paged_attention.paged_decode_attention, interpret=pltpu.InterpretParams()))
                 mp.setattr(norms, "_rmsnorm_pallas", norms._rmsnorm_ref)
             eng = _make_engine(cfg, params, block_size=8, num_blocks=32,
                                prefill_chunk_tokens=8, max_step_tokens=32)
