@@ -353,7 +353,7 @@ def _kernel_errors(cfg, seq: int) -> dict:
 
         return jax.jit(jax.grad(loss, argnums=(0, 1, 2)))(q, k, v)
 
-    got = grads(lambda q, k, v: A._flash(q, k, v, True, scale, 1024, 1024))
+    got = grads(lambda q, k, v: A.flash_attention(q, k, v, True, scale, 1024, 1024))
     want = grads(lambda q, k, v: A.attention_reference(q, k, v, True, scale))
     errors = {
         "flash_fwd": err(out, ref),

@@ -38,7 +38,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..ops import flash_attention, layernorm, paged_attention, ring_attention, rmsnorm, rope_frequencies, rotate_half
-from ..ops.attention import attention_reference, ulysses_attention
+from ..ops.attention import attention_reference, heads_a_step, ulysses_attention
 from ..parallel.mesh import ShardingRules
 
 
@@ -1132,15 +1132,74 @@ def _attention(cfg: GPTConfig, q, k, v, mesh=None):
     return shard_fn(impl, mesh, in_specs=(spec,) * 3, out_specs=spec)(q, k, v)
 
 
+@jax.custom_vjp
+def _flat_proj(h, w):
+    """h [B, S, E] x w [E, F] -> [B, S, F], with the weights' gradient held
+    apart from what becomes of it: see `_flat_proj_bwd`."""
+    return jnp.einsum("bse,ef->bsf", h, w)
+
+
+def _flat_proj_fwd(h, w):
+    return _flat_proj(h, w), (h, w)
+
+
+def _flat_proj_bwd(res, g):
+    """The product's own two gradients, the weights' behind a barrier. Left
+    to itself XLA folds the reshape of dw [E, F] to the parameter's [E, heads,
+    Dh] INTO the product, which then wants g laid (heads, Dh)-major, and
+    turns all of g, the kernels' dq, dk and dv, to [B, F, S] first (three of
+    gpt2-large's eleven copies a layer: 34 MB each for a result of 3 MB;
+    PERF.md §6, PR 49)."""
+    h, w = res
+    dw = jax.lax.optimization_barrier(jnp.einsum("bse,bsf->ef", h, g))
+    return jnp.einsum("bsf,ef->bse", g, w), dw
+
+
+_flat_proj.defvjp(_flat_proj_fwd, _flat_proj_bwd)
+
+
+def _heads_flat(tokens: int, embed: int, heads: int, head_dim: int) -> bool:
+    """Whether the projections to and from heads are said FLAT, over [B, S,
+    heads·Dh], and not with heads and Dh named apart: where the flash kernels
+    read and write that form (`ops/attention.py` `heads_a_step`: heads of 64
+    by the pair) AND the activations are the larger operand, more tokens
+    than E. Named, a result is laid out in tiles of (heads, Dh), half of
+    every tile empty at 64, and XLA took every array of a layer's attention
+    through sequence-minor copies to get there and back (gpt2-large's train
+    step: eleven a layer, 34 MB each). Flat, it is the WEIGHTS that are
+    reshaped, which a served program would first have to write out where it
+    reads them out of the stack INTO the product (PERF.md §6, PR 49)."""
+    return tokens > embed and heads_a_step(heads, head_dim) > 0
+
+
 def _project_qkv(cfg: GPTConfig, p, h):
     """h [B, S, E] -> q [B, S, H, Dh], k and v [B, S, Hkv, Dh]: the fused
-    multi-head w_qkv, or the grouped-query pair w_q / w_kv."""
+    multi-head w_qkv, or the grouped-query pair w_q / w_kv. Flat
+    (`_heads_flat`): a product each, from a slice of the WEIGHTS (a slice of
+    one product's result is a copy of the activations, and a reshape of [3,
+    heads, Dh] cannot keep a sharding of the heads)."""
     if "w_qkv" in p:
+        (B, S, E), (H, D) = h.shape, p["w_qkv"].shape[2:]
+        if _heads_flat(B * S, E, H, D):
+            return tuple((_flat_proj(h, p["w_qkv"][:, t].reshape(E, H * D))
+                          + p["b_qkv"][t].reshape(H * D)).reshape(B, S, H, D)
+                         for t in range(3))
         qkv = jnp.einsum("bse,ethd->btshd", h, p["w_qkv"]) + p["b_qkv"][:, None]
         return qkv[:, 0], qkv[:, 1], qkv[:, 2]
     q = jnp.einsum("bse,ehd->bshd", h, p["w_q"])
     kv = jnp.einsum("bse,ethd->btshd", h, p["w_kv"])
     return q, kv[:, 0], kv[:, 1]
+
+
+def _merge_heads(attn, w_o):
+    """attn [B, heads, S, Dh] x w_o [heads, Dh, E] -> [B, S, E]; flat
+    (`_heads_flat`), what the kernels wrote, [B, S, heads·Dh], is the
+    product's operand as it lies."""
+    B, H, S, D = attn.shape
+    if _heads_flat(B * S, w_o.shape[-1], H, D):
+        return jnp.einsum("bsf,fe->bse", attn.transpose(0, 2, 1, 3).reshape(B, S, H * D),
+                          w_o.reshape(H * D, -1))
+    return jnp.einsum("bhsd,hde->bse", attn, w_o)
 
 
 def _dense_mlp(cfg: GPTConfig, p, mlp_in):
@@ -1428,7 +1487,7 @@ def _block(cfg: GPTConfig, rope_tables, attend, x, layer_params, positions,
                 "bse,eh->bhs", h.astype(jnp.float32), p["w_head_gate"].astype(jnp.float32)))
             attn = (attn * gate[..., None]).astype(attn.dtype)
     if mixer is None:
-        attn_out = jnp.einsum("bhsd,hde->bse", attn, p["w_o"])
+        attn_out = _merge_heads(attn, p["w_o"])
         if "b_o" in p:
             attn_out = attn_out + p["b_o"]
     if cfg.sandwich_norm:

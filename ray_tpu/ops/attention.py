@@ -14,7 +14,9 @@ it is first-class:
   * `ulysses_attention` — all_to_all head<->seq exchange so each device
     runs full-sequence attention on a head subset.
 
-Shapes follow [batch, heads, seq, head_dim] throughout.
+Shapes follow [batch, heads, seq, head_dim] throughout; the flash kernels'
+own operands lie as the projections lay them, [batch, seq, heads * head_dim]
+(`heads_a_step`), and the wrappers own the way between the two.
 """
 
 from __future__ import annotations
@@ -51,8 +53,9 @@ def attention_reference(q, k, v, causal: bool = True, sm_scale: Optional[float] 
 #
 # All three kernels stream K/V (or Q for dk/dv) block-by-block from HBM via a
 # third grid axis instead of holding the whole sequence in VMEM: grid =
-# (batch*heads, outer blocks, streamed blocks), with the running accumulators
-# in VMEM scratch that persists across the innermost ("arbitrary") axis.
+# (batch*heads a step, outer blocks, streamed blocks), with the running
+# accumulators in VMEM scratch that persists across the innermost
+# ("arbitrary") axis.
 # VMEM per step is O(block) not O(S), so a single chip runs S=16k+ (the old
 # whole-KV layout hit the 16 MiB scoped-vmem wall at 16k — VERDICT r3 §weak 1).
 # Causal skipping: the streamed index map clamps past-diagonal steps to the
@@ -90,6 +93,40 @@ def _sub_tile(kernel: str, head_dim: int) -> int:
     if kernel == "flash_fwd" and head_dim < 128:
         return 512
     return 256
+
+
+def heads_a_step(heads: int, head_dim: int) -> int:
+    """Heads a grid step takes when q, k, v, dO, o, dq, dk and dv lie as the
+    projections lay them, [B, S, H·Dh], and a step's block is a COLUMN block
+    of one lane tile: as many heads as fill 128 lanes (a pair of 64: the body
+    runs once a head, on its half of the block, and the results are stored
+    128 lanes wide). 0 where no whole heads fill a lane tile (an odd count of
+    heads of 64, the tiny heads of the CPU's tests) and where a head fills
+    whole tiles by itself (gptj-6b's 256): those operands are laid [B·H, S,
+    Dh], a head a ROW, and a transposed caller pays the relayouts. A head of
+    256 a column block was built, and read WORSE on the chip than its rows:
+    the kernels alone 67.7 us a head for 67.2, more copy bytes in the
+    compiled step (XLA lays the projections' results in tiles of (heads, Dh)
+    either way), `gptj-6b.train-fsdp4` -1.7% (PERF.md §6, PR 49)."""
+    g = 128 // head_dim
+    return g if g >= 2 and g * head_dim == 128 and heads % g == 0 else 0
+
+
+def _to_operand(x):
+    """[B, H, S, Dh] -> the kernels' operand, [B, S, H·Dh] or [B·H, S, Dh]. A
+    caller that transposed the projections' [B, S, H, Dh] to get here (as
+    `models/gpt.py` `_block` does for `attend`) sees XLA cancel the pair."""
+    B, H, S, D = x.shape
+    if heads_a_step(H, D):
+        return x.transpose(0, 2, 1, 3).reshape(B, S, H * D)
+    return x.reshape(B * H, S, D)
+
+
+def _from_operand(x, batch: int, heads: int):
+    """`_to_operand`'s inverse: -> [B, H, S, Dh]."""
+    if x.shape[0] != batch:     # a head a row
+        return x.reshape(batch, heads, *x.shape[1:])
+    return x.reshape(batch, x.shape[1], heads, -1).transpose(0, 2, 1, 3)
 
 
 def _flash_blocks(S: int, Skv: int, D: int, block_q: int, block_k: int):
@@ -239,12 +276,44 @@ def _tn(x, y):
                                preferred_element_type=jnp.float32)
 
 
+def _head_lanes(x, r: int, g: int, D: int, cut: bool = False):
+    """Head r of x [n, g·D], a block of g heads' columns (`heads_a_step`), as
+    an operand of a product: the block with the OTHER heads' lanes zeroed, so
+    that a contraction over all g·D lanes against the whole block of the other
+    operand is head r's alone and no lane moves (128 lanes half zero cost the
+    MXU the passes 64 lanes cost), or, `cut`, its D lanes alone (a static
+    lane slice). What the chip said at a pair of 64 (`scripts.flash_time`, us
+    a head, PERF.md §6, PR 49): every score product and dq, dk, dv by zeroed
+    lanes (cut operands: dq 2.84 -> 3.02, dk/dv 3.67 -> 3.72); the forward's
+    V cut, because Vᵀ·Pᵀ over the whole block computes both heads' rows (2.58
+    -> 2.11; q and k cut as well 2.12)."""
+    if g == 1:
+        return x
+    if cut:
+        return x[:, r * D:(r + 1) * D]
+    lane = jax.lax.broadcasted_iota(jnp.int32, x.shape, 1)
+    return jnp.where((lane >= r * D) & (lane < (r + 1) * D), x, jnp.zeros_like(x))
+
+
+def _join_lanes(parts, D: int):
+    """The heads' results [n, g·D], head r's lanes true in parts[r] (a
+    product against a whole block, `_head_lanes`) -> [n, g·D], every lane
+    true: stored 128 lanes wide."""
+    out = parts[-1]
+    if len(parts) > 1:
+        lane = jax.lax.broadcasted_iota(jnp.int32, out.shape, 1)
+        for r in range(len(parts) - 2, -1, -1):
+            out = jnp.where(lane < (r + 1) * D, parts[r], out)
+    return out
+
+
 def _flash_fwd_kernel(
     q_ref,
     k_ref,
     v_ref,
     o_ref,
     *rest,  # ([lse_ref,] acc_ref, m_ref, l_ref) — lse only on the training path
+    head_dim: int,
     nq: int,
     nk: int,
     causal: bool,
@@ -252,7 +321,8 @@ def _flash_fwd_kernel(
     seq_q: int,
     seq_kv: int,
 ):
-    """One (q block, k block) grid step of the online-softmax forward.
+    """One (q block, k block) grid step of the online-softmax forward, for
+    the g heads whose columns the block holds (`heads_a_step`).
 
     Inputs are PADDED to block multiples by the caller (pl.ds on a ragged
     tail clamps the start index, silently misaligning data vs mask — so
@@ -269,7 +339,8 @@ def _flash_fwd_kernel(
     qi = pl.program_id(1)
     j = pl.program_id(2)
     block_q, block_k = q_ref.shape[1], k_ref.shape[1]
-    tq, tk = _sub_tiles("flash_fwd", q_ref.shape[2], block_q, block_k)
+    D, g = head_dim, q_ref.shape[2] // head_dim
+    tq, tk = _sub_tiles("flash_fwd", D, block_q, block_k)
 
     if nk != 1:
         @pl.when(j == 0)
@@ -278,14 +349,18 @@ def _flash_fwd_kernel(
             m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
             l_ref[...] = jnp.zeros_like(l_ref)
 
-    def _flush(rows, start, acc, m, l):
-        o_ref[0, rows] = (acc / jnp.maximum(l, 1e-30)).T.astype(o_ref.dtype)
+    def _flush(rows, start, accs, ms, ls):
+        """A head's (acc [D, n], m, l [1, n]) each: o's rows, g·D lanes wide."""
+        out = [acc / jnp.maximum(l, 1e-30) for acc, l in zip(accs, ls)]
+        out = out[0] if g == 1 else jnp.concatenate(out, axis=0)
+        o_ref[0, rows] = out.T.astype(o_ref.dtype)
         if lse_ref is not None:
             # logsumexp per row — the only softmax statistic backward needs.
-            # The lse block is the full (1, 1, S_p) row; each qi writes its
+            # The lse block is the full (g, 1, S_p) rows; each qi writes its
             # slice, covering S_p by the time the bh block flushes.
-            lse_ref[0, :, pl.ds(qi * block_q + start, acc.shape[1])] = (
-                m + jnp.log(jnp.maximum(l, 1e-30)))
+            for r, (m, l) in enumerate(zip(ms, ls)):
+                lse_ref[r, :, pl.ds(qi * block_q + start, m.shape[1])] = (
+                    m + jnp.log(jnp.maximum(l, 1e-30)))
 
     # The scores are computed TRANSPOSED, [keys, queries]: a row's maximum and
     # sum run ACROSS registers, not along the lanes of each (1.5 us of a
@@ -297,50 +372,78 @@ def _flash_fwd_kernel(
             rows = slice(a * tq, (a + 1) * tq)
             if not runs:  # rows that see no key of this block (Skv < S)
                 if nk == 1:
-                    _flush(rows, a * tq, jnp.zeros((q_ref.shape[2], tq), jnp.float32),
-                           jnp.full((1, tq), _NEG_INF), jnp.zeros((1, tq), jnp.float32))
+                    _flush(rows, a * tq, [jnp.zeros((D, tq), jnp.float32)] * g,
+                           [jnp.full((1, tq), _NEG_INF)] * g,
+                           [jnp.zeros((1, tq), jnp.float32)] * g)
                 continue
             cols = slice(runs[0][0] * tk, runs[-1][1] * tk)
-            st = _nt(k_ref[0, cols], q_ref[0, rows]) * sm_scale  # [keys, tq] f32
-            st = _mask_runs(masks, st, runs, tk, _NEG_INF, 1, 0)
-            m = jnp.max(st, axis=0, keepdims=True)  # [1, tq]
-            if nk != 1:
-                m_prev = m_ref[:, rows]
-                m = jnp.maximum(m_prev, m)
-            pt = jnp.exp(st - m)
-            l = jnp.sum(pt, axis=0, keepdims=True)
-            acc = _tn(v_ref[0, cols], pt.astype(v_ref.dtype))  # Vᵀ·Pᵀ: [D, tq]
+            q_blk, k_blk, v_blk = q_ref[0, rows], k_ref[0, cols], v_ref[0, cols]
+            state = []
+            for r in range(g):
+                head = slice(r * D, (r + 1) * D)
+                st = _nt(k_blk, _head_lanes(q_blk, r, g, D)) * sm_scale
+                st = _mask_runs(masks, st, runs, tk, _NEG_INF, 1, 0)  # [keys, tq] f32
+                m = jnp.max(st, axis=0, keepdims=True)  # [1, tq]
+                if nk != 1:
+                    m_prev = m_ref[r:r + 1, rows]
+                    m = jnp.maximum(m_prev, m)
+                pt = jnp.exp(st - m)
+                l = jnp.sum(pt, axis=0, keepdims=True)
+                # Vᵀ·Pᵀ: [D, tq]
+                acc = _tn(_head_lanes(v_blk, r, g, D, cut=True), pt.astype(v_blk.dtype))
+                if nk == 1:
+                    state.append((acc, m, l))
+                else:
+                    alpha = jnp.exp(m_prev - m)
+                    m_ref[r:r + 1, rows] = m
+                    l_ref[r:r + 1, rows] = l_ref[r:r + 1, rows] * alpha + l
+                    acc_ref[head, rows] = acc_ref[head, rows] * alpha + acc
             if nk == 1:
                 # One grid step a q block: its state never leaves values.
-                _flush(rows, a * tq, acc, m, l)
-            else:
-                alpha = jnp.exp(m_prev - m)
-                m_ref[:, rows] = m
-                l_ref[:, rows] = l_ref[:, rows] * alpha + l
-                acc_ref[:, rows] = acc_ref[:, rows] * alpha + acc
+                _flush(rows, a * tq, *zip(*state))
 
     _walk_step(qi, j, nq, nk, block_q, block_k, tq, tk, causal, seq_q, seq_kv, False, _step)
 
     if nk != 1:
         @pl.when(j == nk - 1)
         def _last():
-            _flush(slice(None), 0, acc_ref[...], m_ref[...], l_ref[...])
+            _flush(slice(None), 0, *zip(*(
+                (acc_ref[r * D:(r + 1) * D], m_ref[r:r + 1], l_ref[r:r + 1])
+                for r in range(g))))
 
 
-def _flash_fwd_pallas(q, k, v, causal: bool, sm_scale: float, block_q: int, block_k: int,
-                      interpret: bool = False, return_lse: bool = False):
+def _head_blocks(x, head_dim: int):
+    """For an operand [B, S, lanes] (`_to_operand`): (heads a grid step g,
+    the grid's first axis B·H/g, `at`: that axis' index -> (batch, column
+    block)). A head a row ([B·H, S, Dh]) is one column block a batch."""
+    B, _, lanes = x.shape
+    H = lanes // head_dim
+    g = heads_a_step(H, head_dim) or 1
+    assert lanes == H * head_dim and (H == 1 or g > 1), (x.shape, head_dim)
+    n = H // g
+
+    def at(bh):
+        return (bh, 0) if n == 1 else (bh // n, bh % n)
+
+    return g, B * n, at
+
+
+def _flash_fwd_call(qr, kr, vr, head_dim: int, causal: bool, sm_scale: float,
+                    block_q: int, block_k: int, interpret: bool = False,
+                    return_lse: bool = False):
+    """The forward over operands as `_to_operand` lays them: -> o in the same
+    form [, lse [B·H, 1, S_p], padded to whole q blocks]."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
-    B, H, S, D = q.shape
-    Skv = k.shape[2]
+    B, S, lanes = qr.shape
+    Skv, D = kr.shape[1], head_dim
+    g, steps, at = _head_blocks(qr, D)
+    W, BH = g * D, B * lanes // D
     block_q, block_k = _flash_blocks(S, Skv, D, block_q, block_k)
     # Pad to block multiples (see kernel docstring for why).
     S_p = -(-S // block_q) * block_q
     Skv_p = -(-Skv // block_k) * block_k
-    qr = q.reshape(B * H, S, D)
-    kr = k.reshape(B * H, Skv, D)
-    vr = v.reshape(B * H, Skv, D)
     if S_p != S:
         qr = jnp.pad(qr, ((0, 0), (0, S_p - S), (0, 0)))
     if Skv_p != Skv:
@@ -349,29 +452,36 @@ def _flash_fwd_pallas(q, k, v, causal: bool, sm_scale: float, block_q: int, bloc
     nq = S_p // block_q
     nk = Skv_p // block_k
     row_offset = Skv - S
-    grid = (B * H, nq, nk)  # kv innermost: scratch accumulates across it
+    grid = (steps, nq, nk)  # kv innermost: scratch accumulates across it
+
+    def q_index(bh, i, j):
+        b, h = at(bh)
+        return (b, i, h)
 
     if causal:
         # Past-diagonal steps re-map to the last relevant block: same index as
         # the previous step ⇒ Pallas skips the DMA; pl.when skips the compute.
         def kv_index(bh, i, j):
-            return (bh, jnp.minimum(j, _causal_last_kv(i, block_q, block_k, row_offset, nk)), 0)
+            b, h = at(bh)
+            return (b, jnp.minimum(j, _causal_last_kv(i, block_q, block_k, row_offset, nk)), h)
     else:
         def kv_index(bh, i, j):
-            return (bh, j, 0)
+            b, h = at(bh)
+            return (b, j, h)
 
-    out_shape = [jax.ShapeDtypeStruct((B * H, S_p, D), q.dtype)]
-    out_specs = [pl.BlockSpec((1, block_q, D), lambda bh, i, j: (bh, i, 0))]
+    out_shape = [jax.ShapeDtypeStruct(qr.shape, qr.dtype)]
+    out_specs = [pl.BlockSpec((1, block_q, W), q_index)]
     if return_lse:  # inference forward skips the lse compute+HBM write
-        out_shape.append(jax.ShapeDtypeStruct((B * H, 1, S_p), jnp.float32))
-        out_specs.append(pl.BlockSpec((1, 1, S_p), lambda bh, i, j: (bh, 0, 0)))
-    # The training path's lse output is ONE (1,1,S_p) block revisited by
+        out_shape.append(jax.ShapeDtypeStruct((BH, 1, S_p), jnp.float32))
+        out_specs.append(pl.BlockSpec((g, 1, S_p), lambda bh, i, j: (bh, 0, 0)))
+    # The training path's lse output is ONE (g,1,S_p) block revisited by
     # every q-block step — its grid dim must stay "arbitrary" or a megacore
     # partition would write back per-core copies of the shared row.
     q_dim_semantics = "arbitrary" if return_lse else "parallel"
     res = pl.pallas_call(
         functools.partial(
             _flash_fwd_kernel,
+            head_dim=D,
             nq=nq,
             nk=nk,
             causal=causal,
@@ -382,28 +492,28 @@ def _flash_fwd_pallas(q, k, v, causal: bool, sm_scale: float, block_q: int, bloc
         out_shape=tuple(out_shape),
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1, block_q, D), lambda bh, i, j: (bh, i, 0)),
-            pl.BlockSpec((1, block_k, D), kv_index),
-            pl.BlockSpec((1, block_k, D), kv_index),
+            pl.BlockSpec((1, block_q, W), q_index),
+            pl.BlockSpec((1, block_k, W), kv_index),
+            pl.BlockSpec((1, block_k, W), kv_index),
         ],
         out_specs=tuple(out_specs),
         scratch_shapes=[  # a q block's state across its k blocks, transposed
-            pltpu.VMEM((D, block_q), jnp.float32),
-            pltpu.VMEM((1, block_q), jnp.float32),
-            pltpu.VMEM((1, block_q), jnp.float32),
+            pltpu.VMEM((W, block_q), jnp.float32),
+            pltpu.VMEM((g, block_q), jnp.float32),
+            pltpu.VMEM((g, block_q), jnp.float32),
         ],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", q_dim_semantics, "arbitrary")
         ),
         cost_estimate=pl.CostEstimate(
-            flops=4 * B * H * S * Skv * D,
-            bytes_accessed=2 * (qr.size + kr.size + vr.size) * q.dtype.itemsize,
-            transcendentals=B * H * S * Skv,
+            flops=4 * BH * S * Skv * D,
+            bytes_accessed=2 * (qr.size + kr.size + vr.size) * qr.dtype.itemsize,
+            transcendentals=BH * S * Skv,
         ),
         interpret=interpret,
         name="flash_fwd",
     )(qr, kr, vr)
-    out = res[0][:, :S].reshape(B, H, S, D)
+    out = res[0][:, :S]
     if return_lse:
         return out, res[1]  # lse stays padded/flat — backward consumes it as-is
     return out
@@ -411,7 +521,8 @@ def _flash_fwd_pallas(q, k, v, causal: bool, sm_scale: float, block_q: int, bloc
 
 def _flash_bwd_dq_kernel(
     q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref, dq_acc_ref,
-    *, nq: int, nk: int, causal: bool, sm_scale: float, seq_q: int, seq_kv: int,
+    *, head_dim: int, nq: int, nk: int, causal: bool, sm_scale: float, seq_q: int,
+    seq_kv: int,
 ):
     """dQ for one q block: stream k blocks up to the causal diagonal.
 
@@ -423,7 +534,8 @@ def _flash_bwd_dq_kernel(
     qi = pl.program_id(1)
     j = pl.program_id(2)
     block_q, block_k = q_ref.shape[1], k_ref.shape[1]
-    tq, tk = _sub_tiles("flash_bwd_dq", q_ref.shape[2], block_q, block_k)
+    D, g = head_dim, q_ref.shape[2] // head_dim
+    tq, tk = _sub_tiles("flash_bwd_dq", D, block_q, block_k)
 
     if nk != 1:
         @pl.when(j == 0)
@@ -436,17 +548,21 @@ def _flash_bwd_dq_kernel(
             rows = slice(a * tq, (a + 1) * tq)
             if not runs:
                 if nk == 1:
-                    dq_ref[0, rows] = jnp.zeros((tq, q_ref.shape[2]), dq_ref.dtype)
+                    dq_ref[0, rows] = jnp.zeros((tq, g * D), dq_ref.dtype)
                 continue
             cols = slice(runs[0][0] * tk, runs[-1][1] * tk)
-            do = do_ref[0, rows]  # bf16 — MXU operands stay in input dtype
-            k_blk = k_ref[0, cols]
-            lse = lse_ref[0, 0, rows][:, None]      # [tq, 1]
-            delta = delta_ref[0, 0, rows][:, None]  # [tq, 1]
-            p = jnp.exp(_nt(q_ref[0, rows], k_blk) * sm_scale - lse)  # [tq, keys]
-            p = _mask_runs(masks, p, runs, tk, 0.0, 0, 1)
-            ds = (p * (_nt(do, v_ref[0, cols]) - delta)).astype(k_blk.dtype)
-            dq = _nn(ds, k_blk)
+            # bf16 — MXU operands stay in input dtype
+            q_blk, do_blk = q_ref[0, rows], do_ref[0, rows]
+            k_blk, v_blk = k_ref[0, cols], v_ref[0, cols]
+            dqs = []
+            for r in range(g):
+                lse = lse_ref[r, 0, rows][:, None]      # [tq, 1]
+                delta = delta_ref[r, 0, rows][:, None]  # [tq, 1]
+                p = jnp.exp(_nt(_head_lanes(q_blk, r, g, D), k_blk) * sm_scale - lse)
+                p = _mask_runs(masks, p, runs, tk, 0.0, 0, 1)  # [tq, keys]
+                dp = _nt(_head_lanes(do_blk, r, g, D), v_blk)
+                dqs.append(_nn((p * (dp - delta)).astype(k_blk.dtype), k_blk))
+            dq = _join_lanes(dqs, D)
             if nk == 1:
                 dq_ref[0, rows] = (dq * sm_scale).astype(dq_ref.dtype)
             else:
@@ -463,7 +579,8 @@ def _flash_bwd_dq_kernel(
 def _flash_bwd_dkv_kernel(
     q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_ref, dv_ref,
     dk_acc_ref, dv_acc_ref,
-    *, nq: int, nk: int, causal: bool, sm_scale: float, seq_q: int, seq_kv: int,
+    *, head_dim: int, nq: int, nk: int, causal: bool, sm_scale: float, seq_q: int,
+    seq_kv: int,
 ):
     """dK/dV for one k block: stream q blocks from the causal diagonal down.
 
@@ -474,7 +591,8 @@ def _flash_bwd_dkv_kernel(
     ki = pl.program_id(1)
     i = pl.program_id(2)
     block_q, block_k = q_ref.shape[1], k_ref.shape[1]
-    tq, tk = _sub_tiles("flash_bwd_dkv", q_ref.shape[2], block_q, block_k)
+    D, g = head_dim, q_ref.shape[2] // head_dim
+    tq, tk = _sub_tiles("flash_bwd_dkv", D, block_q, block_k)
 
     if nq != 1:
         @pl.when(i == 0)
@@ -492,16 +610,21 @@ def _flash_bwd_dkv_kernel(
             if not runs:  # padding past the last key: the caller cuts it off
                 continue
             rows = slice(runs[0][0] * tq, runs[-1][1] * tq)
-            q_blk = q_ref[0, rows]  # bf16 — MXU operands stay in input dtype
-            do_blk = do_ref[0, rows]
-            pt = jnp.exp(_nt(k_ref[0, cols], q_blk) * sm_scale - lse_ref[0, :, rows])
-            # The mask's row term: padded q rows must not reach p (exp against
-            # a padded-row lse can overflow to inf, and inf · 0, the
-            # zero-padded dO, would make NaNs).
-            pt = _mask_runs(masks, pt, runs, tq, 0.0, 1, 1)  # [tk, queries]
-            dv = _nn(pt.astype(do_blk.dtype), do_blk)
-            dst = pt * (_nt(v_ref[0, cols], do_blk) - delta_ref[0, :, rows])
-            dk = _nn(dst.astype(q_blk.dtype), q_blk)
+            # bf16 — MXU operands stay in input dtype
+            q_blk, do_blk = q_ref[0, rows], do_ref[0, rows]
+            k_blk, v_blk = k_ref[0, cols], v_ref[0, cols]
+            dks, dvs = [], []
+            for r in range(g):
+                pt = jnp.exp(_nt(_head_lanes(k_blk, r, g, D), q_blk) * sm_scale
+                             - lse_ref[r, :, rows])
+                # The mask's row term: padded q rows must not reach p (exp
+                # against a padded-row lse can overflow to inf, and inf · 0,
+                # the zero-padded dO, would make NaNs).
+                pt = _mask_runs(masks, pt, runs, tq, 0.0, 1, 1)  # [tk, queries]
+                dvs.append(_nn(pt.astype(do_blk.dtype), do_blk))
+                dst = pt * (_nt(_head_lanes(v_blk, r, g, D), do_blk) - delta_ref[r, :, rows])
+                dks.append(_nn(dst.astype(q_blk.dtype), q_blk))
+            dk, dv = _join_lanes(dks, D), _join_lanes(dvs, D)
             if nq == 1:
                 dk_ref[0, cols] = (dk * sm_scale).astype(dk_ref.dtype)
                 dv_ref[0, cols] = dv.astype(dv_ref.dtype)
@@ -518,27 +641,37 @@ def _flash_bwd_dkv_kernel(
             dv_ref[0] = dv_acc_ref[...].astype(dv_ref.dtype)
 
 
-def _flash_bwd_pallas(q, k, v, o, lse, g, causal: bool, sm_scale: float,
-                      block_q: int, block_k: int, interpret: bool = False):
-    """Flash backward: two Pallas passes (dq over q blocks; dk/dv over k
-    blocks) against the saved logsumexp — no S×S materialization."""
+def _flash_bwd_call(qr, kr, vr, o, lse, do, head_dim: int, causal: bool, sm_scale: float,
+                    block_q: int, block_k: int, interpret: bool = False):
+    """Flash backward over operands as `_to_operand` lays them (o and dO
+    too): two Pallas passes (dq over q blocks; dk/dv over k blocks) against
+    the saved logsumexp — no S×S materialization. -> dq, dk, dv, same form."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
-    B, H, S, D = q.shape
-    Skv = k.shape[2]
+    B, S, lanes = qr.shape
+    Skv, D = kr.shape[1], head_dim
+    g, steps, at = _head_blocks(qr, D)
+    W, H = g * D, lanes // D
+    BH = B * H
     block_q, block_k = _flash_blocks(S, Skv, D, block_q, block_k)
     S_p = -(-S // block_q) * block_q
     Skv_p = -(-Skv // block_k) * block_k
 
-    # Δ = rowsum(dO ∘ O) — cheap elementwise, XLA fuses it.
-    delta = jnp.sum(g.astype(jnp.float32) * o.astype(jnp.float32), axis=-1)
-
-    qr = q.reshape(B * H, S, D)
-    kr = k.reshape(B * H, Skv, D)
-    vr = v.reshape(B * H, Skv, D)
-    gr = g.reshape(B * H, S, D)
-    dr = delta.reshape(B * H, 1, S)
+    # Δ = rowsum(dO ∘ O) a head, in float32. Over [B, S, H·Dh] that is a sum
+    # over each head's run of lanes, said as a product against the heads' 0/1
+    # columns at full precision (float32's parts against exact ones: the
+    # float32 sum). As a multiply and a sum over a split lane axis XLA wrote
+    # dO ∘ O out in float32, copied it to sequence-minor and summed it there
+    # (PERF.md §6, PR 49).
+    dr = do.astype(jnp.float32) * o.astype(jnp.float32)
+    if H == 1:
+        dr = jnp.sum(dr, axis=-1)
+    else:
+        seg = (jnp.arange(lanes)[:, None] // D == jnp.arange(H)[None, :]).astype(jnp.float32)
+        dr = jnp.einsum("bsf,fh->bhs", dr, seg, precision=jax.lax.Precision.HIGHEST)
+    dr = dr.reshape(BH, 1, S)
+    gr = do
     if S_p != S:
         qr = jnp.pad(qr, ((0, 0), (0, S_p - S), (0, 0)))
         gr = jnp.pad(gr, ((0, 0), (0, S_p - S), (0, 0)))
@@ -552,45 +685,56 @@ def _flash_bwd_pallas(q, k, v, o, lse, g, causal: bool, sm_scale: float,
     nq = S_p // block_q
     nk = Skv_p // block_k
     row_offset = Skv - S
-    kwargs = dict(nq=nq, nk=nk, causal=causal, sm_scale=sm_scale, seq_q=S, seq_kv=Skv)
+    kwargs = dict(head_dim=D, nq=nq, nk=nk, causal=causal, sm_scale=sm_scale, seq_q=S,
+                  seq_kv=Skv)
+
+    def outer(bh, i, j):  # the block a kernel's outer axis names
+        b, h = at(bh)
+        return (b, i, h)
 
     if causal:
         def kv_index(bh, i, j):
-            return (bh, jnp.minimum(j, _causal_last_kv(i, block_q, block_k, row_offset, nk)), 0)
+            b, h = at(bh)
+            return (b, jnp.minimum(j, _causal_last_kv(i, block_q, block_k, row_offset, nk)), h)
 
-        def q_index(bh, ki, i):
-            return (bh, jnp.maximum(i, _causal_first_q(ki, block_q, block_k, row_offset, nq)), 0)
+        def q_block(ki, i):
+            return jnp.maximum(i, _causal_first_q(ki, block_q, block_k, row_offset, nq))
     else:
         def kv_index(bh, i, j):
-            return (bh, j, 0)
+            b, h = at(bh)
+            return (b, j, h)
 
-        def q_index(bh, ki, i):
-            return (bh, i, 0)
+        def q_block(ki, i):
+            return i
+
+    def q_index(bh, ki, i):
+        b, h = at(bh)
+        return (b, q_block(ki, i), h)
 
     def q_row_index(bh, ki, i):
-        return (bh, 0, q_index(bh, ki, i)[1])
+        return (bh, 0, q_block(ki, i))
 
     dq = pl.pallas_call(
         functools.partial(_flash_bwd_dq_kernel, **kwargs),
-        out_shape=jax.ShapeDtypeStruct((B * H, S_p, D), q.dtype),
-        grid=(B * H, nq, nk),
+        out_shape=jax.ShapeDtypeStruct(qr.shape, qr.dtype),
+        grid=(steps, nq, nk),
         in_specs=[
-            pl.BlockSpec((1, block_q, D), lambda bh, i, j: (bh, i, 0)),
-            pl.BlockSpec((1, block_k, D), kv_index),
-            pl.BlockSpec((1, block_k, D), kv_index),
-            pl.BlockSpec((1, block_q, D), lambda bh, i, j: (bh, i, 0)),
-            pl.BlockSpec((1, 1, block_q), lambda bh, i, j: (bh, 0, i)),
-            pl.BlockSpec((1, 1, block_q), lambda bh, i, j: (bh, 0, i)),
+            pl.BlockSpec((1, block_q, W), outer),
+            pl.BlockSpec((1, block_k, W), kv_index),
+            pl.BlockSpec((1, block_k, W), kv_index),
+            pl.BlockSpec((1, block_q, W), outer),
+            pl.BlockSpec((g, 1, block_q), lambda bh, i, j: (bh, 0, i)),
+            pl.BlockSpec((g, 1, block_q), lambda bh, i, j: (bh, 0, i)),
         ],
-        out_specs=pl.BlockSpec((1, block_q, D), lambda bh, i, j: (bh, i, 0)),
-        scratch_shapes=[pltpu.VMEM((block_q, D), jnp.float32)],
+        out_specs=pl.BlockSpec((1, block_q, W), outer),
+        scratch_shapes=[pltpu.VMEM((block_q, W), jnp.float32)],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")
         ),
         cost_estimate=pl.CostEstimate(
-            flops=6 * B * H * S * Skv * D,
-            bytes_accessed=3 * (qr.size + kr.size + vr.size) * q.dtype.itemsize,
-            transcendentals=B * H * S * Skv,
+            flops=6 * BH * S * Skv * D,
+            bytes_accessed=3 * (qr.size + kr.size + vr.size) * qr.dtype.itemsize,
+            transcendentals=BH * S * Skv,
         ),
         interpret=interpret,
         name="flash_bwd_dq",
@@ -599,42 +743,47 @@ def _flash_bwd_pallas(q, k, v, o, lse, g, causal: bool, sm_scale: float,
     dk, dv = pl.pallas_call(
         functools.partial(_flash_bwd_dkv_kernel, **kwargs),
         out_shape=(
-            jax.ShapeDtypeStruct((B * H, Skv_p, D), k.dtype),
-            jax.ShapeDtypeStruct((B * H, Skv_p, D), v.dtype),
+            jax.ShapeDtypeStruct(kr.shape, kr.dtype),
+            jax.ShapeDtypeStruct(vr.shape, vr.dtype),
         ),
-        grid=(B * H, nk, nq),
+        grid=(steps, nk, nq),
         in_specs=[
-            pl.BlockSpec((1, block_q, D), q_index),
-            pl.BlockSpec((1, block_k, D), lambda bh, ki, i: (bh, ki, 0)),
-            pl.BlockSpec((1, block_k, D), lambda bh, ki, i: (bh, ki, 0)),
-            pl.BlockSpec((1, block_q, D), q_index),
-            pl.BlockSpec((1, 1, block_q), q_row_index),
-            pl.BlockSpec((1, 1, block_q), q_row_index),
+            pl.BlockSpec((1, block_q, W), q_index),
+            pl.BlockSpec((1, block_k, W), outer),
+            pl.BlockSpec((1, block_k, W), outer),
+            pl.BlockSpec((1, block_q, W), q_index),
+            pl.BlockSpec((g, 1, block_q), q_row_index),
+            pl.BlockSpec((g, 1, block_q), q_row_index),
         ],
         out_specs=(
-            pl.BlockSpec((1, block_k, D), lambda bh, ki, i: (bh, ki, 0)),
-            pl.BlockSpec((1, block_k, D), lambda bh, ki, i: (bh, ki, 0)),
+            pl.BlockSpec((1, block_k, W), outer),
+            pl.BlockSpec((1, block_k, W), outer),
         ),
         scratch_shapes=[
-            pltpu.VMEM((block_k, D), jnp.float32),
-            pltpu.VMEM((block_k, D), jnp.float32),
+            pltpu.VMEM((block_k, W), jnp.float32),
+            pltpu.VMEM((block_k, W), jnp.float32),
         ],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")
         ),
         cost_estimate=pl.CostEstimate(
-            flops=8 * B * H * S * Skv * D,  # 4 matmuls: s, dv, dp, dk
-            bytes_accessed=3 * (qr.size + kr.size + vr.size) * q.dtype.itemsize,
-            transcendentals=B * H * S * Skv,
+            flops=8 * BH * S * Skv * D,  # 4 matmuls: s, dv, dp, dk
+            bytes_accessed=3 * (qr.size + kr.size + vr.size) * qr.dtype.itemsize,
+            transcendentals=BH * S * Skv,
         ),
         interpret=interpret,
         name="flash_bwd_dkv",
     )(qr, kr, vr, gr, lr, dr)
+    return dq[:, :S], dk[:, :Skv], dv[:, :Skv]
 
-    dq = dq[:, :S].reshape(B, H, S, D)
-    dk = dk[:, :Skv].reshape(B, H, Skv, D)
-    dv = dv[:, :Skv].reshape(B, H, Skv, D)
-    return dq, dk, dv
+
+def _flash_bwd_pallas(q, k, v, o, lse, g, causal: bool, sm_scale: float,
+                      block_q: int, block_k: int, interpret: bool = False):
+    """`_flash_bwd_call` from and to [B, H, S, Dh]."""
+    B, H, _, D = q.shape
+    grads = _flash_bwd_call(*(_to_operand(x) for x in (q, k, v, o)), lse, _to_operand(g),
+                            D, causal, sm_scale, block_q, block_k, interpret)
+    return tuple(_from_operand(x, B, H) for x in grads)
 
 
 def _on_tpu() -> bool:
@@ -659,38 +808,39 @@ def flash_kernels_in(hlo_text: str) -> dict:
     return counts
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
-def _flash(q, k, v, causal, sm_scale, block_q, block_k):
-    return _flash_fwd_pallas(q, k, v, causal, sm_scale, block_q, block_k)
+# The custom_vjp boundary is drawn around the operands' form: residuals and
+# cotangents cross it as the kernels read and write them.
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
+def _flash(qr, kr, vr, head_dim, causal, sm_scale, block_q, block_k):
+    return _flash_fwd_call(qr, kr, vr, head_dim, causal, sm_scale, block_q, block_k)
 
 
-def _flash_fwd(q, k, v, causal, sm_scale, block_q, block_k):
-    out, lse = _flash_fwd_pallas(
-        q, k, v, causal, sm_scale, block_q, block_k, return_lse=True
+def _flash_fwd(qr, kr, vr, head_dim, causal, sm_scale, block_q, block_k):
+    out, lse = _flash_fwd_call(
+        qr, kr, vr, head_dim, causal, sm_scale, block_q, block_k, return_lse=True
     )
-    return out, (q, k, v, out, lse)
+    return out, (qr, kr, vr, out, lse)
 
 
-def _flash_bwd(causal, sm_scale, block_q, block_k, res, g):
-    q, k, v, o, lse = res
-    return _flash_bwd_pallas(q, k, v, o, lse, g, causal, sm_scale, block_q, block_k)
+def _flash_bwd(head_dim, causal, sm_scale, block_q, block_k, res, g):
+    return _flash_bwd_call(*res, g, head_dim, causal, sm_scale, block_q, block_k)
 
 
 _flash.defvjp(_flash_fwd, _flash_bwd)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
-def _flash_stats(q, k, v, causal, sm_scale, block_q, block_k):
-    return _flash_fwd_pallas(
-        q, k, v, causal, sm_scale, block_q, block_k, return_lse=True
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
+def _flash_stats(qr, kr, vr, head_dim, causal, sm_scale, block_q, block_k):
+    return _flash_fwd_call(
+        qr, kr, vr, head_dim, causal, sm_scale, block_q, block_k, return_lse=True
     )
 
 
-def _flash_stats_fwd(q, k, v, causal, sm_scale, block_q, block_k):
+def _flash_stats_fwd(qr, kr, vr, head_dim, causal, sm_scale, block_q, block_k):
     from jax.ad_checkpoint import checkpoint_name
 
-    out, lse = _flash_fwd_pallas(
-        q, k, v, causal, sm_scale, block_q, block_k, return_lse=True
+    out, lse = _flash_fwd_call(
+        qr, kr, vr, head_dim, causal, sm_scale, block_q, block_k, return_lse=True
     )
     # Name the values HERE so the residual vars themselves carry the names:
     # under jax.checkpoint with save_only_these_names("attn_out","attn_lse")
@@ -699,16 +849,31 @@ def _flash_stats_fwd(q, k, v, causal, sm_scale, block_q, block_k):
     # call DCEs away — attention forward runs exactly once per step.
     out = checkpoint_name(out, "attn_out")
     lse = checkpoint_name(lse, "attn_lse")
-    return (out, lse), (q, k, v, out, lse)
+    return (out, lse), (qr, kr, vr, out, lse)
 
 
-def _flash_stats_bwd(causal, sm_scale, block_q, block_k, res, g):
-    q, k, v, o, lse = res
+def _flash_stats_bwd(head_dim, causal, sm_scale, block_q, block_k, res, g):
     g_o, _ = g  # lse cotangent is structurally zero (stats are not a loss path)
-    return _flash_bwd_pallas(q, k, v, o, lse, g_o, causal, sm_scale, block_q, block_k)
+    return _flash_bwd_call(*res, g_o, head_dim, causal, sm_scale, block_q, block_k)
 
 
 _flash_stats.defvjp(_flash_stats_fwd, _flash_stats_bwd)
+
+
+def _through_operands(kernel, q, k, v, *static):
+    """`kernel` (a custom_vjp over operands) from and to [B, H, S, Dh]."""
+    B, H, _, D = q.shape
+    out = kernel(_to_operand(q), _to_operand(k), _to_operand(v), D, *static)
+    if isinstance(out, tuple):
+        return _from_operand(out[0], B, H), *out[1:]
+    return _from_operand(out, B, H)
+
+
+def _flash_fwd_pallas(q, k, v, causal: bool, sm_scale: float, block_q: int, block_k: int,
+                      interpret: bool = False, return_lse: bool = False):
+    """`_flash_fwd_call` from and to [B, H, S, Dh]."""
+    return _through_operands(_flash_fwd_call, q, k, v, causal, sm_scale, block_q, block_k,
+                             interpret, return_lse)
 
 
 def flash_attention_with_stats(
@@ -751,7 +916,7 @@ def flash_attention_with_stats(
         block_q = 1024
     if block_k is None:
         block_k = 1024
-    out, lse = _flash_stats(q, k, v, causal, scale, block_q, block_k)
+    out, lse = _through_operands(_flash_stats, q, k, v, causal, scale, block_q, block_k)
     return out, jax.lax.stop_gradient(lse)
 
 
@@ -780,7 +945,7 @@ def flash_attention(
         block_q = 1024
     if block_k is None:
         block_k = 1024
-    return _flash(q, k, v, causal, scale, block_q, block_k)
+    return _through_operands(_flash, q, k, v, causal, scale, block_q, block_k)
 
 
 # ------------------------------------------------------------ ring attention

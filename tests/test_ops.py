@@ -128,6 +128,78 @@ def test_flash_bwd_kernel_matches_reference(S, Skv, causal, D, block):
                                    atol=2e-2, rtol=2e-2)
 
 
+# The kernels' operand form, by `heads_a_step`: (batch, heads, S, Skv, Dh,
+# block, causal, each head's gain on v and dO). A pair of heads of 64 a grid
+# step in the halves of a 128-lane column block of [B, S, H·Dh] (with heads a
+# thousand times apart: a share of one that leaked into the other's lanes would
+# drown it), a 2 x 2 grid of blocks, Skv != S with a ragged tail; four heads of
+# 32 a step; and the shapes that stay [B·H, S, Dh], a head a row: heads of
+# whole lane tiles (128, 256), an odd count of 64, two of 32.
+OPERAND_FORMS = {
+    "pair-of-64": (2, 4, 256, 256, 64, 128, True, None),
+    "pair-of-64-a-thousand-apart": (1, 2, 256, 256, 64, 256, True, (1.0, 1e3)),
+    "pair-of-64-apart-the-other-way": (1, 2, 256, 256, 64, 128, True, (1e3, 1.0)),
+    "pair-of-64-ragged-Skv-longer": (1, 4, 200, 333, 64, 128, True, None),
+    "pair-of-64-no-diagonal": (2, 2, 200, 200, 64, 128, False, None),
+    "four-of-32": (1, 4, 256, 256, 32, 128, True, (1.0, 1e3, 1.0, 1e3)),
+    "rows-three-of-128": (2, 3, 256, 256, 128, 128, True, None),
+    "rows-two-of-256": (2, 2, 300, 300, 256, 256, True, None),
+    "rows-three-of-64": (2, 3, 256, 256, 64, 128, True, None),
+    "rows-two-of-32": (2, 2, 200, 200, 32, 128, True, None),
+}
+
+
+@pytest.mark.parametrize("case", list(OPERAND_FORMS))
+def test_flash_operand_forms_match_reference(case):
+    """Forward, lse, dq, dk and dv against `attention_reference` and its
+    gradient, a head at a time and to that head's own scale."""
+    from ray_tpu.ops.attention import _flash_bwd_pallas, heads_a_step
+
+    B, H, S, Skv, D, block, causal, gains = OPERAND_FORMS[case]
+    assert bool(heads_a_step(H, D)) == (not case.startswith("rows"))
+    scale = D**-0.5
+    gain = jnp.asarray(gains or (1.0,) * H)[None, :, None, None]
+    q = _rand(B, H, S, D, key=0)
+    k = _rand(B, H, Skv, D, key=1)
+    v = _rand(B, H, Skv, D, key=2) * gain
+    g = _rand(B, H, S, D, key=7) * gain
+    ref, vjp = jax.vjp(lambda q_, k_, v_: attention_reference(q_, k_, v_, causal, scale),
+                       q, k, v)
+    o, lse = _flash_fwd_pallas(q, k, v, causal, scale, block, block, interpret=True,
+                               return_lse=True)
+    grads = _flash_bwd_pallas(q, k, v, o, lse, g, causal, scale, block, block,
+                              interpret=True)
+    np.testing.assert_allclose(np.asarray(lse[:, :, :S]),
+                               np.asarray(_lse_reference(q, k, causal, scale)),
+                               atol=2e-3, rtol=2e-3)
+    for name, got, want, tol in zip(("o", "dq", "dk", "dv"), (o, *grads), (ref, *vjp(g)),
+                                    (2e-3, 2e-2, 2e-2, 2e-2)):
+        for h in range(H):
+            size = float(jnp.abs(want[:, h]).max())
+            np.testing.assert_allclose(
+                np.asarray(got[:, h]) / size, np.asarray(want[:, h]) / size,
+                atol=tol, rtol=tol, err_msg=f"{name}, head {h}")
+
+
+@pytest.mark.parametrize(
+    "heads,head_dim,want",
+    [
+        (20, 64, 2),     # gpt2-large: a pair fills 128 lanes
+        (8, 32, 4), (8, 16, 8),
+        (16, 256, 0),    # gptj-6b: a head of whole lane tiles stays a row
+        (3, 128, 0),
+        (3, 64, 0),      # an odd count of halves
+        (2, 32, 0),      # two quarters
+        (4, 96, 0), (4, 192, 0),    # no whole lane tiles
+        (1, 64, 0),
+    ],
+)
+def test_flash_heads_a_step_rule(heads, head_dim, want):
+    from ray_tpu.ops.attention import heads_a_step
+
+    assert heads_a_step(heads, head_dim) == want
+
+
 @pytest.mark.parametrize(
     "grid,tile,seqs,causal,want",
     [

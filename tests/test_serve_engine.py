@@ -2127,40 +2127,63 @@ def test_the_chunk_attention_kernel_compiles_for_v5e_at_the_cells_widths(v5e_chi
     assert compiled.memory_analysis().temp_size_in_bytes <= 2 * out + (1 << 20)
 
 
-# One device's call of a layer in the two train cells: [B * H, S, D]
+# One device's call of a layer in the two train cells: (batch, heads, S, Dh)
 FLASH_KERNEL_SHAPES = {
-    "gpt2-large.train-13x20-heads-of-64": (260, 1024, 64),
-    "gptj-6b.train-fsdp4-2x16-heads-of-256": (32, 2048, 256),
+    "gpt2-large.train-13x20-heads-of-64": (13, 20, 1024, 64),
+    "gptj-6b.train-fsdp4-2x16-heads-of-256": (2, 16, 2048, 256),
 }
 
 
 @pytest.mark.parametrize("shape", list(FLASH_KERNEL_SHAPES))
-def test_the_flash_kernels_compile_for_v5e_at_the_train_cells_shapes(v5e_chip, shape):
-    """Compile-only: `ops/attention.py`'s forward and two backward kernels,
-    causal, bfloat16, default blocks, at the shapes the two train cells bring
-    them (one grid step a head of 64 at 1,024 tokens; a 2 x 2 grid a head of
-    256 at 2,048), by the chip's compiler: the unrolled walk over sub-tiles
-    lowers at both head widths, and each kernel keeps the name the
-    benchmark's `flash_*_roofline` finds it by."""
+def test_the_flash_kernels_compile_for_v5e_at_the_train_cells_shapes(v5e_chip, shape,
+                                                                     monkeypatch):
+    """Compile-only: a layer's attention as `models/gpt.py` `_block` says it
+    (the projections, the transposes to and from `attend`'s [B, H, S, Dh],
+    the public wrapper the train step takes), forward and gradient, causal,
+    bfloat16, default blocks, at the shapes the two train cells bring, by
+    the chip's compiler. The unrolled walk over sub-tiles lowers at both
+    head widths (a pair of heads of 64 a grid step; a 2 x 2 grid a head of
+    256 at 2,048) and each kernel is there once under the name the
+    benchmark's `flash_*_roofline` finds it by. At heads of 64 the kernels
+    read q, k, v and dO and write o, dq, dk and dv as the flat projections
+    lay them, and NO array of a layer's attention is copied or transposed on
+    its way (eleven were, PERF.md §6, PR 49); heads of 256 stay a row each
+    (`ops/attention.py` `heads_a_step`) and keep their relayouts."""
+    import math
+    import re
+
     import jax
     import jax.numpy as jnp
     from jax.sharding import SingleDeviceSharding
 
+    from ray_tpu.models import gpt
     from ray_tpu.ops import attention
 
-    bh, seq, dh = FLASH_KERNEL_SHAPES[shape]
+    monkeypatch.setattr(attention, "_on_tpu", lambda: True)
+    B, H, S, D = FLASH_KERNEL_SHAPES[shape]
+    E = H * D
     one_chip = SingleDeviceSharding(v5e_chip)
-    x = jax.ShapeDtypeStruct((1, bh, seq, dh), jnp.bfloat16, sharding=one_chip)
+    arr = lambda *s: jax.ShapeDtypeStruct(s, jnp.bfloat16, sharding=one_chip)  # noqa: E731
 
-    def run(q, k, v, g):
-        o, lse = attention._flash_fwd_pallas(q, k, v, True, dh ** -0.5, 1024, 1024,
-                                             return_lse=True)
-        return o, attention._flash_bwd_pallas(q, k, v, o, lse, g, True, dh ** -0.5,
-                                              1024, 1024)
+    def layer(h, p):
+        q, k, v = (a.transpose(0, 2, 1, 3) for a in gpt._project_qkv(None, p, h))
+        attn = attention.flash_attention_with_stats(q, k, v, causal=True)[0]
+        return gpt._merge_heads(attn, p["w_o"])
 
-    compiled = _within(240, lambda: jax.jit(run).lower(x, x, x, x).compile())
-    assert attention.flash_kernels_in(compiled.as_text()) == dict.fromkeys(
-        attention.FLASH_KERNELS, 1)
+    def run(h, p, g):
+        out, vjp = jax.vjp(layer, h, p)
+        return out, vjp(g)
+
+    p = {"w_qkv": arr(E, 3, H, D), "b_qkv": arr(3, H, D), "w_o": arr(H, D, E)}
+    text = _within(240, lambda: jax.jit(run).lower(
+        arr(B, S, E), p, arr(B, S, E)).compile()).as_text()
+    assert attention.flash_kernels_in(text) == dict.fromkeys(attention.FLASH_KERNELS, 1)
+    if not attention.heads_a_step(H, D):
+        return
+    moved = [line.strip()[:160] for line in text.splitlines()
+             for m in [re.search(r"= (?:bf16|f32)\[([\d,]+)\]\S* (?:copy|transpose)\(", line)]
+             if m and math.prod(map(int, m.group(1).split(","))) == B * H * S * D]
+    assert not moved, moved
 
 
 # (K/V heads, query heads a K/V head, key row, value row or None, block, table, lanes)
