@@ -1159,25 +1159,25 @@ _flat_proj.defvjp(_flat_proj_fwd, _flat_proj_bwd)
 
 
 def _heads_flat(tokens: int, embed: int, heads: int, head_dim: int) -> bool:
-    """Whether the projections to and from heads are said FLAT, over [B, S,
-    heads·Dh], and not with heads and Dh named apart: where the flash kernels
-    read and write that form (`ops/attention.py` `heads_a_step`: heads of 64
-    by the pair) AND the activations are the larger operand, more tokens
-    than E. Named, a result is laid out in tiles of (heads, Dh), half of
-    every tile empty at 64, and XLA took every array of a layer's attention
-    through sequence-minor copies to get there and back (gpt2-large's train
-    step: eleven a layer, 34 MB each). Flat, it is the WEIGHTS that are
-    reshaped, which a served program would first have to write out where it
-    reads them out of the stack INTO the product (PERF.md §6, PR 49)."""
+    """Whether the projections to and from heads are said FLAT, over [B, S, heads·Dh], and
+    not with heads and Dh named apart: where the flash kernels read and write that form
+    (`ops/attention.py` `heads_a_step`: heads of 64 by the pair) AND the activations are the
+    larger operand, more tokens than E. Named, a result is laid out in tiles of (heads, Dh),
+    half of every tile empty at 64, and XLA took every array of a layer's attention through
+    sequence-minor copies to get there and back (gpt2-large's train step: eleven a layer, 34
+    MB each). Flat, it is the WEIGHTS that are reshaped, which a served program would first
+    have to write out (PERF.md §6, PR 49)."""
     return tokens > embed and heads_a_step(heads, head_dim) > 0
 
 
 def _project_qkv(cfg: GPTConfig, p, h):
-    """h [B, S, E] -> q [B, S, H, Dh], k and v [B, S, Hkv, Dh]: the fused
-    multi-head w_qkv, or the grouped-query pair w_q / w_kv. Flat
-    (`_heads_flat`): a product each, from a slice of the WEIGHTS (a slice of
-    one product's result is a copy of the activations, and a reshape of [3,
-    heads, Dh] cannot keep a sharding of the heads)."""
+    """h [B, S, E] -> q [B, S, H, Dh], k and v [B, S, Hkv, Dh]: the fused multi-head
+    w_qkv (the form an engine holds: `_project_served`), or the grouped-query pair w_q /
+    w_kv. Flat (`_heads_flat`): a product each, from a slice of the WEIGHTS (a slice of
+    one product's result is a copy of the activations, and a reshape of [3, heads, Dh]
+    cannot keep a sharding of the heads)."""
+    if "w_qkv_served" in p:
+        return _project_served(p, h)
     if "w_qkv" in p:
         (B, S, E), (H, D) = h.shape, p["w_qkv"].shape[2:]
         if _heads_flat(B * S, E, H, D):
@@ -1507,7 +1507,7 @@ def _block(cfg: GPTConfig, rope_tables, attend, x, layer_params, positions,
 
 
 _LAYER_KEYS = (
-    "w_qkv", "b_qkv", "w_q", "w_kv", "w_o", "b_o", "w_head_gate",
+    "w_qkv", "w_qkv_served", "b_qkv", "w_q", "w_kv", "w_o", "b_o", "w_head_gate",
     "w_in", "b_in", "w_out", "b_out",
     "ln1_w", "ln1_b", "ln2_w", "ln2_b", "w_gate",
     "moe_router", "moe_w_in", "moe_w_out", "moe_w_gate", *_POST_NORM_KEYS,
@@ -1519,7 +1519,7 @@ _LAYER_KEYS = (
 _SSM_KEYS = tuple("ssm_" + name for name in (
     "w_in", "conv_w", "conv_b", "w_x", "dt_norm_w", "b_norm_w", "c_norm_w",
     "w_dt", "b_dt", "A_log", "D", "w_out"))
-_ATTN_KEYS = ("w_qkv", "b_qkv", "w_q", "w_kv", "w_o", "b_o", "w_head_gate")
+_ATTN_KEYS = ("w_qkv", "w_qkv_served", "b_qkv", "w_q", "w_kv", "w_o", "b_o", "w_head_gate")
 # The window layers' attention where it is a stack of its own (`n_heads_window`).
 # {its name in the tree: the name `_block` reads}.
 _WINDOW_KEYS = {"win_" + name: name for name in ("w_q", "w_kv", "w_o", "w_head_gate")}
@@ -2841,3 +2841,40 @@ def make_generate(cfg: GPTConfig, max_new_tokens: int, temperature: float = 0.0)
         return jnp.concatenate([toks.T, last[:, None]], axis=1)
 
     return gen
+
+
+# ------------------------------------------------- the stack an engine holds
+def _project_served(p, h):
+    """`_project_qkv` from the form an engine holds (`hold_served`): the three
+    matrices stacked, each OUT-features (heads, Dh) by IN-features,
+    w_qkv_served [3, H, Dh, E], in ONE product. The one form that the decode
+    programs at every lane count, the prefill chunk and the verify step all
+    read as it lies: from the public [E, 3, H, Dh], which the device tiles
+    over (H, Dh), each of them first rewrote the whole stack, every call
+    (1.15 GiB for `ouro-2.6b`). Heads stay NAMED in the product: said flat
+    ("bse,tfe->btsf", then a reshape) the chunk program pays three copies of
+    its activations a layer instead (PERF.md §6, PR 50, with the forms that
+    did NOT cure it)."""
+    qkv = jnp.einsum("bse,thde->btshd", h, p["w_qkv_served"]) + p["b_qkv"][:, None]
+    return qkv[:, 0], qkv[:, 1], qkv[:, 2]
+
+
+@jax.jit
+def _served_qkv(w_qkv):
+    return w_qkv.transpose(0, 2, 3, 4, 1)       # [L, E, 3, H, Dh] -> [L, 3, H, Dh, E]
+
+
+def hold_served(params):
+    """The tree as an engine holds it on the device, for the paged programs
+    alone: w_qkv [L, E, 3, H, Dh] re-formed ONCE to w_qkv_served [L, 3, H, Dh,
+    E] (`_project_served`), under a key of its own so that the tree says
+    which form it is in; every other leaf, the bias too, as it came. The
+    caller's tree is not touched. A tree without w_qkv (the grouped-query
+    pair, latent attention) comes back itself. Returns (tree, the bytes held
+    in another form than the caller's). `init_params`, the trainer, `forward`,
+    checkpoints and the Hugging Face bridge keep the public form."""
+    if "w_qkv" not in params:
+        return params, 0
+    held = {k: v for k, v in params.items() if k != "w_qkv"}
+    w = held["w_qkv_served"] = _served_qkv(params["w_qkv"])
+    return held, w.size * w.dtype.itemsize

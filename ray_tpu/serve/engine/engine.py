@@ -363,9 +363,8 @@ class InferenceEngine:
     ):
         import jax
 
-        from ...models.gpt import (
-            attn_heads_by_window, init_paged_cache, init_params, kv_head_rows, kv_layout,
-        )
+        from ...models.gpt import (attn_heads_by_window, hold_served, init_paged_cache,
+                                   init_params, kv_head_rows, kv_layout)
         from ...ops import paged_attention
 
         self.cfg = dataclasses.replace(cfg, remat=False, remat_policy=None)
@@ -413,7 +412,8 @@ class InferenceEngine:
         self._device = {"platform": dev.platform, "device_kind": dev.device_kind}
         if params is None:
             params = init_params(jax.random.PRNGKey(self.opts.seed), cfg)
-        self.params = params
+        # The paged programs' own tree: the fused q/k/v stack re-formed ONCE, as they read it.
+        self.params, self.weights_reformed_bytes = hold_served(params)
         # A model with state: one slot a lane behind the null slot.
         state_slots = self.opts.max_num_seqs if self._stateful else 0
         self.kv = init_paged_cache(
@@ -1632,9 +1632,8 @@ class InferenceEngine:
             kv_stats = self.block_manager.stats()
             ttfts = list(self._ttfts)
             tpots = list(self._tpots)
-        extra = (
-            {"ttft_recent": ttfts, "tpot_recent": tpots} if include_raw else {}
-        )
+        extra = ({"ttft_recent": ttfts, "tpot_recent": tpots}
+                 if include_raw else {})
         return {
             **extra,
             **self._device,
@@ -1651,6 +1650,7 @@ class InferenceEngine:
             "host_tier_bytes": kv_stats.host_bytes,
             "blocks_imported": self.total_blocks_imported,
             "blocks_exported": self.total_blocks_exported,
+            "weights_reformed_bytes": self.weights_reformed_bytes,
             "window_blocks_released": self.block_manager.window_released,
             "attn_keys_run": self.total_attn_covered[0],
             "attn_keys_padded": self.total_attn_covered[1],

@@ -16,7 +16,13 @@ least half of one layer's pool (K or V), with its layout, and the bytes its
 `copy-start` / `copy-done` pairs move by loop depth (`loop_copy_bytes`: what a
 chunk program's key loop moved between HBM and the compiler's scoped memory on
 every key tile before PR 41, PERF.md §5; 0 inside a layer's loops with the
-chunk kernel, which has no key loop). In a healthy
+chunk kernel, which has no key loop), and ONE number a program,
+`param_relayout_MiB` (`param_relayout_bytes`): the bytes the program moves of
+its own parameters other than the pool before it computes with them (`copy` /
+`transpose` / `copy-start` outside fusions; 1,152 for every paged program of
+`ouro-2.6b` over the public tree, 0 over the tree the engine holds since
+PR 50, `models/gpt.py` `hold_served`, which is the tree compiled here; a
+latent model's prefetched `lead_w_uq` / `lead_w_dq` / `lead_w_ukv` read 109). In a healthy
 paged program the pool enters in the layout the device keeps, is the layer
 scan's carry, and only the in-place row update names it (`fusion(scatter)`
 or `dynamic-update-slice` with the pool's own shape, aliased to the
@@ -162,6 +168,49 @@ def loop_copy_bytes(hlo_text: str) -> dict:
     return dict(sorted(by_depth.items()))
 
 
+_MOVES = ("copy", "transpose", "copy-start")
+
+
+def param_relayouts(hlo_text: str, min_bytes: int = 2**20):
+    """[(parameter, opcode, result with layout, bytes)] of what a compiled
+    program moves of its own PARAMETERS before it computes with them: the
+    `copy` / `transpose` / `copy-start` instructions of the entry computation,
+    outside fusions, whose operand is a program parameter (straight or
+    through bitcasts) other than the pool (`kv`): a weight held in a form the
+    program does not read as it lies is rewritten here, whole, once a call
+    (PERF.md §6, PR 50: `ouro-2.6b`'s fused q/k/v stack, 1,152 MiB in every
+    paged program), and a `copy-start` is a stack the compiler prefetches into
+    its scoped memory. A result under `min_bytes` is left out: a norm's vector
+    or a table on its way there is kilobytes."""
+    inside, source, moved = False, {}, []
+    for line in hlo_text.splitlines():
+        if line.startswith("ENTRY"):
+            inside = True
+        elif line.startswith("}"):
+            inside = False
+        m = _INSTR.match(line) if inside else None
+        if not m:
+            continue
+        name, result, opcode = m.groups()
+        operand = re.match(r"%?([\w.\-]+)", line[m.end():])
+        operand = operand and source.get(operand.group(1))
+        if opcode == "parameter":
+            source[name] = name
+        elif opcode == "bitcast" and operand:
+            source[name] = operand
+        elif opcode in _MOVES and operand and not operand.startswith("kv_"):
+            dtype, dims, layout = _SHAPE.search(result).groups()
+            n = _array_bytes(dtype, dims)
+            if n >= min_bytes:
+                moved.append((operand, opcode, f"{dtype}[{dims}]{layout}", n))
+    return moved
+
+
+def param_relayout_bytes(hlo_text: str, min_bytes: int = 2**20) -> int:
+    """The bytes of `param_relayouts`: one number a program."""
+    return sum(n for *_, n in param_relayouts(hlo_text, min_bytes))
+
+
 def kernels_in(hlo_text: str) -> dict:
     """{name: calls} of the paged attention kernels (`ops/paged_attention.py`:
     the chunk's, the decode step's) in a compiled program's text, by the name
@@ -218,7 +267,7 @@ def rehearse(cfg, device, num_blocks: int, block_size: int, lanes: int = 8,
     import jax.numpy as jnp
     from jax.sharding import SingleDeviceSharding
 
-    from ray_tpu.models.gpt import init_paged_cache, init_params, kv_layout
+    from ray_tpu.models.gpt import hold_served, init_paged_cache, init_params, kv_layout
     from ray_tpu.serve.engine.engine import _paged_jits, init_sampler
 
     one_chip = SingleDeviceSharding(device)
@@ -228,8 +277,9 @@ def rehearse(cfg, device, num_blocks: int, block_size: int, lanes: int = 8,
             lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
             tree)
 
+    # the tree in the form the engine holds it in (`hold_served`)
     params = on_chip(jax.eval_shape(
-        lambda k: init_params(k, cfg), jax.random.PRNGKey(0)))
+        lambda k: hold_served(init_params(k, cfg))[0], jax.random.PRNGKey(0)))
     stateful = int(bool(kv_layout(cfg).state))   # a state slot a lane beside the pool
     kv = on_chip(jax.eval_shape(
         lambda: init_paged_cache(cfg, num_blocks, block_size, lanes * stateful)))
@@ -293,6 +343,7 @@ def rehearse(cfg, device, num_blocks: int, block_size: int, lanes: int = 8,
         mem = compiled.memory_analysis()
         text = compiled.as_text()
         ops = big_ops(text, layer_pool // 2)
+        moved = param_relayouts(text)
         copies = loop_copy_bytes(text)
         # a loop inside a layer: under the layer scan, itself under the pass
         # scan of a looped model
@@ -310,6 +361,7 @@ def rehearse(cfg, device, num_blocks: int, block_size: int, lanes: int = 8,
             ],
             "kernels": kernels_in(text),
             "lower_s": lower_s,
+            "param_relayout_MiB": sum(b for *_, b in moved) / 2**20,
         }
         print(f"{name}: args {mem.argument_size_in_bytes / 2**30:.2f} GiB, "
               f"temp {mem.temp_size_in_bytes / 2**30:.4f}, "
@@ -322,8 +374,12 @@ def rehearse(cfg, device, num_blocks: int, block_size: int, lanes: int = 8,
               flush=True)
         for op, n, shape, b in ops:
             print(f"    {op:28s} {shape}  {b / 2**20:.1f} MiB  %{n}", flush=True)
+        for param, op, shape, b in moved:
+            print(f"    {op + ' of a parameter':28s} {shape}  {b / 2**20:.1f} MiB  %{param}",
+                  flush=True)
         print(f"    attention kernels: {report['programs'][name]['kernels']}; "
-              f"traced and lowered in {lower_s:.2f} s", flush=True)
+              f"traced and lowered in {lower_s:.2f} s; param_relayout_MiB "
+              f"{report['programs'][name]['param_relayout_MiB']:.1f}", flush=True)
         if fusion and name == "decode_step_paged":
             for instr, line, body in fusions_named(text, fusion):
                 operands = sum(     # a stacked weight counts whole: a layer reads its slice
@@ -332,6 +388,16 @@ def rehearse(cfg, device, num_blocks: int, block_size: int, lanes: int = 8,
                 print(f"  %{instr}: parameters {operands / 2**20:.2f} MiB\n    {line}")
                 print("\n".join("      " + b.strip() for b in body), flush=True)
     return report
+
+
+def config_program(name: str):
+    """(model preset, overrides) of the benchmark configuration
+    `benchmarks/configs/<name>.json`: its own program model, as its cells run it."""
+    from benchmarks import harness
+
+    config = harness.load_json(harness.ROOT, f"benchmarks/configs/{name}.json")
+    arch = harness.arch(config["arch"])
+    return arch.program(config, arch.dims(config, False))
 
 
 def main() -> int:
@@ -369,11 +435,7 @@ def main() -> int:
     attention._on_tpu = lambda: True
     overrides = {}
     if a.config:
-        from benchmarks import harness
-
-        config = harness.load_json(harness.ROOT, f"benchmarks/configs/{a.config}.json")
-        arch = harness.arch(config["arch"])
-        a.model, overrides = arch.program(config, arch.dims(config, False))
+        a.model, overrides = config_program(a.config)
     if a.n_layers is not None:
         overrides["n_layers"] = a.n_layers
     cfg = CONFIGS[a.model](**overrides, remat=False, remat_policy=None)

@@ -208,11 +208,12 @@ def test_the_cell_and_its_files_are_in_the_benchmark():
     assert all(per_layer[name]["moves"] in e2e for name in layer)
     names = [m["name"] for m in bench["per_layer"]]
     first = names.index(next(iter(NEW_METRICS)))        # appended, in the issue's order;
-    last = first + len(NEW_METRICS)                     # PR 44's two behind them, PR 49's one
-    assert names[first:last] == list(NEW_METRICS)
+    last = first + len(NEW_METRICS)                     # PR 44's two behind them, PR 49's one,
+    assert names[first:last] == list(NEW_METRICS)       # PR 50's one (the same reader, this cell too)
     assert names[last:] == ["decode_attn_kernel_share", "decode_attn_time_share",
-                            "relayout_time_share"]
-    assert {"decode_attn_kernel_share", "decode_attn_time_share"} <= set(layer)
+                            "relayout_time_share", "relayout_time_share.itl"]
+    assert {"decode_attn_kernel_share", "decode_attn_time_share",
+            "relayout_time_share.itl"} <= set(layer)
     for name in NEW_METRICS:
         assert per_layer[name]["workloads"] == [CELL]
     assert per_layer["kv_window_block_share"]["layer"] == "engine scheduler and KV"

@@ -1469,6 +1469,55 @@ def test_paged_programs_compile_for_v5e_without_a_pool_copy(v5e_chip):
         assert not moved, (name, moved)
 
 
+OURO_PROGRAMS = {
+    "decode-1": "decode_step_paged@1", "decode-2": "decode_step_paged@2",
+    "decode-4": "decode_step_paged", "decode-8": "decode_step_paged@8",
+    "decode-16": "decode_step_paged@16", "chunk-256": "prefill_paged",
+    "verify": "verify_step_paged",
+}
+
+
+@pytest.fixture(scope="module")
+def ouro_rehearsal(v5e_chip):
+    """`ouro-2.6b.reason`'s paged programs compiled once for the described
+    chip, at the cell's pool (320 blocks of 16) and with the kernels the chip
+    runs, as `scripts.paged_rehearse`'s `main` steers them."""
+    from ray_tpu.models.gpt import CONFIGS
+    from ray_tpu.ops import attention
+    from scripts.paged_rehearse import config_program, rehearse
+
+    model, overrides = config_program("ouro-2.6b")
+    cfg = CONFIGS[model](**overrides, remat=False, remat_policy=None)
+    on_tpu, attention._on_tpu = attention._on_tpu, lambda: True
+    try:
+        return cfg, _within(400, lambda: rehearse(
+            cfg, v5e_chip, 320, 16, lanes=4, width=32, chunk=256, spec=4,
+            decode_lanes=(1, 2, 8, 16)))
+    finally:
+        attention._on_tpu = on_tpu
+
+
+@pytest.mark.parametrize("program", list(OURO_PROGRAMS))
+def test_the_looped_models_programs_read_the_held_stack_as_it_lies(ouro_rehearsal, program):
+    """Compile-only, at the published size: no paged program of `ouro-2.6b`
+    moves a parameter before it computes with it. From the public form of the
+    fused q/k/v stack, [48, 2048, 3, 16, 128], every one of them but the
+    one-lane decode step rewrote all of it once a call (a `copy` of 1,152 MiB,
+    1.126 GiB of temporaries; PERF.md §6, PR 50); from the form the engine
+    holds (`gpt.hold_served`) none holds a `copy` or `transpose` of a layer
+    of that stack or more."""
+    cfg, report = ouro_rehearsal
+    prog = report["programs"][OURO_PROGRAMS[program]]
+    assert "refused" not in prog, prog
+    assert prog["param_relayout_MiB"] == 0, prog
+    assert prog["temp_GiB"] < 0.01, prog
+    layer_MiB = cfg.d_model * 3 * cfg.n_heads * cfg.d_head * 2 / 2**20
+    assert layer_MiB == 24 and report["layer_pool_MiB"] == 20
+    moved = [o for o in prog["pool_sized_ops"]    # listed from half a layer's pool up
+             if o["MiB"] >= layer_MiB and ("copy" in o["op"] or "transpose" in o["op"])]
+    assert not moved, moved
+
+
 def test_two_kinds_of_layer_compile_for_v5e_over_one_pool_in_place(v5e_chip):
     """Compile-only, at SmallThinker's published widths (one period of four
     layers, the whole vocabulary, a pool of 1,024 blocks of 64 tokens): the
