@@ -56,7 +56,7 @@ class GPTConfig:
     n_kv_heads: Optional[int] = None
     # Architecture knobs.
     norm: str = "layernorm"          # layernorm | rmsnorm
-    activation: str = "gelu"         # gelu | swiglu | reglu (relu-gated)
+    activation: str = "gelu"  # gelu | swiglu | reglu (relu-gated) | relu2 (relu squared, NO gate: block_pattern)
     pos: str = "learned"             # learned | rotary | none (no positional term)
     rotary_dim: int = 64
     rope_theta: float = 10000.0
@@ -118,20 +118,20 @@ class GPTConfig:
     # `lead_<name>` and run through the same `_block` before the scan.
     dense_layers: int = 0
     d_dense_mlp: int = 0
-    # State-space layers (`ops/ssm.py`, Mamba-1 as Jamba runs it): one entry
-    # a layer, 1 = the layer's mixer is the state-space one, 0 = attention.
-    # The two mixers' weights are two stacks (`ssm_*` [layers of that kind,
-    # ...] beside the attention stack), the norms and the MLP one stack over
-    # all layers; the layer loop is cut into runs of one kind (`_mixed_layers`).
-    # Such a layer keeps no row a token: its state is one fixed slot a
-    # SEQUENCE (`KVLayout.state`). Inner width `ssm_expand` x d_model, a state
-    # of `ssm_state` a channel, a convolution over `ssm_conv` inputs, a step
-    # projected through `ssm_dt_rank`. A model with such layers has no bias.
+    # State-space layers (`ops/ssm.py`), their state one slot a SEQUENCE (`KVLayout.state`), no bias.
+    # `ssm_layout` (Mamba-1): 1 = the layer's mixer is the state-space one, a stack of its own
+    # (`_mixed_layers`). `block_pattern` (Mamba-2 among blocks of ONE mixer): the end of this file.
     ssm_layout: Optional[Tuple[int, ...]] = None
     ssm_state: int = 16
     ssm_conv: int = 4
     ssm_expand: int = 2
     ssm_dt_rank: int = 160
+    block_pattern: Optional[str] = None  # a block a character: M Mamba-2 | E experts | * attention
+    ssm_heads: int = 0                   # Mamba-2: heads of `ssm_head_dim` channels, B and C shared
+    ssm_head_dim: int = 0                # by `ssm_groups` groups of heads, the chunked form's chunk
+    ssm_groups: int = 1; ssm_chunk: int = 128
+    moe_select_bias: bool = False        # experts chosen by score + a bias, weighted by score alone
+    norm_eps: float = 1e-6               # the RMSNorms of a `block_pattern` model (1e-6 elsewhere)
     tie_embeddings: bool = True
     # Mixture-of-Experts (expert parallelism over the ep mesh axis).
     mlp_type: str = "dense"          # dense | moe
@@ -247,8 +247,8 @@ class GPTConfig:
                 "ssm_layout: a one-pass model of plain (grouped-query) attention "
                 "layers without learned positions, a dense gated MLP in every layer")
         if self.sandwich_norm and self.parallel_block:
-            raise ValueError("sandwich_norm norms each sublayer's output on its "
-                             "way into the stream; a parallel_block has one sum")
+            raise ValueError("sandwich_norm norms each sublayer's output; a parallel_block has one sum")
+        _check_block_pattern(self)
 
     @property
     def kv_heads(self) -> int:
@@ -300,6 +300,7 @@ class GPTConfig:
     @property
     def n_params(self) -> int:
         """Parameters HELD (a `moe_held` range counts its own experts)."""
+        if self.block_pattern: return _pattern_params(self)
         E, L, F, V, Hd = self.d_model, self.n_layers, self.d_mlp, self.vocab_size, self.n_heads * self.d_head
         gated = self.activation in ("swiglu", "reglu")
         if self.mlp_type == "moe":
@@ -328,8 +329,7 @@ class GPTConfig:
         total = (attn_all + L * norms + (L - self.dense_layers) * mlp_params
                  + self.dense_layers * 3 * E * self.d_dense_mlp
                  + V * E + (0 if self.tie_embeddings else E * V))
-        if self.ut_steps > 1:
-            total += E + 1  # the exit gate
+        total += (E + 1) * (self.ut_steps > 1)  # the exit gate
         if self.n_heads_window:
             total += E      # counted whole: the final norm too
         if self.pos == "learned":
@@ -787,13 +787,13 @@ def param_logical_dims(cfg: GPTConfig) -> Dict[str, Tuple[Optional[str], ...]]:
         dims["w_q"] = ("layers", "embed", "heads", "head_dim")
         dims["w_kv"] = ("layers", "embed", None, "heads", "head_dim")
     if cfg.kv_lora_rank or cfg.dense_layers or cfg.moe_shared or cfg.ssm_layout \
-            or cfg.n_heads_window or cfg.attn_gate:
+            or cfg.n_heads_window or cfg.attn_gate or cfg.block_pattern:
         raise NotImplementedError(
             "no sharding is written for latent attention (kv_lora_rank), "
             "leading dense layers (dense_layers), a shared expert (moe_shared), "
-            "state-space layers (ssm_layout), attention stacks by kind "
-            "(n_heads_window) or a gate a head (attn_gate): they are served on "
-            "one chip")
+            "state-space layers (ssm_layout), attention stacks by kind (n_heads_window), "
+            "a gate a head (attn_gate) or blocks of one mixer (block_pattern): they are "
+            "served on one chip")
     if cfg.mlp_type == "moe":
         dims["moe_router"] = ("layers", "embed", "experts")
         dims["moe_w_in"] = ("layers", "experts", "embed", "mlp")
@@ -1008,7 +1008,7 @@ def _lead_stack(params):
 
 def init_params(rng, cfg: GPTConfig) -> Dict[str, jnp.ndarray]:
     if cfg.init == "unit_stream":
-        return _init_unit_stream(rng, cfg)
+        return (_init_pattern if cfg.block_pattern else _init_unit_stream)(rng, cfg)
     if cfg.init != "gpt2":
         raise ValueError(f"init {cfg.init!r}: gpt2 | unit_stream")
     if cfg.kv_lora_rank or cfg.dense_layers or cfg.moe_shared or cfg.moe_held \
@@ -1213,7 +1213,7 @@ def _dense_mlp(cfg: GPTConfig, p, mlp_in):
 
 
 def _dropless_mlp(cfg: GPTConfig, router, experts, router_in, mlp_in,
-                  layer=None, valid=None):
+                  layer=None, valid=None, bias=None):
     """The dropless expert layer over [B, S, E]: float32 router on
     `router_in` (the layer's input or the normed MLP input, as
     `cfg.moe_router_in` says), top-k without capacity under the config's
@@ -1234,7 +1234,7 @@ def _dropless_mlp(cfg: GPTConfig, router, experts, router_in, mlp_in,
     B, S, E = mlp_in.shape
     logits = router_in.reshape(B * S, E).astype(jnp.float32) @ router.astype(jnp.float32)
     idx, w = moe.dropless_route(logits, cfg.moe_top_k, cfg.moe_scoring,
-                                cfg.moe_route_scale)
+                                cfg.moe_route_scale, bias)
     combine = moe.dropless_combine(idx, w, cfg.moe_experts)
     if cfg.moe_held:
         combine = combine[:, cfg.moe_held[0]: sum(cfg.moe_held)]
@@ -1357,8 +1357,8 @@ def _refuse_new_fields(cfg: GPTConfig, what: str):
     if cfg.n_heads_window:
         bad.append("attention stacks by kind (n_heads_window): one head count "
                    "and one rotary table")
-    if cfg.attn_gate:
-        bad.append("a gate a head (attn_gate)")
+    if cfg.attn_gate: bad.append("a gate a head (attn_gate)")
+    if cfg.block_pattern: bad.append("blocks of one mixer (block_pattern): three stacks by kind")
     if bad:
         raise NotImplementedError(f"{what} does not support " + ", ".join(bad))
 
@@ -1649,9 +1649,9 @@ def ut_exit_pdf(lams):
 
 def _logits(params, x, cfg: GPTConfig):
     """Final norm and head over hidden states [..., E] -> [..., V] in
-    cfg.dtype; the cache programs hand float32 on. A looped model's stream
-    was normed when its last pass closed (`_close_pass`): no second norm."""
-    if cfg.ut_steps == 1:
+    cfg.dtype; the cache programs hand float32 on. A looped model's stream was normed when
+    its last pass closed (`_close_pass`), a `block_pattern` model's by its own eps: no second norm."""
+    if cfg.ut_steps == 1 and not cfg.block_pattern:     # a `block_pattern` model closes its own
         x = _norm(x, params["ln_f_w"], params.get("ln_f_b"), cfg.norm)
     head = params["tok_embed"].T if cfg.tie_embeddings else params["lm_head"]
     return jnp.einsum("...e,ev->...v", x, head.astype(cfg.dtype))
@@ -1765,11 +1765,11 @@ def global_positions(cfg: GPTConfig, local_seq: int):
 def forward(params, tokens, cfg: GPTConfig, positions=None, mesh=None, return_aux=False):
     """tokens [B, S] → logits [B, S, V] (or (logits, moe_aux_loss) with
     return_aux=True).
-
     mesh=None → plain jit or caller-managed shard_map (manual SPMD).
     mesh given → automatic pjit partitioning with a nested shard_map around
     the attention core when cfg.attn_impl is ring/ulysses.
     """
+    if cfg.block_pattern: return _pattern_forward(params, tokens, cfg, return_aux)
     B, S = tokens.shape
     if positions is None:
         # In automatic (pjit) mode shapes are global — plain arange is right.
@@ -1845,7 +1845,7 @@ def _refuse_looped_training(cfg: GPTConfig, what: str):
         ("moe_shared", cfg.moe_shared), ("moe_held", cfg.moe_held),
         ("moe_scoring", cfg.moe_scoring != "softmax"),
         ("ssm_layout", cfg.ssm_layout), ("n_heads_window", cfg.n_heads_window),
-        ("attn_gate", cfg.attn_gate)) if on]
+        ("attn_gate", cfg.attn_gate), ("block_pattern", cfg.block_pattern)) if on]
     if served:
         raise NotImplementedError(
             f"{what} does not train a model with {', '.join(served)}: "
@@ -2379,9 +2379,8 @@ class KVLayout:
     arrays `state` names, (name, shape a layer a slot, dtype) each, one
     fixed slot a sequence, beside the pool (`init_paged_cache`): the scan's
     float32 state in the shape `ops/ssm.py` keeps it and the convolution's
-    last inputs as one row. `slot_of` of such a layer is its index in those
-    arrays; the pool is as deep as the layers that DO keep rows (`per_group`
-    counts them alone)."""
+    last inputs as one row. `slot_of` of such a layer is its index in those arrays; the pool
+    is as deep as the layers that DO keep rows (`per_group` counts them alone)."""
 
     per_group: int                  # layers in a group
     windows: Tuple[int, ...]        # per group: 0 = keeps every token, else the window
@@ -2392,6 +2391,7 @@ class KVLayout:
     value_row: int = 0              # width of the row in pool "v"; 0: no such pool
     state_layers: int = 0           # layers that keep a state a sequence and no row
     state: Tuple[Tuple[str, Tuple[int, ...], str], ...] = ()
+    kinds: Tuple[Tuple[str, int], ...] = ()     # a `block_pattern` model's blocks: all ("run"), by kind
 
     @property
     def depth(self) -> int:
@@ -2412,8 +2412,8 @@ class KVLayout:
 
 @functools.lru_cache(maxsize=None)
 def kv_layout(cfg: GPTConfig) -> KVLayout:
-    L = cfg.n_layers
-    kinds = cfg.layer_kinds
+    L, kinds = cfg.n_layers, cfg.layer_kinds
+    if cfg.block_pattern: return _pattern_layout(cfg)
     rows = ((-(-(cfg.kv_lora_rank + cfg.rotary_dim) // 128) * 128, 0)
             if cfg.kv_lora_rank else (cfg.kv_heads * cfg.d_head,) * 2)
     if cfg.ssm_layout:      # rows for the attention layers, a slot's state for the rest
@@ -2479,7 +2479,7 @@ def attn_heads_by_window(cfg: GPTConfig) -> Tuple[Tuple[int, int], ...]:
     out once)."""
     win = (cfg.layer_kinds or (None, (0,) * cfg.n_layers))[1]
     heads: Dict[int, int] = {}
-    for h, w, ssm in zip(cfg.layer_heads, win, cfg.ssm_layout or (0,) * cfg.n_layers):
+    for h, w, ssm in zip(cfg.layer_heads, win, _rowless_layers(cfg)):
         if not ssm:
             heads[w] = heads.get(w, 0) + h * cfg.ut_steps
     return tuple(heads.items())
@@ -2600,7 +2600,7 @@ def _paged_layers(params, tokens, pos, valid, block_tables, kv, cfg: GPTConfig,
         return attention_over(q, kk, vv, slot, table,
                               None if kind is None else kind["window"]), (kk, vv)
 
-
+    if cfg.block_pattern: return _paged_blocks(cfg, params, x, kv, attend, real, pos, state_slots)
     stacks = _pop_expert_stacks(cfg, layer_stack)
 
     def layers(carry, base=None):
@@ -2878,3 +2878,333 @@ def hold_served(params):
     held = {k: v for k, v in params.items() if k != "w_qkv"}
     w = held["w_qkv_served"] = _served_qkv(params["w_qkv"])
     return held, w.size * w.dtype.itemsize
+
+
+# ------------------------------------------ blocks of ONE mixer (`block_pattern`)
+# A model whose block l is ONE mixer under ONE norm, h <- h + mixer_c(RMSNorm_l(h)),
+# its kind c the l-th character of `cfg.block_pattern` (the `nemotron_h` family's
+# `hybrid_override_pattern`): "M" a Mamba-2 mixer (`ops/ssm.py` `mamba2_mixer`:
+# `ssm_heads` heads of `ssm_head_dim` channels over a state of `ssm_state`, B and C
+# shared by `ssm_groups` groups, `ssm_conv` taps over x, B and C together), "E"
+# dropless experts of TWO matrices (`activation` "relu2", `ops/moe.py`; sigmoid
+# scores, a selection bias, `moe_shared` always-on experts of `d_mlp` stored as one
+# matrix pair, a range held under `moe_held`), "*" grouped-query attention without a
+# positional term. No block has a second sublayer, so no block runs `_block`. The
+# weights are THREE stacks by kind, each with its own norm (`m2_*` [M blocks, ...],
+# `moe_*` (`moe_w_in` [E blocks, held, F, E]: out-features first) / `shared_*`, `attn_ln_w` / `w_q` / `w_kv` / `w_o` [*
+# blocks, ...]) and the pattern is static: a block reads its stack at its index among
+# its kind, where it lies. An M block keeps a state a sequence (`KVLayout.state`: the
+# convolution's tail and the float32 state [heads, head_dim, state]), a * block rows
+# a token, an E block nothing. Served by `forward` and the paged programs on one
+# chip; everything else refuses it by name. This section stands at the END of the
+# file, and its callers above were edited without moving a line (ROADMAP D20).
+_BLOCK_KINDS = {"M": "ssm", "E": "moe", "*": "attn"}
+_M2_KEYS = ("w_in", "conv_w", "conv_b", "dt_bias", "A_log", "D", "norm_w", "w_out")
+
+
+def _check_block_pattern(cfg: GPTConfig):
+    """`GPTConfig.__post_init__`'s checks of the fields this section reads."""
+    if cfg.moe_select_bias and cfg.moe_scoring != "sigmoid":
+        raise ValueError("moe_select_bias: a selection bias goes with moe_scoring='sigmoid'")
+    if cfg.block_pattern is None:
+        if cfg.activation == "relu2":
+            raise ValueError('activation "relu2" is the two-matrix expert of a '
+                             "block_pattern model; no other MLP here is written without a gate")
+        return
+    if len(cfg.block_pattern) != cfg.n_layers or set(cfg.block_pattern) - set(_BLOCK_KINDS):
+        raise ValueError(f"block_pattern {cfg.block_pattern!r}: one of M, E, * for each "
+                         f"of the {cfg.n_layers} blocks")
+    if (cfg.norm != "rmsnorm" or cfg.pos != "none" or cfg.activation != "relu2"
+            or cfg.mlp_type != "moe" or cfg.moe_routing != "dropless"
+            or cfg.moe_scoring != "sigmoid" or cfg.tie_embeddings or cfg.init != "unit_stream"
+            or cfg.ssm_layout or cfg.layer_kinds is not None or cfg.ut_steps > 1
+            or cfg.kv_lora_rank or cfg.dense_layers or cfg.sandwich_norm or cfg.parallel_block
+            or cfg.n_heads_window or cfg.attn_gate or cfg.kv_heads == cfg.n_heads
+            or cfg.ssm_heads < 1 or cfg.ssm_head_dim < 1 or cfg.ssm_heads % cfg.ssm_groups):
+        raise ValueError(
+            "block_pattern: a one-pass RMSNorm model without positional term or bias, an "
+            'untied head, init="unit_stream", grouped-query attention blocks, Mamba-2 blocks '
+            "(ssm_heads x ssm_head_dim, ssm_groups dividing the heads) and dropless "
+            'sigmoid-scored experts of two matrices (mlp_type="moe", activation="relu2")')
+
+
+def _pattern_counts(cfg: GPTConfig) -> Dict[str, int]:
+    """{"M", "E", "*"}: blocks of each kind."""
+    return {c: cfg.block_pattern.count(c) for c in _BLOCK_KINDS}
+
+
+def _moe_layers(cfg: GPTConfig) -> int:
+    """Layers (blocks) whose MLP is the routed experts."""
+    if cfg.mlp_type != "moe":
+        return 0
+    return _pattern_counts(cfg)["E"] if cfg.block_pattern else cfg.n_layers - cfg.dense_layers
+
+
+# A property of the config, attached here because the class body's lines are counted (D20).
+GPTConfig.moe_layers = property(_moe_layers)
+
+
+def _m2_conv_width(cfg: GPTConfig) -> int:
+    """Channels the Mamba-2 convolution runs over: x, B and C together."""
+    return cfg.ssm_heads * cfg.ssm_head_dim + 2 * cfg.ssm_groups * cfg.ssm_state
+
+
+def _pattern_params(cfg: GPTConfig) -> int:
+    """`GPTConfig.n_params` of a `block_pattern` model: what the tree holds."""
+    E, F, V, n = cfg.d_model, cfg.d_mlp, cfg.vocab_size, _pattern_counts(cfg)
+    Di, Dc, H = cfg.ssm_heads * cfg.ssm_head_dim, _m2_conv_width(cfg), cfg.ssm_heads
+    mamba = E * (Di + Dc + H) + Dc * cfg.ssm_conv + Dc + 3 * H + Di + Di * E + E
+    experts = ((cfg.held_experts + cfg.moe_shared) * 2 * E * F + E * cfg.moe_experts
+               + cfg.moe_select_bias * cfg.moe_experts + E)
+    attn = 2 * E * cfg.d_head * (cfg.n_heads + cfg.kv_heads) + E
+    return n["M"] * mamba + n["E"] * experts + n["*"] * attn + 2 * V * E + E
+
+
+def _init_pattern(rng, cfg: GPTConfig) -> Dict[str, jnp.ndarray]:
+    """`init_params` of a `block_pattern` model under init="unit_stream": a matrix of
+    fan-in n has std gain / sqrt(n), its gain from the preset's `init_gains`; norms
+    1; the Mamba-2 steps log-uniform in [1e-3, 1e-1] through `dt_bias` (softplus
+    inverted), A = -(1 .. 16) a head, D = 1 (the published initialisation's ranges);
+    the selection bias seeded NON-ZERO (std `select_bias`), so that leaving it out
+    changes which experts serve a token."""
+    E, F, V, X, n = cfg.d_model, cfg.d_mlp, cfg.vocab_size, cfg.held_experts, _pattern_counts(cfg)
+    Lm, Le, La = n["M"], n["E"], n["*"]
+    H, Hkv, Dh = cfg.n_heads, cfg.kv_heads, cfg.d_head
+    Hs, Di, Dc, K = cfg.ssm_heads, cfg.ssm_heads * cfg.ssm_head_dim, _m2_conv_width(cfg), cfg.ssm_conv
+    k = jax.random.split(rng, 16)
+    dt, g = cfg.param_dtype, dict(cfg.init_gains)
+
+    def normal(key, shape, gain, fan_in):
+        return (jax.random.normal(key, shape, jnp.float32)
+                * (gain / math.sqrt(fan_in))).astype(dt)
+
+    step = jnp.exp(jax.random.uniform(k[12], (Lm, Hs), jnp.float32,
+                                      math.log(1e-3), math.log(1e-1)))
+    params = {
+        "tok_embed": normal(k[0], (V, E), g["embed"], 1),
+        "lm_head": normal(k[1], (E, V), g["head"], E),
+        "ln_f_w": jnp.ones((E,), dt),
+        "m2_ln_w": jnp.ones((Lm, E), dt),
+        "m2_w_in": normal(k[2], (Lm, E, Di + Dc + Hs), g["ssm_in"], E),
+        "m2_conv_w": normal(k[3], (Lm, K, Dc), g["ssm_conv"], K),
+        "m2_conv_b": jnp.zeros((Lm, Dc), dt),
+        "m2_dt_bias": (step + jnp.log(-jnp.expm1(-step))).astype(dt),
+        "m2_A_log": jnp.log(jax.random.uniform(k[13], (Lm, Hs), jnp.float32, 1.0, 16.0)).astype(dt),
+        "m2_D": jnp.ones((Lm, Hs), dt),
+        "m2_norm_w": jnp.ones((Lm, Di), dt),
+        "m2_w_out": normal(k[4], (Lm, Di, E), g["ssm_out"], Di),
+        "moe_ln_w": jnp.ones((Le, E), dt),
+        "moe_router": normal(k[5], (Le, E, cfg.moe_experts), g["router"], E),
+        "moe_w_in": normal(k[6], (Le, X, F, E), g["mlp_in"], E),    # out-features first (ops/moe.py)
+        "moe_w_out": normal(k[7], (Le, X, F, E), g["expert_out"], F),
+        "attn_ln_w": jnp.ones((La, E), dt),
+        "w_q": normal(k[8], (La, E, H, Dh), g["q"], E),
+        "w_kv": jnp.stack([normal(k[9], (La, E, Hkv, Dh), g["k"], E),
+                           normal(k[10], (La, E, Hkv, Dh), g["v"], E)], axis=2),
+        "w_o": normal(k[11], (La, H, Dh, E), g["o"], H * Dh),
+    }
+    if cfg.moe_select_bias:
+        params["moe_select_bias"] = normal(k[14], (Le, cfg.moe_experts), g["select_bias"], 1)
+    if cfg.moe_shared:
+        ks, Fs = jax.random.split(k[15]), cfg.moe_shared * F
+        params["shared_w_in"] = normal(ks[0], (Le, E, Fs), g["mlp_in"], E)
+        params["shared_w_out"] = normal(ks[1], (Le, Fs, E), g["mlp_out"], Fs)
+    return params
+
+
+def _rowless_layers(cfg: GPTConfig) -> Tuple[int, ...]:
+    """[L] 1 where the layer keeps no K/V row a token (a state-space layer, or a
+    `block_pattern` block that is not attention)."""
+    if cfg.block_pattern:
+        return tuple(int(c != "*") for c in cfg.block_pattern)
+    return cfg.ssm_layout or (0,) * cfg.n_layers
+
+
+def _pattern_layout(cfg: GPTConfig) -> KVLayout:
+    """`kv_layout` of a `block_pattern` model: ONE group as deep as the attention
+    blocks, a state a sequence for the Mamba-2 blocks (the convolution's last
+    inputs as one row in the compute dtype; the float32 state [heads, head_dim,
+    state]), `slot_of` a block's index among its kind, and the blocks a program runs: all
+    of them ("run") and by kind, what the engine's books count a dispatch."""
+    from ..ops import ssm
+
+    seen, slot_of = dict.fromkeys(_BLOCK_KINDS, 0), []
+    for c in cfg.block_pattern:
+        slot_of.append(seen[c])
+        seen[c] += 1
+    row = cfg.kv_heads * cfg.d_head
+    return KVLayout(
+        seen["*"], (0,), (0,) * cfg.n_layers, tuple(slot_of), 1, row, row, seen["M"],
+        (("conv", ((cfg.ssm_conv - 1) * _m2_conv_width(cfg),), jnp.dtype(cfg.dtype).name),
+         ("ssm", ssm.mamba2_state_shape(cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_state),
+          "float32")),
+        (("run", cfg.n_layers), *((_BLOCK_KINDS[c], n) for c, n in seen.items())))
+
+
+def _relu2_mlp(x, w_in, w_out):
+    """W_out relu(W_in x)^2: the always-on expert of two matrices."""
+    u = jnp.einsum("bse,ef->bsf", x, w_in)
+    return jnp.einsum("bsf,fe->bse", jnp.square(jax.nn.relu(u)), w_out)
+
+
+def _pattern_blocks(cfg: GPTConfig, params, x, valid, mamba, attention):
+    """The block loop of a `block_pattern` model over x [B, S, E]: each block's norm,
+    its mixer, the sum into the stream. `mamba(i, p, h)` and `attention(i, q, k, v)`
+    are the program's own (where the state and the rows come from and go to): an M
+    block's weights `p` under `ops/ssm.py`'s names and the normed stream -> what the
+    mixer adds; q [B, H, S, Dh], k, v [B, Hkv, S, Dh] -> attention [B, H, S, Dh];
+    `i` the block's index among its kind. The expert blocks are the same in every
+    program. Returns (x under the FINAL norm, whose eps `_logits` does not take: that
+    one leaves such a stream alone; the routing's load summed over the expert blocks)."""
+    dt = cfg.dtype
+    norm = lambda w: rmsnorm(x, w.astype(dt), cfg.norm_eps)
+    index = dict.fromkeys(_BLOCK_KINDS, 0)
+    loads = jnp.zeros((5 if cfg.moe_held else 2,), jnp.float32)
+    for c in cfg.block_pattern:
+        i = index[c]
+        index[c] += 1
+        if c == "M":
+            h = norm(params["m2_ln_w"][i])
+            x = x + mamba(i, {name: params["m2_" + name][i].astype(dt) for name in _M2_KEYS}, h)
+        elif c == "E":
+            h = norm(params["moe_ln_w"][i])
+            y, load = _dropless_mlp(
+                cfg, params["moe_router"][i], (None, params["moe_w_in"], params["moe_w_out"]),
+                h, h, layer=i, valid=valid,
+                bias=params["moe_select_bias"][i] if cfg.moe_select_bias else None)
+            if cfg.moe_shared:
+                y = y + _relu2_mlp(h, params["shared_w_in"][i].astype(dt),
+                                   params["shared_w_out"][i].astype(dt))
+            x, loads = x + y, loads + load
+        else:
+            h = norm(params["attn_ln_w"][i])
+            q, k, v = (a.transpose(0, 2, 1, 3) for a in _project_qkv(
+                cfg, {"w_q": params["w_q"][i].astype(dt), "w_kv": params["w_kv"][i].astype(dt)}, h))
+            x = x + _merge_heads(attention(i, q, k, v), params["w_o"][i].astype(dt))
+    return rmsnorm(x, params["ln_f_w"].astype(dt), cfg.norm_eps), loads
+
+
+def _paged_blocks(cfg: GPTConfig, params, x, kv, attend, real, pos, state_slots):
+    """`_paged_layers` for a `block_pattern` model, from its embedding x [B, S, E] on:
+    the attention blocks through `_paged_layers`' own `attend` over a pool as deep as
+    they are many, the Mamba-2 blocks from the state of lane b's slot `state_slots[b]`
+    in kv["state"] (gathered, advanced over the lane's real tokens, written back in
+    place), a lane whose first token sits at position 0 from a ZERO state, as a
+    state-space layer of `ssm_layout` does. Returns what `_paged_layers` returns."""
+    if state_slots is None:
+        raise NotImplementedError(
+            "a model with Mamba-2 blocks (block_pattern) is served by prefill_paged "
+            "and decode_step_paged, which name each lane's state slot; a verify step "
+            "would have to roll the state back past the drafts it rejects")
+    from ..ops import ssm
+
+    B = x.shape[0]
+    fresh = (pos[:, 0] == 0)[:, None]
+    tail_shape = (B, cfg.ssm_conv - 1, _m2_conv_width(cfg))
+    pool = {"kv": (kv["k"], kv["v"]), "state": kv["state"]}
+
+    def mamba(i, p, h):
+        st = pool["state"]
+        tail = jnp.where(fresh, 0, st["conv"][i, state_slots])
+        s0 = jnp.where(fresh[..., None, None], 0, st["ssm"][i, state_slots])
+        out, tail, s = ssm.mamba2_mixer(
+            p, h, tail.reshape(tail_shape), s0, real, groups=cfg.ssm_groups,
+            chunk=cfg.ssm_chunk, eps=cfg.norm_eps)
+        pool["state"] = {"conv": st["conv"].at[i, state_slots].set(tail.reshape(B, -1)),
+                         "ssm": st["ssm"].at[i, state_slots].set(s)}
+        return out
+
+    def attention(i, q, k, v):
+        attn, pool["kv"] = attend(*pool["kv"], i, None, q, k, v, None)
+        return attn
+
+    x, loads = _pattern_blocks(cfg, params, x, real, mamba, attention)
+    kk, vv = pool["kv"]
+    return x, {"k": kk, "v": vv, "state": pool["state"]}, loads / cfg.moe_layers, None
+
+
+def _pattern_forward(params, tokens, cfg: GPTConfig, return_aux: bool):
+    """`forward` of a `block_pattern` model: the whole sequence, every sequence's
+    state from zero and dropped at the end, plain causal attention."""
+    from ..ops import ssm
+
+    B, S = tokens.shape
+    x = _embed(params, tokens, None, cfg)
+    everyone = jnp.ones((B, S), bool)
+    tail = jnp.zeros((B, cfg.ssm_conv - 1, _m2_conv_width(cfg)), cfg.dtype)
+    s0 = jnp.zeros((B, *ssm.mamba2_state_shape(cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_state)),
+                   jnp.float32)
+    x, _ = _pattern_blocks(
+        cfg, params, x, everyone,
+        lambda i, p, h: ssm.mamba2_mixer(p, h, tail, s0, everyone, groups=cfg.ssm_groups,
+                                         chunk=cfg.ssm_chunk, eps=cfg.norm_eps)[0],
+        lambda i, q, k, v: _attention_plain(cfg, q, k, v, jnp.arange(S)))
+    logits = _logits(params, x, cfg)
+    return (logits, jnp.zeros((), jnp.float32)) if return_aux else logits
+
+
+def nemotron3_nano_30b_a3b(**kw):
+    """NVIDIA-Nemotron-3-Nano-30B-A3B (huggingface.co/nvidia/NVIDIA-Nemotron-3-Nano-30B-
+    A3B-BF16, `model_type: "nemotron_h"`): 52 blocks of 2688, each ONE mixer under one
+    RMSNorm (eps 1e-5) by `hybrid_override_pattern`: 23 Mamba-2 (64 heads of 64 over a
+    state of 128, 8 groups, 4 taps, chunks of 128), 23 expert blocks (128 routed experts
+    of 1856, W_down relu(W_up x)^2, top-6 by sigmoid score + selection bias, weights
+    normalised x 2.5, one shared expert of 3712 = `moe_shared` 2 x 1856 in one matrix
+    pair) and 6 attention blocks (32 query heads over 2 K/V heads of 128, no positional
+    term); vocabulary 131,072, untied head; no bias but the convolution's. Serving only:
+    `forward` and the paged programs. The benchmark runs stage 0 of four (13 blocks), 64
+    of the 128 experts held, half the vocabulary (benchmarks/configs)."""
+    pattern = kw.get("block_pattern", "MEMEM*EMEMEM*EMEMEM*EMEMEM*EMEMEM*EMEMEMEM*EMEMEMEME")
+    return GPTConfig(
+        **{
+            **dict(
+                n_layers=len(pattern),
+                block_pattern=pattern,
+                d_model=2688,
+                n_heads=32,
+                n_kv_heads=2,
+                d_head=128,
+                d_mlp=1856,
+                vocab_size=131072,
+                max_seq=262144,
+                norm="rmsnorm",
+                norm_eps=1e-5,
+                activation="relu2",
+                pos="none",
+                tie_embeddings=False,
+                ssm_heads=64,
+                ssm_head_dim=64,
+                ssm_groups=8,
+                ssm_state=128,
+                ssm_conv=4,
+                ssm_chunk=128,
+                mlp_type="moe",
+                moe_routing="dropless",
+                moe_experts=128,
+                moe_top_k=6,
+                moe_scoring="sigmoid",
+                moe_route_scale=2.5,
+                moe_router_in="mlp",
+                moe_select_bias=True,
+                moe_shared=2,
+                param_dtype=jnp.bfloat16,
+                # Random weights under which a rounding grows 5-fold through 13 blocks
+                # (`embed` 2.5: each block adds 0.3-1 to a stream of 2.5; at 1.0 it grew
+                # 55-fold and a sound engine read as float8 weights do) and a routed
+                # expert swapped at a near-tie moves a logit less than float8 does
+                # (`expert_out` 0.15), with every other mechanism a visible part of the
+                # logits (`scripts/nemotron_h_tolerance.py` reads each on the chip;
+                # PERF.md §6, PR 51). No program's shape or time depends on the numbers.
+                init="unit_stream",
+                init_gains=(("embed", 2.5), ("head", 1.0), ("q", 1.2), ("k", 1.2), ("v", 1.0),
+                            ("o", 1.0), ("router", 2.0), ("select_bias", 0.3),
+                            ("mlp_in", 1.0), ("mlp_out", 0.5), ("expert_out", 0.15),
+                            ("ssm_in", 1.0), ("ssm_conv", 1.0), ("ssm_out", 1.0)),
+                attn_impl="ref",
+            ),
+            **kw,
+        }
+    )
+
+
+CONFIGS["nemotron3-nano-30b-a3b"] = nemotron3_nano_30b_a3b

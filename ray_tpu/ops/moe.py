@@ -167,12 +167,12 @@ MOE_LOGICAL_DIMS = {
 # count, and none at all for a routing that chose no expert held here.
 
 
-def dropless_route(logits, top_k: int, scoring: str = "softmax", scale: float = 1.0):
-    """logits [N, X] (any float) -> (idx [N, k] int32, weights [N, k] f32):
-    the k largest logits of each token and, as `scoring` says, the softmax
-    over those k ("softmax") or their sigmoids over the sum of those k, times
-    `scale` ("sigmoid": the sigmoid keeps the logits' order, so the k largest
-    scores are the k largest logits)."""
+def dropless_route(logits, top_k: int, scoring: str = "softmax", scale: float = 1.0, bias=None):
+    """logits [N, X] (any float) -> (idx [N, k] int32, weights [N, k] f32): the k largest
+    logits of each token and, as `scoring` says, the softmax over those k ("softmax") or
+    their sigmoids over the sum of those k, times `scale` ("sigmoid": it keeps the logits'
+    order). `bias` [X]: a selection bias, `_route_biased` (chosen by score + bias)."""
+    if bias is not None: return _route_biased(logits, top_k, scoring, scale, bias)
     vals, idx = jax.lax.top_k(logits.astype(jnp.float32), top_k)
     if scoring == "softmax":
         return idx.astype(jnp.int32), jax.nn.softmax(vals, axis=-1)
@@ -194,7 +194,7 @@ def _gated(act: str, g, u):
         return jax.nn.relu(g) * u
     if act == "swiglu":
         return jax.nn.silu(g) * u
-    raise ValueError(f"dropless experts are gated (reglu | swiglu), got {act!r}")
+    return _ungated(act, g, u)      # an expert of two matrices, or a refusal
 
 
 # Rows a tile of the grouped form: the MXU's own height. An expert with one
@@ -254,10 +254,10 @@ def _grouped_plain(x, combine, w_gate, w_in, w_out, activation, layer,
         mine = jax.lax.dynamic_index_in_dim(rows, e, 1, keepdims=False) - tile_first[t]
         pick = mine[None, :] == jnp.arange(rows_tile)[:, None]           # [TM, N]
         tile = jnp.dot(pick.astype(dt), x)            # one 1 a row: exact
-        g = jnp.dot(tile, _one(w_gate, layer, e).astype(dt),
+        g, u = (None if w is None else jnp.dot(     # w_gate None: two matrices, w_in [F, D]
+                    tile, (_one(w, layer, e).T if w_gate is None else _one(w, layer, e)).astype(dt),
                     preferred_element_type=jnp.float32)
-        u = jnp.dot(tile, _one(w_in, layer, e).astype(dt),
-                    preferred_element_type=jnp.float32)
+                for w in (w_gate, w_in))
         o = jnp.dot(_gated(activation, g, u).astype(dt),
                     _one(w_out, layer, e).astype(dt),
                     preferred_element_type=jnp.float32)
@@ -423,7 +423,7 @@ def _grouped_run(x, combine, w_gate, w_in, w_out, layer, *, activation, k,
     plain loop. Jitted for its trace alone: a server's programs differ in
     table widths far more often than in tokens, and every program of one
     token count takes this trace (two kernels' worth) from the cache."""
-    run = _grouped_pallas if kernels else _grouped_plain
+    run = (_grouped_pallas_ungated if w_gate is None else _grouped_pallas) if kernels else _grouped_plain
     return run(x, combine, w_gate, w_in, w_out, activation, layer,
                *dropless_groups(combine, k, rows_tile), rows_tile)
 
@@ -435,7 +435,7 @@ def _grouped_experts(x, combine, w_gate, w_in, w_out, activation, layer, k):
     of both."""
     from .attention import _on_tpu
 
-    D, F = w_gate.shape[-2:]
+    D, F = _lane_widths(w_gate, w_in)
     return _grouped_run(
         x, combine, w_gate, w_in, w_out, layer, activation=activation, k=k,
         rows_tile=GROUP_ROWS, kernels=_on_tpu() and D % 128 == 0 and F % 128 == 0)
@@ -460,15 +460,15 @@ _grouped_experts.defvjp(_grouped_fwd, _grouped_bwd)
 def dropless_experts(x, combine, w_gate, w_in, w_out, activation: str,
                      layer=None, grouped_k: int = 0):
     """y [N, D] = sum_e combine[n, e] * W_out,e( act(W_gate,e x) * (W_in,e x) ).
+    `w_gate` None: an expert is TWO matrices, W_out,e act(W_in,e x) ("relu2"), and `w_in`
+    is kept [X, F, D], out-features first as `w_out` is: the model width fills whole lane
+    tiles whatever F (1856 = 14.5 x 128: kept [D, F] the chip copied the whole stack a call).
 
-    x [N, D]; combine [N, X] f32; weights [X, D, F] / [X, F, D], or with
-    `layer` (a traced index) the whole stacks [L, X, ...] of which layer
-    `layer` is read in place. Two forms, the same mathematics:
-
-    * dense (default): one [N, D] x [D, X*F] product for gate and up, the
-      combine weights folded into the hidden activations, one [N, X*F] x
-      [X*F, D] product down. Every expert meets every token: the reference
-      the tests hold the other form to.
+    x [N, D]; combine [N, X] f32; weights [X, D, F] / [X, F, D], or with `layer` (a traced
+    index) the whole stacks [L, X, ...] of which layer `layer` is read in place. Two forms:
+    * dense (default): one [N, D] x [D, X*F] product for gate and up, the combine weights
+      folded into the hidden activations, one [N, X*F] x [X*F, D] product down. Every
+      expert meets every token: the reference the tests hold the other form to.
     * `grouped_k` = k > 0 (a token's nonzero columns are at most k): the
       tokens that chose an expert are its rows (`dropless_groups`), each
       expert's rows through its gate, up and down products once, a tile of
@@ -491,10 +491,10 @@ def dropless_experts(x, combine, w_gate, w_in, w_out, activation: str,
             for i in range(0, N, _GROUP_TOKENS)]).astype(dt)
     if layer is not None:
         w_gate, w_in, w_out = (
-            jax.lax.dynamic_index_in_dim(a, layer, 0, keepdims=False)
+            None if a is None else jax.lax.dynamic_index_in_dim(a, layer, 0, keepdims=False)
             for a in (w_gate, w_in, w_out))
-    g = jnp.einsum("nd,xdf->nxf", x, w_gate.astype(dt))
-    u = jnp.einsum("nd,xdf->nxf", x, w_in.astype(dt))
+    g = None if w_gate is None else jnp.einsum("nd,xdf->nxf", x, w_gate.astype(dt))
+    u = jnp.einsum("nd,xfd->nxf" if w_gate is None else "nd,xdf->nxf", x, w_in.astype(dt))
     h = _gated(activation, g, u) * combine[..., None].astype(dt)
     return jnp.einsum("nxf,xfd->nd", h, w_out.astype(dt))
 
@@ -517,3 +517,145 @@ def dropless_load(combine, valid=None, top_k: int = 0):
     tokens = combine.shape[0] if valid is None else valid.sum()
     return (*load, here, jnp.asarray(tokens * top_k, jnp.float32),
             (here == 0).astype(jnp.float32))
+
+
+# ------------------------------------------- what stands below the kernels
+# New code of this module goes HERE, behind the functions the served programs'
+# Pallas bodies name by line (ROADMAP D20), each reached from its caller above.
+
+
+def _lane_widths(w_gate, w_in):
+    """(D, F) as `_grouped_experts`' lane-tile test reads them (its line is keyed: D20).
+    Gated experts keep [.., D, F]: both widths are lane dimensions of a block and must be
+    whole tiles of 128. Two-matrix experts keep `w_in` [.., F, D]: F is a SUBLANE dimension
+    of every weight block (whole tiles of 8) and the full extent of the hidden rows'
+    blocks, so where it is a multiple of 8 it is reported as one lane tile."""
+    if w_gate is not None:
+        return w_in.shape[-2:]
+    F, D = w_in.shape[-2:]
+    return D, 128 if F % 8 == 0 else F
+
+
+def _route_biased(logits, top_k: int, scoring: str, scale: float, bias):
+    """`dropless_route` under a selection bias [X]: scores s = sigmoid(logits)
+    over all experts, the k experts with the largest s + bias CHOSEN, the
+    weights the chosen experts' s (without the bias) over their sum, times
+    `scale`: the bias moves which experts serve a token, never how much."""
+    if scoring != "sigmoid":
+        raise ValueError("a selection bias goes with sigmoid scores")
+    scores = jax.nn.sigmoid(logits.astype(jnp.float32))
+    _, idx = jax.lax.top_k(scores + bias.astype(jnp.float32), top_k)
+    kept = jnp.take_along_axis(scores, idx, axis=-1)
+    return idx.astype(jnp.int32), kept / kept.sum(axis=-1, keepdims=True) * scale
+
+
+def _ungated(act: str, g, u):
+    """The activation of an expert WITHOUT a gate matrix (g None)."""
+    if g is None and act == "relu2":
+        return jnp.square(jax.nn.relu(u))
+    raise ValueError(
+        f"dropless experts are gated (reglu | swiglu) or of two matrices (relu2, "
+        f"no gate), got {act!r} with{'out' if g is None else ''} a gate")
+
+
+def _grouped_pallas_ungated(x, combine, w_gate, w_in, w_out, activation, layer,
+                            rows, tile_expert, tile_first, tiles, rows_tile,
+                            interpret=False):
+    """`_grouped_pallas` for experts of two matrices (`w_gate` None): the same
+    layout, grids and down kernel, and a hidden kernel that streams ONE
+    [slab, F] block a step (`moe_grouped_hidden_ungated`) where the gated one
+    streams two: no stand-in matrix, no bytes read for a gate that is not
+    there. `w_in` [L, X, F, D] (`dropless_experts`): a step's block is [F, slab],
+    met by the tile's [rows, slab] over the slab. A function of its own, and not
+    a branch of `_grouped_pallas`, because that one's kernels are keyed by their
+    lines (ROADMAP D20)."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    N, D = x.shape
+    dt = x.dtype
+    if layer is None:       # one layer's weights: a stack of one
+        w_in, w_out = w_in[None], w_out[None]
+        layer = 0
+    F = w_in.shape[-2]
+    TM = rows_tile
+    T = tile_expert.shape[0]
+    td = _weight_tile(F, D, w_in.dtype.itemsize)
+    nd = D // td
+    Np = -(-N // 128) * 128                              # whole lane tiles of tokens
+    slabs = jnp.pad(x, ((0, Np - N), (0, 0))).reshape(Np, nd, td).transpose(1, 0, 2)
+    rows = jnp.pad(rows, ((0, Np - N), (0, 0)), constant_values=-1).T[:, None]
+    weights = jnp.pad(combine, ((0, Np - N), (0, 0))).T[:, None]       # [X, 1, Np]
+    scalars = (tile_expert, tile_first, jnp.asarray(layer, jnp.int32).reshape(1))
+    params = pltpu.CompilerParams(
+        dimension_semantics=("arbitrary", "arbitrary"), vmem_limit_bytes=64 << 20)
+
+    def picked(t, first, rows_ref):
+        return rows_ref[...] - first[t] == jax.lax.broadcasted_iota(
+            jnp.int32, (TM, Np), 0)
+
+    def hidden(te, first, l, rows_ref, x_ref, wu_ref, h_ref, u_acc):
+        k = pl.program_id(1)
+
+        @pl.when(k == 0)
+        def _():
+            u_acc[...] = jnp.zeros_like(u_acc)
+
+        pick = jnp.where(picked(pl.program_id(0), first, rows_ref), 1.0, 0.0).astype(dt)
+        tile = jnp.dot(pick, x_ref[k], preferred_element_type=jnp.float32).astype(dt)
+        u_acc[...] += jax.lax.dot_general(           # [TM, slab] x [F, slab] over the slab
+            tile, wu_ref[...].astype(dt), (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32)
+
+        @pl.when(k == nd - 1)
+        def _():
+            h_ref[...] = _ungated(activation, None, u_acc[...]).astype(dt)
+
+    def down(te, first, l, rows_ref, wt_ref, h_ref, wd_ref, y_ref):
+        t = pl.program_id(1)
+
+        @pl.when(t == 0)
+        def _():
+            y_ref[...] = jnp.zeros_like(y_ref)
+
+        mine = picked(t, first, rows_ref)
+        weight = jnp.sum(jnp.where(mine, wt_ref[...], 0.0), axis=1, keepdims=True)
+        o = jnp.dot(h_ref[...], wd_ref[...].astype(dt),
+                    preferred_element_type=jnp.float32) * weight       # [TM, td]
+        pick = jnp.where(mine, 1.0, 0.0).astype(jnp.bfloat16)
+        hi = o.astype(jnp.bfloat16)
+        lo = (o - hi.astype(jnp.float32)).astype(jnp.bfloat16)
+        back = lambda a: jax.lax.dot_general(      # pick^T a: each row to its token
+            pick, a, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+        y_ref[...] += back(hi) + back(lo)
+
+    column = pl.BlockSpec((None, 1, Np), lambda n, t, te, f, l: (te[t], 0, 0))
+
+    def kernels():
+        h = pl.pallas_call(
+            hidden, out_shape=jax.ShapeDtypeStruct((T * TM, F), dt),
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=3, grid=(tiles, nd),
+                in_specs=[pl.BlockSpec((None, 1, Np), lambda t, k, te, f, l: (te[t], 0, 0)),
+                          pl.BlockSpec((nd, Np, td), lambda t, k, te, f, l: (0, 0, 0)),
+                          pl.BlockSpec((None, None, F, td),
+                                       lambda t, k, te, f, l: (l[0], te[t], 0, k))],
+                out_specs=pl.BlockSpec((TM, F), lambda t, k, te, f, l: (t, 0)),
+                scratch_shapes=[pltpu.VMEM((TM, F), jnp.float32)]),
+            compiler_params=params, interpret=interpret,
+            name="moe_grouped_hidden_ungated",
+        )(*scalars, rows, slabs, w_in)
+        y = pl.pallas_call(
+            down, out_shape=jax.ShapeDtypeStruct((Np, D), jnp.float32),
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=3, grid=(nd, tiles),
+                in_specs=[column, column,
+                          pl.BlockSpec((TM, F), lambda n, t, te, f, l: (t, 0)),
+                          pl.BlockSpec((None, None, F, td),
+                                       lambda n, t, te, f, l: (l[0], te[t], 0, n))],
+                out_specs=pl.BlockSpec((Np, td), lambda n, t, te, f, l: (0, n))),
+            compiler_params=params, interpret=interpret, name="moe_grouped_down",
+        )(*scalars, rows, weights, h, w_out)
+        return y[:N]
+
+    return jax.lax.cond(tiles > 0, kernels, lambda: jnp.zeros((N, D), jnp.float32))

@@ -93,7 +93,7 @@ _STEP_BOOKS = (
     # Query heads x keys the dispatched programs' attention covered, summed
     # over the layers, each under its own window and head count
     # (`ops.paged_attention.paged_attn_cover`), and of them the window layers'.
-    "attn_head_keys", "attn_head_keys_window",
+    "attn_head_keys", "attn_head_keys_window", "blocks_run", "blocks_ssm", "blocks_moe", "blocks_attn",
 )
 
 
@@ -1145,11 +1145,11 @@ class InferenceEngine:
         return form
 
     def _count_state(self, tokens: int, real: int, decode: bool):
-        """Add one program of a model with state to its books: `tokens` the
-        scan ran over as the program is shaped, `real` of them unmasked; a
-        decode program's real lanes each read and write their slot's state."""
+        """Add one program of a model with state to its books: `tokens` the scan ran over as the
+        program is shaped, `real` unmasked; a decode lane reads and writes its slot's state; its blocks by kind."""
         if self._stateful:
             b = self._books
+            for kind, n in self._layout.kinds: b["blocks_" + kind] += n     # a `block_pattern` model's
             b["ssm_tokens_scanned"] += tokens
             b["ssm_tokens_masked"] += tokens - real
             if decode:
@@ -1178,7 +1178,7 @@ class InferenceEngine:
         """Add one program of `tokens` tokens (lanes x tokens a lane, padding
         and all) to the expert layers' count."""
         if self.cfg.mlp_type == "moe":
-            self.total_moe_tokens += tokens * (self.cfg.n_layers - self.cfg.dense_layers)
+            self.total_moe_tokens += tokens * self.cfg.moe_layers
 
     def _run_prefill(self, chunk):
         """Dispatch one prefill chunk: compute prompt[start : start+n] into
@@ -1295,7 +1295,7 @@ class InferenceEngine:
             attrs["experts_touched"] = float(self._step_moe[0])
             attrs["expert_load_max"] = float(self._step_moe[1])
             if self.cfg.moe_held:   # means over the expert layers -> their sums
-                layers = self.cfg.n_layers - self.cfg.dense_layers
+                layers = self.cfg.moe_layers
                 held, total, empty = (
                     int(round(float(v) * layers)) for v in self._step_moe[2:])
                 attrs["assign_held"], attrs["assign_total"] = held, total

@@ -160,7 +160,7 @@ def test_the_cell_and_its_files_are_in_the_benchmark():
     assert readers.reader_spec("moe_grouped_token_share") == {
         "kind": "counter_ratio", "num": "moe_tokens_grouped",
         "den": "moe_tokens_expert", "scale": 100.0}
-    assert per_layer["moe_held_assign_share"]["workloads"] == [CELL]
+    assert per_layer["moe_held_assign_share"]["workloads"][0] == CELL      # later cells append
     assert names.index("moe_grouped_token_share") < names.index("moe_empty_layer_share")
     assert per_layer["moe_empty_layer_share"] == {
         "name": "moe_empty_layer_share", "unit": "%", "better": "higher",

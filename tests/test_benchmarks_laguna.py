@@ -184,7 +184,7 @@ def test_the_cell_and_its_files_are_in_the_benchmark():
 
     resolves()
     bench = harness.benchmark()
-    assert len(bench["workloads"]) == 9 and len(bench["configs"]) == 7
+    assert len(bench["workloads"]) >= 9 and len(bench["configs"]) >= 7   # later PRs append
     assert sum(w["chips"] == 4 for w in bench["workloads"]) == 1
     cell, entry = bench["workloads"][8], bench["configs"][6]
     assert cell["name"] == CELL and entry["name"] == "laguna-xs2"
@@ -210,12 +210,12 @@ def test_the_cell_and_its_files_are_in_the_benchmark():
     first = names.index(next(iter(NEW_METRICS)))        # appended, in the issue's order;
     last = first + len(NEW_METRICS)                     # PR 44's two behind them, PR 49's one,
     assert names[first:last] == list(NEW_METRICS)       # PR 50's one (the same reader, this cell too)
-    assert names[last:] == ["decode_attn_kernel_share", "decode_attn_time_share",
-                            "relayout_time_share", "relayout_time_share.itl"]
+    assert names[last:last + 4] == ["decode_attn_kernel_share", "decode_attn_time_share",
+                                    "relayout_time_share", "relayout_time_share.itl"]    # PR 51's behind
     assert {"decode_attn_kernel_share", "decode_attn_time_share",
             "relayout_time_share.itl"} <= set(layer)
-    for name in NEW_METRICS:
-        assert per_layer[name]["workloads"] == [CELL]
+    for name in NEW_METRICS:       # PR 51's cell reads `moe_grouped_time_share` too
+        assert per_layer[name]["workloads"][0] == CELL and len(per_layer[name]["workloads"]) <= 2
     assert per_layer["kv_window_block_share"]["layer"] == "engine scheduler and KV"
     assert per_layer["moe_grouped_time_share"]["source"] == "device_trace"
     for name in layer:
