@@ -542,7 +542,7 @@ class InferenceEngine:
         self._step_ttfts: List[float] = []     # reset each step()
         self._step_tpots: List[float] = []
         self._step_spec = [0, 0]               # [proposed, accepted]
-        self._tok_window: List[float] = []     # token-emit timestamps
+        self._tok_window: "deque[float]" = deque()     # token-emit stamps, oldest first
         # (t, hits, misses) snapshots — fleet_state's RECENT hit-rate
         # window, the autoscaler's cache-cold signal.
         self._hit_snaps: "deque" = deque(maxlen=64)
@@ -1519,7 +1519,7 @@ class InferenceEngine:
         self._tick_slots()
         with flight.phase("engine.export_metrics", ph, "export_ns"):
             now = time.monotonic()
-            self._tok_window = [t for t in self._tok_window if now - t <= 10.0]
+            _expire_stamps(self._tok_window, now, 10.0)
             kv_stats = self.block_manager.stats()
             stats = {
                 "queue_depth": self.scheduler.queue_depth,
@@ -1830,3 +1830,13 @@ class InferenceEngine:
             now_ns = time.monotonic_ns()
             self._books["loop_ns"] += now_ns - t_ns
             t_ns = now_ns
+
+
+def _expire_stamps(window: "deque[float]", now: float, span: float) -> None:
+    """Drop the stamps more than `span` seconds before `now` from the old
+    end of a window that one thread appends to in time order: a call pays
+    for the stamps that expired since the last one, never for the window's
+    length. (At the END of the file: the compile cache's key carries the
+    line of every `def` and dispatch site above, ROADMAP S7.)"""
+    while window and now - window[0] > span:
+        window.popleft()

@@ -288,9 +288,10 @@ class _Flusher:
         from ..core import api
 
         global records_total
-        merged = {**metric._default_tags, **(tags or {})}
-        key = (metric._name, metric.kind,
-               tuple(sorted((str(k), str(v)) for k, v in merged.items())))
+        # The series of a record without per-call tags is the metric's own,
+        # made once (`_Metric._series`): nothing is merged or sorted a record.
+        key = (metric._series({**metric._default_tags, **tags}) if tags
+               else metric._default_series)
         # A record from a process WITHOUT a runtime is dropped here, never a
         # reason to boot one and never kept: an engine unit test's
         # serve_engine_tokens_total would ship into the cluster a later test
@@ -365,10 +366,17 @@ class _Metric:
         self._description = description
         self._tag_keys = tuple(tag_keys)
         self._default_tags: Dict[str, str] = {}
+        self._default_series = self._series(self._default_tags)
 
     def set_default_tags(self, tags: Dict[str, str]):
         self._default_tags = dict(tags)
+        self._default_series = self._series(self._default_tags)
         return self
+
+    def _series(self, tags: Dict[str, str]) -> _Key:
+        """The pending table's key for this metric under `tags`."""
+        return (self._name, self.kind,
+                tuple(sorted((str(k), str(v)) for k, v in tags.items())))
 
     def _fold(self, entry: dict, value: float):
         """Fold one record into the series' pending message (under the

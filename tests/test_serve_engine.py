@@ -9,6 +9,7 @@ iteration-level scheduling observable through the Serve data plane.
 
 import threading
 import time
+from collections import deque
 
 import pytest
 
@@ -2381,3 +2382,197 @@ def test_a_mixed_run_compiles_the_parents_programs_with_the_decode_kernel(monkey
     assert seen[True][:4] == seen[False][:4] and len(seen[True][0]) >= 3
     assert seen[True][1] == len(seen[True][0])      # one decode program a bucket
     assert (seen[False][4], seen[True][4]) == (False, True)
+
+
+# ------------------------------------------------- the step's export phase
+class _ScriptedClock:
+    """`time` as `engine.py` alone sees it, its monotonic clock set by the
+    test (every other name is the real module's)."""
+
+    def __init__(self, now: float):
+        self.now = now
+
+    def monotonic(self):
+        return self.now
+
+    def monotonic_ns(self):
+        return int(self.now * 1e9)
+
+    def __getattr__(self, name):
+        return getattr(time, name)
+
+
+class _CountedWindow(deque):
+    """A deque that counts what is done to it; walking it whole (what a
+    rebuild does) is counted too."""
+
+    appends = pops = walks = 0
+
+    def append(self, x):
+        self.appends += 1
+        super().append(x)
+
+    def popleft(self):
+        self.pops += 1
+        return super().popleft()
+
+    def __iter__(self):
+        self.walks += 1
+        return super().__iter__()
+
+
+class TestExportPhase:
+    """`export_ns` pays for what happened in the step, never for what the
+    replica did in the last ten seconds: the token window is kept, not
+    rebuilt, and a metric's series key is made once."""
+
+    def test_tokens_per_s_is_the_old_definition_on_a_scripted_clock(
+        self, tiny_engine_parts, monkeypatch
+    ):
+        from ray_tpu.serve.engine import engine as engine_module
+
+        clock = _ScriptedClock(1000.0)
+        monkeypatch.setattr(engine_module, "time", clock)
+        eng = _make_engine(*tiny_engine_parts)
+        assert eng.step()["tokens_per_s"] == 0.0        # the empty window
+        eng._tok_window.append(clock.now)               # one stamp: the floor
+        assert eng.step()["tokens_per_s"] == 1 / 1e-3
+        # 4,000 stamps spread unevenly over 25 s, read at several nows: the
+        # parent's two lines, written out, on a list of its own
+        stamps = sorted(1000.0 + 25.0 * ((i * 0.6180339887) % 1.0) ** 2
+                        for i in range(4000))
+        eng._tok_window.clear()
+        eng._tok_window.extend(stamps)
+        old, seen = list(stamps), set()
+        for now in (1025.0, 1025.0005, 1027.5, 1031.0, 1033.25, 1034.9, 1035.0,
+                    1040.0):
+            clock.now = now
+            old = [t for t in old if now - t <= 10.0]
+            want = len(old) / max(now - old[0], 1e-3) if old else 0.0
+            assert eng.step()["tokens_per_s"] == want, now
+            assert list(eng._tok_window) == old
+            seen.add(want)
+        # a replica that emits nothing for ten seconds drains to empty
+        assert want == 0.0 and not eng._tok_window and len(seen) >= 6
+
+    def test_a_step_touches_what_it_emitted_and_what_expired(
+        self, tiny_engine_parts, monkeypatch
+    ):
+        """50,000 stamps inside the window: a step that emits k tokens and
+        expires m stamps appends k, pops m and walks nothing; the window is
+        the same object after it."""
+        from ray_tpu.serve.engine import engine as engine_module
+
+        clock = _ScriptedClock(5000.0)
+        monkeypatch.setattr(engine_module, "time", clock)
+        eng = _make_engine(*tiny_engine_parts)
+        for i in range(3):
+            eng.submit([1 + i] * 6, max_new_tokens=8)
+        while eng.step()["step_tokens"] < 3:      # all three lanes decode
+            pass
+        live = [4990.5 + 9.0 * i / 50_000 for i in range(50_000)]
+        for m in (0, 7, 1300):
+            clock.now += 0.001
+            gone = [clock.now - 10.0 - 0.01 * (m - i) for i in range(m)]
+            window = eng._tok_window = _CountedWindow(gone + live)
+            before = len(window)
+            stats = eng.step()
+            k = stats["step_tokens"]
+            assert k == 3 and eng._tok_window is window
+            assert (window.appends, window.pops, window.walks) == (k, m, 0)
+            assert len(window) == before + k - m
+            assert stats["tokens_per_s"] == len(window) / (clock.now - live[0])
+            live = list(window)
+
+    @pytest.mark.parametrize("kind, method", [
+        ("gauge", "set"), ("counter", "inc"), ("histogram", "observe")])
+    def test_a_record_lands_in_the_series_the_parent_keyed(self, metric_sink, kind,
+                                                           method):
+        """A metric with constant default tags records into the pending
+        table under the key the parent's `record` computed a call (written
+        out here): the name, the kind, the merged tags as sorted pairs of
+        strings. A per-call `tags=` still makes a series of its own."""
+        from ray_tpu.util import metrics
+
+        tags = {"replica": "app#D#r0", "app": "app", "role": "mixed",
+                "deployment": "D", "shard": 3}
+        name = f"xp_{kind}"
+        m = getattr(metrics, kind.capitalize())(name, "help text").set_default_tags(tags)
+        write = getattr(m, method)
+        own = (name, kind, (("app", "app"), ("deployment", "D"),
+                            ("replica", "app#D#r0"), ("role", "mixed"),
+                            ("shard", "3")))
+        routed = (name, kind, (("app", "app"), ("deployment", "D"),
+                               ("replica", "app#D#r0"), ("role", "other"),
+                               ("route", "7"), ("shard", "3")))
+        pending = metrics._FLUSHER._pending
+        with metrics._FLUSHER._flush_lock:          # one interval
+            write(2.0)
+            entry = pending[own]
+            write(0.5)
+            write(4.0, tags={"route": 7, "role": "other"})
+            write(1.0)
+            assert pending[own] is entry and set(pending) >= {own, routed}
+            assert [k for k in pending if k[0] == name] == [own, routed]
+            # default tags set anew move the metric's series with them
+            m.set_default_tags({"replica": "r1"})
+            write(8.0)
+            assert (name, kind, (("replica", "r1"),)) in pending
+        metrics.flush()
+        (msg,) = metric_sink.series(name, **tags)
+        (other,) = metric_sink.series(name, **{**tags, "route": 7, "role": "other"})
+        (moved,) = metric_sink.series(name, replica="r1")
+        assert msg[2] == other[2] == moved[2] == kind
+        if kind == "gauge":         # its last value
+            assert (msg[3], other[3], moved[3]) == (1.0, 4.0, 8.0)
+        elif kind == "counter":     # its sum
+            assert (msg[3], other[3], moved[3]) == (3.5, 4.0, 8.0)
+        else:                       # every observation
+            assert (msg[5]["count"], msg[5]["sum"]) == (3, 3.5)
+            assert (other[5]["count"], moved[5]["sum"]) == (1, 8.0)
+        assert msg[5]["help"] == "help text"
+
+    def test_a_replicas_engine_exports_the_parents_series(
+        self, tiny_engine_parts, metric_sink
+    ):
+        """A scripted run under a replica's context: every series of the
+        engine arrives under the replica's four tags, the counters as the
+        run's totals, the histograms with every observation."""
+        from ray_tpu.serve import context
+        from ray_tpu.util import metrics
+
+        context._set_replica_context(
+            context.ReplicaContext("app", "LLM", "app#LLM#r0"))
+        try:
+            eng = _make_engine(*tiny_engine_parts)
+        finally:
+            context._set_replica_context(None)
+        with metrics._FLUSHER._flush_lock:          # one interval
+            rids = [eng.submit([1 + i] * 6, max_new_tokens=5) for i in range(3)]
+            steps = _drive(eng)
+        assert all(len(list(eng.stream(rid))) == 5 for rid in rids)
+        metrics.flush()
+        tags = {"app": "app", "deployment": "LLM", "replica": "app#LLM#r0",
+                "role": "mixed"}
+        sent = {m[1]: m for m in metric_sink.sent if m[1].startswith("serve_engine_")}
+        assert all(m[4] == tags for m in sent.values())
+        assert len(sent) == len([m for m in metric_sink.sent
+                                 if m[1].startswith("serve_engine_")])
+        assert {n: m[2] for n, m in sent.items()} == {
+            "serve_engine_queue_depth": "gauge",
+            "serve_engine_running_seqs": "gauge",
+            "serve_engine_kv_utilization": "gauge",
+            "serve_engine_tokens_per_s": "gauge",
+            "serve_engine_host_tier_bytes": "gauge",
+            "serve_engine_tokens_total": "counter",
+            "serve_engine_prefix_cache_misses_total": "counter",
+            "serve_engine_ttft_s": "histogram",
+            "serve_engine_tpot_s": "histogram",
+            "serve_engine_step_budget_tokens": "histogram",
+        }
+        assert sent["serve_engine_tokens_total"][3] == 15.0
+        assert sent["serve_engine_queue_depth"][3] == 0.0
+        assert sent["serve_engine_running_seqs"][3] == 0.0
+        assert sent["serve_engine_ttft_s"][5]["count"] == 3
+        assert sent["serve_engine_tpot_s"][5]["count"] == 3       # one a request
+        assert 0 < sent["serve_engine_step_budget_tokens"][5]["count"] <= steps
