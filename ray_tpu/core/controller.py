@@ -456,6 +456,11 @@ class Controller:
         # (reference: `python/ray/autoscaler/sdk` → GCS resource_request).
         self._explicit_demands: List[Dict[str, float]] = []
         self.timeline: List[dict] = []
+        # Objects the collector freed and their bytes (`_gc_loop`): counted,
+        # not narrated — a streamed item is an object, so an event a
+        # collection was an event a token. `state_summary` hands them back.
+        self.object_gc_collections = 0
+        self.object_gc_bytes = 0
         # Absolute index of timeline[0] — lets poll_events cursors survive
         # truncation (a cursor is "events seen so far", not a list index).
         self._timeline_base = 0
@@ -2191,9 +2196,9 @@ class Controller:
                 if now < obj.gc_at:
                     continue
                 if obj.status == "ready":
-                    size = obj.size
+                    self.object_gc_collections += 1
+                    self.object_gc_bytes += obj.size
                     self._free_object(hex_id)
-                    self._event("object_gc", object=hex_id, size=size)
                 elif not obj.expected:
                     # Zombie entry (late add after free) — drop the state.
                     self.objects.pop(hex_id, None)
@@ -5017,25 +5022,41 @@ class Controller:
                 pass
         return {"ok": True, "workers": n}
 
+    def _timeline_view(self) -> List[dict]:
+        """What `state_summary` (`ray_tpu.timeline()`, `ray-tpu flight`,
+        `/api/traces`) hands back of the ONE list: the newest 10,000 events
+        that are not spans and EVERY span the controller still holds, in
+        time order. Bounded apart, because the two grow apart: lifecycle
+        events with tasks and actors, spans with steps and requests, and a
+        serving window's step records must not be pushed out by the
+        narration of the tasks that carried its requests. What the list
+        itself lost is marked there (`_trim_timeline`)."""
+        spans: List[dict] = []
+        rest: List[dict] = []
+        for ev in self.timeline:
+            (spans if ev.get("event") == "span" else rest).append(ev)
+        out = rest[-10000:] + spans
+        out.sort(key=lambda ev: ev.get("ts", 0.0))     # stable: ties keep arrival order
+        return out
+
     async def h_state_summary(self, conn, meta, msg):
-        if msg.get("counts_only"):  # cheap status — no timeline payload
-            return {
-                "num_workers": len([w for w in self.workers.values() if w.state != DEAD]),
-                "objects": len(self.objects),
-                "store_bytes": self.store_bytes_used,
-                "pending_tasks": len(self.ready_queue) + len(self.waiting_tasks),
-                "running_tasks": len(self.running),
-            }
-        return {
-            "timeline": list(self.timeline[-10000:]),
+        counts = {
             "num_workers": len([w for w in self.workers.values() if w.state != DEAD]),
             "objects": len(self.objects),
             "store_bytes": self.store_bytes_used,
+            "object_gc_collections": self.object_gc_collections,
+            "object_gc_bytes": self.object_gc_bytes,
+            "pending_tasks": len(self.ready_queue) + len(self.waiting_tasks),
+            "running_tasks": len(self.running),
+        }
+        if msg.get("counts_only"):  # cheap status — no timeline payload
+            return counts
+        return {
+            "timeline": self._timeline_view(),
+            **counts,
             "actors": {
                 h: {"state": a.state, "name": a.name} for h, a in self.actors.items()
             },
-            "pending_tasks": len(self.ready_queue) + len(self.waiting_tasks),
-            "running_tasks": len(self.running),
         }
 
     # ------------------------------------------------- state API (listing)
@@ -5421,13 +5442,24 @@ class Controller:
         finally:
             writer.close()
 
+    _TIMELINE_CAP = 100_000
+    _TIMELINE_TRIM = 50_000
+
     def _trim_timeline(self):
         """Cap + cursor-base bookkeeping MUST move together: dropping
         entries without advancing _timeline_base would silently shift
         every poll_events cursor by the truncation amount."""
-        if len(self.timeline) > 100_000:
-            del self.timeline[:50_000]
-            self._timeline_base += 50_000
+        if len(self.timeline) > self._TIMELINE_CAP:
+            n = self._TIMELINE_TRIM
+            spans = sum(ev.get("event") == "span"
+                        for ev in itertools.islice(self.timeline, n))
+            del self.timeline[:n]
+            self._timeline_base += n
+            # Loss is never silent: ONE marker a trim (the pattern of
+            # `flight_spans_dropped` and `actor_events_dropped`), at the tail,
+            # so every cursor taken before the trim still reaches it.
+            self.timeline.append({"ts": time.time(), "event": "timeline_trimmed",
+                                  "n": n, "spans": spans})
 
     # High-volume lifecycle kinds subject to the storm cap (the task-events
     # 4096-cap pattern applied to the ACTOR lifecycle): a 10k-actor wave

@@ -129,13 +129,12 @@ class DashboardServer:
             elif name == "traces":
                 from ..util import tracing
 
-                # Same bounded window as state_summary (what the CLI and
-                # api.timeline() see): keeps the two surfaces consistent and
-                # caps the forest assembly this does on the controller's
-                # event loop (the full timeline can hold 100k events).
+                # Same bounded view as state_summary (what the CLI and
+                # api.timeline() see: the newest 10,000 lifecycle events and
+                # every span held): keeps the two surfaces consistent.
                 # ONE export path shared with `ray-tpu trace`
                 # (tracing.trace_payload): CLI and HTTP cannot drift.
-                events = list(c.timeline[-10000:])
+                events = c._timeline_view()
                 trace_id = query.get("trace_id")
                 if trace_id:
                     t = tracing.trace_payload(events, trace_id=trace_id)["trace"]
@@ -160,7 +159,7 @@ class DashboardServer:
                 await c.h_flight_pull(None, {}, {})
                 await asyncio.sleep(0.25)
                 data = flight.flight_payload(
-                    list(c.timeline[-10000:]), trace_id=query.get("trace_id")
+                    c._timeline_view(), trace_id=query.get("trace_id")
                 )
             elif name == "logs":
                 wid = query.get("worker_id", "")

@@ -73,7 +73,9 @@ def cmd_status(backend, info, args):
         f"Tasks: {summary['running_tasks']} running, {summary['pending_tasks']} pending"
     )
     print(f"Workers: {summary['num_workers']}  Objects: {summary['objects']} "
-          f"({summary['store_bytes'] / 1e6:.1f} MB in store)")
+          f"({summary['store_bytes'] / 1e6:.1f} MB in store; "
+          f"{summary.get('object_gc_collections', 0)} collected, "
+          f"{summary.get('object_gc_bytes', 0) / 1e6:.1f} MB)")
 
 
 def cmd_list(backend, info, args):

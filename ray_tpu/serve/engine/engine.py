@@ -78,6 +78,9 @@ _STEP_BOOKS = (
     "steps", "steps_chunk", "steps_decode", "steps_decode_only", "steps_slow",
     "step_ns", "step_chunk_ns", "step_decode_only_ns", "host_ns", "slow_ns",
     "loop_ns", "waited_ns", *flight.SERVE_STEP_PHASES,
+    # The span less its six phases: what a step spends between them, which
+    # no phase books.
+    "between_ns",
     "decode_lanes", "decode_bucket_lanes", "decode_lanes_beside_chunk",
     "prefill_tokens", "prefill_tokens_padded",
     # A model with state a sequence (`KVLayout.state`), else 0: tokens its
@@ -88,8 +91,15 @@ _STEP_BOOKS = (
     "ssm_tokens_scanned", "ssm_tokens_masked", "ssm_state_bytes",
     "state_slot_held_ns", "state_slot_cap_ns",
     # The pool over TIME (`_tick_slots`): block-nanoseconds live sequences
-    # held, and of them those in window groups' tables.
-    "kv_block_held_ns", "kv_window_block_ns",
+    # held, of them those in window groups' tables, and the pool's blocks x
+    # the nanoseconds (held over it: the step record's `kv_util` over time).
+    "kv_block_held_ns", "kv_window_block_ns", "kv_block_cap_ns",
+    # What a decode program hands back beside its ids, which rides the step
+    # record as a float (`_decode_facts`): the steps that read it, and its
+    # sum in thousandths (an expert model's `experts_touched`, a looped
+    # model's `exit_step_mean`) or millionths (`expert_load_max`).
+    "moe_steps_read", "moe_experts_touched_milli", "moe_load_max_ppm",
+    "ut_steps_read", "ut_exit_step_milli",
     # Query heads x keys the dispatched programs' attention covered, summed
     # over the layers, each under its own window and head count
     # (`ops.paged_attention.paged_attn_cover`), and of them the window layers'.
@@ -1165,6 +1175,7 @@ class InferenceEngine:
         kv, b = self.block_manager, self._books
         b["kv_block_held_ns"] += dt * kv.blocks_held
         b["kv_window_block_ns"] += dt * kv.window_blocks_held
+        b["kv_block_cap_ns"] += dt * (kv.num_blocks - 1)    # block 0 is the null block
         if self._stateful:
             b["state_slot_held_ns"] += dt * kv.state_slots_held
             b["state_slot_cap_ns"] += dt * kv.state_slots
@@ -1290,10 +1301,14 @@ class InferenceEngine:
         """What a decode program handed back beside its ids (the step's
         routing, the exit gate), as attributes of its step's record."""
         attrs = {}
+        b = self._books
         if self.cfg.mlp_type == "moe":
             self._step_moe = facts.pop(0)
             attrs["experts_touched"] = float(self._step_moe[0])
             attrs["expert_load_max"] = float(self._step_moe[1])
+            b["moe_steps_read"] += 1
+            b["moe_experts_touched_milli"] += round(1e3 * attrs["experts_touched"])
+            b["moe_load_max_ppm"] += round(1e6 * attrs["expert_load_max"])
             if self.cfg.moe_held:   # means over the expert layers -> their sums
                 layers = self.cfg.moe_layers
                 held, total, empty = (
@@ -1310,6 +1325,8 @@ class InferenceEngine:
             attrs["ut_passes"] = len(pdf)
             attrs["exit_step_mean"] = sum(t * p for t, p in enumerate(pdf, 1))
             attrs["exit_cdf_early"] = sum(pdf[:-1])
+            b["ut_steps_read"] += 1
+            b["ut_exit_step_milli"] += round(1e3 * attrs["exit_step_mean"])
         return attrs
 
     def _run_verify(self, out: SchedulerOutput):
@@ -1596,6 +1613,8 @@ class InferenceEngine:
         b["host_ns"] += host
         for key, ns in times.items():
             b[key] += ns
+        b["between_ns"] += span - sum(
+            times[k] for k in flight.SERVE_STEP_PHASES[:-1])
         b["prefill_tokens"] += self._step_chunks[0]
         b["prefill_tokens_padded"] += self._step_chunks[1]
         if lanes:
@@ -1634,6 +1653,7 @@ class InferenceEngine:
             tpots = list(self._tpots)
         extra = ({"ttft_recent": ttfts, "tpot_recent": tpots}
                  if include_raw else {})
+        ring = flight.recorder()
         return {
             **extra,
             **self._device,
@@ -1673,6 +1693,9 @@ class InferenceEngine:
             "state_slots_claimed": self.block_manager.states_claimed,
             "state_slots_released": self.block_manager.states_released,
             **self._books,
+            # This PROCESS's ring (util/flight.py), since it started.
+            "flight_spans_recorded": ring.recorded_total,
+            "flight_spans_dropped": ring.dropped_total,
             **dict(zip(("stream_tokens", "stream_wake_ns", "stream_send_ns",
                         "stream_behind"), self._delivery.read())),
             "gc_ns": _GC.ns,

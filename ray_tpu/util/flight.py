@@ -17,7 +17,20 @@ request-scale spans (proxy, replica, engine requests) take the ring too:
   ``task_events_dropped`` and the controller's ``actor_events_dropped``:
   overflow drops the NEWEST span and one ``flight_spans_dropped`` marker
   rides the next drain. Death-kind spans (``kind`` in ``death/abort``)
-  are exempt from the cap — a storm must not evict the evidence.
+  are exempt from the cap — a storm must not evict the evidence. Two
+  totals since the process started, never reset, say how whole the ring
+  has been: ``recorded_total`` (every span ``record`` was handed) and
+  ``dropped_total`` (``engine_stats()`` ``flight_spans_recorded`` /
+  ``flight_spans_dropped`` in a serving replica).
+* What the controller keeps of them: ONE timeline list of at most 100,000
+  events of every kind; ``state_summary`` (``ray_tpu.timeline()``,
+  ``ray-tpu flight``, ``/api/flight``) hands back EVERY span in it and,
+  bounded apart, the newest 10,000 other events, in time order. When the
+  list overflows it drops its oldest 50,000 and leaves ONE
+  ``timeline_trimmed`` event (``n``, ``spans``); collected objects are
+  counted, not narrated. So ``ray-tpu flight`` after an untraced serving
+  run sees every ``engine.step`` and ``engine.stall`` of some four windows
+  of the busiest cell, and a timeline without that marker lost nothing.
 * Spans leave the process three ways: a periodic flusher thread ships
   drained batches over the existing task_events channel
   (``tracing.record_events``); executing workers piggyback drained spans
@@ -80,6 +93,12 @@ class FlightRecorder:
         self._lock = threading.Lock()
         self._buf: List[Dict[str, Any]] = []
         self._dropped = 0
+        # Since the process started, and never reset (`drain` resets only
+        # `_dropped`, the marker's count): every span `record` was handed,
+        # and of them those a full ring refused (with what `requeue` could
+        # not put back). `InferenceEngine.stats` hands both on.
+        self.recorded_total = 0
+        self.dropped_total = 0
         # monotonic→wall anchor, taken once; clock_offset re-bases onto
         # the controller's clock (RTT-midpoint handshake at registration).
         self._anchor_wall = time.time()
@@ -150,8 +169,10 @@ class FlightRecorder:
             "args": args,
         }
         with self._lock:
+            self.recorded_total += 1
             if len(self._buf) >= self.cap and kind not in DEATH_KINDS:
                 self._dropped += 1
+                self.dropped_total += 1
                 return
             self._buf.append(ev)
 
@@ -208,6 +229,7 @@ class FlightRecorder:
             room = self.cap - len(self._buf)
             keep = events[:max(room, 0)]
             self._dropped += len(events) - len(keep)
+            self.dropped_total += len(events) - len(keep)
             self._buf = keep + self._buf
 
 

@@ -9,7 +9,9 @@ itself: the `engine.step` phase attrs, the engine's request spans and
 `serve.handle`, reduced by `ray_tpu.util.flight.serve_report` over the
 spans of the measured window. The last line of stdout is one JSON object:
 the cell's end-to-end metrics, its existing per-layer metrics, `serve`
-(the report) and, with a profiler session, `idle_gaps` and
+(the report), `timeline` (what `ray_tpu.timeline()` handed back after the
+window: the seconds it took, its events by kind, the step records inside
+the window, the controller's resident memory) and, with a profiler session, `idle_gaps` and
 `idle_named_share`: the share of the device's idle time in the traced
 window that is named by the engine's own `engine.*` annotations.
 
@@ -32,6 +34,8 @@ numbers per-layer metrics."""
 from __future__ import annotations
 
 import argparse
+import collections
+import glob
 import json
 import math
 import os
@@ -81,6 +85,33 @@ def replica_class(profile: str):
             "frames": me.PhaseReplicaFrames}[profile]
 
 
+def _census(events, took_s: float, w0: float, seconds: float) -> dict:
+    """What `ray_tpu.timeline()` handed back after the window and what it
+    cost: the seconds the call took, its events by kind (spans by name),
+    how many `engine.step` records start inside the window (to hold against
+    the books' `steps`) and the controller process's resident memory."""
+    kinds = collections.Counter(
+        f"span:{ev.get('name')}" if ev.get("event") == "span" else ev.get("event")
+        for ev in events)
+    steps = [ev["ts"] for ev in events if ev.get("event") == "span"
+             and ev.get("name") == "engine.step"]
+    rss = None
+    for path in glob.glob("/proc/[0-9]*/cmdline"):
+        try:
+            with open(path, "rb") as f:
+                if b"ray_tpu.core.controller_main" not in f.read():
+                    continue
+            with open(path.replace("cmdline", "status")) as f:
+                rss = max(rss or 0, next(
+                    int(l.split()[1]) * 1024 for l in f if l.startswith("VmRSS")))
+        except (OSError, StopIteration):
+            continue
+    return {"seconds": took_s, "events": len(events),
+            "kinds": dict(kinds.most_common()),
+            "steps_in_window": sum(w0 <= ts <= w0 + seconds for ts in steps),
+            "controller_rss_bytes": rss}
+
+
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--workload", required=True)
@@ -117,7 +148,10 @@ def main(argv=None) -> int:
         flight.flush()                       # this process's serve.handle spans
         time.sleep(1.0)
         w0 = t0_wall + obs["phases"]["setup_s"]
-        spans = [ev for ev in ray_tpu.timeline()
+        t = time.perf_counter()
+        events = ray_tpu.timeline()
+        census = _census(events, time.perf_counter() - t, w0, seconds)
+        spans = [ev for ev in events
                  if ev.get("event") == "span" and w0 <= ev.get("ts", 0) <= w0 + seconds
                  and ev.get("name", "").startswith(("engine.", "serve."))]
     except BaseException:
@@ -139,7 +173,8 @@ def main(argv=None) -> int:
             "device": obs["device"], "attempted": obs["attempted"],
             "failed": obs["failed"], "checks": obs["checks"],
             "metrics": metrics, "counters": obs["counters"],
-            "spans": len(spans), "serve": flight.serve_report(spans)}
+            "spans": len(spans), "serve": flight.serve_report(spans),
+            "timeline": census}
     tr = obs.get("trace")
     if tr:
         gaps = tr["breakdown"]["idle_gaps"]

@@ -44,6 +44,12 @@ JOINED = ("moe_held_assign_share", "moe_grouped_time_share",
           "setup_deploy_s", "setup_warm_s")
 
 
+# ... and, since PR 53, the gauges' integer books, which need no span to arrive
+BOOKS_53 = ("kv_util_mean_books.itl", "decode_lanes_mean_books",
+            "moe_experts_touched_mean_books", "moe_expert_load_max_books",
+            "step_between_ms", "flight_drop_share")
+
+
 @pytest.fixture(scope="module")
 def obs():
     os.makedirs(harness.OUT, exist_ok=True)
@@ -198,11 +204,13 @@ def test_the_cell_and_its_files_are_in_the_benchmark():
     e2e = harness.cell_metrics(bench, CELL, "end_to_end")
     assert set(e2e) == {"setup_s", "itl_p90_ms"}
     layer = harness.cell_metrics(bench, CELL, "per_layer")
-    assert set(NEW_METRICS) | set(SPLIT) | set(JOINED) | {"setup_attach_s"} == set(layer)
+    assert set(NEW_METRICS) | set(SPLIT) | set(JOINED) | set(BOOKS_53) | {"setup_attach_s"} == set(layer)
     per_layer = {m["name"]: m for m in bench["per_layer"]}
     assert all(per_layer[name]["moves"] in e2e for name in layer)
     names = [m["name"] for m in bench["per_layer"]]
-    assert names[-len(NEW_METRICS) - len(SPLIT):] == list(NEW_METRICS) + list(SPLIT)   # appended
+    at = names.index(next(iter(NEW_METRICS)))               # appended, in PR 51's order
+    assert names[at:at + len(NEW_METRICS) + len(SPLIT)] == list(NEW_METRICS) + list(SPLIT)
+    assert set(BOOKS_53) <= set(names[at + len(NEW_METRICS) + len(SPLIT):])    # PR 53's behind them
     for name in NEW_METRICS:
         assert per_layer[name]["workloads"] == [CELL]
         assert per_layer[name]["layer"] == "paged model path"

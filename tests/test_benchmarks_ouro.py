@@ -49,6 +49,27 @@ def test_rehearsal_is_correct_and_reads_both_new_metrics(obs):
         assert readers.read(name, obs) > 0, name
 
 
+def test_rehearsal_keeps_the_exit_gates_integer_books(obs):
+    """ISSUE 53: the looped model's `exit_step_mean` rides the step record
+    as a float; its integer books are among the rehearsal's counts and
+    their ratio reads what the span twin reads."""
+    c = obs["counters"]
+    new = ("kv_block_cap_ns", "ut_steps_read", "ut_exit_step_milli",
+           "between_ns", "flight_spans_recorded")
+    assert all(type(c[k]) is int and c[k] > 0 for k in new), {k: c.get(k) for k in new}
+    assert c["moe_steps_read"] == c["moe_experts_touched_milli"] == c["moe_load_max_ppm"] == 0
+    assert abs(c["ut_steps_read"] - c["steps_decode"]) <= 2     # a step in flight at an edge
+    assert c["flight_spans_dropped"] == 0
+    # the pool over TIME: the books' window runs on past the spans' 4 s, to the
+    # runner's `bench_window_end` call
+    for books, twin, rel in (("ut_exit_step_mean_books", "ut_exit_step_mean", 0.1),
+                             ("decode_lanes_mean_books", "decode_lanes_mean", 0.1),
+                             ("kv_util_mean_books.itl", "kv_util_mean.itl", 0.3)):
+        assert readers.read(books, obs) == pytest.approx(readers.read(twin, obs), rel=rel), books
+    assert readers.read("flight_drop_share", obs) == 0.0
+    assert 0.0 < readers.read("step_between_ms", obs) < readers.read("engine_step_ms_books", obs)
+
+
 def test_weight_bytes_and_pool_bytes_equal_the_hand_count(obs):
     m = obs["facts"]["model"]
     # a layer: q, k, v, o of 64x64 and gate, up, down of 64x96; 3 layers read

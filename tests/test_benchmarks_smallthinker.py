@@ -45,6 +45,36 @@ def test_rehearsal_is_correct_and_counts_what_the_layer_did(obs):
         assert readers.read(name, obs) > 0, name
 
 
+def test_rehearsal_keeps_the_gauges_integer_books(obs):
+    """ISSUE 53: what the expert model's step record carries as floats has
+    integer books among the rehearsal's counts, and each new metric reads
+    what its span twin reads over the same window (the two windows differ
+    by the steps at their edges)."""
+    c = obs["counters"]
+    new = ("kv_block_cap_ns", "moe_steps_read", "moe_experts_touched_milli",
+           "moe_load_max_ppm", "between_ns", "flight_spans_recorded")
+    assert all(type(c[k]) is int and c[k] > 0 for k in new), {k: c.get(k) for k in new}
+    assert c["ut_steps_read"] == c["ut_exit_step_milli"] == c["flight_spans_dropped"] == 0
+    assert abs(c["moe_steps_read"] - c["steps_decode"]) <= 2    # a step in flight at an edge
+    # exact where the engine is stepped by hand (`test_serve_engine.py`); here
+    # the window's two readings come from another thread than the one that books
+    assert c["between_ns"] + sum(c[k] for k in (
+        "sched_ns", "side_ns", "build_ns", "dispatch_ns", "fetch_ns",
+        "sample_ns")) == pytest.approx(c["step_ns"], rel=1e-3)
+    # the pool over TIME: the books' window runs on past the spans' 4 s, to the
+    # runner's `bench_window_end` call, and nothing arrives in that part
+    for books, twin, rel in (
+            ("moe_experts_touched_mean_books", "moe_experts_touched_mean", 0.1),
+            ("moe_expert_load_max_books", "moe_expert_load_max", 0.1),
+            ("decode_lanes_mean_books", "decode_lanes_mean", 0.1),
+            ("kv_util_mean_books", "kv_util_mean", 0.3)):
+        assert readers.read(books, obs) == pytest.approx(readers.read(twin, obs), rel=rel), books
+    assert readers.read("flight_drop_share", obs) == 0.0
+    assert 0.0 < readers.read("step_between_ms", obs) < readers.read("engine_step_ms_books", obs)
+    steps = [ev for ev in obs["spans"] if ev["name"] == "engine.step"]
+    assert len(steps) >= 0.9 * c["steps"]       # the window's records all came back
+
+
 def test_weight_bytes_and_pool_bytes_equal_the_hand_count(obs):
     m = obs["facts"]["model"]
     # a layer: q 64x64, k and v 64x32 each, o 64x64; router 64x8; 2 of the 8

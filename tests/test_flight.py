@@ -100,6 +100,30 @@ def test_requeue_respects_cap_and_counts_overflow():
     assert rec.dropped == 3
 
 
+def test_cumulative_counts_survive_drain_and_requeue():
+    """Beside the per-drain `dropped` (the marker's count), the recorder
+    keeps two totals since the process started: every span `record` was
+    handed, and those a full ring refused or `requeue` could not put back.
+    Neither `drain` nor `requeue` resets them, and a requeued span is not
+    recorded twice."""
+    rec = FlightRecorder(cap=4, component="unit")
+    t = flight.now_ns()
+    for i in range(6):
+        rec.record(f"s{i}", t, t, lane="test")
+    assert (rec.recorded_total, rec.dropped_total, rec.dropped) == (6, 2, 2)
+    out = rec.drain()
+    assert len(out) == 5 and rec.dropped == 0               # 4 spans + the marker
+    assert (rec.recorded_total, rec.dropped_total) == (6, 2)
+    rec.record("live", t, t, lane="test")
+    rec.requeue(out[:4])                                    # room for 3 of the 4
+    assert len(rec) == 4 and rec.dropped == 1
+    assert (rec.recorded_total, rec.dropped_total) == (7, 3)
+    rec.record("late", t, t, lane="test", kind="death")     # exempt from the cap
+    assert (rec.recorded_total, rec.dropped_total) == (8, 3)
+    rec.drain()
+    assert rec.drain() == [] and (rec.recorded_total, rec.dropped_total) == (8, 3)
+
+
 def test_clock_offset_inside_its_own_uncertainty_is_none():
     """An offset smaller than half the round trip that measured it is not
     evidence of skew: processes of one host keep the host's one clock."""
@@ -371,7 +395,14 @@ _BOOKS_OBS = {"counters": {
     "stream_behind": 50, "sched_ns": 100_000_000, "side_ns": 50_000_000,
     "build_ns": 1_500_000_000, "dispatch_ns": 900_000_000,
     "fetch_ns": 17_000_000_000, "sample_ns": 450_000_000,
-    "export_ns": 2_000_000_000, "steps_slow": 3, "gc_collections": 7}}
+    "export_ns": 2_000_000_000, "steps_slow": 3, "gc_collections": 7,
+    # ISSUE 53: the gauges' integers, the two holes of the span, the ring
+    "kv_block_held_ns": 9_000_000_000_000, "kv_block_cap_ns": 45_000_000_000_000,
+    "moe_steps_read": 800, "moe_experts_touched_milli": 20_200_000,
+    "moe_load_max_ppm": 100_000_000, "ut_steps_read": 800,
+    "ut_exit_step_milli": 2_000_000, "between_ns": 400_000_000,
+    "flight_spans_recorded": 4000,
+    "flight_spans_dropped": 10}}
 
 
 @pytest.mark.parametrize("name, want", [
@@ -388,6 +419,12 @@ _BOOKS_OBS = {"counters": {
     ("step_dispatch_ms", 0.9), ("step_fetch_ms_books", 17.0),
     ("step_sample_ms", 0.45), ("step_export_ms_books", 2.0),
     ("stall_steps", 3.0), ("gc_collections", 7.0),
+    ("kv_util_mean_books", 20.0), ("kv_util_mean_books.itl", 20.0),
+    ("decode_lanes_mean_books", 3.0), ("decode_lanes_mean_books.sat", 3.0),
+    ("moe_experts_touched_mean_books", 25.25), ("moe_expert_load_max_books", 12.5),
+    ("ut_exit_step_mean_books", 2.5), ("step_between_ms", 0.4),
+    ("step_between_ms.sat", 0.4), ("flight_drop_share", 0.25),
+    ("flight_drop_share.sat", 0.25),
 ])
 def test_metric_files_read_the_books(name, want):
     """Every per-layer metric of the books is a `BENCHMARK.json` entry with
@@ -565,6 +602,11 @@ class _StubController:
     """Just enough controller for DashboardServer._route: a timeline plus
     the flight_pull handler the /api/flight endpoint awaits."""
 
+    from ray_tpu.core.controller import Controller
+
+    _timeline_view = Controller._timeline_view   # what both surfaces export
+    del Controller
+
     def __init__(self, timeline):
         self.timeline = list(timeline)
         self.pulls = 0
@@ -598,7 +640,7 @@ def test_cli_and_dashboard_flight_exports_identical():
     c = _StubController(events)
     got = _route_json(c, "/api/flight", {})
     got.pop("ts")  # the HTTP envelope's scrape stamp
-    want = flight.flight_payload(events)  # == what cmd_flight prints/writes
+    want = flight.flight_payload(c._timeline_view())  # == what cmd_flight prints/writes
     assert c.pulls == 1  # the endpoint poked the workers first
     assert json.dumps(got, sort_keys=True, default=str) == json.dumps(
         want, sort_keys=True, default=str)
@@ -606,7 +648,7 @@ def test_cli_and_dashboard_flight_exports_identical():
     # And restricted to one request id, still identical.
     got = _route_json(c, "/api/flight", {"trace_id": "req-1"})
     got.pop("ts")
-    want = flight.flight_payload(events, trace_id="req-1")
+    want = flight.flight_payload(c._timeline_view(), trace_id="req-1")
     assert json.dumps(got, sort_keys=True, default=str) == json.dumps(
         want, sort_keys=True, default=str)
 
@@ -629,6 +671,89 @@ def test_cli_and_dashboard_trace_exports_identical():
     want = tracing.trace_payload(events, limit=50)
     assert json.dumps(got, sort_keys=True, default=str) == json.dumps(
         want, sort_keys=True, default=str)
+
+
+# --------------------------------------- what the controller keeps and hands on
+@pytest.fixture
+def bare_controller(tmp_path, monkeypatch):
+    """A Controller with no socket and no loop: its timeline, the handlers
+    that fill it and the ones that read it."""
+    monkeypatch.setenv("RAY_TPU_CONTROLLER_SHARD_THREADS", "0")
+    from ray_tpu.core import config as rt_config
+
+    rt_config._reset_cache_for_tests()
+    from ray_tpu.core.controller import Controller
+
+    return Controller(num_cpus=1, resources={}, session_dir=str(tmp_path / "sess"),
+                      object_store_memory=1 << 20, standalone=True)
+
+
+def _ask(handler, msg):
+    return asyncio.new_event_loop().run_until_complete(handler(None, {}, msg))
+
+
+def test_state_summary_bounds_spans_and_lifecycle_events_apart(bare_controller):
+    """12,000 spans and 12,000 lifecycle events arrive as `task_events`
+    batches, interleaved: EVERY span comes back (a serving window's step
+    records are not pushed out by the narration of its requests' tasks), at
+    most 10,000 of the others (the newest), all in time order, and the
+    cheap form carries no timeline."""
+    c = bare_controller
+    t0 = 1000.0
+    for b in range(120):
+        batch = []
+        for i in range(100):
+            k = b * 100 + i
+            # a span is stamped with its START and shipped later: out of order
+            batch.append(_span("engine.step", t0 + k * 0.01 - 0.3, 0.005,
+                               lane="serve/engine", seq=k))
+            batch.append({"ts": t0 + k * 0.01, "event": "task_span",
+                          "task": f"t{k}", "seq": k})
+        _ask(c.h_task_events, {"events": batch})
+    got = _ask(c.h_state_summary, {})["timeline"]
+    spans = [e for e in got if e["event"] == "span"]
+    rest = [e for e in got if e["event"] != "span"]
+    assert [e["args"]["seq"] for e in spans] == list(range(12_000))
+    assert [e["seq"] for e in rest] == list(range(2_000, 12_000))
+    assert [e["ts"] for e in got] == sorted(e["ts"] for e in got)
+    assert len(c.timeline) == 24_000 and c._timeline_base == 0     # ONE list, untrimmed
+    cheap = _ask(c.h_state_summary, {"counts_only": True})
+    assert "timeline" not in cheap and cheap["object_gc_collections"] == 0
+    assert cheap["object_gc_bytes"] == 0
+
+
+def test_a_trim_leaves_one_marker_and_cursors_still_hold(bare_controller, monkeypatch):
+    """The list's own bound: a trim drops the oldest half-cap and says so
+    ONCE (`timeline_trimmed`: how many, how many of them spans), and a
+    `poll_events` cursor taken before the trim still yields every event
+    that came after it."""
+    c = bare_controller
+    monkeypatch.setattr(type(c), "_TIMELINE_CAP", 1_000)
+    monkeypatch.setattr(type(c), "_TIMELINE_TRIM", 500)
+    first = [(_span("engine.step", 10.0 + i, 0.1, lane="serve/engine") if i % 5 == 0
+              else {"ts": 10.0 + i, "event": "task_span", "task": f"t{i}"})
+             for i in range(900)]
+    _ask(c.h_task_events, {"events": first})
+    cursor = _ask(c.h_poll_events, {"cursor": -1})["cursor"]
+    assert cursor == 900 and not any(
+        e["event"] == "timeline_trimmed" for e in c.timeline)
+    later = [{"ts": 2000.0 + i, "event": "actor_death", "actor": f"a{i}"}
+             for i in range(150)]
+    _ask(c.h_task_events, {"events": later})        # 1,050 > the cap: one trim
+    markers = [e for e in c.timeline if e["event"] == "timeline_trimmed"]
+    assert len(markers) == 1 and c._timeline_base == 500
+    assert (markers[0]["n"], markers[0]["spans"]) == (500, 100)
+    assert len(c.timeline) == 900 - 500 + 150 + 1
+    polled = _ask(c.h_poll_events, {"cursor": cursor, "kinds": ["actor_death"]})
+    assert [e["actor"] for e in polled["events"]] == [f"a{i}" for i in range(150)]
+    assert polled["cursor"] == c._timeline_base + len(c.timeline)
+    # a cursor from before the trimmed part clamps forward to what is left
+    old = _ask(c.h_poll_events, {"cursor": 100, "limit": 10_000})
+    assert len(old["events"]) == len(c.timeline)
+    # the view reports the loss too: the marker is a lifecycle event
+    view = _ask(c.h_state_summary, {})["timeline"]
+    assert sum(e["event"] == "timeline_trimmed" for e in view) == 1
+    assert sum(e["event"] == "span" for e in view) == 180 - 100
 
 
 # ------------------------------------------------------------ shipping e2e

@@ -306,6 +306,46 @@ def test_cli_status_and_lists(cluster_rt):
     assert "flight spans:" in r.stdout
 
 
+def test_streamed_items_are_counted_not_narrated_and_spans_stay(cluster_rt):
+    """A streamed call's items are objects, collected once their refs are
+    gone: the controller COUNTS the collections (`state_summary`, both
+    forms) and writes no `object_gc` event, so 300 streamed items leave the
+    50 spans recorded before them where they were."""
+    from ray_tpu.util import flight
+
+    backend = cluster_rt
+    t = flight.now_ns()
+    for i in range(50):
+        flight.record("probe.marker", t + i, t + i + 1000, lane="test", seq=i)
+    assert flight.flush() >= 50
+    before = backend._request({"type": "state_summary", "counts_only": True})
+
+    @ray_tpu.remote
+    class Producer:
+        def gen(self, n):
+            for i in range(n):
+                yield bytes(64) + i.to_bytes(4, "big")
+
+    p = Producer.remote()
+    items = [ray_tpu.get(r) for r in p.gen.options(num_returns="streaming").remote(300)]
+    assert len(items) == 300 and len(set(items)) == 300
+    deadline = time.monotonic() + 30.0      # the collector's grace, then its sweep
+    while time.monotonic() < deadline:
+        now = backend._request({"type": "state_summary", "counts_only": True})
+        if now["object_gc_collections"] - before["object_gc_collections"] >= 300:
+            break
+        time.sleep(0.2)
+    full = backend.state_summary()
+    assert full["object_gc_collections"] - before["object_gc_collections"] >= 300
+    assert full["object_gc_bytes"] - before["object_gc_bytes"] >= 300 * 68
+    events = ray_tpu.timeline()
+    assert not [e for e in events if e.get("event") == "object_gc"]
+    markers = [e for e in events if e.get("name") == "probe.marker"]
+    assert sorted(e["args"]["seq"] for e in markers) == list(range(50))
+    r = _run_cli("status")
+    assert r.returncode == 0 and "collected" in r.stdout, r.stdout + r.stderr
+
+
 def test_cli_timeline_writes_chrome_trace(cluster_rt, tmp_path):
     @ray_tpu.remote
     def noop():

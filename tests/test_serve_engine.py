@@ -1938,6 +1938,87 @@ class TestEngineBooks:
         elif recorder == "cap64":
             assert all(sums[k] < st[k] for k in ("steps", "decode_lanes", "build_ns"))
 
+    @pytest.mark.parametrize("kind", ["dense", "experts", "looped"])
+    def test_gauge_books_equal_the_means_over_the_step_records(self, ring, kind):
+        """What rides the step record as a FLOAT has integer books (ISSUE 53):
+        on a tiny dense model, an expert model and a looped one, stepped by
+        hand with the recorder on, each ratio of the new books equals the
+        same mean taken over the `engine.step` records: `experts_touched`,
+        `expert_load_max` and `exit_step_mean` over the steps that read
+        them, `kv_util` over TIME (as `_tick_slots` weights it exactly, as
+        `readers.span_time_mean` weights it nearly: the two differ by
+        which end of a step its own duration is booked at). And the span is
+        whole: `between_ns` + the six phases = `step_ns`, to the
+        nanosecond."""
+        import jax
+        from benchmarks import readers
+        from ray_tpu.models.gpt import init_params
+        from ray_tpu.util import flight
+
+        cfg = _tiny_cfg(**{
+            "dense": {},
+            "experts": dict(mlp_type="moe", moe_routing="dropless",
+                            moe_experts=4, moe_top_k=2),
+            "looped": dict(ut_steps=3)}[kind])
+        params = jax.tree_util.tree_map(
+            lambda a: a * 3.0, init_params(jax.random.PRNGKey(3), cfg))
+
+        def run():
+            eng = _make_engine(cfg, params, prefill_chunk_tokens=8)
+            eng.step()                  # no work: the books' clock starts here
+            st0, w0 = eng.stats(), flight.recorder().wall(flight.now_ns())
+            rids = [eng.submit([1 + i] * 21, max_new_tokens=12 + 5 * i)
+                    for i in range(6)]
+            _drive(eng)
+            w1 = flight.recorder().wall(flight.now_ns())
+            assert [len(list(eng.stream(r))) for r in rids] == [
+                12 + 5 * i for i in range(6)]
+            st1 = eng.stats()
+            return {k: st1[k] - st0[k] for k in st1 if type(st1[k]) is int}, w0, w1
+
+        run()                           # compiles the programs
+        flight._reset_for_tests()
+        d, w0, w1 = run()
+        steps = ring("engine.step")
+        assert len(steps) == d["steps"] == d["flight_spans_recorded"] > 20
+        assert d["flight_spans_dropped"] == 0
+        # the span, whole
+        assert d["between_ns"] + sum(d[k] for k in IN_SPAN) == d["step_ns"]
+        assert 0 <= d["between_ns"] < 0.2 * d["step_ns"]
+        # the gauges that come back with the ids
+        decodes = [e["args"] for e in steps if e["args"]["decodes"]]
+        mean = lambda key: sum(a[key] for a in decodes) / len(decodes)
+        ratio = lambda num, den, scale: scale * d[num] / d[den]
+        assert d["decode_lanes"] / d["steps_decode"] == mean("decodes")
+        if kind == "experts":
+            assert d["moe_steps_read"] == len(decodes)
+            assert ratio("moe_experts_touched_milli", "moe_steps_read", 1e-3) == (
+                pytest.approx(mean("experts_touched"), abs=6e-4))
+            assert ratio("moe_load_max_ppm", "moe_steps_read", 1e-6) == (
+                pytest.approx(mean("expert_load_max"), abs=6e-7))
+            assert 2.0 <= mean("experts_touched") <= 4.0
+        else:
+            assert d["moe_steps_read"] == d["moe_experts_touched_milli"] == 0
+        if kind == "looped":
+            assert d["ut_steps_read"] == len(decodes)
+            assert ratio("ut_exit_step_milli", "ut_steps_read", 1e-3) == (
+                pytest.approx(mean("exit_step_mean"), abs=6e-4))
+            assert 1.0 < mean("exit_step_mean") < 3.0
+        else:
+            assert d["ut_steps_read"] == d["ut_exit_step_milli"] == 0
+        # the pool over time: every step of this run wrote a record, so the
+        # ticks are the records' own edges
+        ends = [w0] + [e["ts"] + e["dur"] for e in steps]
+        util = [0.0] + [e["args"]["kv_util"] for e in steps]
+        held = sum(util[i] * (e["ts"] - ends[i]) + util[i + 1] * e["dur"]
+                   for i, e in enumerate(steps))
+        books = d["kv_block_held_ns"] / d["kv_block_cap_ns"]
+        assert d["kv_block_cap_ns"] % 63 == 0      # 64 blocks less the null block
+        assert books == pytest.approx(held / (ends[-1] - w0), rel=0.05)
+        obs = {"window": {"t0": w0, "seconds": w1 - w0}, "spans": steps}
+        twin = readers.span_time_mean(obs, readers.reader_spec("kv_util_mean"))
+        assert 100.0 * books == pytest.approx(twin, rel=0.15) and 5.0 < twin < 60.0
+
     def test_stream_tokens_are_what_eight_concurrent_consumers_took(
         self, tiny_engine_parts
     ):
