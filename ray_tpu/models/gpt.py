@@ -130,7 +130,7 @@ class GPTConfig:
     ssm_heads: int = 0                   # Mamba-2: heads of `ssm_head_dim` channels, B and C shared
     ssm_head_dim: int = 0                # by `ssm_groups` groups of heads, the chunked form's chunk
     ssm_groups: int = 1; ssm_chunk: int = 128
-    moe_select_bias: bool = False        # experts chosen by score + a bias, weighted by score alone
+    moe_select_bias: bool = False; layer_pattern: Optional[str] = None  # by score + a bias | a layer a character, (mw)+mf(gc)+: the END of this file
     norm_eps: float = 1e-6               # the RMSNorms of a `block_pattern` model (1e-6 elsewhere)
     tie_embeddings: bool = True
     # Mixture-of-Experts (expert parallelism over the ep mesh axis).
@@ -248,7 +248,7 @@ class GPTConfig:
                 "layers without learned positions, a dense gated MLP in every layer")
         if self.sandwich_norm and self.parallel_block:
             raise ValueError("sandwich_norm norms each sublayer's output; a parallel_block has one sum")
-        _check_block_pattern(self)
+        _check_block_pattern(self); _check_layer_pattern(self)
 
     @property
     def kv_heads(self) -> int:
@@ -300,7 +300,7 @@ class GPTConfig:
     @property
     def n_params(self) -> int:
         """Parameters HELD (a `moe_held` range counts its own experts)."""
-        if self.block_pattern: return _pattern_params(self)
+        if self.block_pattern or self.layer_pattern: return (_pattern_params if self.block_pattern else _sambay_params)(self)
         E, L, F, V, Hd = self.d_model, self.n_layers, self.d_mlp, self.vocab_size, self.n_heads * self.d_head
         gated = self.activation in ("swiglu", "reglu")
         if self.mlp_type == "moe":
@@ -787,12 +787,12 @@ def param_logical_dims(cfg: GPTConfig) -> Dict[str, Tuple[Optional[str], ...]]:
         dims["w_q"] = ("layers", "embed", "heads", "head_dim")
         dims["w_kv"] = ("layers", "embed", None, "heads", "head_dim")
     if cfg.kv_lora_rank or cfg.dense_layers or cfg.moe_shared or cfg.ssm_layout \
-            or cfg.n_heads_window or cfg.attn_gate or cfg.block_pattern:
+            or cfg.n_heads_window or cfg.attn_gate or cfg.block_pattern or cfg.layer_pattern:
         raise NotImplementedError(
             "no sharding is written for latent attention (kv_lora_rank), "
             "leading dense layers (dense_layers), a shared expert (moe_shared), "
             "state-space layers (ssm_layout), attention stacks by kind (n_heads_window), "
-            "a gate a head (attn_gate) or blocks of one mixer (block_pattern): they are "
+            "a gate a head (attn_gate), blocks of one mixer (block_pattern) or a decoder-hybrid-decoder (layer_pattern): they are "
             "served on one chip")
     if cfg.mlp_type == "moe":
         dims["moe_router"] = ("layers", "embed", "experts")
@@ -1008,7 +1008,7 @@ def _lead_stack(params):
 
 def init_params(rng, cfg: GPTConfig) -> Dict[str, jnp.ndarray]:
     if cfg.init == "unit_stream":
-        return (_init_pattern if cfg.block_pattern else _init_unit_stream)(rng, cfg)
+        return (_init_pattern if cfg.block_pattern else _init_sambay if cfg.layer_pattern else _init_unit_stream)(rng, cfg)
     if cfg.init != "gpt2":
         raise ValueError(f"init {cfg.init!r}: gpt2 | unit_stream")
     if cfg.kv_lora_rank or cfg.dense_layers or cfg.moe_shared or cfg.moe_held \
@@ -1358,7 +1358,7 @@ def _refuse_new_fields(cfg: GPTConfig, what: str):
         bad.append("attention stacks by kind (n_heads_window): one head count "
                    "and one rotary table")
     if cfg.attn_gate: bad.append("a gate a head (attn_gate)")
-    if cfg.block_pattern: bad.append("blocks of one mixer (block_pattern): three stacks by kind")
+    if cfg.block_pattern or cfg.layer_pattern: bad.append("blocks of one mixer (block_pattern): three stacks by kind" if cfg.block_pattern else "a decoder-hybrid-decoder (layer_pattern): four stacks by a layer's place, rows that later layers read")
     if bad:
         raise NotImplementedError(f"{what} does not support " + ", ".join(bad))
 
@@ -1769,7 +1769,7 @@ def forward(params, tokens, cfg: GPTConfig, positions=None, mesh=None, return_au
     mesh given → automatic pjit partitioning with a nested shard_map around
     the attention core when cfg.attn_impl is ring/ulysses.
     """
-    if cfg.block_pattern: return _pattern_forward(params, tokens, cfg, return_aux)
+    if cfg.block_pattern or cfg.layer_pattern: return (_pattern_forward if cfg.block_pattern else _sambay_forward)(params, tokens, cfg, return_aux)
     B, S = tokens.shape
     if positions is None:
         # In automatic (pjit) mode shapes are global — plain arange is right.
@@ -1845,7 +1845,7 @@ def _refuse_looped_training(cfg: GPTConfig, what: str):
         ("moe_shared", cfg.moe_shared), ("moe_held", cfg.moe_held),
         ("moe_scoring", cfg.moe_scoring != "softmax"),
         ("ssm_layout", cfg.ssm_layout), ("n_heads_window", cfg.n_heads_window),
-        ("attn_gate", cfg.attn_gate), ("block_pattern", cfg.block_pattern)) if on]
+        ("attn_gate", cfg.attn_gate), ("block_pattern", cfg.block_pattern), ("layer_pattern", cfg.layer_pattern)) if on]
     if served:
         raise NotImplementedError(
             f"{what} does not train a model with {', '.join(served)}: "
@@ -2391,7 +2391,7 @@ class KVLayout:
     value_row: int = 0              # width of the row in pool "v"; 0: no such pool
     state_layers: int = 0           # layers that keep a state a sequence and no row
     state: Tuple[Tuple[str, Tuple[int, ...], str], ...] = ()
-    kinds: Tuple[Tuple[str, int], ...] = ()     # a `block_pattern` model's blocks: all ("run"), by kind
+    kinds: Tuple[Tuple[str, int], ...] = (); reads: Tuple[int, ...] = ()    # a `block_pattern` model's blocks: all ("run"), by kind | [L] 1: the layer reads the rows at its group and slot and writes none
 
     @property
     def depth(self) -> int:
@@ -2413,7 +2413,7 @@ class KVLayout:
 @functools.lru_cache(maxsize=None)
 def kv_layout(cfg: GPTConfig) -> KVLayout:
     L, kinds = cfg.n_layers, cfg.layer_kinds
-    if cfg.block_pattern: return _pattern_layout(cfg)
+    if cfg.block_pattern or cfg.layer_pattern: return (_pattern_layout if cfg.block_pattern else _sambay_layout)(cfg)
     rows = ((-(-(cfg.kv_lora_rank + cfg.rotary_dim) // 128) * 128, 0)
             if cfg.kv_lora_rank else (cfg.kv_heads * cfg.d_head,) * 2)
     if cfg.ssm_layout:      # rows for the attention layers, a slot's state for the rest
@@ -2469,14 +2469,14 @@ def kv_head_rows(cfg: GPTConfig) -> Tuple[int, int, int]:
     the paged pool sees them: a latent model's ONE head (`_paged_layers`)."""
     if cfg.kv_lora_rank:
         return 1, kv_layout(cfg).key_row, cfg.kv_lora_rank
-    return cfg.kv_heads, cfg.d_head, cfg.d_head
+    return (cfg.kv_heads // 2, 2 * cfg.d_head, 2 * cfg.d_head) if cfg.layer_pattern else (cfg.kv_heads, cfg.d_head, cfg.d_head)    # differential attention: K/V PAIRS
 
 
 def attn_heads_by_window(cfg: GPTConfig) -> Tuple[Tuple[int, int], ...]:
-    """((window or 0, query heads x passes summed over the attention layers
-    of that window), ...): what the host's count of a paged program's
-    attention weighs keys by (`ops/paged_attention.py`; the engine works it
-    out once)."""
+    """((window or 0, query heads x passes summed over the attention layers of that window), ...): what the
+    host's count of a paged program's attention weighs keys by (`ops/paged_attention.py`; the engine
+    works it out once)."""
+    if cfg.layer_pattern: return _sambay_heads_by_window(cfg)
     win = (cfg.layer_kinds or (None, (0,) * cfg.n_layers))[1]
     heads: Dict[int, int] = {}
     for h, w, ssm in zip(cfg.layer_heads, win, _rowless_layers(cfg)):
@@ -2732,10 +2732,10 @@ def prefill_paged(params, tokens, real_len, pos_offset, block_table, kv,
     state (`KVLayout.state`) `state_slot` (a traced scalar) names the
     sequence's slot in kv["state"]: the chunk continues the state the chunk
     before it left there (from zero where pos_offset is 0), and its padding
-    advances nothing. Returns
-    (next-token logits [V] f32 at global position pos_offset + real_len -
-    1, kv) — only meaningful on the FINAL chunk of a prompt.
+    advances nothing. Returns (next-token logits [V] f32 at global position
+    pos_offset + real_len - 1, kv) — only meaningful on the FINAL chunk of a prompt.
     """
+    if cfg.layer_pattern: return _sambay_prefill(params, tokens, real_len, pos_offset, block_table, kv, cfg, state_slot)
     rel = jnp.arange(tokens.shape[1])
     pos = (pos_offset + rel)[None]               # global token positions [1, Sp]
     x, kv, _, _ = _paged_layers(
@@ -2761,10 +2761,10 @@ def decode_step_paged(params, token, positions, block_tables, kv, cfg: GPTConfig
     mean over layers, padding lanes left out), kv. For a looped model
     (`cfg.ut_steps` > 1) what its exit gate read comes back the same way:
     (logits, [passes run] f32 = the exit distribution, one entry a pass the
-    program ran, mean over the real lanes), kv. For a model with state,
-    `state_slots` [B] int32 names each lane's slot in kv["state"] (a padding
-    lane's is 0): the step is a chunk of one token from that state.
+    program ran, mean over the real lanes), kv. For a model with state, `state_slots` [B] int32 names
+    each lane's slot in kv["state"] (a padding lane's is 0): the step is a chunk of one token from that state.
     """
+    if cfg.layer_pattern: return _sambay_decode(params, token, positions, block_tables, kv, cfg, state_slots)
     x, kv, load, exits = _paged_layers(
         params, token[:, None], positions[:, None], True, block_tables, kv, cfg,
         state_slots
@@ -2788,9 +2788,9 @@ def verify_step_paged(params, tokens, positions, valid_len, block_tables, kv,
     history 0..positions[b]+j), so logits[b, j] is EXACTLY what a
     sequential `decode_step_paged` would produce after accepting drafts
     0..j-1 — the greedy accept rule (longest matching draft prefix + one
-    corrective/bonus token) therefore reproduces non-speculative greedy
-    decode token-for-token. Returns (logits [B, K1, V] f32, kv).
+    corrective/bonus token) therefore reproduces non-speculative greedy decode token-for-token. Returns (logits [B, K1, V] f32, kv).
     """
+    if cfg.layer_pattern: raise NotImplementedError("a decoder-hybrid-decoder (layer_pattern) takes no verify step: its state is not rolled back past a rejected draft")
     rel = jnp.arange(tokens.shape[1])[None, :]
     pos = positions[:, None] + rel                              # [B, K1]
     x, kv, _, _ = _paged_layers(
@@ -3017,7 +3017,7 @@ def _rowless_layers(cfg: GPTConfig) -> Tuple[int, ...]:
     `block_pattern` block that is not attention)."""
     if cfg.block_pattern:
         return tuple(int(c != "*") for c in cfg.block_pattern)
-    return cfg.ssm_layout or (0,) * cfg.n_layers
+    return tuple(int(c in "mg") for c in cfg.layer_pattern) if cfg.layer_pattern else cfg.ssm_layout or (0,) * cfg.n_layers
 
 
 def _pattern_layout(cfg: GPTConfig) -> KVLayout:
@@ -3208,3 +3208,445 @@ def nemotron3_nano_30b_a3b(**kw):
 
 
 CONFIGS["nemotron3-nano-30b-a3b"] = nemotron3_nano_30b_a3b
+
+
+# ------------------------- a decoder-hybrid-decoder (`layer_pattern`, SambaY)
+# A model of two decoders over one stream (arXiv:2507.06607; Phi-4-mini-flash-
+# reasoning): every layer l is a = h + mixer_l(LN1_l(h)), h <- a + MLP_l(LN2_l(a))
+# under LayerNorms WITH bias and the dense SiLU-gated MLP, its mixer the l-th
+# character of `cfg.layer_pattern`:
+#
+#   "m" Mamba-1 as published (no inner norm: `ops/sambay.py` `mamba_mixer_plain`),
+#       a state a sequence; the LAST one also hands out its scan output `m`
+#   "w" differential attention under `sliding_window`, rows in a window group
+#   "f" differential attention, full and causal, rows in the ONE full group
+#   "g" a gated memory unit over `m` of the same token: keeps nothing
+#   "c" differential CROSS attention: its own queries over the "f" layer's rows,
+#       through that layer's block table; it writes no row and keeps nothing
+#
+# in the form (mw)+ m f (gc)+: a SELF-decoder of (m, w) pairs closed by the (m, f)
+# pair, and a CROSS-decoder of (g, c) pairs that has no state of its own and mixes
+# tokens only through the "f" layer's rows, so its output at a position needs
+# nothing of its output at earlier positions: a prefill chunk runs it on ONE token
+# a lane, the last real one (`_sambay_paged`), and the positions before a prompt's
+# last have no logits. Differential attention rides the paged kernels as
+# grouped-query attention over K/V PAIRS of 2 x d_head = 128 (`ops/sambay.py`,
+# `kv_head_rows`). No positional term. The weights are stacks by a layer's place in
+# its pair: `sm_*`, `sa_*` [self pairs, ...] (the Mamba layer and the attention
+# layer of a self-decoder pair, each with its own norms and MLP) and `cg_*`, `ca_*`
+# [cross pairs, ...]; the walk is TWO `lax.scan`s, one a decoder, the window and the
+# layer's index riding as data. Served by `forward` and the paged programs on one
+# chip; everything else refuses it by name. At the END of the file, its callers
+# above edited without moving a line (ROADMAP D20).
+_SAMBAY_MAMBA = ("w_in", "conv_w", "conv_b", "w_x", "w_dt", "b_dt", "A_log", "D", "w_out")
+_SAMBAY_GATHER_TOKENS = 128     # tokens of a gathered slice of the pool (`_sambay_paged`)
+
+
+def _sambay_pairs(cfg: GPTConfig) -> Tuple[int, int]:
+    """(self-decoder pairs, the (m, f) pair among them; cross-decoder pairs)."""
+    cross = cfg.layer_pattern.count("c")
+    return cfg.n_layers // 2 - cross, cross
+
+
+def _check_layer_pattern(cfg: GPTConfig):
+    """`GPTConfig.__post_init__`'s checks of the fields this section reads."""
+    if cfg.layer_pattern is None:
+        return
+    n, pattern = cfg.n_layers, cfg.layer_pattern
+    P = pattern.count("m")
+    if len(pattern) != n or pattern != "mw" * (P - 1) + "mf" + "gc" * (n // 2 - P) \
+            or P < 2 or P == n // 2:
+        raise ValueError(f"layer_pattern {pattern!r}: (mw)+ m f (gc)+ over the "
+                         f"{n} layers")
+    if (cfg.norm != "layernorm" or cfg.pos != "none" or cfg.activation != "swiglu"
+            or cfg.mlp_type != "dense" or not cfg.tie_embeddings or cfg.init != "unit_stream"
+            or cfg.block_pattern or cfg.ssm_layout or cfg.layer_kinds is not None
+            or cfg.ut_steps > 1 or cfg.kv_lora_rank or cfg.dense_layers or cfg.sandwich_norm
+            or cfg.parallel_block or cfg.n_heads_window or cfg.attn_gate
+            or cfg.n_heads % 2 or cfg.kv_heads % 2 or cfg.n_heads % cfg.kv_heads
+            or cfg.sliding_window < 1):
+        raise ValueError(
+            "layer_pattern: a one-pass LayerNorm model without positional term, a tied "
+            'head, init="unit_stream", the dense SiLU-gated MLP, an even number of query '
+            "and of K/V heads (differential attention pairs them) and sliding_window >= 1")
+
+
+def _sambay_params(cfg: GPTConfig) -> int:
+    """`GPTConfig.n_params` of a `layer_pattern` model: what the tree holds."""
+    E, F, V, Hd, Kd = cfg.d_model, cfg.d_mlp, cfg.vocab_size, cfg.n_heads * cfg.d_head, cfg.kv_heads * cfg.d_head
+    Di, N, R, K = cfg.ssm_inner, cfg.ssm_state, cfg.ssm_dt_rank, cfg.ssm_conv
+    P, C = _sambay_pairs(cfg)
+    layer = 4 * E + 3 * E * F
+    mamba = E * 2 * Di + Di * K + Di + Di * (R + 2 * N) + R * Di + Di + N * Di + Di + Di * E
+    lam = 4 * cfg.d_head + 2 * cfg.d_head
+    attn = E * (Hd + 2 * Kd) + Hd + 2 * Kd + Hd * E + E + lam
+    cross = E * Hd + Hd + Hd * E + E + lam
+    return (cfg.n_layers * layer + P * (mamba + attn) + C * (2 * E * Di + cross)
+            + V * E + 2 * E)
+
+
+def _init_sambay(rng, cfg: GPTConfig) -> Dict[str, jnp.ndarray]:
+    """`init_params` of a `layer_pattern` model under init="unit_stream": a matrix of
+    fan-in n has std gain / sqrt(n), its gain from the preset's `init_gains`; the
+    Mamba mixers by the family's published initialisation (`_init_ssm_stack`'s: steps
+    log-uniform in 0.001-0.1 through `b_dt`, A = -(1 .. N), D = 1), the B and C columns
+    of `w_x` under a gain of their own (`ssm_bc`: no inner norm scales them here);
+    EVERY bias and the `lambda` vectors seeded non-zero (`bias`, `lam`), so that
+    leaving one out changes the result; the final norm's gain alternating in sign, as
+    every tied preset's (`_init_unit_stream`)."""
+    E, F, V, H, Hkv, Dh = cfg.d_model, cfg.d_mlp, cfg.vocab_size, cfg.n_heads, cfg.kv_heads, cfg.d_head
+    Di, N, R, K = cfg.ssm_inner, cfg.ssm_state, cfg.ssm_dt_rank, cfg.ssm_conv
+    (P, C), dt, g = _sambay_pairs(cfg), cfg.param_dtype, dict(cfg.init_gains)
+    keys = iter(jax.random.split(rng, 64))
+
+    def n(shape, gain, fan_in):
+        return (jax.random.normal(next(keys), shape, jnp.float32)
+                * (gain / math.sqrt(fan_in))).astype(dt)
+
+    def layer(L):       # the norms and the MLP every layer has
+        return {"ln1_w": jnp.ones((L, E), dt), "ln1_b": n((L, E), g["bias"], 1),
+                "ln2_w": jnp.ones((L, E), dt), "ln2_b": n((L, E), g["bias"], 1),
+                "w_gate": n((L, E, F), g["mlp_in"], E), "w_in": n((L, E, F), g["mlp_in"], E),
+                "w_out": n((L, F, E), g["mlp_out"], F)}
+
+    def attention(L, cross):        # the projection's columns: q, or q | k | v
+        heads = ((H, "q"),) if cross else ((H, "q"), (Hkv, "k"), (Hkv, "v"))
+        w = jnp.concatenate([n((L, E, h * Dh), g[name], E) for h, name in heads], axis=-1)
+        return {"w_q" if cross else "w_qkv": w,
+                "b_q" if cross else "b_qkv": n((L, w.shape[-1]), g["bias"], 1),
+                "w_o": n((L, H * Dh, E), g["o"], H * Dh), "b_o": n((L, E), g["bias"], 1),
+                "lam": n((L, 4, Dh), g["lam"], 1), "sub_w": jnp.ones((L, 2 * Dh), dt)}
+
+    step = jnp.exp(jax.random.uniform(next(keys), (P, Di), jnp.float32)
+                   * (math.log(0.1) - math.log(0.001)) + math.log(0.001))
+    mamba = {
+        "w_in": n((P, E, 2 * Di), g["ssm_in"], E), "conv_w": n((P, K, Di), g["ssm_conv"], K),
+        "conv_b": n((P, Di), g["bias"], 1),
+        "w_x": jnp.concatenate([n((P, Di, R), g["ssm_x"], Di),
+                                n((P, Di, 2 * N), g["ssm_bc"], Di)], axis=-1),
+        "w_dt": n((P, R, Di), g["ssm_dt"], R),
+        "b_dt": (step + jnp.log(-jnp.expm1(-step))).astype(dt),
+        "A_log": jnp.broadcast_to(jnp.log(jnp.arange(1, N + 1, dtype=jnp.float32))[None, :, None],
+                                  (P, N, Di)).astype(dt),
+        "D": jnp.ones((P, Di), dt), "w_out": n((P, Di, E), g["ssm_out"], Di)}
+    stacks = {
+        "sm": {**layer(P), **{"ssm_" + name: a for name, a in mamba.items()}},
+        "sa": {**layer(P), **attention(P, False)},
+        "cg": {**layer(C), "gmu_gate": n((C, E, Di), g["gmu_gate"], E),
+               "gmu_out": n((C, Di, E), g["gmu_out"], Di)},
+        "ca": {**layer(C), **attention(C, True)}}
+    return {"tok_embed": n((V, E), g["embed"], 1),
+            "ln_f_w": jnp.where(jnp.arange(E) % 2, -1, 1).astype(dt),
+            "ln_f_b": n((E,), g["bias"], 1),
+            **{f"{role}_{name}": a for role, stack in stacks.items() for name, a in stack.items()}}
+
+
+def _sambay_layout(cfg: GPTConfig) -> KVLayout:
+    """`kv_layout` of a `layer_pattern` model: every "w" layer a window group of its
+    own and the "f" layer the one full group (per_group 1: a pool ONE layer deep, a
+    block table a group a sequence); a "c" layer on the "f" layer's group and slot,
+    marked in `reads` (it writes no row there); an "m" layer's slot its index among
+    the state arrays; a "g" layer keeps nothing (group 0, slot 0: never read)."""
+    from ..ops import ssm
+
+    P, _ = _sambay_pairs(cfg)
+    group_of = [l // 2 if c in "wf" else P - 1 if c == "c" else 0
+                for l, c in enumerate(cfg.layer_pattern)]
+    slot_of = [l // 2 if c == "m" else 0 for l, c in enumerate(cfg.layer_pattern)]
+    row = cfg.kv_heads * cfg.d_head
+    return KVLayout(
+        1, (cfg.sliding_window,) * (P - 1) + (0,), tuple(group_of), tuple(slot_of), 1,
+        row, row, P,
+        (("conv", ((cfg.ssm_conv - 1) * cfg.ssm_inner,), jnp.dtype(cfg.dtype).name),
+         ("ssm", ssm.state_shape(cfg.ssm_inner, cfg.ssm_state), "float32")),
+        reads=tuple(int(c == "c") for c in cfg.layer_pattern))
+
+
+def _sambay_heads_by_window(cfg: GPTConfig) -> Tuple[Tuple[int, int], ...]:
+    """`attn_heads_by_window`: the "w" layers under the window, the "f" layer and the
+    "c" layers that read its rows under none."""
+    n = cfg.layer_pattern.count
+    return ((cfg.sliding_window, n("w") * cfg.n_heads), (0, (n("f") + n("c")) * cfg.n_heads))
+
+
+def _sambay_stack(params, role: str, i):
+    """Layer i of the stack `role` (`sm`, `sa`, `cg`, `ca`), read where it lies."""
+    return {name[3:]: a[i] for name, a in params.items() if name.startswith(role + "_")}
+
+
+def _sambay_layer(cfg: GPTConfig, p, x, mixer):
+    """One layer over x [B, S, E]: `mixer(normed stream) -> (what it adds to the
+    stream, what else it hands on)`. Returns (x, what the mixer handed on)."""
+    dt = cfg.dtype
+    norm = lambda x, w, b: layernorm(x, w.astype(dt), b.astype(dt), cfg.norm_eps)
+    out, handed = mixer(norm(x, p["ln1_w"], p["ln1_b"]))
+    a = x + out
+    return a + _gated_mlp(cfg, norm(a, p["ln2_w"], p["ln2_b"]), p["w_gate"].astype(dt),
+                          p["w_in"].astype(dt), p["w_out"].astype(dt)), handed
+
+
+def _sambay_attention(cfg: GPTConfig, p, h, layer, attend):
+    """A differential attention mixer over h [B, S, E]: `attend(q [B, H, S, 2 Dh], k,
+    v [B, S, Hkv Dh] or None for a cross layer) -> ([B, H, S, 2 Dh], store)`, `layer`
+    its index in the whole stack (a traced scalar). Returns (what it adds, store)."""
+    from ..ops import sambay
+
+    dt, (B, S, _), Hd = cfg.dtype, h.shape, cfg.n_heads * cfg.d_head
+    cross = "w_q" in p
+    qkv = (jnp.einsum("bse,ef->bsf", h, p["w_q" if cross else "w_qkv"].astype(dt))
+           + p["b_q" if cross else "b_qkv"].astype(dt))
+    k, v = (None, None) if cross else jnp.split(qkv[..., Hd:], 2, axis=-1)
+    attn, store = attend(
+        sambay.diff_queries(qkv[..., :Hd].reshape(B, S, cfg.n_heads, cfg.d_head)), k, v)
+    out = sambay.diff_combine(attn, sambay.diff_lambda(p["lam"], layer), p["sub_w"],
+                              1.0 - sambay.lambda_init(layer), cfg.norm_eps)
+    return jnp.einsum("bsf,fe->bse", out, p["w_o"].astype(dt)) + p["b_o"].astype(dt), store
+
+
+def _sambay_decoders(cfg: GPTConfig, params, x, store, mamba, attend, last):
+    """Both decoders over x [B, S, E]. `store` is whatever `mamba` and `attend` keep
+    between layers (the pool and the state arrays, or a dense layer's rows); it rides
+    both scans' carry. `mamba(store, i, p, h) -> (out, (m, store))`: pair i's Mamba
+    mixer, `p` its weights under `ops/ssm.py`'s names; `attend(store, i, window, q, k,
+    v) -> (attn, store)`: pair i's attention under `window` (an int32 scalar), a cross
+    layer's where k is None (i then P - 1: the "f" layer's rows). `last(x, m) -> (x,
+    m)` cuts the stream and `m` to the tokens the cross-decoder runs on, between the
+    decoders. Returns (x, store)."""
+    from ..ops import sambay
+
+    dt, (P, C) = cfg.dtype, _sambay_pairs(cfg)
+    windows = jnp.asarray([cfg.sliding_window] * (P - 1) + [_NO_WINDOW], jnp.int32)
+
+    def self_pair(carry, inp):
+        x, _, store = carry
+        i, window = inp
+        pm, pa = _sambay_stack(params, "sm", i), _sambay_stack(params, "sa", i)
+        weights = {name: pm["ssm_" + name].astype(dt) for name in _SAMBAY_MAMBA}
+        x, (m, store) = _sambay_layer(cfg, pm, x, lambda h: mamba(store, i, weights, h))
+        x, store = _sambay_layer(cfg, pa, x, lambda h: _sambay_attention(
+            cfg, pa, h, 2 * i + 1, functools.partial(attend, store, i, window)))
+        return (x, m, store), None
+
+    m0 = jnp.zeros(x.shape[:2] + (cfg.ssm_inner,), dt)
+    (x, m, store), _ = jax.lax.scan(self_pair, (x, m0, store), (jnp.arange(P), windows))
+    x, m = last(x, m)
+
+    def cross_pair(carry, i):
+        x, store = carry
+        pg, pc = _sambay_stack(params, "cg", i), _sambay_stack(params, "ca", i)
+        x, _ = _sambay_layer(cfg, pg, x, lambda h: (sambay.gated_memory_unit(
+            h, m, pg["gmu_gate"].astype(dt), pg["gmu_out"].astype(dt)), None))
+        x, store = _sambay_layer(cfg, pc, x, lambda h: _sambay_attention(
+            cfg, pc, h, 2 * (P + i) + 1,
+            lambda q, k, v: attend(store, P - 1, windows[P - 1], q, None, None)))
+        return (x, store), None
+
+    (x, store), _ = jax.lax.scan(cross_pair, (x, store), jnp.arange(C))
+    return x, store
+
+
+def sambay_cross_tokens(tokens: int, chunk: bool) -> int:
+    """How many of a program's `tokens` tokens a lane the cross-decoder of a
+    `layer_pattern` model runs on: ONE in a chunk program (the cross layers mix tokens
+    only through the "f" layer's rows, so only the token whose logits are read needs
+    them), all of them otherwise (a decode step's one). `_sambay_paged` shapes the
+    cross-decoder's stream by this answer and the engine books it for every chunk
+    program it dispatches (`engine.py` `_book_shared`): one rule, asked twice."""
+    return 1 if chunk else tokens
+
+
+def _sambay_paged(params, tokens, pos, valid, block_tables, kv, cfg: GPTConfig,
+                  state_slots, last=None):
+    """`_paged_layers` for a `layer_pattern` model: lane b brings S new tokens at
+    positions pos[b] over its NINE block tables [B, G, W] (`_sambay_layout`) and its
+    state slot. The self-decoder runs over all S: a "w" / "f" layer writes its rows
+    into its own group's blocks FIRST and attends over that table (`PagedAttention`,
+    the pool one layer deep: slot 0), an "m" layer advances the state of the lane's
+    slot over its real tokens (from ZERO where the lane's first token sits at
+    position 0, as `_paged_layers` has it). The cross-decoder runs on ONE token a
+    lane: `last` [B] names it among the S (a chunk's last real token), None where S
+    is 1 (a decode step); its "c" layers attend from that token's position over the
+    "f" layer's table in the one-query form and write nothing. (`sambay_cross_tokens`
+    is that "ONE": the stream is cut to as many tokens as it says, those that end at
+    `last`.) Returns (the stream of those tokens [B, 1, E] before the final norm, kv)."""
+    from ..ops import sambay
+
+    if state_slots is None:
+        raise NotImplementedError(
+            "a decoder-hybrid-decoder (layer_pattern) is served by prefill_paged and "
+            "decode_step_paged, which name each lane's state slot")
+    (B, S), W, BS = tokens.shape, block_tables.shape[-1], kv["k"].shape[2]
+    Hkv, Dh, Dv = kv_head_rows(cfg)
+    shape = (BS, Hkv, Dh, Dv, cfg.d_head ** -0.5, kv["k"].dtype, cfg.n_heads)
+    x = _embed(params, tokens, pos, cfg)
+    blk, off = jnp.minimum(pos // BS, W - 1), pos % BS
+    # A program of several tokens a lane GATHERS its table's rows (`PagedAttention`'s
+    # one-shot and chunk forms), and the chip's compiler cuts a gather whose slices
+    # pass some 512 KiB (a block of 512 tokens of 1280 is 1.25 MiB) into column
+    # strips of the WHOLE pool first: 8 ms a layer a GiB of pool (PERF.md §6, PR 56).
+    # Such a program reads the pool as blocks of at most `_SAMBAY_GATHER_TOKENS`, a
+    # reshape that moves nothing, each table entry as that many entries: the same
+    # keys, tiles and bounds, so the host's count stands as it is. The split belongs
+    # where the gather lives, `ops/paged_attention.py` `PagedAttention._tiled`, which
+    # D20 keeps shut in a PR that adds a cell: the PR that opens that file (ROADMAP
+    # S2, S17) takes it there for every model and deletes it here.
+    r = BS // _SAMBAY_GATHER_TOKENS if S > 1 and BS % _SAMBAY_GATHER_TOKENS == 0 else 1
+    fine = jnp.where(block_tables[..., None] == 0, 0,
+                     block_tables[..., None] * r + jnp.arange(r)).reshape(B, -1, W * r)
+    over = paged_attention.PagedAttention(pos, valid, fine, BS // r, *shape[1:])
+    real = over.real
+    fresh = (pos[:, 0] == 0)[:, None]
+    tail_shape = (B, cfg.ssm_conv - 1, cfg.ssm_inner)
+    # (attention, its tables, blocks it reads a pool block as) of the self-decoder's
+    # layers and of the cross layers: one query a lane, the pool as it lies
+    n = sambay_cross_tokens(S, last is not None)
+    at = None if last is None else last[:, None] + jnp.arange(1 - n, 1)    # n, ending at `last`
+    forms = {False: (over, fine, r), True: (over, fine, r) if last is None else (
+        paged_attention.PagedAttention(
+            jnp.take_along_axis(pos, at, axis=1), jnp.take_along_axis(real, at, axis=1),
+            block_tables, *shape), block_tables, 1)}
+
+    def mamba(store, i, p, h):
+        kk, vv, conv, state = store
+        tail = jnp.where(fresh, 0, conv[i, state_slots])
+        s0 = jnp.where(fresh[..., None, None], 0, state[i, state_slots])
+        out, m, tail, s = sambay.mamba_mixer_plain(p, h, tail.reshape(tail_shape), s0, real)
+        return out, (m, (kk, vv, conv.at[i, state_slots].set(tail.reshape(B, -1)),
+                         state.at[i, state_slots].set(s)))
+
+    def attend(store, i, window, q, k, v):
+        kk, vv, conv, state = store
+        table = jnp.take(block_tables, i, axis=1)
+        if k is not None:       # a "w" or "f" layer: its rows first
+            ph = jnp.where(valid, jnp.take_along_axis(table, blk, axis=1), 0)
+            kk = kk.at[0, ph, off].set(k.astype(kk.dtype))
+            vv = vv.at[0, ph, off].set(v.astype(vv.dtype))
+        attention, tables, blocks = forms[k is None]
+        rows = lambda pool: pool.reshape(1, -1, BS // blocks, pool.shape[-1])
+        return (attention(q, rows(kk), rows(vv), 0, jnp.take(tables, i, axis=1), window),
+                (kk, vv, conv, state))
+
+    def at_last(x, m):
+        if last is None:
+            return x, m
+        return (jnp.take_along_axis(x, at[..., None], axis=1),
+                jnp.take_along_axis(m, at[..., None], axis=1))
+
+    st = kv["state"]
+    x, (kk, vv, conv, state) = _sambay_decoders(
+        cfg, params, x, (kv["k"], kv["v"], st["conv"], st["ssm"]), mamba, attend, at_last)
+    return x, {"k": kk, "v": vv, "state": {"conv": conv, "ssm": state}}
+
+
+def _sambay_prefill(params, tokens, real_len, pos_offset, block_table, kv, cfg, state_slot):
+    """`prefill_paged` of a `layer_pattern` model: the self-decoder over the chunk, the
+    cross-decoder on its last real token alone."""
+    rel = jnp.arange(tokens.shape[1])
+    x, kv = _sambay_paged(
+        params, tokens, (pos_offset + rel)[None], (rel < real_len)[None], block_table[None],
+        kv, cfg, None if state_slot is None else state_slot[None],
+        jnp.maximum(real_len - 1, 0)[None])
+    return _logits(params, x[0, -1], cfg).astype(jnp.float32), kv
+
+
+def _sambay_decode(params, token, positions, block_tables, kv, cfg, state_slots):
+    """`decode_step_paged` of a `layer_pattern` model: all the layers on one token a lane."""
+    x, kv = _sambay_paged(
+        params, token[:, None], positions[:, None], True, block_tables, kv, cfg, state_slots)
+    return _logits(params, x[:, 0], cfg).astype(jnp.float32), kv
+
+
+def _sambay_forward(params, tokens, cfg: GPTConfig, return_aux: bool):
+    """`forward` of a `layer_pattern` model: the whole sequence through every layer at
+    every position, every sequence's state from zero and dropped at the end, the
+    differential attention's two softmaxes dense under the layer's mask."""
+    from ..ops import sambay, ssm
+
+    B, S = tokens.shape
+    x = _embed(params, tokens, None, cfg)
+    everyone = jnp.ones((B, S), bool)
+    tail = jnp.zeros((B, cfg.ssm_conv - 1, cfg.ssm_inner), cfg.dtype)
+    s0 = jnp.zeros((B, *ssm.state_shape(cfg.ssm_inner, cfg.ssm_state)), jnp.float32)
+    i, j = jnp.arange(S)[:, None], jnp.arange(S)[None, :]
+    rows = lambda a: a.reshape(B, S, cfg.kv_heads // 2, 2 * cfg.d_head).transpose(0, 2, 1, 3)
+
+    def mamba(store, l, p, h):
+        out, m, _, _ = sambay.mamba_mixer_plain(p, h, tail, s0, everyone)
+        return out, (m, store)
+
+    def attend(store, l, window, q, k, v):
+        if k is not None:       # a cross layer (k None) reads what the "f" layer left
+            store = (rows(k), rows(v))
+        k, v = store
+        qg = q.reshape(B, k.shape[1], -1, S, q.shape[-1])
+        scores = jnp.einsum("bgrsd,bgtd->bgrst", qg, k,
+                            preferred_element_type=jnp.float32) * cfg.d_head ** -0.5
+        probs = jax.nn.softmax(jnp.where((j <= i) & (j > i - window), scores, -1e30), axis=-1)
+        out = jnp.einsum("bgrst,bgtd->bgrsd", probs.astype(v.dtype), v)
+        return out.reshape(q.shape[:3] + v.shape[-1:]), store
+
+    zero = jnp.zeros((B, cfg.kv_heads // 2, S, 2 * cfg.d_head), cfg.dtype)
+    x, _ = _sambay_decoders(cfg, params, x, (zero, zero), mamba, attend, lambda x, m: (x, m))
+    logits = _logits(params, x, cfg)
+    return (logits, jnp.zeros((), jnp.float32)) if return_aux else logits
+
+
+def phi4_mini_flash(**kw):
+    """Phi-4-mini-flash-reasoning (huggingface.co/microsoft/Phi-4-mini-flash-reasoning,
+    `model_type: "phi4flash"`, the SambaY decoder-hybrid-decoder of arXiv:2507.06607): 32
+    layers of 2560 under LayerNorms with bias (eps 1e-5) and a SiLU-gated MLP of 10,240
+    each; even layers below 18 Mamba-1 (inner width 5120, state 16, 4 taps, rank 160,
+    no inner norm), odd ones below 16 differential attention under a window of 512,
+    layer 17 full differential attention (40 query heads over 20 K/V heads of 64,
+    paired); layers 18-30 even gated memory units over layer 16's scan output, 19-31 odd
+    differential cross attention over layer 17's rows; vocabulary 200,064 TIED; no
+    positional term. Serving only: `forward` and the paged programs, whole on one chip."""
+    L = kw.get("n_layers", 32)
+    return GPTConfig(
+        **{
+            **dict(
+                n_layers=L,
+                layer_pattern="mw" * (L // 4) + "mf" + "gc" * (L // 4 - 1),
+                d_model=2560,
+                n_heads=40,
+                n_kv_heads=20,
+                d_head=64,
+                d_mlp=10240,
+                vocab_size=200064,
+                max_seq=262144,
+                norm="layernorm",
+                norm_eps=1e-5,
+                activation="swiglu",
+                pos="none",
+                sliding_window=512,
+                ssm_state=16,
+                ssm_conv=4,
+                ssm_expand=2,
+                ssm_dt_rank=160,
+                tie_embeddings=True,
+                param_dtype=jnp.bfloat16,
+                # As `jamba2_3b`: a stream that keeps the token (embedding std 1) under
+                # layers that together add about as much again. No inner norm scales B
+                # and C here, so their columns of `w_x` carry the gain that Jamba's
+                # norms did (`ssm_bc`: B and C of size 1.5-2), which makes what the
+                # state adds the larger part of `m` beside the skip term D c; the
+                # memory units' `gmu_out` and the mixers' `ssm_out` keep a rounding from
+                # growing through 32 layers (`scripts/phi4flash_tolerance.py` reads the
+                # growth and each control on the chip; PERF.md §6, PR 56). Biases and
+                # the `lambda` vectors are seeded non-zero (`bias`, `lam`: N(0, 0.1)) so
+                # that leaving one out is seen. No program's shape or time depends on
+                # the numbers.
+                init="unit_stream",
+                init_gains=(("embed", 1.0), ("q", 1.2), ("k", 1.2), ("v", 1.0), ("o", 1.0),
+                            ("mlp_in", 1.0), ("mlp_out", 0.35), ("bias", 0.1), ("lam", 0.1),
+                            ("ssm_in", 1.0), ("ssm_conv", 1.0), ("ssm_x", 1.0),
+                            ("ssm_bc", 3.0), ("ssm_dt", 1.0), ("ssm_out", 0.2),
+                            ("gmu_gate", 1.0), ("gmu_out", 0.2)),
+                attn_impl="ref",
+            ),
+            **kw,
+        }
+    )
+
+
+CONFIGS["phi4-mini-flash"] = phi4_mini_flash

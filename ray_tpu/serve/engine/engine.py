@@ -103,7 +103,7 @@ _STEP_BOOKS = (
     # Query heads x keys the dispatched programs' attention covered, summed
     # over the layers, each under its own window and head count
     # (`ops.paged_attention.paged_attn_cover`), and of them the window layers'.
-    "attn_head_keys", "attn_head_keys_window", "blocks_run", "blocks_ssm", "blocks_moe", "blocks_attn",
+    "attn_head_keys", "attn_head_keys_window", "blocks_run", "blocks_ssm", "blocks_moe", "blocks_attn", "shared_kv_read_bytes", "kv_read_bytes", "cross_decoder_tokens",     # `_book_shared`
 )
 
 
@@ -1136,12 +1136,11 @@ class InferenceEngine:
                 fut.set_exception(e)
 
     def _count_attn(self, tokens: int, width: int, last_pos, real, first_pos=None) -> str:
-        """Ask the programs' own rule, once, for the form of the attention of
-        a program of `tokens` tokens a lane over tables `width` blocks wide,
-        add its (keys run, keys padded) to the step's and the engine's counts
-        and its heads x keys by layer kind to the books, from the arithmetic
-        its own bounds come from, and return the form. `first_pos`: each
-        lane's first query, where it is not its last (a chunk)."""
+        """Ask the programs' own rule, once, for the form of the attention of a program of `tokens`
+        tokens a lane over tables `width` blocks wide, add its (keys run, keys padded) to the step's
+        and the engine's counts and its heads x keys by layer kind to the books (`_book_shared`: the
+        rows that several layers read), from the arithmetic its own bounds come from, and return the
+        form. `first_pos`: each lane's first query, where it is not its last (a chunk)."""
         form = self._paged_attention.paged_attn_form(
             tokens, width, *self._attn_shapes)
         run, padded, window, every = self._paged_attention.paged_attn_cover(
@@ -1152,6 +1151,7 @@ class InferenceEngine:
             count[1] += padded
         self._books["attn_head_keys_window"] += window
         self._books["attn_head_keys"] += every
+        _book_shared(self, first_pos is None, tokens, self._np.size(last_pos), run, every)
         return form
 
     def _count_state(self, tokens: int, real: int, decode: bool):
@@ -1863,3 +1863,37 @@ def _expire_stamps(window: "deque[float]", now: float, span: float) -> None:
     line of every `def` and dispatch site above, ROADMAP S7.)"""
     while window and now - window[0] > span:
         window.popleft()
+
+
+def _book_shared(engine: "InferenceEngine", decode: bool, tokens: int, lanes: int, run: int, every: int) -> None:
+    """One dispatched program of a model some of whose layers READ rows that
+    another layer wrote (`KVLayout.reads`: a decoder-hybrid-decoder's cross
+    layers) into the books (`shared_kv_read_bytes`, `kv_read_bytes`,
+    `cross_decoder_tokens`), from what `_count_attn` counted:
+    `run` keys a layer without window covered, `every` query heads x keys over
+    all attention layers. A decode program: the bytes of SHARED rows its
+    attention read (the writer and each of its readers read every covered key's
+    K and V row) beside the row bytes all its attention layers read. A chunk
+    program of `tokens` tokens a lane: the tokens its cross-decoder ran on, by the
+    rule the program itself cuts its stream by (`models/gpt.py`
+    `sambay_cross_tokens`). Nothing for any other model. (At the END of the file:
+    ROADMAP S7.)"""
+    lay = engine._layout
+    if not lay.reads:
+        return
+    books = engine._books
+    if not decode:
+        from ...models.gpt import sambay_cross_tokens
+
+        books["cross_decoder_tokens"] += lanes * sambay_cross_tokens(tokens, True)
+        return
+    sharing = _SHARING.get(lay)
+    if sharing is None:     # layers that read shared rows, their writers among them
+        written = {(lay.group_of[l], lay.slot_of[l]) for l, reads in enumerate(lay.reads) if reads}
+        sharing = _SHARING[lay] = sum(lay.reads) + len(written)
+    row = (lay.key_row + lay.value_row) * engine._jnp.dtype(engine.cfg.dtype).itemsize
+    books["shared_kv_read_bytes"] += run * row * sharing
+    books["kv_read_bytes"] += every // engine.cfg.n_heads * row
+
+
+_SHARING: Dict[Any, int] = {}   # by `KVLayout`: worked out once, not a dispatch

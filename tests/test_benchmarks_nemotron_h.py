@@ -217,11 +217,13 @@ def test_the_cell_and_its_files_are_in_the_benchmark():
     for name in SPLIT:      # the accepted entry's own words, but for what it moves and where
         base = per_layer[name[:-len(".itl")]]
         assert CELL not in base["workloads"] and base["moves"] == "ttft_mean_ms"
-        assert per_layer[name] == {**base, "name": name, "moves": "itl_p90_ms",
-                                   "workloads": [CELL]}
+        mine = per_layer[name]
+        assert {**mine, "workloads": None} == {**base, "name": name, "moves": "itl_p90_ms",
+                                               "workloads": None}
+        assert mine["workloads"][0] == CELL and len(mine["workloads"]) <= 2   # PR 56's cell reads them too
     assert per_layer["ssd_time_share"]["source"] == "device_trace"
     for name in JOINED:
-        assert per_layer[name]["workloads"][-1] == CELL          # appended to the list
+        assert CELL in per_layer[name]["workloads"][-2:]         # appended to the list (PR 56's behind it)
     for name in layer:
         assert readers.reader_spec(name)["kind"] in readers.KINDS, name
     # every published key of the catalog's row, under its own name; three reduced
