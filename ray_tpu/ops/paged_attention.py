@@ -385,10 +385,10 @@ PAGED_DECODE_KERNEL = "paged_decode_attn"
 # a short lane holds). Set on the chip (PERF.md §6, PR 44).
 _DECODE_GROUP_BYTES = 1 << 20
 _DECODE_GROUP_KEYS = 512
-# Tables reach the kernel padded to ONE width a lane count, as wide as the pool
-# has blocks or as a scalar operand of this many bytes holds: the kernel is
-# then the same for every table width a server warms, and those programs
-# share one trace of it (`_paged_decode_call` is jitted for that).
+# Tables reach the kernel at ONE width a lane count, as wide as the pool has
+# blocks or as a scalar operand of this many bytes holds (`decode_table_width`):
+# the engine builds a decode step's tables at it, so a server warms ONE decode
+# program a lane bucket; a narrower table is padded to it, one trace either way.
 _DECODE_TABLE_BYTES = 32 << 10
 
 
@@ -545,7 +545,7 @@ def paged_decode_attention(q, keys, values, slot, table, pos, real, window, *,
     nb, bs = keys.shape[1:3]
     window = jnp.asarray(window, jnp.int32)
     first, blocks = paged_decode_span(jnp, pos, real, window, bs, width)
-    wide = max(width, min(nb, _DECODE_TABLE_BYTES // (4 * B)))
+    wide = max(width, decode_table_width(B, nb))
     block_bytes = bs * sum(pool.shape[-1] * pool.dtype.itemsize
                            for pool in (keys, values) if pool is not None)
     gb = max(1, min(wide, _DECODE_GROUP_BYTES // block_bytes,      # blocks a group
@@ -603,3 +603,11 @@ def _paged_decode_call(q, keys, values, slot, table, first, blocks, pos, window,
     # head h's own dv columns of its R rows
     out = out[:, :M].reshape(B, heads, R, heads, dv)
     return jnp.einsum("bhrhd->bhrd", out) if heads > 1 else out[:, :, :, 0]
+
+
+def decode_table_width(lanes: int, num_blocks: int) -> int:
+    """The ONE table width the decode kernel takes at `lanes` lanes over a pool
+    of `num_blocks` blocks: the pool's blocks, or what `_DECODE_TABLE_BYTES` of
+    int32 hold a lane. A wider table keeps its width. (At the END of the file:
+    the compile cache's key carries the line of every `def` above, ROADMAP S7.)"""
+    return min(num_blocks, _DECODE_TABLE_BYTES // (4 * lanes))

@@ -103,7 +103,7 @@ _STEP_BOOKS = (
     # Query heads x keys the dispatched programs' attention covered, summed
     # over the layers, each under its own window and head count
     # (`ops.paged_attention.paged_attn_cover`), and of them the window layers'.
-    "attn_head_keys", "attn_head_keys_window", "blocks_run", "blocks_ssm", "blocks_moe", "blocks_attn", "shared_kv_read_bytes", "kv_read_bytes", "cross_decoder_tokens",     # `_book_shared`
+    "attn_head_keys", "attn_head_keys_window", "blocks_run", "blocks_ssm", "blocks_moe", "blocks_attn", "shared_kv_read_bytes", "kv_read_bytes", "cross_decoder_tokens", "decode_width_fixed",     # `_book_shared`, `_decode_tables`
 )
 
 
@@ -1428,10 +1428,11 @@ class InferenceEngine:
             lanes = np.zeros((4 + self._stateful, B), np.int32)
             lanes[0] = self._spare
             lanes[3] = 1
-            tables = np.zeros(self._table_shape(B, W), np.int32)  # padding lanes -> null block
+            lanes[1, :len(seqs)] = [seq.num_tokens - 1 for seq in seqs]     # where this token's KV lands
+            form = self._count_attn(1, W, lanes[1], np.arange(B) < len(seqs))
+            tables = _decode_tables(self, form, B, W)             # padding lanes -> null block
             for i, seq in enumerate(seqs):
                 lanes[0, i] = seq.slot
-                lanes[1, i] = seq.num_tokens - 1    # where this token's KV lands
                 if seq.unread:
                     lanes[3, i] = 0
                 else:
@@ -1440,7 +1441,6 @@ class InferenceEngine:
                 self._tables_into(tables[i], seq)
             self._step_chained = int(not lanes[3].all())
             self.total_decode_chained += self._step_chained
-            form = self._count_attn(1, W, lanes[1], np.arange(B) < len(seqs))
             self.total_attn_decodes_kernel += form == self._paged_attention.DECODE_KERNEL
             self._count_moe(B)
             self._count_state(B, len(seqs), decode=True)
@@ -1897,3 +1897,22 @@ def _book_shared(engine: "InferenceEngine", decode: bool, tokens: int, lanes: in
 
 
 _SHARING: Dict[Any, int] = {}   # by `KVLayout`: worked out once, not a dispatch
+
+
+def _decode_tables(engine: "InferenceEngine", form: str, lanes: int, bucket: int):
+    """The zeroed block tables [lanes, (groups,) width] of a decode step of `form`
+    (what the programs' own rule gave `_count_attn`) whose widest sequence holds
+    `bucket` blocks (the scheduler's power of two). The decode kernel reads each lane's
+    blocks through its table and pads whatever table it gets to ONE width a lane count
+    (`ops.paged_attention.decode_table_width`), so its tables ARE that width, no wider
+    than a sequence this engine admits can hold (`submit`) and never under the bucket:
+    the decode program is then keyed by its lanes alone, and a server warms one a lane
+    bucket instead of one a (lanes, width) pair (booked: `decode_width_fixed`). Any
+    other form gathers by the table's width, which stays the bucket. (At the END of the
+    file: ROADMAP S7.)"""
+    pa, opts, width = engine._paged_attention, engine.opts, bucket
+    if form == pa.DECODE_KERNEL:
+        most = _next_pow2(min(-(-engine.cfg.max_seq // opts.block_size), opts.num_blocks))
+        width = max(bucket, min(pa.decode_table_width(lanes, opts.num_blocks), most))
+        engine._books["decode_width_fixed"] += 1
+    return engine._np.zeros(engine._table_shape(lanes, width), engine._np.int32)

@@ -94,6 +94,31 @@ def test_the_four_metric_files_read_a_canned_observation():
     assert readers.read("ssm_masked_token_share", plain) is None
 
 
+SERVING = ["gpt2-large.chat", "gpt2-large.chat-sat", "smallthinker-21b-a3b.mixed-len",
+           "ouro-2.6b.reason", "ax-k1.longdoc", "jamba2-3b.chat-burst", "laguna-xs2.codegen",
+           "nemotron3-nano-30b-a3b.agent-reason", "phi4-mini-flash.long-reason"]
+
+
+@pytest.mark.parametrize("cell", SERVING)
+def test_every_serving_cell_reads_the_decode_tables_one_width_share(cell, obs):
+    """PR 57's metric is data alone: a file for the `counter_ratio` reader and one
+    entry, the nine serving cells, moving `setup_s`. A parent without the counter
+    reads nothing; a CPU rehearsal, whose programs gather by the bucket, reads 0."""
+    name = "decode_width_fixed_share"
+    assert readers.reader_spec(name) == {"kind": "counter_ratio", "num": "decode_width_fixed",
+                                         "den": "decode_dispatched", "scale": 100.0}
+    bench = harness.benchmark()
+    entry = next(m for m in bench["per_layer"] if m["name"] == name)
+    assert {**entry, "workloads": entry["workloads"][:9]} == {       # a later serving cell appends
+        "name": name, "unit": "%", "better": "higher", "source": "program_counter",
+        "layer": "weights and compile", "moves": "setup_s", "workloads": SERVING}
+    assert name in harness.cell_metrics(bench, cell, "per_layer")
+    assert "setup_s" in harness.cell_metrics(bench, cell, "end_to_end")
+    assert readers.read(name, {"counters": {"decode_width_fixed": 30, "decode_dispatched": 40}}) == 75.0
+    assert readers.read(name, {"counters": {"decode_dispatched": 40}}) is None        # the parent
+    assert obs["counters"]["decode_dispatched"] > 0 and readers.read(name, obs) == 0.0
+
+
 def test_weight_bytes_and_pool_bytes_equal_the_hand_count(obs):
     m = obs["facts"]["model"]
     # tiny preset: E 64, Di 128, N 16, R 8, K 4

@@ -48,6 +48,8 @@ JOINED = ("moe_held_assign_share", "moe_grouped_time_share",
 BOOKS_53 = ("kv_util_mean_books.itl", "decode_lanes_mean_books",
             "moe_experts_touched_mean_books", "moe_expert_load_max_books",
             "step_between_ms", "flight_drop_share")
+# ... and what later PRs listed every serving cell in (PR 57)
+LATER = {"decode_width_fixed_share"}
 
 
 @pytest.fixture(scope="module")
@@ -204,7 +206,7 @@ def test_the_cell_and_its_files_are_in_the_benchmark():
     e2e = harness.cell_metrics(bench, CELL, "end_to_end")
     assert set(e2e) == {"setup_s", "itl_p90_ms"}
     layer = harness.cell_metrics(bench, CELL, "per_layer")
-    assert set(NEW_METRICS) | set(SPLIT) | set(JOINED) | set(BOOKS_53) | {"setup_attach_s"} == set(layer)
+    assert set(NEW_METRICS) | set(SPLIT) | set(JOINED) | set(BOOKS_53) | {"setup_attach_s"} == set(layer) - LATER
     per_layer = {m["name"]: m for m in bench["per_layer"]}
     assert all(per_layer[name]["moves"] in e2e for name in layer)
     names = [m["name"] for m in bench["per_layer"]]

@@ -196,8 +196,9 @@ def test_the_cell_and_its_files_are_in_the_benchmark():
     per_layer = {m["name"]: m for m in bench["per_layer"]}
     assert all(per_layer[name]["moves"] in e2e for name in layer)
     names = [m["name"] for m in bench["per_layer"]]
-    assert [n.split(".")[0] for n in names[-3:]] == list(NEW_METRICS)      # appended, in order
-    for name in names[-3:]:
+    at = names.index(next(iter(NEW_METRICS)))           # appended, in PR 56's order; later PRs' behind
+    assert [n.split(".")[0] for n in names[at:at + 3]] == list(NEW_METRICS)
+    for name in names[at:at + 3]:
         assert per_layer[name]["workloads"] == [CELL] and name in layer
         assert per_layer[name]["source"] == "program_counter"
     assert {"decode_hbm_roofline", "decode_device_ms", "serve_idle_share", "setup_warm_s",

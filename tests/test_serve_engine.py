@@ -2421,11 +2421,12 @@ def test_decode_programs_are_counted_on_the_programs_rule(tiny_engine_parts, ker
 
 
 def test_a_mixed_run_compiles_the_parents_programs_with_the_decode_kernel(monkeypatch):
-    """Shapes and program keys depend on (lanes, width) alone: a mixed run
-    whose decode steps take the kernel (interpret mode, traced as the chip
-    traces them) compiles one decode program a (lanes, width) bucket it met,
-    the buckets and the count those of the run that gathers, and emits its
-    tokens."""
+    """A mixed run whose decode steps take the kernel (interpret mode, traced
+    as the chip traces them) meets the (lanes, width) buckets of the run that
+    gathers, compiles its chunk programs and emits its tokens; the run that
+    gathers compiles one decode program a (lanes, width) bucket it met, the
+    kernel's run one a LANE bucket: its tables have one width a lane count
+    (`engine._decode_tables`)."""
     import functools
 
     import jax
@@ -2460,9 +2461,136 @@ def test_a_mixed_run_compiles_the_parents_programs_with_the_decode_kernel(monkey
             seen[by_kernel] = (buckets, eng._decode._cache_size(), eng._prefill._cache_size(),
                                [list(eng.stream(rid)) for rid in ids],
                                st["attn_decodes_kernel"] == st["decode_dispatched"])
-    assert seen[True][:4] == seen[False][:4] and len(seen[True][0]) >= 3
-    assert seen[True][1] == len(seen[True][0])      # one decode program a bucket
+    assert seen[True][0] == seen[False][0] and len(seen[True][0]) >= 3
+    assert seen[True][2:4] == seen[False][2:4]
+    assert seen[False][1] == len(seen[False][0])    # one decode program a bucket
+    assert seen[True][1] == len({lanes for lanes, _ in seen[True][0]}) < seen[False][1]
     assert (seen[False][4], seen[True][4]) == (False, True)
+
+
+# ------------------------------------- the decode step's tables: one width a lane bucket
+def _steer_to_the_decode_kernel(eng):
+    """The HOST's copy of the rule says a one-token step takes the decode
+    kernel, as it does on the chip for heads of 128; the programs keep the
+    CPU's forms, whose masks come from positions."""
+    import types
+
+    from ray_tpu.ops import paged_attention
+
+    rule = paged_attention.paged_attn_form
+    eng._paged_attention = types.SimpleNamespace(**{
+        **vars(paged_attention),
+        "paged_attn_form": lambda tokens, width, *shapes: (
+            paged_attention.DECODE_KERNEL if tokens == 1 else rule(tokens, width, *shapes))})
+
+
+# (block size, pool blocks, max_seq, lanes) -> the kernel's one width: the pool's
+# blocks or 32 KiB of int32 a lane, no wider than a sequence's blocks (a power of two)
+DECODE_TABLE_WIDTHS = {
+    "the-pool": ((4, 64, 4096, 4), 64),
+    "a-sequence": ((16, 64, 256, 4), 16),
+    "a-sequence-rounded-up": ((4, 512, 200, 1), 64),
+    "the-scalar-operand": ((4, 2048, 4096, 32), 256),
+    "the-scalar-operand-at-four-lanes": ((4, 4096, 65536, 4), 2048),
+}
+
+
+@pytest.mark.parametrize("form", ["DECODE_KERNEL", "ONE_SHOT", "KEY_LOOP"])
+@pytest.mark.parametrize("case", list(DECODE_TABLE_WIDTHS))
+def test_decode_tables_take_the_kernels_one_width_or_the_bucket(case, form):
+    """The width rule's truth table (`engine._decode_tables`): for the decode
+    kernel, the kernel module's own width a lane count, clipped to what a
+    sequence this engine admits can hold and never under the scheduler's
+    bucket, each such program booked; for any other form (every CPU run,
+    heads of 64 on the chip): the bucket, to the byte."""
+    import numpy as np
+
+    from ray_tpu.ops import paged_attention
+    from ray_tpu.serve.engine import engine as engine_module
+
+    (bs, blocks, max_seq, lanes), one = DECODE_TABLE_WIDTHS[case]
+    form = getattr(paged_attention, form)
+    kernel = form == paged_attention.DECODE_KERNEL
+    eng = _make_engine(_tiny_cfg(max_seq=max_seq), block_size=bs, num_blocks=blocks)
+    assert paged_attention.decode_table_width(lanes, blocks) == min(
+        blocks, paged_attention._DECODE_TABLE_BYTES // (4 * lanes)) >= one
+    for n, bucket in enumerate([1 << k for k in range(13)], 1):
+        tables = engine_module._decode_tables(eng, form, lanes, bucket)
+        assert tables.dtype == np.int32 and not tables.any()
+        assert tables.shape == (lanes, max(bucket, one) if kernel else bucket)
+        assert eng.stats()["decode_width_fixed"] == (n if kernel else 0)
+    eng._groups = 5         # one table a KV group: [lanes, groups, width]
+    assert engine_module._decode_tables(eng, form, lanes, 2).shape == (
+        lanes, 5, one if kernel else 2)
+
+
+GROWING = [([7, 3, 11], 34), ([5, 5, 5, 9, 8, 2], 20), ([44], 27)]   # 1 -> 10 blocks of 4
+
+
+def _grown_run(monkeypatch, cfg, params, fixed: bool, waves):
+    """One engine over fresh programs, its width rule steered to the kernel's
+    answer or not, `waves` of (prompt, new tokens) each submitted together
+    and drained before the next -> ((lanes, width) buckets dispatched, decode
+    programs compiled, the tokens by request, the engine's stats)."""
+    from ray_tpu.serve.engine import engine as engine_module
+
+    with monkeypatch.context() as mp:
+        mp.setattr(engine_module, "_JITS", None)
+        eng = _make_engine(cfg, params, block_size=4, num_blocks=64,
+                           prefill_chunk_tokens=8, max_step_tokens=32)
+        if fixed:
+            _steer_to_the_decode_kernel(eng)
+        buckets, ids = set(), []
+        for wave in waves:
+            ids += [eng.submit(prompt, new) for prompt, new in wave]
+            for plan, dispatched, _ in _walk(eng):
+                if dispatched:
+                    buckets.add((plan.batch_bucket, plan.width_bucket))
+        return (buckets, eng._decode._cache_size(),
+                [list(eng.stream(rid)) for rid in ids], eng.stats())
+
+
+def test_tables_at_one_width_give_the_bucketed_runs_tokens(monkeypatch, tiny_engine_parts):
+    """The mask, not the table's width, decides what a query sees: over
+    requests whose tables grow through four buckets, the run whose decode
+    tables all have the kernel's one width emits, token for token, what the
+    bucketed run emits (the dense reference's), plans the same buckets and
+    counts the same padded keys: the books keep the width the scheduler planned."""
+    cfg, params = tiny_engine_parts
+    bucketed, fixed = (_grown_run(monkeypatch, cfg, params, f, [GROWING]) for f in (False, True))
+    assert fixed[2] == bucketed[2] == [
+        _reference(cfg, params, prompt, new) for prompt, new in GROWING]
+    assert fixed[0] == bucketed[0] and len({w for _, w in fixed[0]}) >= 4
+    assert fixed[3]["attn_keys_padded"] == bucketed[3]["attn_keys_padded"]
+    assert fixed[3]["decode_width_fixed"] == fixed[3]["decode_dispatched"] > 30
+    assert bucketed[3]["decode_width_fixed"] == 0
+
+
+def test_a_warm_plan_compiles_one_decode_program_a_lane_bucket(monkeypatch, tiny_engine_parts):
+    """The benchmark's warm-up (`benchmarks.traffic.warm_plan`: a wave a table
+    width, each passing the lanes through every bucket) replayed through
+    `submit`: lanes x widths decode programs at the scheduler's widths, ONE a
+    lane bucket at the kernel's width, the first wave's; the same tokens."""
+    import numpy as np
+
+    from benchmarks import traffic
+
+    cfg, params = tiny_engine_parts
+    mix = {"pool_seed": 7, "arrivals": {"process": "poisson", "rate_rps": 2.0},
+           "prompt_len": {"dist": "uniform", "min": 2, "max": 40},
+           "output_len": {"dist": "uniform", "min": 2, "max": 20}, "max_total": 64}
+    plan = traffic.warm_plan(mix, 8.0, block_size=4, max_num_seqs=4, chunk=8)[:-1]
+    rng = np.random.default_rng(0)
+    waves = [[(rng.integers(1, cfg.vocab_size, n).tolist(), new) for n, new in wave]
+             for wave in plan]
+    bucketed, fixed = (_grown_run(monkeypatch, cfg, params, f, waves) for f in (False, True))
+    assert len(waves) >= 3 and fixed[2] == bucketed[2]
+    lanes, widths = ({b[i] for b in bucketed[0]} for i in (0, 1))
+    assert lanes == {1, 2, 4} and len(widths) >= len(waves)
+    pinned = sorted(widths)[-3:]    # the widths the last waves' long requests pin
+    assert {(n, w) for n in lanes for w in pinned} <= bucketed[0], bucketed[0]
+    assert bucketed[1] == len(bucketed[0]) >= len(lanes) * len(waves)
+    assert fixed[1] == len(lanes)
 
 
 # ------------------------------------------------- the step's export phase
