@@ -129,7 +129,7 @@ class GPTConfig:
     block_pattern: Optional[str] = None  # a block a character: M Mamba-2 | E experts | * attention
     ssm_heads: int = 0                   # Mamba-2: heads of `ssm_head_dim` channels, B and C shared
     ssm_head_dim: int = 0                # by `ssm_groups` groups of heads, the chunked form's chunk
-    ssm_groups: int = 1; ssm_chunk: int = 128
+    ssm_groups: int = 1; ssm_chunk: int = 128; gdn_interval: int = 0  # > 0: gated delta-net layers, every `gdn_interval`-th gated attention: the END of this file
     moe_select_bias: bool = False; layer_pattern: Optional[str] = None  # by score + a bias | a layer a character, (mw)+mf(gc)+: the END of this file
     norm_eps: float = 1e-6               # the RMSNorms of a `block_pattern` model (1e-6 elsewhere)
     tie_embeddings: bool = True
@@ -248,7 +248,7 @@ class GPTConfig:
                 "layers without learned positions, a dense gated MLP in every layer")
         if self.sandwich_norm and self.parallel_block:
             raise ValueError("sandwich_norm norms each sublayer's output; a parallel_block has one sum")
-        _check_block_pattern(self); _check_layer_pattern(self)
+        _check_block_pattern(self); _check_layer_pattern(self); _check_gdn(self)
 
     @property
     def kv_heads(self) -> int:
@@ -300,7 +300,7 @@ class GPTConfig:
     @property
     def n_params(self) -> int:
         """Parameters HELD (a `moe_held` range counts its own experts)."""
-        if self.block_pattern or self.layer_pattern: return (_pattern_params if self.block_pattern else _sambay_params)(self)
+        if self.block_pattern or self.layer_pattern or self.gdn_interval: return (_pattern_params if self.block_pattern else _sambay_params if self.layer_pattern else _gdn_params)(self)
         E, L, F, V, Hd = self.d_model, self.n_layers, self.d_mlp, self.vocab_size, self.n_heads * self.d_head
         gated = self.activation in ("swiglu", "reglu")
         if self.mlp_type == "moe":
@@ -787,12 +787,12 @@ def param_logical_dims(cfg: GPTConfig) -> Dict[str, Tuple[Optional[str], ...]]:
         dims["w_q"] = ("layers", "embed", "heads", "head_dim")
         dims["w_kv"] = ("layers", "embed", None, "heads", "head_dim")
     if cfg.kv_lora_rank or cfg.dense_layers or cfg.moe_shared or cfg.ssm_layout \
-            or cfg.n_heads_window or cfg.attn_gate or cfg.block_pattern or cfg.layer_pattern:
+            or cfg.n_heads_window or cfg.attn_gate or cfg.block_pattern or cfg.layer_pattern or cfg.gdn_interval:
         raise NotImplementedError(
             "no sharding is written for latent attention (kv_lora_rank), "
             "leading dense layers (dense_layers), a shared expert (moe_shared), "
             "state-space layers (ssm_layout), attention stacks by kind (n_heads_window), "
-            "a gate a head (attn_gate), blocks of one mixer (block_pattern) or a decoder-hybrid-decoder (layer_pattern): they are "
+            "a gate a head (attn_gate), blocks of one mixer (block_pattern) or a decoder-hybrid-decoder (layer_pattern), gated delta-net layers (gdn_interval): they are "
             "served on one chip")
     if cfg.mlp_type == "moe":
         dims["moe_router"] = ("layers", "embed", "experts")
@@ -1008,7 +1008,7 @@ def _lead_stack(params):
 
 def init_params(rng, cfg: GPTConfig) -> Dict[str, jnp.ndarray]:
     if cfg.init == "unit_stream":
-        return (_init_pattern if cfg.block_pattern else _init_sambay if cfg.layer_pattern else _init_unit_stream)(rng, cfg)
+        return (_init_pattern if cfg.block_pattern else _init_sambay if cfg.layer_pattern else _init_gdn if cfg.gdn_interval else _init_unit_stream)(rng, cfg)
     if cfg.init != "gpt2":
         raise ValueError(f"init {cfg.init!r}: gpt2 | unit_stream")
     if cfg.kv_lora_rank or cfg.dense_layers or cfg.moe_shared or cfg.moe_held \
@@ -1358,7 +1358,7 @@ def _refuse_new_fields(cfg: GPTConfig, what: str):
         bad.append("attention stacks by kind (n_heads_window): one head count "
                    "and one rotary table")
     if cfg.attn_gate: bad.append("a gate a head (attn_gate)")
-    if cfg.block_pattern or cfg.layer_pattern: bad.append("blocks of one mixer (block_pattern): three stacks by kind" if cfg.block_pattern else "a decoder-hybrid-decoder (layer_pattern): four stacks by a layer's place, rows that later layers read")
+    if cfg.block_pattern or cfg.layer_pattern or cfg.gdn_interval: bad.append("blocks of one mixer (block_pattern): three stacks by kind" if cfg.block_pattern else "a decoder-hybrid-decoder (layer_pattern): four stacks by a layer's place, rows that later layers read" if cfg.layer_pattern else "gated delta-net layers (gdn_interval): stacks by kind, a state a sequence")
     if bad:
         raise NotImplementedError(f"{what} does not support " + ", ".join(bad))
 
@@ -1651,7 +1651,7 @@ def _logits(params, x, cfg: GPTConfig):
     """Final norm and head over hidden states [..., E] -> [..., V] in
     cfg.dtype; the cache programs hand float32 on. A looped model's stream was normed when
     its last pass closed (`_close_pass`), a `block_pattern` model's by its own eps: no second norm."""
-    if cfg.ut_steps == 1 and not cfg.block_pattern:     # a `block_pattern` model closes its own
+    if cfg.ut_steps == 1 and not cfg.block_pattern and not cfg.gdn_interval:     # a `block_pattern` model closes its own
         x = _norm(x, params["ln_f_w"], params.get("ln_f_b"), cfg.norm)
     head = params["tok_embed"].T if cfg.tie_embeddings else params["lm_head"]
     return jnp.einsum("...e,ev->...v", x, head.astype(cfg.dtype))
@@ -1769,7 +1769,7 @@ def forward(params, tokens, cfg: GPTConfig, positions=None, mesh=None, return_au
     mesh given → automatic pjit partitioning with a nested shard_map around
     the attention core when cfg.attn_impl is ring/ulysses.
     """
-    if cfg.block_pattern or cfg.layer_pattern: return (_pattern_forward if cfg.block_pattern else _sambay_forward)(params, tokens, cfg, return_aux)
+    if cfg.block_pattern or cfg.layer_pattern or cfg.gdn_interval: return (_pattern_forward if cfg.block_pattern else _sambay_forward if cfg.layer_pattern else _gdn_forward)(params, tokens, cfg, return_aux)
     B, S = tokens.shape
     if positions is None:
         # In automatic (pjit) mode shapes are global — plain arange is right.
@@ -1845,7 +1845,7 @@ def _refuse_looped_training(cfg: GPTConfig, what: str):
         ("moe_shared", cfg.moe_shared), ("moe_held", cfg.moe_held),
         ("moe_scoring", cfg.moe_scoring != "softmax"),
         ("ssm_layout", cfg.ssm_layout), ("n_heads_window", cfg.n_heads_window),
-        ("attn_gate", cfg.attn_gate), ("block_pattern", cfg.block_pattern), ("layer_pattern", cfg.layer_pattern)) if on]
+        ("attn_gate", cfg.attn_gate), ("block_pattern", cfg.block_pattern), ("layer_pattern", cfg.layer_pattern), ("gdn_interval", cfg.gdn_interval)) if on]
     if served:
         raise NotImplementedError(
             f"{what} does not train a model with {', '.join(served)}: "
@@ -2413,7 +2413,7 @@ class KVLayout:
 @functools.lru_cache(maxsize=None)
 def kv_layout(cfg: GPTConfig) -> KVLayout:
     L, kinds = cfg.n_layers, cfg.layer_kinds
-    if cfg.block_pattern or cfg.layer_pattern: return (_pattern_layout if cfg.block_pattern else _sambay_layout)(cfg)
+    if cfg.block_pattern or cfg.layer_pattern or cfg.gdn_interval: return (_pattern_layout if cfg.block_pattern else _sambay_layout if cfg.layer_pattern else _gdn_layout)(cfg)
     rows = ((-(-(cfg.kv_lora_rank + cfg.rotary_dim) // 128) * 128, 0)
             if cfg.kv_lora_rank else (cfg.kv_heads * cfg.d_head,) * 2)
     if cfg.ssm_layout:      # rows for the attention layers, a slot's state for the rest
@@ -2476,7 +2476,7 @@ def attn_heads_by_window(cfg: GPTConfig) -> Tuple[Tuple[int, int], ...]:
     """((window or 0, query heads x passes summed over the attention layers of that window), ...): what the
     host's count of a paged program's attention weighs keys by (`ops/paged_attention.py`; the engine
     works it out once)."""
-    if cfg.layer_pattern: return _sambay_heads_by_window(cfg)
+    if cfg.layer_pattern or cfg.gdn_interval: return _sambay_heads_by_window(cfg) if cfg.layer_pattern else ((0, _gdn_counts(cfg)[1] * cfg.n_heads),)
     win = (cfg.layer_kinds or (None, (0,) * cfg.n_layers))[1]
     heads: Dict[int, int] = {}
     for h, w, ssm in zip(cfg.layer_heads, win, _rowless_layers(cfg)):
@@ -2599,7 +2599,7 @@ def _paged_layers(params, tokens, pos, valid, block_tables, kv, cfg: GPTConfig,
             vv = vv.at[slot, ph, off].set(v.astype(vv.dtype))
         return attention_over(q, kk, vv, slot, table,
                               None if kind is None else kind["window"]), (kk, vv)
-
+    if cfg.gdn_interval: return _gdn_paged(cfg, params, x, kv, attend, real, pos, state_slots, rope_tables)
     if cfg.block_pattern: return _paged_blocks(cfg, params, x, kv, attend, real, pos, state_slots)
     stacks = _pop_expert_stacks(cfg, layer_stack)
 
@@ -3650,3 +3650,345 @@ def phi4_mini_flash(**kw):
 
 
 CONFIGS["phi4-mini-flash"] = phi4_mini_flash
+
+
+# ------------------------------ gated delta-net layers (`gdn_interval`, Qwen3-Next)
+# A pre-norm residual stack under ZERO-CENTRED RMSNorms (x / rms(x) * (1 + w), `w`
+# stored: the block norms, the final one and the norms a query and a key head) whose
+# layer l is x <- x + Mixer_l(N1(x)); x <- x + MoE(N2(x)). The mixer is GATED
+# ATTENTION where (l + 1) % `gdn_interval` == 0: `n_heads` query heads over
+# `n_kv_heads` K/V heads of `d_head`, the query projection twice as wide (head n = [q_n
+# | gate_n]), a norm a query and a key head before the rotary term over the first
+# `rotary_dim` features, sigmoid(gate) times the attention output ELEMENTWISE before
+# the output projection. Every other layer is a GATED DELTA NET (`ops/delta.py`):
+# `ssm_heads` value heads of `ssm_head_dim` served by `ssm_groups` key heads of
+# `ssm_state`, `ssm_conv` taps over q, k and v together, chunks of `ssm_chunk`; its
+# state a sequence (`KVLayout.state`: the convolution's tail and the float32 matrix
+# [heads, key, value]). The MLP of EVERY layer is the dropless experts (softmax over
+# the kept logits, a range held under `moe_held`) beside ONE shared expert times
+# sigmoid(w . h), a scalar a token. The weights are stacks by kind: `gdn_*` [delta
+# layers, ...], `ga_*` [attention layers, ...], the norms and the MLP [L, ...]; the
+# walk is a `lax.scan` over the periods of `gdn_interval` layers, inside it a scan over
+# the period's delta layers, each layer's weights read where they lie. Served by
+# `forward` and the paged programs on one chip; everything else refuses it by name.
+# At the END of the file, its callers above edited without moving a line (ROADMAP D20).
+_GDN_KEYS = ("w_qkvz", "w_ba", "conv_w", "dt_bias", "A_log", "norm_w", "w_out")
+
+
+def _check_gdn(cfg: GPTConfig):
+    """`GPTConfig.__post_init__`'s checks of the fields this section reads."""
+    if not cfg.gdn_interval:
+        return
+    if (cfg.gdn_interval < 2 or cfg.n_layers % cfg.gdn_interval or cfg.norm != "rmsnorm"
+            or cfg.pos != "rotary" or cfg.activation != "swiglu" or cfg.mlp_type != "moe"
+            or cfg.moe_routing != "dropless" or cfg.moe_scoring != "softmax"
+            or cfg.moe_router_in != "mlp" or cfg.moe_shared != 1 or cfg.tie_embeddings
+            or cfg.init != "unit_stream" or cfg.block_pattern or cfg.layer_pattern
+            or cfg.ssm_layout or cfg.layer_kinds is not None or cfg.ut_steps > 1
+            or cfg.kv_lora_rank or cfg.dense_layers or cfg.sandwich_norm or cfg.parallel_block
+            or cfg.n_heads_window or cfg.attn_gate or cfg.rope_scaling
+            or cfg.ssm_heads < 1 or cfg.ssm_head_dim < 1 or cfg.ssm_heads % cfg.ssm_groups):
+        raise ValueError(
+            "gdn_interval: periods of gdn_interval >= 2 layers dividing n_layers in a "
+            'one-pass RMSNorm model with an unscaled rotary term, an untied head, '
+            'init="unit_stream", gated delta-net layers (ssm_heads x ssm_head_dim over '
+            "ssm_groups key heads of ssm_state) and, in every layer, dropless softmax-"
+            "routed gated experts on the normed MLP input beside one shared expert")
+
+
+def _gdn_counts(cfg: GPTConfig) -> Tuple[int, int]:
+    """(gated delta-net layers, gated attention layers)."""
+    periods = cfg.n_layers // cfg.gdn_interval
+    return cfg.n_layers - periods, periods
+
+
+def _gdn_conv_width(cfg: GPTConfig) -> int:
+    """Channels under the delta net's convolution: q, k and v together."""
+    return 2 * cfg.ssm_groups * cfg.ssm_state + cfg.ssm_heads * cfg.ssm_head_dim
+
+
+def _gdn_params(cfg: GPTConfig) -> int:
+    """`GPTConfig.n_params` of a `gdn_interval` model: what the tree holds."""
+    E, F, V, Hs = cfg.d_model, cfg.d_mlp, cfg.vocab_size, cfg.ssm_heads
+    Dv, Dc, Hd = Hs * cfg.ssm_head_dim, _gdn_conv_width(cfg), cfg.n_heads * cfg.d_head
+    delta = (E * (Dc + Dv) + E * 2 * Hs + Dc * cfg.ssm_conv + 2 * Hs + cfg.ssm_head_dim
+             + Dv * E)
+    attn = E * 2 * Hd + 2 * E * cfg.kv_heads * cfg.d_head + Hd * E + 2 * cfg.d_head
+    mlp = (cfg.held_experts + 1) * 3 * E * F + E * cfg.moe_experts + E
+    n_delta, n_attn = _gdn_counts(cfg)
+    return (n_delta * delta + n_attn * attn + cfg.n_layers * (mlp + 2 * E)
+            + 2 * V * E + E)
+
+
+def _init_gdn(rng, cfg: GPTConfig) -> Dict[str, jnp.ndarray]:
+    """`init_params` of a `gdn_interval` model under init="unit_stream": a matrix of
+    fan-in n has std gain / sqrt(n), its gain from the preset's `init_gains`; the
+    zero-centred norms' stored gains N(0, `norm`) (published: 0; seeded so that a
+    plain gain reads otherwise), the delta net's inner gain 1; its steps log-uniform
+    in 0.001-0.1 through `dt_bias` (softplus inverted) and A = -(1 .. 16) a head:
+    Mamba-2's ranges (published: `dt_bias` 1, A uniform in 0-16), for decays a token
+    from near 1 down to exp(-4)."""
+    E, F, V, X = cfg.d_model, cfg.d_mlp, cfg.vocab_size, cfg.held_experts
+    H, Hkv, Dh, Hs, K = cfg.n_heads, cfg.kv_heads, cfg.d_head, cfg.ssm_heads, cfg.ssm_conv
+    Dv, Dc, L = Hs * cfg.ssm_head_dim, _gdn_conv_width(cfg), cfg.n_layers
+    (Ld, La), dt, g = _gdn_counts(cfg), cfg.param_dtype, dict(cfg.init_gains)
+    keys = iter(jax.random.split(rng, 32))
+
+    def n(shape, gain, fan_in):
+        return (jax.random.normal(next(keys), shape, jnp.float32)
+                * (gain / math.sqrt(fan_in))).astype(dt)
+
+    step = jnp.exp(jax.random.uniform(next(keys), (Ld, Hs), jnp.float32,
+                                      math.log(1e-3), math.log(1e-1)))
+    return {
+        "tok_embed": n((V, E), g["embed"], 1), "lm_head": n((E, V), g["head"], E),
+        "ln_f_w": n((E,), g["norm"], 1),
+        "ln1_w": n((L, E), g["norm"], 1), "ln2_w": n((L, E), g["norm"], 1),
+        "gdn_w_qkvz": n((Ld, E, Dc + Dv), g["ssm_in"], E),
+        "gdn_w_ba": n((Ld, E, 2 * Hs), g["ssm_ba"], E),
+        "gdn_conv_w": n((Ld, K, Dc), g["ssm_conv"], K),
+        "gdn_dt_bias": (step + jnp.log(-jnp.expm1(-step))).astype(dt),
+        "gdn_A_log": jnp.log(jax.random.uniform(next(keys), (Ld, Hs), jnp.float32,
+                                                1.0, 16.0)).astype(dt),
+        "gdn_norm_w": jnp.ones((Ld, cfg.ssm_head_dim), dt),
+        "gdn_w_out": n((Ld, Dv, E), g["ssm_out"], Dv),
+        "ga_w_q": n((La, E, H, 2 * Dh), g["q"], E),
+        "ga_w_kv": jnp.stack([n((La, E, Hkv, Dh), g["k"], E),
+                              n((La, E, Hkv, Dh), g["v"], E)], axis=2),
+        "ga_q_norm_w": n((La, Dh), g["norm"], 1), "ga_k_norm_w": n((La, Dh), g["norm"], 1),
+        "ga_w_o": n((La, H, Dh, E), g["o"], H * Dh),
+        "moe_router": n((L, E, cfg.moe_experts), g["router"], E),
+        "moe_w_gate": n((L, X, E, F), g["mlp_in"], E),
+        "moe_w_in": n((L, X, E, F), g["mlp_in"], E),
+        "moe_w_out": n((L, X, F, E), g["expert_out"], F),
+        "shared_w_gate": n((L, E, F), g["mlp_in"], E),
+        "shared_w_in": n((L, E, F), g["mlp_in"], E),
+        "shared_w_out": n((L, F, E), g["mlp_out"], F),
+        "shared_gate": n((L, E), g["shared_gate"], E),
+    }
+
+
+def _gdn_layout(cfg: GPTConfig) -> KVLayout:
+    """`kv_layout` of a `gdn_interval` model: ONE group as deep as the attention
+    layers, a state a sequence for the delta layers (the convolution's last inputs
+    as one row in the compute dtype; the float32 matrix [heads, key, value]),
+    `slot_of` a layer's index among its kind."""
+    from ..ops import delta
+
+    n_delta, n_attn = _gdn_counts(cfg)
+    slot_of = [l // cfg.gdn_interval if (l + 1) % cfg.gdn_interval == 0
+               else l - l // cfg.gdn_interval for l in range(cfg.n_layers)]
+    row = cfg.kv_heads * cfg.d_head
+    return KVLayout(
+        n_attn, (0,), (0,) * cfg.n_layers, tuple(slot_of), 1, row, row, n_delta,
+        (("conv", ((cfg.ssm_conv - 1) * _gdn_conv_width(cfg),), jnp.dtype(cfg.dtype).name),
+         ("gdn", delta.delta_state_shape(cfg.ssm_heads, cfg.ssm_state, cfg.ssm_head_dim),
+          "float32")))
+
+
+def _zero_centred_norm(x, w, eps):
+    """x / rms(x) * (1 + w) over the last axis, float32 inside."""
+    return rmsnorm(x, 1.0 + w.astype(jnp.float32), eps)
+
+
+def _gdn_attention(cfg: GPTConfig, p, h, rope_tables, positions, attend):
+    """The gated attention mixer over h [B, S, E]: `attend(q [B, H, S, Dh], k, v [B,
+    Hkv, S, Dh]) -> (attention [B, H, S, Dh], store)`; `p` the layer's `ga_*`
+    weights. Returns (what it adds to the stream, store)."""
+    dt, Dh = cfg.dtype, cfg.d_head
+    qg = jnp.einsum("bse,ehd->bhsd", h, p["w_q"].astype(dt))           # head n: [q_n | gate_n]
+    kv = jnp.einsum("bse,etgd->tbgsd", h, p["w_kv"].astype(dt))
+    q = _zero_centred_norm(qg[..., :Dh], p["q_norm_w"], cfg.norm_eps)
+    k = _zero_centred_norm(kv[0], p["k_norm_w"], cfg.norm_eps)
+    q, k = _rotary(cfg, rope_tables, positions, q, k)
+    attn, store = attend(q, k, kv[1])
+    gate = jax.nn.sigmoid(qg[..., Dh:].astype(jnp.float32))
+    return _merge_heads((attn * gate).astype(dt), p["w_o"].astype(dt)), store
+
+
+def _gdn_mlp(cfg: GPTConfig, params, l, h, valid):
+    """Layer l's MLP over h [B, S, E] (normed): (held experts' part + the gated
+    shared expert, the routing's load)."""
+    dt = cfg.dtype
+    y, load = _dropless_mlp(
+        cfg, params["moe_router"][l],
+        (params["moe_w_gate"], params["moe_w_in"], params["moe_w_out"]), h, h,
+        layer=l, valid=valid)
+    shared = _gated_mlp(cfg, h, *(params[k][l].astype(dt) for k in (
+        "shared_w_gate", "shared_w_in", "shared_w_out")))
+    gate = jax.nn.sigmoid(jnp.einsum(       # float32, as the router's logits
+        "bse,e->bs", h.astype(jnp.float32), params["shared_gate"][l].astype(jnp.float32)))
+    return y + (shared * gate[..., None]).astype(dt), load
+
+
+def _gdn_layers(cfg: GPTConfig, params, x, store, valid, delta_net, attention):
+    """The layer walk over x [B, S, E]. `store` is whatever the mixers keep between
+    layers (the pool and the state arrays); it rides both scans' carry.
+    `delta_net(store, i, p, h) -> (out, store)`: delta layer i (among its kind), `p`
+    its weights under `ops/delta.py`'s names; `attention(store, i, p, h) -> (out,
+    store)`: attention layer i. Returns (x under the FINAL norm, which `_logits` then
+    leaves alone, store, the routing's load summed over the layers)."""
+    dt, per = cfg.dtype, cfg.gdn_interval
+    norm = lambda x, w: _zero_centred_norm(x, w, cfg.norm_eps)
+
+    def layer(x, loads, l, mixer):
+        out, kept = mixer(norm(x, params["ln1_w"][l]))
+        x = x + out
+        y, load = _gdn_mlp(cfg, params, l, norm(x, params["ln2_w"][l]), valid)
+        return x + y, loads + load, kept
+
+    def delta_layer(carry, idx):
+        x, loads, store = carry
+        l, i = idx
+        p = {name: params["gdn_" + name][i].astype(dt) for name in _GDN_KEYS}
+        x, loads, store = layer(x, loads, l, lambda h: delta_net(store, i, p, h))
+        return (x, loads, store), None
+
+    def period(carry, n):
+        first = n * per
+        carry, _ = jax.lax.scan(delta_layer, carry, (
+            first + jnp.arange(per - 1), n * (per - 1) + jnp.arange(per - 1)))
+        x, loads, store = carry
+        p = {name[3:]: a[n] for name, a in params.items() if name.startswith("ga_")}
+        x, loads, store = layer(x, loads, first + per - 1,
+                                lambda h: attention(store, n, p, h))
+        return (x, loads, store), None
+
+    loads = jnp.zeros((5 if cfg.moe_held else 2,), jnp.float32)
+    (x, loads, store), _ = jax.lax.scan(
+        period, (x, loads, store), jnp.arange(cfg.n_layers // per))
+    return norm(x, params["ln_f_w"]), store, loads
+
+
+def _gdn_paged(cfg: GPTConfig, params, x, kv, attend, real, pos, state_slots, rope_tables):
+    """`_paged_layers` for a `gdn_interval` model, from its embedding x [B, S, E] on:
+    the attention layers through `_paged_layers`' own `attend` over a pool as deep as
+    they are many, the delta layers from the state of lane b's slot `state_slots[b]`
+    in kv["state"] (gathered, advanced over the lane's real tokens, written back in
+    place), a lane whose first token sits at position 0 from a ZERO state, as a
+    state-space layer of `ssm_layout` does. Returns what `_paged_layers` returns."""
+    if state_slots is None:
+        raise NotImplementedError(
+            "a model with gated delta-net layers (gdn_interval) is served by "
+            "prefill_paged and decode_step_paged, which name each lane's state slot; "
+            "a verify step would have to roll the state back past the drafts it rejects")
+    from ..ops import delta
+
+    B = x.shape[0]
+    fresh = (pos[:, 0] == 0)[:, None]
+    tail_shape = (B, cfg.ssm_conv - 1, _gdn_conv_width(cfg))
+
+    def delta_net(store, i, p, h):
+        kk, vv, conv, state = store
+        tail = jnp.where(fresh, 0, conv[i, state_slots])
+        s0 = jnp.where(fresh[..., None, None], 0, state[i, state_slots])
+        out, tail, s = delta.gated_delta_mixer(
+            p, h, tail.reshape(tail_shape), s0, real, key_heads=cfg.ssm_groups,
+            chunk=cfg.ssm_chunk, eps=cfg.norm_eps)
+        return out, (kk, vv, conv.at[i, state_slots].set(tail.reshape(B, -1)),
+                     state.at[i, state_slots].set(s))
+
+    def attention(store, i, p, h):
+        kk, vv, conv, state = store
+        out, (kk, vv) = _gdn_attention(
+            cfg, p, h, rope_tables, pos,
+            lambda q, k, v: attend(kk, vv, i, None, q, k, v, None))
+        return out, (kk, vv, conv, state)
+
+    st = kv["state"]
+    x, (kk, vv, conv, state), loads = _gdn_layers(
+        cfg, params, x, (kv["k"], kv["v"], st["conv"], st["gdn"]), real, delta_net, attention)
+    return (x, {"k": kk, "v": vv, "state": {"conv": conv, "gdn": state}},
+            loads / cfg.n_layers, None)
+
+
+def _gdn_forward(params, tokens, cfg: GPTConfig, return_aux: bool):
+    """`forward` of a `gdn_interval` model: the whole sequence, every sequence's
+    state from zero and dropped at the end, plain causal attention."""
+    from ..ops import delta
+
+    B, S = tokens.shape
+    x = _embed(params, tokens, None, cfg)
+    everyone, positions = jnp.ones((B, S), bool), jnp.arange(S)
+    tail = jnp.zeros((B, cfg.ssm_conv - 1, _gdn_conv_width(cfg)), cfg.dtype)
+    s0 = jnp.zeros((B, *delta.delta_state_shape(cfg.ssm_heads, cfg.ssm_state, cfg.ssm_head_dim)),
+                   jnp.float32)
+    rope_tables = _rope_tables(cfg)
+
+    def delta_net(store, i, p, h):
+        return delta.gated_delta_mixer(p, h, tail, s0, everyone, key_heads=cfg.ssm_groups,
+                                       chunk=cfg.ssm_chunk, eps=cfg.norm_eps)[0], store
+
+    def attention(store, i, p, h):
+        return _gdn_attention(
+            cfg, p, h, rope_tables, positions,
+            lambda q, k, v: (_attention_plain(cfg, q, k, v, positions), store))
+
+    x, _, _ = _gdn_layers(cfg, params, x, (), everyone, delta_net, attention)
+    logits = _logits(params, x, cfg)
+    return (logits, jnp.zeros((), jnp.float32)) if return_aux else logits
+
+
+def qwen3_next_80b_a3b(**kw):
+    """Qwen3-Next-80B-A3B-Instruct (huggingface.co/Qwen/Qwen3-Next-80B-A3B-Instruct,
+    `model_type: "qwen3_next"`): 48 layers of 2048 under zero-centred RMSNorms (eps
+    1e-6), layer l gated attention where (l + 1) % 4 == 0 (16 query heads over 2 K/V
+    heads of 256, a norm a query and a key head, rotary over the first 64 features,
+    theta 1e7, an elementwise sigmoid gate on the output) and else a gated delta net
+    (32 value heads of 128 served by 16 key heads of 128, 4 taps, chunks of 64); in
+    every layer 512 softmax-routed SiLU-gated experts of 512 (top-10, normalised)
+    beside one shared expert under a sigmoid gate a token; vocabulary 151,936, untied
+    head; no bias. Serving only: `forward` and the paged programs. The benchmark runs
+    stage 0 of six (8 layers), 128 of the 512 experts held, a quarter of the
+    vocabulary (benchmarks/configs)."""
+    return GPTConfig(
+        **{
+            **dict(
+                n_layers=48,
+                gdn_interval=4,
+                d_model=2048,
+                n_heads=16,
+                n_kv_heads=2,
+                d_head=256,
+                d_mlp=512,
+                vocab_size=151936,
+                max_seq=262144,
+                norm="rmsnorm",
+                norm_eps=1e-6,
+                activation="swiglu",
+                pos="rotary",
+                rotary_dim=64,
+                rope_theta=1e7,
+                tie_embeddings=False,
+                ssm_heads=32,
+                ssm_head_dim=128,
+                ssm_groups=16,
+                ssm_state=128,
+                ssm_conv=4,
+                ssm_chunk=64,
+                mlp_type="moe",
+                moe_routing="dropless",
+                moe_experts=512,
+                moe_top_k=10,
+                moe_scoring="softmax",
+                moe_router_in="mlp",
+                moe_shared=1,
+                param_dtype=jnp.bfloat16,
+                # `laguna_xs2`'s gains for what the two share (a stream of 1.5, the
+                # router, the experts' and the shared expert's outputs); the delta
+                # net's by `scripts/qwen3next_tolerance.py` on the chip (PERF.md §6,
+                # PR 58). No program's shape or time depends on the numbers.
+                init="unit_stream",
+                init_gains=(("embed", 1.5), ("head", 1.0), ("norm", 0.1), ("q", 1.5),
+                            ("k", 1.5), ("v", 1.0), ("o", 0.9), ("router", 2.0),
+                            ("mlp_in", 1.0), ("mlp_out", 0.5), ("expert_out", 0.1),
+                            ("shared_gate", 1.0), ("ssm_in", 1.0), ("ssm_ba", 1.0),
+                            ("ssm_conv", 1.0), ("ssm_out", 1.0)),
+                attn_impl="ref",
+            ),
+            **kw,
+        }
+    )
+
+
+CONFIGS["qwen3-next-80b-a3b"] = qwen3_next_80b_a3b

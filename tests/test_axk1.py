@@ -526,8 +526,8 @@ def test_config_and_architecture_module_refuse_what_is_not_the_model():
     assert (full.n_layers, full.dense_layers, full.moe_experts, full.moe_top_k) == (61, 1, 192, 8)
     assert 518e9 < full.n_params < 520e9            # the published 519 B
     # PR 40: ssm_*; PR 43: window kind, gate; PR 51: block_pattern, Mamba-2's four, the bias, norm_eps;
-    # PR 56: layer_pattern
-    assert len(dataclasses.fields(gpt.GPTConfig)) == 34 + 10 + 5 + 4 + 7 + 1
+    # PR 56: layer_pattern; PR 58: gdn_interval
+    assert len(dataclasses.fields(gpt.GPTConfig)) == 34 + 10 + 5 + 4 + 7 + 1 + 1
 
 
 # ------------------------------------------------- the models the repo had

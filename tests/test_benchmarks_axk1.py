@@ -154,7 +154,7 @@ def test_the_cell_and_its_files_are_in_the_benchmark():
     assert all(per_layer[name]["moves"] in e2e for name in layer)
     names = [m["name"] for m in bench["per_layer"]]     # later PRs append
     assert names.index("moe_held_assign_share") < names.index("moe_grouped_token_share")
-    assert per_layer["moe_grouped_token_share"]["workloads"] == [
+    assert per_layer["moe_grouped_token_share"]["workloads"][:2] == [
         "smallthinker-21b-a3b.mixed-len", CELL]
     assert per_layer["moe_grouped_token_share"]["moves"] == "ttft_mean_ms"
     assert readers.reader_spec("moe_grouped_token_share") == {

@@ -204,8 +204,8 @@ def test_the_cell_and_its_files_are_in_the_benchmark():
     first = names.index(next(iter(NEW_METRICS)))        # appended, in the issue's order;
     assert names[first:first + 4] == list(NEW_METRICS)  # PR 41's two behind them
     assert names[first + 4:first + 6] == ["chunk_attn_kernel_share", "chunk_attn_time_share"]
-    for name in NEW_METRICS:       # PR 51's cell reads two of them too
-        assert per_layer[name]["workloads"][0] == CELL and len(per_layer[name]["workloads"]) <= 2
+    for name in NEW_METRICS:       # later cells read some of them too, behind it
+        assert per_layer[name]["workloads"][0] == CELL
     assert per_layer["state_slot_util_share"]["layer"] == "engine scheduler and KV"
     assert per_layer["ssm_scan_time_share"]["source"] == "device_trace"
     for name in layer:

@@ -214,8 +214,8 @@ def test_the_cell_and_its_files_are_in_the_benchmark():
                                     "relayout_time_share", "relayout_time_share.itl"]    # PR 51's behind
     assert {"decode_attn_kernel_share", "decode_attn_time_share",
             "relayout_time_share.itl"} <= set(layer)
-    for name in NEW_METRICS:       # PR 51's cell reads `moe_grouped_time_share` too
-        assert per_layer[name]["workloads"][0] == CELL and len(per_layer[name]["workloads"]) <= 2
+    for name in NEW_METRICS:       # later cells read `moe_grouped_time_share` too, behind it
+        assert per_layer[name]["workloads"][0] == CELL
     assert per_layer["kv_window_block_share"]["layer"] == "engine scheduler and KV"
     assert per_layer["moe_grouped_time_share"]["source"] == "device_trace"
     for name in layer:

@@ -66,23 +66,37 @@ def obs():
 
 
 def test_rehearsal_is_correct_and_counts_state_slots_experts_and_blocks(obs):
+    """The counters are the window's deltas of two snapshots that another thread takes
+    WHILE the step thread books a program (the window closes on requests in flight), so
+    an identity between two counters holds up to ONE program at either end: the
+    rehearsal's step budget (48 tokens, 8 lanes). Held exactly it failed one run in
+    some under the driver's six workers (PR 57's run) and never alone."""
     checks = obs["checks"]
     assert all(v for v in checks.values() if isinstance(v, bool)), checks
     assert checks["tokens_match_reference"] and checks["token_err"] < 0.03
     assert obs["failed"] == 0 and obs["attempted"] > 0
     c, m = obs["counters"], obs["facts"]["model"]
+    opts = obs["facts"]["engine_options"]
+    program = 2 * opts["max_step_tokens"]        # a program's tokens, at both ends
     assert c["state_slots_claimed"] >= c["total_finished"] > 0 and c["prefix_hits"] == 0
-    assert c["ssm_tokens_scanned"] == c["prefill_tokens_padded"] + c["decode_bucket_lanes"]
-    assert c["ssm_state_bytes"] == 2 * c["decode_lanes"] * arch.state_bytes(m)
+    assert abs(c["ssm_tokens_scanned"]
+               - c["prefill_tokens_padded"] - c["decode_bucket_lanes"]) <= program
+    assert abs(c["ssm_state_bytes"] - 2 * c["decode_lanes"] * arch.state_bytes(m)) <= \
+        2 * 2 * opts["max_num_seqs"] * arch.state_bytes(m)
     # the rehearsal's pattern MEM*E: two Mamba-2 blocks, two expert blocks, one attention
-    assert (c["blocks_ssm"], c["blocks_moe"], c["blocks_attn"]) == \
-        (2 * c["blocks_run"] // 5, 2 * c["blocks_run"] // 5, c["blocks_run"] // 5)
+    run = c["blocks_run"]
+    assert run > 500 and all(abs(5 * c["blocks_" + kind] - n * run) <= 2 * 5 * 5
+                             for kind, n in (("ssm", 2), ("moe", 2), ("attn", 1)))
     assert 0 < c["moe_assign_held"] < c["moe_assign_total"]         # 4 of 8 experts held
     assert readers.read("ssd_state_mb_step", obs) == 1e-6 * c["ssm_state_bytes"] / c["steps_decode"]
-    assert readers.read("ssd_block_share", obs) == readers.read("moe_block_share", obs) == 40.0
+    assert abs(readers.read("ssd_block_share", obs) - 40.0) < 0.5
+    assert abs(readers.read("moe_block_share", obs) - 40.0) < 0.5
+    # the gauges by their integer books (PR 53): a span that has not reached the
+    # controller a second after the window reads as nothing, the books never
     for name in ("state_slot_util_share", "ssm_masked_token_share", "moe_held_assign_share",
-                 "moe_experts_touched_mean", "moe_expert_load_max", "engine_step_ms_books",
-                 "step_build_ms_books", "step_fetch_ms_books", "step_export_ms_books"):
+                 "moe_experts_touched_mean_books", "moe_expert_load_max_books",
+                 "engine_step_ms_books", "step_build_ms_books", "step_fetch_ms_books",
+                 "step_export_ms_books"):
         assert readers.read(name, obs) > 0, name
     assert 20 < readers.read("moe_held_assign_share", obs) < 80
 
@@ -222,10 +236,10 @@ def test_the_cell_and_its_files_are_in_the_benchmark():
         mine = per_layer[name]
         assert {**mine, "workloads": None} == {**base, "name": name, "moves": "itl_p90_ms",
                                                "workloads": None}
-        assert mine["workloads"][0] == CELL and len(mine["workloads"]) <= 2   # PR 56's cell reads them too
+        assert mine["workloads"][0] == CELL         # later cells read them too, behind it
     assert per_layer["ssd_time_share"]["source"] == "device_trace"
     for name in JOINED:
-        assert CELL in per_layer[name]["workloads"][-2:]         # appended to the list (PR 56's behind it)
+        assert CELL in per_layer[name]["workloads"][1:]          # appended to the list (later cells behind it)
     for name in layer:
         assert readers.reader_spec(name)["kind"] in readers.KINDS, name
     # every published key of the catalog's row, under its own name; three reduced
